@@ -646,21 +646,31 @@ def cmd_simulate(args) -> int:
     return 1
 
 
-def cmd_serve(args) -> int:
-    """OpenAI-compatible HTTP endpoint over the serving engine."""
+class ServeConfigError(ValueError):
+    """The config file cannot be served; ``str()`` is the operator message."""
+
+
+def build_server(config_path, host: str = "127.0.0.1", port: int = 8000,
+                 allow_runtime_adapters: bool = False):
+    """Config file -> a constructed (not yet serving) ``OpenAIServer``.
+
+    THE construction path of ``runbook serve``: load + validate the
+    config, build the engine client from ``config.llm``, front it with the
+    HTTP server. ``chip_smoke.py`` calls this same function, so what the
+    smoke proves on the chip is what the CLI starts. ``server.client`` is
+    the ``JaxTpuClient``; ``server.shutdown()`` stops everything built
+    here."""
     from runbookai_tpu.model.jax_tpu import JaxTpuClient
     from runbookai_tpu.server.openai_api import OpenAIServer
 
-    config = _load(args)
+    config = load_config(path=config_path)
     if config.llm.provider != "jax-tpu":
-        print("serve requires llm.provider: jax-tpu (a real engine to serve)",
-              file=sys.stderr)
-        return 1
+        raise ServeConfigError(
+            "serve requires llm.provider: jax-tpu (a real engine to serve)")
     problems = [p for p in validate_config(config) if "llm." in p]
     if problems:
-        for p in problems:
-            print(f"config error: {p}", file=sys.stderr)
-        return 1
+        raise ServeConfigError(
+            "\n".join(f"config error: {p}" for p in problems))
     client = JaxTpuClient.from_config(config.llm)
     # Multi-model fleets serve under the DEFAULT group's name; the
     # request's model field selects any group (GET /v1/models lists all).
@@ -706,13 +716,24 @@ def cmd_serve(args) -> int:
     elif emb_cfg.enabled:
         print("note: /v1/embeddings disabled — set knowledge.embedder."
               "model_path to serve real bge embeddings", file=sys.stderr)
-    server = OpenAIServer(client, model_name=served_name,
-                          host=args.host, port=args.port,
-                          allow_runtime_adapters=args.allow_adapter_loading,
-                          embedder=embedder)
-    print(f"serving {served_name} at http://{args.host}:{server.port}/v1 "
+    return OpenAIServer(client, model_name=served_name,
+                        host=host, port=port,
+                        allow_runtime_adapters=allow_runtime_adapters,
+                        embedder=embedder)
+
+
+def cmd_serve(args) -> int:
+    """OpenAI-compatible HTTP endpoint over the serving engine."""
+    try:
+        server = build_server(getattr(args, "config", None), args.host,
+                              args.port, args.allow_adapter_loading)
+    except ServeConfigError as e:
+        print(e, file=sys.stderr)
+        return 1
+    print(f"serving {server.model_name} at "
+          f"http://{args.host}:{server.port}/v1 "
           f"(POST /v1/chat/completions"
-          + (", /v1/embeddings" if embedder else "")
+          + (", /v1/embeddings" if server.embedder else "")
           + ", GET /v1/models, /healthz, /metrics, /debug/steps)")
     try:
         server.serve_forever()
@@ -1362,8 +1383,8 @@ def cmd_tune(args) -> int:
     import os
 
     if args.smoke and not os.environ.get("JAX_PLATFORMS"):
-        # The smoke path is a CPU contract — don't let a half-up
-        # accelerator plugin hang a bounded-time sweep.
+        # --smoke asks for the CPU by name: a bounded sweep of the tiny
+        # model, wherever it is run.
         os.environ["JAX_PLATFORMS"] = "cpu"
 
     from runbookai_tpu.autotune.cost_model import (
@@ -1429,11 +1450,13 @@ def cmd_tune(args) -> int:
         if hw_name == "auto":
             import jax
 
-            if jax.default_backend() == "cpu":
-                hw_name = "cpu"
-            else:
-                kind = jax.devices()[0].device_kind.lower()
-                hw_name = "v6e" if "v6" in kind else "v5e"
+            from runbookai_tpu.autotune.cost_model import hardware_for
+
+            try:
+                hw_name = hardware_for(jax.devices()[0]).name
+            except KeyError as e:
+                print(e.args[0], file=sys.stderr)
+                return 1
         hw, weights = HARDWARE[hw_name], args.weights
     out = args.out or str(
         Path(config.runbook_dir) / "plans" / f"{model}.{hw.name}.json")
@@ -1840,7 +1863,7 @@ def build_parser() -> argparse.ArgumentParser:
     tune.add_argument("--smoke", action="store_true",
                       help="bounded CPU smoke sweep (tiny model + space)")
     tune.add_argument("--hw", default="auto",
-                      choices=["auto", "v5e", "v6e", "v5e-tunnel", "cpu"],
+                      choices=["auto", "v5e", "v6e", "cpu"],
                       help="hardware envelope for the cost model")
     tune.add_argument("--weights", default="int8", choices=["int8", "bf16"])
     tune.add_argument("--prompt-len", type=int, default=512)
@@ -2087,8 +2110,11 @@ def build_parser() -> argparse.ArgumentParser:
 
 
 def main(argv=None) -> int:
+    from runbookai_tpu.utils.compile_cache import ensure_compile_cache
+
     parser = build_parser()
     args = parser.parse_args(argv)
+    ensure_compile_cache()
     try:
         return args.fn(args)
     except KeyboardInterrupt:

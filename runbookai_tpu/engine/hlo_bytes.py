@@ -161,8 +161,6 @@ def decode_accounting(core, compiled=None) -> dict[str, float]:
     compiled = compiled if compiled is not None else lower_decode(core)
     ma = compiled.memory_analysis()
     ca = compiled.cost_analysis() or {}
-    if isinstance(ca, (list, tuple)):  # older jaxlib: list of per-program dicts
-        ca = ca[0] if ca else {}
     weights = param_nbytes(core.params)
     kv = kv_pool_nbytes(core)
     return {
@@ -172,11 +170,7 @@ def decode_accounting(core, compiled=None) -> dict[str, float]:
         "argument_size_in_bytes": ma.argument_size_in_bytes,
         "temp_size_in_bytes": ma.temp_size_in_bytes,
         "output_size_in_bytes": ma.output_size_in_bytes,
-        # Renamed across jaxlib versions (CompiledMemoryStats); absent on
-        # some builds — NaN rather than AttributeError, the accounting
-        # contract is the argument/temp/output split above.
-        "peak_memory_in_bytes": float(getattr(
-            ma, "peak_memory_in_bytes", float("nan"))),
+        "peak_memory_in_bytes": float(ma.peak_memory_in_bytes),
         "bytes_accessed": float(ca.get("bytes accessed", float("nan"))),
         "flops": float(ca.get("flops", float("nan"))),
     }

@@ -202,6 +202,9 @@ class JaxTpuClient(BaseLLMClient):
         # zero history surface (/debug/query reports itself disabled,
         # /healthz has no history block, bundles no lookback).
         self.tsdb = None
+        # Fleet supervisors (chaos/supervisor.py, wired by
+        # _wire_supervisors in from_config); shutdown() stops them.
+        self.supervisors: list = []
 
     # --------------------------------------------------------- model groups
 
@@ -455,8 +458,67 @@ class JaxTpuClient(BaseLLMClient):
                                        sampling):
             yield piece
 
+    def runtime_info(self) -> dict:
+        """What this process actually serves on, as resolved — the
+        ``runtime`` block of ``/healthz``. Device facts are JAX's own;
+        the implementations are the engine's config AFTER its static
+        rules and kernel probes (``EngineCore.__init__``), not what the
+        YAML asked for."""
+        import jax
+
+        from runbookai_tpu.engine.hlo_bytes import (
+            kv_pool_nbytes,
+            param_nbytes,
+        )
+        from runbookai_tpu.models.quant import is_quantized
+        from runbookai_tpu.native import NativePageAllocator
+
+        core = self.core
+        devices = jax.devices()
+
+        def device_row(d) -> dict:
+            stats = d.memory_stats() or {}  # the CPU backend reports none
+            return {"id": d.id,
+                    "bytes_in_use": stats.get("bytes_in_use"),
+                    "peak_bytes_in_use": stats.get("peak_bytes_in_use"),
+                    "bytes_limit": stats.get("bytes_limit")}
+
+        quantized = any(is_quantized(v)
+                        for v in core.params["layers"].values())
+        return {
+            "platform": devices[0].platform,
+            "device_kind": devices[0].device_kind,
+            "device_count": len(devices),
+            "devices": [device_row(d) for d in devices],
+            "model": core.cfg.name,
+            "n_layers": core.cfg.n_layers,
+            "weight_dtype": ("int8" if quantized
+                             else str(core.params["embed"].dtype)),
+            "kv_dtype": jnp.dtype(core.ecfg.kv_dtype).name,
+            "attn_impl": core.ecfg.attn_impl,
+            "qmm_impl": core.ecfg.qmm_impl,
+            "mixed_dispatch": core._mixed,
+            "overlap_decode": core.ecfg.overlap_decode,
+            "allocator": ("native" if isinstance(core.kv.allocator,
+                                                 NativePageAllocator)
+                          else "python"),
+            # Per engine replica, summed over the devices it spans.
+            "weight_bytes": param_nbytes(core.params),
+            "kv_pool_bytes": kv_pool_nbytes(core),
+            "compile_cache_dir": jax.config.jax_compilation_cache_dir,
+            # Which devices hold each engine replica's KV pool.
+            "replicas": [
+                sorted(d.id for d in
+                       jax.tree.leaves(c._kv_k)[0].devices())
+                for c in self.cores],
+        }
+
     async def shutdown(self) -> None:
-        tsdb = getattr(self, "tsdb", None)
-        if tsdb is not None:
-            tsdb.stop()
+        """Stop everything ``from_config`` started: the sampler threads
+        (metric history, incident monitor), the fleet supervisors, then
+        the engine loops — so the process that holds the chip can exit."""
+        for stoppable in (self.tsdb, self.incident_monitor,
+                          *self.supervisors):
+            if stoppable is not None:
+                stoppable.stop()
         await self.engine.stop()

@@ -24,7 +24,7 @@ from typing import Any, Optional
 
 import jax
 
-from runbookai_tpu.models.llama import CONFIGS, LlamaConfig
+from runbookai_tpu.models.llama import LlamaConfig
 
 _CONFIG_FILE = "config.json"
 _TREE_DIR = "pytree"
@@ -137,13 +137,19 @@ def convert_hf_to_checkpoint(
 
     from runbookai_tpu.models.hf_loader import load_or_init
 
-    if not Path(model_path).exists() and not allow_random_init:
-        raise FileNotFoundError(
-            f"weights convert: model_path does not exist: {model_path} "
-            "(pass --random-init to write a random-weights checkpoint)")
+    if not Path(model_path).exists():
+        if not allow_random_init:
+            raise FileNotFoundError(
+                f"weights convert: model_path does not exist: {model_path} "
+                "(pass --random-init to write a random-weights checkpoint)")
+        if model_name == "hf-model":
+            # Random init asked for and no model named: the tiny test
+            # model, said here and not left to the loader (an unknown
+            # name given on purpose raises there).
+            model_name = "llama3-test"
 
     cfg, params = load_or_init(
-        model_name if model_name in CONFIGS else "hf-model",
-        model_path, dtype=dtype or jnp.bfloat16, quantize_int8=quantize_int8,
+        model_name, model_path, dtype=dtype or jnp.bfloat16,
+        quantize_int8=quantize_int8,
     )
     return save_checkpoint(out_path, cfg, params)

@@ -19,7 +19,12 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from runbookai_tpu.models.llama import CONFIGS, LlamaConfig, init_params
+from runbookai_tpu.models.llama import (
+    CONFIGS,
+    LlamaConfig,
+    init_params,
+    init_params_quantized,
+)
 
 # Our layer-stacked param leaf -> (HF template, transpose?)
 _LAYER_MAP = {
@@ -265,12 +270,15 @@ def load_or_init(
         cfg = config_from_hf(model_path, name=model_name)
         return load_params(model_path, cfg, dtype=dtype, shardings=shardings,
                            quantize_int8=quantize_int8)
-    cfg = CONFIGS[model_name] if model_name in CONFIGS else CONFIGS["llama3-test"]
-    params = init_params(jax.random.PRNGKey(seed), cfg, dtype=dtype)
-    if quantize_int8:
-        from runbookai_tpu.models.quant import quantize_params
-
-        params = quantize_params(params)
+    if model_name not in CONFIGS:
+        raise KeyError(
+            f"unknown model {model_name!r} and no checkpoint at "
+            f"{str(model_path)!r}; known configs: {sorted(CONFIGS)}")
+    cfg = CONFIGS[model_name]
+    # int8 leaves are sampled directly: a 7B bf16 tree (15 GB) plus the
+    # float32 temporaries of quantizing it cannot exist on a 16 GB chip.
+    init = init_params_quantized if quantize_int8 else init_params
+    params = init(jax.random.PRNGKey(seed), cfg, dtype=dtype)
     if shardings:
         params = jax.tree.map(
             lambda x, s: jax.device_put(x, s) if s is not None else x,

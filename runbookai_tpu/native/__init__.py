@@ -7,9 +7,15 @@ as a behavior-identical fallback (the test suite diffs the two backends over
 randomized op sequences).
 
 Build model: a single translation unit (``src/runtime.cpp``) compiled on
-first use with ``g++ -O2 -shared -fPIC`` into ``_build/libruntime.so`` and
-cached by source mtime. No pybind11 (not in the image) — plain C ABI +
-ctypes. Set ``RUNBOOKAI_NATIVE=0`` to force the Python fallback.
+first use with ``g++ -O2 -shared -fPIC`` into the git-ignored ``_build/``.
+The library's file name carries the SHA-256 of the source and the compile
+line, so the only library ever loaded is one built from the source in this
+checkout: an older build, or a ``.so`` somebody copied in, has another name
+and is never opened. No pybind11 (not in the image) — plain C ABI + ctypes.
+``RUNBOOKAI_NATIVE=0`` selects the Python allocator; so does a build that
+fails, with the compiler's message logged as a warning. Either way
+:func:`backend` says which allocator serves (``/healthz`` ``runtime``
+block, ``chip_smoke.py``).
 
 The reference has no first-party native code (SURVEY.md §2.9); this module is
 new construction for the TPU build's runtime layer.
@@ -18,6 +24,8 @@ new construction for the TPU build's runtime layer.
 from __future__ import annotations
 
 import ctypes
+import hashlib
+import logging
 import os
 import subprocess
 from pathlib import Path
@@ -27,30 +35,31 @@ import numpy as np
 
 _SRC = Path(__file__).parent / "src" / "runtime.cpp"
 _BUILD_DIR = Path(__file__).parent / "_build"
-_LIB_PATH = _BUILD_DIR / "libruntime.so"
+_CXX = ("g++", "-O2", "-std=c++17", "-shared", "-fPIC")
 
 _lib: Optional[ctypes.CDLL] = None
 _load_attempted = False
 
 
-def _compile() -> bool:
+def _lib_path() -> Path:
+    digest = hashlib.sha256(_SRC.read_bytes() + " ".join(_CXX).encode())
+    return _BUILD_DIR / f"libruntime-{digest.hexdigest()[:16]}.so"
+
+
+def _compile(lib_path: Path) -> None:
     # Build to a process-private temp path and os.replace() into place so
     # concurrent first-compiles can't interleave writes into the cached .so.
-    tmp = _LIB_PATH.with_suffix(f".{os.getpid()}.tmp.so")
-    cmd = ["g++", "-O2", "-std=c++17", "-shared", "-fPIC",
-           str(_SRC), "-o", str(tmp)]
+    tmp = lib_path.with_suffix(f".{os.getpid()}.tmp.so")
     try:
         _BUILD_DIR.mkdir(exist_ok=True)
-        res = subprocess.run(cmd, capture_output=True, text=True, timeout=120)
-        if res.returncode != 0 or not tmp.is_file():
-            return False
-        os.replace(tmp, _LIB_PATH)
-    except (OSError, subprocess.TimeoutExpired):
-        # Read-only installs (site-packages, runfiles) fall back to Python.
-        return False
+        res = subprocess.run([*_CXX, str(_SRC), "-o", str(tmp)],
+                             capture_output=True, text=True, timeout=120)
+        if res.returncode != 0:
+            raise OSError(f"g++ exited {res.returncode}: "
+                          f"{res.stderr.strip()[-400:]}")
+        os.replace(tmp, lib_path)
     finally:
         tmp.unlink(missing_ok=True)
-    return _LIB_PATH.is_file()
 
 
 def _load() -> Optional[ctypes.CDLL]:
@@ -61,16 +70,17 @@ def _load() -> Optional[ctypes.CDLL]:
     if os.environ.get("RUNBOOKAI_NATIVE", "1") == "0":
         return None
     try:
-        stale = (not _LIB_PATH.is_file()
-                 or (_SRC.is_file()
-                     and _LIB_PATH.stat().st_mtime < _SRC.stat().st_mtime))
-    except OSError:
-        stale = not _LIB_PATH.is_file()
-    if stale and (not _SRC.is_file() or not _compile()):
-        return None
-    try:
-        lib = ctypes.CDLL(str(_LIB_PATH))
-    except OSError:
+        lib_path = _lib_path()
+        if not lib_path.is_file():
+            _compile(lib_path)
+        lib = ctypes.CDLL(str(lib_path))
+    except (OSError, subprocess.TimeoutExpired) as e:
+        # No compiler, a read-only install, a source that does not build:
+        # the Python allocator is behavior-identical (tests diff the two),
+        # so serving goes on — but never silently.
+        logging.getLogger(__name__).warning(
+            "native runtime not built from %s (%s); serving with the "
+            "Python page allocator", _SRC, e)
         return None
 
     lib.rk_alloc_create.restype = ctypes.c_void_p
@@ -101,6 +111,11 @@ def _load() -> Optional[ctypes.CDLL]:
 
 def available() -> bool:
     return _load() is not None
+
+
+def backend() -> str:
+    """Which page allocator / block hasher serves: ``native`` or ``python``."""
+    return "native" if available() else "python"
 
 
 class NativePageAllocator:

@@ -394,6 +394,19 @@ def _chunk_kernel(
                 blk.reshape(tq, group, hd).astype(o_ref.dtype))
 
 
+def chunk_q_block(t: int, n_q: int) -> int:
+    """Query rows per grid step of :func:`paged_chunk_attention`: about
+    1k accumulator rows (TQ * n_q) to bound VMEM scratch, and a multiple
+    of 8 — every per-kv-head row block is TQ * group high, and Mosaic
+    stacks those blocks (scores, masks, probabilities) along sublanes.
+    With a GQA group of 7 an unaligned block (1024 // 28 = 36 rows, 252
+    per head) fails to compile: the i1 mask concatenate dies in
+    ``tpu.bitcast_vreg`` with "Invalid vector register cast" (TPU v5
+    lite, jax 0.9.0). A short chunk pads up to one 8-row block."""
+    cap = max(8, (1024 // n_q) // 8 * 8)
+    return min(-(-t // 8) * 8, cap)
+
+
 def paged_chunk_attention(
     q: jnp.ndarray,  # [B, T, n_q, hd]
     k_flat: jnp.ndarray,  # [num_pages * page_size, n_kv, hd]
@@ -424,8 +437,7 @@ def paged_chunk_attention(
     v_pages = v_flat.reshape(-1, page_size, n_kv, hd)
     q_start = q_positions[:, 0].astype(jnp.int32)
 
-    # Block the query dim so VMEM scratch stays bounded (~1k accumulator rows).
-    tq = q_block if q_block is not None else min(t, max(1, 1024 // n_q))
+    tq = q_block if q_block is not None else chunk_q_block(t, n_q)
     t_pad = ((t + tq - 1) // tq) * tq
     n_qb = t_pad // tq
     if t_pad != t:

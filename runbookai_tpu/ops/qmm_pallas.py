@@ -68,9 +68,15 @@ def _qmm_kernel(x_ref, q_ref, s_ref, o_ref, acc_ref, *, n_k: int):
         acc_ref[:] = jnp.zeros_like(acc_ref)
 
     # int8 tile widens in-register on its way into the MXU; f32 accumulate.
+    # The precision is spelled out because the operands are bf16 by
+    # construction (bf16 x int8 products are exact in one MXU pass): a
+    # process-wide jax_default_matmul_precision of "highest" would
+    # otherwise ask Mosaic for an fp32 contraction of bf16 operands, which
+    # it refuses ("Bad lhs type", TPU v5 lite, jax 0.9.0).
     acc_ref[:] += jax.lax.dot_general(
         x_ref[:], q_ref[:].astype(x_ref.dtype),
         (((1,), (0,)), ((), ())),
+        precision=jax.lax.Precision.DEFAULT,
         preferred_element_type=jnp.float32,
     )
 

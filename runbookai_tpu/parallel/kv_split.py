@@ -171,7 +171,7 @@ def _partial_flash(
     acc0 = jnp.zeros((b, t, nkvl, group, d), dtype=jnp.float32)
     # scan carries inside shard_map must be marked device-varying up front
     # (the body output varies over the mesh axes; jax requires the init to
-    # match). _mark_varying handles the pcast/pvary API generations.
+    # match). _mark_varying is lax.pcast(..., to="varying").
     from runbookai_tpu.parallel.ring_attention import _mark_varying
 
     m0, l0, acc0 = (_mark_varying(_mark_varying(x, SEQ_AXIS), MODEL_AXIS)

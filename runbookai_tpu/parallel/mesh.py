@@ -44,10 +44,13 @@ def build_mesh(
 ) -> Mesh:
     """Build a (data, pipe, seq, model) mesh over the first N needed devices.
 
-    Uses ``mesh_utils.create_device_mesh`` when the whole device set is used
-    (it picks an ICI-friendly physical layout — the ``seq``/``pipe`` axes land
-    on rings so ppermute hops are nearest-neighbor); falls back to a simple
-    reshape for subsets (tests, single-chip).
+    On TPU, a mesh over the whole device set comes from
+    ``mesh_utils.create_device_mesh``, which picks an ICI-friendly physical
+    layout (the ``seq``/``pipe`` axes land on rings so ppermute hops are
+    nearest-neighbor) — and whose refusal of a shape is an error, not a
+    reason to serve collectives over an arbitrary device order. Subsets
+    (a fleet replica's slice) and the CPU's virtual devices, which have no
+    topology, take the devices in order.
     """
     devices = list(devices if devices is not None else jax.devices())
     shape = (data, pipe, seq, model)
@@ -55,15 +58,12 @@ def build_mesh(
     if need > len(devices):
         raise ValueError(
             f"mesh {'x'.join(map(str, shape))} needs {need} devices, have {len(devices)}")
-    if need == len(devices):
-        try:
-            from jax.experimental import mesh_utils
+    if need == len(devices) and devices[0].platform == "tpu":
+        from jax.experimental import mesh_utils
 
-            arr = mesh_utils.create_device_mesh(shape, devices=devices)
-            return Mesh(arr, AXIS_ORDER)
-        except Exception:
-            pass
-    arr = np.asarray(devices[:need]).reshape(shape)
+        arr = mesh_utils.create_device_mesh(shape, devices=devices)
+    else:
+        arr = np.asarray(devices[:need]).reshape(shape)
     return Mesh(arr, AXIS_ORDER)
 
 

@@ -32,11 +32,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-try:
-    from jax import shard_map
-except ImportError:  # older jax
-    from jax.experimental.shard_map import shard_map
-
 from runbookai_tpu.models.llama import (
     LlamaConfig,
     dense_causal_attention,
@@ -124,20 +119,14 @@ def forward_train_pp(
     if not cfg.tie_embeddings:
         param_specs["lm_head"] = P()
 
-    kwargs = {}
-    try:
-        import inspect
-
-        if "axis_names" in inspect.signature(shard_map).parameters:
-            kwargs["axis_names"] = {axis_name}
-    except (TypeError, ValueError):
-        pass
-    fn = shard_map(
+    fn = jax.shard_map(
         partial(_pipeline_local, cfg=cfg, axis_name=axis_name),
         mesh=mesh,
         in_specs=(param_specs, P()),
         out_specs=P(),
-        **kwargs,
+        # Manual over this axis only — data/model placements stay
+        # automatic so TP-sharded weights compose without gathering.
+        axis_names={axis_name},
     )
     logits = fn(params, tokens_mb)
     return logits.reshape(b, t, -1)

@@ -33,10 +33,6 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-try:
-    from jax import shard_map
-except ImportError:  # older jax
-    from jax.experimental.shard_map import shard_map
 
 NEG_INF = -1e30
 
@@ -44,21 +40,10 @@ SEQ_AXIS = "seq"
 
 
 def _mark_varying(x, axis_name):
-    """Mark an array device-varying over ``axis_name`` for shard_map's VMA check.
-
-    Newer jax spells this ``lax.pcast(..., to='varying')``; older ``lax.pvary``;
-    oldest shard_map has no VMA tracking at all.
-    """
-    pcast = getattr(jax.lax, "pcast", None)
-    if pcast is not None:
-        try:
-            return pcast(x, (axis_name,), to="varying")
-        except TypeError:
-            pass
-    pvary = getattr(jax.lax, "pvary", None)
-    if pvary is not None:
-        return pvary(x, axis_name)
-    return x
+    """Mark an array device-varying over ``axis_name`` for shard_map's
+    varying-manual-axes check (scan carries that start replicated and
+    become per-device on the first ppermute)."""
+    return jax.lax.pcast(x, (axis_name,), to="varying")
 
 
 def _flash_block(qf, kb, vb, mask, m, l, acc):
@@ -161,32 +146,20 @@ def ring_attention(
     spec = P(None, axis_name, None, None)
     seg_spec = P(None, axis_name)
     # Only the seq axis goes manual; data/model stay automatic so DP/TP
-    # placements on the same mesh compose (older jax lacks axis_names — there
-    # every axis is manual, which still works since specs leave them unused).
-    kwargs = {}
-    try:
-        import inspect
-
-        if "axis_names" in inspect.signature(shard_map).parameters:
-            kwargs["axis_names"] = {axis_name}
-    except (TypeError, ValueError):
-        pass
+    # placements on the same mesh compose.
+    smap = partial(jax.shard_map, mesh=mesh, out_specs=spec,
+                   axis_names={axis_name})
     if seg_ids is None:
-        fn = shard_map(
+        fn = smap(
             partial(ring_attention_local, axis_name=axis_name, causal=causal),
-            mesh=mesh,
-            in_specs=(spec, spec, spec),
-            out_specs=spec,
-            **kwargs,
-        )
+            in_specs=(spec, spec, spec))
         return fn(q, k, v)
 
     def body(q, k, v, seg):
         return ring_attention_local(q, k, v, axis_name=axis_name, causal=causal,
                                     seg_ids=seg)
 
-    fn = shard_map(body, mesh=mesh, in_specs=(spec, spec, spec, seg_spec),
-                   out_specs=spec, **kwargs)
+    fn = smap(body, in_specs=(spec, spec, spec, seg_spec))
     return fn(q, k, v, seg_ids)
 
 

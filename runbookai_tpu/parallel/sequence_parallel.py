@@ -21,11 +21,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-try:
-    from jax import shard_map
-except ImportError:  # older jax
-    from jax.experimental.shard_map import shard_map
-
 from runbookai_tpu.models.llama import LlamaConfig, forward_train
 from runbookai_tpu.parallel.mesh import SEQ_AXIS
 from runbookai_tpu.parallel.ring_attention import ring_attention_local
@@ -60,21 +55,13 @@ def forward_train_sp(
     same math); returns [B, T, vocab] float32 logits sharded along T.
     """
     tok_spec = P(None, axis_name)
-    kwargs = {}
-    try:
-        import inspect
-
-        if "axis_names" in inspect.signature(shard_map).parameters:
-            # Manual over seq only — data/model placements stay automatic so
-            # TP-sharded weights compose without gathering.
-            kwargs["axis_names"] = {axis_name}
-    except (TypeError, ValueError):
-        pass
-    fn = shard_map(
+    fn = jax.shard_map(
         partial(_forward_local, cfg=cfg, axis_name=axis_name),
         mesh=mesh,
         in_specs=(P(), tok_spec),
         out_specs=P(None, axis_name, None),
-        **kwargs,
+        # Manual over this axis only — data/model placements stay
+        # automatic so TP-sharded weights compose without gathering.
+        axis_names={axis_name},
     )
     return fn(params, tokens)

@@ -649,6 +649,12 @@ def make_handler(bridge: _EngineBridge, model_name: str,
                         "pages_cached": kv.allocator.cached_pages,
                         "utilization": round(kv.utilization(), 4)}
                     body["metrics"] = m
+                runtime = getattr(client, "runtime_info", None)
+                if runtime is not None:
+                    # Device, resolved kernels, allocator, compile cache:
+                    # what this process serves ON (chip_smoke.py reads
+                    # its facts here).
+                    body["runtime"] = runtime()
                 slo = getattr(client, "slo_monitor", None)
                 if slo is not None and slo.objectives:
                     # Live SLO state (utils/slo.py): targets vs current
@@ -1396,6 +1402,14 @@ def make_handler(bridge: _EngineBridge, model_name: str,
     return Handler
 
 
+class _HTTPServer(ThreadingHTTPServer):
+    # listen(2) backlog. At the stdlib's 5, a burst of simultaneous
+    # connects wider than that is reset by the kernel before a handler
+    # thread ever accepts it (32 at once from chip_smoke.py's four-replica
+    # burst: "Connection reset by peer").
+    request_queue_size = 128
+
+
 class OpenAIServer:
     """Lifecycle wrapper: build, serve_forever (or background), shutdown."""
 
@@ -1403,11 +1417,12 @@ class OpenAIServer:
                  port: int = 8000, request_timeout: float = 600.0,
                  allow_runtime_adapters: bool = False, embedder=None):
         self.bridge = _EngineBridge(client)
-        self.httpd = ThreadingHTTPServer(
+        self.httpd = _HTTPServer(
             (host, port), make_handler(self.bridge, model_name,
                                        request_timeout,
                                        allow_runtime_adapters, embedder))
         self.model_name = model_name
+        self.embedder = embedder
 
     @property
     def port(self) -> int:

@@ -242,29 +242,32 @@ def device_trace(logdir: str | Path) -> Iterator[None]:
 
 @contextlib.contextmanager
 def try_device_trace(logdir: str | Path) -> Iterator[bool]:
-    """Probe-gated :func:`device_trace`: yields True when the capture
-    started, False when ``jax.profiler`` (or its backend plumbing) is
-    unavailable — the enclosed work runs either way, so on-demand
-    profiling (``runbook profile``, ``bench.py --profile``) degrades to a
-    clean skip on dependency-free CPU CI instead of crashing the run."""
+    """:func:`device_trace` for callers that ask for a profile on whatever
+    backend they run on (``runbook profile``, ``bench.py --profile``):
+    yields True when the capture started. On the CPU a ``jax.profiler``
+    that cannot start yields False and the enclosed work runs unprofiled —
+    dependency-free CI has nothing to trace. On a TPU the device trace is
+    what was asked for, so a profiler that will not start (or stop)
+    raises."""
+    import jax
+
+    on_tpu = jax.default_backend() == "tpu"
     started = False
     try:
-        import jax
-
         jax.profiler.start_trace(str(logdir))
         started = True
-    except Exception:  # noqa: BLE001 — any capture failure means "skip"
-        pass
+    except Exception:  # noqa: BLE001 — CPU: any capture failure means "skip"
+        if on_tpu:
+            raise
     try:
         yield started
     finally:
         if started:
             try:
-                import jax
-
                 jax.profiler.stop_trace()
-            except Exception:  # noqa: BLE001 — a failed stop must not
-                pass  # poison the run whose work already completed
+            except Exception:  # noqa: BLE001 — CPU: the work already ran
+                if on_tpu:
+                    raise
 
 
 def read_spans(path: str | Path) -> list[dict[str, Any]]:

@@ -592,13 +592,10 @@ def test_bench_plan_refuses_model_mismatch(tmp_path, monkeypatch):
     save_plan(PlanArtifact(model="llama3-8b-instruct", topology={"tp": 1},
                            engine={"num_pages": 64}), out)
     monkeypatch.setenv("BENCH_PLAN", str(out))
-    buf = io.StringIO()
-    with contextlib.redirect_stdout(buf):
+    with pytest.raises(ValueError, match="tuned for model"):
         bench_mod.run_inner("llama3-test", False,
                             {"ok": True, "platform": "cpu", "kind": "cpu",
                              "n": 1})
-    result = json.loads(buf.getvalue().strip().splitlines()[-1])
-    assert "tuned for model" in result["details"]["error"]
 
 
 # --------------------------------------------- trace dispatch counters

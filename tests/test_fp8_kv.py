@@ -99,22 +99,30 @@ def test_fp8_pallas_tokens_match_fp8_xla():
     assert outs["pallas"] == outs["xla"], outs
 
 
-def test_probe_downgrade_on_mosaic_failure(monkeypatch):
-    """If the probe compile fails (a backend whose Mosaic rejects fp8
-    loads), the engine falls back to the XLA path instead of crashing on
-    the first real dispatch."""
+def test_refused_kernel_fails_engine_construction(monkeypatch):
+    """A kernel the config selected and the backend refuses is an error
+    carrying the backend's text, raised out of the constructor — never a
+    quiet switch to the XLA path under the same name (at the parent commit
+    this engine came up with attn_impl="xla" and a log line)."""
+    import pytest
+
     from runbookai_tpu.engine import engine as engine_mod
+    from runbookai_tpu.ops import paged_attention_pallas as kernels
+
+    def refuse(*args, **kwargs):
+        raise NotImplementedError("Mosaic: unaligned sublane slice")
 
     engine_mod._probe_pallas_attn_cached.cache_clear()
-    monkeypatch.setattr(
-        engine_mod, "_probe_pallas_attn", lambda cfg, ecfg, act, mesh=None: False)
+    monkeypatch.setattr(kernels, "paged_decode_attention", refuse)
     tok = ByteTokenizer()
     params = init_params(jax.random.PRNGKey(0), CFG, dtype=jnp.float32)
-    core = EngineCore(CFG, params, tok, EngineConfig(
-        page_size=4, num_pages=64, max_batch_slots=2, prefill_chunk=8,
-        max_seq_len=64, kv_dtype=jnp.float8_e4m3fn, block_pages=4,
-        attn_impl="pallas", speculative=False))
-    assert core.ecfg.attn_impl == "xla"
+    with pytest.raises(NotImplementedError, match="unaligned sublane"):
+        EngineCore(CFG, params, tok, EngineConfig(
+            page_size=4, num_pages=64, max_batch_slots=2, prefill_chunk=8,
+            max_seq_len=64, kv_dtype=jnp.float8_e4m3fn, block_pages=4,
+            attn_impl="pallas", speculative=False))
+    # A failure is not cached as a verdict: the same shapes probe again.
+    assert engine_mod._probe_pallas_attn_cached.cache_info().currsize == 0
 
 
 def test_kv_cache_dtype_config_mapping():

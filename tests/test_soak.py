@@ -11,7 +11,7 @@ under pool pressure, crash recovery (the engine loop restarts and serves
 again), bounded ack history, and no fd/RSS growth.
 
 Run:  RUNBOOK_SOAK=1 [RUNBOOK_SOAK_SECONDS=600] pytest tests/test_soak.py
-Record the run in BENCHLOG.md (reliability posture parity with the
+Record the run in CHANGES.md (reliability posture parity with the
 reference's gateway, src/slack/gateway.ts:531).
 """
 
