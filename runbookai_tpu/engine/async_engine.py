@@ -23,6 +23,7 @@ from runbookai_tpu.engine.request import (
     FinishReason,
     SamplingParams,
 )
+from runbookai_tpu.utils.trace import annotate
 
 
 class AsyncEngine:
@@ -32,6 +33,16 @@ class AsyncEngine:
         self._wake: Optional[asyncio.Event] = None
         self._task: Optional[asyncio.Task] = None
         self._stopped = False
+        # ``engine.loop`` on the profiler's clock: from the end of a step
+        # that leaves work to the start of the next, which is this
+        # class's doing (the hop back to the event loop, whatever the
+        # loop runs before it resumes ``_loop``, the hop out to a worker
+        # thread, the wait for the lock). On the records' clock the same
+        # interval is a record's ``t_start`` less the ``t_end`` before it.
+        # Opened on one worker thread and closed on the next: the
+        # profiler takes the event whole at its close, on that thread's
+        # line. Touched under the lock only.
+        self._gap = None
         # Monotonic count of engine-loop crashes (step exceptions). The
         # fleet supervisor reads this as its STICKY crash signal: a
         # caller's start() may restart a crashed loop before the
@@ -75,6 +86,7 @@ class AsyncEngine:
         # leave streams/done_events waiting on a drain that never comes.
         def _flush() -> None:
             with self._lock:
+                self._end_gap()
                 self.core.flush()
 
         try:
@@ -86,6 +98,8 @@ class AsyncEngine:
         while not self._stopped:
             with self._lock:
                 has_work = self.core.has_work
+                if not has_work:
+                    self._end_gap()  # idle for want of work, not of the loop
             if not has_work:
                 self._wake.clear()
                 await self._wake.wait()
@@ -121,9 +135,18 @@ class AsyncEngine:
             # first step, wedging has_work true forever.
             self.core.discard_inflight()
 
+    def _end_gap(self) -> None:
+        gap, self._gap = self._gap, None
+        if gap is not None:
+            gap.__exit__(None, None, None)
+
     def _locked_step(self) -> None:
         with self._lock:
+            self._end_gap()
             self.core.step()
+            if self.core.has_work:
+                self._gap = annotate("engine.loop")
+                self._gap.__enter__()
 
     @property
     def loop_crashed(self) -> bool:

@@ -29,7 +29,9 @@ Static-shape tricks:
 
 from __future__ import annotations
 
+import contextlib
 import functools
+import threading
 import time
 from dataclasses import dataclass, fields
 from functools import partial
@@ -39,7 +41,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from runbookai_tpu.engine.flight_recorder import FlightRecorder
+from runbookai_tpu.engine.flight_recorder import (
+    PHASE_SPANS,
+    FlightRecorder,
+    OpenStep,
+)
 from runbookai_tpu.engine.kv_cache import KVCacheManager, hash_blocks
 from runbookai_tpu.engine.request import (
     EngineOutput,
@@ -56,6 +62,27 @@ from runbookai_tpu.ops.sampling import sample_tokens
 from runbookai_tpu.sched import class_label, class_name
 from runbookai_tpu.utils import metrics as metrics_mod
 from runbookai_tpu.utils.trace import annotate, get_tracer
+
+# Programs this PROCESS compiled, or loaded from the persistent cache (the
+# event wraps both), and the seconds that took: jax.monitoring has one
+# process-wide listener list, so there is one listener, here. A step
+# reads the totals at its two ends; the difference is its ``compile_s``
+# (what names a stall as a compile) and adds to the engine's
+# ``compile_time_s`` / ``compiles``. Replicas stepping side by side each
+# see what the process compiled meanwhile.
+_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+_compile_lock = threading.Lock()
+_compile_totals = [0, 0.0]
+
+
+def _on_compile(event: str, duration: float, **_: Any) -> None:
+    if event == _COMPILE_EVENT:
+        with _compile_lock:
+            _compile_totals[0] += 1
+            _compile_totals[1] += duration
+
+
+jax.monitoring.register_event_duration_secs_listener(_on_compile)
 
 
 @dataclass
@@ -976,7 +1003,8 @@ class EngineCore:
                         "decode_dispatches": 0, "mixed_steps": 0,
                         "mixed_tokens": 0, "mixed_time_s": 0.0,
                         "kv_pages_imported": 0, "kv_pages_exported": 0,
-                        "kv_spill_readmits": 0}
+                        "kv_spill_readmits": 0,
+                        "compile_time_s": 0.0, "compiles": 0}
         # Flight-recorder mark for page transfers: imports/exports happen
         # BETWEEN steps (under the engine lock, not inside step()), so the
         # per-step record reports the delta since the last recorded step
@@ -999,6 +1027,13 @@ class EngineCore:
         # engine DOING on the slow steps?). The step thread is the only
         # writer; /debug/steps snapshots under the AsyncEngine lock.
         self.flight = FlightRecorder(self.ecfg.flight_recorder_steps)
+        # The step being run (None between steps and with the recorder
+        # off), and what the next record will carry: requests first
+        # admitted, and lifecycle records of requests retired, since the
+        # last recorded step (an abort lands between steps).
+        self._open: Optional[OpenStep] = None
+        self._admitted_log: list[list] = []
+        self._finished_log: list[dict] = []
         self._install_metrics()
 
     def _install_metrics(self) -> None:
@@ -1135,7 +1170,37 @@ class EngineCore:
             self.params = dict(self.params)
             self.params["lora"] = self.lora.stacked()
 
+    @contextlib.contextmanager
+    def _span(self, phase: str):
+        """One phase of a step on both clocks: its seconds in the open
+        step's ``phases`` (time.monotonic()), and the same two boundaries
+        as a span on the profiler's (``PHASE_SPANS``; a no-op without a
+        profiler session). Outside a recorded step only the latter.
+
+        The always-on counters keep their own perf_counter() stamps
+        beside these (``t_build`` / ``t_issue`` / ``t_fetch`` below:
+        ``decode_dispatch_time_s``, ``decode_host_time_s``,
+        ``decode_host_overlap_s`` and the ``*_time_s``, which are
+        Prometheus series, ``/healthz`` and the record's ``dispatch_s`` /
+        ``host_s`` / ``overlap_s``). They count with the recorder off,
+        when no phase is timed, and they cut a step otherwise: the decode
+        side only, its host time as build plus emit in one, and the part
+        of that an in-flight window hid. Neither set derives from the
+        other."""
+        name = PHASE_SPANS.get(phase)
+        with annotate(name) if name else contextlib.nullcontext():
+            step = self._open
+            if step is None:
+                yield
+                return
+            step.enter(phase)
+            try:
+                yield
+            finally:
+                step.exit()
+
     def submit(self, req: EngineRequest) -> None:
+        req.t_enqueued = time.monotonic()
         if self._rid_prefix and not req.request_id.startswith(self._rid_prefix):
             # Replica namespace: the engine-internal id gains the r{idx}-
             # prefix (tracer JSONL, KV seq ids, abort lookups); the
@@ -1315,10 +1380,11 @@ class EngineCore:
         tokens through this single point; the host copy was started
         asynchronously at dispatch time, so in the lagged pipeline this
         wait is bounded by whatever device time the host failed to hide."""
-        # runbook: noqa[RBK002] — sanctioned sync: the async-egress
-        # consumption point — the one token fetch in the decode loop
-        # (prefill TTFT and the logprob triple keep their own fetches).
-        return np.asarray(jax.device_get(toks_dev))
+        with self._span("fetch"):
+            # runbook: noqa[RBK002] — sanctioned sync: the async-egress
+            # consumption point — the one token fetch in the decode loop
+            # (prefill TTFT and the logprob triple keep their own fetches).
+            return np.asarray(jax.device_get(toks_dev))
 
     def _drain(self, pending: _PendingDecode, overlapped: bool) -> np.ndarray:
         """Consume one decode window: fetch its tokens and emit them.
@@ -1334,11 +1400,12 @@ class EngineCore:
         toks_host = self._fetch_tokens(pending.toks_dev)
         t_fetch = time.perf_counter()
         emitted = 0
-        for step_idx in range(pending.k):
-            for req, slot in pending.reqs:
-                if req.state == RequestState.DECODE:
-                    self._emit_token(req, int(toks_host[slot, step_idx]))
-                    emitted += 1
+        with self._span("emit"):
+            for step_idx in range(pending.k):
+                for req, slot in pending.reqs:
+                    if req.state == RequestState.DECODE:
+                        self._emit_token(req, int(toks_host[slot, step_idx]))
+                        emitted += 1
         t_emit = time.perf_counter()
         self.metrics["decode_tokens"] += emitted
         self.metrics["decode_steps"] += pending.k
@@ -1448,9 +1515,13 @@ class EngineCore:
                 # its OWN published pages is recompute avoidance, not a
                 # prompt-cache hit the client should be billed less for.
                 req.cached_tokens = cached
-                wait_s = time.perf_counter() - req.arrival_time
+                req.t_admitted = time.perf_counter()
+                wait_s = req.t_admitted - req.arrival_time
                 self.hist_queue_wait.observe(wait_s)
                 self.hist_class_queue_wait.labels(cls=cls).observe(wait_s)
+                if self.flight.enabled:
+                    self._admitted_log.append(
+                        [req.request_id, wait_s, len(req.prompt_ids), cached])
             self._m_class_admits.labels(cls=cls).inc()
             self.metrics["cached_prefix_tokens"] += cached
             self.prefilling.append(req)
@@ -1506,6 +1577,7 @@ class EngineCore:
         self._fold_into_prompt(victim, prefill_pos=0)
         victim.state = RequestState.WAITING
         self.waiting.insert(0, victim)
+        victim.preemptions += 1
         self.metrics["preemptions"] += 1
         self._bump_epoch()
         return True
@@ -1536,19 +1608,8 @@ class EngineCore:
         if req.first_token_time is not None and req.num_generated > 1:
             self.hist_tpot.observe((now - req.first_token_time)
                                    / (req.num_generated - 1))
-        # One JSONL line per request ties the engine's view back to the
-        # server's x-request-id (req.trace_id) — the join key between a
-        # trace record and the request's metrics. No-op when tracing is off.
-        meta = {"request": req.request_id,
-                "reason": req.finish_reason.value if req.finish_reason else None,
-                "generated": req.num_generated}
-        if self.replica_idx is not None:
-            meta["replica"] = self.replica_idx
-        if req.ttft_ms is not None:
-            meta["ttft_ms"] = round(req.ttft_ms, 3)
-        if req.trace_id is not None:
-            meta["trace_id"] = req.trace_id
-        self.tracer.event("engine.request", **meta)
+        if self.flight.enabled or self.tracer.enabled:
+            self._retire(req, now)
         if self.workload_tap is not None:
             # Workload fingerprinting (obs/): sample the finished request.
             # Best-effort — observation must never fail a request.
@@ -1556,6 +1617,42 @@ class EngineCore:
                 self.workload_tap(req)
             except Exception:  # noqa: BLE001 — observer errors stay silent
                 pass
+
+    def _retire(self, req: EngineRequest, now: float) -> None:
+        """The request's lifecycle record (``LIFECYCLE_FIELDS``), built
+        once: the next step record's ``finished`` gets it, and the
+        tracer's ``engine.request`` line — which ties the engine's view
+        back to the server's x-request-id (``trace_id``) — is written
+        from it, so the two cannot disagree."""
+        life = {
+            "id": req.request_id, "trace_id": req.trace_id,
+            "t_received": (req.arrival_time if req.t_received is None
+                           else req.t_received),
+            "t_enqueued": req.t_enqueued, "t_admitted": req.t_admitted,
+            "t_first_token": req.first_token_time,
+            "t_first_write": req.t_first_write, "t_finished": now,
+            "prompt_tokens": len(req.prompt_ids) - len(req.folded_out_ids),
+            "cached_tokens": req.cached_tokens,
+            "generated": req.num_generated,
+            "preemptions": req.preemptions,
+            "reason": req.finish_reason.value if req.finish_reason else None,
+            "max_emit_gap_s": req.max_emit_gap_s,
+        }
+        req.lifecycle = life
+        # The handler thread may have flushed the first chunk between the
+        # read above and the assignment (EngineRequest.mark_first_write).
+        life["t_first_write"] = req.t_first_write
+        if self.flight.enabled:
+            self._finished_log.append(life)
+        if self.tracer.enabled:
+            meta = {"request": req.request_id,
+                    **{k: v for k, v in life.items()
+                       if k != "id" and v is not None}}
+            if self.replica_idx is not None:
+                meta["replica"] = self.replica_idx
+            if req.ttft_ms is not None:
+                meta["ttft_ms"] = round(req.ttft_ms, 3)
+            self.tracer.event("engine.request", **meta)
 
     def _finish(self, req: EngineRequest, reason: FinishReason) -> None:
         req.state = RequestState.FINISHED
@@ -1638,39 +1735,42 @@ class EngineCore:
         per-row chunking still bounds dispatch latency for decode overlap.
         """
         t0 = time.perf_counter()
-        rows: list[tuple[EngineRequest, int, int]] = []  # (req, chunk, new_ctx)
-        for req in list(self.prefilling[: max(1, self.ecfg.prefill_batch)]):
-            chunk_len = min(self.ecfg.prefill_chunk,
-                            len(req.prompt_ids) - req.prefill_pos)
-            new_ctx = req.prefill_pos + chunk_len
-            if self.kv.spill is not None:
-                # Capture the retired pages this extension would evict into
-                # the host spill tier BEFORE they are recycled (the one
-                # point evicted bytes are still addressable).
-                alloc = self.kv.seqs.get(req.request_id)
-                need = (alloc.pages_needed(new_ctx, self.ecfg.page_size)
-                        if alloc is not None else 0)
-                if need:
-                    self.kv.spill_evictable(self._kv_k, self._kv_v, need)
-            try:
-                self.kv.extend(req.request_id, new_ctx)
-            except MemoryError:
-                if rows:
-                    # Run what fits; this request retries next step. Keep
-                    # scanning — a later request's (smaller) extension may
-                    # still fit this dispatch (ADVICE r2: breaking here
-                    # head-of-line blocked the rest of the batch). Liveness:
-                    # the HEAD request always fails with rows empty (FIFO
-                    # scan), taking the preempt/abort path below — and a
-                    # skipped request reaches the head in bounded steps as
-                    # earlier rows finish, so no request starves.
-                    continue
-                if self._preempt_youngest():
-                    return  # retry next step
-                self.prefilling.remove(req)
-                self._finish(req, FinishReason.ABORTED)
-                return
-            rows.append((req, chunk_len, new_ctx))
+        # Row selection grows the page tables (and may preempt, whose drain
+        # books its own fetch and emit): build, like the arrays below.
+        with self._span("build"):
+            rows: list[tuple[EngineRequest, int, int]] = []  # (req, chunk, new_ctx)
+            for req in list(self.prefilling[: max(1, self.ecfg.prefill_batch)]):
+                chunk_len = min(self.ecfg.prefill_chunk,
+                                len(req.prompt_ids) - req.prefill_pos)
+                new_ctx = req.prefill_pos + chunk_len
+                if self.kv.spill is not None:
+                    # Capture the retired pages this extension would evict into
+                    # the host spill tier BEFORE they are recycled (the one
+                    # point evicted bytes are still addressable).
+                    alloc = self.kv.seqs.get(req.request_id)
+                    need = (alloc.pages_needed(new_ctx, self.ecfg.page_size)
+                            if alloc is not None else 0)
+                    if need:
+                        self.kv.spill_evictable(self._kv_k, self._kv_v, need)
+                try:
+                    self.kv.extend(req.request_id, new_ctx)
+                except MemoryError:
+                    if rows:
+                        # Run what fits; this request retries next step. Keep
+                        # scanning — a later request's (smaller) extension may
+                        # still fit this dispatch (ADVICE r2: breaking here
+                        # head-of-line blocked the rest of the batch). Liveness:
+                        # the HEAD request always fails with rows empty (FIFO
+                        # scan), taking the preempt/abort path below — and a
+                        # skipped request reaches the head in bounded steps as
+                        # earlier rows finish, so no request starves.
+                        continue
+                    if self._preempt_youngest():
+                        return  # retry next step
+                    self.prefilling.remove(req)
+                    self._finish(req, FinishReason.ABORTED)
+                    return
+                rows.append((req, chunk_len, new_ctx))
         if not rows:
             return
 
@@ -1680,20 +1780,21 @@ class EngineCore:
         b = 1
         while b < len(rows):
             b *= 2
-        t = self.ecfg.prefill_chunk
-        tokens = np.zeros((b, t), dtype=np.int32)
-        positions = np.full((b, t), self._trash_pos(), dtype=np.int32)
-        ctx_lens = np.ones((b,), dtype=np.int32)
-        last_idx = np.zeros((b,), dtype=np.int32)
-        adapter_ids = np.zeros((b,), dtype=np.int32)
-        tables = self._tables_for([r for r, _, _ in rows] +
-                                  [None] * (b - len(rows)))
-        for i, (req, chunk_len, new_ctx) in enumerate(rows):
-            tokens[i, :chunk_len] = req.prompt_ids[req.prefill_pos:new_ctx]
-            positions[i, :chunk_len] = np.arange(req.prefill_pos, new_ctx)
-            ctx_lens[i] = new_ctx
-            last_idx[i] = chunk_len - 1
-            adapter_ids[i] = req.adapter_idx
+        with self._span("build"):
+            t = self.ecfg.prefill_chunk
+            tokens = np.zeros((b, t), dtype=np.int32)
+            positions = np.full((b, t), self._trash_pos(), dtype=np.int32)
+            ctx_lens = np.ones((b,), dtype=np.int32)
+            last_idx = np.zeros((b,), dtype=np.int32)
+            adapter_ids = np.zeros((b,), dtype=np.int32)
+            tables = self._tables_for([r for r, _, _ in rows] +
+                                      [None] * (b - len(rows)))
+            for i, (req, chunk_len, new_ctx) in enumerate(rows):
+                tokens[i, :chunk_len] = req.prompt_ids[req.prefill_pos:new_ctx]
+                positions[i, :chunk_len] = np.arange(req.prefill_pos, new_ctx)
+                ctx_lens[i] = new_ctx
+                last_idx[i] = chunk_len - 1
+                adapter_ids[i] = req.adapter_idx
 
         pf_meta: dict[str, Any] = {"batch": len(rows),
                                    "tokens": int(sum(c for _, c, _ in rows))}
@@ -1702,7 +1803,7 @@ class EngineCore:
             # chunks rode this dispatch (built only when tracing is on).
             pf_meta["requests"] = [r.request_id for r, _, _ in rows]
         with self.tracer.span("engine.prefill", **pf_meta), \
-                annotate("prefill"):
+                annotate("prefill"), self._span("issue"):
             last_logits, self._kv_k, self._kv_v = _prefill_step(
                 self.params, self.cfg, jnp.asarray(tokens), self._kv_k, self._kv_v,
                 jnp.asarray(positions), jnp.asarray(tables),
@@ -1712,6 +1813,8 @@ class EngineCore:
                 attn_impl=self.ecfg.attn_impl, mesh=self.mesh,
                 qmm_impl=self.ecfg.qmm_impl,
             )
+        if self._open is not None:
+            self._open.dispatched("_prefill_step")
 
         done_rows: list[tuple[int, EngineRequest]] = []
         self.metrics["prefill_steps"] += 1
@@ -1756,66 +1859,68 @@ class EngineCore:
             # Sample every completed row's first output token in ONE batched
             # dispatch + sync (per-row sampling would re-serialize the TTFT
             # win for short prompts finishing together).
-            temps = np.zeros((b,), dtype=np.float32)
-            top_ps = np.ones((b,), dtype=np.float32)
-            top_ks = np.zeros((b,), dtype=np.int32)
-            need_mask = False
-            mask = np.ones((b, self.cfg.vocab_size), dtype=bool)
-            use_pen = any(req.sampling.penalized for _, req in done_rows)
-            use_seed = any(req.sampling.seed is not None
-                           for _, req in done_rows)
-            use_bias = any(req.sampling.logit_bias for _, req in done_rows)
-            pres = np.zeros((b,), dtype=np.float32)
-            freq = np.zeros((b,), dtype=np.float32)
-            seeds = np.full((b,), -1, dtype=np.int32)
-            bias = (np.zeros((b, self.cfg.vocab_size), dtype=np.float32)
-                    if use_bias else None)
-            slot_map = np.zeros((b,), dtype=np.int32)
-            for i, req in done_rows:
-                temps[i] = req.sampling.temperature
-                top_ps[i] = req.sampling.top_p
-                top_ks[i] = req.sampling.top_k
-                pres[i] = req.sampling.presence_penalty
-                freq[i] = req.sampling.frequency_penalty
-                slot_map[i] = req.slot
-                if req.sampling.seed is not None:
-                    seeds[i] = req.sampling.seed & 0x7FFFFFFF
-                if bias is not None:
-                    for tok_id, b_val in req.sampling.logit_bias:
-                        bias[i, tok_id] = b_val
-                if self.mask_fn and req.sampling.guided:
-                    m = self.mask_fn(req)
-                    if m is not None:
-                        _set_mask_row(mask, i, m)
-                        need_mask = True
-            counts_rows = (jnp.take(self._tok_counts,
-                                    jnp.asarray(slot_map), axis=0)
-                           if use_pen else None)
-            self._key, sub = jax.random.split(self._key)
-            toks = sample_tokens(
-                last_logits, sub, jnp.asarray(temps), jnp.asarray(top_ps),
-                jnp.asarray(mask) if need_mask else None,
-                jnp.asarray(top_ks),
-                counts=counts_rows,
-                presence=jnp.asarray(pres) if use_pen else None,
-                frequency=jnp.asarray(freq) if use_pen else None,
-                seeds=jnp.asarray(seeds) if use_seed else None,
-                positions=jnp.asarray(ctx_lens) if use_seed else None,
-                bias=jnp.asarray(bias) if use_bias else None,
-            )
-            # Wire the first tokens into the device-resident decode feed
-            # before fetching them: row i scatters to its slot, pad rows
-            # scatter out of bounds and drop (fixed shape per prefill
-            # width, so no extra compile per batch composition).
-            feed_idx = np.full((b,), self.ecfg.max_batch_slots,
-                               dtype=np.int32)
-            for i, req in done_rows:
-                feed_idx[i] = req.slot
-            self._feed_toks = self._feed_toks.at[jnp.asarray(feed_idx)].set(
-                toks, mode="drop")
-            # runbook: noqa[RBK002] — sanctioned sync: the one batched
-            # first-token fetch per prefill dispatch (TTFT emission point).
-            toks_host = np.asarray(jax.device_get(toks))
+            with self._span("build"):
+                temps = np.zeros((b,), dtype=np.float32)
+                top_ps = np.ones((b,), dtype=np.float32)
+                top_ks = np.zeros((b,), dtype=np.int32)
+                need_mask = False
+                mask = np.ones((b, self.cfg.vocab_size), dtype=bool)
+                use_pen = any(req.sampling.penalized for _, req in done_rows)
+                use_seed = any(req.sampling.seed is not None
+                               for _, req in done_rows)
+                use_bias = any(req.sampling.logit_bias for _, req in done_rows)
+                pres = np.zeros((b,), dtype=np.float32)
+                freq = np.zeros((b,), dtype=np.float32)
+                seeds = np.full((b,), -1, dtype=np.int32)
+                bias = (np.zeros((b, self.cfg.vocab_size), dtype=np.float32)
+                        if use_bias else None)
+                slot_map = np.zeros((b,), dtype=np.int32)
+                for i, req in done_rows:
+                    temps[i] = req.sampling.temperature
+                    top_ps[i] = req.sampling.top_p
+                    top_ks[i] = req.sampling.top_k
+                    pres[i] = req.sampling.presence_penalty
+                    freq[i] = req.sampling.frequency_penalty
+                    slot_map[i] = req.slot
+                    if req.sampling.seed is not None:
+                        seeds[i] = req.sampling.seed & 0x7FFFFFFF
+                    if bias is not None:
+                        for tok_id, b_val in req.sampling.logit_bias:
+                            bias[i, tok_id] = b_val
+                    if self.mask_fn and req.sampling.guided:
+                        m = self.mask_fn(req)
+                        if m is not None:
+                            _set_mask_row(mask, i, m)
+                            need_mask = True
+                counts_rows = (jnp.take(self._tok_counts,
+                                        jnp.asarray(slot_map), axis=0)
+                               if use_pen else None)
+                self._key, sub = jax.random.split(self._key)
+                toks = sample_tokens(
+                    last_logits, sub, jnp.asarray(temps), jnp.asarray(top_ps),
+                    jnp.asarray(mask) if need_mask else None,
+                    jnp.asarray(top_ks),
+                    counts=counts_rows,
+                    presence=jnp.asarray(pres) if use_pen else None,
+                    frequency=jnp.asarray(freq) if use_pen else None,
+                    seeds=jnp.asarray(seeds) if use_seed else None,
+                    positions=jnp.asarray(ctx_lens) if use_seed else None,
+                    bias=jnp.asarray(bias) if use_bias else None,
+                )
+                # Wire the first tokens into the device-resident decode feed
+                # before fetching them: row i scatters to its slot, pad rows
+                # scatter out of bounds and drop (fixed shape per prefill
+                # width, so no extra compile per batch composition).
+                feed_idx = np.full((b,), self.ecfg.max_batch_slots,
+                                   dtype=np.int32)
+                for i, req in done_rows:
+                    feed_idx[i] = req.slot
+                self._feed_toks = self._feed_toks.at[jnp.asarray(feed_idx)].set(
+                    toks, mode="drop")
+            with self._span("fetch"):
+                # runbook: noqa[RBK002] — sanctioned sync: the one batched
+                # first-token fetch per prefill dispatch (TTFT emission point).
+                toks_host = np.asarray(jax.device_get(toks))
             lp_pairs = [(i, req) for i, req in done_rows
                         if req.sampling.logprobs]
             if lp_pairs:
@@ -1833,12 +1938,13 @@ class EngineCore:
                     self._tok_counts, jnp.asarray(slot_map),
                     jnp.asarray(toks_host.astype(np.int32)),
                     jnp.asarray(live))
-            for i, req in done_rows:
-                if req.first_token_time is None:  # true TTFT across preemption
-                    req.first_token_time = time.perf_counter()
-                    self.hist_ttft.observe(req.first_token_time
-                                           - req.arrival_time)
-                self._emit_token(req, int(toks_host[i]))
+            with self._span("emit"):
+                for i, req in done_rows:
+                    if req.first_token_time is None:  # true TTFT across preemption
+                        req.first_token_time = time.perf_counter()
+                        self.hist_ttft.observe(req.first_token_time
+                                               - req.arrival_time)
+                    self._emit_token(req, int(toks_host[i]))
         self.metrics["prefill_time_s"] += time.perf_counter() - t0
 
     def _seed_counts_for(self, req: EngineRequest,
@@ -1893,6 +1999,12 @@ class EngineCore:
 
     def _emit_token(self, req: EngineRequest, token: int) -> None:
         """Record a sampled token and apply finish rules."""
+        if self._open is not None:
+            now = time.monotonic()
+            if (req.last_emit_time is not None
+                    and now - req.last_emit_time > req.max_emit_gap_s):
+                req.max_emit_gap_s = now - req.last_emit_time
+            req.last_emit_time = now
         req.out_ids.append(token)
         if req.on_token is not None:
             req.on_token(token)
@@ -1981,32 +2093,36 @@ class EngineCore:
         """Speculative dispatch: feed [last, draft...] as one T=k chunk and
         accept the agreeing prefix."""
         t0 = time.perf_counter()
-        self._grow_pages_for_decode(k)
+        with self._span("build"):
+            self._grow_pages_for_decode(k)
         if not self.decoding:
             return
 
         b = self.ecfg.max_batch_slots
-        tokens = np.zeros((b, k), dtype=np.int32)
-        positions = np.zeros((b, k), dtype=np.int32)
-        ctx_lens = np.zeros((b,), dtype=np.int32)
-        feeds: dict[str, list[int]] = {}
-        for req in self.decoding:
-            i = req.slot
-            draft = drafts.get(req.request_id, [])[: k - 1]
-            feed = [self._last_token[req.request_id]] + draft
-            feed = feed + [feed[-1]] * (k - len(feed))  # pad rows to T=k
-            feeds[req.request_id] = feed
-            tokens[i] = feed
-            positions[i] = np.arange(req.ctx_len - 1, req.ctx_len - 1 + k)
-            ctx_lens[i] = req.ctx_len + k - 1  # keys written for all fed tokens
-            self.metrics["spec_drafted"] += len(draft)
-        si = self._slot_inputs()
+        with self._span("build"):
+            tokens = np.zeros((b, k), dtype=np.int32)
+            positions = np.zeros((b, k), dtype=np.int32)
+            ctx_lens = np.zeros((b,), dtype=np.int32)
+            feeds: dict[str, list[int]] = {}
+            for req in self.decoding:
+                i = req.slot
+                draft = drafts.get(req.request_id, [])[: k - 1]
+                feed = [self._last_token[req.request_id]] + draft
+                feed = feed + [feed[-1]] * (k - len(feed))  # pad rows to T=k
+                feeds[req.request_id] = feed
+                tokens[i] = feed
+                positions[i] = np.arange(req.ctx_len - 1, req.ctx_len - 1 + k)
+                ctx_lens[i] = req.ctx_len + k - 1  # keys written for all fed tokens
+                self.metrics["spec_drafted"] += len(draft)
+            si = self._slot_inputs()
 
         spec_meta: dict[str, Any] = {"k": k, "batch": len(self.decoding)}
         if self.tracer.enabled:
             spec_meta["requests"] = [r.request_id for r in self.decoding]
+        if self._open is not None:
+            self._open.dispatched("_decode_spec", k, len(self.decoding))
         with self.tracer.span("engine.decode_spec", **spec_meta), \
-                annotate("decode_spec"):
+                annotate("decode_spec"), self._span("issue"):
             t_issue = time.perf_counter()
             toks, self._kv_k, self._kv_v = _decode_spec(
                 self.params, self.cfg, jnp.asarray(tokens), jnp.asarray(positions),
@@ -2020,19 +2136,20 @@ class EngineCore:
             t_fetch = time.perf_counter()
 
         emitted = 0
-        for req in list(self.decoding):
-            i = req.slot
-            feed = feeds[req.request_id]
-            draft = drafts.get(req.request_id, [])[: k - 1]
-            self._emit_token(req, int(toks_host[i, 0]))
-            emitted += 1
-            j = 1
-            while (req.state == RequestState.DECODE and j <= len(draft)
-                   and feed[j] == int(toks_host[i, j - 1])):
-                self._emit_token(req, int(toks_host[i, j]))
+        with self._span("emit"):
+            for req in list(self.decoding):
+                i = req.slot
+                feed = feeds[req.request_id]
+                draft = drafts.get(req.request_id, [])[: k - 1]
+                self._emit_token(req, int(toks_host[i, 0]))
                 emitted += 1
-                self.metrics["spec_accepted"] += 1
-                j += 1
+                j = 1
+                while (req.state == RequestState.DECODE and j <= len(draft)
+                       and feed[j] == int(toks_host[i, j - 1])):
+                    self._emit_token(req, int(toks_host[i, j]))
+                    emitted += 1
+                    self.metrics["spec_accepted"] += 1
+                    j += 1
         # Re-arm the device-resident feed with each survivor's last
         # accepted token (the verify argmax buffer's last column is not the
         # accepted tail); pad rows scatter out of bounds and drop.
@@ -2188,128 +2305,131 @@ class EngineCore:
             self._drain_pending()
         if not self._can_mix():
             return False
-        rq = _RAGGED_BLOCK
-        b = self.ecfg.max_batch_slots
-        # Prefill row selection: FIFO, chunked, budget- and row-capped.
-        # Stopping (not skipping) at the first ineligible/unfittable
-        # request preserves admission order; the classic path serves it.
-        pf_rows: list[tuple[EngineRequest, int, int]] = []
-        used = 0
-        for req in list(self.prefilling[: self._mix_pf_rows]):
-            if req.sampling.forced_sync:
-                break
-            room = self._mix_pf_tokens - used
-            if room < 1:
-                break
-            chunk = min(self.ecfg.prefill_chunk,
-                        len(req.prompt_ids) - req.prefill_pos, room)
-            new_ctx = req.prefill_pos + chunk
-            try:
-                self.kv.extend(req.request_id, new_ctx)
-            except MemoryError:
-                break  # run what fits; classic preempts when nothing does
-            pf_rows.append((req, chunk, new_ctx))
-            used += -(-chunk // rq) * rq
-        if not pf_rows:
-            return False
-        # Decode page growth AFTER the prefill extends, mirroring the
-        # classic step order (prefill dispatch precedes decode). The
-        # internal preemption/drain may finish or evict decoders — or the
-        # whole decode side — so re-check before committing to the mix.
-        self._grow_pages_for_decode(1)
-        if not self.decoding:
-            return False
+        with self._span("build"):
+            rq = _RAGGED_BLOCK
+            b = self.ecfg.max_batch_slots
+            # Prefill row selection: FIFO, chunked, budget- and row-capped.
+            # Stopping (not skipping) at the first ineligible/unfittable
+            # request preserves admission order; the classic path serves it.
+            pf_rows: list[tuple[EngineRequest, int, int]] = []
+            used = 0
+            for req in list(self.prefilling[: self._mix_pf_rows]):
+                if req.sampling.forced_sync:
+                    break
+                room = self._mix_pf_tokens - used
+                if room < 1:
+                    break
+                chunk = min(self.ecfg.prefill_chunk,
+                            len(req.prompt_ids) - req.prefill_pos, room)
+                new_ctx = req.prefill_pos + chunk
+                try:
+                    self.kv.extend(req.request_id, new_ctx)
+                except MemoryError:
+                    break  # run what fits; classic preempts when nothing does
+                pf_rows.append((req, chunk, new_ctx))
+                used += -(-chunk // rq) * rq
+            if not pf_rows:
+                return False
+            # Decode page growth AFTER the prefill extends, mirroring the
+            # classic step order (prefill dispatch precedes decode). The
+            # internal preemption/drain may finish or evict decoders — or the
+            # whole decode side — so re-check before committing to the mix.
+            self._grow_pages_for_decode(1)
+            if not self.decoding:
+                return False
 
         t_build = time.perf_counter()
-        n = b * rq + self._mix_pf_tokens
-        n_pf = self._mix_pf_rows
-        pad_row = self._mix_rows - 1
-        trash = self._trash_pos()
-        tokens = np.zeros((n,), dtype=np.int32)
-        positions = np.full((n,), trash, dtype=np.int32)
-        row_ids = np.full((n,), pad_row, dtype=np.int32)
-        ctx_lens = np.zeros((self._mix_rows,), dtype=np.int32)
-        adapters = np.zeros((self._mix_rows,), dtype=np.int32)
-        dec_idx = np.arange(b, dtype=np.int32) * rq
-        dec_live = np.zeros((b,), dtype=np.int32)
-        for req in self.decoding:
-            s = req.slot
-            ec = req.ctx_len + self._lead(req)  # scheduled context
-            positions[s * rq] = ec - 1
-            row_ids[s * rq: (s + 1) * rq] = s
-            ctx_lens[s] = ec
-            adapters[s] = req.adapter_idx
-            dec_live[s] = 1
-        pf_last = np.zeros((n_pf,), dtype=np.int32)
-        off = b * rq
-        for j, (req, chunk, new_ctx) in enumerate(pf_rows):
-            r = b + j
-            tokens[off: off + chunk] = req.prompt_ids[req.prefill_pos:new_ctx]
-            positions[off: off + chunk] = np.arange(req.prefill_pos, new_ctx)
-            row_ids[off: off + (-(-chunk // rq) * rq)] = r
-            ctx_lens[r] = new_ctx
-            adapters[r] = req.adapter_idx
-            pf_last[j] = off + chunk - 1
-            off += -(-chunk // rq) * rq
-        tables = self._tables_for(
-            list(self._slots) + [r for r, _, _ in pf_rows]
-            + [None] * (n_pf - len(pf_rows)) + [None])
+        with self._span("build"):
+            n = b * rq + self._mix_pf_tokens
+            n_pf = self._mix_pf_rows
+            pad_row = self._mix_rows - 1
+            trash = self._trash_pos()
+            tokens = np.zeros((n,), dtype=np.int32)
+            positions = np.full((n,), trash, dtype=np.int32)
+            row_ids = np.full((n,), pad_row, dtype=np.int32)
+            ctx_lens = np.zeros((self._mix_rows,), dtype=np.int32)
+            adapters = np.zeros((self._mix_rows,), dtype=np.int32)
+            dec_idx = np.arange(b, dtype=np.int32) * rq
+            dec_live = np.zeros((b,), dtype=np.int32)
+            for req in self.decoding:
+                s = req.slot
+                ec = req.ctx_len + self._lead(req)  # scheduled context
+                positions[s * rq] = ec - 1
+                row_ids[s * rq: (s + 1) * rq] = s
+                ctx_lens[s] = ec
+                adapters[s] = req.adapter_idx
+                dec_live[s] = 1
+            pf_last = np.zeros((n_pf,), dtype=np.int32)
+            off = b * rq
+            for j, (req, chunk, new_ctx) in enumerate(pf_rows):
+                r = b + j
+                tokens[off: off + chunk] = req.prompt_ids[req.prefill_pos:new_ctx]
+                positions[off: off + chunk] = np.arange(req.prefill_pos, new_ctx)
+                row_ids[off: off + (-(-chunk // rq) * rq)] = r
+                ctx_lens[r] = new_ctx
+                adapters[r] = req.adapter_idx
+                pf_last[j] = off + chunk - 1
+                off += -(-chunk // rq) * rq
+            tables = self._tables_for(
+                list(self._slots) + [r for r, _, _ in pf_rows]
+                + [None] * (n_pf - len(pf_rows)) + [None])
 
-        # Completions are host-known before the dispatch: precompute the
-        # slot each will take (same lowest-free-slot order the classic
-        # path uses) so penalty count rows can be prepared NOW — the
-        # in-dispatch first-token sampling reads them.
-        done: list[tuple[int, EngineRequest, int]] = []
-        free = [i for i, s in enumerate(self._slots) if s is None]
-        for j, (req, chunk, new_ctx) in enumerate(pf_rows):
-            if new_ctx >= len(req.prompt_ids):
-                done.append((j, req, free.pop(0)))
-        fresh_pen = np.zeros((b,), dtype=bool)
-        for j, req, slot in done:
-            if req.sampling.penalized:
-                if req.all_out_ids:
-                    self._seed_counts_for(req, slot=slot)
-                else:
-                    fresh_pen[slot] = True
-        if fresh_pen.any():
-            self._tok_counts = _reset_count_rows(
-                self._tok_counts, jnp.asarray(fresh_pen))
+            # Completions are host-known before the dispatch: precompute the
+            # slot each will take (same lowest-free-slot order the classic
+            # path uses) so penalty count rows can be prepared NOW — the
+            # in-dispatch first-token sampling reads them.
+            done: list[tuple[int, EngineRequest, int]] = []
+            free = [i for i, s in enumerate(self._slots) if s is None]
+            for j, (req, chunk, new_ctx) in enumerate(pf_rows):
+                if new_ctx >= len(req.prompt_ids):
+                    done.append((j, req, free.pop(0)))
+            fresh_pen = np.zeros((b,), dtype=bool)
+            for j, req, slot in done:
+                if req.sampling.penalized:
+                    if req.all_out_ids:
+                        self._seed_counts_for(req, slot=slot)
+                    else:
+                        fresh_pen[slot] = True
+            if fresh_pen.any():
+                self._tok_counts = _reset_count_rows(
+                    self._tok_counts, jnp.asarray(fresh_pen))
 
-        si = self._slot_inputs()
-        pf_temps = np.zeros((n_pf,), dtype=np.float32)
-        pf_top_ps = np.ones((n_pf,), dtype=np.float32)
-        pf_top_ks = np.zeros((n_pf,), dtype=np.int32)
-        pf_pres = np.zeros((n_pf,), dtype=np.float32)
-        pf_freq = np.zeros((n_pf,), dtype=np.float32)
-        pf_seeds = np.full((n_pf,), -1, dtype=np.int32)
-        pf_slot_map = np.full((n_pf,), b, dtype=np.int32)  # b → dropped
-        pf_live = np.zeros((n_pf,), dtype=np.int32)
-        pf_use_pen = any(req.sampling.penalized for _, req, _ in done)
-        pf_use_seed = any(req.sampling.seed is not None
-                          for _, req, _ in done)
-        pf_use_bias = any(req.sampling.logit_bias for _, req, _ in done)
-        pf_bias = (np.zeros((n_pf, self.cfg.vocab_size), dtype=np.float32)
-                   if pf_use_bias else None)
-        for j, req, slot in done:
-            pf_temps[j] = req.sampling.temperature
-            pf_top_ps[j] = req.sampling.top_p
-            pf_top_ks[j] = req.sampling.top_k
-            pf_pres[j] = req.sampling.presence_penalty
-            pf_freq[j] = req.sampling.frequency_penalty
-            pf_slot_map[j] = slot
-            if req.sampling.penalized:
-                pf_live[j] = 1
-            if req.sampling.seed is not None:
-                pf_seeds[j] = req.sampling.seed & 0x7FFFFFFF
-            if pf_bias is not None:
-                for tok_id, b_val in req.sampling.logit_bias:
-                    pf_bias[j, tok_id] = b_val
-        use_pen = si.use_pen or pf_use_pen
+            si = self._slot_inputs()
+            pf_temps = np.zeros((n_pf,), dtype=np.float32)
+            pf_top_ps = np.ones((n_pf,), dtype=np.float32)
+            pf_top_ks = np.zeros((n_pf,), dtype=np.int32)
+            pf_pres = np.zeros((n_pf,), dtype=np.float32)
+            pf_freq = np.zeros((n_pf,), dtype=np.float32)
+            pf_seeds = np.full((n_pf,), -1, dtype=np.int32)
+            pf_slot_map = np.full((n_pf,), b, dtype=np.int32)  # b → dropped
+            pf_live = np.zeros((n_pf,), dtype=np.int32)
+            pf_use_pen = any(req.sampling.penalized for _, req, _ in done)
+            pf_use_seed = any(req.sampling.seed is not None
+                              for _, req, _ in done)
+            pf_use_bias = any(req.sampling.logit_bias for _, req, _ in done)
+            pf_bias = (np.zeros((n_pf, self.cfg.vocab_size), dtype=np.float32)
+                       if pf_use_bias else None)
+            for j, req, slot in done:
+                pf_temps[j] = req.sampling.temperature
+                pf_top_ps[j] = req.sampling.top_p
+                pf_top_ks[j] = req.sampling.top_k
+                pf_pres[j] = req.sampling.presence_penalty
+                pf_freq[j] = req.sampling.frequency_penalty
+                pf_slot_map[j] = slot
+                if req.sampling.penalized:
+                    pf_live[j] = 1
+                if req.sampling.seed is not None:
+                    pf_seeds[j] = req.sampling.seed & 0x7FFFFFFF
+                if pf_bias is not None:
+                    for tok_id, b_val in req.sampling.logit_bias:
+                        pf_bias[j, tok_id] = b_val
+            use_pen = si.use_pen or pf_use_pen
 
-        real_tokens = len(self.decoding) + sum(c for _, c, _ in pf_rows)
-        dec_snapshot = list(self.decoding)
-        inflight = self._pending is not None
-        self._key, sub = jax.random.split(self._key)
+            real_tokens = len(self.decoding) + sum(c for _, c, _ in pf_rows)
+            dec_snapshot = list(self.decoding)
+            inflight = self._pending is not None
+            self._key, sub = jax.random.split(self._key)
+
         mix_meta: dict[str, Any] = {"batch": len(dec_snapshot),
                                     "prefill_rows": len(pf_rows),
                                     "tokens": int(real_tokens)}
@@ -2317,7 +2437,10 @@ class EngineCore:
             mix_meta["requests"] = (
                 [r.request_id for r in dec_snapshot]
                 + [r.request_id for r, _, _ in pf_rows])
-        with self.tracer.span("engine.mixed", **mix_meta), annotate("mixed"):
+        if self._open is not None:
+            self._open.dispatched("_mixed_step", 1, len(dec_snapshot))
+        with self.tracer.span("engine.mixed", **mix_meta), \
+                annotate("mixed"), self._span("issue"):
             t_issue = time.perf_counter()
             (toks_win, pf_toks, feed_new, self._kv_k, self._kv_v,
              counts_out) = _mixed_step(
@@ -2372,16 +2495,18 @@ class EngineCore:
             self.decoding.append(req)
         if done:
             self._bump_epoch()  # slot→request mapping changed
-            # runbook: noqa[RBK002] — sanctioned sync: the one batched
-            # mixed-step first-token fetch (TTFT emission; decode rows
-            # stay device-resident in the overlap window).
-            pf_host = np.asarray(jax.device_get(pf_toks))
-            for j, req, slot in done:
-                if req.first_token_time is None:
-                    req.first_token_time = time.perf_counter()
-                    self.hist_ttft.observe(req.first_token_time
-                                           - req.arrival_time)
-                self._emit_token(req, int(pf_host[j]))
+            with self._span("fetch"):
+                # runbook: noqa[RBK002] — sanctioned sync: the one batched
+                # mixed-step first-token fetch (TTFT emission; decode rows
+                # stay device-resident in the overlap window).
+                pf_host = np.asarray(jax.device_get(pf_toks))
+            with self._span("emit"):
+                for j, req, slot in done:
+                    if req.first_token_time is None:
+                        req.first_token_time = time.perf_counter()
+                        self.hist_ttft.observe(req.first_token_time
+                                               - req.arrival_time)
+                    self._emit_token(req, int(pf_host[j]))
 
         # Decode rows ride the overlap pipeline exactly like _run_decode.
         if self.ecfg.overlap_decode:
@@ -2472,18 +2597,21 @@ class EngineCore:
                 self._drain_pending()
                 if not self.decoding:
                     return
-                if self.draft is not None:
-                    committed = [(r.request_id,
-                                  r.prompt_ids[: r.prefill_pos] + r.out_ids)
-                                 for r in self.decoding]
-                    drafts = self.draft.draft(committed, k - 1)
-                    for r in self.decoding:  # prompt-lookup fallback
-                        if not drafts.get(r.request_id):
-                            drafts[r.request_id] = self._draft_for(r, k - 1)
-                    self.metrics.update(self.draft.metrics)
-                else:
-                    drafts = {r.request_id: self._draft_for(r, k - 1)
-                              for r in self.decoding}
+                with self._span("draft"):
+                    if self.draft is not None:
+                        committed = [
+                            (r.request_id,
+                             r.prompt_ids[: r.prefill_pos] + r.out_ids)
+                            for r in self.decoding]
+                        drafts = self.draft.draft(committed, k - 1)
+                        for r in self.decoding:  # prompt-lookup fallback
+                            if not drafts.get(r.request_id):
+                                drafts[r.request_id] = self._draft_for(
+                                    r, k - 1)
+                        self.metrics.update(self.draft.metrics)
+                    else:
+                        drafts = {r.request_id: self._draft_for(r, k - 1)
+                                  for r in self.decoding}
                 # Worth it only when most of the batch drafts (nonempty
                 # decoding list makes this imply at least one draft): an
                 # undrafted request gets 1 token from a spec dispatch vs k
@@ -2498,7 +2626,8 @@ class EngineCore:
                     2 ** (self._spec_miss_streak - 1))
         # Grow pages to cover scheduled ctx + K for every sequence; preempt
         # on pressure (preemption drains the lagged window internally).
-        self._grow_pages_for_decode(k)
+        with self._span("build"):
+            self._grow_pages_for_decode(k)
         if not self.decoding:
             self.metrics["decode_time_s"] += (
                 (time.perf_counter() - t0) - (self._drain_time_acc - acc0))
@@ -2507,39 +2636,44 @@ class EngineCore:
         b = self.ecfg.max_batch_slots
         inflight = self._pending is not None
         t_build = time.perf_counter()
-        si = self._slot_inputs()
-        positions = np.zeros((b, 1), dtype=np.int32)
-        ctx_lens = np.zeros((b,), dtype=np.int32)
-        need_mask = False
-        mask = None
-        if self.mask_fn and any(r.sampling.guided for r in self.decoding):
-            mask = np.ones((b, self.cfg.vocab_size), dtype=bool)
-        for req in self.decoding:
-            i = req.slot
-            ec = req.ctx_len + self._lead(req)  # scheduled context
-            positions[i, 0] = ec - 1  # position of the token being fed
-            ctx_lens[i] = ec
-            if mask is not None and req.sampling.guided:
-                m = self.mask_fn(req)
-                if m is not None:
-                    _set_mask_row(mask, i, m)
-                    need_mask = True
-        self._key, sub = jax.random.split(self._key)
-        pen_kw = dict(
-            counts=self._tok_counts if si.use_pen else None,
-            pres=si.pres if si.use_pen else None,
-            freq=si.freq if si.use_pen else None,
-            seeds=si.seeds if si.use_seed else None,
-            bias=si.bias if si.use_bias else None,
-        )
-        # Device-resident token feedback: each slot's last sampled token
-        # never visits the host on the input side.
-        tokens_dev = self._feed_toks[:, None]
+        with self._span("build"):
+            si = self._slot_inputs()
+            positions = np.zeros((b, 1), dtype=np.int32)
+            ctx_lens = np.zeros((b,), dtype=np.int32)
+            need_mask = False
+            mask = None
+            if self.mask_fn and any(r.sampling.guided for r in self.decoding):
+                mask = np.ones((b, self.cfg.vocab_size), dtype=bool)
+            for req in self.decoding:
+                i = req.slot
+                ec = req.ctx_len + self._lead(req)  # scheduled context
+                positions[i, 0] = ec - 1  # position of the token being fed
+                ctx_lens[i] = ec
+                if mask is not None and req.sampling.guided:
+                    m = self.mask_fn(req)
+                    if m is not None:
+                        _set_mask_row(mask, i, m)
+                        need_mask = True
+            self._key, sub = jax.random.split(self._key)
+            pen_kw = dict(
+                counts=self._tok_counts if si.use_pen else None,
+                pres=si.pres if si.use_pen else None,
+                freq=si.freq if si.use_pen else None,
+                seeds=si.seeds if si.use_seed else None,
+                bias=si.bias if si.use_bias else None,
+            )
+            # Device-resident token feedback: each slot's last sampled token
+            # never visits the host on the input side.
+            tokens_dev = self._feed_toks[:, None]
 
         dec_meta: dict[str, Any] = {"k": k, "batch": len(self.decoding)}
         if self.tracer.enabled:
             dec_meta["requests"] = [r.request_id for r in self.decoding]
-        with self.tracer.span("engine.decode", **dec_meta), annotate("decode"):
+        if self._open is not None:
+            self._open.dispatched("_decode_step" if k == 1 else "_decode_multi",
+                                  k, len(self.decoding))
+        with self.tracer.span("engine.decode", **dec_meta), \
+                annotate("decode"), self._span("issue"):
             t_issue = time.perf_counter()
             last_logits = None
             if k == 1:
@@ -2638,28 +2772,42 @@ class EngineCore:
         recording = self.flight.enabled
         if recording:
             m = self.metrics
-            t0 = time.perf_counter()
             pre = (m["prefill_steps"], m["decode_dispatches"],
                    m["mixed_steps"], m["prefill_tokens"],
                    m["decode_tokens"], m["decode_dispatch_time_s"],
                    m["decode_host_time_s"], m["decode_host_overlap_s"],
                    m["preemptions"])
-        self._admit()
-        if not (self._can_mix() and self._run_mixed()):
-            if self.prefilling:
-                self._run_prefill()
-            self._run_decode()
-        if self.feedback is not None:
-            # SLO feedback (sched/feedback.py): every interval window the
-            # controller moves the mixed-dispatch prefill share one level
-            # against the live TPOT burn. None (the default) = untouched.
-            self.feedback.on_step(self)
-        if recording:
-            self._record_step(t0, pre)
+            self._open = OpenStep()
+        compiles0, compile_s0 = _compile_totals
+        try:
+            # ``step`` is the number this step's record will get: the join
+            # between a span on the profiler's clock and the record.
+            with annotate("engine.step", step=self.flight.total_steps):
+                with self._span("admit"):
+                    self._admit()
+                if not (self._can_mix() and self._run_mixed()):
+                    if self.prefilling:
+                        self._run_prefill()
+                    self._run_decode()
+                if self.feedback is not None:
+                    # SLO feedback (sched/feedback.py): every interval
+                    # window the controller moves the mixed-dispatch
+                    # prefill share one level against the live TPOT burn.
+                    # None (the default) = untouched.
+                    self.feedback.on_step(self)
+                compiles, compile_s = _compile_totals
+                if compiles != compiles0:
+                    self.metrics["compiles"] += compiles - compiles0
+                    self.metrics["compile_time_s"] += compile_s - compile_s0
+                if recording:
+                    self._record_step(pre, compile_s - compile_s0)
+        finally:
+            self._open = None
         return self.finished[before:]
 
-    def _record_step(self, t0: float, pre: tuple) -> None:
-        """Append this step's flight record (O(1): one dict + ring slot).
+    def _record_step(self, pre: tuple, compile_s: float) -> None:
+        """Close the open step into its flight record (O(1): one dict +
+        ring slot).
 
         Dispatch kind derives from the PR 4 counters' deltas — ``mixed``
         for the unified ragged step, ``prefill+decode`` when the classic
@@ -2667,6 +2815,7 @@ class EngineCore:
         step. Token counts follow the metrics dict's semantics: decode
         tokens book at window DRAIN, one window late under overlap."""
         m = self.metrics
+        step = self._open
         d_prefill = m["prefill_steps"] - pre[0]
         d_decode = m["decode_dispatches"] - pre[1]
         d_mixed = m["mixed_steps"] - pre[2]
@@ -2688,12 +2837,13 @@ class EngineCore:
         for r in self.decoding:
             label = class_name(r.priority)
             classes[label] = classes.get(label, 0) + 1
+        prefill_tokens = m["prefill_tokens"] - pre[3]
+        decode_tokens = m["decode_tokens"] - pre[4]
         rec = {
             "ts": round(time.time(), 6),
             "kind": kind,
             "classes": classes,
-            "tokens": (m["prefill_tokens"] - pre[3]
-                       + m["decode_tokens"] - pre[4]),
+            "tokens": prefill_tokens + decode_tokens,
             "batch": batch,
             "occupancy": round(batch / self.ecfg.max_batch_slots, 4),
             "queue_depth": len(self.waiting) + len(self.prefilling),
@@ -2702,9 +2852,17 @@ class EngineCore:
             "dispatch_s": round(m["decode_dispatch_time_s"] - pre[5], 6),
             "host_s": round(m["decode_host_time_s"] - pre[6], 6),
             "overlap_s": round(m["decode_host_overlap_s"] - pre[7], 6),
-            "wall_s": round(time.perf_counter() - t0, 6),
             "preemptions": m["preemptions"] - pre[8],
+            "program": step.programs,
+            "k": step.k,
+            "rows": step.rows,
+            "prefill_tokens": prefill_tokens,
+            "decode_tokens": decode_tokens,
+            "compile_s": round(compile_s, 6),
+            "admitted": self._admitted_log,
+            "finished": self._finished_log,
         }
+        self._admitted_log, self._finished_log = [], []
         # Page transfers land BETWEEN steps (cross-replica pulls, disagg
         # handoffs, spill readmits run under the engine lock outside
         # step()), so these deltas are measured against the LAST RECORDED
@@ -2717,6 +2875,15 @@ class EngineCore:
         self._flight_kv_mark = (imported, exported)
         if self.replica_idx is not None:
             rec["replica"] = self.replica_idx
+        # The step's two ends last, so that ``other`` — the wall no phase
+        # accounts for — holds everything down to this record's making.
+        t_end = time.monotonic()
+        wall = t_end - step.t_start
+        phases = step.phases
+        phases["other"] = wall - sum(phases.values())
+        rec["t_start"], rec["t_end"] = step.t_start, t_end
+        rec["wall_s"] = round(wall, 6)
+        rec["phases"] = {k: round(v, 6) for k, v in phases.items()}
         self.flight.append(rec)
 
     def run_until_idle(self, max_steps: int = 100_000) -> list[EngineRequest]:

@@ -11,7 +11,11 @@ prefill / decode / mixed — plus ``prefill+decode`` for a split step that
 ran both, and ``idle`` for a drain-only step), real tokens this dispatch,
 batch occupancy (total AND per priority class — the scheduler-fairness
 picture), queue depth, KV-pool free pages, the dispatch/host/overlap wall
-split, preemptions, and the replica index when fleeted.
+split, preemptions, and the replica index when fleeted. The record is
+also the program's span of the step: its two ends on ``time.monotonic()``,
+the seconds of each phase (:class:`OpenStep`), the step program(s) it
+dispatched, seconds the process spent compiling in it, and the requests
+first admitted and retired in it (``docs/observability.md``).
 
 Design constraints (pinned by ``tests/test_observability.py``):
 
@@ -31,6 +35,7 @@ Design constraints (pinned by ``tests/test_observability.py``):
 from __future__ import annotations
 
 import json
+import time
 from pathlib import Path
 from typing import Any, Optional
 
@@ -43,7 +48,69 @@ STEP_RECORD_FIELDS = (
     "queue_depth", "kv_free_pages", "kv_utilization", "dispatch_s",
     "host_s", "overlap_s", "wall_s", "preemptions", "kv_imported",
     "kv_exported", "replica",
+    # The record as a span (docs/observability.md): its two ends on
+    # time.monotonic(), where its seconds went, what it dispatched, and
+    # the requests that entered and left the engine in it.
+    "t_start", "t_end", "phases", "program", "k", "rows",
+    "prefill_tokens", "decode_tokens", "compile_s", "admitted", "finished",
 )
+
+# ``phases`` keys besides "other" (= wall_s less their sum), and the
+# profiler span that marks the same boundaries on the device trace's
+# clock ("issue" is the dispatch annotation: prefill / mixed / decode /
+# decode_spec).
+STEP_PHASES = ("admit", "build", "issue", "fetch", "emit", "draft")
+PHASE_SPANS = {"admit": "engine.admit", "build": "engine.build",
+               "fetch": "engine.fetch_tokens", "emit": "engine.emit",
+               "draft": "engine.draft"}
+
+# One entry of ``finished``: a request's lifecycle, built once, in
+# EngineCore._retire. Its times are on one clock, CLOCK_MONOTONIC:
+# ``t_received``, ``t_enqueued`` and ``t_first_write`` read
+# time.monotonic(); the others are the perf_counter() stamps the engine's
+# histograms already took (EngineRequest says why).
+LIFECYCLE_FIELDS = (
+    "id", "trace_id", "t_received", "t_enqueued", "t_admitted",
+    "t_first_token", "t_first_write", "t_finished", "prompt_tokens",
+    "cached_tokens", "generated", "preemptions", "reason",
+    "max_emit_gap_s",
+)
+
+
+class OpenStep:
+    """The step being run, until it becomes a record: seconds per phase,
+    and what was dispatched. A phase entered inside another pauses the
+    outer one, so no interval is counted twice however the engine's
+    drains nest. Written by the step thread only."""
+
+    __slots__ = ("t_start", "phases", "programs", "k", "rows",
+                 "_stack", "_t")
+
+    def __init__(self):
+        self.phases = dict.fromkeys(STEP_PHASES, 0.0)
+        self.programs: list[str] = []
+        self.k = 0  # decode steps in the dispatch
+        self.rows = 0  # decode rows dispatched
+        self._stack: list[str] = []
+        self._t = 0.0
+        self.t_start = time.monotonic()
+
+    def enter(self, phase: str) -> None:
+        now = time.monotonic()
+        if self._stack:
+            self.phases[self._stack[-1]] += now - self._t
+        self._stack.append(phase)
+        self._t = now
+
+    def exit(self) -> None:
+        now = time.monotonic()
+        self.phases[self._stack.pop()] += now - self._t
+        self._t = now
+
+    def dispatched(self, program: str, k: int = 0, rows: int = 0) -> None:
+        self.programs.append(program)
+        if k:
+            self.k, self.rows = k, rows
 
 
 class FlightRecorder:
@@ -115,7 +182,8 @@ class FlightRecorder:
         classes: dict[str, int] = {}
         merged: dict[str, Any] = {
             "steps_recorded": 0, "steps_total": 0, "capacity": 0,
-            "tokens": 0, "occupancy_p50": 0.0, "occupancy_p95": 0.0,
+            "tokens": 0, "prefill_tokens": 0, "decode_tokens": 0,
+            "decode_row_steps": 0, "occupancy_p50": 0.0, "occupancy_p95": 0.0,
             "kv_utilization_peak": 0.0, "queue_depth_peak": 0,
         }
         for s in summaries:
@@ -124,7 +192,8 @@ class FlightRecorder:
             for cls, count in s.get("class_slot_steps", {}).items():
                 classes[cls] = classes.get(cls, 0) + count
             for key in ("steps_recorded", "steps_total", "capacity",
-                        "tokens"):
+                        "tokens", "prefill_tokens", "decode_tokens",
+                        "decode_row_steps"):
                 merged[key] += s.get(key, 0)
             for key in ("occupancy_p50", "occupancy_p95",
                         "kv_utilization_peak", "queue_depth_peak"):
@@ -135,15 +204,16 @@ class FlightRecorder:
 
     def summary(self) -> dict[str, Any]:
         """Step-level provenance for a measured run (bench
-        ``flight_summary``): per-dispatch-kind step counts, occupancy
-        p50/p95, and the KV-pressure peak over the retained window."""
+        ``flight_summary``): per-dispatch-kind step counts, tokens by
+        side against the decode row-steps dispatched, occupancy p50/p95,
+        and the KV-pressure peak over the retained window."""
         records = self.snapshot()
         kinds: dict[str, int] = {}
         classes: dict[str, int] = {}
         occ: list[float] = []
         kv_peak = 0.0
         queue_peak = 0
-        tokens = 0
+        tokens = prefill_tokens = decode_tokens = decode_row_steps = 0
         for rec in records:
             kinds[str(rec.get("kind", "?"))] = (
                 kinds.get(str(rec.get("kind", "?")), 0) + 1)
@@ -156,6 +226,9 @@ class FlightRecorder:
             kv_peak = max(kv_peak, float(rec.get("kv_utilization", 0.0)))
             queue_peak = max(queue_peak, int(rec.get("queue_depth", 0)))
             tokens += int(rec.get("tokens", 0))
+            prefill_tokens += int(rec.get("prefill_tokens", 0))
+            decode_tokens += int(rec.get("decode_tokens", 0))
+            decode_row_steps += int(rec.get("rows", 0)) * int(rec.get("k", 0))
         occ.sort()
         return {
             "steps_recorded": len(records),
@@ -164,6 +237,13 @@ class FlightRecorder:
             "dispatch_kinds": dict(sorted(kinds.items())),
             "class_slot_steps": dict(sorted(classes.items())),
             "tokens": tokens,
+            # ``tokens`` by side, and the decode side's denominator: row x
+            # step pairs dispatched (``rows`` * ``k``). decode_tokens over
+            # it is the share of dispatched decode work that became a
+            # token (a window runs on past a row's stop or length limit).
+            "prefill_tokens": prefill_tokens,
+            "decode_tokens": decode_tokens,
+            "decode_row_steps": decode_row_steps,
             "occupancy_p50": round(_percentile(occ, 50), 4),
             "occupancy_p95": round(_percentile(occ, 95), 4),
             "kv_utilization_peak": round(kv_peak, 4),

@@ -3,6 +3,7 @@
 from __future__ import annotations
 
 import asyncio
+import contextvars
 import time
 import uuid
 from dataclasses import dataclass, field
@@ -16,6 +17,28 @@ class FleetSaturated(RuntimeError):
     placements; the HTTP layer maps it to 503 before headers, or to an
     SSE error event once they are out). Lives here, not in fleet.py, so
     the server can catch it without importing the jax-heavy fleet module."""
+
+
+@dataclass
+class RequestOrigin:
+    """What a front door stamps on a request before the engine sees it.
+    It travels by context, not by keyword: the HTTP handler sets
+    :data:`request_origin`, asyncio copies the calling thread's context
+    into every coroutine the handler starts on the engine's loop
+    (``run_coroutine_threadsafe``), and an :class:`EngineRequest` made
+    there reads it. A further stamp is a field here, and no signature
+    between the handler and the engine changes."""
+
+    t_received: float  # time.monotonic() at the handler's start
+
+
+request_origin: contextvars.ContextVar[Optional[RequestOrigin]] = (
+    contextvars.ContextVar("runbook_request_origin", default=None))
+
+
+def _t_received() -> Optional[float]:
+    origin = request_origin.get()
+    return None if origin is None else origin.t_received
 
 
 class RequestState(str, Enum):
@@ -137,6 +160,33 @@ class EngineRequest:
     out_logprobs: list = field(default_factory=list)
     # Prompt tokens served from the prefix cache at admission.
     cached_tokens: int = 0
+    # Lifecycle stamps. The engine builds ONE record from them when the
+    # request retires — the flight record's ``finished`` entry and the
+    # tracer's engine.request event (docs/observability.md). The stamps
+    # new with that record read time.monotonic(). ``t_admitted`` reads
+    # perf_counter() like ``arrival_time``, ``first_token_time`` and
+    # ``finish_time`` above, because queue wait, TTFT and TPOT are
+    # differences among those four; on Linux both are CLOCK_MONOTONIC
+    # (tests/test_step_spans.py says so), so the record is on one clock.
+    # ``t_received`` is the front door's (RequestOrigin); None for a
+    # caller that has none (arrival_time stands in).
+    t_received: Optional[float] = field(default_factory=_t_received)
+    t_enqueued: Optional[float] = None  # EngineCore.submit, monotonic()
+    t_admitted: Optional[float] = None  # first admission only
+    t_first_write: Optional[float] = None  # first content chunk flushed
+    last_emit_time: Optional[float] = None
+    max_emit_gap_s: float = 0.0  # longest interval between two emits
+    preemptions: int = 0
+    lifecycle: Optional[dict] = None  # the record, once retired
+
+    def mark_first_write(self, t: float) -> None:
+        """The server flushed this request's first content chunk at ``t``
+        (handler thread). A request can retire before that write; its
+        record is then already in the ring, and is completed in place."""
+        if self.t_first_write is None:
+            self.t_first_write = t
+            if self.lifecycle is not None:
+                self.lifecycle["t_first_write"] = t
 
     @property
     def ctx_len(self) -> int:

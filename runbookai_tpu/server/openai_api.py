@@ -64,6 +64,7 @@ No reference counterpart (RunbookAI calls hosted APIs; SURVEY.md §2.2).
 from __future__ import annotations
 
 import asyncio
+import contextlib
 import json
 import threading
 import time
@@ -72,14 +73,19 @@ from concurrent.futures import TimeoutError as _FutTimeout  # builtin alias 3.11
 from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 from typing import Any, Optional
 
-from runbookai_tpu.engine.request import FinishReason, FleetSaturated
+from runbookai_tpu.engine.request import (
+    FinishReason,
+    FleetSaturated,
+    RequestOrigin,
+    request_origin,
+)
 from runbookai_tpu.sched import (
     CLASS_NAMES,
     PRIORITY_INTERACTIVE,
     class_priority,
 )
 from runbookai_tpu.utils.metrics import REQUEST_LATENCY_BUCKETS, get_registry
-from runbookai_tpu.utils.trace import get_tracer
+from runbookai_tpu.utils.trace import annotate, get_tracer
 
 # Bounded route-label cardinality: anything else is scraped as "other".
 _KNOWN_ROUTES = frozenset((
@@ -365,12 +371,28 @@ def make_handler(bridge: _EngineBridge, model_name: str,
             route = bare if bare in _KNOWN_ROUTES else "other"
             tracer = get_tracer()
             tracer.set_context(request_id=self._request_id)
-            t0 = time.perf_counter()
+            # time.monotonic(): the request's t_received, on the clock of
+            # the engine's step and lifecycle records. It reaches the
+            # EngineRequest by context (request.RequestOrigin).
+            t0 = time.monotonic()
+            origin = request_origin.set(RequestOrigin(t_received=t0))
+            # On the profiler's clock, ``server.parse``: from here (body
+            # read, template, tokenise, admission) to the engine hand-off,
+            # where the route closes it, or to the end of a route that
+            # has none (closing twice is harmless). Stat ``request`` is
+            # the lifecycle record's ``trace_id``: the join that splits a
+            # request's way in into parse and hand-off
+            # (benchmark/tools/front_door.py).
+            self._parse = contextlib.ExitStack()
+            self._parse.enter_context(
+                annotate("server.parse", request=self._request_id))
             try:
                 with tracer.span("server.request", route=route,
                                  method=method):
                     fn()
             finally:
+                self._parse.close()
+                request_origin.reset(origin)
                 tracer.clear_context()
                 status = str(self._status or 500)
                 requests_total.labels(
@@ -378,7 +400,7 @@ def make_handler(bridge: _EngineBridge, model_name: str,
                     status=status if status in _KNOWN_STATUSES
                     else "other").inc()
                 request_latency.labels(route=route, method=method).observe(
-                    time.perf_counter() - t0)
+                    time.monotonic() - t0)
 
         def _json(self, code: int, payload: dict,
                   headers: Optional[dict] = None) -> None:
@@ -921,6 +943,7 @@ def make_handler(bridge: _EngineBridge, model_name: str,
                                 request_id=self._request_id)
                             for i in range(n)], return_exceptions=True)
 
+                    self._parse.close()  # the engine's from here
                     outs = bridge.run(_gen_n(), timeout=request_timeout + 60)
                     if any(isinstance(o, BaseException) for o in outs):
                         self._settle_tenant(admission, 0)
@@ -1064,6 +1087,7 @@ def make_handler(bridge: _EngineBridge, model_name: str,
                     return await asyncio.gather(*jobs,
                                                 return_exceptions=True)
 
+                self._parse.close()  # the engine's from here
                 outs = bridge.run(_gen_all(), timeout=request_timeout + 60)
                 if any(isinstance(o, BaseException) for o in outs):
                     self._settle_tenant(admission, 0)
@@ -1261,23 +1285,29 @@ def make_handler(bridge: _EngineBridge, model_name: str,
             tokenizer = (tokenizer if tokenizer is not None
                          else client.tokenizer)
             model = model or model_name
+            self._parse.close()  # the engine's from here
             self.send_response(200)
             self.send_header("Content-Type", "text/event-stream")
             self.send_header("Cache-Control", "no-cache")
             self.send_header("Transfer-Encoding", "chunked")
             self.end_headers()
 
+            # ``server.write``: each SSE write, with the request it is for.
+            write_meta = {"request": getattr(self, "_request_id", "")}
+
             def send_chunk(payload: dict) -> None:
-                data = f"data: {json.dumps(payload)}\n\n".encode()
-                self.wfile.write(f"{len(data):x}\r\n".encode() + data
-                                 + b"\r\n")
-                self.wfile.flush()
+                with annotate("server.write", **write_meta):
+                    data = f"data: {json.dumps(payload)}\n\n".encode()
+                    self.wfile.write(f"{len(data):x}\r\n".encode() + data
+                                     + b"\r\n")
+                    self.wfile.flush()
 
             def send_terminator(extra: bytes = b"") -> None:
-                done = extra + b"data: [DONE]\n\n"
-                self.wfile.write(f"{len(done):x}\r\n".encode() + done
-                                 + b"\r\n0\r\n\r\n")
-                self.wfile.flush()
+                with annotate("server.write", **write_meta):
+                    done = extra + b"data: [DONE]\n\n"
+                    self.wfile.write(f"{len(done):x}\r\n".encode() + done
+                                     + b"\r\n0\r\n\r\n")
+                    self.wfile.flush()
 
             chunk_id = f"chatcmpl-{uuid.uuid4().hex[:12]}"
             send_chunk(_chunk_payload(model, {"role": "assistant"},
@@ -1322,6 +1352,12 @@ def make_handler(bridge: _EngineBridge, model_name: str,
                         if lp is not None:
                             payload["choices"][0]["logprobs"] = lp
                         send_chunk(payload)
+                        if req_sink:
+                            # The first content chunk is on the socket
+                            # (later calls change nothing): the request's
+                            # t_first_write. The fleet appends the SERVING
+                            # attempt's request last.
+                            req_sink[-1].mark_first_write(time.monotonic())
                 finally:
                     # Settle the tenant reservation at the TRUE size: the
                     # tokens the client actually received are billed even

@@ -53,7 +53,12 @@ def _ctx(rec: dict[str, Any]) -> dict[str, Any]:
 
 
 def _start_ts(rec: dict[str, Any]) -> float:
-    """Span records are written at CLOSE (ts = end); order by start."""
+    """When a record STARTED. New records carry ``t0``, time.monotonic()
+    at the start — the clock of the engine's step records; a file from
+    before that has only ``ts``, the wall clock at CLOSE, less ``ms``.
+    One file is all of one kind, so differences within it hold."""
+    if "t0" in rec:
+        return float(rec["t0"])
     return float(rec.get("ts", 0.0)) - float(rec.get("ms", 0.0)) / 1e3
 
 
@@ -170,7 +175,7 @@ def build_timeline(spans: list[dict[str, Any]],
         name = str(rec.get("name", ""))
         if name not in INCIDENT_EVENTS:
             continue
-        ts = float(rec.get("ts", 0.0))
+        ts = _start_ts(rec)
         if not (t0 - 1.0 <= ts <= t_end + 1.0):
             continue
         meta = _meta(rec)

@@ -4,11 +4,13 @@ SURVEY.md §5.1 — the reference has no tracer; its only "trace" is per-tool
 ``durationMs`` plus the scratchpad JSONL. The TPU build adds the real thing:
 
 - :class:`Tracer` — nested host spans appended as JSONL (one object per
-  span: ts, name, ms, depth, meta). Cheap enough to leave on in production;
-  a disabled tracer costs one ``if``.
-- :func:`annotate` — ``jax.profiler.TraceAnnotation`` passthrough so engine
-  dispatches (prefill/decode/spec) show up on the XProf/TensorBoard device
-  timeline with meaningful names.
+  span: ts, t0, name, ms, depth, meta). ``ts`` is the wall clock at CLOSE;
+  ``t0`` is ``time.monotonic()`` at the START: the clock of the engine's
+  step and lifecycle records and of the load generator. Cheap enough to
+  leave on in production; a disabled tracer costs one ``if``.
+- :func:`annotate` — ``jax.profiler.TraceAnnotation`` passthrough so the
+  engine's step, its phases and its dispatches show up on the
+  XProf/TensorBoard timeline, on the device trace's clock, by name.
 - :func:`device_trace` — context manager around
   ``jax.profiler.start_trace``/``stop_trace`` for capturing a device profile
   of any region (``RUNBOOK_DEVICE_TRACE=<logdir>`` wraps a whole CLI run).
@@ -149,15 +151,15 @@ class Tracer:
         if not self.enabled:
             yield
             return
-        t0 = time.perf_counter()
+        t0 = time.monotonic()
         self._depth += 1
         depth = self._depth
         try:
             yield
         finally:
             self._depth -= 1
-            rec = {"ts": time.time(), "name": name, "depth": depth,
-                   "ms": round((time.perf_counter() - t0) * 1e3, 3)}
+            rec = {"ts": time.time(), "t0": t0, "name": name, "depth": depth,
+                   "ms": round((time.monotonic() - t0) * 1e3, 3)}
             ctx = self._ctx()
             if ctx:
                 rec["ctx"] = ctx
@@ -169,7 +171,8 @@ class Tracer:
         """Zero-duration marker."""
         if not self.enabled:
             return
-        rec = {"ts": time.time(), "name": name, "depth": self._depth + 1, "ms": 0.0}
+        rec = {"ts": time.time(), "t0": time.monotonic(), "name": name,
+               "depth": self._depth + 1, "ms": 0.0}
         ctx = self._ctx()
         if ctx:
             rec["ctx"] = ctx
@@ -221,11 +224,13 @@ def set_tracer(tracer: Optional[Tracer]) -> None:
     _global = tracer if tracer is not None else _NULL
 
 
-def annotate(name: str):
-    """Named region on the XProf device timeline (no-op off-profile)."""
+def annotate(name: str, **meta: Any):
+    """Named region on the profiler's host timeline (no-op off-profile).
+    ``meta`` rides as the event's stats: ``annotate("engine.step",
+    step=n)`` reads back as name ``engine.step``, stat ``step``."""
     import jax
 
-    return jax.profiler.TraceAnnotation(name)
+    return jax.profiler.TraceAnnotation(name, **meta)
 
 
 @contextlib.contextmanager

@@ -34,7 +34,13 @@ def rec(i, kind="decode", **kw):
             "kv_free_pages": 10, "kv_utilization": 0.1,
             "dispatch_s": 0.001, "host_s": 0.0005, "overlap_s": 0.0,
             "wall_s": 0.002, "preemptions": 0, "kv_imported": 0,
-            "kv_exported": 0}
+            "kv_exported": 0, "t_start": float(i), "t_end": i + 0.002,
+            "phases": {"admit": 0.0, "build": 0.0005, "issue": 0.001,
+                       "fetch": 0.0, "emit": 0.0, "draft": 0.0,
+                       "other": 0.0005},
+            "program": ["_decode_multi"], "k": 8, "rows": 1,
+            "prefill_tokens": 0, "decode_tokens": 2, "compile_s": 0.0,
+            "admitted": [], "finished": []}
     base.update(kw)
     return base
 

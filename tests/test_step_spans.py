@@ -1,0 +1,386 @@
+"""The step record as a span (docs/observability.md): every step's two
+ends and phases, the lifecycle record of every retired request, what the
+front door stamps, and that none of it is built with the recorder off.
+CPU, tiny engine and server."""
+
+from __future__ import annotations
+
+import dataclasses
+import json
+import time
+import urllib.request
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from runbookai_tpu.engine.engine import EngineConfig, EngineCore
+from runbookai_tpu.engine.flight_recorder import (
+    LIFECYCLE_FIELDS,
+    PHASE_SPANS,
+    STEP_PHASES,
+    STEP_RECORD_FIELDS,
+    OpenStep,
+)
+from runbookai_tpu.engine.request import (
+    EngineRequest,
+    RequestOrigin,
+    SamplingParams,
+    request_origin,
+)
+from runbookai_tpu.models.llama import CONFIGS, init_params
+from runbookai_tpu.utils.tokens import ByteTokenizer
+
+NEW_FIELDS = ("t_start", "t_end", "phases", "program", "k", "rows",
+              "prefill_tokens", "decode_tokens", "compile_s", "admitted",
+              "finished")
+ORDER = ("t_received", "t_enqueued", "t_admitted", "t_first_token",
+         "t_finished")
+
+
+@pytest.fixture(scope="module")
+def parts():
+    cfg = CONFIGS["llama3-test"]
+    return cfg, init_params(jax.random.PRNGKey(0), cfg, dtype=jnp.float32)
+
+
+def make_core(parts, **kw) -> EngineCore:
+    cfg, params = parts
+    settings = dict(page_size=4, num_pages=64, max_batch_slots=4,
+                    prefill_chunk=8, max_seq_len=128, block_pages=4,
+                    kv_dtype=jnp.float32, flight_recorder_steps=256)
+    settings.update(kw)
+    return EngineCore(cfg, params, ByteTokenizer(), EngineConfig(**settings))
+
+
+def request(text: bytes, n: int = 6) -> EngineRequest:
+    return EngineRequest(prompt_ids=list(text), sampling=SamplingParams(
+        temperature=0.0, max_new_tokens=n, stop_token_ids=()))
+
+
+def test_the_two_clocks_are_one():
+    """The lifecycle record mixes perf_counter() stamps the engine always
+    took (arrival, first token, finish) with monotonic() ones: on Linux
+    both read CLOCK_MONOTONIC. Said here, not assumed."""
+    for _ in range(5):
+        a, b, c = time.monotonic(), time.perf_counter(), time.monotonic()
+        assert a - 1e-3 <= b <= c + 1e-3
+
+
+def test_the_field_lists_hold_the_new_fields():
+    assert set(NEW_FIELDS) <= set(STEP_RECORD_FIELDS)
+    assert set(PHASE_SPANS) < set(STEP_PHASES) and "issue" in STEP_PHASES
+    assert set(ORDER) < set(LIFECYCLE_FIELDS)
+
+
+def test_a_phase_inside_another_pauses_it():
+    step = OpenStep()
+    step.enter("build")
+    time.sleep(0.01)
+    step.enter("fetch")
+    time.sleep(0.02)
+    step.exit()
+    step.exit()
+    enclosed = time.monotonic() - step.t_start
+    step.dispatched("_prefill_step")
+    step.dispatched("_decode_multi", 8, 3)
+    build, fetch = step.phases["build"], step.phases["fetch"]
+    assert build >= 0.01 and fetch >= 0.02
+    assert build + fetch <= enclosed  # build is NOT the whole it enclosed
+    assert sum(step.phases.values()) == build + fetch
+    assert (step.programs, step.k, step.rows) == (
+        ["_prefill_step", "_decode_multi"], 8, 3)
+
+
+def check_steps(steps: list[dict]) -> None:
+    for s in steps:
+        assert set(NEW_FIELDS) <= set(s), s
+        assert s["t_start"] <= s["t_end"]
+        assert set(s["phases"]) == set(STEP_PHASES) | {"other"}
+        assert all(v >= 0.0 for v in s["phases"].values()), s["phases"]
+        assert abs(sum(s["phases"].values()) - s["wall_s"]) < 1e-3
+        assert abs((s["t_end"] - s["t_start"]) - s["wall_s"]) < 1e-3
+        assert s["tokens"] == s["prefill_tokens"] + s["decode_tokens"]
+        assert (s["rows"] > 0) == (s["k"] > 0)
+        assert all(p.startswith("_") for p in s["program"])
+        assert bool(s["program"]) == (s["kind"] != "idle")
+    ends = [(s["t_start"], s["t_end"]) for s in steps]
+    assert all(a[1] <= b[0] for a, b in zip(ends, ends[1:]))  # no overlap
+
+
+def check_lifecycle(steps: list[dict], ids: list[str]) -> dict[str, dict]:
+    """Every request of ``ids`` in exactly ONE record's ``finished``."""
+    seen = [f for s in steps for f in s["finished"]]
+    assert sorted(f["id"] for f in seen) == sorted(ids)
+    for f in seen:
+        assert set(f) == set(LIFECYCLE_FIELDS)
+        # An abort can retire a request before it was admitted or had a
+        # token: those stamps stay None.
+        stamps = [f[k] for k in ORDER if f[k] is not None]
+        assert stamps == sorted(stamps), f
+        owner = [s for s in steps if f in s["finished"]][0]
+        assert f["t_finished"] <= owner["t_end"]
+    return {f["id"]: f for f in seen}
+
+
+def test_every_step_is_a_span_and_every_request_retires_once(parts):
+    core = make_core(parts)
+    reqs = [request(t) for t in (b"hello flight", b"recorder test", b"x")]
+    for r in reqs:
+        core.submit(r)
+    core.run_until_idle()
+    steps = core.flight.snapshot()
+    check_steps(steps)
+    life = check_lifecycle(steps, [r.request_id for r in reqs])
+    admitted = [a for s in steps for a in s["admitted"]]
+    assert sorted(a[0] for a in admitted) == sorted(life)
+    for rid, wait_s, prompt_tokens, cached in admitted:
+        f = life[rid]
+        assert wait_s == pytest.approx(f["t_admitted"] - f["t_received"], abs=1e-3)
+        assert (prompt_tokens, cached) == (f["prompt_tokens"], f["cached_tokens"])
+    for r in reqs:
+        f = life[r.request_id]
+        assert f is r.lifecycle and f["reason"] == "max_tokens"
+        assert f["generated"] == 6 and f["preemptions"] == 0
+        assert f["t_received"] == r.arrival_time  # no front door
+        assert f["t_first_write"] is None  # never streamed
+        assert 0.0 < f["max_emit_gap_s"] <= f["t_finished"] - f["t_first_token"]
+    assert core.metrics["compile_time_s"] == pytest.approx(
+        sum(s["compile_s"] for s in steps), abs=1e-4)
+
+
+def test_the_summary_reads_the_dispatch_fields(parts):
+    """``flight.summary()`` (bench's ``flight_summary``) is the program's
+    reader of ``k``, ``rows``, ``prefill_tokens`` and ``decode_tokens``."""
+    from runbookai_tpu.engine.flight_recorder import FlightRecorder
+
+    core = make_core(parts, decode_steps_per_dispatch=4)
+    for text in (b"one prompt here", b"and another"):
+        core.submit(request(text, n=6))
+    core.run_until_idle()
+    steps, s = core.flight.snapshot(), core.flight.summary()
+    assert s["prefill_tokens"] == sum(x["prefill_tokens"] for x in steps) \
+        == len(b"one prompt here") + len(b"and another")
+    assert s["prefill_tokens"] + s["decode_tokens"] == s["tokens"]
+    assert s["decode_row_steps"] == sum(x["rows"] * x["k"] for x in steps)
+    # Every decode token came out of a dispatched row-step; the windows
+    # ran on past max_new_tokens, so some row-steps gave none.
+    assert 0 < s["decode_tokens"] < s["decode_row_steps"]
+    merged = FlightRecorder.merge_summaries([s, s])
+    for key in ("prefill_tokens", "decode_tokens", "decode_row_steps"):
+        assert merged[key] == 2 * s[key]
+
+
+def test_a_compile_is_booked_to_the_step_it_stalled(parts):
+    """Whether a program compiles here hangs on what the process compiled
+    before, so the listener is fed by hand, from inside a step (the
+    feedback hook runs there)."""
+    from runbookai_tpu.engine import engine as engine_mod
+
+    class CompilesOnce:
+        fired = False
+
+        def on_step(self, core):
+            if not self.fired:
+                self.fired = True
+                engine_mod._on_compile(engine_mod._COMPILE_EVENT, 0.25)
+                engine_mod._on_compile("/jax/another/event", 9.0)
+
+    core = make_core(parts)
+    core.step()  # an idle step first: the booking is per step
+    before = dict(core.metrics)
+    core.feedback = CompilesOnce()
+    core.submit(request(b"compile"))
+    core.run_until_idle()
+    idle, stalled, *rest = core.flight.snapshot()
+    assert idle["compile_s"] == 0.0
+    assert 0.25 <= stalled["compile_s"] < 9.0
+    assert core.metrics["compiles"] - before["compiles"] >= 1
+    assert core.metrics["compile_time_s"] == pytest.approx(
+        sum(s["compile_s"] for s in core.flight.snapshot()), abs=1e-4)
+
+
+def test_a_preempted_request_keeps_its_first_admission(parts):
+    core = make_core(parts, num_pages=20, max_batch_slots=2,
+                     decode_steps_per_dispatch=1, admit_headroom_tokens=8)
+    reqs = [request(bytes([ch]) * 21, n=40) for ch in b"ab"]
+    for r in reqs:
+        core.submit(r)
+    core.run_until_idle()
+    assert core.metrics["preemptions"] >= 1, "scenario must actually preempt"
+    steps = core.flight.snapshot()
+    check_steps(steps)
+    life = check_lifecycle(steps, [r.request_id for r in reqs])
+    admitted = [a for s in steps for a in s["admitted"]]
+    assert sorted(a[0] for a in admitted) == sorted(life)  # once each
+    assert sum(f["preemptions"] for f in life.values()) == \
+        core.metrics["preemptions"]
+    for a in admitted:
+        f = life[a[0]]
+        assert a[2] == f["prompt_tokens"] == 21  # not the folded prompt
+        assert f["t_admitted"] - f["t_received"] == pytest.approx(a[1], abs=1e-3)
+
+
+def test_an_abort_between_steps_lands_in_the_next_record(parts):
+    core = make_core(parts)
+    keep, gone = request(b"stays", n=4), request(b"goes away", n=40)
+    core.submit(keep)
+    core.submit(gone)
+    core.step()
+    assert core.abort(gone.request_id)
+    core.run_until_idle()
+    life = check_lifecycle(core.flight.snapshot(),
+                           [keep.request_id, gone.request_id])
+    assert life[gone.request_id]["reason"] == "aborted"
+
+
+def test_with_the_recorder_off_nothing_is_built(parts):
+    core = make_core(parts, flight_recorder_steps=0)
+    req = request(b"off")
+    core.submit(req)
+    core.step()
+    assert core._open is None
+    core.run_until_idle()
+    assert req.finish_reason is not None and req.lifecycle is None
+    assert req.max_emit_gap_s == 0.0 and req.last_emit_time is None
+    assert core._admitted_log == [] and core._finished_log == []
+    assert len(core.flight) == 0
+
+
+def test_the_first_write_reaches_the_record_whoever_comes_first(parts):
+    """The handler thread stamps the first write while the engine thread
+    retires the request: whichever order the two take, the record in the
+    ring ends up with the stamp (time-bounded; more threads than cores
+    would add nothing: the race has two sides)."""
+    import sys
+    import threading
+
+    core = make_core(parts)
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    deadline = time.monotonic() + 2.0
+    rounds = 0
+    try:
+        while time.monotonic() < deadline and rounds < 3000:
+            req = request(b"x")
+            writer = threading.Thread(target=req.mark_first_write, args=(7.0,))
+            writer.start()
+            core._retire(req, time.monotonic())
+            writer.join(timeout=5)
+            assert not writer.is_alive()
+            assert req.lifecycle["t_first_write"] == 7.0
+            rounds += 1
+    finally:
+        sys.setswitchinterval(old)
+    assert rounds > 100 and len(core._finished_log) == rounds
+
+
+# ---- through the front door --------------------------------------------------
+
+
+@pytest.fixture(scope="module")
+def server():
+    from runbookai_tpu.model.jax_tpu import JaxTpuClient
+    from runbookai_tpu.server.openai_api import OpenAIServer
+
+    client = JaxTpuClient.for_testing(max_new_tokens=6)
+    srv = OpenAIServer(client, model_name="llama3-test", port=0)
+    srv.start_background()
+    yield srv
+    srv.shutdown()
+
+
+def chat(srv, content: str, stream: bool, rid: str) -> bytes:
+    req = urllib.request.Request(
+        f"http://127.0.0.1:{srv.port}/v1/chat/completions",
+        data=json.dumps({"messages": [{"role": "user", "content": content}],
+                         "max_tokens": 5, "stream": stream}).encode(),
+        headers={"Content-Type": "application/json", "x-request-id": rid},
+        method="POST")
+    with urllib.request.urlopen(req, timeout=120) as r:
+        return r.read()
+
+
+def test_the_front_door_stamps_both_ends(server):
+    t_before = time.monotonic()
+    assert b"[DONE]" in chat(server, "stream me", True, "rid-streamed")
+    chat(server, "all at once", False, "rid-whole")
+    with urllib.request.urlopen(
+            f"http://127.0.0.1:{server.port}/debug/steps?n=512", timeout=60) as r:
+        steps = json.loads(r.read())["steps"]
+    check_steps(steps)
+    by_trace = {f["trace_id"]: f for s in steps for f in s["finished"]}
+    streamed, whole = by_trace["rid-streamed"], by_trace["rid-whole"]
+    waits = {a[0]: a[1] for s in steps for a in s["admitted"]}
+    for f in (streamed, whole):
+        stamps = [f[k] for k in ORDER]
+        assert stamps == sorted(stamps) and stamps[0] >= t_before
+        # The handler's start, carried across the thread hop by context:
+        # before the EngineRequest was made (its arrival_time, from which
+        # the queue wait counts), with parse, template and tokenise between.
+        arrival = f["t_admitted"] - waits[f["id"]]
+        assert f["t_received"] < arrival <= f["t_enqueued"] + 1e-3
+    assert streamed["t_first_write"] >= streamed["t_first_token"]
+    assert whole["t_first_write"] is None
+    with urllib.request.urlopen(
+            f"http://127.0.0.1:{server.port}/healthz", timeout=60) as r:
+        health = json.loads(r.read())["metrics"]
+    assert health["compiles"] >= 0 and health["compile_time_s"] >= 0.0
+
+
+async def test_the_fleets_debug_steps_keep_the_new_fields():
+    from runbookai_tpu.model.jax_tpu import JaxTpuClient
+
+    client = JaxTpuClient.for_testing(max_new_tokens=6, dp_replicas=2)
+    fleet = client.engine
+    sp = SamplingParams(temperature=0.0, max_new_tokens=6, stop_token_ids=())
+    # As the HTTP handler does it: the stamp travels by context, through
+    # the fleet's routing and the replica's AsyncEngine, by no keyword.
+    t_received = time.monotonic()
+    origin = request_origin.set(RequestOrigin(t_received=t_received))
+    outs = [await fleet.generate(list(text), sp, request_id=rid)
+            for text, rid in ((b"the quick brown fox", "rid-a"),
+                              (b"zebra stripes xyz", "rid-b"))]
+    request_origin.reset(origin)
+    assert request(b"after the handler").t_received is None
+    steps = fleet.debug_steps()["steps"]
+    await fleet.stop()
+    assert all(o.token_ids for o in outs)
+    assert {s["replica"] for s in steps} <= {0, 1}
+    for s in steps:
+        assert set(NEW_FIELDS) <= set(s)
+    finished = {f["trace_id"]: f for s in steps for f in s["finished"]}
+    assert set(finished) == {"rid-a", "rid-b"}
+    assert all(f["t_received"] == t_received for f in finished.values())
+
+
+def test_the_tracer_is_on_the_shared_clock(tmp_path):
+    """``t0`` = time.monotonic() at the START of a span or event, beside
+    the wall-clock ``ts`` taken at its close; the timeline orders by it,
+    and a file from before ``t0`` still reads."""
+    from runbookai_tpu.utils.timeline import build_timeline
+    from runbookai_tpu.utils.trace import Tracer, read_spans, summarize_spans
+
+    tracer = Tracer(tmp_path / "t.jsonl")
+    before = time.monotonic()
+    with tracer.span("engine.decode", requests=["r1"]):
+        tracer.event("engine.enqueue", request="r1", prompt_tokens=3)
+        time.sleep(0.01)
+    after = time.monotonic()
+    tracer.event("engine.request", request="r1", reason="stop_token",
+                 generated=2)
+    tracer.close()
+    spans = read_spans(tmp_path / "t.jsonl")
+    by_name = {r["name"]: r for r in spans}
+    outer, inner = by_name["engine.decode"], by_name["engine.enqueue"]
+    assert before <= outer["t0"] <= inner["t0"] <= after
+    assert outer["ms"] >= 10.0 and outer["t0"] + outer["ms"] / 1e3 <= after + 1e-3
+    assert all({"ts", "t0", "name", "depth", "ms"} <= set(r) for r in spans)
+    # Written at close, the span comes AFTER the event inside it in the
+    # file; by t0 it comes first.
+    assert [r["name"] for r in spans][:2] == ["engine.enqueue", "engine.decode"]
+    names = [e["name"] for e in build_timeline(spans, "r1")["events"]]
+    assert names == ["engine.decode", "engine.enqueue", "engine.request"]
+    old = [{k: v for k, v in r.items() if k != "t0"} for r in spans]
+    assert build_timeline(old, "r1")["finish"]["generated"] == 2
+    assert summarize_spans(old) == summarize_spans(spans)
