@@ -31,6 +31,7 @@ SURVEY.md §2.2); this is the TPU serving stack's self-audit.
 
 from __future__ import annotations
 
+import functools
 import math
 import re
 from typing import Any, Iterable
@@ -44,7 +45,54 @@ _WIDE_DTYPES = {"bf16": 2, "f16": 2, "f32": 4, "f64": 8}
 
 # `%name = dtype[dims]{layout} op(...)` — optimized HLO instruction line.
 _INSTR = re.compile(r"^(?:ROOT\s+)?%?[\w.\-]+\s*=\s*([a-z0-9]+)\[([\d,]*)\]")
-_COMPUTATION = re.compile(r"^(?:ENTRY\s+)?%?([\w.\-]+)\s*(?:\([^)]*\))?\s*->.*\{")
+# `%name (params) -> type {` — a computation's header; parameters of a
+# loop body are tuples, so the list nests parentheses.
+_COMPUTATION = re.compile(r"^(?:ENTRY\s+)?%?([\w.\-]+)\s+\(.*->.*\{$")
+# name, op and operand list of an instruction; the computation a fusion calls.
+_NAMED = re.compile(r"^(?:ROOT\s+)?%?([\w.\-]+)\s*=\s*\S+\s+([\w\-]+)\((.*)")
+_CALLS = re.compile(r"\bfusion\(.*\bcalls=%?([\w.\-]+)")
+
+
+def _computations(hlo_text: str) -> dict[str, list[str]]:
+    """Optimized HLO as ``computation name -> its instruction lines``."""
+    comps: dict[str, list[str]] = {}
+    lines: list[str] | None = None
+    for raw in hlo_text.splitlines():
+        line = raw.strip()
+        if lines is None:
+            comp = _COMPUTATION.match(line)
+            if comp is not None:
+                lines = comps.setdefault(comp.group(1), [])
+        elif line == "}":
+            lines = None
+        else:
+            lines.append(line)
+    return comps
+
+
+def _fusion_bodies(comps: dict[str, list[str]]) -> set[str]:
+    """Computations whose values are virtual: the bodies fusions call
+    (named ``fused_computation*`` or, for a wrapped single op, after it).
+    Loop/scan bodies and reduction combinators are NOT among them:
+    while-body instructions own buffers (a per-layer dequant or pool
+    slice inside the scan over layers is exactly the hazard), and
+    combinator regions are scalar so they never match a tensor's dims."""
+    bodies = {name for name in comps if "fused" in name}
+    for lines in comps.values():
+        for line in lines:
+            called = _CALLS.search(line)
+            if called is not None:
+                bodies.add(called.group(1))
+    return bodies
+
+
+def _dims(line: str) -> tuple[str, tuple[int, ...]] | None:
+    """(dtype, dims) of an instruction's array result; None for tuples,
+    scalars and lines that are no instruction."""
+    m = _INSTR.match(line)
+    if m is None or not m.group(2):
+        return None
+    return m.group(1), tuple(int(d) for d in m.group(2).split(","))
 
 
 def quantized_weight_shapes(params: Any) -> set[tuple[int, ...]]:
@@ -80,64 +128,151 @@ def wide_weight_materializations(
     inside fusion computations are skipped (virtual values); fusion
     ROOTS appear at their call sites and are caught."""
     targets = {tuple(s) for s in weight_shapes}
+    comps = _computations(hlo_text)
+    virtual = _fusion_bodies(comps)
     bad: list[str] = []
-    in_fused_body = False
-    depth = 0
-    for raw in hlo_text.splitlines():
-        line = raw.strip()
-        comp = _COMPUTATION.match(line)
-        if comp is not None and line.endswith("{"):
-            name = comp.group(1)
-            # ONLY fusion computations hold virtual values. Loop/scan
-            # bodies and reduction combinators are scanned too: while-body
-            # instructions own buffers (a per-layer dequant inside the
-            # scan over layers is exactly the hazard), and combinator
-            # regions are scalar so they can never match a weight shape.
-            in_fused_body = "fused" in name
-            depth = 1
+    for name, lines in comps.items():
+        if name in virtual:
             continue
-        if depth:
-            depth += line.count("{") - line.count("}")
-            if depth <= 0:
-                in_fused_body = False
-                depth = 0
+        for line in lines:
+            result = _dims(line)
+            if result is None or "parameter(" in line:
                 continue
-        if in_fused_body:
-            continue
-        m = _INSTR.match(line)
-        if m is None or "parameter(" in line:
-            continue
-        dtype, dims = m.group(1), m.group(2)
-        if dtype not in _WIDE_DTYPES or not dims:
-            continue
-        if tuple(int(d) for d in dims.split(",")) in targets:
-            bad.append(line[:200])
+            if result[0] in _WIDE_DTYPES and result[1] in targets:
+                bad.append(line[:200])
     return bad
 
 
 def lower_decode(core, *, qmm_impl: str | None = None,
-                 attn_impl: str | None = None):
-    """Lower + compile the engine's single-token decode dispatch — the
+                 attn_impl: str | None = None,
+                 program: str = "_decode_step", sharding=None):
+    """Lower + compile one of the engine's decode-side dispatches — the
     exact jitted function and argument shapes ``EngineCore._run_decode``
-    uses — WITHOUT executing it (donation only applies on execute, so
-    the live pool buffers are safe to pass)."""
-    from runbookai_tpu.engine.engine import _decode_step
+    (``"_decode_step"``, or ``"_decode_multi"`` at the configured steps
+    per dispatch) and ``EngineCore._run_mixed`` (``"_mixed_step"``) use —
+    WITHOUT executing it (donation only applies on execute, so the live
+    pool buffers are safe to pass). With ``sharding`` every array goes in
+    as its shape on that sharding instead: the way to compile for a
+    device that is described and not attached
+    (``jax.experimental.topologies``)."""
+    from runbookai_tpu.engine import engine
 
-    b = core.ecfg.max_batch_slots
-    tables = jnp.zeros((b, core.kv.max_pages_per_seq + 1), jnp.int32)
-    return _decode_step.lower(
-        core.params, core.cfg,
-        jnp.zeros((b, 1), jnp.int32), jnp.zeros((b, 1), jnp.int32),
-        core._kv_k, core._kv_v, tables,
-        jnp.ones((b,), jnp.int32),
-        jnp.zeros((b,), jnp.float32), jnp.ones((b,), jnp.float32),
-        jnp.zeros((b,), jnp.int32), jax.random.PRNGKey(0), None,
-        jnp.zeros((b,), jnp.int32),
-        page_size=core.ecfg.page_size, block_pages=core.ecfg.block_pages,
-        attn_impl=attn_impl if attn_impl is not None else core.ecfg.attn_impl,
+    ecfg = core.ecfg
+    b = ecfg.max_batch_slots
+    i32 = functools.partial(jnp.zeros, dtype=jnp.int32)
+    f32 = functools.partial(jnp.zeros, dtype=jnp.float32)
+    pages = core.kv.max_pages_per_seq + 1
+    static = dict(
+        page_size=ecfg.page_size, block_pages=ecfg.block_pages,
+        attn_impl=attn_impl if attn_impl is not None else ecfg.attn_impl,
         mesh=core.mesh,
-        qmm_impl=qmm_impl if qmm_impl is not None else core.ecfg.qmm_impl,
-    ).compile()
+        qmm_impl=qmm_impl if qmm_impl is not None else ecfg.qmm_impl,
+    )
+    key = jax.random.PRNGKey(0)
+    if program == "_mixed_step":
+        rq = engine._RAGGED_BLOCK
+        n = b * rq + core._mix_pf_tokens
+        n_pf, rows = core._mix_pf_rows, core._mix_rows
+        step, static["ragged_block"] = engine._mixed_step, rq
+        args = (core.params, core.cfg, i32((n,)), i32((b,)), i32((b,)),
+                i32((n,)), i32((n,)), core._kv_k, core._kv_v,
+                i32((rows, pages)), i32((rows,)), i32((rows,)),
+                i32((n_pf,)), f32((b,)), f32((b,)), i32((b,)), key,
+                f32((n_pf,)), f32((n_pf,)), i32((n_pf,)), i32((n_pf,)),
+                i32((n_pf,)))
+    else:
+        args = (core.params, core.cfg, i32((b, 1)), i32((b, 1)),
+                core._kv_k, core._kv_v, i32((b, pages)),
+                jnp.ones((b,), jnp.int32), f32((b,)),
+                jnp.ones((b,), jnp.float32), i32((b,)), key)
+        if program == "_decode_multi":
+            step, args = engine._decode_multi, args + (i32((b,)),)
+            static["k_steps"] = ecfg.decode_steps_per_dispatch
+        elif program == "_decode_step":
+            step, args = engine._decode_step, args + (None, i32((b,)))
+        else:
+            raise ValueError(f"no such decode program: {program!r}")
+    if sharding is not None:
+        args = jax.tree.map(
+            lambda a: (jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                            sharding=sharding)
+                       if isinstance(a, jax.Array) else a), args)
+    return step.lower(*args, **static).compile()
+
+
+def kv_pool_shapes(core) -> set[tuple[int, ...]]:
+    """Dims a materialized KV pool would take in the compiled program:
+    ``[L, tokens, n_kv, hd]`` and its row and page views. int8 pools add
+    the scale arrays' forms."""
+    ps = core.ecfg.page_size
+    shapes: set[tuple[int, ...]] = set()
+    for leaf in jax.tree.leaves((core._kv_k, core._kv_v)):
+        n_layers, tokens, *rest = leaf.shape
+        for lead in ((n_layers, tokens), (n_layers * tokens,),
+                     (n_layers * tokens // ps, ps)):
+            shapes.add(lead + tuple(rest))
+    return shapes
+
+
+# Ops that name a buffer without owning a new one.
+_VIEW_OPS = {"parameter", "get-tuple-element", "bitcast"}
+
+
+def _writes_rows_in_place(line: str, lines: list[str],
+                          comps: dict[str, list[str]],
+                          layer_elems: int) -> bool:
+    """True for the page write itself: a ``scatter``, or a
+    ``dynamic-update-slice`` whose update is smaller than one layer of
+    the pool — at the top level or as the root of the fusion ``line``
+    calls. Both write their rows into the operand's own buffer."""
+    called = _CALLS.search(line)
+    if called is not None:
+        lines = comps.get(called.group(1), [])
+        line = next((ln for ln in lines if ln.startswith("ROOT ")), "")
+    named = _NAMED.match(line)
+    if named is None:
+        return False
+    if named.group(2) == "scatter":
+        return True
+    if named.group(2) != "dynamic-update-slice":
+        return False
+    update = named.group(3).split(",")[1].split()[-1].lstrip("%")
+    shapes = {m.group(1): _dims(ln) for ln in lines
+              if (m := _NAMED.match(ln)) is not None}
+    found = shapes.get(update)
+    return found is not None and math.prod(found[1]) < layer_elems
+
+
+def kv_pool_materializations(compiled, core) -> list[str]:
+    """Offending lines of a compiled step program: instructions outside
+    fusion bodies whose result is a buffer of the KV pool's dims
+    (:func:`kv_pool_shapes`), other than the program's parameters, views
+    of them (``get-tuple-element`` out of the loops' own tuples,
+    ``bitcast``) and the page write itself
+    (:func:`_writes_rows_in_place`). The pool rides the layer scan's
+    carry and is written in place (``models/llama.py``), so a clean
+    program has none: a ``copy``, a ``broadcast`` or ``AllocateBuffer``
+    (a second pool) or a layer-sized ``dynamic-update-slice`` (a layer
+    written through) each move gigabytes a pass at serving size. The
+    readers' layer slice is not hunted: it is the kernels' feed."""
+    targets = kv_pool_shapes(core)
+    layer_elems = min(math.prod(leaf.shape[1:]) for leaf in
+                      jax.tree.leaves((core._kv_k, core._kv_v)))
+    comps = _computations(compiled.as_text())
+    virtual = _fusion_bodies(comps)
+    bad: list[str] = []
+    for name, lines in comps.items():
+        if name in virtual:
+            continue
+        for line in lines:
+            result, named = _dims(line), _NAMED.match(line)
+            if result is None or named is None or result[1] not in targets:
+                continue
+            if named.group(2) in _VIEW_OPS or _writes_rows_in_place(
+                    line, lines, comps, layer_elems):
+                continue
+            bad.append(line[:200])
+    return bad
 
 
 def param_nbytes(params: Any) -> int:

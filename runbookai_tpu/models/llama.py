@@ -8,7 +8,18 @@ Design (TPU-first, no reference counterpart — RunbookAI calls hosted APIs):
   and makes TP sharding specs uniform.
 - A single forward covers chunked prefill and decode (decode is T=1): the
   chunk's K/V are scattered into the paged pool, then queries attend over the
-  pool via :func:`runbookai_tpu.ops.attention.paged_attention`.
+  pool via :func:`runbookai_tpu.ops.attention.paged_attention` or the Pallas
+  kernels of :mod:`runbookai_tpu.ops.paged_attention_pallas`.
+- The KV pool, ``[n_layers, num_pages * page_size, n_kv, head_dim]`` a side,
+  rides the layer scan's CARRY whole and is updated in place, layer by
+  layer: each layer's scatter writes its B*T rows at ``(layer, dest)``, so
+  a step program holds ONE pool — the donated one — and nothing
+  pool-shaped is copied and no layer is written through, whatever the
+  program (decode, multi-step decode, verify, prefill, mixed).
+  ``engine/hlo_bytes.kv_pool_materializations`` holds the compiled programs
+  to that. Attention reads the layer's slice of the carry, which the chip
+  stages in on-chip memory for the kernels. The pool's shape outside the
+  forward is unchanged.
 - GQA (n_kv_heads < n_heads), RMSNorm in float32, bf16 weights by default,
   logits in float32 for stable sampling/grammar masking.
 """
@@ -391,6 +402,13 @@ def _forward_hidden(
     :func:`forward_impl` (full [B, T, vocab] logits) and
     :func:`forward_ragged_impl` (mixed prefill+decode batches, which gather
     the few rows they need before paying for the vocab projection).
+
+    The scan over layers carries ``(hidden, kv_k, kv_v)``; its ``xs`` are
+    the layer's parameters and its number. The page writers (the flat
+    scatter, and kv-split's ``shard_map`` one) take the whole pool and
+    that number and write their rows in place; every reader (XLA gather,
+    Pallas kernels, their TP and kv-split wraps, int8 pools) takes the
+    layer's slice of the carry.
     """
     b, t = tokens.shape
     hd, n_kv = cfg.head_dim, cfg.n_kv_heads
@@ -431,8 +449,13 @@ def _forward_hidden(
             qmm_impl = "xla"
     mm = partial(qmm, impl=qmm_impl)
 
-    def layer_step(hidden, layer_in):
-        lp, lp_lora, k_pages, v_pages = layer_in
+    def layer_step(carry, layer_in):
+        # The pool rides the CARRY, whole: a scan's stacked output can
+        # never share its scanned input's buffer, so handing the pool in
+        # as ``xs`` and taking it back as ``ys`` copied all of it every
+        # pass and wrote every layer through again.
+        hidden, kv_k, kv_v = carry
+        lp, lp_lora, li = layer_in
         x = rms_norm(hidden, lp["attn_norm"], cfg.norm_eps)
         q, k, v = mm(x, lp["wq"]), mm(x, lp["wk"]), mm(x, lp["wv"])
         if lp_lora is not None:
@@ -447,23 +470,36 @@ def _forward_hidden(
         q = apply_rope(q, positions, cfg.rope_theta, cfg.rope_scaling)
         k = apply_rope(k, positions, cfg.rope_theta, cfg.rope_scaling)
 
-        # Scatter the whole batch's K/V into the page pool in one scatter
-        # (program size stays flat as max_batch_slots grows; disjoint page
-        # ownership makes flattened destinations collision-free).
+        # Scatter the whole batch's K/V into layer ``li`` of the pool in
+        # one scatter (program size stays flat as max_batch_slots grows;
+        # disjoint page ownership makes flattened destinations
+        # collision-free).
         if kv_split_active:
             from runbookai_tpu.parallel.kv_split import (
                 write_kv_pages_batch_kv_split,
             )
 
-            k_pages = write_kv_pages_batch_kv_split(
-                mesh, k_pages, k, positions, page_tables, page_size)
-            v_pages = write_kv_pages_batch_kv_split(
-                mesh, v_pages, v, positions, page_tables, page_size)
+            kv_k = write_kv_pages_batch_kv_split(
+                mesh, kv_k, k, positions, page_tables, page_size, layer=li)
+            kv_v = write_kv_pages_batch_kv_split(
+                mesh, kv_v, v, positions, page_tables, page_size, layer=li)
         else:
-            k_pages = write_kv_pages_batch(k_pages, k, positions,
-                                           page_tables, page_size)
-            v_pages = write_kv_pages_batch(v_pages, v, positions,
-                                           page_tables, page_size)
+            kv_k = write_kv_pages_batch(kv_k, k, positions, page_tables,
+                                        page_size, layer=li)
+            kv_v = write_kv_pages_batch(kv_v, v, positions, page_tables,
+                                        page_size, layer=li)
+        # The readers take the layer's slice of the carry, [tokens, n_kv,
+        # hd], as when the scan handed it to them. On the chip XLA stages
+        # that slice in on-chip memory and the Pallas kernels fetch their
+        # 16 KB pages from there; fetching them straight out of the pool
+        # in HBM (a layer coordinate in the kernels' index_maps, no slice)
+        # was measured and doubled both kernels' time (PERF.md section 6,
+        # PR 25).
+        def layer_slice(pool):  # int8 pools are (values, scales)
+            return jax.tree.map(lambda a: jax.lax.dynamic_index_in_dim(
+                a, li, keepdims=False), pool)
+
+        k_pages, v_pages = layer_slice(kv_k), layer_slice(kv_v)
 
         # int8 pools: the decode kernel reads int8 pages + scales
         # directly (widened in VMEM); chunked prefill is compute-bound
@@ -551,12 +587,13 @@ def _forward_hidden(
 
         y = rms_norm(hidden, lp["mlp_norm"], cfg.norm_eps)
         hidden = hidden + ffn_block(y, lp, cfg, qmm_impl=qmm_impl)
-        return hidden, (k_pages, v_pages)
+        return (hidden, kv_k, kv_v), None
 
-    h, (kv_k_new, kv_v_new) = jax.lax.scan(
-        layer_step, h, (params["layers"], lora, kv_k, kv_v)
+    (h, kv_k, kv_v), _ = jax.lax.scan(
+        layer_step, (h, kv_k, kv_v),
+        (params["layers"], lora, jnp.arange(cfg.n_layers, dtype=jnp.int32)),
     )
-    return h, kv_k_new, kv_v_new
+    return h, kv_k, kv_v
 
 
 def forward_impl(
@@ -583,7 +620,11 @@ def forward_impl(
     decode kernel when T == 1; with a TP ``mesh`` the kernel runs per
     model-axis shard via shard_map (falling back to the XLA gather path only
     when GQA heads don't divide the axis — the pool replicates there too).
-    Donate ``kv_k``/``kv_v`` at the jit call site for in-place page updates.
+    Donate ``kv_k``/``kv_v`` at the jit call site for in-place page updates:
+    the pool is the layer scan's carry and each layer scatters its rows
+    into it, so with the donation the program writes B*T rows a layer into
+    the caller's buffer and allocates no second pool (without it, XLA
+    copies the pool once, on entry).
     """
     h, kv_k_new, kv_v_new = _forward_hidden(
         params, cfg, tokens, positions, kv_k, kv_v, page_tables, ctx_lens,

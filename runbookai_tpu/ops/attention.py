@@ -42,6 +42,23 @@ def quantize_kv(new_kv: jnp.ndarray) -> tuple[jnp.ndarray, jnp.ndarray]:
     return jnp.clip(q, -127, 127).astype(jnp.int8), scale
 
 
+def pool_rows(kv, layer):
+    """The pool as rows: its ``[L * tokens, ...]`` view and the first row
+    of ``layer`` (a traced scalar inside the layer scan). The serving
+    forward carries the WHOLE ``[L, tokens, n_kv, hd]`` pool through its
+    scan over layers and the page writers address one layer of it by
+    this row offset, so the write is a scatter of a few rows into the
+    carried buffer. The reshape only merges the two leading axes (a
+    bitcast). ``layer=None``: ``kv`` is one layer's ``[tokens, ...]``
+    already."""
+    if layer is None:
+        return kv, 0
+    if isinstance(kv, tuple):  # int8 pool: values + per-vector scales
+        return (tuple(a.reshape((-1,) + a.shape[2:]) for a in kv),
+                layer * kv[0].shape[1])
+    return kv.reshape((-1,) + kv.shape[2:]), layer * kv.shape[1]
+
+
 def _dequant_gather(kv_flat, flat_idx):
     """Gather pool rows at ``flat_idx``; dequantize when the pool is an
     (int8 values, f32 scales) tuple."""
@@ -76,6 +93,7 @@ def write_kv_pages_batch(
     positions: jnp.ndarray,  # [B, T] absolute positions (pads -> trash column)
     page_tables: jnp.ndarray,  # [B, max_pages(+1)] physical page ids per seq
     page_size: int,
+    layer=None,
 ) -> jnp.ndarray:
     """Scatter a whole batch's new K/V in ONE flat scatter.
 
@@ -84,18 +102,28 @@ def write_kv_pages_batch(
     flattened destinations never collide — except padding rows, whose
     positions resolve through the trailing trash column to the reserved
     null page 0 (PageAllocator.NULL_PAGE), which is never read.
+
+    With ``layer`` (the serving forward), ``kv_flat`` is the WHOLE pool
+    ``[L, tokens, n_kv, head_dim]`` and the rows land at
+    ``layer * tokens + dest`` of its row view (:func:`pool_rows`): the
+    scatter touches B * T rows of the buffer the layer scan carries and
+    nothing else, so XLA updates the pool in place. Returns the pool in
+    the shape it came in.
     """
     b, t = positions.shape
     logical_page = positions // page_size
     offset = positions % page_size
     phys = jnp.take_along_axis(page_tables, logical_page, axis=1)  # [B, T]
-    dest = (phys * page_size + offset).reshape(b * t)
+    rows, base = pool_rows(kv_flat, layer)
+    dest = base + (phys * page_size + offset).reshape(b * t)
     flat_new = new_kv.reshape((b * t,) + new_kv.shape[2:])
     if isinstance(kv_flat, tuple):  # int8 pool: values + per-vector scales
-        vals, scales = kv_flat
+        vals, scales = rows
         q, s = quantize_kv(flat_new)
-        return vals.at[dest].set(q), scales.at[dest].set(s)
-    return kv_flat.at[dest].set(flat_new.astype(kv_flat.dtype))
+        return (vals.at[dest].set(q).reshape(kv_flat[0].shape),
+                scales.at[dest].set(s).reshape(kv_flat[1].shape))
+    return rows.at[dest].set(flat_new.astype(rows.dtype)).reshape(
+        kv_flat.shape)
 
 
 def paged_attention(

@@ -41,6 +41,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from runbookai_tpu.ops.attention import pool_rows
 from runbookai_tpu.parallel.mesh import MODEL_AXIS, SEQ_AXIS
 
 NEG_INF = -1e30
@@ -300,18 +301,25 @@ def write_kv_pages_batch_kv_split(
     positions: jnp.ndarray,  # [B, T] (replicated)
     page_tables: jnp.ndarray,  # [B, max_pages(+1)] (replicated)
     page_size: int,
+    layer=None,
 ) -> jnp.ndarray:
     """Batch K/V scatter where each device keeps only writes landing in
     its own page slice (out-of-slice destinations drop — they are some
-    other device's writes)."""
+    other device's writes). With ``layer`` (the serving forward)
+    ``kv_flat`` is the whole ``[L, tokens, n_kv, hd]`` pool the layer
+    scan carries and comes back in that shape, that layer's rows written
+    in place (``ops.attention.write_kv_pages_batch``)."""
+    shape = kv_flat.shape
+    if layer is None:  # one layer's [tokens, n_kv, hd]: layer 0 of one
+        kv_flat, layer = kv_flat[None], 0
     pg_shards = mesh.shape.get(SEQ_AXIS, 1)
-    if (kv_flat.shape[0] // page_size) % pg_shards != 0:
+    if (kv_flat.shape[1] // page_size) % pg_shards != 0:
         raise ValueError(
-            f"num_pages={kv_flat.shape[0] // page_size} must divide by "
+            f"num_pages={kv_flat.shape[1] // page_size} must divide by "
             f"pg_shards={pg_shards}")
-    tokens_local = kv_flat.shape[0] // pg_shards
+    tokens_local = kv_flat.shape[1] // pg_shards
 
-    def local_fn(kv_l, new_l, pos, tables):
+    def local_fn(kv_l, new_l, pos, tables, ly):
         my_pg = jax.lax.axis_index(SEQ_AXIS)
         b, t = pos.shape
         logical_page = pos // page_size
@@ -323,14 +331,17 @@ def write_kv_pages_batch_kv_split(
         # mode='drop' only drops high indices — a negative index wraps
         # Python-style and would corrupt this shard's mirror slot.
         in_slice = (local >= 0) & (local < tokens_local)
-        local = jnp.where(in_slice, local, tokens_local)
+        rows, base = pool_rows(kv_l, ly)
+        local = jnp.where(in_slice, base + local, rows.shape[0])
         flat_new = new_l.reshape((b * t,) + new_l.shape[2:])
-        return kv_l.at[local].set(flat_new.astype(kv_l.dtype), mode="drop")
+        return rows.at[local].set(flat_new.astype(rows.dtype),
+                                  mode="drop").reshape(kv_l.shape)
 
-    kv_spec = P(SEQ_AXIS, MODEL_AXIS, None)
+    kv_spec = P(None, SEQ_AXIS, MODEL_AXIS, None)
     return jax.shard_map(
         local_fn, mesh=mesh,
         in_specs=(kv_spec, P(None, None, MODEL_AXIS, None), P(None, None),
-                  P(None, None)),
+                  P(None, None), P()),
         out_specs=kv_spec,
-    )(kv_flat, new_kv, positions, page_tables)
+    )(kv_flat, new_kv, positions, page_tables,
+      jnp.asarray(layer, jnp.int32)).reshape(shape)

@@ -13,7 +13,12 @@ arguments. These tests pin it to the COMPILED decode program instead:
   + KV pool + O(batch) operands; fp8 KV halves pool argument bytes
   exactly;
 - `memory_plan` arithmetic cross-checks against a live engine's actual
-  allocations (VERDICT r4 weak #4).
+  allocations (VERDICT r4 weak #4);
+- no step program owns a buffer of the KV pool's shape beyond the pool
+  it was given, nor writes a whole layer through
+  (`kv_pool_materializations`): the pool rides the layer scan's carry.
+  Checked on the CPU's program and on the program compiled for a v5e
+  chip that is described, not attached.
 
 The on-device twins (real Mosaic, no interpret) live in
 ``test_pallas_on_device.py``.
@@ -27,6 +32,7 @@ import pytest
 from runbookai_tpu.engine.engine import EngineConfig, EngineCore
 from runbookai_tpu.engine.hlo_bytes import (
     decode_accounting,
+    kv_pool_materializations,
     kv_pool_nbytes,
     lower_decode,
     param_nbytes,
@@ -175,3 +181,118 @@ def test_memory_plan_fp8_kv_cross_check():
     from runbookai_tpu.engine.hlo_bytes import check_plan
 
     check_plan(core, plan)
+
+
+# ------------------------------------------------- the pool is not copied
+#
+# models/llama.py carries the whole pool through its scan over layers and
+# writes it in place. Before that it went in as the scan's xs and came
+# back as its stacked ys, which XLA can never alias: every pass copied the
+# pool aside and wrote every layer through (on the chip 0.93 s of a 5 s
+# trace, and a second pool of temporary memory a program).
+
+STEP_PROGRAMS = ["_decode_step", "_decode_multi", "_mixed_step"]
+KV_CFG = LlamaConfig(
+    name="hlo-kv-test", vocab_size=262, dim=64, n_layers=4, n_heads=4,
+    n_kv_heads=2, ffn_dim=128, max_seq_len=512, rope_theta=10_000.0,
+)
+
+
+@pytest.fixture(scope="module")
+def kv_core():
+    """Four layers and a pool (1 MB a side) larger than everything else
+    a step holds. float32: the CPU widens a bf16 scatter to f32 and back,
+    pool and all, which says nothing of the chip."""
+    params = init_params(jax.random.PRNGKey(0), KV_CFG, dtype=jnp.float32)
+    return EngineCore(KV_CFG, params, ByteTokenizer(), EngineConfig(
+        page_size=4, num_pages=512, max_batch_slots=4, prefill_chunk=8,
+        max_seq_len=128, block_pages=4, kv_dtype=jnp.float32))
+
+
+def test_detector_flags_pool_scanned_in_and_stacked_out(kv_core):
+    """The structure this guards against, in miniature: the pool as a
+    scan's xs and ys. The detector must name the second pool — and stay
+    silent on the same writes made into the carry."""
+    k = kv_core._kv_k
+    rows = jnp.ones((2,) + k.shape[2:], k.dtype)
+
+    def scanned(pool):
+        def layer(h, k_l):
+            return h + 1.0, k_l.at[jnp.asarray([3, 9])].set(rows * h)
+        return jax.lax.scan(layer, 0.0, pool)[1]
+
+    def carried(pool):
+        def layer(carry, li):
+            h, pool = carry
+            flat = pool.reshape((-1,) + pool.shape[2:])
+            dest = li * pool.shape[1] + jnp.asarray([3, 9])
+            return (h + 1.0, flat.at[dest].set(rows * h).reshape(pool.shape)
+                    ), None
+        return jax.lax.scan(layer, (0.0, pool),
+                            jnp.arange(pool.shape[0]))[0][1]
+
+    assert kv_pool_materializations(
+        jax.jit(scanned, donate_argnums=0).lower(k).compile(), kv_core)
+    assert kv_pool_materializations(
+        jax.jit(carried, donate_argnums=0).lower(k).compile(), kv_core) == []
+
+
+def _assert_one_pool(compiled, core):
+    bad = kv_pool_materializations(compiled, core)
+    assert bad == [], "\n".join(bad)
+    # ... and the temporaries (the readers' K and V layer slices and the
+    # activations) stay under ONE side of the pool, which a copy of K or
+    # of V alone would not.
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < core._kv_k.nbytes, (temp, core._kv_k.nbytes)
+
+
+@pytest.mark.parametrize("program", STEP_PROGRAMS)
+def test_step_program_owns_no_second_kv_pool(kv_core, program):
+    _assert_one_pool(lower_decode(kv_core, program=program), kv_core)
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    """A v5e chip that is described and not attached: the TPU's own
+    compiler, Mosaic included, with nothing to run on. Described inside
+    the fixture, so only the worker given this file loads libtpu."""
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — whatever stops libtpu here
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def chip_core():
+    """Head width 128 and 16-token pages, as Mosaic's tiling wants them.
+    The bf16 pool is 151 MB a side, more than the chip's 128 MiB of
+    on-chip memory: a pool that fits there is prefetched into it whole,
+    which at serving size (1.4 GB a side) cannot happen."""
+    cfg = LlamaConfig(
+        name="hlo-kv-chip-test", vocab_size=262, dim=512, n_layers=3,
+        n_heads=4, n_kv_heads=2, ffn_dim=1024, max_seq_len=512,
+        rope_theta=10_000.0)
+    params = init_params(jax.random.PRNGKey(0), cfg, dtype=jnp.bfloat16)
+    return EngineCore(cfg, params, ByteTokenizer(), EngineConfig(
+        page_size=16, num_pages=6144, max_batch_slots=8, prefill_chunk=64,
+        max_seq_len=512, block_pages=4, kv_dtype=jnp.bfloat16))
+
+
+@pytest.mark.parametrize("program", STEP_PROGRAMS)
+def test_step_program_for_the_chip_owns_no_second_kv_pool(
+        one_chip, chip_core, program, monkeypatch):
+    """The same, on what the chip would run: the Pallas attention kernels
+    under real Mosaic read the layer's slice of the carried pool, and no
+    layout change of the pool may appear in front of the slice or the
+    scatter. (``interpret=`` follows the default backend, the CPU here.)"""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    compiled = lower_decode(chip_core, program=program, attn_impl="pallas",
+                            sharding=one_chip)
+    assert "tpu_custom_call" in compiled.as_text()
+    _assert_one_pool(compiled, chip_core)
