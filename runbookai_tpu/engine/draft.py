@@ -61,9 +61,10 @@ class DraftWorker:
         self.attn_impl = attn_impl
         self.max_batch_slots = max_batch_slots
         dtype = params["embed"].dtype
+        (layers, heads, dim), v_side = cfg.kv_pool_spec  # as EngineCore's
         self.kv = KVCacheManager(
-            n_layers=cfg.n_layers, num_pages=num_pages, page_size=page_size,
-            n_kv_heads=cfg.n_kv_heads, head_dim=cfg.head_dim,
+            n_layers=layers, num_pages=num_pages, page_size=page_size,
+            n_kv_heads=heads, head_dim=dim, v_side=v_side,
             max_seq_len=max_seq_len, dtype=dtype)
         self._kv_k = self.kv.pool.kv_k
         self._kv_v = self.kv.pool.kv_v
@@ -149,7 +150,7 @@ class DraftWorker:
                 tables[i] = self._table_row(rid)
                 ctx_lens[i] = start + len(chunk)
                 self.metrics["draft_sync_tokens"] += len(chunk)
-            _, self._kv_k, self._kv_v = _prefill_step(
+            _, self._kv_k, self._kv_v, _ = _prefill_step(
                 self.params, self.cfg, jnp.asarray(tokens), self._kv_k,
                 self._kv_v, jnp.asarray(positions), jnp.asarray(tables),
                 jnp.asarray(ctx_lens),
@@ -201,7 +202,7 @@ class DraftWorker:
             ctx_lens[i] = len(hist)
             tables[i] = self._table_row(rid)
         greedy = np.zeros((b,), dtype=np.float32)
-        toks, self._kv_k, self._kv_v, _ = _decode_multi(
+        toks, self._kv_k, self._kv_v, _, _ = _decode_multi(
             self.params, self.cfg, jnp.asarray(tokens), jnp.asarray(positions),
             self._kv_k, self._kv_v, jnp.asarray(tables),
             jnp.asarray(ctx_lens), jnp.asarray(greedy),

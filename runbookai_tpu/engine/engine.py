@@ -53,11 +53,6 @@ from runbookai_tpu.engine.request import (
     FinishReason,
     RequestState,
 )
-from runbookai_tpu.models.llama import (
-    LlamaConfig,
-    forward_impl,
-    forward_ragged_impl,
-)
 from runbookai_tpu.ops.sampling import sample_tokens
 from runbookai_tpu.sched import class_label, class_name
 from runbookai_tpu.utils import metrics as metrics_mod
@@ -246,16 +241,24 @@ def resolve_kv_dtype(name: Optional[str], default: Any) -> Any:
     return resolved
 
 
+# The step programs take their forward from the configuration
+# (``cfg.forwards()``: models/llama.py, models/longcat.py), never from a model
+# module by name, and each returns, last, the forward's expert counts: four
+# integers for a model with an expert share (models/longcat.py
+# ``EXPERT_COUNTS``), None — no output at all — for any other.
+
+
 @partial(jax.jit, static_argnames=("cfg", "page_size", "block_pages", "attn_impl",
                                    "mesh", "qmm_impl"),
          donate_argnums=(4, 5, 14))
 def _decode_step(
-    params, cfg: LlamaConfig, tokens, positions, kv_k, kv_v, tables, ctx_lens,
+    params, cfg, tokens, positions, kv_k, kv_v, tables, ctx_lens,
     temps, top_ps, top_ks, key, mask, adapter_ids, counts=None, pres=None,
     freq=None, seeds=None, bias=None, *, page_size: int,
     block_pages: int, attn_impl: str = "xla", mesh=None, qmm_impl: str = "xla",
 ):
-    logits, kv_k, kv_v = forward_impl(
+    forward, _ = cfg.forwards()
+    logits, kv_k, kv_v, experts = forward(
         params, cfg, tokens, positions, kv_k, kv_v, tables, ctx_lens,
         page_size=page_size, block_pages=block_pages, attn_impl=attn_impl,
         mesh=mesh, adapter_ids=adapter_ids, qmm_impl=qmm_impl,
@@ -265,7 +268,7 @@ def _decode_step(
                         seeds=seeds, positions=ctx_lens, bias=bias)
     if counts is not None:
         counts = counts.at[jnp.arange(tok.shape[0]), tok].add(1)
-    return tok, logits[:, -1], kv_k, kv_v, counts
+    return tok, logits[:, -1], kv_k, kv_v, counts, experts
 
 
 @partial(jax.jit,
@@ -273,7 +276,7 @@ def _decode_step(
                           "mesh", "qmm_impl"),
          donate_argnums=(4, 5, 13))
 def _decode_multi(
-    params, cfg: LlamaConfig, tokens, positions, kv_k, kv_v, tables, ctx_lens,
+    params, cfg, tokens, positions, kv_k, kv_v, tables, ctx_lens,
     temps, top_ps, top_ks, key, adapter_ids, counts=None, pres=None,
     freq=None, seeds=None, bias=None, *, page_size: int, block_pages: int,
     k_steps: int, attn_impl: str = "xla", mesh=None, qmm_impl: str = "xla",
@@ -290,9 +293,11 @@ def _decode_multi(
     multi-token amortization.
     """
 
+    forward, _ = cfg.forwards()
+
     def step(carry, _):
         tokens, positions, kv_k, kv_v, ctx_lens, key, counts = carry
-        logits, kv_k, kv_v = forward_impl(
+        logits, kv_k, kv_v, experts = forward(
             params, cfg, tokens, positions, kv_k, kv_v, tables, ctx_lens,
             page_size=page_size, block_pages=block_pages, attn_impl=attn_impl,
             mesh=mesh, adapter_ids=adapter_ids, qmm_impl=qmm_impl,
@@ -305,20 +310,22 @@ def _decode_multi(
             counts = counts.at[jnp.arange(tok.shape[0]), tok].add(1)
         carry = (tok[:, None], positions + 1, kv_k, kv_v, ctx_lens + 1, key,
                  counts)
-        return carry, tok
+        return carry, (tok, experts)
 
-    (_, _, kv_k, kv_v, _, _, counts), toks = jax.lax.scan(
+    (_, _, kv_k, kv_v, _, _, counts), (toks, experts) = jax.lax.scan(
         step, (tokens, positions, kv_k, kv_v, ctx_lens, key, counts), None,
         length=k_steps,
     )
-    return toks.T, kv_k, kv_v, counts  # [B, K]
+    if experts is not None:
+        experts = jnp.sum(experts, axis=0)  # over the K passes
+    return toks.T, kv_k, kv_v, counts, experts  # [B, K]
 
 
 @partial(jax.jit, static_argnames=("cfg", "page_size", "block_pages", "attn_impl",
                                    "mesh", "qmm_impl"),
          donate_argnums=(4, 5))
 def _decode_spec(
-    params, cfg: LlamaConfig, tokens, positions, kv_k, kv_v, tables, ctx_lens,
+    params, cfg, tokens, positions, kv_k, kv_v, tables, ctx_lens,
     adapter_ids, page_size: int, block_pages: int, attn_impl: str = "xla",
     mesh=None, qmm_impl: str = "xla",
 ):
@@ -335,31 +342,34 @@ def _decode_spec(
     kernel (``paged_chunk_attention``) — positions are contiguous from
     ``ctx-1``, satisfying the kernel's contiguity contract.
     """
-    logits, kv_k, kv_v = forward_impl(
+    forward, _ = cfg.forwards()
+    logits, kv_k, kv_v, experts = forward(
         params, cfg, tokens, positions, kv_k, kv_v, tables, ctx_lens,
         page_size=page_size, block_pages=block_pages, attn_impl=attn_impl,
         mesh=mesh, adapter_ids=adapter_ids, qmm_impl=qmm_impl,
     )
-    return jnp.argmax(logits, axis=-1).astype(jnp.int32), kv_k, kv_v  # [B, K]
+    return (jnp.argmax(logits, axis=-1).astype(jnp.int32), kv_k, kv_v,
+            experts)  # tokens [B, K]
 
 
 @partial(jax.jit, static_argnames=("cfg", "page_size", "block_pages", "attn_impl",
                                    "mesh", "qmm_impl"),
          donate_argnums=(3, 4))
 def _prefill_step(
-    params, cfg: LlamaConfig, tokens, kv_k, kv_v, positions, tables, ctx_lens,
+    params, cfg, tokens, kv_k, kv_v, positions, tables, ctx_lens,
     last_idx, adapter_ids, page_size: int, block_pages: int,
     attn_impl: str = "xla", mesh=None, qmm_impl: str = "xla",
 ):
     """Prefill one chunk for a BATCH of sequences; returns each row's final
     real-token logits ([B, vocab])."""
-    logits, kv_k, kv_v = forward_impl(
+    forward, _ = cfg.forwards()
+    logits, kv_k, kv_v, experts = forward(
         params, cfg, tokens, positions, kv_k, kv_v, tables, ctx_lens,
         page_size=page_size, block_pages=block_pages, attn_impl=attn_impl,
         mesh=mesh, adapter_ids=adapter_ids, qmm_impl=qmm_impl,
     )
     rows = jnp.arange(logits.shape[0])
-    return logits[rows, last_idx], kv_k, kv_v
+    return logits[rows, last_idx], kv_k, kv_v, experts
 
 
 # Row-run alignment of the mixed ragged token buffer: every row's token run
@@ -374,7 +384,7 @@ _RAGGED_BLOCK = 8
                                    "ragged_block"),
          donate_argnums=(7, 8, 23))
 def _mixed_step(
-    params, cfg: LlamaConfig, tokens, feed_toks, dec_idx, positions, row_ids,
+    params, cfg, tokens, feed_toks, dec_idx, positions, row_ids,
     kv_k, kv_v, tables, ctx_lens, adapter_rows, pf_last_idx, temps, top_ps,
     top_ks, key, pf_temps, pf_top_ps, pf_top_ks, pf_slot_map, pf_live,
     dec_live=None, counts=None, pres=None, freq=None, seeds=None, bias=None,
@@ -408,7 +418,8 @@ def _mixed_step(
     b = feed_toks.shape[0]
     tokens = tokens.at[dec_idx].set(feed_toks)
     sel_idx = jnp.concatenate([dec_idx, pf_last_idx])
-    logits, kv_k, kv_v = forward_ragged_impl(
+    _, forward_ragged = cfg.forwards()
+    logits, kv_k, kv_v, experts = forward_ragged(
         params, cfg, tokens, positions, row_ids, kv_k, kv_v, tables,
         ctx_lens, sel_idx, page_size=page_size, block_pages=block_pages,
         attn_impl=attn_impl, mesh=mesh, adapter_ids=adapter_rows,
@@ -431,7 +442,7 @@ def _mixed_step(
     if counts is not None:
         counts = counts.at[pf_slot_map, pf_tok].add(pf_live, mode="drop")
     feed_new = dec_tok.at[pf_slot_map].set(pf_tok, mode="drop")
-    return dec_tok[:, None], pf_tok, feed_new, kv_k, kv_v, counts
+    return dec_tok[:, None], pf_tok, feed_new, kv_k, kv_v, counts, experts
 
 
 @functools.lru_cache(maxsize=8)
@@ -713,7 +724,31 @@ LEGACY_COUNTER_EXPORTS: tuple[tuple[str, str, str], ...] = (
      "Real tokens processed by mixed dispatches"),
     ("mixed_time_s", "runbook_mixed_time_seconds_total",
      "Wall-clock spent building and issuing mixed dispatches"),
+    ("experts_touched", "runbook_experts_touched_total",
+     "Held experts that got at least one live token in a forward pass, "
+     "summed over layers and passes (models with an expert share)"),
+    ("expert_overflows", "runbook_expert_overflows_total",
+     "Expert layers of a forward pass whose dispatch overflowed a held "
+     "expert's slots and ran every token through every held expert "
+     "instead (exact, slower)"),
 )
+
+def export_expert_pairs(reg, value_of: Callable[[str], float]) -> None:
+    """``runbook_expert_pairs_total{kind=held|zero|absent}`` as scrape-time
+    callbacks over ``value_of(metrics-dict key)`` — one engine's dict, or a
+    fleet's sum."""
+    pairs = reg.counter(
+        "runbook_expert_pairs_total",
+        "Token-expert pairs of live tokens by where the chosen expert is: "
+        "held by this process, an identity (zero-computation) expert, or "
+        "absent (another process's share); summed over layers",
+        labels=("kind",))
+    pairs.labels(kind="held").set_function(
+        lambda: value_of("expert_pairs_held"))
+    pairs.labels(kind="zero").set_function(
+        lambda: value_of("expert_pairs_zero"))
+    pairs.labels(kind="absent").set_function(
+        lambda: value_of("expert_pairs_absent"))
 
 
 _TOPK_LOGPROBS = 20  # OpenAI's top_logprobs ceiling; one compiled shape
@@ -746,6 +781,10 @@ class _PendingDecode:
     reqs: list[tuple[EngineRequest, int]]
     req_ids: frozenset[str]
     k: int
+    # The dispatch's expert counts (None: the family counts none), fetched
+    # with the tokens, and what the step record attributes them to.
+    experts_dev: Optional[jax.Array] = None
+    program: str = ""
 
 
 @dataclass
@@ -778,7 +817,7 @@ class EngineCore:
 
     def __init__(
         self,
-        model_cfg: LlamaConfig,
+        model_cfg,
         params: Any,
         tokenizer: Any,
         engine_cfg: Optional[EngineConfig] = None,
@@ -842,6 +881,22 @@ class EngineCore:
         # wrappers have no scale plumbing, and the page-split layout
         # refuses.
         _kv_int8 = jnp.dtype(self.ecfg.kv_dtype) == jnp.int8
+        # What the configuration's family does not do yet is refused here,
+        # by name, before anything is built (the dataclass says what).
+        refused = model_cfg.unsupported(
+            lora=lora_registry is not None, model_axis=_model_tp,
+            seq_axis=mesh.shape.get(_SEQ, 1) if mesh is not None else 1,
+            kv_dtype=self.ecfg.kv_dtype,
+            quantized=any(is_quantized(v)
+                          for v in self.params["layers"].values()))
+        if refused:
+            raise ValueError(
+                f"model {model_cfg.name!r} (family {model_cfg.family!r}) "
+                f"does not support: {'; '.join(refused)}")
+        if not model_cfg.pallas_attention and self.ecfg.attn_impl == "pallas":
+            # The Pallas kernels read per-head K/V pages; this family's
+            # forward has its own attention over its own pool.
+            self.ecfg = _dc.replace(self.ecfg, attn_impl="xla")
         if _kv_int8 and _kv_split_mesh:
             raise ValueError(
                 "kv_dtype=int8 is not supported on a KV page-split "
@@ -907,12 +962,14 @@ class EngineCore:
 
             kv_sharding = kv_pool_sharding(model_cfg, mesh)
 
+        (pool_layers, pool_heads, pool_dim), v_side = model_cfg.kv_pool_spec
         self.kv = KVCacheManager(
-            n_layers=model_cfg.n_layers,
+            n_layers=pool_layers,
             num_pages=self.ecfg.num_pages,
             page_size=self.ecfg.page_size,
-            n_kv_heads=model_cfg.n_kv_heads,
-            head_dim=model_cfg.head_dim,
+            n_kv_heads=pool_heads,
+            head_dim=pool_dim,
+            v_side=v_side,
             max_seq_len=self.ecfg.max_seq_len,
             dtype=self.ecfg.kv_dtype,
             sharding=kv_sharding,
@@ -1004,7 +1061,17 @@ class EngineCore:
                         "mixed_tokens": 0, "mixed_time_s": 0.0,
                         "kv_pages_imported": 0, "kv_pages_exported": 0,
                         "kv_spill_readmits": 0,
-                        "compile_time_s": 0.0, "compiles": 0}
+                        "compile_time_s": 0.0, "compiles": 0,
+                        # Token-expert pairs by where they fell, and held
+                        # experts touched, summed over layers and passes
+                        # (a family with no expert share leaves them 0).
+                        "expert_pairs_held": 0, "expert_pairs_zero": 0,
+                        "expert_pairs_absent": 0, "experts_touched": 0,
+                        "expert_overflows": 0}
+        # Expert counts of dispatches that fetched no token of their own
+        # (a prefill chunk that completed no prompt): (program, passes,
+        # device array), riding the next token fetch.
+        self._experts_parked: list[tuple[str, int, jax.Array]] = []
         # Flight-recorder mark for page transfers: imports/exports happen
         # BETWEEN steps (under the engine lock, not inside step()), so the
         # per-step record reports the delta since the last recorded step
@@ -1147,6 +1214,7 @@ class EngineCore:
         for key, name, help_text in LEGACY_COUNTER_EXPORTS:
             reg.counter(name, help_text).set_function(
                 lambda k=key: float(self.metrics.get(k, 0)))
+        export_expert_pairs(reg, lambda k: float(self.metrics.get(k, 0)))
         reg.gauge("runbook_decode_overlap_ratio",
                   "Fraction of host decode work hidden behind device "
                   "execution by the lagged pipeline (0 in forced-sync mode)"
@@ -1374,17 +1442,41 @@ class EngineCore:
         self._slot_cache = si
         return si
 
-    def _fetch_tokens(self, toks_dev: jax.Array) -> np.ndarray:
+    def _fetch_tokens(self, toks_dev: jax.Array, experts_dev=None,
+                      program: str = "", passes: int = 1) -> np.ndarray:
         """THE decode-loop token egress. Every decode path (lagged drain,
         forced-sync, guided k=1, speculative verify) consumes its sampled
         tokens through this single point; the host copy was started
         asynchronously at dispatch time, so in the lagged pipeline this
-        wait is bounded by whatever device time the host failed to hide."""
+        wait is bounded by whatever device time the host failed to hide.
+        The dispatch's expert counts (``experts_dev``, five integers) come
+        over in the same ``device_get``, with those of any earlier
+        dispatch that fetched no token (``_experts_parked``)."""
         with self._span("fetch"):
+            parked, self._experts_parked = self._experts_parked, []
+            if experts_dev is not None:
+                parked.append((program, passes, experts_dev))
             # runbook: noqa[RBK002] — sanctioned sync: the async-egress
             # consumption point — the one token fetch in the decode loop
             # (prefill TTFT and the logprob triple keep their own fetches).
-            return np.asarray(jax.device_get(toks_dev))
+            toks, counts = jax.device_get((toks_dev, [e for _, _, e in parked]))
+        for (prog, n_pass, _), c in zip(parked, counts):
+            self._note_experts(prog, n_pass, c)
+        return np.asarray(toks)
+
+    def _note_experts(self, program: str, passes: int, counts) -> None:
+        """Book one dispatch's expert counts (models/longcat.py
+        ``EXPERT_COUNTS``): the engine's totals, and the record of the step
+        that fetched them."""
+        held, zero, absent, touched, overflow = (int(c) for c in counts)
+        for key, n in (("expert_pairs_held", held), ("expert_pairs_zero", zero),
+                       ("expert_pairs_absent", absent),
+                       ("experts_touched", touched),
+                       ("expert_overflows", overflow)):
+            self.metrics[key] += n
+        if self._open is not None:
+            self._open.fetched_experts(program, passes, held, zero, absent,
+                                       touched, overflow)
 
     def _drain(self, pending: _PendingDecode, overlapped: bool) -> np.ndarray:
         """Consume one decode window: fetch its tokens and emit them.
@@ -1397,7 +1489,9 @@ class EngineCore:
         ``overlapped`` marks emission work running while the next dispatch
         executes on device — the time the pipeline hides."""
         t0 = time.perf_counter()
-        toks_host = self._fetch_tokens(pending.toks_dev)
+        toks_host = self._fetch_tokens(pending.toks_dev, pending.experts_dev,
+                                       pending.program, pending.k)
+        pending.experts_dev = None  # booked once, whoever fetches again
         t_fetch = time.perf_counter()
         emitted = 0
         with self._span("emit"):
@@ -1804,7 +1898,7 @@ class EngineCore:
             pf_meta["requests"] = [r.request_id for r, _, _ in rows]
         with self.tracer.span("engine.prefill", **pf_meta), \
                 annotate("prefill"), self._span("issue"):
-            last_logits, self._kv_k, self._kv_v = _prefill_step(
+            last_logits, self._kv_k, self._kv_v, experts = _prefill_step(
                 self.params, self.cfg, jnp.asarray(tokens), self._kv_k, self._kv_v,
                 jnp.asarray(positions), jnp.asarray(tables),
                 jnp.asarray(ctx_lens), jnp.asarray(last_idx),
@@ -1815,6 +1909,8 @@ class EngineCore:
             )
         if self._open is not None:
             self._open.dispatched("_prefill_step")
+        if experts is not None:
+            self._experts_parked.append(("_prefill_step", 1, experts))
 
         done_rows: list[tuple[int, EngineRequest]] = []
         self.metrics["prefill_steps"] += 1
@@ -2124,7 +2220,7 @@ class EngineCore:
         with self.tracer.span("engine.decode_spec", **spec_meta), \
                 annotate("decode_spec"), self._span("issue"):
             t_issue = time.perf_counter()
-            toks, self._kv_k, self._kv_v = _decode_spec(
+            toks, self._kv_k, self._kv_v, experts = _decode_spec(
                 self.params, self.cfg, jnp.asarray(tokens), jnp.asarray(positions),
                 self._kv_k, self._kv_v, si.tables, jnp.asarray(ctx_lens),
                 si.adapters,
@@ -2132,7 +2228,7 @@ class EngineCore:
                 attn_impl=self.ecfg.attn_impl, mesh=self.mesh,
                 qmm_impl=self.ecfg.qmm_impl,
             )
-            toks_host = self._fetch_tokens(toks)  # [B, k]
+            toks_host = self._fetch_tokens(toks, experts, "_decode_spec")  # [B, k]
             t_fetch = time.perf_counter()
 
         emitted = 0
@@ -2443,7 +2539,7 @@ class EngineCore:
                 annotate("mixed"), self._span("issue"):
             t_issue = time.perf_counter()
             (toks_win, pf_toks, feed_new, self._kv_k, self._kv_v,
-             counts_out) = _mixed_step(
+             counts_out, experts) = _mixed_step(
                 self.params, self.cfg, jnp.asarray(tokens), self._feed_toks,
                 jnp.asarray(dec_idx), jnp.asarray(positions),
                 jnp.asarray(row_ids), self._kv_k, self._kv_v,
@@ -2476,7 +2572,7 @@ class EngineCore:
             toks_dev=toks_win,
             reqs=[(r, r.slot) for r in dec_snapshot],
             req_ids=frozenset(r.request_id for r in dec_snapshot),
-            k=1,
+            k=1, experts_dev=experts, program="_mixed_step",
         )
         if hasattr(toks_win, "copy_to_host_async"):
             toks_win.copy_to_host_async()
@@ -2678,7 +2774,7 @@ class EngineCore:
             last_logits = None
             if k == 1:
                 (toks, last_logits, self._kv_k, self._kv_v,
-                 counts_out) = _decode_step(
+                 counts_out, experts) = _decode_step(
                     self.params, self.cfg, tokens_dev, jnp.asarray(positions),
                     self._kv_k, self._kv_v, si.tables, jnp.asarray(ctx_lens),
                     si.temps, si.top_ps, si.top_ks, sub,
@@ -2691,7 +2787,8 @@ class EngineCore:
                 self._feed_toks = toks
                 toks_win = toks[:, None]  # [B, 1]
             else:
-                toks_win, self._kv_k, self._kv_v, counts_out = _decode_multi(
+                (toks_win, self._kv_k, self._kv_v, counts_out,
+                 experts) = _decode_multi(
                     self.params, self.cfg, tokens_dev, jnp.asarray(positions),
                     self._kv_k, self._kv_v, si.tables, jnp.asarray(ctx_lens),
                     si.temps, si.top_ps, si.top_ks, sub,
@@ -2709,7 +2806,8 @@ class EngineCore:
             toks_dev=toks_win,
             reqs=[(r, r.slot) for r in self.decoding],
             req_ids=frozenset(r.request_id for r in self.decoding),
-            k=k,
+            k=k, experts_dev=experts,
+            program="_decode_step" if k == 1 else "_decode_multi",
         )
         # Start the token egress behind the (async) dispatch: by the time
         # the window is drained, the DMA has had a full device step to land.
@@ -2862,6 +2960,8 @@ class EngineCore:
             "admitted": self._admitted_log,
             "finished": self._finished_log,
         }
+        if step.experts is not None:
+            rec["experts"] = step.experts
         self._admitted_log, self._finished_log = [], []
         # Page transfers land BETWEEN steps (cross-replica pulls, disagg
         # handoffs, spill readmits run under the engine lock outside

@@ -74,6 +74,7 @@ from runbookai_tpu.engine.engine import (
     LEGACY_COUNTER_EXPORTS,
     EngineConfig,
     EngineCore,
+    export_expert_pairs,
 )
 from runbookai_tpu.engine.kv_cache import hash_blocks
 from runbookai_tpu.engine.request import (
@@ -269,6 +270,8 @@ def install_fleet_aggregates(cores: Sequence[EngineCore]) -> None:
     for key, name, help_text in LEGACY_COUNTER_EXPORTS:
         reg.counter(name, help_text).set_function(
             lambda k=key: float(sum(c.metrics.get(k, 0) for c in cores)))
+    export_expert_pairs(
+        reg, lambda k: float(sum(c.metrics.get(k, 0) for c in cores)))
 
 
 def build_engine_fleet(

@@ -54,6 +54,14 @@ STEP_RECORD_FIELDS = (
     "t_start", "t_end", "phases", "program", "k", "rows",
     "prefill_tokens", "decode_tokens", "compile_s", "admitted", "finished",
 )
+# Keys a record has only in some steps. ``experts``: where the model has an
+# expert share (models/longcat.py), the expert counts this step FETCHED (a
+# decode window's arrive with its tokens, one step after its dispatch):
+# token-expert pairs on ``held``, identity (``zero``) and ``absent``
+# experts, held experts ``touched`` and expert layers whose dispatch took
+# the slow path (``overflow``), summed over layers, from ``passes`` forward
+# passes of ``programs``.
+OPTIONAL_STEP_FIELDS = ("experts",)
 
 # ``phases`` keys besides "other" (= wall_s less their sum), and the
 # profiler span that marks the same boundaries on the device trace's
@@ -83,12 +91,13 @@ class OpenStep:
     outer one, so no interval is counted twice however the engine's
     drains nest. Written by the step thread only."""
 
-    __slots__ = ("t_start", "phases", "programs", "k", "rows",
+    __slots__ = ("t_start", "phases", "programs", "k", "rows", "experts",
                  "_stack", "_t")
 
     def __init__(self):
         self.phases = dict.fromkeys(STEP_PHASES, 0.0)
         self.programs: list[str] = []
+        self.experts: Optional[dict[str, Any]] = None
         self.k = 0  # decode steps in the dispatch
         self.rows = 0  # decode rows dispatched
         self._stack: list[str] = []
@@ -111,6 +120,24 @@ class OpenStep:
         self.programs.append(program)
         if k:
             self.k, self.rows = k, rows
+
+    def fetched_experts(self, program: str, passes: int, held: int,
+                        zero: int, absent: int, touched: int,
+                        overflow: int) -> None:
+        """Add one dispatch's expert counts, as they reach the host."""
+        e = self.experts
+        if e is None:
+            e = self.experts = {"held": 0, "zero": 0, "absent": 0,
+                                "touched": 0, "overflow": 0, "passes": 0,
+                                "programs": []}
+        e["held"] += held
+        e["zero"] += zero
+        e["absent"] += absent
+        e["touched"] += touched
+        e["overflow"] += overflow
+        e["passes"] += passes
+        if program not in e["programs"]:
+            e["programs"].append(program)
 
 
 class FlightRecorder:

@@ -51,8 +51,16 @@ class PagePool:
         head_dim: int,
         dtype=jnp.bfloat16,
         sharding=None,
+        v_side: Optional[tuple[int, int, int]] = None,
     ) -> "PagePool":
-        shape = (n_layers, num_pages * page_size, n_kv_heads, head_dim)
+        """``v_side``: (layers, heads, values a head) of ``kv_v`` where it
+        is not ``kv_k``'s (a latent pool: ``kv_k`` holds the latent of every
+        attention sublayer, ``kv_v`` the rotated keys). Both sides keep
+        ``num_pages * page_size`` token rows on axis 1."""
+        tokens = num_pages * page_size
+        k_shape = (n_layers, tokens, n_kv_heads, head_dim)
+        v_shape = (k_shape if v_side is None
+                   else (v_side[0], tokens, v_side[1], v_side[2]))
         # int8 pools carry one f32 absmax scale per (token, kv head) —
         # tuple leaves thread through jit/scan/donation as a pytree, so
         # no engine signature changes (ops/attention.py quantize_kv).
@@ -70,22 +78,23 @@ class PagePool:
             # pool on one device first — an OOM at exactly the scale TP
             # exists for. One jitted closure per shape, reused for K and
             # V, so each zeros program compiles once.
-            zeros = jax.jit(lambda: jnp.zeros(shape, dtype=dtype),
-                            out_shardings=sharding)
-            zeros_s = (jax.jit(lambda: jnp.zeros(shape[:3], jnp.float32),
-                               out_shardings=scale_sharding)
+            zeros = jax.jit(lambda shape: jnp.zeros(shape, dtype=dtype),
+                            static_argnums=0, out_shardings=sharding)
+            zeros_s = (jax.jit(lambda shape: jnp.zeros(shape, jnp.float32),
+                               static_argnums=0, out_shardings=scale_sharding)
                        if quantized else None)
 
-            def alloc():
-                return (zeros(), zeros_s()) if quantized else zeros()
+            def alloc(shape):
+                return ((zeros(shape), zeros_s(shape[:3])) if quantized
+                        else zeros(shape))
         else:
-            def alloc():
+            def alloc(shape):
                 vals = jnp.zeros(shape, dtype=dtype)
                 if quantized:
                     return vals, jnp.zeros(shape[:3], jnp.float32)
                 return vals
 
-        kv_k, kv_v = alloc(), alloc()
+        kv_k, kv_v = alloc(k_shape), alloc(v_shape)
         return PagePool(
             kv_k=kv_k,
             kv_v=kv_v,
@@ -390,9 +399,11 @@ class KVCacheManager:
         allocator: Optional[PageAllocator] = None,
         sharding=None,
         spill_pages: int = 0,
+        v_side: Optional[tuple[int, int, int]] = None,
     ):
         self.pool = PagePool.create(n_layers, num_pages, page_size, n_kv_heads,
-                                    head_dim, dtype, sharding=sharding)
+                                    head_dim, dtype, sharding=sharding,
+                                    v_side=v_side)
         if allocator is None:
             from runbookai_tpu.native import make_page_allocator
 

@@ -109,32 +109,37 @@ def plan_serving(
     tier holds full-width pages regardless of the device sharding) and
     reported as ``host_spill_bytes`` — host budget, never HBM.
     """
-    from runbookai_tpu.parallel.kv_split import plan_kv_split
+    from runbookai_tpu.parallel.kv_split import KVSplitPlan, plan_kv_split
 
-    plan = plan_kv_split(cfg, tp)
-
-    layer_matmul = cfg.matmul_params - cfg.dim * cfg.vocab_size
-    wkv = cfg.n_layers * 2 * cfg.dim * cfg.n_kv_heads * cfg.head_dim
-    emb_head = 2 * cfg.vocab_size * cfg.dim  # embed + lm head (or tied x2)
-    if weights == "int8":
-        # wk/wv shard kv_shards-way only; everything else full-tp.
-        per_chip = ((layer_matmul - wkv) / max(tp, 1)
-                    + wkv / max(plan.kv_shards, 1)
-                    + layer_matmul / cfg.dim * 4 / max(tp, 1)  # scales
-                    + emb_head * 2 / max(tp, 1))  # bf16
+    # The pool's layout is the configuration's own (two K/V sides of n_kv
+    # heads a layer, or a latent and a rotated key a sublayer).
+    sides = cfg.kv_pool_spec  # (layers, heads, values a head), K and V
+    if hasattr(cfg, "n_kv_heads"):
+        plan = plan_kv_split(cfg, tp)
+        layer_matmul = cfg.matmul_params - cfg.dim * cfg.vocab_size
+        wkv = cfg.n_layers * 2 * cfg.dim * cfg.n_kv_heads * cfg.head_dim
+        emb_head = 2 * cfg.vocab_size * cfg.dim  # embed + lm head (or tied x2)
+        if weights == "int8":
+            # wk/wv shard kv_shards-way only; everything else full-tp.
+            per_chip = ((layer_matmul - wkv) / max(tp, 1)
+                        + wkv / max(plan.kv_shards, 1)
+                        + layer_matmul / cfg.dim * 4 / max(tp, 1)  # scales
+                        + emb_head * 2 / max(tp, 1))  # bf16
+        else:
+            per_chip = ((layer_matmul - wkv) * 2 / max(tp, 1)
+                        + wkv * 2 / max(plan.kv_shards, 1)
+                        + emb_head * 2 / max(tp, 1))
+        per_chip += (cfg.n_layers * 2 + 1) * cfg.dim * 4  # norms, replicated
     else:
-        per_chip = ((layer_matmul - wkv) * 2 / max(tp, 1)
-                    + wkv * 2 / max(plan.kv_shards, 1)
-                    + emb_head * 2 / max(tp, 1))
-    per_chip += (cfg.n_layers * 2 + 1) * cfg.dim * 4  # norms, replicated
+        # A family with no layout across chips yet (the engine refuses a
+        # model axis for it): every chip holds the whole share, as stored.
+        plan = KVSplitPlan(tp=tp, kv_shards=1, pg_shards=1)
+        per_chip = cfg.total_params * 2
 
-    kv_per_token = (cfg.n_layers * 2
-                    * (cfg.n_kv_heads / max(plan.kv_shards, 1))
-                    * (cfg.head_dim * kv_dtype_bytes + kv_scale_bytes)
-                    / max(plan.pg_shards, 1))
+    spill_token = sum(layers * heads * (dim * kv_dtype_bytes + kv_scale_bytes)
+                      for layers, heads, dim in sides)
+    kv_per_token = spill_token / max(plan.kv_shards, 1) / max(plan.pg_shards, 1)
     budget = max(0, hbm_bytes - int(per_chip) - headroom_bytes)
-    spill_token = (cfg.n_layers * 2 * cfg.n_kv_heads
-                   * (cfg.head_dim * kv_dtype_bytes + kv_scale_bytes))
     return ServingPlan(
         model=cfg.name, tp=tp, kv_shards=plan.kv_shards,
         pg_shards=plan.pg_shards, hbm_bytes=hbm_bytes,

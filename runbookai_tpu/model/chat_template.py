@@ -45,12 +45,12 @@ def render_message(role: str, content: str) -> str:
 
 
 _FAMILY_FORMATS = {"llama": "llama3", "qwen2": "chatml", "mistral": "mistral",
-                   "mixtral": "mistral"}
+                   "mixtral": "mistral", "longcat": "longcat"}
 
 
 def format_for_model(model_name: str, family: str | None = None) -> str:
     """Prompt format by model family: ``llama3`` (default), ``chatml``
-    (Qwen2), ``mistral`` ([INST] wrapping).
+    (Qwen2), ``mistral`` ([INST] wrapping), ``longcat`` (plain-text rounds).
 
     ``family`` — the loaded config's authoritative family (from HF
     ``model_type``) — wins; the name sniff is the fallback for bare names
@@ -103,8 +103,22 @@ def _render_mistral(system: str, history, user_prompt: str) -> str:
     return "".join(out)
 
 
+def _render_longcat(system: str, history, user_prompt: str) -> str:
+    # LongCat-Flash-Chat's shape: plain text, no special strings — a system
+    # line, then numbered rounds; the last round ends at the opener.
+    turns = list(history or []) + [("user", user_prompt)]
+    out, n = [f"SYSTEM:{system}"], 0
+    for role, content in turns:
+        if role == "user":
+            out.append(f" [Round {n}] USER:{content} ASSISTANT:")
+            n += 1
+        else:
+            out.append(f"{content}</longcat_s>")
+    return "".join(out)
+
+
 _RENDERERS = {"llama3": _render_llama3, "chatml": _render_chatml,
-              "mistral": _render_mistral}
+              "mistral": _render_mistral, "longcat": _render_longcat}
 
 
 def build_chat_prompt(

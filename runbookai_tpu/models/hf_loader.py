@@ -58,6 +58,10 @@ SUPPORTED_MODEL_TYPES = ("llama", "qwen2", "mistral", "mixtral")
 def config_from_hf(model_dir: str | Path, name: str = "hf-model") -> LlamaConfig:
     raw = json.loads((Path(model_dir) / "config.json").read_text())
     model_type = raw.get("model_type", "llama")
+    if model_type.startswith("longcat"):
+        raise NotImplementedError(
+            f"model_type {model_type!r}: the longcat family runs on seeded "
+            f"random weights only (models/longcat.py); no checkpoint loader yet")
     if model_type not in SUPPORTED_MODEL_TYPES:
         raise ValueError(
             f"model_type {model_type!r} not supported; known: "
@@ -236,6 +240,27 @@ def load_params(
     return cfg, params
 
 
+def quiet_control_tokens(params: Any, vocab_size: int) -> Any:
+    """Seeded weights with the head's columns of the byte tokenizer's control
+    ids (``ByteTokenizer.special_ids``: begin/end of text, headers, eot,
+    pad) set to zero, so that greedy decoding over a RANDOM head never ends
+    an answer: a checkpoint ends one where it learnt to, a random head where
+    the seed happens to put a stop id on top — two of a vocabulary's rows,
+    so one answer in twenty of a few hundred tokens at 16,384 rows, and the
+    seed then sets how much work a stream of requests is.
+
+    For the random-init path only, whatever the family. Applied today where
+    a family's seeded recipe is new (longcat): the dense families' recipe is
+    held bit for bit by ``benchmark/blocks/dense/weights.py`` and the tests
+    of ``init_params``, and at their 128k-152k rows a stop id tops a random
+    head once in some 70,000 tokens; moving them is a benchmark change."""
+    from runbookai_tpu.utils.tokens import ByteTokenizer
+
+    quiet = sorted(t for t in ByteTokenizer().special_ids if t < vocab_size)
+    head = params["lm_head"].at[:, jnp.asarray(quiet, jnp.int32)].set(0)
+    return {**params, "lm_head": head}
+
+
 def load_or_init(
     model_name: str,
     model_path: Optional[str | Path],
@@ -249,6 +274,14 @@ def load_or_init(
     Random init keeps every serving path exercisable in the no-egress
     environment (BASELINE.md configs run with real weights when provided).
     """
+    from runbookai_tpu.models.longcat import LongcatConfig
+
+    longcat = isinstance(CONFIGS.get(model_name), LongcatConfig)
+    if longcat and model_path and Path(model_path).exists():
+        raise NotImplementedError(
+            f"model {model_name!r}: no loader for checkpoints of the longcat "
+            f"family yet (MLA and expert tensor names); leave llm.model_path "
+            f"unset to serve seeded random weights")
     if model_path and Path(model_path).exists():
         from runbookai_tpu.models.checkpoint import is_checkpoint, load_checkpoint
 
@@ -275,6 +308,15 @@ def load_or_init(
             f"unknown model {model_name!r} and no checkpoint at "
             f"{str(model_path)!r}; known configs: {sorted(CONFIGS)}")
     cfg = CONFIGS[model_name]
+    if longcat:
+        from runbookai_tpu.models import longcat as longcat_model
+
+        if quantize_int8 or shardings:
+            raise ValueError(
+                f"model {model_name!r} (family longcat) serves bf16 or "
+                f"float32 weights on one chip: no int8 matrices, no mesh")
+        return cfg, quiet_control_tokens(longcat_model.init_params(
+            jax.random.PRNGKey(seed), cfg, dtype=dtype), cfg.vocab_size)
     # int8 leaves are sampled directly: a 7B bf16 tree (15 GB) plus the
     # float32 temporaries of quantizing it cannot exist on a 16 GB chip.
     init = init_params_quantized if quantize_int8 else init_params

@@ -34,6 +34,8 @@ from typing import Any, Optional
 import jax
 import jax.numpy as jnp
 
+from runbookai_tpu.models.longcat import CONFIGS as _LONGCAT_CONFIGS
+from runbookai_tpu.models.longcat import LongcatConfig
 from runbookai_tpu.ops.attention import paged_attention, write_kv_pages_batch
 from runbookai_tpu.ops.rope import apply_rope
 
@@ -77,9 +79,33 @@ class LlamaConfig:
     # e.g. 1.25–2.0 (token-expert assignments past the capacity drop).
     capacity_factor: float = 0.0
 
+    # The engine's Pallas attention kernels read this family's pages.
+    pallas_attention = True
+
     @property
     def head_dim(self) -> int:
         return self.dim // self.n_heads
+
+    @property
+    def kv_pool_spec(self) -> tuple[tuple[int, int, int], tuple[int, int, int]]:
+        """The pool's two sides, each (layers, heads, values a head)."""
+        side = (self.n_layers, self.n_kv_heads, self.head_dim)
+        return side, side
+
+    def forwards(self):
+        """(forward, ragged forward) as the engine's step programs call
+        them: the serving signatures, returning ``(logits, kv_k, kv_v,
+        expert counts)`` — None here: a family that counts nothing adds no
+        output to a step program."""
+        def counted(fn):
+            return lambda *a, **kw: (*fn(*a, **kw), None)
+
+        return counted(forward_impl), counted(forward_ragged_impl)
+
+    def unsupported(self, **_asked) -> list[str]:
+        """This family's forward covers everything the engine can be asked
+        for; what it resolves or refuses is the engine's own table."""
+        return []
 
     @property
     def matmul_params(self) -> int:
@@ -112,7 +138,7 @@ class LlamaConfig:
                 + ffn_delta)
 
 
-CONFIGS: dict[str, LlamaConfig] = {
+CONFIGS: dict[str, LlamaConfig | LongcatConfig] = {
     "llama3-8b-instruct": LlamaConfig(
         name="llama3-8b-instruct", vocab_size=128_256, dim=4096, n_layers=32,
         n_heads=32, n_kv_heads=8, ffn_dim=14_336,
@@ -219,10 +245,12 @@ CONFIGS: dict[str, LlamaConfig] = {
         n_kv_heads=2, ffn_dim=128, max_seq_len=8192, rope_theta=10_000.0,
         family="mixtral", n_experts=4, top_k_experts=2,
     ),
+    # Another architecture, its own dataclass and forward (models/longcat.py).
+    **_LONGCAT_CONFIGS,
 }
 
 
-def get_config(name: str) -> LlamaConfig:
+def get_config(name: str) -> LlamaConfig | LongcatConfig:
     if name not in CONFIGS:
         raise KeyError(f"Unknown model {name!r}; known: {sorted(CONFIGS)}")
     return CONFIGS[name]

@@ -90,3 +90,111 @@ def moe_ffn(
     back = flat[dest].reshape(n, top_k, d)                         # [N, K, D]
     combined = jnp.sum(back * gate_vals[..., None].astype(back.dtype), axis=1)
     return combined.reshape(b, t, d)
+
+
+# --------------------------------------------------------------------------- #
+# A layer that holds a SHARE of the experts (expert parallelism, one chip's   #
+# side of it), beside experts that compute nothing (identity experts).        #
+# --------------------------------------------------------------------------- #
+
+
+def route_scaled(
+    u: jnp.ndarray,            # [N, D] post-norm hidden
+    router: jnp.ndarray,       # [D, E_routed + E_zero] float32
+    bias: jnp.ndarray,         # [E_routed + E_zero] float32, moves the CHOICE only
+    top_k: int,
+    scale: float,
+) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """Scores ``s = softmax_f32(u W_r)`` over ALL outputs; the ``top_k`` of
+    ``s + bias`` are chosen; a chosen expert's weight is ``scale * s``, not
+    renormalised. Returns (chosen ids [N, K], their weights [N, K] f32).
+    The product runs at ``highest`` precision: it is small, and a score
+    decides which experts run."""
+    logits = jnp.dot(u.astype(jnp.float32), router.astype(jnp.float32),
+                     precision=jax.lax.Precision.HIGHEST)
+    s = jax.nn.softmax(logits, axis=-1)
+    _, chosen = jax.lax.top_k(s + bias.astype(jnp.float32), top_k)
+    return chosen, scale * jnp.take_along_axis(s, chosen, axis=-1)
+
+
+def held_capacity(n_tokens: int, top_k: int, n_outputs: int) -> int:
+    """Slots a held expert's queue gets on the fast path: eight times its
+    expected load under even routing (``n_tokens * top_k / n_outputs``), at
+    least 8, never more than every token. A queue that would overflow sends
+    the call down the exact slow path instead (:func:`held_expert_ffn`), so
+    the number trades speed only, never a token."""
+    expected = n_tokens * top_k / n_outputs
+    return max(1, min(n_tokens, max(8, math.ceil(8 * expected))))
+
+
+def held_expert_ffn(
+    u: jnp.ndarray,            # [N, D]
+    local: jnp.ndarray,        # [N, K] chosen expert as an index into the held ones; n_held = not held here
+    weights: jnp.ndarray,      # [N, K] f32 gate weights of the choices
+    w_gate: Any,               # [E_held, D, F], or [L, E_held, D, F] with ``layer``
+    w_up: Any,                 # [E_held, D, F]
+    w_down: Any,               # [E_held, F, D]
+    cap: int,
+    layer=None,                # traced scalar: which layer of stacked weights
+) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """``sum_{chosen j held here} w_j SwiGLU_j(u)``, [N, D] float32, and
+    whether the call took the slow path (int32 0 or 1). Dropless.
+
+    Only pairs that fell on a held expert are dispatched, so the cost does
+    not grow with the experts that live elsewhere. Fast path: every held
+    expert's queue has ``cap`` slots; tokens are gathered into ``[E_held,
+    cap, D]``, the experts run as one batched product, and the results are
+    scatter-added back under their weights. If any queue is longer than
+    ``cap`` (decided on the device, ``lax.cond``), the call instead runs
+    each held expert over every token under a weight that is zero where it
+    was not chosen: slower, the same sum. The caller sends pads and free
+    slots to no expert (``local`` = n_held): identical pad rows would all
+    queue at one expert and overflow it for nothing.
+
+    Inside a scan over layers, hand in the STACKED weights and ``layer``:
+    a ``lax.cond`` materialises its operands, so a layer's slice taken
+    outside would be copied (three matrices of every held expert, each
+    pass); taken inside a branch it is read in place by the product."""
+    from runbookai_tpu.models.llama import qmm  # deferred: models->ops cycle
+
+    n, d = u.shape
+    k = local.shape[1]
+    e = jax.tree.leaves(w_gate)[0].shape[0 if layer is None else 1]
+    flat = local.reshape(-1)                                        # [N*K]
+    onehot = jax.nn.one_hot(flat, e, dtype=jnp.int32)               # not held -> zeros
+    slot = jnp.sum((jnp.cumsum(onehot, axis=0) - onehot) * onehot, axis=-1)
+    fits = jnp.max(jnp.sum(onehot, axis=0)) <= cap
+
+    def swiglu(x, idx=None):
+        at = tuple(i for i in (layer, idx) if i is not None)
+
+        def pick(w):
+            return jax.tree.map(lambda a: a[at], w) if at else w
+
+        act = jax.nn.silu(qmm(x, pick(w_gate))) * qmm(x, pick(w_up))
+        return qmm(act, pick(w_down))
+
+    def slotted(_):
+        dest = jnp.where((flat < e) & (slot < cap), flat * cap + slot, e * cap)
+        token = jnp.arange(n * k, dtype=jnp.int32) // k
+        src = jnp.full((e * cap + 1,), n, jnp.int32).at[dest].set(token)[:-1]
+        w_slot = jnp.zeros((e * cap + 1,), jnp.float32).at[dest].set(
+            weights.reshape(-1))[:-1]
+        u_pad = jnp.concatenate([u, jnp.zeros((1, d), u.dtype)])
+        out_e = swiglu(u_pad[src].reshape(e, cap, d)).reshape(e * cap, d)
+        out = jnp.zeros((n + 1, d), jnp.float32).at[src].add(
+            out_e.astype(jnp.float32) * w_slot[:, None])
+        return out[:n]
+
+    def every_token(_):
+        w_dense = jnp.zeros((n, e + 1), jnp.float32).at[
+            jnp.arange(n)[:, None], local].add(weights)[:, :e]     # [N, E_held]
+
+        def one(acc, i):
+            return acc + swiglu(u, i).astype(jnp.float32) * w_dense[:, i, None], None
+
+        acc, _ = jax.lax.scan(one, jnp.zeros((n, d), jnp.float32),
+                              jnp.arange(e))
+        return acc
+
+    return jax.lax.cond(fits, slotted, every_token, None), (~fits).astype(jnp.int32)

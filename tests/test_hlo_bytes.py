@@ -296,3 +296,37 @@ def test_step_program_for_the_chip_owns_no_second_kv_pool(
                             sharding=one_chip)
     assert "tpu_custom_call" in compiled.as_text()
     _assert_one_pool(compiled, chip_core)
+
+
+@pytest.fixture(scope="module")
+def latent_chip_core():
+    """The latent (MLA) pool at its published row widths — 512-value
+    latents, and a layer's two 64-value rotated keys side by side in one
+    128-value row — under the published head sizes, two double layers. The
+    latent side is 403 MB: more than on-chip memory, as at serving size."""
+    from runbookai_tpu.models import longcat
+
+    cfg = longcat.LongcatConfig(
+        name="hlo-latent-chip-test", vocab_size=262, hidden_size=512,
+        ffn_hidden_size=1024, expert_ffn_hidden_size=256, num_layers=2,
+        num_attention_heads=8, q_lora_rank=256, kv_lora_rank=512,
+        qk_nope_head_dim=128, qk_rope_head_dim=64, v_head_dim=128,
+        n_routed_experts=32, zero_expert_num=16, moe_topk=4,
+        routed_scaling_factor=6.0, n_experts_held=8,
+        max_position_embeddings=512)
+    params = longcat.init_params(jax.random.PRNGKey(0), cfg, jnp.bfloat16)
+    return EngineCore(cfg, params, ByteTokenizer(), EngineConfig(
+        page_size=16, num_pages=6144, max_batch_slots=8, prefill_chunk=64,
+        max_seq_len=512, block_pages=4, kv_dtype=jnp.bfloat16))
+
+
+@pytest.mark.parametrize("program", STEP_PROGRAMS)
+def test_latent_step_program_for_the_chip_owns_no_second_pool(
+        one_chip, latent_chip_core, program):
+    """models/longcat.py's forward, compiled by the TPU's compiler: the
+    page walk gathers rows out of the carried pool, the writers scatter
+    whole rows into it, and neither side of the pool is copied, re-laid out
+    or sliced (a ``[.., 64]`` pool of rotated keys was, every sublayer; a
+    page-shaped view of the latents was, every call: ``ops/mla.py``)."""
+    compiled = lower_decode(latent_chip_core, program=program, sharding=one_chip)
+    _assert_one_pool(compiled, latent_chip_core)

@@ -172,7 +172,7 @@ def test_decode_step_matches_per_layer_loop(params, attn_impl):
     logits_ref = lm_head_logits(params, CFG, h)[:, -1]
     tok_ref = _sample(logits_ref, key, ctx_lens)
 
-    tok, logits, k_new, v_new, _ = _decode_step(
+    tok, logits, k_new, v_new, _, _ = _decode_step(
         params, CFG, tokens, positions, kv_k + 0, kv_v + 0, tables, ctx_lens,
         *_greedy(4), key, None, jnp.zeros((4,), jnp.int32),
         attn_impl=attn_impl, **STATIC)
@@ -203,7 +203,7 @@ def test_decode_multi_matches_per_layer_loop(params, attn_impl):
         toks_ref.append(tok)
         tok_r, pos_r, ctx_r = tok[:, None], pos_r + 1, ctx_r + 1
 
-    toks, k_new, v_new, _ = _decode_multi(
+    toks, k_new, v_new, _, _ = _decode_multi(
         params, CFG, tokens, positions, kv_k + 0, kv_v + 0, tables,
         jnp.asarray(ctx), *_greedy(4), key, jnp.zeros((4,), jnp.int32),
         k_steps=k_steps, attn_impl=attn_impl, **STATIC)
@@ -235,7 +235,7 @@ def test_prefill_step_matches_per_layer_loop(params, attn_impl):
                                   tables, ctx_lens, attn_impl)
     logits_ref = lm_head_logits(params, CFG, h)[jnp.arange(2), last_idx]
 
-    logits, k_new, v_new = _prefill_step(
+    logits, k_new, v_new, _ = _prefill_step(
         params, CFG, tokens, kv_k + 0, kv_v + 0, positions, tables, ctx_lens,
         last_idx, jnp.zeros((2,), jnp.int32), attn_impl=attn_impl, **STATIC)
     np.testing.assert_array_equal(np.asarray(logits), np.asarray(logits_ref))
@@ -285,7 +285,7 @@ def test_mixed_step_matches_per_layer_loop(params, attn_impl):
     dec_ref = _sample(logits[:b], key_dec, ctx_lens[:b])
     pf_ref = _sample(logits[b:], key_pf, ctx_lens[b:b + n_pf])
 
-    toks_win, pf_toks, feed_new, k_new, v_new, _ = _mixed_step(
+    toks_win, pf_toks, feed_new, k_new, v_new, _, _ = _mixed_step(
         params, CFG, tokens, feed, dec_idx, positions, row_ids, kv_k + 0,
         kv_v + 0, tables, ctx_lens, jnp.zeros((rows,), jnp.int32), pf_last,
         *_greedy(b), key, *_greedy(n_pf), pf_slot_map,
