@@ -1362,6 +1362,12 @@ class EngineCore:
     def _trash_pos(self) -> int:
         return self.kv.max_pages_per_seq * self.ecfg.page_size
 
+    def _pages_held(self, ctx_lens: np.ndarray) -> int:
+        """KV pages the rows of a dispatch hold, from the context lengths
+        it is given (0 for a row with no request): what the decode
+        kernel's page walk reads (the step record's ``kv_pages_live``)."""
+        return int((-(-ctx_lens // self.ecfg.page_size)).sum())
+
     def _adapter_ids_for_slots(self) -> np.ndarray:
         """Per-slot LoRA adapter rows (0 = base) for a decode dispatch."""
         ids = np.zeros((self.ecfg.max_batch_slots,), dtype=np.int32)
@@ -2216,7 +2222,8 @@ class EngineCore:
         if self.tracer.enabled:
             spec_meta["requests"] = [r.request_id for r in self.decoding]
         if self._open is not None:
-            self._open.dispatched("_decode_spec", k, len(self.decoding))
+            self._open.dispatched("_decode_spec", k, len(self.decoding),
+                                  self._pages_held(ctx_lens))
         with self.tracer.span("engine.decode_spec", **spec_meta), \
                 annotate("decode_spec"), self._span("issue"):
             t_issue = time.perf_counter()
@@ -2534,7 +2541,8 @@ class EngineCore:
                 [r.request_id for r in dec_snapshot]
                 + [r.request_id for r, _, _ in pf_rows])
         if self._open is not None:
-            self._open.dispatched("_mixed_step", 1, len(dec_snapshot))
+            self._open.dispatched("_mixed_step", 1, len(dec_snapshot),
+                                  self._pages_held(ctx_lens))
         with self.tracer.span("engine.mixed", **mix_meta), \
                 annotate("mixed"), self._span("issue"):
             t_issue = time.perf_counter()
@@ -2767,7 +2775,8 @@ class EngineCore:
             dec_meta["requests"] = [r.request_id for r in self.decoding]
         if self._open is not None:
             self._open.dispatched("_decode_step" if k == 1 else "_decode_multi",
-                                  k, len(self.decoding))
+                                  k, len(self.decoding),
+                                  self._pages_held(ctx_lens))
         with self.tracer.span("engine.decode", **dec_meta), \
                 annotate("decode"), self._span("issue"):
             t_issue = time.perf_counter()
@@ -2954,6 +2963,7 @@ class EngineCore:
             "program": step.programs,
             "k": step.k,
             "rows": step.rows,
+            "kv_pages_live": step.kv_pages_live,
             "prefill_tokens": prefill_tokens,
             "decode_tokens": decode_tokens,
             "compile_s": round(compile_s, 6),

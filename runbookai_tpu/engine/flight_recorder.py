@@ -51,7 +51,7 @@ STEP_RECORD_FIELDS = (
     # The record as a span (docs/observability.md): its two ends on
     # time.monotonic(), where its seconds went, what it dispatched, and
     # the requests that entered and left the engine in it.
-    "t_start", "t_end", "phases", "program", "k", "rows",
+    "t_start", "t_end", "phases", "program", "k", "rows", "kv_pages_live",
     "prefill_tokens", "decode_tokens", "compile_s", "admitted", "finished",
 )
 # Keys a record has only in some steps. ``experts``: where the model has an
@@ -91,8 +91,8 @@ class OpenStep:
     outer one, so no interval is counted twice however the engine's
     drains nest. Written by the step thread only."""
 
-    __slots__ = ("t_start", "phases", "programs", "k", "rows", "experts",
-                 "_stack", "_t")
+    __slots__ = ("t_start", "phases", "programs", "k", "rows",
+                 "kv_pages_live", "experts", "_stack", "_t")
 
     def __init__(self):
         self.phases = dict.fromkeys(STEP_PHASES, 0.0)
@@ -100,6 +100,7 @@ class OpenStep:
         self.experts: Optional[dict[str, Any]] = None
         self.k = 0  # decode steps in the dispatch
         self.rows = 0  # decode rows dispatched
+        self.kv_pages_live = 0  # KV pages the dispatch's rows held
         self._stack: list[str] = []
         self._t = 0.0
         self.t_start = time.monotonic()
@@ -116,10 +117,11 @@ class OpenStep:
         self.phases[self._stack.pop()] += now - self._t
         self._t = now
 
-    def dispatched(self, program: str, k: int = 0, rows: int = 0) -> None:
+    def dispatched(self, program: str, k: int = 0, rows: int = 0,
+                   kv_pages_live: int = 0) -> None:
         self.programs.append(program)
         if k:
-            self.k, self.rows = k, rows
+            self.k, self.rows, self.kv_pages_live = k, rows, kv_pages_live
 
     def fetched_experts(self, program: str, passes: int, held: int,
                         zero: int, absent: int, touched: int,

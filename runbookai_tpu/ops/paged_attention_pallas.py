@@ -1,25 +1,30 @@
-"""Pallas TPU kernel: ragged paged attention for the decode hot loop.
+"""Pallas TPU kernels: ragged paged attention over the engine's KV pool.
 
 SURVEY.md §7 names this "the single riskiest piece of device code": the XLA
 fallback (:mod:`runbookai_tpu.ops.attention`) re-gathers KV through the page
-table every step; this kernel instead drives the page-table indirection with
-**scalar prefetch** — the grid's K/V block index_maps read the prefetched page
-table, so Mosaic pipelines exactly the pages each sequence owns from HBM into
-VMEM (double-buffered) and flash-accumulates in VMEM scratch.
+table every step; these kernels read the **scalar-prefetched** page table
+and fetch exactly the pages a sequence owns into VMEM, flash-accumulating
+(m, l, acc) in float32 scratch (PAPERS.md "Ragged Paged Attention").
 
-Pattern per PAPERS.md "Ragged Paged Attention" + the pallas guide
-(PrefetchScalarGridSpec): grid = (batch, pages); for a fixed sequence the page
-axis iterates sequentially, carrying (m, l, acc) scratch; the output block is
-written on the sequence's last page step. Decode-shaped (T = 1).
+Two kernels:
 
-Two kernels share the flash-accumulate pattern:
-
-- :func:`paged_decode_attention` — decode-shaped (T = 1), grid (batch, pages).
+- :func:`paged_decode_attention` — decode-shaped (T = 1). **The page walk is
+  inside the kernel**: grid = (rows,), and each row loops
+  ``cdiv(ctx_lens[row], pages a step x page_size)`` times over ITS page
+  table, so an empty slot costs one grid step and a row of 400 tokens the
+  work of 400 tokens, whatever the table's width. The pool stays where XLA
+  put it (``memory_space=pl.ANY``); a step issues one async copy a live
+  page into a double-buffered VMEM group, the next group's copies in
+  flight while this one is accumulated. Pages a step comes from the page's
+  bytes against a fixed VMEM budget (:func:`decode_pages_per_step`). One
+  walk serves every pool: raw pages, int8 pages with scales, and a page-
+  split shard that skips the pages it does not own and returns partials.
 - :func:`paged_chunk_attention` — T > 1 (chunked prefill and the speculative
   verify forward), grid (batch, q_blocks, pages) with the page axis innermost
-  so scratch carries across a sequence's pages; query positions are scalar-
-  prefetched for the causal+ragged mask, and the query dimension is blocked
-  to bound VMEM scratch (TQ·n_q accumulator rows per step).
+  so scratch carries across a sequence's pages, one page a grid step; query
+  positions are scalar-prefetched for the causal+ragged mask, and the query
+  dimension is blocked to bound VMEM scratch (TQ·n_q accumulator rows per
+  step). (ROADMAP A2: the decode walk is what it should take over.)
 
 Selected by ``EngineConfig.attn_impl = "pallas"``; interpret mode keeps it
 testable on CPU meshes.
@@ -36,153 +41,272 @@ from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 
+# VMEM the decode walk may spend on page buffers (K and V, two groups
+# each), and the most cache positions one step takes: past that a step's
+# scores outgrow the vector registers and a row's last, part-filled group
+# wastes more than a longer step saves in loop trips.
+_DECODE_KV_VMEM_BYTES = 1 << 20
+_DECODE_STEP_POSITIONS = 512
 
-def _flash_page_accumulate(q_ref, k_ref, v_ref, m_ref, l_ref, acc_ref,
-                           base, ctx, n_kv: int, group: int,
-                           page_size: int, ks_ref=None, vs_ref=None) -> None:
-    """Shared online-softmax accumulation of one K/V page into the
-    (m, l, acc) scratch — the body of ALL decode kernels (full-pool,
-    kv-split partial, int8-scaled), kept in one place so masking/numerics
-    fixes cannot diverge. Masked positions are explicitly zeroed in p
-    (exp underflow handles them too, but the explicit mask keeps l exact
-    by construction). With ``ks_ref``/``vs_ref`` the K/V page holds int8
-    values and these are their per-(token, head) f32 absmax scales,
-    applied on the in-VMEM widen (ops/attention.py quantize_kv)."""
-    q = q_ref[0].astype(jnp.float32)  # [n_q, hd]
-    hd = q.shape[-1]
-    scale = 1.0 / (hd ** 0.5)
-    pos = base + jax.lax.broadcasted_iota(jnp.int32, (1, page_size), 1)
-    valid = pos < ctx  # [1, page_size]
+
+def decode_pages_per_step(page_size: int, n_kv: int, hd: int, kv_dtype,
+                          pages_per_seq: int) -> int:
+    """Pages one step of the decode walk fetches and accumulates: what the
+    VMEM budget holds of this pool's pages, at most ``_DECODE_STEP_POSITIONS``
+    positions and never more than a row's table has columns."""
+    page_bytes = page_size * n_kv * hd * jnp.dtype(kv_dtype).itemsize
+    return max(1, min(_DECODE_KV_VMEM_BYTES // (4 * page_bytes),
+                      _DECODE_STEP_POSITIONS // page_size, pages_per_seq))
+
+
+def _flash_accumulate(q, k, v, valid, m_ref, l_ref, acc_ref,
+                      k_scale=None, v_scale=None) -> None:
+    """Online-softmax accumulation of one group of pages into the (m, l,
+    acc) scratch — the body of EVERY decode walk (raw, int8-scaled,
+    kv-split partial), kept in one place so masking/numerics fixes cannot
+    diverge. ``k``/``v`` are the group as it lies in the pool, ``[columns,
+    hd]`` with a column per (position, kv head); ``q`` is every query head
+    ``[n_q, hd]`` in float32, already scaled; ``valid [n_q, columns]`` says
+    which columns are a live position of the row's own kv head. All heads
+    go through one product: a foreign head's column is masked like a dead
+    position, so it adds an exact zero. Masked positions are explicitly
+    zeroed in p (exp underflow handles them too, but the explicit mask
+    keeps l exact by construction). ``k_scale``/``v_scale [1, columns]`` are
+    an int8 pool's per-(token, head) absmax scales (ops/attention.py
+    quantize_kv): q·(k·s) = (q·k)·s and p·(v·s) = (p·s)·v, so they scale
+    the scores and the probabilities, not the pages."""
+    s = jax.lax.dot_general(
+        q, k.astype(jnp.float32), (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32)  # [n_q, columns]
+    if k_scale is not None:
+        s = s * k_scale
+    s = jnp.where(valid, s, NEG_INF)
 
     m_prev = m_ref[:, :1]  # [n_q, 1]
-    l_prev = l_ref[:, :1]
-    acc_prev = acc_ref[:]
-
-    s_rows = []
-    v_heads = []
-    for h in range(n_kv):
-        k_h = k_ref[0, :, h, :].astype(jnp.float32)  # [ps, hd]
-        if ks_ref is not None:
-            k_h = k_h * ks_ref[0, :, h][:, None]
-        q_h = q[h * group : (h + 1) * group]  # [group, hd]
-        s_h = jax.lax.dot_general(
-            q_h * scale, k_h, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )  # [group, ps]
-        s_rows.append(jnp.where(valid, s_h, NEG_INF))
-        v_h = v_ref[0, :, h, :].astype(jnp.float32)  # [ps, hd]
-        if vs_ref is not None:
-            v_h = v_h * vs_ref[0, :, h][:, None]
-        v_heads.append(v_h)
-    s = jnp.concatenate(s_rows, axis=0)  # [n_q, ps] (kv-major head order)
-
-    m_blk = jnp.max(s, axis=1, keepdims=True)
-    m_new = jnp.maximum(m_prev, m_blk)
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
     alpha = jnp.exp(m_prev - m_new)
-    p_blk = jnp.where(valid, jnp.exp(s - m_new), 0.0)  # [1,ps] broadcasts
-    l_new = l_prev * alpha + jnp.sum(p_blk, axis=1, keepdims=True)
-
-    pv_rows = []
-    for h in range(n_kv):
-        p_h = p_blk[h * group : (h + 1) * group]
-        pv_rows.append(jax.lax.dot_general(
-            p_h, v_heads[h], (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ))  # [group, hd]
-    pv = jnp.concatenate(pv_rows, axis=0)  # [n_q, hd]
-
-    acc_ref[:] = acc_prev * alpha + pv
+    p = jnp.where(valid, jnp.exp(s - m_new), 0.0)
+    l_ref[:, :1] = l_ref[:, :1] * alpha + jnp.sum(p, axis=1, keepdims=True)
     m_ref[:, :1] = m_new
-    l_ref[:, :1] = l_new
+    if v_scale is not None:
+        p = p * v_scale
+    acc_ref[:] = acc_ref[:] * alpha + jax.lax.dot_general(
+        p, v.astype(jnp.float32), (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)  # [n_q, hd]
 
 
-def _decode_kernel(
-    # scalar prefetch:
-    page_tables_ref,  # [B, P] int32 (SMEM)
-    ctx_lens_ref,  # [B] int32 (SMEM)
-    # blocks:
-    q_ref,  # [1, n_q, hd]
-    k_ref,  # [1, page_size, n_kv, hd]
-    v_ref,  # [1, page_size, n_kv, hd]
-    o_ref,  # [1, n_q, hd]
-    # scratch:
-    m_ref,  # [n_q, 128] f32
-    l_ref,  # [n_q, 128] f32
-    acc_ref,  # [n_q, hd] f32
-    *,
-    page_size: int,
-    n_kv: int,
-    group: int,
-    pages_per_seq: int,
-):
-    b = pl.program_id(0)
-    p = pl.program_id(1)
+def _decode_walk_kernel(*refs, page_size: int, n_kv: int, group: int,
+                        pages_per_step: int, sm_scale: float, scaled: bool,
+                        pages_local: int | None):
+    """One grid step = one row: walk its live pages, ``pages_per_step`` at a
+    time. What a page is and what the row writes at the end are the two
+    things the pools differ in:
 
-    @pl.when(p == 0)
-    def _init():
-        m_ref[:] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[:] = jnp.zeros_like(l_ref)
-        acc_ref[:] = jnp.zeros_like(acc_ref)
+    - ``scaled``: int8 pages, with ``[page_size, n_kv]`` float32 scales
+      (kv heads along lanes, padded to the lane width) fetched beside each
+      page;
+    - ``pages_local`` (kv-split): the sources are this device's page SLICE
+      of that many pages, the table holds GLOBAL ids, pages owned by other
+      shards are neither fetched nor counted, and the row writes the flash
+      partials ``(acc, m, l)`` for the cross-shard merge
+      (``parallel/kv_split.py``) instead of the normalised output.
+    """
+    partial = pages_local is not None
+    n_src = 4 if scaled else 2
+    it = iter(refs)
+    # scalar prefetch (SMEM): page table [B, P], context lengths [B], and
+    # the kv-split shard index [1]
+    tables_ref, ctx_ref = next(it), next(it)
+    shard = next(it)[0] if partial else None
+    q_ref = next(it)  # [1, n_q, hd]
+    srcs = [next(it) for _ in range(n_src)]  # k, v[, k scales, v scales]
+    outs = [next(it) for _ in range(3 if partial else 1)]
+    bufs = [next(it) for _ in range(n_src)]  # [2, pages_per_step, ...]
+    sems = next(it)  # DMA [2, n_src]
+    m_ref, l_ref, acc_ref = it  # [n_q, 128], [n_q, 128], [n_q, hd] f32
 
-    ctx = ctx_lens_ref[b]
-    base = p * page_size
+    row = pl.program_id(0)
+    ctx = ctx_ref[row]
+    n_q, hd = q_ref.shape[1:]
+    rows_per_page = page_size * n_kv
+    columns = pages_per_step * rows_per_page
+    span = pages_per_step * page_size
+    last_col = tables_ref.shape[1] - 1
 
-    @pl.when(base < ctx)
-    def _accumulate():
-        _flash_page_accumulate(q_ref, k_ref, v_ref, m_ref, l_ref, acc_ref,
-                               base, ctx, n_kv, group, page_size)
+    m_ref[:] = jnp.full_like(m_ref, NEG_INF)
+    l_ref[:] = jnp.zeros_like(l_ref)
+    acc_ref[:] = jnp.zeros_like(acc_ref)
 
-    @pl.when(p == pages_per_seq - 1)
-    def _finalize():
-        l_final = jnp.maximum(l_ref[:, :1], 1e-30)
-        o_ref[0] = (acc_ref[:] / l_final).astype(o_ref.dtype)
+    def page(step, i):
+        """Table column ``step * pages_per_step + i`` of this row: whether
+        the walk reads it, and its index in the sources."""
+        col = step * pages_per_step + i
+        live = col * page_size < ctx
+        pid = tables_ref[row, jnp.minimum(col, last_col)]
+        if partial:
+            live = live & (pid // pages_local == shard)
+            pid = pid - shard * pages_local
+        return live, pid
+
+    def copies(step, slot, wait: bool) -> None:
+        """Start, or wait for, the copies of one group into ``slot``."""
+        def one(i, _):
+            live, pid = page(step, i)
+
+            @pl.when(live)
+            def _():
+                for s, (src, buf) in enumerate(zip(srcs, bufs)):
+                    copy = pltpu.make_async_copy(
+                        src.at[pid], buf.at[slot, i], sems.at[slot, s])
+                    copy.wait() if wait else copy.start()
+
+            if wait:
+                # A page the walk does not read is masked out of p, and
+                # 0 x what its buffer held must be 0: never-written VMEM
+                # or another row's page may hold a NaN. Zero the V side.
+                @pl.when(jnp.logical_not(live))
+                def _():
+                    for buf in bufs[1::2]:
+                        buf[slot, i] = jnp.zeros(buf.shape[2:], buf.dtype)
+
+        jax.lax.fori_loop(0, pages_per_step, one, None)
+
+    # A column of a group is (position, kv head), as the pool lays a page
+    # out; query head r reads kv head r // group (kv-major head order, the
+    # grouping the model's reshape uses — no permutation needed).
+    col = jax.lax.broadcasted_iota(jnp.int32, (1, columns), 1)
+    col_pos, col_head = jax.lax.div(col, n_kv), jax.lax.rem(col, n_kv)
+    q_row = jax.lax.broadcasted_iota(jnp.int32, (n_q, 1), 0)
+    row_head = sum((q_row >= h * group).astype(jnp.int32)
+                   for h in range(1, n_kv))
+    own_head = col_head == row_head  # [n_q, columns]
+    q = q_ref[0].astype(jnp.float32) * sm_scale
+
+    if scaled:
+        # Which scale lane (kv head) a column reads, and which position.
+        pick = (jax.lax.broadcasted_iota(
+            jnp.int32, (bufs[2].shape[-1], columns), 0) == col_head
+        ).astype(jnp.float32)
+        own_pos = jax.lax.broadcasted_iota(
+            jnp.int32, (span, 1), 0) == col_pos  # [span, columns]
+
+    def scale_row(buf, slot):
+        """A group's scales ``[pages, page_size, lanes]`` as the row ``[1,
+        columns]`` its scores carry them in. The lanes-to-columns move is
+        an exact product: x[t, c] = scales[t, head of c] (one term, times
+        1), of which column c keeps the entry of its own position."""
+        x = jax.lax.dot_general(
+            buf[slot].reshape(span, buf.shape[-1]), pick,
+            (((1,), (0,)), ((), ())), precision=jax.lax.Precision.HIGHEST,
+            preferred_element_type=jnp.float32)  # [span, columns]
+        return jnp.sum(jnp.where(own_pos, x, 0.0), axis=0, keepdims=True)
+
+    n_steps = pl.cdiv(ctx, span)
+
+    @pl.when(n_steps > 0)
+    def _first():
+        copies(0, 0, wait=False)
+
+    def step(i, _):
+        slot = jax.lax.rem(i, 2)
+
+        @pl.when(i + 1 < n_steps)
+        def _next():
+            copies(i + 1, 1 - slot, wait=False)
+
+        copies(i, slot, wait=True)
+        valid = own_head & (i * span + col_pos < ctx)
+        if partial:
+            col_page = jax.lax.div(col, rows_per_page)
+            mine = jax.lax.fori_loop(
+                0, pages_per_step,
+                lambda j, mine: jnp.where(
+                    col_page == j, page(i, j)[0].astype(jnp.int32), mine),
+                jnp.zeros_like(col))
+            valid = valid & (mine > 0)
+        _flash_accumulate(
+            q, bufs[0][slot].reshape(columns, hd),
+            bufs[1][slot].reshape(columns, hd), valid, m_ref, l_ref, acc_ref,
+            k_scale=scale_row(bufs[2], slot) if scaled else None,
+            v_scale=scale_row(bufs[3], slot) if scaled else None)
+
+    jax.lax.fori_loop(0, n_steps, step, None)
+
+    if partial:
+        outs[0][0], outs[1][0], outs[2][0] = acc_ref[:], m_ref[:], l_ref[:]
+    else:
+        outs[0][0] = (acc_ref[:] / jnp.maximum(l_ref[:, :1], 1e-30)
+                      ).astype(outs[0].dtype)
 
 
-def _decode_kernel_int8(
-    # scalar prefetch:
-    page_tables_ref,  # [B, P] int32 (SMEM)
-    ctx_lens_ref,  # [B] int32 (SMEM)
-    # blocks:
-    q_ref,  # [1, n_q, hd]
-    k_ref,  # [1, page_size, n_kv, hd] int8
-    v_ref,  # [1, page_size, n_kv, hd] int8
-    ks_ref,  # [1, page_size, n_kv] f32 absmax scales
-    vs_ref,  # [1, page_size, n_kv] f32
-    o_ref,  # [1, n_q, hd]
-    # scratch:
-    m_ref,
-    l_ref,
-    acc_ref,
-    *,
-    page_size: int,
-    n_kv: int,
-    group: int,
-    pages_per_seq: int,
-):
-    """int8-KV decode: identical flash accumulation, values widened and
-    scaled in VMEM on load — HBM still moves 1 byte/value."""
-    b = pl.program_id(0)
-    p = pl.program_id(1)
+def _lane_pad(x: jnp.ndarray) -> jnp.ndarray:
+    """``x`` with its minor dimension zero-padded to a multiple of the 128
+    lanes: Mosaic copies a page out of an ``ANY``-space operand only in
+    whole lane tiles."""
+    short = -x.shape[-1] % 128
+    return jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, short)]) if short else x
 
-    @pl.when(p == 0)
-    def _init():
-        m_ref[:] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[:] = jnp.zeros_like(l_ref)
-        acc_ref[:] = jnp.zeros_like(acc_ref)
 
-    ctx = ctx_lens_ref[b]
-    base = p * page_size
+def _decode_walk(q, k_flat, v_flat, page_tables, ctx_lens, page_size: int,
+                 interpret: bool, shard=None, pages_local: int | None = None):
+    """Launch :func:`_decode_walk_kernel` over the rows of ``q``. The page
+    table is the call's first operand and the result ``[rows, n_q, hd]``:
+    the benchmark finds the kernel in a trace by those two shapes
+    (``benchmark/kernels/paged_attention_decode.py``). A head narrower than
+    the lanes (the test-size models) is zero-padded to them, which leaves
+    every score and, once sliced, the output what they were."""
+    b, n_q, hd = q.shape
+    scaled = isinstance(k_flat, tuple)
+    k_vals, k_scales = k_flat if scaled else (k_flat, None)
+    v_vals, v_scales = v_flat if scaled else (v_flat, None)
+    n_kv = k_vals.shape[1]
+    q, k_vals, v_vals = _lane_pad(q), _lane_pad(k_vals), _lane_pad(v_vals)
+    hd_lanes = q.shape[-1]
+    g = decode_pages_per_step(page_size, n_kv, hd_lanes, k_vals.dtype,
+                              page_tables.shape[1])
+    # A page as one [positions x kv heads, hd] block: the same bytes as
+    # [page_size, n_kv, hd], and every kv head's keys in one operand.
+    page = (page_size * n_kv, hd_lanes)
+    srcs = [a.reshape(-1, *page) for a in (k_vals, v_vals)]
+    bufs = [pltpu.VMEM((2, g, *page), k_vals.dtype)] * 2
+    if scaled:
+        srcs += [_lane_pad(a.reshape(-1, page_size, n_kv))
+                 for a in (k_scales, v_scales)]
+        bufs += [pltpu.VMEM((2, g, *srcs[-1].shape[1:]), k_scales.dtype)] * 2
+    prefetch = [page_tables, ctx_lens]
+    partial = pages_local is not None
+    if partial:
+        prefetch.append(shard.reshape(1))
 
-    @pl.when(base < ctx)
-    def _accumulate():
-        _flash_page_accumulate(q_ref, k_ref, v_ref, m_ref, l_ref, acc_ref,
-                               base, ctx, n_kv, group, page_size,
-                               ks_ref=ks_ref, vs_ref=vs_ref)
+    def row_block(width):
+        return pl.BlockSpec((1, n_q, width), lambda r, *_: (r, 0, 0))
 
-    @pl.when(p == pages_per_seq - 1)
-    def _finalize():
-        l_final = jnp.maximum(l_ref[:, :1], 1e-30)
-        o_ref[0] = (acc_ref[:] / l_final).astype(o_ref.dtype)
+    out_kinds = ([(hd_lanes, jnp.float32), (128, jnp.float32),
+                  (128, jnp.float32)] if partial else [(hd_lanes, q.dtype)])
+    outs = pl.pallas_call(
+        functools.partial(
+            _decode_walk_kernel, page_size=page_size, n_kv=n_kv,
+            group=n_q // n_kv, pages_per_step=g, sm_scale=hd ** -0.5,
+            scaled=scaled, pages_local=pages_local),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=len(prefetch),
+            grid=(b,),
+            in_specs=[row_block(hd_lanes)]
+            + [pl.BlockSpec(memory_space=pl.ANY)] * len(srcs),
+            out_specs=[row_block(w) for w, _ in out_kinds],
+            scratch_shapes=bufs + [
+                pltpu.SemaphoreType.DMA((2, len(srcs))),
+                pltpu.VMEM((n_q, 128), jnp.float32),  # m
+                pltpu.VMEM((n_q, 128), jnp.float32),  # l
+                pltpu.VMEM((n_q, hd_lanes), jnp.float32),  # acc
+            ],
+        ),
+        out_shape=[jax.ShapeDtypeStruct((b, n_q, w), dt)
+                   for w, dt in out_kinds],
+        interpret=interpret,
+    )(*prefetch, q, *srcs)
+    out, *stats = outs
+    return (out[..., :hd], *stats) if partial else out[..., :hd]
 
 
 def paged_decode_attention(
@@ -194,101 +318,11 @@ def paged_decode_attention(
     page_size: int,
     interpret: bool = False,
 ) -> jnp.ndarray:
-    """Ragged paged attention for decode (one query token per sequence)."""
-    if isinstance(k_flat, tuple):
-        return _paged_decode_attention_int8(
-            q, k_flat, v_flat, page_tables, ctx_lens,
-            page_size=page_size, interpret=interpret)
-    b, n_q, hd = q.shape
-    n_kv = k_flat.shape[1]
-    group = n_q // n_kv
-    pages_per_seq = page_tables.shape[1]
-    k_pages = k_flat.reshape(-1, page_size, n_kv, hd)
-    v_pages = v_flat.reshape(-1, page_size, n_kv, hd)
-
-    # Query head order for the kernel is kv-major ([kv0 g0..gN, kv1 g0..], the
-    # same grouping the model's reshape uses) — no permutation needed.
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(b, pages_per_seq),
-        in_specs=[
-            pl.BlockSpec((1, n_q, hd), lambda b_, p_, pt, cl: (b_, 0, 0)),
-            pl.BlockSpec((1, page_size, n_kv, hd),
-                         lambda b_, p_, pt, cl: (pt[b_, p_], 0, 0, 0)),
-            pl.BlockSpec((1, page_size, n_kv, hd),
-                         lambda b_, p_, pt, cl: (pt[b_, p_], 0, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, n_q, hd), lambda b_, p_, pt, cl: (b_, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((n_q, 128), jnp.float32),  # m
-            pltpu.VMEM((n_q, 128), jnp.float32),  # l
-            pltpu.VMEM((n_q, hd), jnp.float32),  # acc
-        ],
-    )
-    kernel = functools.partial(
-        _decode_kernel, page_size=page_size, n_kv=n_kv, group=group,
-        pages_per_seq=pages_per_seq,
-    )
-    return pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, n_q, hd), q.dtype),
-        interpret=interpret,
-    )(page_tables, ctx_lens, q, k_pages, v_pages)
-
-
-def _paged_decode_attention_int8(
-    q: jnp.ndarray,  # [B, n_q, hd]
-    k_flat: tuple,  # (int8 values [tokens, n_kv, hd], f32 scales [tokens, n_kv])
-    v_flat: tuple,
-    page_tables: jnp.ndarray,
-    ctx_lens: jnp.ndarray,
-    page_size: int,
-    interpret: bool = False,
-) -> jnp.ndarray:
-    """Decode over the int8-scaled pool: same grid/prefetch as the raw
-    kernel with two extra per-page scale blocks."""
-    b, n_q, hd = q.shape
-    k_vals, k_scales = k_flat
-    v_vals, v_scales = v_flat
-    n_kv = k_vals.shape[1]
-    group = n_q // n_kv
-    pages_per_seq = page_tables.shape[1]
-    k_pages = k_vals.reshape(-1, page_size, n_kv, hd)
-    v_pages = v_vals.reshape(-1, page_size, n_kv, hd)
-    ks_pages = k_scales.reshape(-1, page_size, n_kv)
-    vs_pages = v_scales.reshape(-1, page_size, n_kv)
-
-    kv_map = lambda b_, p_, pt, cl: (pt[b_, p_], 0, 0, 0)  # noqa: E731
-    s_map = lambda b_, p_, pt, cl: (pt[b_, p_], 0, 0)  # noqa: E731
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(b, pages_per_seq),
-        in_specs=[
-            pl.BlockSpec((1, n_q, hd), lambda b_, p_, pt, cl: (b_, 0, 0)),
-            pl.BlockSpec((1, page_size, n_kv, hd), kv_map),
-            pl.BlockSpec((1, page_size, n_kv, hd), kv_map),
-            pl.BlockSpec((1, page_size, n_kv), s_map),
-            pl.BlockSpec((1, page_size, n_kv), s_map),
-        ],
-        out_specs=pl.BlockSpec((1, n_q, hd),
-                               lambda b_, p_, pt, cl: (b_, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((n_q, 128), jnp.float32),
-            pltpu.VMEM((n_q, 128), jnp.float32),
-            pltpu.VMEM((n_q, hd), jnp.float32),
-        ],
-    )
-    kernel = functools.partial(
-        _decode_kernel_int8, page_size=page_size, n_kv=n_kv, group=group,
-        pages_per_seq=pages_per_seq,
-    )
-    return pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, n_q, hd), q.dtype),
-        interpret=interpret,
-    )(page_tables, ctx_lens, q, k_pages, v_pages, ks_pages, vs_pages)
+    """Ragged paged attention for decode (one query token per sequence).
+    An int8 pool is ``(values [tokens, n_kv, hd], f32 scales [tokens,
+    n_kv])``: HBM still moves 1 byte a value, widened in VMEM."""
+    return _decode_walk(q, k_flat, v_flat, page_tables, ctx_lens, page_size,
+                        interpret)
 
 
 def _chunk_kernel(
@@ -522,60 +556,6 @@ def paged_ragged_attention(
     ).reshape(n, n_q, hd)
 
 
-def _decode_kernel_partial(
-    # scalar prefetch:
-    page_tables_ref,  # [B, P] int32 GLOBAL page ids (SMEM)
-    ctx_lens_ref,  # [B] int32 (SMEM)
-    shard_ref,  # [1] int32 — this device's page-shard index (SMEM)
-    # blocks:
-    q_ref,  # [1, n_q, hd]
-    k_ref,  # [1, page_size, n_kv, hd]  (LOCAL pool slice)
-    v_ref,
-    # outputs (un-normalized partials for the cross-shard merge):
-    acc_out,  # [1, n_q, hd] f32
-    m_out,  # [1, n_q, 128] f32
-    l_out,  # [1, n_q, 128] f32
-    # scratch:
-    m_ref,
-    l_ref,
-    acc_ref,
-    *,
-    page_size: int,
-    n_kv: int,
-    group: int,
-    pages_per_seq: int,
-    pages_local: int,
-):
-    """KV page-split variant of :func:`_decode_kernel`: the pool ref is
-    this device's page SLICE, pages not owned here are skipped (their
-    shard contributes them), and the outputs are the flash partials
-    ``(acc, m, l)`` — the shard_map wrapper merges across the ``seq``
-    axis (``parallel/kv_split.py`` math) and normalizes."""
-    b = pl.program_id(0)
-    p = pl.program_id(1)
-
-    @pl.when(p == 0)
-    def _init():
-        m_ref[:] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[:] = jnp.zeros_like(l_ref)
-        acc_ref[:] = jnp.zeros_like(acc_ref)
-
-    ctx = ctx_lens_ref[b]
-    base = p * page_size
-    owned = (page_tables_ref[b, p] // pages_local) == shard_ref[0]
-
-    @pl.when((base < ctx) & owned)
-    def _accumulate():
-        _flash_page_accumulate(q_ref, k_ref, v_ref, m_ref, l_ref, acc_ref,
-                               base, ctx, n_kv, group, page_size)
-
-    @pl.when(p == pages_per_seq - 1)
-    def _finalize():
-        acc_out[0] = acc_ref[:]
-        m_out[0] = m_ref[:]
-        l_out[0] = l_ref[:]
-
-
 def paged_decode_attention_partial(
     q: jnp.ndarray,  # [B, n_q, hd]
     k_local: jnp.ndarray,  # [pages_local * page_size, n_kv, hd]
@@ -587,55 +567,14 @@ def paged_decode_attention_partial(
     pages_local: int,
     interpret: bool = False,
 ):
-    """Flash partials over a LOCAL page slice; returns (acc, m, l) with
-    m/l padded to lane width (column 0 is the value)."""
-    b, n_q, hd = q.shape
-    n_kv = k_local.shape[1]
-    group = n_q // n_kv
-    pages_per_seq = page_tables.shape[1]
-    k_pages = k_local.reshape(-1, page_size, n_kv, hd)
-    v_pages = v_local.reshape(-1, page_size, n_kv, hd)
-
-    def kv_map(b_, p_, pt, cl, sh):
-        # Foreign pages clamp to slot 0 — the ownership predicate skips
-        # their accumulation, so the fetched block is never read.
-        local = pt[b_, p_] - sh[0] * pages_local
-        return (jnp.clip(local, 0, pages_local - 1), 0, 0, 0)
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
-        grid=(b, pages_per_seq),
-        in_specs=[
-            pl.BlockSpec((1, n_q, hd), lambda b_, p_, pt, cl, sh: (b_, 0, 0)),
-            pl.BlockSpec((1, page_size, n_kv, hd), kv_map),
-            pl.BlockSpec((1, page_size, n_kv, hd), kv_map),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, n_q, hd), lambda b_, p_, pt, cl, sh: (b_, 0, 0)),
-            pl.BlockSpec((1, n_q, 128), lambda b_, p_, pt, cl, sh: (b_, 0, 0)),
-            pl.BlockSpec((1, n_q, 128), lambda b_, p_, pt, cl, sh: (b_, 0, 0)),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((n_q, 128), jnp.float32),
-            pltpu.VMEM((n_q, 128), jnp.float32),
-            pltpu.VMEM((n_q, hd), jnp.float32),
-        ],
-    )
-    kernel = functools.partial(
-        _decode_kernel_partial, page_size=page_size, n_kv=n_kv, group=group,
-        pages_per_seq=pages_per_seq, pages_local=pages_local,
-    )
-    acc, m, l = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((b, n_q, hd), jnp.float32),
-            jax.ShapeDtypeStruct((b, n_q, 128), jnp.float32),
-            jax.ShapeDtypeStruct((b, n_q, 128), jnp.float32),
-        ],
-        interpret=interpret,
-    )(page_tables, ctx_lens, my_pg.reshape(1), q, k_pages, v_pages)
-    return acc, m[..., 0], l[..., 0]
+    """Flash partials over a LOCAL page slice (the kv-split walk of
+    :func:`_decode_walk_kernel`); returns (acc, m, l), the shard_map
+    wrapper's inputs (``parallel/kv_split.py`` merges across the ``seq``
+    axis and normalizes)."""
+    acc, m, l = _decode_walk(q, k_local, v_local, page_tables, ctx_lens,
+                             page_size, interpret, shard=my_pg,
+                             pages_local=pages_local)
+    return acc, m[..., 0], l[..., 0]  # m/l are lane-padded: column 0
 
 
 # --------------------------------------------------------------------- TP ---

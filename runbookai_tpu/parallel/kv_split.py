@@ -253,9 +253,10 @@ def paged_decode_attention_kv_split_pallas(
     interpret: bool = False,
 ) -> jnp.ndarray:
     """Decode attention on the page-split pool via the Pallas partial
-    kernel: each device runs ``_decode_kernel_partial`` over its OWN page
-    slice (ownership-masked, locally-indexed scalar-prefetch maps) and
-    the flash partials merge across ``seq`` exactly like the XLA path."""
+    kernel: each device runs the kv-split walk of ``_decode_walk_kernel``
+    over its OWN page slice (pages it does not own are neither fetched
+    nor counted; owned ones are indexed locally) and the flash partials
+    merge across ``seq`` exactly like the XLA path."""
     from runbookai_tpu.ops.paged_attention_pallas import (
         paged_decode_attention_partial,
     )

@@ -39,6 +39,7 @@ def rec(i, kind="decode", **kw):
                        "fetch": 0.0, "emit": 0.0, "draft": 0.0,
                        "other": 0.0005},
             "program": ["_decode_multi"], "k": 8, "rows": 1,
+            "kv_pages_live": 3,
             "prefill_tokens": 0, "decode_tokens": 2, "compile_s": 0.0,
             "admitted": [], "finished": []}
     base.update(kw)

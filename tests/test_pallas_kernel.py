@@ -66,6 +66,164 @@ def test_pallas_decode_null_pages_are_masked():
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), rtol=2e-5, atol=2e-5)
 
 
+# ------------------------------------------------------------ the page walk
+#
+# The decode kernel walks each row's live pages inside the kernel, several a
+# step (``decode_pages_per_step``: a value of the shapes), so the cases are
+# laid against the step: a context of exactly one step, one more, the whole
+# table. Every pool goes through the same cases: raw pages, int8 pages with
+# scales, and a two-shard page split whose partials are merged here as
+# ``parallel/kv_split.py`` merges them across the mesh.
+
+WALK_PS = 8
+
+
+def _walk_case(ctx_lens, n_kv, group, seed=0, hd=128):
+    """Rows of ``ctx_lens`` tokens over a pool in which every page a row
+    does NOT own is poison (NaN; an int8 pool's scales), and every table
+    column past a row's live pages points at one: fetching a dead column,
+    even to mask it, turns the output into NaN."""
+    from runbookai_tpu.ops.paged_attention_pallas import decode_pages_per_step
+
+    rng = np.random.default_rng(seed)
+    g = decode_pages_per_step(WALK_PS, n_kv, hd, jnp.float32, 10**6)
+    width = 2 * g + 1  # two steps and a page: no multiple of the step
+    live = [-(-c // WALK_PS) for c in ctx_lens]
+    num_pages = 2 * (1 + sum(live) // 2 + 1)  # even: two shards of pages
+    k = np.full((num_pages * WALK_PS, n_kv, hd), np.nan, np.float32)
+    v = np.full_like(k, np.nan)
+    order = rng.permutation(np.arange(1, num_pages))  # page 0 is null
+    tables = np.full((len(ctx_lens), width), order[-1], np.int32)  # poison
+    nxt = 0
+    for i, ctx in enumerate(ctx_lens):
+        for col in range(live[i]):
+            page = order[nxt]
+            nxt += 1
+            tables[i, col] = page
+            rows = slice(page * WALK_PS, (page + 1) * WALK_PS)
+            k[rows] = rng.normal(size=(WALK_PS, n_kv, hd))
+            v[rows] = rng.normal(size=(WALK_PS, n_kv, hd))
+    assert nxt < num_pages - 1  # the poison page stays poison
+    q = jnp.asarray(rng.normal(size=(len(ctx_lens), n_kv * group, hd)),
+                    jnp.float32)
+    return (q, jnp.asarray(k), jnp.asarray(v), jnp.asarray(tables),
+            jnp.asarray(ctx_lens, jnp.int32), g, width)
+
+
+def _walk(pool, q, k, v, tables, ctx, interpret=True):
+    """(kernel output, XLA reference) for one kind of pool."""
+    from runbookai_tpu.ops.attention import quantize_kv
+    from runbookai_tpu.ops.paged_attention_pallas import (
+        paged_decode_attention_partial,
+    )
+
+    qpos = jnp.maximum(ctx - 1, 0)[:, None]
+    if pool == "int8":
+        # Quantize the written pages; a poison page keeps NaN SCALES.
+        dead = jnp.isnan(k[:, :1, :1])
+        (kq, ks), (vq, vs) = (quantize_kv(jnp.nan_to_num(a)) for a in (k, v))
+        k = (kq, jnp.where(dead[..., 0], jnp.nan, ks))
+        v = (vq, jnp.where(dead[..., 0], jnp.nan, vs))
+    # The XLA gather reads dead columns (it masks them afterwards), so the
+    # reference reads a pool whose poison is zeros.
+    clean = jax.tree.map(jnp.nan_to_num, (k, v))
+    want = paged_attention(q[:, None], *clean, tables, ctx, qpos,
+                           page_size=WALK_PS, block_pages=4)[:, 0]
+    if pool != "partial":
+        return paged_decode_attention(q, k, v, tables, ctx,
+                                      page_size=WALK_PS,
+                                      interpret=interpret), want
+    shards, tokens_local = 2, k.shape[0] // 2
+    parts = [paged_decode_attention_partial(
+        q, k[s * tokens_local:(s + 1) * tokens_local],
+        v[s * tokens_local:(s + 1) * tokens_local], tables, ctx,
+        jnp.int32(s), page_size=WALK_PS,
+        pages_local=tokens_local // WALK_PS, interpret=interpret)
+        for s in range(shards)]
+    m_g = jnp.maximum(parts[0][1], parts[1][1])  # parallel/kv_split.py's merge
+    corr = [jnp.exp(m - m_g) for _, m, _ in parts]
+    l_g = sum(c * l for c, (_, _, l) in zip(corr, parts))
+    acc_g = sum(c[..., None] * acc for c, (acc, _, _) in zip(corr, parts))
+    return acc_g / jnp.maximum(l_g[..., None], 1e-30), want
+
+
+def _assert_walk(pool, ctx_lens, n_kv, group, **kw):
+    q, k, v, tables, ctx, _, _ = _walk_case(ctx_lens, n_kv, group)
+    got, want = _walk(pool, q, k, v, tables, ctx, **kw)
+    got, want = np.asarray(got), np.asarray(want)
+    live = np.asarray(ctx_lens) > 0
+    assert np.all(got[~live] == 0.0)  # an empty slot writes zeros
+    np.testing.assert_allclose(got[live], want[live], rtol=2e-5, atol=2e-5)
+
+
+WALK_POOLS = ["raw", "int8", "partial"]
+
+
+@pytest.mark.parametrize("pool", WALK_POOLS)
+def test_walk_skips_empty_rows(pool):
+    """``ctx == 0`` at row 0, in the middle and at the end."""
+    _assert_walk(pool, [0, 9, 0, 0, 33, 0], n_kv=4, group=2)
+
+
+@pytest.mark.parametrize("pool", WALK_POOLS)
+@pytest.mark.parametrize("ctx_of", [
+    "one", "page", "page+1", "step", "step+1", "table"])
+def test_walk_context_edges(pool, ctx_of):
+    """A context of 1, a page, a page + 1, exactly one step of the walk,
+    one step + 1, and the whole table, beside a short row."""
+    from runbookai_tpu.ops.paged_attention_pallas import decode_pages_per_step
+
+    g = decode_pages_per_step(WALK_PS, 4, 128, jnp.float32, 10**6)
+    ctx = {"one": 1, "page": WALK_PS, "page+1": WALK_PS + 1,
+           "step": g * WALK_PS, "step+1": g * WALK_PS + 1,
+           "table": (2 * g + 1) * WALK_PS}[ctx_of]
+    _assert_walk(pool, [ctx, 5], n_kv=4, group=2)
+
+
+@pytest.mark.parametrize("pool", WALK_POOLS)
+@pytest.mark.parametrize("n_kv", [4, 1])
+def test_walk_gqa_group_seven(pool, n_kv):
+    """Qwen2.5-7B's group of 7: four KV heads, and the one a tp 4 shard
+    holds."""
+    _assert_walk(pool, [3, 0, 150, 41], n_kv=n_kv, group=7)
+
+
+def test_walk_never_fetches_a_dead_column():
+    """The poison the cases above lay is live: a table that points a LIVE
+    column at the poison page does turn the row into NaN, so a kernel that
+    fetched dead columns would have failed them."""
+    q, k, v, tables, ctx, _, _ = _walk_case([20, 5], n_kv=4, group=2)
+    poison = tables[0, -1]
+    got = paged_decode_attention(q, k, v, tables.at[0, 1].set(poison), ctx,
+                                 page_size=WALK_PS, interpret=True)
+    assert np.isnan(np.asarray(got[0])).all()
+    assert not np.isnan(np.asarray(got[1])).any()
+
+
+@pytest.mark.parametrize("pool", WALK_POOLS)
+def test_walk_under_the_tpu_interpreter(pool):
+    """The same walk where never-written VMEM reads as NaN and the copies
+    and their semaphores are simulated (``pltpu.InterpretParams``): a
+    group's unfetched tail must not reach the output."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    _assert_walk(pool, [0, 11, 150], n_kv=4, group=2,
+                 interpret=pltpu.InterpretParams())
+
+
+def test_walk_pages_per_step_follows_the_shapes():
+    """Pages a step is a value of the page's bytes, the step's positions
+    and the table, not an option."""
+    from runbookai_tpu.ops.paged_attention_pallas import decode_pages_per_step
+
+    assert decode_pages_per_step(16, 4, 128, jnp.bfloat16, 513) == 16
+    assert decode_pages_per_step(16, 8, 128, jnp.bfloat16, 513) == 8
+    assert decode_pages_per_step(16, 1, 128, jnp.bfloat16, 513) == 32
+    assert decode_pages_per_step(16, 4, 128, jnp.int8, 513) == 32
+    assert decode_pages_per_step(4, 2, 32, jnp.float32, 8) == 8
+    assert decode_pages_per_step(16, 64, 256, jnp.float32, 513) == 1
+
+
 def _build_pool(rng, ctx_lens_list, n_kv, hd, ps, pages, max_pages):
     kf = jnp.zeros((pages * ps, n_kv, hd), jnp.float32)
     vf = jnp.zeros((pages * ps, n_kv, hd), jnp.float32)

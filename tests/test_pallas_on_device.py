@@ -13,6 +13,8 @@ Run on the chip (the conftest otherwise forces the CPU):
     RUNBOOK_ON_DEVICE=1 python -m pytest tests/test_pallas_on_device.py -q
 """
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -335,6 +337,16 @@ def test_fp8_engine_pallas_on_device():
     assert len(req.out_ids) == 8
 
 
+def _merge_partials(parts):
+    """The cross-shard flash merge of ``parallel/kv_split.py`` (a psum
+    under shard_map in serving), on the host: (acc, m, l) a shard."""
+    m_g = functools.reduce(jnp.maximum, [m for _, m, _ in parts])
+    corr = [jnp.exp(m - m_g) for _, m, _ in parts]
+    l_g = sum(c * l for c, (_, _, l) in zip(corr, parts))
+    acc_g = sum(c[..., None] * acc for c, (acc, _, _) in zip(corr, parts))
+    return acc_g / jnp.maximum(l_g[..., None], 1e-30)
+
+
 def test_kv_split_partial_kernel_on_device():
     """Mosaic compiles the ownership-masked partial decode kernel; the
     two-shard merge (host-side here, psum under shard_map in serving)
@@ -364,12 +376,7 @@ def test_kv_split_partial_kernel_on_device():
         parts.append(paged_decode_attention_partial(
             q, k_l, v_l, tables, ctx, jnp.int32(s), page_size=PS,
             pages_local=pages_local, interpret=False))
-    m_g = jnp.maximum(parts[0][1], parts[1][1])
-    corr = [jnp.exp(p[1] - m_g) for p in parts]
-    l_g = sum(c * p[2] for c, p in zip(corr, parts))
-    acc_g = sum(c[..., None] * p[0] for c, p in zip(corr, parts))
-    got = acc_g / jnp.maximum(l_g[..., None], 1e-30)
-    np.testing.assert_allclose(np.asarray(got, np.float32),
+    np.testing.assert_allclose(np.asarray(_merge_partials(parts), np.float32),
                                np.asarray(want, np.float32),
                                atol=2e-2, rtol=2e-2)
 
@@ -546,6 +553,71 @@ def test_decode_kernel_at_serving_shapes(n_q, n_kv, hd):
                            (ctx - 1)[:, None], page_size=PS)[:, 0]
     np.testing.assert_allclose(np.asarray(got, np.float32),
                                np.asarray(want, np.float32), **ATTN_TOL)
+
+
+# The benchmark cell's dispatch: 16 slots of which six or so hold a request
+# (the others read 0..7 over an 8-pass window), a table 513 columns wide,
+# Qwen2.5-7B's 28 / 4 heads — and one row at the full 8192 tokens. Pages a
+# row does not own are poison (NaN; an int8 pool's scales) and so is every
+# table column past a row's live pages: the walk may not fetch them.
+CELL_CTX = [430, 3, 0, 612, 5, 250, 0, 1, 8192, 7, 0, 520, 2, 0, 260, 4]
+
+
+def _cell_case(rng, n_q=28, n_kv=4, hd=128, num_pages=1024):
+    live = [-(-c // PS) for c in CELL_CTX]
+    k = np.full((num_pages * PS, n_kv, hd), np.nan, np.float32)
+    v = np.full_like(k, np.nan)
+    order = rng.permutation(np.arange(1, num_pages))
+    tables = np.full((len(CELL_CTX), SERVE_MAX_PAGES), order[-1], np.int32)
+    nxt = 0
+    for i, n in enumerate(live):
+        for col in range(n):
+            page = order[nxt]
+            nxt += 1
+            tables[i, col] = page
+            rows = slice(page * PS, (page + 1) * PS)
+            k[rows] = rng.normal(size=(PS, n_kv, hd))
+            v[rows] = rng.normal(size=(PS, n_kv, hd))
+    assert nxt < num_pages - 1
+    q = jnp.asarray(rng.normal(size=(len(CELL_CTX), n_q, hd)), jnp.bfloat16)
+    return (q, jnp.asarray(k, jnp.bfloat16), jnp.asarray(v, jnp.bfloat16),
+            jnp.asarray(tables), jnp.asarray(CELL_CTX, jnp.int32))
+
+
+@pytest.mark.usefixtures("serving_precision")
+@pytest.mark.parametrize("pool", ["raw", "int8", "partial"])
+def test_decode_walk_at_the_cells_shape(pool):
+    from runbookai_tpu.ops.attention import quantize_kv
+    from runbookai_tpu.ops.paged_attention_pallas import (
+        paged_decode_attention_partial,
+    )
+
+    q, k, v, tables, ctx = _cell_case(np.random.default_rng(13))
+    tol = ATTN_TOL
+    if pool == "int8":
+        dead = jnp.isnan(k[:, :, 0].astype(jnp.float32))
+        (kq, ks), (vq, vs) = (quantize_kv(jnp.nan_to_num(a)) for a in (k, v))
+        k = (kq, jnp.where(dead, jnp.nan, ks))
+        v = (vq, jnp.where(dead, jnp.nan, vs))
+        tol = dict(atol=5e-2, rtol=5e-2)
+    # XLA's gather reads dead columns (and masks them afterwards).
+    clean = jax.tree.map(jnp.nan_to_num, (k, v))
+    want = paged_attention(q[:, None], *clean, tables, ctx,
+                           jnp.maximum(ctx - 1, 0)[:, None], page_size=PS)[:, 0]
+    if pool == "partial":
+        half = k.shape[0] // 2
+        parts = [paged_decode_attention_partial(
+            q, k[s * half:(s + 1) * half], v[s * half:(s + 1) * half], tables,
+            ctx, jnp.int32(s), page_size=PS, pages_local=half // PS,
+            interpret=False) for s in range(2)]
+        got = _merge_partials(parts)
+    else:
+        got = paged_decode_attention(q, k, v, tables, ctx, page_size=PS,
+                                     interpret=False)
+    got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
+    live = np.asarray(CELL_CTX) > 0
+    assert np.all(got[~live] == 0.0)  # an empty slot writes zeros
+    np.testing.assert_allclose(got[live], want[live], **tol)
 
 
 @pytest.mark.usefixtures("serving_precision")

@@ -32,8 +32,8 @@ from runbookai_tpu.models.llama import CONFIGS, init_params
 from runbookai_tpu.utils.tokens import ByteTokenizer
 
 NEW_FIELDS = ("t_start", "t_end", "phases", "program", "k", "rows",
-              "prefill_tokens", "decode_tokens", "compile_s", "admitted",
-              "finished")
+              "kv_pages_live", "prefill_tokens", "decode_tokens", "compile_s",
+              "admitted", "finished")
 ORDER = ("t_received", "t_enqueued", "t_admitted", "t_first_token",
          "t_finished")
 
@@ -83,13 +83,13 @@ def test_a_phase_inside_another_pauses_it():
     step.exit()
     enclosed = time.monotonic() - step.t_start
     step.dispatched("_prefill_step")
-    step.dispatched("_decode_multi", 8, 3)
+    step.dispatched("_decode_multi", 8, 3, kv_pages_live=17)
     build, fetch = step.phases["build"], step.phases["fetch"]
     assert build >= 0.01 and fetch >= 0.02
     assert build + fetch <= enclosed  # build is NOT the whole it enclosed
     assert sum(step.phases.values()) == build + fetch
-    assert (step.programs, step.k, step.rows) == (
-        ["_prefill_step", "_decode_multi"], 8, 3)
+    assert (step.programs, step.k, step.rows, step.kv_pages_live) == (
+        ["_prefill_step", "_decode_multi"], 8, 3, 17)
 
 
 def check_steps(steps: list[dict]) -> None:
@@ -101,7 +101,7 @@ def check_steps(steps: list[dict]) -> None:
         assert abs(sum(s["phases"].values()) - s["wall_s"]) < 1e-3
         assert abs((s["t_end"] - s["t_start"]) - s["wall_s"]) < 1e-3
         assert s["tokens"] == s["prefill_tokens"] + s["decode_tokens"]
-        assert (s["rows"] > 0) == (s["k"] > 0)
+        assert (s["rows"] > 0) == (s["k"] > 0) == (s["kv_pages_live"] > 0)
         assert all(p.startswith("_") for p in s["program"])
         assert bool(s["program"]) == (s["kind"] != "idle")
     ends = [(s["t_start"], s["t_end"]) for s in steps]
@@ -147,6 +147,41 @@ def test_every_step_is_a_span_and_every_request_retires_once(parts):
         assert 0.0 < f["max_emit_gap_s"] <= f["t_finished"] - f["t_first_token"]
     assert core.metrics["compile_time_s"] == pytest.approx(
         sum(s["compile_s"] for s in steps), abs=1e-4)
+
+
+@pytest.mark.parametrize("steps_per_dispatch", [1, 4])
+def test_the_record_counts_the_pages_its_rows_hold(parts, monkeypatch,
+                                                   steps_per_dispatch):
+    """``kv_pages_live`` is Σ cdiv(ctx, page_size) over the context lengths
+    the decode program was GIVEN (what the decode kernel's page walk
+    reads), an empty slot counting nothing: checked against the arrays the
+    dispatches received, one a record, in order."""
+    import numpy as np
+
+    from runbookai_tpu.engine import engine
+
+    given: list[tuple[str, int]] = []
+    for name in ("_decode_step", "_decode_multi"):
+        def spy(*args, _real=getattr(engine, name), _name=name, **kw):
+            ctx_lens = np.asarray(args[7])  # params, cfg, tokens, positions,
+            given.append((_name, sum(       # kv_k, kv_v, tables, ctx_lens
+                -(-int(c) // 4) for c in ctx_lens if c > 0)))
+            return _real(*args, **kw)
+        monkeypatch.setattr(engine, name, spy)
+
+    core = make_core(parts, decode_steps_per_dispatch=steps_per_dispatch,
+                     mixed_dispatch=False)
+    assert core.ecfg.page_size == 4
+    for text, n in ((b"a prompt of twenty-two", 9), (b"short", 5), (b"x", 12)):
+        core.submit(request(text, n=n))
+    core.run_until_idle()
+    steps = core.flight.snapshot()
+    check_steps(steps)
+    recorded = [(p, s["kv_pages_live"]) for s in steps
+                for p in s["program"] if p in ("_decode_step", "_decode_multi")]
+    assert recorded == given and len(given) >= 3
+    assert max(pages for _, pages in given) >= 6 + 2 + 1  # all three rows
+    assert all(s["kv_pages_live"] == 0 for s in steps if not s["k"])
 
 
 def test_the_summary_reads_the_dispatch_fields(parts):
