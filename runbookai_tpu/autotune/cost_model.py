@@ -130,7 +130,8 @@ class Hardware:
                 "host_ram_bytes": self.host_ram_bytes}
 
 
-# Spec-sheet envelopes (bench.py carries the same peak-FLOPs table); "cpu"
+# Spec-sheet envelopes (benchmark/peaks.json holds the chip's for the
+# runner; ROADMAP C8); "cpu"
 # is deliberately pessimistic — it exists so the CPU smoke path orders
 # candidates sanely, not to predict CPU tok/s.
 HARDWARE: dict[str, Hardware] = {
@@ -325,7 +326,7 @@ class CostModel:
             plan = self.residency(cand, max_seq_len=ctx)
         # Every co-resident replica pins its OWN tier; budget the worst
         # case of all dp replicas sharing one host (single-host fleets —
-        # the CPU/bench shape — and the conservative bound for pods).
+        # the CPU shape — and the conservative bound for pods).
         spill_total = plan.host_spill_bytes * max(1, cand.dp_replicas)
         if spill_total > self.hw.host_ram_bytes // 2:
             return False, (
@@ -430,7 +431,7 @@ class CostModel:
 @dataclass(frozen=True)
 class SearchSpace:
     """Axis values the sweep enumerates (cartesian product, then pruned).
-    Defaults cover the hand-picked regimes BENCHLOG has actually A/B'd."""
+    Defaults cover the hand-picked regimes that were A/B'd by hand."""
 
     page_size: tuple[int, ...] = (16,)
     num_pages: tuple[int, ...] = (1024, 2048, 4096)

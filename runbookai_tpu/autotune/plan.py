@@ -2,7 +2,7 @@
 
 A plan is one JSON document per model×topology that pins every engine knob
 the sweep decided, with provenance (cost-model scores, measured figures,
-git sha) so a banked bench number can always be traced back to the exact
+git sha) so a measured figure can always be traced back to the exact
 config that produced it — the FlashInfer-Bench artifact-driven loop
 (PAPERS.md) applied to this engine's knob space.
 
@@ -12,8 +12,8 @@ Consumers:
   values become the defaults; keys the operator set explicitly in YAML
   still win (:func:`apply_plan_to_llm` reads pydantic's
   ``model_fields_set`` for exactly that precedence).
-- ``bench.py --plan PATH`` — every bench arm can pin its exact config and
-  records the plan id/hash in its artifact.
+- ``EngineConfig.from_plan`` — the engine block as a config, for callers
+  that build a core themselves (the tuner's measured arms).
 - ``runbook plan show|validate`` — operator inspection; tier-1 validates
   every checked-in ``plans/*.json`` against this schema.
 
@@ -36,8 +36,8 @@ PLAN_SCHEMA_VERSION = 1
 # Engine-block keys a plan may carry, mapped 1:1 onto EngineConfig fields
 # (kv_dtype travels as a string; EngineConfig.from_plan resolves it).
 # Slot/page values are PER REPLICA when dp_replicas > 1 — the EngineConfig
-# / llm.* contract, honored identically by the tuner's measured arms,
-# bench --plan, and from_config.
+# / llm.* contract, honored identically by the tuner's measured arms
+# and from_config.
 ENGINE_PLAN_KEYS = frozenset({
     "page_size", "num_pages", "max_batch_slots", "prefill_chunk",
     "max_seq_len", "block_pages", "decode_steps_per_dispatch",
@@ -258,7 +258,7 @@ def apply_plan_to_llm(llm_cfg, plan: PlanArtifact):
     if "kv_dtype" in plan.engine and "kv_cache_dtype" not in explicit:
         # 1:1 spelling — llm.kv_cache_dtype accepts the full plan set,
         # and engine.resolve_kv_dtype gives every consumer (llm.plan,
-        # bench --plan, from_plan) the same pool for the same string
+        # from_plan) the same pool for the same string
         # ("bf16" pins bfloat16 even on float32 activations; "auto"
         # follows them).
         updates["kv_cache_dtype"] = plan.engine["kv_dtype"]
@@ -281,8 +281,7 @@ def engine_only_overrides(plan: PlanArtifact) -> dict[str, Any]:
 
 
 def engine_config_dict(ecfg) -> dict[str, Any]:
-    """JSON-safe dump of a resolved EngineConfig (bench artifacts, plan
-    provenance): every dataclass field, kv_dtype as its dtype name."""
+    """JSON-safe dump of a resolved EngineConfig (plan provenance): every dataclass field, kv_dtype as its dtype name."""
     import jax.numpy as jnp
 
     out: dict[str, Any] = {}

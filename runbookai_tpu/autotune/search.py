@@ -10,9 +10,10 @@ AIConfigurator's two-stage loop (PAPERS.md) over this engine's knobs:
 
 2. **Measured refinement** — run each survivor (plus the hand-picked
    baseline, so a shipped plan can never regress it) through a short
-   in-process serving run reusing bench.py's harness: same warmup-then-
-   reset protocol, same counters, same deterministic prompt stream. The
-   best *measured* candidate becomes the plan.
+   in-process serving run: a deterministic prompt stream, a warm-up that
+   compiles every program shape, ``EngineCore.reset_metrics()``, then
+   the window the figures are read from. The best *measured* candidate
+   becomes the plan.
 
 The output is a :class:`~runbookai_tpu.autotune.plan.PlanArtifact` with
 full provenance: cost-model scores, per-candidate measured figures, the
@@ -42,25 +43,6 @@ from runbookai_tpu.autotune.plan import (
     git_sha,
     save_plan,
 )
-
-
-def _bench_module():
-    """bench.py's harness helpers, importable both from a repo checkout
-    (tests put the root on sys.path) and an installed package."""
-    try:
-        import bench  # repo root on sys.path (tests, source checkouts)
-
-        return bench
-    except ImportError:
-        import importlib.util
-
-        path = Path(__file__).resolve().parents[2] / "bench.py"
-        spec = importlib.util.spec_from_file_location("bench", path)
-        if spec is None or spec.loader is None:
-            raise ImportError(f"bench.py not found at {path}")
-        mod = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(mod)
-        return mod
 
 
 # ------------------------------------------------------------- analytic
@@ -105,16 +87,15 @@ def measure_candidate(model_cfg, params, tokenizer, cand: Candidate,
                       new_tokens: int = 16, seed: int = 0,
                       attn_impl: str = "xla",
                       qmm_impl: str = "xla") -> dict[str, Any]:
-    """One short measured serving run of ``cand`` — bench.py's protocol
-    in-process: deterministic prompts, warmup to compile every program
-    shape, counter reset (``bench.reset_warmup_metrics``), then the
-    measured window. Returns the figures a plan's provenance records."""
+    """One short measured serving run of ``cand``, in-process:
+    deterministic prompts, warmup to compile every program shape,
+    counter reset (``EngineCore.reset_metrics``), then the measured
+    window. Returns the figures a plan's provenance records."""
     import numpy as np
 
     from runbookai_tpu.engine.engine import EngineConfig, EngineCore
     from runbookai_tpu.engine.request import EngineRequest, SamplingParams
 
-    bench = _bench_module()
     ecfg = EngineConfig.from_plan(
         cand.engine_plan_block(),
         default_kv_dtype=params["embed"].dtype,
@@ -132,13 +113,13 @@ def measure_candidate(model_cfg, params, tokenizer, cand: Candidate,
 
     if cand.dp_replicas > 1:
         return _measure_fleet(model_cfg, params, tokenizer, ecfg,
-                              make_req, bench, n_requests=n_requests)
+                              make_req, n_requests=n_requests)
 
     core = EngineCore(model_cfg, params, tokenizer, ecfg)
     for _ in range(min(ecfg.max_batch_slots, n_requests)):
         core.submit(make_req())
     core.run_until_idle()
-    bench.reset_warmup_metrics(core)
+    core.reset_metrics()
 
     reqs = [make_req() for _ in range(n_requests)]
     t0 = time.perf_counter()
@@ -169,8 +150,8 @@ def measure_candidate(model_cfg, params, tokenizer, cand: Candidate,
     }
 
 
-def _measure_fleet(model_cfg, params, tokenizer, ecfg, make_req, bench,
-                   *, n_requests: int) -> dict[str, Any]:
+def _measure_fleet(model_cfg, params, tokenizer, ecfg, make_req, *,
+                   n_requests: int) -> dict[str, Any]:
     """The dp>1 measured arm: a candidate's slots/pages are PER REPLICA
     (the same contract as ``llm.*`` config and ``EngineConfig`` — so a
     plan applied via ``llm.plan`` serves exactly the budget the sweep
@@ -191,7 +172,7 @@ def _measure_fleet(model_cfg, params, tokenizer, ecfg, make_req, bench,
             core.submit(make_req())
     for core in cores:
         core.run_until_idle()
-        bench.reset_warmup_metrics(core)
+        core.reset_metrics()
 
     fleet = AsyncFleet(cores)
     reqs = [make_req() for _ in range(n_requests)]

@@ -11,8 +11,9 @@ Two halves, composable but independent:
   failover through the router's retry path, online replica rebuild
   (``AsyncFleet.rebuild_replica``) and hysteresis-guarded rejoin.
 
-The ``bench.py --soak-scenarios`` arm drives both against the full
-composed stack and gates on production invariants (zero lost requests
+The soak gate (:mod:`~runbookai_tpu.chaos.soak`; ``python -m
+runbookai_tpu.chaos.soak``) drives both against the full composed stack
+and returns a verdict per production invariant (zero lost requests
 outside fault windows, TTFT bounds, fairness, RSS/fd bounds, seeded
 digest determinism) — the serving twin of tier-1.
 """

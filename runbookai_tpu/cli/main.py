@@ -767,8 +767,8 @@ def cmd_metrics(args) -> int:
             summary = {k: v for k, v in summary.items() if args.span in k}
         else:
             # Dispatch-kind counters (PR 4 attribution) recovered from the
-            # trace alone — a tune run's measured refinement (or any bench
-            # arm) is sanity-checkable without its Prometheus scrape: zero
+            # trace alone — a tune run's measured refinement is
+            # sanity-checkable without its Prometheus scrape: zero
             # engine.mixed spans under a mixed-dispatch plan is a lie.
             summary["dispatch_counters"] = dispatch_counters(spans)
             # Queue-wait and router-placement live in EVENT meta (ms=0),
@@ -1513,14 +1513,6 @@ def cmd_plan(args) -> int:
     return 1
 
 
-def cmd_bench(args) -> int:
-    import runpy
-
-    runpy.run_path(str(Path(__file__).resolve().parents[2] / "bench.py"),
-                   run_name="__main__")
-    return 0
-
-
 def cmd_weights(args) -> int:
     from runbookai_tpu.models.checkpoint import (
         checkpoint_config,
@@ -1850,9 +1842,6 @@ def build_parser() -> argparse.ArgumentParser:
     serve.add_argument("--allow-adapter-loading", action="store_true",
                        help="enable POST /v1/adapters (operator action)")
     serve.set_defaults(fn=cmd_serve)
-
-    bench = sub.add_parser("bench", help="serving benchmark (one JSON line)")
-    bench.set_defaults(fn=cmd_bench)
 
     tune = sub.add_parser(
         "tune", help="serving-plan autotuner: cost-model sweep + measured "

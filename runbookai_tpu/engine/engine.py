@@ -166,8 +166,7 @@ class EngineConfig:
     # Flight recorder (engine/flight_recorder.py): retain the last N
     # per-step records (dispatch kind, tokens, occupancy, queue depth,
     # KV pressure, wall split) in a preallocated ring — O(1) append off
-    # the hot path, surfaced via GET /debug/steps and bench's
-    # flight_summary. 0 disables recording entirely.
+    # the hot path, surfaced via GET /debug/steps. 0 disables recording entirely.
     flight_recorder_steps: int = 512
     # Host-RAM spill tier (engine/kv_cache.HostSpillTier): retain up to
     # this many evicted prefix-cache pages in host memory so a re-sent
@@ -226,7 +225,7 @@ class EngineConfig:
 
 def resolve_kv_dtype(name: Optional[str], default: Any) -> Any:
     """The ONE resolver for every kv-dtype spelling a plan or config can
-    carry: ``bench --plan``, :meth:`EngineConfig.from_plan` and
+    carry: :meth:`EngineConfig.from_plan` and
     ``from_config`` must allocate the same pool for the same string.
     "auto"/empty/None follow ``default`` (the activation dtype); "bf16"
     pins a bfloat16 pool even on float32 activations; unknown names
@@ -858,7 +857,7 @@ class EngineCore:
         self.mask_fn = mask_fn
         self.advance_fn = advance_fn
         # The config is resolved ONCE, here, into what the compiled steps
-        # will run; self.ecfg after this block is what /healthz, bench and
+        # will run; self.ecfg after this block is what /healthz and
         # chip_smoke report. Two kinds of rule apply, and only two:
         # static ones (a combination the forward has no kernel plumbing
         # for — the same conditions models/llama.py tests at trace time)
@@ -1036,7 +1035,7 @@ class EngineCore:
         self._drain_time_acc = 0.0
         # Serving metrics (BASELINE.md contract: TTFT + tokens/sec/chip).
         # This dict stays the single source of truth for the step counters
-        # (/healthz contract, bench resets, tests); the registry re-exports
+        # (/healthz contract, reset_metrics, tests); the registry re-exports
         # it via scrape-time callbacks in _install_metrics.
         # decode_time_s remains the total decode wall; the dispatch/host/
         # overlap components split it so the pipeline's win is attributable
@@ -1112,7 +1111,7 @@ class EngineCore:
         there is exactly one source of truth and zero per-step overhead.
         Registration is get-or-create and ``set_function`` replaces the
         previous callback, so rebuilding an engine in-process (tests,
-        bench children) re-binds the gauges to the newest core. A
+        a supervisor's rebuild) re-binds the gauges to the newest core. A
         standalone engine also clears any per-replica labeled callbacks a
         previous FLEET left behind (fleet.py's ``_install_metrics``
         re-binds them when a fleet is current): without this, falling
@@ -1231,6 +1230,22 @@ class EngineCore:
         return cached / total if total else 0.0
 
     # ------------------------------------------------------------------ API
+
+    def reset_metrics(self) -> None:
+        """Forget what was counted so far: step counters, the TTFT and
+        TPOT histograms and the flight ring restart from zero. Callers
+        that warm an idle engine up before a window they read (the
+        autotuner's measured refinement, the soak gate) call this between
+        the two; nothing on the serving path does."""
+        for key, value in self.metrics.items():
+            self.metrics[key] = type(value)()
+        # The flight recorder reports page-transfer DELTAS against this
+        # mark; zeroing the counters without it would make the next
+        # recorded step report a negative import delta.
+        self._flight_kv_mark = (0, 0)
+        self.hist_ttft.reset()
+        self.hist_tpot.reset()
+        self.flight.reset()
 
     def refresh_lora(self) -> None:
         """Pick up adapters registered after engine construction."""

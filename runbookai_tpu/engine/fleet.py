@@ -172,9 +172,10 @@ def split_engine_budget(engine_cfg: EngineConfig, dp: int) -> EngineConfig:
 
     The split is exact, never rounded UP past the total (a floor that
     rounded the per-replica pool up would hand a dp arm more aggregate
-    pages than dp=1 and fake a win via fewer preemptions — the bench
-    --dp arm's fixed-total-budget contract, and this helper's ONLY
-    caller). Plan artifacts and the autotuner's measured arms carry
+    pages than dp=1 and fake a win via fewer preemptions — a
+    fixed-total-budget A/B's contract; no caller is left in the package
+    since the dp arm that made one went, ROADMAP C19). Plan artifacts
+    and the autotuner's measured arms carry
     PER-REPLICA slot/page budgets already (the llm.*/EngineConfig
     contract) and must never pass through this split.
     Allocator minimums: 1 slot, 2 pages per replica.
@@ -412,7 +413,7 @@ class AsyncFleet:
         # The handoff IS a pull, so disaggregation forces page sharing on.
         self._kv_share = bool(self.cfg.kv_share or n_pf)
         # Router state below is mutated ONLY under this lock (routing runs
-        # on event-loop threads and, for bench/eval drivers, possibly
+        # on event-loop threads and, for eval drivers, possibly
         # several of them).
         self._lock = threading.Lock()
         self._routed = [0] * self.dp

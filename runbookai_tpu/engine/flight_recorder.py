@@ -29,7 +29,7 @@ Design constraints (pinned by ``tests/test_observability.py``):
   ~50 steps/s stays a few MB regardless of run length.
 - **Dumpable**: :meth:`snapshot` (newest-last dicts) for ``/debug/steps``
   and the AsyncFleet aggregation, :meth:`dump_jsonl` for offline diffing,
-  :meth:`summary` for bench's ``flight_summary`` provenance block.
+  :meth:`summary` for a window's step-level roll-up.
 """
 
 from __future__ import annotations
@@ -174,8 +174,9 @@ class FlightRecorder:
         self._next += 1
 
     def reset(self) -> None:
-        """Drop every record and restart the step cursor (bench warmup:
-        the measured window's provenance must exclude compile traffic)."""
+        """Drop every record and restart the step cursor
+        (``EngineCore.reset_metrics``: a measured window's records must
+        exclude the warm-up's)."""
         self._buf = [None] * self.capacity
         self._next = 0
 
@@ -232,8 +233,8 @@ class FlightRecorder:
         return merged
 
     def summary(self) -> dict[str, Any]:
-        """Step-level provenance for a measured run (bench
-        ``flight_summary``): per-dispatch-kind step counts, tokens by
+        """Step-level provenance for a measured run:
+        per-dispatch-kind step counts, tokens by
         side against the decode row-steps dispatched, occupancy p50/p95,
         and the KV-pressure peak over the retained window."""
         records = self.snapshot()
@@ -249,7 +250,7 @@ class FlightRecorder:
             for cls, n in (rec.get("classes") or {}).items():
                 # Slot-steps per priority class: who actually occupied
                 # the decode batch over the window (the scheduler's
-                # fairness evidence in bench flight summaries).
+                # fairness evidence, tests/test_sched.py).
                 classes[str(cls)] = classes.get(str(cls), 0) + int(n)
             occ.append(float(rec.get("occupancy", 0.0)))
             kv_peak = max(kv_peak, float(rec.get("kv_utilization", 0.0)))

@@ -9,7 +9,7 @@ the only planning layer: the serving-plan autotuner
 (:mod:`runbookai_tpu.autotune`) composes these numbers with an HLO-bytes
 roofline to search the full knob space, and its cost model delegates every
 residency figure here (pinned equal by tests/test_autotune.py) — engine,
-bench, docs, and tuner all quote ONE arithmetic.
+docs, and tuner all quote ONE arithmetic.
 
 The headline numbers it encodes (v5e, 16 GB/chip):
 

@@ -59,8 +59,8 @@ INCIDENT_SIGNALS = (
 INCIDENT_SCHEMA_VERSION = 1
 
 # Which signal classes each injected fault kind is expected to surface
-# as — the detection-coverage invariant's mapping (bench.py
-# --soak-scenarios: every injected fault window must overlap a detected
+# as — the detection-coverage invariant's mapping (chaos/soak.py:
+# every injected fault window must overlap a detected
 # incident of a matching class). Kinds in COVERAGE_REQUIRED_KINDS are
 # GATED (their detection path — supervisor transitions — is
 # deterministic); the rest are reported in the coverage table but a miss

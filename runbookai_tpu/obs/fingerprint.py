@@ -54,9 +54,9 @@ DESCRIPTOR_KEYS = ("prompt_len", "output_len", "concurrency",
 
 # Default "plan is stale" drift threshold (llm.obs.drift_threshold):
 # roughly "one scale dimension doubled AND a share appeared", or any
-# single dimension moving ~4x alone. Calibrated against the bench --shift
-# scenario (short-chat -> long-context/guided crosses it; steady traffic
-# against its own descriptor stays well under).
+# single dimension moving ~4x alone. Calibrated against the shift scenario
+# of tests/test_obs.py (short-chat -> long-context/guided crosses it;
+# steady traffic against its own descriptor stays well under).
 DEFAULT_DRIFT_THRESHOLD = 0.35
 
 
@@ -329,7 +329,7 @@ class WorkloadFingerprinter:
             self._samples.append(sample)
 
     def reset(self) -> None:
-        """Drop every sample (bench phase boundaries, warmup exclusion)."""
+        """Drop every sample (phase boundaries, warmup exclusion)."""
         with self._lock:
             self._samples.clear()
 

@@ -34,13 +34,13 @@ Surfaces (everywhere the platform already looks):
 - ``incident.open`` / ``incident.resolve`` tracer events, stitched into
   ``runbook timeline`` as a span band (utils/timeline.py) so a dp retry
   during an incident is visible in one view;
-- the ``bench.py --soak-scenarios`` detection-coverage invariant:
+- the soak gate's (``chaos/soak.py``) detection-coverage invariant:
   every injected fault window must overlap a detected incident of a
   matching signal class, and the chaos-free baseline pass must open
   zero incidents (the false-positive gate).
 
 Threading: one daemon poll thread (``poll_once`` public for
-deterministic drivers — bench, fixtures). Detector state mutates only
+deterministic drivers — tests, fixtures). Detector state mutates only
 under ``self._lock``; bundle writes, tracer events and metric bumps run
 OUTSIDE it (blocking I/O under a lock is exactly what ``runbook lint``
 RBK003 exists to catch).
@@ -400,7 +400,7 @@ class IncidentMonitor:
     # ---------------------------------------------------------- detection
 
     def poll_once(self, now: Optional[float] = None) -> list[tuple[str, dict]]:
-        """One detection fold (public so bench and tests can drive the
+        """One detection fold (public so tests can drive the
         machine deterministically without the thread). Side effects —
         bundle capture, tracer events, metric bumps — run outside the
         state lock."""

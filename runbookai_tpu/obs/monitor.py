@@ -27,7 +27,7 @@ empty/warmup window drops the series rather than scraping drift=0):
 
 Surfaces: ``GET /debug/workload`` and the ``/healthz`` ``workload``
 block (per-group + merged fleet-wide, like ``debug_steps``), the
-``runbook workload`` CLI, ``bench.py`` details, and a rotated on-disk
+``runbook workload`` CLI, and a rotated on-disk
 fingerprint history with window provenance (``llm.obs.history_dir``).
 """
 

@@ -24,7 +24,7 @@ Contracts:
   and self-accounting through ``runbook_tsdb_series`` /
   ``runbook_tsdb_samples_total`` / ``runbook_tsdb_memory_bytes``.
 - **deterministic**: the clock is injected and ``sample_once(now)`` /
-  ``ingest(now, ...)`` are public, so tests and bench drive the store
+  ``ingest(now, ...)`` are public, so tests drive the store
   without threads or sleeps; the query evaluator on top
   (:mod:`runbookai_tpu.obs.query`) is a pure function of (store
   contents, query, now).
@@ -32,7 +32,7 @@ Contracts:
 Surfaces: ``GET /debug/query`` + the ``/healthz`` ``history`` block
 (server/openai_api.py), ``runbook query`` (cli/main.py), incident-bundle
 lookback history + store-derived detector readings (obs/incident.py),
-and the soak gate's query-expressed invariants (bench.py).
+and the soak gate's query-expressed invariants (chaos/soak.py).
 """
 
 from __future__ import annotations
@@ -133,7 +133,7 @@ class MetricsTSDB:
     # ------------------------------------------------------------ sampling
 
     def sample_once(self, now: Optional[float] = None) -> int:
-        """One registry sweep at ``now`` (public — bench and tests drive
+        """One registry sweep at ``now`` (public — tests drive
         the store deterministically without the thread). Returns the
         number of samples appended. A series the registry exposes
         nothing for this tick stores nothing — absence, never zero."""

@@ -16,7 +16,7 @@ truth for the eval suite.
 Traffic half (``traffic.py``): the seeded serving-workload scenario mix
 (short chat, agentic chains, batch floods, shared-prefix sessions,
 spiky tenants) the chaos soak gate drives through the full composed
-stack — ``bench.py --soak-scenarios`` (docs/robustness.md).
+stack — ``chaos/soak.py`` (docs/robustness.md).
 """
 
 from runbookai_tpu.simulate.generator import (

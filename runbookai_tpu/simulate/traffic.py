@@ -3,7 +3,7 @@
 ``simulate/generator.py`` fabricates *incidents* for the agent to
 investigate; this module fabricates the *serving workload* a
 million-session deployment actually sees — the mix the chaos soak
-(``bench.py --soak-scenarios``) drives through the full composed stack:
+(``chaos/soak.py``) drives through the full composed stack:
 
 ``short_chat``
     Single-turn interactive requests, short prompts, streamed — the
@@ -108,7 +108,7 @@ class TrafficMix:
 
 
 def _prompt(rng: random.Random, n: int) -> tuple:
-    """Byte-vocabulary prompt ids (the bench harness serves the byte
+    """Byte-vocabulary prompt ids (the soak gate serves the byte
     tokenizer; real deployments swap prompts, not the mix shape)."""
     return tuple(rng.randrange(0, 256) for _ in range(n))
 
@@ -144,7 +144,7 @@ def generate_traffic(seed: int, duration_s: float, *,
     pool = [c for c in classes for _ in range(weights[c])]
     while len(picks) < n:
         picks.append(pool[rng.randrange(len(pool))])
-    # One shared session prefix per mix (page-aligned at the bench's
+    # One shared session prefix per mix (page-aligned at the soak gate's
     # page_size=16): every shared_prefix_session chain reuses it.
     shared_prefix = _prompt(rng, max(16, int(64 * prompt_scale) // 16 * 16))
 

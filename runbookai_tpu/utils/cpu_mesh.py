@@ -4,8 +4,7 @@ Sharding/parallelism code is validated without TPU hardware on a virtual
 CPU mesh (``--xla_force_host_platform_device_count``, SURVEY.md §4). The
 device count is an XLA flag and the platform a jax config value, and both
 must be set before the jax backend initializes. Shared by
-``tests/conftest.py``, ``bench.py --cpu`` and
-``__graft_entry__.dryrun_multichip``.
+``tests/conftest.py`` and ``__graft_entry__.dryrun_multichip``.
 """
 
 from __future__ import annotations

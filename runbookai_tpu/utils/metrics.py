@@ -101,7 +101,7 @@ class HistogramWindow:
       returned counts), so sparse traffic accumulates until it carries
       at least ``min_obs`` observations instead of being dropped;
     - a histogram reset under us (any bucket count going backwards —
-      bench warmup, tests) resyncs the mark and yields None rather than
+      a warm-up's reset, tests) resyncs the mark and yields None rather than
       a garbage negative window;
     - ``prime_zero=True`` makes the first window read everything
       observed so far (the feedback controller's first decision);
@@ -456,7 +456,7 @@ class Histogram(_Metric):
         """Approximate q-th percentile (linear interpolation inside the
         bucket; the ``+Inf`` bucket clamps to the last finite bound).
         Accuracy is bounded by bucket width — good enough for tail-latency
-        tracking (``bench.py`` p95s), not for exact SLO math."""
+        tracking, not for exact SLO math."""
         return self._interpolate(self._state(key)[0], q)
 
     def _interpolate(self, counts: list[float], q: float) -> Optional[float]:
@@ -614,7 +614,7 @@ class MetricsRegistry:
         return out
 
     def reset(self) -> None:
-        """Zero every metric's stored state (tests, bench warmup)."""
+        """Zero every metric's stored state (tests, a warm-up)."""
         for metric in self:
             metric.reset()
 

@@ -157,7 +157,7 @@ class SLOMonitor:
     # ------------------------------------------------------------------ API
 
     def evaluate(self) -> dict[str, dict[str, Any]]:
-        """One evaluation pass for ``/healthz`` / bench: per objective,
+        """One evaluation pass for ``/healthz``: per objective,
         target, current, burn ratio, and breached (None current = the
         histogram has no observations yet). Counts breaches into
         ``runbook_slo_violations_total`` like a scrape does."""

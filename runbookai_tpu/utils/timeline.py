@@ -66,7 +66,7 @@ def resolve_engine_requests(spans: list[dict[str, Any]],
                             request_id: str) -> set[str]:
     """Engine-internal request ids owned by ``request_id``.
 
-    The query id may itself BE an engine id (bench/tests trace without a
+    The query id may itself BE an engine id (tests trace without a
     server in front), or an ``x-request-id`` that one or more engine
     requests carried as ``trace_id`` (fleet retries → several)."""
     rids = {request_id}

@@ -248,7 +248,7 @@ def device_trace(logdir: str | Path) -> Iterator[None]:
 @contextlib.contextmanager
 def try_device_trace(logdir: str | Path) -> Iterator[bool]:
     """:func:`device_trace` for callers that ask for a profile on whatever
-    backend they run on (``runbook profile``, ``bench.py --profile``):
+    backend they run on (``runbook profile``):
     yields True when the capture started. On the CPU a ``jax.profiler``
     that cannot start yields False and the enclosed work runs unprofiled —
     dependency-free CI has nothing to trace. On a TPU the device trace is
@@ -311,7 +311,7 @@ _DISPATCH_SPANS = {
 
 def dispatch_counters(spans: list[dict[str, Any]]) -> dict[str, int]:
     """Dispatch-kind counts recovered from a span JSONL — lets a tune
-    run's measured refinement (or any banked bench arm) be sanity-checked
+    run's measured refinement be sanity-checked
     from its trace alone: a config that claims mixed dispatch but traces
     zero ``engine.mixed`` spans did not serve the config it claims."""
     out = {"prefill_steps": 0, "decode_dispatches": 0, "mixed_steps": 0}

@@ -1,16 +1,15 @@
 """Real-weights discovery: the on-ramp from random-init to measured quality.
 
-Bench and eval run random-init weights in the no-egress build environment —
-identical compute, but the QUALITY axis (eval pass@1, speculation
+The benchmark and eval run random-init weights in the no-egress build
+environment — identical compute, but the QUALITY axis (eval pass@1, speculation
 acceptance) is meaningless until a real checkpoint is in play. VERDICT r4
 next-round #3 asks for (a) automatic pickup of a real checkpoint the moment
-one exists and (b) an explicit marker in every bench/eval artifact until
+one exists and (b) an explicit marker in every eval artifact until
 then, so "quality: unmeasured" is stated rather than implied.
 
 Protocol once weights exist (see docs/WEIGHTS.md for the full recipe):
 
     export RUNBOOK_WEIGHTS=/path/to/checkpoints   # dir of dirs, or one model
-    python bench.py                               # picks them up, marks it
     runbook eval --live                           # pass@1 against threshold 0.7
 
 ``RUNBOOK_WEIGHTS`` may point at a single HF/orbax checkpoint directory or
@@ -49,7 +48,7 @@ def discover_weights(model_name: Optional[str] = None,
 
 
 def quality_marker(weights_path: Optional[str]) -> str:
-    """The honesty string carried in every bench/eval artifact."""
+    """The honesty string carried in every eval artifact."""
     if weights_path:
         return f"real weights: {weights_path}"
     return QUALITY_UNMEASURED

@@ -23,7 +23,7 @@ import jax
 # Full-precision matmuls so numerics tests compare exactly.
 jax.config.update("jax_default_matmul_precision", "highest")
 # Tier-1 keeps no compile cache, whichever entry point a test drives
-# (cli.main, bench, chip_smoke all place one: utils/compile_cache.py).
+# (cli.main and chip_smoke place one: utils/compile_cache.py).
 jax.config.update("jax_enable_compilation_cache", False)
 
 import asyncio

@@ -13,16 +13,14 @@ The contracts this file pins:
   YAML keys override plan values, model mismatches are refused, and
   every checked-in ``plans/*.json`` validates — with unknown schema
   versions rejected, never half-read;
-- ``bench.py --plan`` resolves to the same EngineConfig as the
-  equivalent explicit-flag run (byte-identical output digests);
+- a plan resolves to the same EngineConfig as the equivalent explicit
+  fields, through ``EngineConfig.from_plan`` and through ``llm.plan``
+  (byte-identical streams); its budget is per replica;
 - ``runbook metrics --trace`` recovers the PR-4 dispatch-kind counters
   from a span JSONL alone.
 """
 
-import contextlib
-import io
 import json
-import os
 from pathlib import Path
 
 import jax
@@ -210,6 +208,79 @@ def test_tune_winner_never_regresses_baseline(tuned):
         assert set(arm["dispatches"]) == {"prefill_steps",
                                           "decode_dispatches",
                                           "mixed_steps"}
+
+
+def _measure(cand, **kw):
+    from runbookai_tpu.autotune.search import measure_candidate
+
+    params = init_params(jax.random.PRNGKey(0), CFG, dtype=jnp.float32)
+    return measure_candidate(
+        CFG, params, ByteTokenizer(), cand,
+        Workload(prompt_len=24, output_len=8, concurrency=2),
+        n_requests=2, new_tokens=8, **kw)
+
+
+@pytest.mark.parametrize("dp", [1, 2])
+def test_measure_candidate_window_excludes_the_warmup(monkeypatch, dp):
+    """``measure_candidate`` forgets the warm-up through the engine's own
+    ``reset_metrics()``: once a core (every replica of a dp candidate),
+    leaving every counter, both histograms and the flight ring at zero,
+    so the figures describe the measured window alone."""
+    cand = Candidate(page_size=4, num_pages=64, max_batch_slots=2,
+                     prefill_chunk=32, kv_dtype="auto", max_seq_len=128,
+                     dp_replicas=dp)
+    after_reset = []
+    real = EngineCore.reset_metrics
+
+    def spy(core):
+        warm = dict(core.metrics)
+        assert warm["decode_tokens"] > 0 and len(core.flight) > 0
+        real(core)
+        after_reset.append((dict(core.metrics), len(core.flight),
+                            core.hist_ttft.count, core.hist_tpot.count,
+                            core._flight_kv_mark, warm))
+
+    monkeypatch.setattr(EngineCore, "reset_metrics", spy)
+    figures = _measure(cand)
+    assert len(after_reset) == dp
+    for metrics, flight_len, ttft_n, tpot_n, mark, _ in after_reset:
+        assert not any(metrics.values()), metrics
+        assert (flight_len, ttft_n, tpot_n, mark) == (0, 0, 0, (0, 0))
+    # The window's dispatches, not the warm-up's on top: with the reset
+    # taken out the same run counts strictly more.
+    monkeypatch.setattr(EngineCore, "reset_metrics", lambda core: None)
+    unforgotten = _measure(cand)
+    assert sum(figures["dispatches"].values()) < \
+        sum(unforgotten["dispatches"].values())
+
+
+def test_measure_candidate_needs_nothing_from_the_repository_root(
+        monkeypatch, tmp_path):
+    """`runbook tune`'s measured refinement runs from an installed
+    package: with the repository's root off ``sys.path`` (and not the
+    working directory) it loads no module by path and imports no
+    ``bench``."""
+    import importlib.util
+    import sys
+
+    monkeypatch.chdir(tmp_path)
+    monkeypatch.setattr(sys, "path", [
+        p for p in sys.path
+        if p and Path(p).resolve() != REPO])
+    monkeypatch.delitem(sys.modules, "bench", raising=False)
+
+    def no_path_loads(name, location=None, *a, **kw):
+        raise AssertionError(f"loaded {name!r} by path from {location}")
+
+    monkeypatch.setattr(importlib.util, "spec_from_file_location",
+                        no_path_loads)
+    with pytest.raises(ImportError):
+        importlib.import_module("bench")
+    figures = _measure(Candidate(
+        page_size=4, num_pages=64, max_batch_slots=2, prefill_chunk=32,
+        kv_dtype="auto", max_seq_len=128))
+    assert figures["requests"] == 2 and figures["decode_tok_s"] > 0
+    assert "bench" not in sys.modules
 
 
 def test_tune_skips_unmeasurable_arms(monkeypatch, tmp_path):
@@ -428,7 +499,7 @@ def test_engine_config_from_plan_unit():
 
 def test_plan_kv_dtype_resolves_identically_across_consumers():
     """One resolver, one meaning: plan "bf16" is a bfloat16 pool for
-    every consumer (llm.plan, bench --plan, from_plan) even on float32
+    every consumer (llm.plan, from_plan) even on float32
     activations, and "auto" follows them — the budget the sweep scored
     is the budget every consumer allocates."""
     from runbookai_tpu.engine.engine import resolve_kv_dtype
@@ -443,7 +514,7 @@ def test_plan_kv_dtype_resolves_identically_across_consumers():
         resolve_kv_dtype("fp4", jnp.float32)
     # apply_plan_to_llm forwards the plan spelling 1:1 (llm.kv_cache_dtype
     # accepts the full set), so from_config resolves through the same
-    # function as bench --plan and from_plan.
+    # function as from_plan.
     plan = PlanArtifact(model="llama3-test", topology={"tp": 1},
                         engine={"kv_dtype": "bf16"})
     assert apply_plan_to_llm(LLMConfig(), plan).kv_cache_dtype == "bf16"
@@ -494,67 +565,88 @@ def test_split_engine_budget_never_rounds_up():
     assert tiny.max_batch_slots == 1 and tiny.num_pages == 2
 
 
-# -------------------------------------------------- bench --plan parity
+# ------------------------------------------------ plan parity, end to end
 
 
-def test_bench_plan_matches_explicit_flags(tmp_path, monkeypatch):
-    """`bench.py --plan` with an artifact == the equivalent explicit-flag
-    run: byte-identical output digests, identical resolved
-    engine_config, and the plan id/hash recorded in details."""
-    import bench as bench_mod
+def _served(llm_kw, prompts):
+    """Build a client from ``LLMConfig(**llm_kw)``, serve ``prompts``
+    greedily, shut it down; returns (resolved EngineConfig as a dict,
+    per-replica configs, token streams)."""
+    import asyncio
 
+    from runbookai_tpu.engine.request import SamplingParams
+    from runbookai_tpu.model.jax_tpu import JaxTpuClient
+    from runbookai_tpu.utils.config import LLMConfig
+
+    client = JaxTpuClient.from_config(LLMConfig(
+        provider="jax-tpu", model="llama3-test", dtype="float32", **llm_kw))
+
+    async def run():
+        try:
+            return [(await client.engine.generate(
+                list(p), SamplingParams(temperature=0.0, max_new_tokens=12,
+                                        stop_token_ids=()))).token_ids
+                    for p in prompts]
+        finally:
+            await client.shutdown()
+
+    cores = getattr(client.engine, "cores", None) or [client.core]
+    return (engine_config_dict(client.core.ecfg),
+            [c.ecfg for c in cores], asyncio.run(run()))
+
+
+def test_plan_matches_explicit_fields(tmp_path):
+    """A plan gives the same EngineConfig as the equivalent explicit
+    fields, through both consumers: ``EngineConfig.from_plan`` equals the
+    hand-built config, ``llm.plan`` serves exactly that config, and the
+    same keys written out in the ``llm`` block resolve alike and serve
+    byte-identical streams; an explicit key still beats the plan's."""
+    engine = {"page_size": 16, "num_pages": 64, "max_batch_slots": 2,
+              "prefill_chunk": 128, "max_seq_len": 2048,
+              "block_pages": 16, "decode_steps_per_dispatch": 8,
+              "prefill_batch": 1, "kv_dtype": "auto",
+              "speculative": True, "dp_replicas": 1}
     plan = PlanArtifact(
         model="llama3-test",
         topology={"platform": "cpu", "device_kind": "cpu", "chips": 1,
                   "tp": 1, "dp_replicas": 1},
-        engine={"page_size": 16, "num_pages": 64, "max_batch_slots": 2,
-                "prefill_chunk": 128, "max_seq_len": 2048,
-                "block_pages": 16, "decode_steps_per_dispatch": 8,
-                "prefill_batch": 1, "kv_dtype": "auto",
-                "speculative": True, "dp_replicas": 1})
-    path = tmp_path / "bench-plan.json"
+        engine=engine)
+    path = tmp_path / "plan.json"
     save_plan(plan, path)
-    probe = {"ok": True, "platform": "cpu", "kind": "cpu", "n": 1}
-    for var, val in (("BENCH_REQUESTS", "2"), ("BENCH_PROMPT", "64"),
-                     ("BENCH_NEW", "12"), ("BENCH_BGE", "0"),
-                     ("BENCH_GUIDED", "0")):
-        monkeypatch.setenv(var, val)
 
-    def run(extra):
-        for k, v in extra.items():
-            os.environ[k] = v
-        buf = io.StringIO()
-        try:
-            with contextlib.redirect_stdout(buf):
-                bench_mod.run_inner("llama3-test", False, probe)
-        finally:
-            for k in extra:
-                os.environ.pop(k, None)
-        return json.loads(buf.getvalue().strip().splitlines()[-1])
+    by_hand = EngineConfig(
+        page_size=16, num_pages=64, max_batch_slots=2, prefill_chunk=128,
+        max_seq_len=2048, block_pages=16, decode_steps_per_dispatch=8,
+        prefill_batch=1, kv_dtype=jnp.float32, speculative=True,
+        dp_replicas=1)
+    assert EngineConfig.from_plan(
+        load_plan(path).engine, default_kv_dtype=jnp.float32) == by_hand
 
-    flags = run({"BENCH_SLOTS": "2", "BENCH_PAGES": "64",
-                 "BENCH_PREFILL_BATCH": "1"})
-    via_plan = run({"BENCH_PLAN": str(path)})
-    assert "error" not in flags["details"], flags["details"]
-    assert flags["details"]["outputs_digest"] == \
-        via_plan["details"]["outputs_digest"]
-    assert flags["details"]["engine_config"] == \
-        via_plan["details"]["engine_config"]
-    assert via_plan["details"]["plan"]["id"] == plan.plan_id
-    assert via_plan["details"]["plan"]["hash"] == plan.content_hash
-    assert flags["details"]["plan"] is None
-    # Explicit env beats the plan key, mirroring YAML-over-plan.
-    override = run({"BENCH_PLAN": str(path), "BENCH_SLOTS": "1"})
-    assert override["details"]["engine_config"]["max_batch_slots"] == 1
-    assert override["details"]["engine_config"]["num_pages"] == 64
+    prompts = [list(range(65, 129)), list(range(40, 104))]
+    via_plan, _, plan_streams = _served({"plan": str(path)}, prompts)
+    # llm.plan applies the whole block, the keys with no llm.* spelling
+    # (prefill_batch, block_pages, speculative) included.
+    # (from_config also spells out the backend's impls and llm.sched's
+    # default class weights, which None stands for.)
+    assert via_plan == engine_config_dict(by_hand) | {
+        "attn_impl": "xla", "qmm_impl": "xla",
+        "sched_weights": {0: 1.0, 1: 8.0}}
+    spelled = {"page_size": 16, "num_pages": 64, "max_batch_slots": 2,
+               "prefill_chunk": 128, "max_seq_len": 2048, "decode_steps": 8}
+    explicit, _, explicit_streams = _served(spelled, prompts)
+    for key, value in spelled.items():
+        key = {"decode_steps": "decode_steps_per_dispatch"}.get(key, key)
+        assert explicit[key] == via_plan[key] == value, key
+    assert plan_streams == explicit_streams
+    override, _, _ = _served({"plan": str(path), "max_batch_slots": 1}, [])
+    assert override["max_batch_slots"] == 1
+    assert override["num_pages"] == 64
 
 
-def test_bench_plan_dp_budget_is_per_replica(tmp_path, monkeypatch):
+def test_plan_dp_budget_is_per_replica(tmp_path):
     """A plan's slots/pages are PER REPLICA (the llm.*/EngineConfig
-    contract): a plan-sized fleet must serve each replica the plan's
-    budget, not re-split it the way the --dp fixed-total A/B does."""
-    import bench as bench_mod
-
+    contract): a plan-sized fleet serves each replica the plan's own
+    budget; nothing splits it across the replicas."""
     plan = PlanArtifact(
         model="llama3-test",
         topology={"platform": "cpu", "device_kind": "cpu", "chips": 2,
@@ -566,36 +658,32 @@ def test_bench_plan_dp_budget_is_per_replica(tmp_path, monkeypatch):
                 "dp_replicas": 2})
     path = tmp_path / "dp-plan.json"
     save_plan(plan, path)
-    probe = {"ok": True, "platform": "cpu", "kind": "cpu", "n": 2}
-    for var, val in (("BENCH_REQUESTS", "2"), ("BENCH_PROMPT", "48"),
-                     ("BENCH_NEW", "8"), ("BENCH_BGE", "0"),
-                     ("BENCH_GUIDED", "0"), ("BENCH_PLAN", str(path))):
-        monkeypatch.setenv(var, val)
-    monkeypatch.delenv("BENCH_DP", raising=False)
-    buf = io.StringIO()
-    with contextlib.redirect_stdout(buf):
-        bench_mod.run_inner("llama3-test", False, probe)
-    result = json.loads(buf.getvalue().strip().splitlines()[-1])
-    d = result["details"]
-    assert "error" not in d, d
-    assert d["dp"] == 2
-    # Un-split: each replica serves the plan's own budget.
-    assert d["batch_slots_per_replica"] == 2
-    assert d["num_pages_per_replica"] == 64
-    assert d["plan"]["id"] == plan.plan_id
+    _, per_replica, streams = _served(
+        {"plan": str(path)}, [list(range(65, 113)), list(range(30, 78))])
+    assert len(per_replica) == 2
+    for ecfg in per_replica:
+        assert ecfg.dp_replicas == 2
+        assert ecfg.max_batch_slots == 2 and ecfg.num_pages == 64
+    assert all(len(ids) == 12 for ids in streams)
 
 
-def test_bench_plan_refuses_model_mismatch(tmp_path, monkeypatch):
-    import bench as bench_mod
+def test_plan_for_another_model_is_refused_by_every_consumer(tmp_path):
+    """A plan is per model x topology: the single-model client refuses
+    one tuned for another model, and so does a group of ``llm.models``
+    (its own derived config goes through the same ``apply_group_plan``)."""
+    from runbookai_tpu.model.jax_tpu import JaxTpuClient
+    from runbookai_tpu.utils.config import LLMConfig, ModelGroupConfig
 
-    _, out = None, tmp_path / "other.json"
+    out = tmp_path / "other.json"
     save_plan(PlanArtifact(model="llama3-8b-instruct", topology={"tp": 1},
                            engine={"num_pages": 64}), out)
-    monkeypatch.setenv("BENCH_PLAN", str(out))
     with pytest.raises(ValueError, match="tuned for model"):
-        bench_mod.run_inner("llama3-test", False,
-                            {"ok": True, "platform": "cpu", "kind": "cpu",
-                             "n": 1})
+        JaxTpuClient.from_config(LLMConfig(
+            provider="jax-tpu", model="llama3-test", plan=str(out)))
+    with pytest.raises(ValueError, match="tuned for model"):
+        JaxTpuClient.from_config(LLMConfig(
+            provider="jax-tpu", model="llama3-test", dtype="float32",
+            models=[ModelGroupConfig(name="llama3-test", plan=str(out))]))
 
 
 # --------------------------------------------- trace dispatch counters

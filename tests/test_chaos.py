@@ -402,3 +402,260 @@ def test_supervisor_states_inventory():
     # docs/robustness.md) — additions must update all three.
     assert SUPERVISOR_STATES == ("healthy", "suspect", "failed",
                                  "rebuilding", "rejoining")
+
+
+# ------------------------------------------------------- the soak gate
+
+
+# Two replicas in the group the schedule crashes: with one, every chain that
+# meets the outage is lost and a mix this short has tenants of a single chain.
+# Under chaos 3.5 s a pass: seed 14's crash lands at 2.07 s, after 13 of the 15
+# chains have arrived (the batch flood 0.35 s before it) and before the last
+# (2.22 s), so chains are compared on a loaded machine too and the crash meets
+# traffic. At 2 s a loaded machine compares as few as 3 of 13; at 3 s and a
+# quarter of the lengths the last chain (1.72 s) can finish before the crash
+# (1.78 s) and the pass ends with it unapplied.
+@pytest.mark.parametrize("models", [None, "llama3-test:2,qwen2-test"],
+                         ids=["one_group", "two_groups"])
+@pytest.mark.parametrize("chaos", [True, False],
+                         ids=["chaos", "no_chaos"])
+def test_soak_gate(tmp_path, chaos, models):
+    """The composed gate (chaos/soak.py) on the tiny models: the seeded
+    mix twice through identically built fleets. With chaos every
+    invariant holds and the supervisor's transition record shows the
+    injected crash detected, failed over, rebuilt and rejoined, with its
+    incident captured; without chaos neither pass opens an incident.
+    Either way the two passes agree byte for byte on every chain outside
+    a fault window."""
+    from runbookai_tpu.chaos.soak import soak_gate
+
+    inv = soak_gate(3.5 if chaos else 2.0, models=models, chaos=chaos,
+                    token_scale=0.25,
+                    incident_dir=str(tmp_path / "bundles"))
+    assert [k for k, v in inv.items() if not v["passed"]] == [], inv
+    assert set(inv) == {
+        "zero_lost_outside_fault_windows", "interactive_ttft_p95",
+        "tenant_fairness", "rss_bound", "fd_bound", "digest_determinism",
+        "turns_timed_out", "supervisor_recovered", "detection_coverage",
+        "query_stores_held_the_pass",
+        "query_baseline_zero_incidents", "query_baseline_zero_lost",
+        "query_detection_coverage", "query_interactive_ttft_p95"}
+    traffic = inv["zero_lost_outside_fault_windows"]
+    chains = traffic["chains"]
+    assert chains > 0 and traffic["turns"] >= chains
+    # Every scenario class was exercised.
+    assert set(traffic["classes"]) == {
+        "short_chat", "agentic_chain", "batch_flood",
+        "shared_prefix_session", "spiky_tenant"}
+    det = inv["digest_determinism"]
+    assert det["compared"] > 0 and det["mismatched"] == []
+    assert inv["turns_timed_out"]["baseline"] == []
+    assert inv["turns_timed_out"]["chaos"] == []
+    # Each pass's store held the signal the query_* verdicts read.
+    for store in ("baseline", "chaos"):
+        held = inv["query_stores_held_the_pass"][store]
+        assert held["series"] > 0 and held["samples"] > 0
+        assert held["dropped_series"] == 0
+    cov = inv["detection_coverage"]
+    assert cov["baseline_opens"] == 0
+    rec = inv["supervisor_recovered"]
+    assert rec["crash_applied"] is chaos
+    if not chaos:
+        # No fault window: every chain of the second pass is compared.
+        assert det["compared"] == chains
+        assert cov["chaos_incidents"] == 0 and cov["coverage"] == []
+        assert rec["transitions"] == []
+        assert traffic["fault_windows"] == []
+        return
+    tos = [t["to"] for t in rec["transitions"]]
+    for state in ("failed", "rebuilding", "rejoining", "healthy"):
+        assert state in tos, tos
+    assert rec["rebuilds_total"] >= 1
+    crash_rows = [r for r in cov["coverage"] if r["kind"] == "replica_crash"]
+    assert crash_rows, cov["coverage"]
+    for row in crash_rows:
+        assert row["detected_signal"] == "replica_failure"
+        assert row["incident"] and row["mttd_s"] is not None
+    assert cov["bundles"] and all(
+        b["hash_verified"] and b["schema_valid"] and b["has_history"]
+        for b in cov["bundles"])
+    # A named incident_dir keeps the bundles for `runbook incident show`.
+    assert {b["name"] for b in cov["bundles"]} <= {
+        p.name for p in (tmp_path / "bundles").iterdir()}
+    # The store caught the incident's open window in flight: the gauge is
+    # absent while nothing is open.
+    qcov = inv["query_detection_coverage"]
+    assert qcov["crash_applied"] and any(v >= 1 for v in qcov["values"])
+
+
+def _pass_record(*, windows, transitions, incidents=(), origin=1000.0):
+    """What ``_soak_scenarios_pass`` returns, as far as the window and
+    coverage arithmetic reads it (offsets in seconds from ``origin``)."""
+    return {
+        "wall_origin": origin,
+        "chaos": {"windows": [dict(w, status=w.get("status", "applied"))
+                              for w in windows]},
+        "supervisors": [{"transitions": [
+            {"replica": r, "to": to, "ts": origin + at}
+            for r, to, at in transitions]}],
+        "incidents": list(incidents),
+    }
+
+
+def test_soak_effective_windows_extend_to_recovery():
+    """A crash or wedge window stays open until its replica's next
+    rejoin; every supervisor failure arc is a window of its own; other
+    kinds keep their scheduled end. A chain counts as inside when it
+    overlaps ANY of them, so overlapping windows act as their union."""
+    from runbookai_tpu.chaos.soak import _overlaps, _soak_effective_windows
+
+    passed = _pass_record(
+        windows=[
+            {"kind": "replica_crash", "replica": 0,
+             "applied_at_s": 2.0, "ends_at_s": 2.0},
+            {"kind": "kv_pull_delay", "replica": 1,
+             "applied_at_s": 3.0, "ends_at_s": 4.0},
+            {"kind": "replica_wedge", "replica": 1,
+             "applied_at_s": 9.0, "ends_at_s": 9.5}],
+        transitions=[(0, "failed", 2.3), (0, "rebuilding", 2.4),
+                     (0, "rejoining", 5.0), (0, "healthy", 6.0)])
+    windows = _soak_effective_windows(passed)
+    assert windows == [
+        pytest.approx((1.9, 6.1)),      # the crash, to replica 0's rejoin
+        pytest.approx((2.9, 4.1)),      # a delay keeps its scheduled end
+        (pytest.approx(8.9), float("inf")),  # a wedge that never rejoined
+        pytest.approx((2.2, 6.1)),      # the supervisor's own arc
+    ]
+
+    def chain(start, end):
+        return {"t_start_s": start, "t_end_s": end}
+
+    assert _overlaps(chain(5.0, 5.5), windows)    # after the crash's
+    # scheduled end, before the rejoin: inside the fault
+    assert _overlaps(chain(4.05, 4.08), windows)  # covered by the union
+    assert not _overlaps(chain(6.2, 8.8), windows)
+    assert not _overlaps(chain(0.0, 1.9), windows)
+    assert _overlaps(chain(50.0, 51.0), windows)  # the open-ended wedge
+    assert _soak_effective_windows({"chaos": None}) == []
+
+
+def test_incident_coverage_flags_an_uncovered_required_window():
+    """One row per APPLIED window. A crash window no incident of a
+    matching signal class overlaps fails the required check; an
+    overlapping ``replica_failure`` incident passes it and banks the
+    time to detect; a missed optional kind is reported, not gated."""
+    from runbookai_tpu.chaos.soak import _incident_coverage
+
+    windows = [
+        {"kind": "replica_crash", "replica": 0,
+         "applied_at_s": 2.0, "ends_at_s": 2.0},
+        {"kind": "kv_pull_delay", "replica": 1,
+         "applied_at_s": 3.0, "ends_at_s": 3.5},
+        {"kind": "tenant_flood", "replica": 0, "status": "skipped",
+         "applied_at_s": 4.0, "ends_at_s": 4.5}]
+    transitions = [(0, "failed", 2.3), (0, "healthy", 6.0)]
+
+    def incident(signal, opened, resolved, origin=1000.0):
+        return {"id": f"inc-{signal}", "signal": signal,
+                "opened_ts": origin + opened,
+                "resolved_ts": (origin + resolved
+                                if resolved is not None else None)}
+
+    # The wrong signal class, and the right class outside the window.
+    rows, ok = _incident_coverage(_pass_record(
+        windows=windows, transitions=transitions,
+        incidents=[incident("queue_wait", 2.5, 3.0),
+                   incident("replica_failure", 7.0, 8.0)]))
+    assert ok is False
+    assert [r["kind"] for r in rows] == ["replica_crash", "kv_pull_delay"]
+    assert rows[0]["required"] and rows[0]["detected_signal"] is None
+    assert rows[0]["window_s"] == [2.0, 6.0]      # extended to the rejoin
+    # The delay window matched queue_wait — one of its expected classes.
+    assert rows[1]["detected_signal"] == "queue_wait"
+    assert not rows[1]["required"]
+
+    rows, ok = _incident_coverage(_pass_record(
+        windows=windows, transitions=transitions,
+        incidents=[incident("replica_failure", 2.4, None)]))
+    assert ok is True
+    assert rows[0]["detected_signal"] == "replica_failure"
+    assert rows[0]["incident"] == "inc-replica_failure"
+    assert rows[0]["mttd_s"] == pytest.approx(0.4)
+    assert rows[1]["detected_signal"] is None     # reported, not gated
+    assert _incident_coverage({"chaos": None}) == ([], True)
+
+
+def test_soak_query_reads_what_the_evaluator_returns():
+    """A gate condition evaluated through the embedded store gives what
+    obs/query.py gives for the same expression at the store's newest
+    sample; an empty store is 'never sampled', not zero."""
+    from runbookai_tpu.chaos.soak import _soak_query
+    from runbookai_tpu.obs import MetricsTSDB, evaluate
+    from runbookai_tpu.utils.metrics import MetricsRegistry
+
+    store = MetricsTSDB(interval_s=1.0, retention_s=3600.0, max_series=64,
+                        registry=MetricsRegistry(), clock=lambda: 500.0)
+    assert _soak_query(store, "increase(runbook_incident_total[60s])") == {
+        "expr": "increase(runbook_incident_total[60s])", "values": []}
+    for ts, v in ((100, 0), (110, 2), (140, 5)):
+        store.ingest(ts, "runbook_incident_total",
+                     {"signal": "replica_failure"}, v)
+    store.ingest(140, "runbook_incident_total", {"signal": "slo_burn"}, 0)
+    store.ingest(120, "runbook_incident_open",
+                 {"signal": "replica_failure"}, 1)
+    for expr in ("increase(runbook_incident_total[60s])",
+                 "max_over_time(runbook_incident_open[60s])",
+                 "increase(runbook_router_shed_total[60s])"):
+        got = _soak_query(store, expr)
+        want = evaluate(store, expr, now=140)
+        assert got == {"expr": expr,
+                       "values": [r["value"] for r in want["result"]]}
+    # One sample in the window is no increase: that series is absent.
+    assert _soak_query(
+        store, "increase(runbook_incident_total[60s])")["values"] == [5.0]
+
+
+def test_soak_cli_exits_nonzero_on_a_failed_invariant(monkeypatch, capsys):
+    """``python -m runbookai_tpu.chaos.soak``: one JSON document; a
+    failed invariant is named in it and the exit code is non-zero. A
+    TTFT bound of 0 fails both TTFT verdicts and nothing else."""
+    from runbookai_tpu.chaos import soak
+
+    monkeypatch.setattr(soak, "TTFT_P95_BOUND_MS", 0.0)
+    rc = soak.main(["2", "--no-chaos", "--seed", "3"])
+    out = capsys.readouterr().out.strip().splitlines()
+    assert len(out) == 1
+    doc = json.loads(out[0])
+    assert rc == 1 and doc["passed"] is False
+    assert doc["failed"] == ["interactive_ttft_p95",
+                             "query_interactive_ttft_p95"]
+    assert doc["invariants"]["interactive_ttft_p95"]["bound_ms"] == 0.0
+    assert doc["invariants"]["interactive_ttft_p95"]["p95_ms"] > 0
+    assert doc["invariants"]["detection_coverage"]["chaos_incidents"] == 0
+    with pytest.raises(ValueError, match="unknown model config"):
+        soak.main(["2", "--models", "no-such-model"])
+
+
+def test_soak_gate_counts_a_turn_that_never_returns_as_lost(monkeypatch,
+                                                            capfd):
+    """The gate ends with a verdict, never by waiting: a turn past
+    ``TURN_TIMEOUT_S`` is recorded as lost (here every turn is) and named
+    by a verdict of its own, which no fault window excuses; the first of
+    a pass dumps the stacks. With nothing compared, determinism fails."""
+    from runbookai_tpu.chaos import soak
+
+    monkeypatch.setattr(soak, "TURN_TIMEOUT_S", 1e-4)
+    inv = soak.soak_gate(2.0, chaos=False, token_scale=0.25)
+    lost = inv["zero_lost_outside_fault_windows"]
+    assert lost["passed"] is False
+    assert lost["lost_total"] == lost["chains"] > 0
+    assert len(lost["lost_outside_windows"]) == lost["chains"]
+    assert inv["tenant_fairness"]["passed"] is False
+    assert inv["digest_determinism"]["compared"] == 0
+    assert inv["digest_determinism"]["passed"] is False
+    timed = inv["turns_timed_out"]
+    assert timed["passed"] is False and timed["bound_s"] == 1e-4
+    assert len(timed["baseline"]) == len(timed["chaos"]) == lost["chains"]
+    err = capfd.readouterr().err
+    assert err.count("every thread's and task's stack follows") == 2
+    assert "run_chain" in err      # a task's stack: where the turn waits

@@ -331,3 +331,46 @@ def test_converters_chew_checked_in_mini_datasets(tmp_path):
         assert len(cases) == want_cases
         assert any(want_service in c.expected_services for c in cases)
         assert all(c.expected_root_cause for c in cases)
+
+
+# ---------------------------------------------------------------------------
+# weights discovery and the quality marker every eval artifact carries
+# ---------------------------------------------------------------------------
+
+
+def test_weights_discovery_and_quality_marker(tmp_path, monkeypatch):
+    from runbookai_tpu.utils.weights import (
+        QUALITY_UNMEASURED,
+        discover_weights,
+        quality_marker,
+    )
+
+    monkeypatch.delenv("RUNBOOK_WEIGHTS", raising=False)
+    assert discover_weights("llama3-8b-instruct") is None
+    assert quality_marker(None) == QUALITY_UNMEASURED
+
+    # Parent-of-models layout wins over the root itself.
+    (tmp_path / "llama3-8b-instruct").mkdir()
+    monkeypatch.setenv("RUNBOOK_WEIGHTS", str(tmp_path))
+    assert discover_weights("llama3-8b-instruct") == str(
+        tmp_path / "llama3-8b-instruct")
+    assert discover_weights("other-model") == str(tmp_path)
+    # Configured path beats the env var.
+    cfgd = tmp_path / "explicit"
+    cfgd.mkdir()
+    assert discover_weights("llama3-8b-instruct", str(cfgd)) == str(cfgd)
+    assert "real weights" in quality_marker(str(cfgd))
+
+
+def test_eval_artifacts_carry_quality_marker(tmp_path, monkeypatch):
+    # Every eval artifact must state whether quality was measured with
+    # real weights (VERDICT r4 #3).
+    from runbookai_tpu.evalsuite.run_all import run_all_benchmarks
+    from runbookai_tpu.utils.weights import QUALITY_UNMEASURED
+
+    monkeypatch.delenv("RUNBOOK_WEIGHTS", raising=False)
+    agg = run_all_benchmarks(datasets_root=tmp_path / "none",
+                             out_dir=tmp_path / "out")
+    assert agg["quality"] == QUALITY_UNMEASURED
+    on_disk = json.loads((tmp_path / "out" / "run-all.json").read_text())
+    assert on_disk["quality"] == QUALITY_UNMEASURED

@@ -1,10 +1,9 @@
 """PR 7 deep-introspection layer: engine flight recorder ring semantics,
 SLO burn math over synthetic histogram fills, request-timeline stitching
-(including a live dp=2 fleet trace), the /debug/steps scrape shape, trace
-JSONL rotation, and the bench --profile / BENCH_SLO provenance blocks."""
+(including a live dp=2 fleet trace), the /debug/steps scrape shape and
+trace JSONL rotation."""
 
 import json
-import os
 import threading
 import urllib.error
 import urllib.request
@@ -653,82 +652,3 @@ async def test_dp2_fleet_trace_timeline_and_debug_steps(tmp_path, capsys):
     assert life["queue_wait_ms"]["count"] >= 2
     assert set(life["router"]["placements"]) <= {"0", "1"}
     assert sum(life["router"]["placements"].values()) == 2
-
-
-# --------------------------------------------------------------------------- #
-# bench: --profile smoke + BENCH_SLO breach + flight_summary provenance       #
-# --------------------------------------------------------------------------- #
-
-
-def _bench_env(monkeypatch, **extra):
-    for var, val in (("BENCH_REQUESTS", "2"), ("BENCH_PROMPT", "48"),
-                     ("BENCH_NEW", "16"), ("BENCH_SLOTS", "2"),
-                     ("BENCH_PAGES", "64"), ("BENCH_PREFILL_BATCH", "1"),
-                     ("BENCH_BGE", "0"), ("BENCH_GUIDED", "0")):
-        monkeypatch.setenv(var, val)
-    for var in ("BENCH_PROFILE", "BENCH_SLO", "BENCH_DP", "BENCH_PLAN"):
-        monkeypatch.delenv(var, raising=False)
-    for var, val in extra.items():
-        monkeypatch.setenv(var, val)
-
-
-def test_bench_profile_slo_and_flight_summary(tmp_path, monkeypatch, capsys):
-    """The cpu-sanity arm with --profile + a deliberately breached SLO:
-    details must carry the produced-or-cleanly-skipped profile record,
-    a burn_ratio > 1, and the recorder's flight_summary provenance."""
-    import bench as bench_mod
-
-    prof_dir = tmp_path / "xprof"
-    _bench_env(monkeypatch, BENCH_PROFILE=str(prof_dir),
-               BENCH_SLO='{"tpot_p95_ms": 0.001}')
-    probe = {"ok": True, "platform": "cpu", "kind": "cpu", "n": 1}
-    bench_mod.run_bench("llama3-test", False, probe)
-    out = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
-    d = out["details"]
-    assert "error" not in d, d
-
-    prof = d["profile"]
-    assert prof["dir"] == str(prof_dir)
-    if prof["captured"]:
-        assert os.path.isdir(prof_dir), "captured but no trace directory"
-        assert "skipped" not in prof
-    else:
-        assert prof["skipped"] == "jax.profiler capture unavailable"
-
-    # 1µs TPOT target on CPU: burning by construction.
-    slo = d["slo"]["tpot_p95_ms"]
-    assert slo["target_ms"] == 0.001
-    assert slo["burn_ratio"] is not None and slo["burn_ratio"] > 1.0
-    assert slo["breached"] is True
-
-    fs = d["flight_summary"]
-    assert fs["steps_recorded"] > 0
-    # Warmup reset: the provenance describes the measured window only.
-    assert fs["steps_recorded"] == fs["steps_total"]
-    assert sum(fs["dispatch_kinds"].values()) == fs["steps_recorded"]
-    assert 0.0 <= fs["occupancy_p95"] <= 1.0
-    assert 0.0 <= fs["kv_utilization_peak"] <= 1.0
-    assert fs["tokens"] > 0
-
-
-def test_bench_without_slo_or_profile_has_no_blocks(monkeypatch, capsys):
-    import bench as bench_mod
-
-    _bench_env(monkeypatch)
-    probe = {"ok": True, "platform": "cpu", "kind": "cpu", "n": 1}
-    bench_mod.run_bench("llama3-test", False, probe)
-    out = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
-    d = out["details"]
-    assert "error" not in d, d
-    assert "profile" not in d and "slo" not in d
-    assert d["flight_summary"]["steps_recorded"] > 0  # always present
-
-
-def test_bench_rejects_malformed_slo(monkeypatch, capsys):
-    import bench as bench_mod
-
-    _bench_env(monkeypatch, BENCH_SLO="not json")
-    probe = {"ok": True, "platform": "cpu", "kind": "cpu", "n": 1}
-    bench_mod.run_bench("llama3-test", False, probe)
-    out = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
-    assert "error" in out["details"]["slo"]

@@ -670,7 +670,7 @@ def test_feedback_shrinks_grows_and_clamps():
     assert ('runbook_sched_feedback_adjustments_total'
             '{direction="shrink"}') in text
     assert 'runbook_sched_mixed_prefill_tokens{replica="0"} 64' in text
-    # A histogram reset under the controller (bench warmup) resyncs the
+    # A histogram reset under the controller (reset_metrics) resyncs the
     # window mark instead of serving a garbage negative window.
     hist.reset()
     assert ctl.burn() is None

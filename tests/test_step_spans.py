@@ -185,7 +185,7 @@ def test_the_record_counts_the_pages_its_rows_hold(parts, monkeypatch,
 
 
 def test_the_summary_reads_the_dispatch_fields(parts):
-    """``flight.summary()`` (bench's ``flight_summary``) is the program's
+    """``flight.summary()`` is the program's
     reader of ``k``, ``rows``, ``prefill_tokens`` and ``decode_tokens``."""
     from runbookai_tpu.engine.flight_recorder import FlightRecorder
 

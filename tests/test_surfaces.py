@@ -477,3 +477,30 @@ def test_live_tree_sink_repaints_during_run():
                          out=out2, enabled=False)
     sink2(AgentEvent("hypothesis_created", {"id": "H3"}))
     assert out2.getvalue() == "" and plain == ["hypothesis_created"]
+
+
+def test_package_loads_nothing_from_the_repository_root():
+    """An installed package has no repository root beside it: no module
+    under ``runbookai_tpu/`` imports ``bench``, names ``bench.py`` or
+    builds a path out of ``parents[2]`` to a ``.py`` file, and the
+    ``runbook`` parser has no ``bench`` subcommand (it could only run a
+    file the package does not ship)."""
+    import argparse
+    import re
+    from pathlib import Path
+
+    import runbookai_tpu
+    from runbookai_tpu.cli.main import build_parser
+
+    offending = re.compile(
+        r"^\s*(import|from)\s+bench\b|bench\.py|parents\[2\][^\n]*\.py",
+        re.MULTILINE)
+    package = Path(runbookai_tpu.__file__).parent
+    hits = {str(p.relative_to(package)): m.group(0)
+            for p in sorted(package.rglob("*.py"))
+            if (m := offending.search(p.read_text()))}
+    assert hits == {}
+    commands = next(a for a in build_parser()._actions
+                    if isinstance(a, argparse._SubParsersAction))
+    assert "bench" not in commands.choices
+    assert {"serve", "tune", "profile"} <= set(commands.choices)
