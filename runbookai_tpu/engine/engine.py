@@ -563,10 +563,12 @@ def _probe_pallas_attn_int8(model_cfg, ecfg, act_dtype) -> None:
 @functools.lru_cache(maxsize=8)
 def _probe_qmm_pallas_cached(backend: str, m: int, k: int, n: int,
                              act_dtype_name: str, mesh=None) -> bool:
-    """The int8 qmm kernel at the model's real (K, N); raises what Mosaic
-    raises. One shape is representative: the lowering concern is the
-    int8 load/convert pattern, not a particular multiple-of-128 tile
-    count.
+    """The int8 qmm kernel at the model's real (K, N), called as the decode
+    programs call it — a STACKED ``[L, K, N]`` operand (two layers stand
+    for any depth: the kernel's code does not depend on L) and a layer's
+    number; raises what Mosaic raises. One shape is representative: the
+    lowering concern is the int8 copy/convert pattern, not a particular
+    multiple-of-128 block count.
 
     With a multi-device ``mesh`` the operands are committed replicated on
     it first, so the probe exercises the same GSPMD partitioning of the
@@ -576,17 +578,18 @@ def _probe_qmm_pallas_cached(backend: str, m: int, k: int, n: int,
     from runbookai_tpu.ops.qmm_pallas import qmm_pallas
 
     x = jnp.zeros((m, k), jnp.dtype(act_dtype_name))
-    q = jnp.zeros((k, n), jnp.int8)
+    q = jnp.zeros((2, k, n), jnp.int8)
     s = jnp.zeros((1, n), jnp.float32)
+    layer = jnp.ones((), jnp.int32)
     if mesh is not None and mesh.size > 1:
         from runbookai_tpu.parallel.mesh import replicated
 
         rep = replicated(mesh)
-        x, q, s = (jax.device_put(a, rep) for a in (x, q, s))
+        x, q, s, layer = (jax.device_put(a, rep) for a in (x, q, s, layer))
     # runbook: noqa[RBK002] — probe barrier: one qmm compile at the real
     # (K, N) proves the Mosaic int8 dot before the first live dispatch.
     jax.block_until_ready(
-        qmm_pallas(x, q, s, interpret=backend == "cpu"))
+        qmm_pallas(x, q, s, layer, interpret=backend == "cpu"))
     return True
 
 

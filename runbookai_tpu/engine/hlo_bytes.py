@@ -15,6 +15,9 @@ COMPILED program (VERDICT r4 next-round #2):
   smoking gun, mechanically detected. Fusion-body lines are excluded:
   values inside a fusion computation are virtual; only fusion roots and
   top-level/loop-body instructions own buffers.
+  :func:`layer_weight_copies` hunts the same shapes at the STORED width:
+  one layer's int8 matrix copied out of the stacked array in front of
+  the Pallas matmul, which reads the stack in place.
 - :func:`lower_decode` lowers+compiles the engine's REAL decode dispatch
   (the same jitted ``_decode_step`` serving uses) without executing it,
   so the analysis covers the program that runs, not a proxy.
@@ -42,6 +45,7 @@ import jax.numpy as jnp
 # Dtype widths as HLO spells them; int8/u8/fp8 (1 byte) are the stored
 # widths — materializing THOSE is fine, the hazard is 2+ byte copies.
 _WIDE_DTYPES = {"bf16": 2, "f16": 2, "f32": 4, "f64": 8}
+_NARROW_DTYPES = {"s8", "u8", "f8e4m3fn", "f8e5m2"}
 
 # `%name = dtype[dims]{layout} op(...)` — optimized HLO instruction line.
 _INSTR = re.compile(r"^(?:ROOT\s+)?%?[\w.\-]+\s*=\s*([a-z0-9]+)\[([\d,]*)\]")
@@ -51,6 +55,8 @@ _COMPUTATION = re.compile(r"^(?:ENTRY\s+)?%?([\w.\-]+)\s+\(.*->.*\{$")
 # name, op and operand list of an instruction; the computation a fusion calls.
 _NAMED = re.compile(r"^(?:ROOT\s+)?%?([\w.\-]+)\s*=\s*\S+\s+([\w\-]+)\((.*)")
 _CALLS = re.compile(r"\bfusion\(.*\bcalls=%?([\w.\-]+)")
+# Ops that name a buffer without owning a new one.
+_VIEW_OPS = {"parameter", "get-tuple-element", "bitcast"}
 
 
 def _computations(hlo_text: str) -> dict[str, list[str]]:
@@ -119,15 +125,17 @@ def quantized_weight_shapes(params: Any) -> set[tuple[int, ...]]:
     return shapes
 
 
-def wide_weight_materializations(
-    hlo_text: str, weight_shapes: Iterable[tuple[int, ...]]
-) -> list[str]:
-    """Offending lines: instructions in optimized HLO whose result is a
-    wide-dtype (>= 2 byte) buffer with exactly a quantized weight's dims
-    (full stacked tensor, per-layer slice, or keep-dims slice). Lines
-    inside fusion computations are skipped (virtual values); fusion
-    ROOTS appear at their call sites and are caught."""
+def _weight_shaped_buffers(hlo_text: str,
+                           weight_shapes: Iterable[tuple[int, ...]],
+                           dtypes: Iterable[str]) -> list[str]:
+    """Instructions of optimized HLO that own a buffer of one of
+    ``dtypes`` with exactly a quantized weight's dims (full stacked
+    tensor, per-layer slice, or keep-dims slice). Lines inside fusion
+    computations are skipped (virtual values); fusion ROOTS appear at
+    their call sites and are caught. Parameters and views of them own
+    nothing."""
     targets = {tuple(s) for s in weight_shapes}
+    dtypes = set(dtypes)
     comps = _computations(hlo_text)
     virtual = _fusion_bodies(comps)
     bad: list[str] = []
@@ -135,12 +143,35 @@ def wide_weight_materializations(
         if name in virtual:
             continue
         for line in lines:
-            result = _dims(line)
-            if result is None or "parameter(" in line:
+            result, named = _dims(line), _NAMED.match(line)
+            if result is None or named is None or named.group(2) in _VIEW_OPS:
                 continue
-            if result[0] in _WIDE_DTYPES and result[1] in targets:
+            if result[0] in dtypes and result[1] in targets:
                 bad.append(line[:200])
     return bad
+
+
+def wide_weight_materializations(
+    hlo_text: str, weight_shapes: Iterable[tuple[int, ...]]
+) -> list[str]:
+    """Offending lines: instructions in optimized HLO whose result is a
+    wide-dtype (>= 2 byte) buffer with exactly a quantized weight's dims
+    — a dequantized copy, three times the bytes."""
+    return _weight_shaped_buffers(hlo_text, weight_shapes, _WIDE_DTYPES)
+
+
+def layer_weight_copies(
+    hlo_text: str, weight_shapes: Iterable[tuple[int, ...]]
+) -> list[str]:
+    """Offending lines: instructions whose result is a STORED-width (1
+    byte) buffer with a quantized weight's dims — one layer's ``s8[K, N]``
+    sliced or copied out of the stacked ``[L, K, N]`` array (on the chip
+    ``%dynamic-slice_bitcast_fusion = s8[K, N]`` in the layer scan's
+    body, in front of every call of the Pallas matmul: each matrix
+    handled twice a layer, PERF.md section 6, PR 30), or a copy of the
+    stack. The kernel reads the stack in place (``ops/qmm_pallas.py``),
+    so a decode program with ``qmm_impl="pallas"`` has none."""
+    return _weight_shaped_buffers(hlo_text, weight_shapes, _NARROW_DTYPES)
 
 
 def lower_decode(core, *, qmm_impl: str | None = None,
@@ -212,10 +243,6 @@ def kv_pool_shapes(core) -> set[tuple[int, ...]]:
                      (n_layers * tokens // ps, ps)):
             shapes.add(lead + tuple(rest))
     return shapes
-
-
-# Ops that name a buffer without owning a new one.
-_VIEW_OPS = {"parameter", "get-tuple-element", "bitcast"}
 
 
 def _writes_rows_in_place(line: str, lines: list[str],

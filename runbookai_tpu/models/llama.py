@@ -361,25 +361,30 @@ def qmm(x: jnp.ndarray, w: Any, impl: str = "xla") -> jnp.ndarray:
     scale applies to the result — identical math to dequantize-first, since
     the scale is constant along the contraction.
 
-    ``impl="pallas"`` streams the int8 tiles through the Pallas kernel
+    ``impl="pallas"`` reads the int8 matrix through the Pallas kernel
     (:mod:`runbookai_tpu.ops.qmm_pallas`) at decode/verify shapes — the
-    convert happens in-register, so HBM moves half the bf16 bytes by
-    construction instead of by fusion luck. Shapes the kernel does not
-    cover (chunked prefill M, ragged dims, unquantized leaves) fall back
-    to the XLA expression below, same math.
+    convert happens in VMEM, so HBM moves half the bf16 bytes by
+    construction instead of by fusion luck. A leaf that also carries
+    ``"layer"`` holds the layer scan's STACKED ``q [L, in, out]`` with the
+    layer's number and that layer's scales (:func:`_forward_hidden`, which
+    has checked the shape): the kernel reads that layer's matrix where it
+    lies. Shapes the kernel does not cover (chunked prefill M, ragged
+    dims, unquantized leaves) fall back to the XLA expression below, same
+    math.
     """
     if isinstance(w, dict):
-        if impl == "pallas" and w["q"].ndim == 2:
+        if "layer" in w or (impl == "pallas" and w["q"].ndim == 2):
             from runbookai_tpu.ops.qmm_pallas import (
                 qmm_pallas,
                 qmm_pallas_eligible,
             )
 
             lead = x.shape[:-1]
-            k_dim, n = w["q"].shape
-            if qmm_pallas_eligible(math.prod(lead), k_dim, n):
+            k_dim, n = w["q"].shape[-2:]
+            if "layer" in w or qmm_pallas_eligible(math.prod(lead), k_dim, n):
                 out = qmm_pallas(
                     x.reshape(-1, k_dim), w["q"], w["s"].reshape(1, n),
+                    w.get("layer"),
                     interpret=jax.default_backend() == "cpu",
                 )
                 return out.reshape(*lead, n)
@@ -432,11 +437,12 @@ def _forward_hidden(
     the few rows they need before paying for the vocab projection).
 
     The scan over layers carries ``(hidden, kv_k, kv_v)``; its ``xs`` are
-    the layer's parameters and its number. The page writers (the flat
-    scatter, and kv-split's ``shard_map`` one) take the whole pool and
-    that number and write their rows in place; every reader (XLA gather,
-    Pallas kernels, their TP and kv-split wraps, int8 pools) takes the
-    layer's slice of the carry.
+    the layer's parameters and its number — less the int8 matrices the
+    Pallas matmul reads in place in their stacked arrays. The page
+    writers (the flat scatter, and kv-split's ``shard_map`` one) take the
+    whole pool and that number and write their rows in place; every
+    reader (XLA gather, Pallas kernels, their TP and kv-split wraps, int8
+    pools) takes the layer's slice of the carry.
     """
     b, t = tokens.shape
     hd, n_kv = cfg.head_dim, cfg.n_kv_heads
@@ -476,6 +482,23 @@ def _forward_hidden(
         if mesh.shape.get(MODEL_AXIS, 1) > 1 or kv_split_active:
             qmm_impl = "xla"
     mm = partial(qmm, impl=qmm_impl)
+    # An int8 matrix that the Pallas kernel reads in place at this
+    # program's M (the decode programs; a static shape test,
+    # ``reads_in_place``) does NOT ride the scan's ``xs``: a scan's
+    # per-layer slice does not fuse into a custom call, so XLA copied
+    # ``s8[K, N]`` out of the stack before every call and each matrix was
+    # handled twice a layer. Those reach ``layer_step`` stacked, with the
+    # layer's number. Their scales, every other leaf, and every leaf of a
+    # program the kernel does not cover (mixed and prefill M) are ``xs``.
+    layers, stacked = params["layers"], {}
+    if qmm_impl == "pallas":
+        from runbookai_tpu.ops.qmm_pallas import reads_in_place
+
+        stacked = {name: w["q"] for name, w in layers.items()
+                   if isinstance(w, dict) and w["q"].ndim == 3
+                   and reads_in_place(b * t, w["q"].shape)}
+        layers = {name: {"s": w["s"]} if name in stacked else w
+                  for name, w in layers.items()}
 
     def layer_step(carry, layer_in):
         # The pool rides the CARRY, whole: a scan's stacked output can
@@ -484,6 +507,8 @@ def _forward_hidden(
         # pass and wrote every layer through again.
         hidden, kv_k, kv_v = carry
         lp, lp_lora, li = layer_in
+        lp = {**lp, **{name: {"q": q, "s": lp[name]["s"], "layer": li}
+                       for name, q in stacked.items()}}
         x = rms_norm(hidden, lp["attn_norm"], cfg.norm_eps)
         q, k, v = mm(x, lp["wq"]), mm(x, lp["wk"]), mm(x, lp["wv"])
         if lp_lora is not None:
@@ -622,7 +647,7 @@ def _forward_hidden(
 
     (h, kv_k, kv_v), _ = jax.lax.scan(
         layer_step, (h, kv_k, kv_v),
-        (params["layers"], lora, jnp.arange(cfg.n_layers, dtype=jnp.int32)),
+        (layers, lora, jnp.arange(cfg.n_layers, dtype=jnp.int32)),
     )
     return h, kv_k, kv_v
 

@@ -726,6 +726,43 @@ def test_qmm_kernel_at_qwen7b_shapes(k, n):
 
 
 @pytest.mark.usefixtures("serving_precision")
+@pytest.mark.parametrize("m", [16, 128])
+@pytest.mark.parametrize("k,n", [(3584, 3584), (3584, 512),
+                                 (3584, 18944), (18944, 3584)])
+def test_qmm_kernel_reads_its_layer_of_the_cells_stack(k, n, m):
+    """The call the decode programs make, at the dense cell's size: the
+    stacked ``s8[28, K, N]`` array of each of a Qwen2.5-7B layer's seven
+    matrices (four shapes) and a layer's number, at the rows of
+    ``_decode_multi`` (16) and of ``_decode_spec`` (128), against the XLA
+    expression on that layer's slice — the first layer, one inside, the
+    last — and not another layer's. int8 values and scales drawn directly
+    (a float32 stack to quantize would not fit beside it); the scale makes
+    outputs N(0, 1), so the tolerance is ``test_qmm_kernel_at_qwen7b_
+    shapes``'s."""
+    from runbookai_tpu.ops.qmm_pallas import qmm_pallas, qmm_pallas_eligible
+
+    layers = 28
+    assert qmm_pallas_eligible(m, k, n)
+    kq, ks, kx = jax.random.split(jax.random.PRNGKey(k + n + m), 3)
+    q = jax.random.randint(kq, (layers, k, n), -127, 128, dtype=jnp.int8)
+    s = (3 ** 0.5 / (127.0 * k ** 0.5)) * jax.random.uniform(
+        ks, (layers, 1, n), jnp.float32, 0.5, 1.5)
+    x = jax.random.normal(kx, (m, k), jnp.bfloat16)
+
+    def ref(layer):
+        return ((x @ q[layer].astype(x.dtype)) * s[layer].astype(x.dtype)
+                ).astype(jnp.float32)
+
+    for layer in (0, 13, 27):
+        got = qmm_pallas(x, q, s[layer], jnp.int32(layer), interpret=False)
+        np.testing.assert_allclose(np.asarray(got, np.float32),
+                                   np.asarray(ref(layer)),
+                                   atol=5e-2, rtol=5e-2)
+    assert not np.allclose(np.asarray(got, np.float32), np.asarray(ref(26)),
+                           atol=0.5, rtol=0.5)
+
+
+@pytest.mark.usefixtures("serving_precision")
 def test_forward_logits_pallas_vs_xla_at_qwen7b_widths():
     """Two Qwen2.5-7B-wide int8 layers (hidden 3584, 28/4 heads of 128,
     FFN 18944, q/k/v biases) through the serving forward: a 64-token

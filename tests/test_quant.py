@@ -7,6 +7,7 @@ the memory path. These tests pin the scheme's invariants on the tiny config.
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from runbookai_tpu.engine.engine import EngineConfig, EngineCore
 from runbookai_tpu.engine.request import EngineRequest, SamplingParams
@@ -194,6 +195,116 @@ def test_qmm_pallas_kernel_matches_xla_expression():
                                    rtol=2e-5, atol=2e-5)
 
 
+# The 7B cell's seven matrices, a quarter the size with the divisibility
+# kept (3584 = 2^9 x 7 -> 896 = 2^7 x 7; 18944 = 2^9 x 37 -> 4736; 512 ->
+# 128): wq/wo, wk/wv, w_gate/w_up, w_down.
+CELL_SHAPES = [(896, 896), (896, 128), (896, 4736), (4736, 896)]
+# ... and their blocks at full size and decode's M = 16.
+BLOCKS_7B = {"w_gate": (128, 18944), "w_down": (512, 3584),
+             "wq": (512, 3584), "wk": (3584, 512)}
+
+
+@pytest.fixture
+def quarter_blocks(monkeypatch):
+    """The kernel's block budget scaled down with the shapes, so that a
+    matrix is several blocks along K ((128, 896) for wq and w_down), or
+    along N ((896, 128), 37 of them, for w_gate), as at full size. The
+    budget is read while a call is traced, so what was traced under
+    another budget goes."""
+    from runbookai_tpu.ops import qmm_pallas
+
+    monkeypatch.setattr(qmm_pallas, "_BLOCK_BYTES", 256 * 1024)
+    jax.clear_caches()
+    yield qmm_pallas
+    jax.clear_caches()
+
+
+def _stack(k, n, layers=3):
+    w = jax.random.normal(jax.random.PRNGKey(k + n), (layers, k, n),
+                          jnp.float32) / k**0.5
+    return quantize_tensor(w)  # q [L, K, N] int8, s [L, 1, N] f32
+
+
+@pytest.mark.parametrize("k,n", CELL_SHAPES)
+@pytest.mark.parametrize("m", [1, 16, 17, 128, 256])
+def test_qmm_pallas_stacked_call_reads_its_layer(quarter_blocks, m, k, n):
+    """The call the decode programs make — the stacked ``[L, K, N]`` array
+    and a layer's number — is ``(x @ q[l]) * s[l]`` for every layer of the
+    stack, and a wrong number is a wrong answer."""
+    qmm_pallas = quarter_blocks
+    assert qmm_pallas.blocks(m, k, n) in {(128, 896), (896, 128)}
+    wq = _stack(k, n)
+    x = jax.random.normal(jax.random.PRNGKey(m), (m, k), jnp.float32)
+    refs = [(x @ wq["q"][l].astype(x.dtype)) * wq["s"][l] for l in range(3)]
+    for l in range(3):
+        got = qmm_pallas.qmm_pallas(x, wq["q"], wq["s"][l], jnp.int32(l),
+                                    interpret=True)
+        np.testing.assert_allclose(np.asarray(got), np.asarray(refs[l]),
+                                   rtol=2e-5, atol=2e-5)
+        wrong = refs[(l + 1) % 3]
+        assert not np.allclose(np.asarray(got), np.asarray(wrong),
+                               rtol=1e-2, atol=1e-2)
+
+
+@pytest.mark.parametrize("k,n", CELL_SHAPES)
+def test_qmm_pallas_one_matrix_is_the_stack_of_one(quarter_blocks, k, n):
+    """The 2-D call is unchanged: the same kernel at L = 1."""
+    qmm_pallas = quarter_blocks
+    wq = _stack(k, n)
+    x = jax.random.normal(jax.random.PRNGKey(3), (16, k), jnp.float32)
+    got = qmm_pallas.qmm_pallas(x, wq["q"][1], wq["s"][1], interpret=True)
+    stacked = qmm_pallas.qmm_pallas(x, wq["q"][1:2], wq["s"][1],
+                                    jnp.int32(0), interpret=True)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(stacked))
+    np.testing.assert_allclose(
+        np.asarray(got),
+        np.asarray((x @ wq["q"][1].astype(x.dtype)) * wq["s"][1]),
+        rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize("k,n", [(896, 896), (896, 4736)])
+def test_qmm_pallas_under_the_tpu_interpreter(quarter_blocks, k, n):
+    """The same call where never-written VMEM reads as NaN and the copies
+    and their semaphores are simulated (``pltpu.InterpretParams``): a
+    block multiplied before its copy landed, or a buffer overwritten while
+    it is read, would not match. bf16 activations, as served."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    qmm_pallas = quarter_blocks
+    wq = _stack(k, n)
+    x = jax.random.normal(jax.random.PRNGKey(4), (16, k), jnp.bfloat16)
+    got = qmm_pallas.qmm_pallas(x, wq["q"], wq["s"][2], jnp.int32(2),
+                                interpret=pltpu.InterpretParams())
+    ref = (x @ wq["q"][2].astype(x.dtype)).astype(jnp.float32) * wq["s"][2]
+    np.testing.assert_allclose(np.asarray(got, np.float32), np.asarray(ref),
+                               rtol=2e-2, atol=2e-2)
+
+
+def test_qmm_pallas_blocks_follow_the_shapes():
+    """Block sizes are a value of (M, K, N) and the budgets stated once in
+    the file, not an option: whole rows at decode's M, narrower where the
+    accumulator of a verify-sized M would not fit; which stacks a forward
+    hands over whole is a value of their bytes."""
+    from runbookai_tpu.ops.qmm_pallas import blocks, reads_in_place
+
+    assert blocks(16, 3584, 18944) == BLOCKS_7B["w_gate"]
+    assert blocks(16, 18944, 3584) == BLOCKS_7B["w_down"]
+    assert blocks(16, 3584, 3584) == BLOCKS_7B["wq"]
+    assert blocks(16, 3584, 512) == BLOCKS_7B["wk"]
+    for m in (128, 256):
+        bk, bn = blocks(m, 3584, 18944)
+        assert 18944 % bn == 0 and bn % 128 == 0 and bn < 18944
+        assert 8 * m * bn <= 8 * 2 ** 20  # the output side's share
+        assert 2 * bk * bn + 8 * m * bn <= 15 * 2 ** 20
+        assert 3584 % bk == 0 and bk % 128 == 0
+    # 28 layers of the 7B cell: the FFN and wq/wo stacks are read in place,
+    # wk/wv (51 MB a stack: XLA would prefetch all 28 layers) are not.
+    assert reads_in_place(16, (28, 3584, 18944))
+    assert reads_in_place(16, (28, 3584, 3584))
+    assert not reads_in_place(16, (28, 3584, 512))
+    assert not reads_in_place(640, (28, 3584, 18944))  # the mixed step's M
+
+
 def test_qmm_pallas_eligibility_boundaries():
     from runbookai_tpu.ops.qmm_pallas import MAX_PALLAS_M, qmm_pallas_eligible
 
@@ -223,14 +334,26 @@ def test_qmm_dispatch_uses_kernel_only_when_eligible():
                                rtol=2e-5, atol=2e-5)
 
 
-def test_engine_decode_matches_across_qmm_impls():
+@pytest.mark.parametrize("in_place", [True, False],
+                         ids=["stack_read_in_place", "layer_sliced_by_scan"])
+def test_engine_decode_matches_across_qmm_impls(in_place, monkeypatch):
     """Greedy engine decode with qmm_impl='pallas' reproduces the XLA
-    path's tokens on a config whose projections are kernel-eligible."""
+    path's tokens on a config whose projections are kernel-eligible, token
+    for token: with every int8 stack handed to the kernel whole with the
+    layer's number (what a serving model's large stacks get; the test
+    model's are far under the size rule, so the rule is set aside), and
+    with every matrix sliced out by the layer scan (what its small ones
+    get)."""
     from runbookai_tpu.models.llama import LlamaConfig
+    from runbookai_tpu.ops import qmm_pallas
 
+    monkeypatch.setattr(qmm_pallas, "_ON_CHIP_BYTES",
+                        0 if in_place else 1 << 40)
+    jax.clear_caches()
     cfg = LlamaConfig(name="qmm-test", vocab_size=262, dim=128, n_layers=2,
                       n_heads=4, n_kv_heads=2, ffn_dim=256, max_seq_len=512,
                       rope_theta=10_000.0)
+    assert qmm_pallas.reads_in_place(2, (2, 128, 256)) is in_place
     tok = ByteTokenizer()
     params = quantize_params(init_params(jax.random.PRNGKey(3), cfg,
                                          dtype=jnp.float32))
@@ -247,6 +370,7 @@ def test_engine_decode_matches_across_qmm_impls():
         core.submit(req)
         core.run_until_idle()
         outs[impl] = req.out_ids
+    jax.clear_caches()
     assert outs["pallas"] == outs["xla"], outs
 
 

@@ -287,14 +287,22 @@ def test_qmm_probe_runs_under_multidevice_mesh():
         "cpu", 8, 256, 512, "bfloat16", mesh=mesh)
 
 
-def test_engine_int8_dp_mesh_serves(setup):
+@pytest.mark.parametrize("in_place", [True, False],
+                         ids=["stack_read_in_place", "layer_sliced_by_scan"])
+def test_engine_int8_dp_mesh_serves(setup, in_place, monkeypatch):
     """int8 weights + multi-device DP-only mesh + qmm auto path: engine
     construction runs the mesh-aware probe and the first dispatch must
-    not crash (the ADVICE r4 failure mode)."""
+    not crash (the ADVICE r4 failure mode) — with the kernel handed the
+    replicated stacks whole (a serving model's large ones; the size rule
+    set aside for the test model's) and with each layer sliced out."""
     from runbookai_tpu.models.quant import quantize_params
+    from runbookai_tpu.ops import qmm_pallas
 
     from runbookai_tpu.parallel.mesh import replicated
 
+    monkeypatch.setattr(qmm_pallas, "_ON_CHIP_BYTES",
+                        0 if in_place else 1 << 40)
+    jax.clear_caches()
     tok, params, mesh, _ = setup
     dp_mesh = build_mesh(data=2)
     qparams = quantize_params(params)
