@@ -46,7 +46,11 @@ from runbookai_tpu.engine.flight_recorder import (
     FlightRecorder,
     OpenStep,
 )
-from runbookai_tpu.engine.kv_cache import KVCacheManager, hash_blocks
+from runbookai_tpu.engine.kv_cache import (
+    STATE_COUNTERS,
+    KVCacheManager,
+    hash_blocks,
+)
 from runbookai_tpu.engine.request import (
     EngineOutput,
     EngineRequest,
@@ -245,19 +249,64 @@ def resolve_kv_dtype(name: Optional[str], default: Any) -> Any:
 # module by name, and each returns, last, the forward's expert counts: four
 # integers for a model with an expert share (models/longcat.py
 # ``EXPERT_COUNTS``), None — no output at all — for any other.
+#
+# A model with recurrent layers (``cfg.state_pool_spec``: models/
+# qwen3_next.py) has a second pool beside the pages, indexed by batch slot:
+# ``state``, donated and carried through every step program as the page pool
+# is, with ``state_rows`` — the slot of each row of the call, where a row is
+# not its own slot — and handed back as one more result, last. For every
+# other model both are None: no operand goes in, no result comes out
+# (``_with_state``), and the program compiles to what it was.
+
+
+def _run_forward(forward, state, state_rows, *args, **kw):
+    """A family's forward as ``(logits, kv_k, kv_v, experts, state')``."""
+    if state is None:
+        return (*forward(*args, **kw), None)
+    return forward(*args, state=state, state_rows=state_rows, **kw)
+
+
+def _with_state(results: tuple, state) -> tuple:
+    """A step program's results, with the state pool last where there is one."""
+    return results if state is None else (*results, state)
+
+
+@partial(jax.jit, donate_argnums=(0,))
+def _state_admit(state, snaps, slot, src):
+    """Slot ``slot`` of the state pool starts a sequence: from row ``src``
+    of the snapshot pool (a prefix hit), or from zero (``src`` < 0)."""
+    def one(a, s):
+        if s.shape[1]:
+            row = jax.lax.dynamic_index_in_dim(s, jnp.maximum(src, 0), 1)
+            row = jnp.where(src >= 0, row, jnp.zeros_like(row))
+        else:  # no snapshot pool: every sequence starts from zero
+            row = jnp.zeros_like(a[:, :1])
+        return jax.lax.dynamic_update_slice_in_dim(a, row, slot, axis=1)
+
+    return tuple(one(a, s) for a, s in zip(state, snaps))
+
+
+@partial(jax.jit, donate_argnums=(0,))
+def _state_snapshot(snaps, state, dst, slot):
+    """Row ``dst`` of the snapshot pool becomes slot ``slot``'s state."""
+    return tuple(jax.lax.dynamic_update_slice_in_dim(
+        s, jax.lax.dynamic_index_in_dim(a, slot, 1), dst, axis=1)
+        for s, a in zip(snaps, state))
 
 
 @partial(jax.jit, static_argnames=("cfg", "page_size", "block_pages", "attn_impl",
                                    "mesh", "qmm_impl"),
-         donate_argnums=(4, 5, 14))
+         donate_argnums=(4, 5, 14), donate_argnames=("state",))
 def _decode_step(
     params, cfg, tokens, positions, kv_k, kv_v, tables, ctx_lens,
     temps, top_ps, top_ks, key, mask, adapter_ids, counts=None, pres=None,
     freq=None, seeds=None, bias=None, *, page_size: int,
     block_pages: int, attn_impl: str = "xla", mesh=None, qmm_impl: str = "xla",
+    state=None,
 ):
     forward, _ = cfg.forwards()
-    logits, kv_k, kv_v, experts = forward(
+    logits, kv_k, kv_v, experts, state = _run_forward(
+        forward, state, None,
         params, cfg, tokens, positions, kv_k, kv_v, tables, ctx_lens,
         page_size=page_size, block_pages=block_pages, attn_impl=attn_impl,
         mesh=mesh, adapter_ids=adapter_ids, qmm_impl=qmm_impl,
@@ -267,18 +316,19 @@ def _decode_step(
                         seeds=seeds, positions=ctx_lens, bias=bias)
     if counts is not None:
         counts = counts.at[jnp.arange(tok.shape[0]), tok].add(1)
-    return tok, logits[:, -1], kv_k, kv_v, counts, experts
+    return _with_state((tok, logits[:, -1], kv_k, kv_v, counts, experts), state)
 
 
 @partial(jax.jit,
          static_argnames=("cfg", "page_size", "block_pages", "k_steps", "attn_impl",
                           "mesh", "qmm_impl"),
-         donate_argnums=(4, 5, 13))
+         donate_argnums=(4, 5, 13), donate_argnames=("state",))
 def _decode_multi(
     params, cfg, tokens, positions, kv_k, kv_v, tables, ctx_lens,
     temps, top_ps, top_ks, key, adapter_ids, counts=None, pres=None,
     freq=None, seeds=None, bias=None, *, page_size: int, block_pages: int,
     k_steps: int, attn_impl: str = "xla", mesh=None, qmm_impl: str = "xla",
+    state=None,
 ):
     """K autoregressive decode steps in ONE dispatch (on-device sampling).
 
@@ -289,14 +339,19 @@ def _decode_multi(
     are discarded — their KV writes are position-addressed, so accepted tokens
     simply overwrite them later). Penalty ``counts`` and per-request
     ``seeds`` ride the scan carry, so penalized/seeded sampling keeps the
-    multi-token amortization.
+    multi-token amortization. A recurrent ``state`` rides it too, and is
+    NOT position-addressed: a row's tokens past its stop have gone into its
+    slot's state. That is sound because a stop ends the sequence: the slot
+    is zeroed or restored when it is next admitted (``_state_admit``), and
+    nothing reads it before.
     """
 
     forward, _ = cfg.forwards()
 
     def step(carry, _):
-        tokens, positions, kv_k, kv_v, ctx_lens, key, counts = carry
-        logits, kv_k, kv_v, experts = forward(
+        tokens, positions, kv_k, kv_v, ctx_lens, key, counts, state = carry
+        logits, kv_k, kv_v, experts, state = _run_forward(
+            forward, state, None,
             params, cfg, tokens, positions, kv_k, kv_v, tables, ctx_lens,
             page_size=page_size, block_pages=block_pages, attn_impl=attn_impl,
             mesh=mesh, adapter_ids=adapter_ids, qmm_impl=qmm_impl,
@@ -308,16 +363,16 @@ def _decode_multi(
         if counts is not None:
             counts = counts.at[jnp.arange(tok.shape[0]), tok].add(1)
         carry = (tok[:, None], positions + 1, kv_k, kv_v, ctx_lens + 1, key,
-                 counts)
+                 counts, state)
         return carry, (tok, experts)
 
-    (_, _, kv_k, kv_v, _, _, counts), (toks, experts) = jax.lax.scan(
-        step, (tokens, positions, kv_k, kv_v, ctx_lens, key, counts), None,
-        length=k_steps,
+    (_, _, kv_k, kv_v, _, _, counts, state), (toks, experts) = jax.lax.scan(
+        step, (tokens, positions, kv_k, kv_v, ctx_lens, key, counts, state),
+        None, length=k_steps,
     )
     if experts is not None:
         experts = jnp.sum(experts, axis=0)  # over the K passes
-    return toks.T, kv_k, kv_v, counts, experts  # [B, K]
+    return _with_state((toks.T, kv_k, kv_v, counts, experts), state)  # [B, K]
 
 
 @partial(jax.jit, static_argnames=("cfg", "page_size", "block_pages", "attn_impl",
@@ -353,22 +408,24 @@ def _decode_spec(
 
 @partial(jax.jit, static_argnames=("cfg", "page_size", "block_pages", "attn_impl",
                                    "mesh", "qmm_impl"),
-         donate_argnums=(3, 4))
+         donate_argnums=(3, 4), donate_argnames=("state",))
 def _prefill_step(
     params, cfg, tokens, kv_k, kv_v, positions, tables, ctx_lens,
     last_idx, adapter_ids, page_size: int, block_pages: int,
     attn_impl: str = "xla", mesh=None, qmm_impl: str = "xla",
+    state=None, state_rows=None,
 ):
     """Prefill one chunk for a BATCH of sequences; returns each row's final
     real-token logits ([B, vocab])."""
     forward, _ = cfg.forwards()
-    logits, kv_k, kv_v, experts = forward(
+    logits, kv_k, kv_v, experts, state = _run_forward(
+        forward, state, state_rows,
         params, cfg, tokens, positions, kv_k, kv_v, tables, ctx_lens,
         page_size=page_size, block_pages=block_pages, attn_impl=attn_impl,
         mesh=mesh, adapter_ids=adapter_ids, qmm_impl=qmm_impl,
     )
     rows = jnp.arange(logits.shape[0])
-    return logits[rows, last_idx], kv_k, kv_v, experts
+    return _with_state((logits[rows, last_idx], kv_k, kv_v, experts), state)
 
 
 # Row-run alignment of the mixed ragged token buffer: every row's token run
@@ -381,7 +438,7 @@ _RAGGED_BLOCK = 8
 @partial(jax.jit, static_argnames=("cfg", "page_size", "block_pages",
                                    "attn_impl", "mesh", "qmm_impl",
                                    "ragged_block"),
-         donate_argnums=(7, 8, 23))
+         donate_argnums=(7, 8, 23), donate_argnames=("state",))
 def _mixed_step(
     params, cfg, tokens, feed_toks, dec_idx, positions, row_ids,
     kv_k, kv_v, tables, ctx_lens, adapter_rows, pf_last_idx, temps, top_ps,
@@ -390,6 +447,7 @@ def _mixed_step(
     pf_pres=None, pf_freq=None, pf_seeds=None, pf_bias=None, *,
     page_size: int, block_pages: int, attn_impl: str = "xla", mesh=None,
     qmm_impl: str = "xla", ragged_block: int = _RAGGED_BLOCK,
+    state=None, state_rows=None,
 ):
     """ONE unified mixed prefill+decode dispatch (the ragged forward).
 
@@ -418,7 +476,8 @@ def _mixed_step(
     tokens = tokens.at[dec_idx].set(feed_toks)
     sel_idx = jnp.concatenate([dec_idx, pf_last_idx])
     _, forward_ragged = cfg.forwards()
-    logits, kv_k, kv_v, experts = forward_ragged(
+    logits, kv_k, kv_v, experts, state = _run_forward(
+        forward_ragged, state, state_rows,
         params, cfg, tokens, positions, row_ids, kv_k, kv_v, tables,
         ctx_lens, sel_idx, page_size=page_size, block_pages=block_pages,
         attn_impl=attn_impl, mesh=mesh, adapter_ids=adapter_rows,
@@ -441,7 +500,8 @@ def _mixed_step(
     if counts is not None:
         counts = counts.at[pf_slot_map, pf_tok].add(pf_live, mode="drop")
     feed_new = dec_tok.at[pf_slot_map].set(pf_tok, mode="drop")
-    return dec_tok[:, None], pf_tok, feed_new, kv_k, kv_v, counts, experts
+    return _with_state(
+        (dec_tok[:, None], pf_tok, feed_new, kv_k, kv_v, counts, experts), state)
 
 
 @functools.lru_cache(maxsize=8)
@@ -733,6 +793,18 @@ LEGACY_COUNTER_EXPORTS: tuple[tuple[str, str, str], ...] = (
      "Expert layers of a forward pass whose dispatch overflowed a held "
      "expert's slots and ran every token through every held expert "
      "instead (exact, slower)"),
+    ("state_snapshots_taken", "runbook_state_snapshots_taken_total",
+     "Recurrent-state snapshots taken where a prefill chunk ended on a "
+     "page boundary (models with recurrent layers)"),
+    ("state_snapshots_restored", "runbook_state_snapshots_restored_total",
+     "Admissions whose slot state was restored from a snapshot"),
+    ("state_snapshot_evictions", "runbook_state_snapshot_evictions_total",
+     "Snapshots dropped because the snapshot pool was full"),
+    ("state_hash_tokens_matched", "runbook_state_hash_tokens_matched_total",
+     "Prompt tokens admissions matched by page hash (models with "
+     "recurrent layers)"),
+    ("state_hash_tokens_granted", "runbook_state_hash_tokens_granted_total",
+     "Of those, the tokens granted: up to a boundary with a snapshot"),
 )
 
 def export_expert_pairs(reg, value_of: Callable[[str], float]) -> None:
@@ -890,7 +962,9 @@ class EngineCore:
             seq_axis=mesh.shape.get(_SEQ, 1) if mesh is not None else 1,
             kv_dtype=self.ecfg.kv_dtype,
             quantized=any(is_quantized(v)
-                          for v in self.params["layers"].values()))
+                          for v in self.params["layers"].values()),
+            speculative=self.ecfg.speculative,
+            draft=draft_worker is not None)
         if refused:
             raise ValueError(
                 f"model {model_cfg.name!r} (family {model_cfg.family!r}) "
@@ -965,6 +1039,9 @@ class EngineCore:
             kv_sharding = kv_pool_sharding(model_cfg, mesh)
 
         (pool_layers, pool_heads, pool_dim), v_side = model_cfg.kv_pool_spec
+        # Recurrent layers keep their state a SLOT, not a token: a second
+        # pool beside the pages, and a pool of snapshots behind prefix hits.
+        state_spec = getattr(model_cfg, "state_pool_spec", None)
         self.kv = KVCacheManager(
             n_layers=pool_layers,
             num_pages=self.ecfg.num_pages,
@@ -976,6 +1053,8 @@ class EngineCore:
             dtype=self.ecfg.kv_dtype,
             sharding=kv_sharding,
             spill_pages=self.ecfg.kv_spill_pages,
+            state_snapshots=(model_cfg.state_snapshots if state_spec
+                             else None),
         )
         self._kv_k = self.kv.pool.kv_k
         self._kv_v = self.kv.pool.kv_v
@@ -992,6 +1071,17 @@ class EngineCore:
             def _home(x):
                 return x
         self._key = _home(jax.random.PRNGKey(seed))
+        # ``[linear layers, slots, ...]`` a leaf, zeroed or restored when a
+        # slot is admitted; the snapshot pool has the same leaves with its
+        # rows in the slots' place. None: the model has no such state.
+        self._state = self._snaps = None
+        if state_spec:
+            def pool(rows):
+                return tuple(_home(jnp.zeros((shape[0], rows, *shape[1:]), dt))
+                             for shape, dt in state_spec)
+
+            self._state = pool(self.ecfg.max_batch_slots)
+            self._snaps = pool(model_cfg.state_snapshots)
 
         # OpenAI repetition penalties: device-resident per-slot token
         # counts, seeded at slot assignment from the (folded) prompt and
@@ -1069,7 +1159,14 @@ class EngineCore:
                         # (a family with no expert share leaves them 0).
                         "expert_pairs_held": 0, "expert_pairs_zero": 0,
                         "expert_pairs_absent": 0, "experts_touched": 0,
-                        "expert_overflows": 0}
+                        "expert_overflows": 0,
+                        # A model with recurrent state (the KV manager's
+                        # StateSnapshots counts them; 0 for any other):
+                        # snapshots taken at page boundaries, admissions
+                        # restored from one, snapshots evicted by a full
+                        # pool, and the prompt tokens admissions MATCHED
+                        # by page hash beside those they were GRANTED.
+                        **{"state_" + k: 0 for k in STATE_COUNTERS}}
         # Expert counts of dispatches that fetched no token of their own
         # (a prefill chunk that completed no prompt): (program, passes,
         # device array), riding the next token fetch.
@@ -1079,6 +1176,7 @@ class EngineCore:
         # per-step record reports the delta since the last recorded step
         # rather than an intra-step delta that would always read 0.
         self._flight_kv_mark = (0, 0)
+        self._state_mark: dict[str, int] = {}
         # Workload-fingerprint tap (runbookai_tpu/obs): called once per
         # finishing request from _observe_finish with the EngineRequest.
         # None = no observer; the callee appends to a bounded deque — one
@@ -1246,6 +1344,9 @@ class EngineCore:
         # mark; zeroing the counters without it would make the next
         # recorded step report a negative import delta.
         self._flight_kv_mark = (0, 0)
+        self._state_mark = {}
+        if self.kv.snapshots is not None:
+            self.kv.snapshots.reset_counters()
         self.hist_ttft.reset()
         self.hist_tpot.reset()
         self.flight.reset()
@@ -1625,6 +1726,8 @@ class EngineCore:
                                           hashes=req.block_hashes,
                                           matched=matched,
                                           hash_seed=req.adapter_idx)
+            if self._state is not None:
+                self._admit_state(req)
             req.state = RequestState.PREFILL
             req.prefill_pos = cached
             cls = class_label(req.priority)
@@ -1654,6 +1757,67 @@ class EngineCore:
                 if req.trace_id is not None:
                     meta["trace_id"] = req.trace_id
                 self.tracer.event("engine.admit", **meta)
+
+    def _admit_state(self, req: EngineRequest) -> None:
+        """Give an admitted sequence its slot of the state pool — the
+        batch slot it will decode in, held from here on, since prefill
+        already runs from and into it — and start it: from the snapshot
+        its prefix hit was granted with, or from zero."""
+        held = {r.state_slot for r in self.prefilling}
+        req.state_slot = next(i for i, s in enumerate(self._slots)
+                              if s is None and i not in held)
+        src = self.kv.seqs[req.request_id].restore_from
+        with annotate("engine.state_restore"):
+            self._state = _state_admit(
+                self._state, self._snaps, jnp.int32(req.state_slot),
+                jnp.int32(-1 if src is None else src))
+        self._sync_state_counters()
+
+    def _snapshot_state(self, req: EngineRequest, new_ctx: int) -> None:
+        """A prefill chunk of ``req`` was just issued and ends at
+        ``new_ctx``: on a page boundary, keep the state it leaves behind,
+        tied to the hash of the page that ends there (issued after the
+        chunk's program, so the device copies what the chunk wrote)."""
+        if self._state is None or new_ctx % self.ecfg.page_size:
+            return
+        dst = self.kv.take_snapshot(req.request_id, req.prompt_ids[:new_ctx],
+                                    hashes=req.block_hashes)
+        if dst is not None:
+            with annotate("engine.state_snapshot"):
+                self._snaps = _state_snapshot(
+                    self._snaps, self._state, jnp.int32(dst),
+                    jnp.int32(req.state_slot))
+        self._sync_state_counters()
+
+    def _sync_state_counters(self) -> None:
+        for name, count in self.kv.snapshots.counters.items():
+            self.metrics["state_" + name] = count
+
+    def _state_rows(self, reqs, n: int) -> Optional[jax.Array]:
+        """The state-pool slot of each of ``n`` rows (``reqs`` first, then
+        pads, which get a slot out of range: read as any, never written)."""
+        if self._state is None:
+            return None
+        rows = np.full((n,), self.ecfg.max_batch_slots, dtype=np.int32)
+        for i, r in enumerate(reqs):
+            if r is not None:
+                rows[i] = r.slot if r.slot is not None else r.state_slot
+        return jnp.asarray(rows)
+
+    def _keep_state(self, results: tuple) -> tuple:
+        """A step program's results less the state pool it hands back last
+        (a model with one), which becomes the engine's."""
+        if self._state is None:
+            return results
+        *rest, self._state = results
+        return tuple(rest)
+
+    def _free_slot(self, req: EngineRequest) -> int:
+        """The batch slot a request that finished its prompt decodes in:
+        the one its state is in, else the lowest free."""
+        if req.state_slot is not None:
+            return req.state_slot
+        return self._slots.index(None)
 
     @staticmethod
     def _fold_into_prompt(req: EngineRequest, prefill_pos: int) -> None:
@@ -1687,6 +1851,7 @@ class EngineCore:
         if victim.slot is not None:
             self._slots[victim.slot] = None
             victim.slot = None
+        victim.state_slot = None  # re-admission restores or recomputes
         # Publish the victim's full pages before freeing: re-admission will
         # match its own prefix and recompute only the tail.
         self.kv.release(victim.request_id, token_ids=self._kv_valid_tokens(victim))
@@ -1780,6 +1945,7 @@ class EngineCore:
         if req.slot is not None:
             self._slots[req.slot] = None
             req.slot = None
+        req.state_slot = None
         if req in self.decoding:
             self.decoding.remove(req)
         if req in self.prefilling:
@@ -1804,6 +1970,7 @@ class EngineCore:
         if req.slot is not None and req.slot < len(self._slots):
             self._slots[req.slot] = None
             req.slot = None
+        req.state_slot = None
         try:
             if req.request_id in self.kv.seqs:
                 self.kv.release(req.request_id,
@@ -1922,15 +2089,18 @@ class EngineCore:
             pf_meta["requests"] = [r.request_id for r, _, _ in rows]
         with self.tracer.span("engine.prefill", **pf_meta), \
                 annotate("prefill"), self._span("issue"):
-            last_logits, self._kv_k, self._kv_v, experts = _prefill_step(
+            last_logits, self._kv_k, self._kv_v, experts = self._keep_state(_prefill_step(
                 self.params, self.cfg, jnp.asarray(tokens), self._kv_k, self._kv_v,
                 jnp.asarray(positions), jnp.asarray(tables),
                 jnp.asarray(ctx_lens), jnp.asarray(last_idx),
                 jnp.asarray(adapter_ids),
                 page_size=self.ecfg.page_size, block_pages=self.ecfg.block_pages,
                 attn_impl=self.ecfg.attn_impl, mesh=self.mesh,
-                qmm_impl=self.ecfg.qmm_impl,
-            )
+                qmm_impl=self.ecfg.qmm_impl, state=self._state,
+                state_rows=self._state_rows([r for r, _, _ in rows], b),
+            ))
+        for req, _, new_ctx in rows:
+            self._snapshot_state(req, new_ctx)
         if self._open is not None:
             self._open.dispatched("_prefill_step")
         if experts is not None:
@@ -1961,7 +2131,7 @@ class EngineCore:
                 self.kv.register_prefix(req.request_id, req.prompt_ids,
                                         hashes=req.block_hashes)
                 self.prefilling.remove(req)
-                slot = self._slots.index(None)
+                slot = self._free_slot(req)
                 self._slots[slot] = req
                 req.slot = slot
                 req.state = RequestState.DECODE
@@ -2503,7 +2673,12 @@ class EngineCore:
             free = [i for i, s in enumerate(self._slots) if s is None]
             for j, (req, chunk, new_ctx) in enumerate(pf_rows):
                 if new_ctx >= len(req.prompt_ids):
-                    done.append((j, req, free.pop(0)))
+                    # A sequence with recurrent state decodes where its
+                    # state is (no other prefilling request holds it).
+                    slot = (free[0] if req.state_slot is None
+                            else req.state_slot)
+                    free.remove(slot)
+                    done.append((j, req, slot))
             fresh_pen = np.zeros((b,), dtype=bool)
             for j, req, slot in done:
                 if req.sampling.penalized:
@@ -2565,7 +2740,7 @@ class EngineCore:
                 annotate("mixed"), self._span("issue"):
             t_issue = time.perf_counter()
             (toks_win, pf_toks, feed_new, self._kv_k, self._kv_v,
-             counts_out, experts) = _mixed_step(
+             counts_out, experts) = self._keep_state(_mixed_step(
                 self.params, self.cfg, jnp.asarray(tokens), self._feed_toks,
                 jnp.asarray(dec_idx), jnp.asarray(positions),
                 jnp.asarray(row_ids), self._kv_k, self._kv_v,
@@ -2589,7 +2764,13 @@ class EngineCore:
                 block_pages=self.ecfg.block_pages,
                 attn_impl=self.ecfg.attn_impl, mesh=self.mesh,
                 qmm_impl=self.ecfg.qmm_impl, ragged_block=rq,
-            )
+                state=self._state,
+                state_rows=self._state_rows(
+                    list(self._slots) + [r for r, _, _ in pf_rows],
+                    self._mix_rows),
+            ))
+        for req, _, new_ctx in pf_rows:
+            self._snapshot_state(req, new_ctx)
         if counts_out is not None:
             self._tok_counts = counts_out
         self._feed_toks = feed_new
@@ -2801,7 +2982,7 @@ class EngineCore:
             last_logits = None
             if k == 1:
                 (toks, last_logits, self._kv_k, self._kv_v,
-                 counts_out, experts) = _decode_step(
+                 counts_out, experts) = self._keep_state(_decode_step(
                     self.params, self.cfg, tokens_dev, jnp.asarray(positions),
                     self._kv_k, self._kv_v, si.tables, jnp.asarray(ctx_lens),
                     si.temps, si.top_ps, si.top_ks, sub,
@@ -2809,21 +2990,21 @@ class EngineCore:
                     si.adapters, **pen_kw,
                     page_size=self.ecfg.page_size, block_pages=self.ecfg.block_pages,
                     attn_impl=self.ecfg.attn_impl, mesh=self.mesh,
-                    qmm_impl=self.ecfg.qmm_impl,
-                )
+                    qmm_impl=self.ecfg.qmm_impl, state=self._state,
+                ))
                 self._feed_toks = toks
                 toks_win = toks[:, None]  # [B, 1]
             else:
                 (toks_win, self._kv_k, self._kv_v, counts_out,
-                 experts) = _decode_multi(
+                 experts) = self._keep_state(_decode_multi(
                     self.params, self.cfg, tokens_dev, jnp.asarray(positions),
                     self._kv_k, self._kv_v, si.tables, jnp.asarray(ctx_lens),
                     si.temps, si.top_ps, si.top_ks, sub,
                     si.adapters, **pen_kw,
                     page_size=self.ecfg.page_size, block_pages=self.ecfg.block_pages,
                     k_steps=k, attn_impl=self.ecfg.attn_impl, mesh=self.mesh,
-                    qmm_impl=self.ecfg.qmm_impl,
-                )
+                    qmm_impl=self.ecfg.qmm_impl, state=self._state,
+                ))
                 self._feed_toks = toks_win[:, -1]
             if counts_out is not None:
                 self._tok_counts = counts_out
@@ -2990,6 +3171,14 @@ class EngineCore:
         }
         if step.experts is not None:
             rec["experts"] = step.experts
+        if self._state is not None:
+            # Slots that hold a sequence's state (decoding or prefilling),
+            # and the snapshot counters' growth over this step.
+            rec["state"] = {
+                "slots_live": len(self.decoding) + len(self.prefilling),
+                **{k: m["state_" + k] - self._state_mark.get(k, 0)
+                   for k in STATE_COUNTERS}}
+            self._state_mark = {k: m["state_" + k] for k in STATE_COUNTERS}
         self._admitted_log, self._finished_log = [], []
         # Page transfers land BETWEEN steps (cross-replica pulls, disagg
         # handoffs, spill readmits run under the engine lock outside

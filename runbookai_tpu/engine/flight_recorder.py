@@ -60,8 +60,12 @@ STEP_RECORD_FIELDS = (
 # token-expert pairs on ``held``, identity (``zero``) and ``absent``
 # experts, held experts ``touched`` and expert layers whose dispatch took
 # the slow path (``overflow``), summed over layers, from ``passes`` forward
-# passes of ``programs``.
-OPTIONAL_STEP_FIELDS = ("experts",)
+# passes of ``programs``. ``state``: where the model has recurrent layers
+# (models/qwen3_next.py), in every step: ``slots_live`` (slots of the state
+# pool that hold a sequence), and this step's ``snapshots_taken``,
+# ``snapshots_restored``, ``snapshot_evictions``, ``hash_tokens_matched``
+# and ``hash_tokens_granted`` (engine/kv_cache.py ``StateSnapshots``).
+OPTIONAL_STEP_FIELDS = ("experts", "state")
 
 # ``phases`` keys besides "other" (= wall_s less their sum), and the
 # profiler span that marks the same boundaries on the device trace's

@@ -223,12 +223,17 @@ def lower_decode(core, *, qmm_impl: str | None = None,
             step, args = engine._decode_step, args + (None, i32((b,)))
         else:
             raise ValueError(f"no such decode program: {program!r}")
+    traced = {}
+    if core._state is not None:  # a model with recurrent state: its pool
+        traced["state"] = core._state
+        if program == "_mixed_step":
+            traced["state_rows"] = i32((rows,))
     if sharding is not None:
-        args = jax.tree.map(
+        args, traced = jax.tree.map(
             lambda a: (jax.ShapeDtypeStruct(a.shape, a.dtype,
                                             sharding=sharding)
-                       if isinstance(a, jax.Array) else a), args)
-    return step.lower(*args, **static).compile()
+                       if isinstance(a, jax.Array) else a), (args, traced))
+    return step.lower(*args, **static, **traced).compile()
 
 
 def kv_pool_shapes(core) -> set[tuple[int, ...]]:

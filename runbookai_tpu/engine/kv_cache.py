@@ -18,6 +18,15 @@ Two interchangeable backends implement the allocator+index: pure Python here,
 and the C++ one in :mod:`runbookai_tpu.native` (selected automatically when
 the compiled library is available; ``RUNBOOKAI_NATIVE=0`` disables).
 
+A model some of whose layers keep RECURRENT state (a fixed-size matrix a
+sequence, not token rows: ``models/qwen3_next.py``) makes a page hit only
+half a hit: the pages hold the attention layers' keys and values up to the
+boundary, and the recurrent layers' state AT that boundary has to be at
+hand too. :class:`StateSnapshots` is the host-side index of a small device
+pool of such states, each tied to the chain hash of the page that ends at
+its boundary; with one, a match is GRANTED only back to the deepest
+boundary that has a snapshot (to nothing if none has).
+
 No reference counterpart (SURVEY.md §2.9 item 2 — green-field requirement).
 """
 
@@ -369,6 +378,86 @@ class PageAllocator:
             del self._hash_to_page[h]
 
 
+STATE_COUNTERS = ("snapshots_taken", "snapshots_restored", "snapshot_evictions",
+                  "hash_tokens_matched", "hash_tokens_granted")
+
+
+@dataclass
+class _Snapshot:
+    idx: int  # row of the device snapshot pool
+    block_hash: int  # chain hash of the page that ends at the boundary
+    page: int  # the page that held it when the snapshot was taken
+    hits: int = 0  # admissions restored from it
+    last_use: int = 0
+
+
+class StateSnapshots:
+    """Host-side index of the device pool of recurrent-state snapshots.
+
+    A snapshot is the recurrent layers' state after exactly the tokens of
+    a page-aligned prefix, keyed by that prefix's chain hash. The engine
+    copies a slot's state into row ``idx`` of the pool when a prefill
+    chunk ends on a page boundary (:meth:`KVCacheManager.take_snapshot`)
+    and copies it back into a slot when an admission is granted that
+    boundary. A snapshot leaves with its page (:meth:`sweep`: the hash no
+    longer resolves to the page it was taken beside) or before it, when
+    the pool is full: the victim is the least recently taken of the
+    snapshots that were NEVER restored, and only when every one has been,
+    the least recently restored — a shared prefix's boundary outlives the
+    boundaries inside the one-off prompts that follow it."""
+
+    def __init__(self, size: int):
+        self.size = int(size)
+        self._by_hash: dict[int, _Snapshot] = {}
+        self._free = list(range(self.size - 1, -1, -1))
+        self._tick = 0
+        self.reset_counters()
+
+    def __len__(self) -> int:
+        return len(self._by_hash)
+
+    def reset_counters(self) -> None:
+        """What the engine's ``state_*`` metrics and the step record's
+        ``state`` report: snapshots taken, admissions restored from one,
+        snapshots evicted by a full pool, and the prompt tokens admissions
+        matched by page hash beside those they were granted."""
+        self.counters = dict.fromkeys(STATE_COUNTERS, 0)
+
+    def lookup(self, block_hash: int) -> Optional[_Snapshot]:
+        return self._by_hash.get(block_hash)
+
+    def sweep(self, allocator) -> None:
+        """Drop the snapshots whose page was recycled or re-registered."""
+        for h, snap in list(self._by_hash.items()):
+            if allocator.lookup(h) != snap.page:
+                del self._by_hash[h]
+                self._free.append(snap.idx)
+
+    def touch(self, snap: _Snapshot) -> None:
+        self._tick += 1
+        snap.hits += 1
+        snap.last_use = self._tick
+        self.counters["snapshots_restored"] += 1
+
+    def take(self, block_hash: int, page: int) -> Optional[int]:
+        """The pool row to copy a state into for ``block_hash``, or None
+        when it already has a snapshot (or the pool has no rows)."""
+        if not self.size or block_hash in self._by_hash:
+            return None
+        if not self._free:
+            victim = min(self._by_hash.values(),
+                         key=lambda s: (s.hits > 0, s.last_use))
+            del self._by_hash[victim.block_hash]
+            self._free.append(victim.idx)
+            self.counters["snapshot_evictions"] += 1
+        self._tick += 1
+        snap = _Snapshot(self._free.pop(), block_hash, page,
+                         last_use=self._tick)
+        self._by_hash[block_hash] = snap
+        self.counters["snapshots_taken"] += 1
+        return snap.idx
+
+
 @dataclass
 class SequenceAllocation:
     """Pages owned by one live sequence."""
@@ -377,6 +466,9 @@ class SequenceAllocation:
     ctx_len: int = 0  # tokens currently cached
     registered_blocks: int = 0  # full pages whose hashes are published
     hash_seed: int = 0  # prefix-cache namespace (LoRA adapter_idx)
+    # Row of the snapshot pool the admission was granted its prefix from
+    # (a model with recurrent state): the engine restores it into the slot.
+    restore_from: Optional[int] = None
 
     def pages_needed(self, new_len: int, page_size: int) -> int:
         have = len(self.pages)
@@ -400,7 +492,14 @@ class KVCacheManager:
         sharding=None,
         spill_pages: int = 0,
         v_side: Optional[tuple[int, int, int]] = None,
+        state_snapshots: Optional[int] = None,
     ):
+        # None: every layer's state is token rows in pages, and a page hit
+        # is the whole hit. A number (0 included): the model also keeps
+        # recurrent state, and a hit is granted only to a boundary whose
+        # snapshot is among that many (the engine owns the device pool).
+        self.snapshots: Optional[StateSnapshots] = (
+            None if state_snapshots is None else StateSnapshots(state_snapshots))
         self.pool = PagePool.create(n_layers, num_pages, page_size, n_kv_heads,
                                     head_dim, dtype, sharding=sharding,
                                     v_side=v_side)
@@ -468,12 +567,33 @@ class KVCacheManager:
             matched.append(page)
         return matched
 
+    def _grant(self, matched: list[int], prompt_ids: Sequence[int],
+               hashes: Optional[list[int]], hash_seed: int = 0,
+               sweep: bool = True) -> tuple[list[int], Optional[_Snapshot]]:
+        """What of a verified hash match an admission may USE: all of it
+        where pages are the whole state; with recurrent state, the pages
+        up to the deepest boundary that has a snapshot, and that snapshot
+        (nothing, if no boundary of the match has one)."""
+        if self.snapshots is None:
+            return matched, None
+        if sweep:
+            self.snapshots.sweep(self.allocator)
+        chain = self._prompt_hashes(prompt_ids, hashes, hash_seed)
+        for b in range(len(matched), 0, -1):
+            snap = self.snapshots.lookup(chain[b - 1])
+            if snap is not None:
+                return matched[:b], snap
+        return [], None
+
     def match_prefix(self, prompt_ids: Sequence[int],
                      hashes: Optional[list[int]] = None,
                      hash_seed: int = 0) -> int:
-        """Longest reusable page-aligned prefix length (read-only probe)."""
-        return len(self._match_pages(prompt_ids, hashes,
-                                     hash_seed)) * self.page_size
+        """Longest reusable page-aligned prefix length (read-only probe:
+        routers call it without the engine's lock, so nothing is swept)."""
+        matched = self._match_pages(prompt_ids, hashes, hash_seed)
+        granted, _ = self._grant(matched, prompt_ids, hashes, hash_seed,
+                                 sweep=False)
+        return len(granted) * self.page_size
 
     def probe_admit(self, prompt_ids: Sequence[int], headroom_tokens: int = 0,
                     hashes: Optional[list[int]] = None,
@@ -488,7 +608,9 @@ class KVCacheManager:
         pages are returned so ``add_sequence(matched=...)`` needn't re-walk
         the chain (valid only until the next alloc/release).
         """
-        matched = self._match_pages(prompt_ids, hashes, hash_seed)
+        matched, _ = self._grant(
+            self._match_pages(prompt_ids, hashes, hash_seed), prompt_ids,
+            hashes, hash_seed)
         cached = len(matched) * self.page_size
         reserved = sum(1 for p in matched if self.allocator.is_retired(p))
         need = self.add_pages_needed(len(prompt_ids), cached, headroom_tokens)
@@ -507,8 +629,20 @@ class KVCacheManager:
         alloc = SequenceAllocation(hash_seed=hash_seed)
         cached = 0
         if prompt_ids:
-            pages = (matched if matched is not None
-                     else self._match_pages(prompt_ids, hashes, hash_seed))
+            if self.snapshots is not None:
+                # The match is walked again: what was matched and what of
+                # it is granted are both counted, here, once an admission.
+                full = self._match_pages(prompt_ids, hashes, hash_seed)
+                pages, snap = self._grant(full, prompt_ids, hashes, hash_seed)
+                counters = self.snapshots.counters
+                counters["hash_tokens_matched"] += len(full) * self.page_size
+                counters["hash_tokens_granted"] += len(pages) * self.page_size
+                if snap is not None:
+                    self.snapshots.touch(snap)
+                    alloc.restore_from = snap.idx
+            else:
+                pages = (matched if matched is not None
+                         else self._match_pages(prompt_ids, hashes, hash_seed))
             for page in pages:
                 self.allocator.acquire(page)
                 alloc.pages.append(page)
@@ -540,6 +674,27 @@ class KVCacheManager:
                 self._page_tokens[page] = tuple(
                     token_ids[b * self.page_size : (b + 1) * self.page_size])
         alloc.registered_blocks = max(alloc.registered_blocks, max_blocks)
+
+    def take_snapshot(self, seq_id: str, token_ids: Sequence[int],
+                      hashes: Optional[list[int]] = None) -> Optional[int]:
+        """A prefill chunk of ``seq_id`` ended on a page boundary, after
+        ``token_ids``: publish its full pages (a snapshot is only ever
+        reached through a verified page match) and name the row of the
+        snapshot pool the engine should copy the slot's state into — None
+        where that boundary has a snapshot already, or its page lost the
+        publish to nobody (no page backs the hash)."""
+        if self.snapshots is None or not len(token_ids) \
+                or len(token_ids) % self.page_size:
+            return None
+        self.register_prefix(seq_id, token_ids, hashes)
+        block = len(token_ids) // self.page_size - 1
+        alloc = self.seqs[seq_id]
+        if hashes is None or len(hashes) <= block:
+            hashes = hash_blocks(token_ids, self.page_size, seed=alloc.hash_seed)
+        page = self.allocator.lookup(hashes[block])
+        if page is None:
+            return None
+        return self.snapshots.take(hashes[block], page)
 
     # ------------------------------------------------- page transfer / spill
 

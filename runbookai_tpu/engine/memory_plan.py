@@ -24,7 +24,10 @@ The headline numbers it encodes (v5e, 16 GB/chip):
 
 from __future__ import annotations
 
+import math
 from dataclasses import dataclass
+
+import numpy as np
 
 GiB = 1024**3
 
@@ -45,6 +48,10 @@ class ServingPlan:
     # pins in HOST memory, not HBM — it never competes with the pool
     # budget above, but an operator sizing a box must still see it.
     host_spill_bytes: int = 0
+    # A model with recurrent layers (``cfg.state_pool_spec``): its state
+    # pool of ``batch`` slots and its snapshot pool, already taken out of
+    # ``pool_budget_bytes``. 0 for every other model.
+    state_pool_bytes: int = 0
 
     @property
     def context_bytes_per_chip(self) -> float:
@@ -73,6 +80,9 @@ class ServingPlan:
     def explain(self) -> str:
         spill = (f"; host spill tier {self.host_spill_bytes / GiB:.2f} GiB "
                  f"(host RAM)" if self.host_spill_bytes else "")
+        if self.state_pool_bytes:
+            spill += (f"; recurrent state pool {self.state_pool_bytes / GiB:.2f}"
+                      f" GiB (slots and snapshots, out of the pool budget)")
         return (
             f"{self.model} tp{self.tp} (kv{self.kv_shards}×pg"
             f"{self.pg_shards}): weights {self.weight_bytes_per_chip / GiB:.2f}"
@@ -139,7 +149,14 @@ def plan_serving(
     spill_token = sum(layers * heads * (dim * kv_dtype_bytes + kv_scale_bytes)
                       for layers, heads, dim in sides)
     kv_per_token = spill_token / max(plan.kv_shards, 1) / max(plan.pg_shards, 1)
-    budget = max(0, hbm_bytes - int(per_chip) - headroom_bytes)
+    # Recurrent layers' state (the configuration says which arrays, in
+    # which precision): a batch slot and a snapshot.
+    state_bytes = 0
+    if getattr(cfg, "state_pool_spec", None):
+        slot = sum(math.prod(shape) * np.dtype(dtype).itemsize
+                   for shape, dtype in cfg.state_pool_spec)
+        state_bytes = (batch + cfg.state_snapshots) * slot
+    budget = max(0, hbm_bytes - int(per_chip) - headroom_bytes - state_bytes)
     return ServingPlan(
         model=cfg.name, tp=tp, kv_shards=plan.kv_shards,
         pg_shards=plan.pg_shards, hbm_bytes=hbm_bytes,
@@ -147,4 +164,5 @@ def plan_serving(
         kv_bytes_per_token_per_chip=kv_per_token,
         pool_budget_bytes=budget, max_seq_len=max_seq_len, batch=batch,
         host_spill_bytes=int(kv_spill_pages * page_size * spill_token),
+        state_pool_bytes=state_bytes,
     )

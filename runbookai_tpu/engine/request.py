@@ -144,6 +144,9 @@ class EngineRequest:
     # Memoized full-page hash chain over prompt_ids (admission hot path).
     block_hashes: Optional[list[int]] = None
     slot: Optional[int] = None  # decode batch slot index
+    # The slot of the state pool a sequence with recurrent layers runs in,
+    # from admission on (prefill included); it decodes in the same one.
+    state_slot: Optional[int] = None
     first_token_time: Optional[float] = None  # TTFT measurement
     finish_time: Optional[float] = None  # set by _finish; e2e/TPOT source
     finish_reason: Optional[FinishReason] = None

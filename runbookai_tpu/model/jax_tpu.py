@@ -505,6 +505,11 @@ class JaxTpuClient(BaseLLMClient):
             # Per engine replica, summed over the devices it spans.
             "weight_bytes": param_nbytes(core.params),
             "kv_pool_bytes": kv_pool_nbytes(core),
+            # The slot-indexed pool of recurrent state and its snapshot
+            # pool (0 for a model whose state is all pages).
+            "state_pool_bytes": sum(
+                leaf.nbytes
+                for leaf in jax.tree.leaves((core._state, core._snaps))),
             "compile_cache_dir": jax.config.jax_compilation_cache_dir,
             # Which devices hold each engine replica's KV pool.
             "replicas": [

@@ -62,6 +62,10 @@ def config_from_hf(model_dir: str | Path, name: str = "hf-model") -> LlamaConfig
         raise NotImplementedError(
             f"model_type {model_type!r}: the longcat family runs on seeded "
             f"random weights only (models/longcat.py); no checkpoint loader yet")
+    if model_type.startswith("qwen3_next"):
+        raise NotImplementedError(
+            f"model_type {model_type!r}: the qwen3-next family runs on seeded "
+            f"random weights only (models/qwen3_next.py); no checkpoint loader yet")
     if model_type not in SUPPORTED_MODEL_TYPES:
         raise ValueError(
             f"model_type {model_type!r} not supported; known: "
@@ -275,6 +279,7 @@ def load_or_init(
     environment (BASELINE.md configs run with real weights when provided).
     """
     from runbookai_tpu.models.longcat import LongcatConfig
+    from runbookai_tpu.models.qwen3_next import Qwen3NextConfig
 
     longcat = isinstance(CONFIGS.get(model_name), LongcatConfig)
     if longcat and model_path and Path(model_path).exists():
@@ -282,6 +287,13 @@ def load_or_init(
             f"model {model_name!r}: no loader for checkpoints of the longcat "
             f"family yet (MLA and expert tensor names); leave llm.model_path "
             f"unset to serve seeded random weights")
+    qwen3_next = isinstance(CONFIGS.get(model_name), Qwen3NextConfig)
+    if qwen3_next and model_path and Path(model_path).exists():
+        raise NotImplementedError(
+            f"model {model_name!r}: no loader for checkpoints of the "
+            f"qwen3-next family yet (linear-attention and expert tensor "
+            f"names); leave llm.model_path unset to serve seeded random "
+            f"weights")
     if model_path and Path(model_path).exists():
         from runbookai_tpu.models.checkpoint import is_checkpoint, load_checkpoint
 
@@ -308,14 +320,17 @@ def load_or_init(
             f"unknown model {model_name!r} and no checkpoint at "
             f"{str(model_path)!r}; known configs: {sorted(CONFIGS)}")
     cfg = CONFIGS[model_name]
-    if longcat:
+    if longcat or qwen3_next:
         from runbookai_tpu.models import longcat as longcat_model
+        from runbookai_tpu.models import qwen3_next as qwen3_next_model
 
         if quantize_int8 or shardings:
             raise ValueError(
-                f"model {model_name!r} (family longcat) serves bf16 or "
+                f"model {model_name!r} (family "
+                f"{'longcat' if longcat else 'qwen3-next'}) serves bf16 or "
                 f"float32 weights on one chip: no int8 matrices, no mesh")
-        return cfg, quiet_control_tokens(longcat_model.init_params(
+        model = longcat_model if longcat else qwen3_next_model
+        return cfg, quiet_control_tokens(model.init_params(
             jax.random.PRNGKey(seed), cfg, dtype=dtype), cfg.vocab_size)
     # int8 leaves are sampled directly: a 7B bf16 tree (15 GB) plus the
     # float32 temporaries of quantizing it cannot exist on a 16 GB chip.

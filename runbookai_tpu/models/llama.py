@@ -36,6 +36,8 @@ import jax.numpy as jnp
 
 from runbookai_tpu.models.longcat import CONFIGS as _LONGCAT_CONFIGS
 from runbookai_tpu.models.longcat import LongcatConfig
+from runbookai_tpu.models.qwen3_next import CONFIGS as _QWEN3_NEXT_CONFIGS
+from runbookai_tpu.models.qwen3_next import Qwen3NextConfig
 from runbookai_tpu.ops.attention import paged_attention, write_kv_pages_batch
 from runbookai_tpu.ops.rope import apply_rope
 
@@ -81,6 +83,8 @@ class LlamaConfig:
 
     # The engine's Pallas attention kernels read this family's pages.
     pallas_attention = True
+    # No layer keeps state that is not token rows in pages.
+    state_pool_spec = None
 
     @property
     def head_dim(self) -> int:
@@ -138,7 +142,7 @@ class LlamaConfig:
                 + ffn_delta)
 
 
-CONFIGS: dict[str, LlamaConfig | LongcatConfig] = {
+CONFIGS: dict[str, LlamaConfig | LongcatConfig | Qwen3NextConfig] = {
     "llama3-8b-instruct": LlamaConfig(
         name="llama3-8b-instruct", vocab_size=128_256, dim=4096, n_layers=32,
         n_heads=32, n_kv_heads=8, ffn_dim=14_336,
@@ -247,10 +251,12 @@ CONFIGS: dict[str, LlamaConfig | LongcatConfig] = {
     ),
     # Another architecture, its own dataclass and forward (models/longcat.py).
     **_LONGCAT_CONFIGS,
+    # A period of unlike layers over two kinds of state (models/qwen3_next.py).
+    **_QWEN3_NEXT_CONFIGS,
 }
 
 
-def get_config(name: str) -> LlamaConfig | LongcatConfig:
+def get_config(name: str) -> LlamaConfig | LongcatConfig | Qwen3NextConfig:
     if name not in CONFIGS:
         raise KeyError(f"Unknown model {name!r}; known: {sorted(CONFIGS)}")
     return CONFIGS[name]

@@ -107,6 +107,8 @@ class LongcatConfig:
     # latent cache has none, so attention here is the XLA path whatever
     # ``attn_impl`` asks for (the engine resolves it to "xla" and says so).
     pallas_attention = False
+    # No layer keeps state that is not token rows in pages.
+    state_pool_spec = None
 
     @property
     def dim(self) -> int:
@@ -143,7 +145,7 @@ class LongcatConfig:
         return forward_counted, forward_ragged_counted
 
     def unsupported(self, *, lora: bool, model_axis: int, seq_axis: int,
-                    kv_dtype, quantized: bool) -> list[str]:
+                    kv_dtype, quantized: bool, **_asked) -> list[str]:
         """What this family's forward does not do yet, of what the engine
         was asked for — refused by name at engine init, never served
         wrong."""

@@ -135,8 +135,18 @@ def paged_attention(
     q_positions: jnp.ndarray,  # [B, T] absolute positions of the queries
     page_size: int,
     block_pages: int = 32,
+    walk_live: bool = False,
+    gather_pages: bool = False,
 ) -> jnp.ndarray:
-    """Blockwise ragged paged attention. Returns [B, T, n_q, head_dim]."""
+    """Blockwise ragged paged attention. Returns [B, T, n_q, head_dim].
+
+    ``walk_live``: stop at the block that holds the batch's longest context
+    (a loop whose length the device decides) instead of walking every block
+    of the page table: a pool sized for 16k-token sequences costs a batch
+    of 3k-token ones a fifth of the gathers. ``gather_pages``: gather WHOLE
+    pages (``[page_size, n_kv, head_dim]`` a row of the gather) instead of
+    token rows: on the chip a gather of 1 KB rows ran at 92 GB/s (PERF.md,
+    PR 31). Raw-dtype pools only."""
     b, t, n_q, d = q.shape
     n_kv = (k_flat[0] if isinstance(k_flat, tuple) else k_flat).shape[1]
     group = n_q // n_kv
@@ -160,8 +170,22 @@ def paged_attention(
         flat_idx = (
             phys_blk[:, token_off // page_size] * page_size + token_off % page_size
         )  # [B, block_tokens]
-        kb = _dequant_gather(k_flat, flat_idx)  # [B, block_tokens, n_kv, d]
-        vb = _dequant_gather(v_flat, flat_idx)
+        if gather_pages:
+            def pages_of(flat):
+                # ``page_size`` consecutive token rows a gathered row, out of
+                # the pool as it lies: a ``[pages, page_size, ...]`` VIEW of a
+                # ``[tokens, 2, 256]`` pool was re-laid out whole, 403 MB a
+                # side and call (seen in the compiled program).
+                slabs = jax.vmap(lambda start: jax.lax.dynamic_slice_in_dim(
+                    flat, start, page_size, axis=0))(
+                        (phys_blk * page_size).reshape(-1))
+                return slabs.reshape(b, block_tokens, *flat.shape[1:]).astype(
+                    jnp.float32)
+
+            kb, vb = pages_of(k_flat), pages_of(v_flat)
+        else:
+            kb = _dequant_gather(k_flat, flat_idx)  # [B, block_tokens, n_kv, d]
+            vb = _dequant_gather(v_flat, flat_idx)
 
         # Absolute cache positions covered by this block (same for every seq).
         cache_pos = blk * block_tokens + token_off  # [block_tokens]
@@ -185,7 +209,13 @@ def paged_attention(
     m0 = jnp.full((b, t, n_kv, group), NEG_INF, dtype=jnp.float32)
     l0 = jnp.zeros((b, t, n_kv, group), dtype=jnp.float32)
     acc0 = jnp.zeros((b, t, n_kv, group, d), dtype=jnp.float32)
-    (m, l, acc), _ = jax.lax.scan(block_step, (m0, l0, acc0), jnp.arange(n_blocks))
+    if walk_live:
+        live_blocks = jnp.minimum(
+            n_blocks, (jnp.max(ctx_lens) + block_tokens - 1) // block_tokens)
+        m, l, acc = jax.lax.fori_loop(
+            0, live_blocks, lambda blk, c: block_step(c, blk)[0], (m0, l0, acc0))
+    else:
+        (m, l, acc), _ = jax.lax.scan(block_step, (m0, l0, acc0), jnp.arange(n_blocks))
 
     out = acc / jnp.maximum(l[..., None], 1e-30)
     return out.reshape(b, t, n_q, d).astype(q.dtype)

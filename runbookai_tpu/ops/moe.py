@@ -117,14 +117,44 @@ def route_scaled(
     return chosen, scale * jnp.take_along_axis(s, chosen, axis=-1)
 
 
-def held_capacity(n_tokens: int, top_k: int, n_outputs: int) -> int:
-    """Slots a held expert's queue gets on the fast path: eight times its
-    expected load under even routing (``n_tokens * top_k / n_outputs``), at
-    least 8, never more than every token. A queue that would overflow sends
-    the call down the exact slow path instead (:func:`held_expert_ffn`), so
-    the number trades speed only, never a token."""
+def route_renormalised(
+    u: jnp.ndarray,            # [N, D] post-norm hidden
+    router: jnp.ndarray,       # [D, E] float32
+    top_k: int,
+) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """Scores ``p = softmax_f32(u W_g)`` over ALL experts; the ``top_k``
+    largest are chosen and their weights renormalised to sum to one
+    (``norm_topk_prob``). Returns (chosen ids [N, K], weights [N, K] f32).
+    As :func:`route_scaled`, the product runs at ``highest`` precision."""
+    logits = jnp.dot(u.astype(jnp.float32), router.astype(jnp.float32),
+                     precision=jax.lax.Precision.HIGHEST)
+    w, chosen = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), top_k)
+    return chosen, w / jnp.sum(w, axis=-1, keepdims=True)
+
+
+def shared_expert(u: jnp.ndarray, w_gate: Any, w_up: Any, w_down: Any,
+                  gate: jnp.ndarray) -> jnp.ndarray:
+    """``sigmoid(u w_sg) * SwiGLU_shared(u)``: the expert every token runs,
+    under its own sigmoid gate (``gate`` [D, 1]). Every share of an
+    expert-parallel group computes it for its own tokens; a sum over the
+    shares counts it once."""
+    from runbookai_tpu.models.llama import qmm  # deferred: models->ops cycle
+
+    y = qmm(jax.nn.silu(qmm(u, w_gate)) * qmm(u, w_up), w_down)
+    g = jax.nn.sigmoid(u.astype(jnp.float32) @ gate.astype(jnp.float32))
+    return (g * y.astype(jnp.float32)).astype(u.dtype)
+
+
+def held_capacity(n_tokens: int, top_k: int, n_outputs: int,
+                  factor: int = 8) -> int:
+    """Slots a held expert's queue gets on the fast path: ``factor`` times
+    its expected load under even routing (``n_tokens * top_k /
+    n_outputs``), at least 8, never more than every token. A queue that
+    would overflow sends the call down the exact slow path instead
+    (:func:`held_expert_ffn`), so the number trades speed only, never a
+    token."""
     expected = n_tokens * top_k / n_outputs
-    return max(1, min(n_tokens, max(8, math.ceil(8 * expected))))
+    return max(1, min(n_tokens, max(8, math.ceil(factor * expected))))
 
 
 def held_expert_ffn(

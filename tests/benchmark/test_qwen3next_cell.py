@@ -1,0 +1,309 @@
+"""The files the ``qwen3next`` block and its cell bring (CPU, tier-1): the
+configuration against the published config and the floors, the block's
+bytes against the issue's arithmetic, the new readers on operations reduced
+from the builder's own trace of the cell and on hand-made records — what
+they read, and that they read nothing (and do not raise) from a program or
+a block without it, as the parent of the PR that added them — and both
+controls of ``correct`` at test size."""
+
+from __future__ import annotations
+
+import dataclasses
+import json
+import re
+from pathlib import Path
+
+import numpy as np
+import pytest
+
+from benchmark import blocks, serving
+from benchmark.kernels import gdn_chunk, gdn_step
+from benchmark.layer_metrics._common import load_metric_file
+
+ROOT = Path(__file__).resolve().parents[2]
+BENCH = json.loads((ROOT / "BENCHMARK.json").read_text())
+CONFIG = json.loads((ROOT / "benchmark/configs/qwen3-next-80b-ep4-bf16.json").read_text())
+LIMITS = json.loads((ROOT / "benchmark/configs/qwen3-next-80b-ep4-bf16.limits.json").read_text())
+SLICE = json.loads((ROOT / "benchmark/testdata/sysprompt_open_slice.json").read_text())
+METRICS = ROOT / "benchmark" / "layer_metrics"
+CELL = "qwen3next.sysprompt-open"
+NEW = ["gdn_decode_roofline", "gdn_chunk_roofline", "state_snapshot_grant_share",
+       "state_copy_ms", "expert_touched_share"]
+
+
+def _model():
+    return serving.reference_cfg(serving.model_config(CONFIG))
+
+
+def test_every_published_number_is_in_the_file_and_only_the_cut_differs():
+    pub = CONFIG["published"]
+    differs = {k for k, v in pub.items() if CONFIG[k] != v}
+    assert differs == {"num_hidden_layers", "vocab_size"} <= set(CONFIG["reduced"])
+    assert CONFIG["reduced"] == ["num_hidden_layers", "n_experts_held", "vocab_size"]
+    assert (CONFIG["num_hidden_layers"], CONFIG["n_experts_held"], CONFIG["vocab_size"]) == (
+        12, 128, 37984)
+    entry = [c for c in BENCH["configs"] if c["name"] == CONFIG["name"]][0]
+    assert entry["reduced"] == CONFIG["reduced"] and entry["source"] == CONFIG["source"]
+    model = _model()  # checked against CONFIGS["qwen3-next-80b-a3b-instruct"] key by key
+    assert (model["num_experts"], model["num_experts_per_tok"]) == (512, 10)
+    assert model["family"] == "qwen2" and model["state_snapshots"] == 16
+    # the floors: three whole periods, 128 >= 8 experts, a quarter >= an eighth of the rows
+    assert model["num_hidden_layers"] % model["full_attention_interval"] == 0
+    assert CONFIG["vocab_size"] * 4 == pub["vocab_size"]
+    for width in ("hidden_size", "head_dim", "linear_key_head_dim", "linear_value_head_dim",
+                  "moe_intermediate_size", "shared_expert_intermediate_size",
+                  "linear_conv_kernel_dim", "num_experts_per_tok"):
+        assert CONFIG[width] == pub[width] and width not in CONFIG["reduced"]
+
+
+def test_the_cut_is_the_issues_arithmetic():
+    b, model = blocks.load("qwen3next").bytes, _model()
+    assert b.expert_params(model) == 3_145_728
+    assert model["n_experts_held"] * b.expert_params(model) == 402_653_184
+    assert b.layer_params(model, full=True) == (27_263_488, 4_200_448)
+    assert b.layer_params(model, full=False) == (33_718_464, 4_200_448)
+    full, linear = (sum(b.layer_params(model, f)) + 402_653_184 for f in (True, False))
+    layers = 3 * full + 9 * linear
+    assert layers == pytest.approx(5_267.5e6, rel=1e-4)
+    embed_and_head = 2 * 37_984 * 2048
+    assert embed_and_head == pytest.approx(155.6e6, rel=1e-3)
+    # what the pieces count is what the program's own count says it holds
+    assert layers + embed_and_head + 2048 == serving.model_config(CONFIG).total_params
+    precision = CONFIG["precision"]
+    # the issue's 19.3 MB a slot had a bfloat16 tail; it is float32 (`assumed`): 19.8 MB
+    assert b.state_slot_bytes(model, precision) == 9 * (32 * 128 * 128 * 4 + 3 * 8192 * 4)
+    assert 64 * b.state_slot_bytes(model, precision) == pytest.approx(1.27e9, rel=0.005)
+    assert 16 * b.state_slot_bytes(model, precision) == pytest.approx(0.317e9, rel=0.005)
+    assert b.kv_token_bytes(model) == 6_144
+    resident = b.resident_bytes(model, CONFIG["llm"], precision)
+    pool = 8192 * 16 * 6_144
+    assert pool == pytest.approx(0.81e9, rel=0.01)
+    assert 13.2e9 < resident < 13.3e9  # the issue's 13.2 GB, the router, norms and tail at float32
+    assert resident >= 0.25 * 17_179_869_184  # the floor of cell_too_small, three times over
+    # the pools are IN the number: without them it would be short by 1.55 GB
+    bare = dataclasses.replace(serving.model_config(CONFIG), state_snapshots=0)
+    less = b.resident_bytes(serving.reference_cfg(bare), dict(CONFIG["llm"], max_batch_slots=0),
+                            precision)
+    assert resident - less == 80 * b.state_slot_bytes(model, precision)
+    # a pass: everything outside the held experts once, the head, the live keys and values
+    assert b.step_bytes(model, 0) == pytest.approx(1.052e9, rel=0.005)
+    assert b.step_bytes(model, 50_000) - b.step_bytes(model, 0) == 50_000 * 6_144
+    assert not hasattr(b, "attention_bytes_per_call")  # the dense kernel's
+
+
+def test_the_kernel_files_count_and_find_their_events():
+    # the step: a live row's state read and written, its tail, its inputs
+    row = 2 * 32 * 128 * 128 * 4 + 2 * 3 * 8192 * 4 + (8192 + 4096 + 64) * 4
+    assert gdn_step.bytes_per_call(10, 32, 128, 128, 8192, 4) == 10 * row
+    sizes = (64, 32, 128, 128)
+    for name in SLICE["step_events"]:
+        assert gdn_step.is_event(name, *sizes) and not gdn_chunk.is_event(name, *sizes), name
+    for name in SLICE["chunk_events"]:
+        assert gdn_chunk.is_event(name, *sizes) and not gdn_step.is_event(name, *sizes), name
+    for name in SLICE["other_events"]:
+        assert not gdn_step.is_event(name, *sizes) and not gdn_chunk.is_event(name, *sizes), name
+    loop = ("%while.7 = (s32[], bf16[64,1,2048], f32[9,64,32,128,128], bf16[9,64,3,8192]) "
+            "while((s32[], bf16[64,1,2048]) %t)")
+    assert gdn_step.pattern(*sizes).search(loop) and not gdn_step.is_event(loop, *sizes)
+    write_back = ("%fusion.1728 = f32[9,64,32,128,128] fusion(f32[9,64,32,128,128] %a, "
+                  "f32[4,32,128,128] %copy-done.164)")  # the chunked rule's rows into the pool
+    assert gdn_chunk.is_event(write_back, *sizes) and not gdn_step.is_event(write_back, *sizes)
+    assert not gdn_step.is_event(SLICE["step_events"][0], 16, 32, 128, 128)
+    assert gdn_step.op_kind("%x.1 = (f32[2], s32[]) fusion(f32[2] %a)") == "fusion"
+    per_token = 2 * (64 * (3 * 128 + 2 * 128) + 64 * 64 / 2 + 3 * 128 * 128)
+    assert gdn_chunk.ops_per_call(100, 32, 128, 128) == 100 * 32 * per_token
+    assert gdn_chunk.bytes_per_call(100, 1, 32, 128, 128, 8192) == (
+        100 * ((8192 + 64) * 4 + 4096 * 4) + 2 * 32 * 128 * 128 * 4)
+
+
+def _run(steps=(), trace=None, model=None, block="qwen3next", health=None):
+    reqs = [{"status": 200, "error": None, "text": "x", "times": [1.0, 9.0],
+             "prompt_tokens": 2800, "done_marker": True, "terminated": True,
+             "completion_tokens": 1, "finish": "length", "max_tokens": 1}] * 30
+    state = {"state_snapshots_taken": 0, "state_hash_tokens_matched": 0,
+             "state_hash_tokens_granted": 0, "prefill_tokens": 0}
+    before, start, stop, after = (dict(state, **h) for h in (health or [{}] * 4))
+    return {"steps": list(steps), "model": model or _model(), "block": blocks.load(block),
+            "llm": CONFIG["llm"], "reqs": reqs,
+            "runtime": {"state_pool_bytes": 1.5e9 if block == "qwen3next" else 0},
+            "health_before": {"metrics": before}, "health_after": {"metrics": after},
+            "traced": {"t_start": 4.0, "t_stop": 9.0, "health_start": {"metrics": start},
+                       "health_stop": {"metrics": stop}},
+            "peaks": {"hbm_bytes_per_s": 819e9, "bf16_flops": 197e12}, "trace": trace}
+
+
+def _read(name, run):
+    return load_metric_file(METRICS / f"{name}.py").read(run)
+
+
+def test_the_device_readers_on_the_builders_own_slice():
+    """``testdata/sysprompt_open_slice.json``: the operations and programs
+    of one traced slice of the cell on the chip, reduced
+    (``trace_reduce.totals``), with what the run's readers printed."""
+    run = _run(trace={"ops": SLICE["ops"], "modules": SLICE["modules"]},
+               health=[{}, {"prefill_tokens": 0},
+                       {"prefill_tokens": SLICE["traced_prefill_tokens"]}, {}])
+    run["reqs"] = run["reqs"][:1] * SLICE["live_rows"]
+    run["traced"].update(t_start=0.0, t_stop=SLICE["slice_seconds"])
+    for name in ("gdn_decode_roofline", "gdn_chunk_roofline", "state_copy_ms"):
+        assert 0 < _read(name, run) < 100
+        # what the run itself printed, from its own count of live rows and tokens
+        assert _read(name, run) == pytest.approx(SLICE["printed"][name], rel=0.25)
+    step_s = sum(t["seconds"] for n, t in SLICE["ops"].items()
+                 if gdn_step.is_event(n, 64, 32, 128, 128))
+    passes = SLICE["modules"]["jit__decode_multi"]["count"] * 8
+    calls = (passes + SLICE["modules"]["jit__mixed_step"]["count"]) * 9
+    need = gdn_step.bytes_per_call(SLICE["live_rows"], 32, 128, 128, 8192, 4) / 819e9
+    assert _read("gdn_decode_roofline", run) == pytest.approx(100 * need / (step_s / calls))
+    copies = sum(SLICE["modules"][p]["seconds"] for p in ("jit__state_admit", "jit__state_snapshot"))
+    assert _read("state_copy_ms", run) == pytest.approx(1e3 * copies / SLICE["slice_seconds"])
+    # a slice that held no copy reads 0, not nothing: the program has the state
+    quiet = dict(run, trace={"ops": {}, "modules": {}})
+    assert _read("state_copy_ms", quiet) == 0.0
+
+
+def test_the_counter_readers_on_hand_made_records():
+    decode = {"held": 600, "zero": 0, "absent": 1800, "touched": 4000, "overflow": 0,
+              "passes": 8, "programs": ["_decode_multi"]}
+    mixed = {"held": 1500, "zero": 0, "absent": 4000, "touched": 1400, "overflow": 0,
+             "passes": 1, "programs": ["_mixed_step"]}
+    run = _run([{"step": 1, "experts": decode}, {"step": 2}, {"step": 3, "experts": mixed}],
+               health=[{"state_hash_tokens_matched": 2096, "state_hash_tokens_granted": 0}, {}, {},
+                       {"state_hash_tokens_matched": 2096 * 41, "state_hash_tokens_granted": 2048 * 40}])
+    # decode records only: 4,000 of 8 passes x 12 expert layers x 128 held
+    assert _read("expert_touched_share", run) == pytest.approx(100 * 4000 / (8 * 12 * 128))
+    assert _read("state_snapshot_grant_share", run) == pytest.approx(100 * 2048 / 2096)
+    assert _read("state_snapshot_grant_share", _run()) is None  # nothing matched: no share
+
+
+@pytest.mark.parametrize("name", NEW)
+def test_a_program_or_block_without_it_is_read_as_nothing(name):
+    """The parent has no ``state`` counter, no state-shaped operation and no
+    copy program; the dense block's model has none of the keys."""
+    parent = _run([{"step": 1}, {"step": 2}], trace={"ops": {}, "modules": {}})
+    for h in ("health_before", "health_after"):
+        parent[h] = {"metrics": {"prefill_tokens": 5}}
+    parent["runtime"] = {}
+    assert _read(name, parent) is None
+    qwen = json.loads((ROOT / BENCH["configs"][0]["file"]).read_text())
+    dense = serving.reference_cfg(serving.model_config(qwen))
+    other = _run([{"step": 1}], trace={"ops": {}, "modules": {}}, model=dense, block="dense")
+    for h in ("health_before", "health_after"):
+        other[h] = {"metrics": {}}
+    assert _read(name, other) is None
+
+
+def test_entries_of_the_new_cell():
+    cell = [w for w in BENCH["workloads"] if w["name"] == CELL][0]
+    assert (cell["config"], cell["traffic"], cell["chips"]) == (CONFIG["name"], "sysprompt-open", 1)
+    assert BENCH["workloads"][-1] is cell and BENCH["configs"][-1]["name"] == CONFIG["name"]
+    by_name = {m["name"]: m for m in BENCH["per_layer"]}
+    assert [m["name"] for m in BENCH["per_layer"][-5:]] == NEW
+    for name in NEW:
+        assert by_name[name]["workloads"] == [CELL] and by_name[name]["moves"] == "tpot_p50_ms"
+    for name, m in by_name.items():  # nothing that listed its cells was given this one
+        if name not in NEW and "workloads" in m:
+            assert CELL not in m["workloads"]
+    traffic = json.loads((ROOT / "benchmark/traffic/sysprompt-open.json").read_text())
+    assert traffic["generator"] == "open_loop" and traffic["check_sample"] == 4
+    assert len(traffic["system"].encode()) == 2048  # one byte a token going in
+    from runbookai_tpu.agent.prompts import SYSTEM_PROMPT
+
+    assert traffic["system"].startswith(SYSTEM_PROMPT) and len(SYSTEM_PROMPT.encode()) == 1657
+    reason = json.loads((ROOT / "benchmark/traffic/reason-open.json").read_text())
+    assert traffic["prompt_tokens"] == reason["prompt_tokens"]
+    assert traffic["max_tokens"] == {"dist": "lognormal", "median": 256, "sigma": 0.6,
+                                     "min": 64, "max": 512}
+    ours, theirs = traffic["warmup"]["bursts"], reason["warmup"]["bursts"]
+    assert ours[:-1] == theirs[:-1] and ours[-1] == dict(theirs[-1], prompt_tokens=8000)
+    assert (2048 + 64 + traffic["prompt_tokens"]["max"] + traffic["max_tokens"]["max"]
+            < CONFIG["llm"]["max_seq_len"])
+    assert not re.search(r"<\|", traffic["system"])
+    rate = json.loads((ROOT / f"benchmark/cells/{CELL}.json").read_text())
+    assert rate["rate_rps"] > 0 and "knee" in rate["note"]
+
+
+def test_every_request_of_the_plan_hangs_on_the_system_text():
+    """The shared prefix as the program will hash it: every prompt of a
+    window starts with the same 131 pages, and differs inside the 132nd."""
+    from benchmark import generators
+    from benchmark.reference import tokens
+    from runbookai_tpu.engine.kv_cache import hash_blocks
+
+    traffic = json.loads((ROOT / "benchmark/traffic/sysprompt-open.json").read_text())
+    plan = generators.load("open_loop").plan(traffic, {"rate_rps": 1.0}, 2147483999, 20)
+    chains = [hash_blocks(tokens.prompt_ids(r["messages"], "qwen2"), 16, seed=0)
+              for r in plan["requests"]]
+    shared = min(next(i for i, (a, b) in enumerate(zip(chains[0], c)) if a != b)
+                 for c in chains[1:])
+    assert shared * 16 == 2096  # the template's turn header and the seed's digits ride along
+    assert 2048 <= shared * 16 < 2048 + 64
+
+
+def _gaps(seed, lowp=None):
+    """Widest gap of the program's greedy tokens (bf16 weights, the served
+    forward) and of each control's over one sequence of 384 tokens, at the
+    test preset with the delta rule's heads as wide as published (128
+    values): at the preset's own 16-value heads the rule's conditioning
+    amplifies ANY rounding of the hidden stream to a gap of 3-4, bfloat16
+    and fp8 alike, which says nothing about the cell (PERF.md section 2)."""
+    import jax.numpy as jnp
+
+    from runbookai_tpu.models import qwen3_next
+    from runbookai_tpu.models.llama import CONFIGS
+
+    cfg = dataclasses.replace(CONFIGS["qwen3-next-test"], linear_key_head_dim=128,
+                              linear_value_head_dim=128, hidden_size=128, head_dim=64)
+    block, ref_cfg, t = blocks.load("qwen3next"), dataclasses.asdict(cfg), 384
+    params = block.weights.make_params(ref_cfg, seed % 2 ** 31, False)
+    ids = np.random.default_rng(seed).integers(0, 256, size=t).tolist()
+    ref = np.asarray(block.forward.logits(params, ref_cfg, ids, t))
+    kv = [jnp.zeros((cfg.n_periods, 32 * 16, cfg.num_key_value_heads, cfg.head_dim),
+                    jnp.bfloat16) for _ in range(2)]
+    served, *_ = qwen3_next.forward_impl(
+        params, cfg, jnp.asarray([ids], jnp.int32), jnp.arange(t, dtype=jnp.int32)[None],
+        *kv, jnp.arange(1, 26, dtype=jnp.int32)[None], jnp.asarray([t]), page_size=16,
+        state=qwen3_next.empty_state(cfg, 1))
+    rows = np.arange(t)
+    gap = lambda lg: float((ref.max(axis=1) - ref[rows, np.asarray(lg).argmax(axis=1)]).max())  # noqa: E731
+    return gap(served[0]), {k: gap(block.forward.logits(params, ref_cfg, ids, t, k))
+                            for k in (lowp or [])}
+
+
+@pytest.mark.parametrize("seed", [3, 2 ** 31 + 11])
+def test_the_fp8_control_reads_over_the_served_path(seed):
+    """The reference in fp8 where the configuration states bfloat16 reads
+    at least twice what the served bf16 path reads, which is under the
+    cell's limit; rounding the paged cache alone to fp8 moves nothing
+    (why ``correct`` also compares the bytes)."""
+    sound, control = _gaps(seed, ["fp8", "kv_fp8"])
+    assert sound <= LIMITS["logit_gap"] and 2 * sound < control["fp8"], (sound, control)
+    assert control["kv_fp8"] < sound
+
+
+def test_the_programs_own_fp8_cache_comes_out_not_correct():
+    """The served control on this cell: the program with its fp8 KV cache
+    keeps fewer bytes than the configuration states."""
+    import subprocess
+    import sys
+
+    env = {**__import__("os").environ, "JAX_PLATFORMS": "cpu", "PYTHONPATH": str(ROOT)}
+    p = subprocess.run(
+        [sys.executable, "-m", "benchmark.run", "--workload", CELL, "--seed", "2147496002",
+         "--seconds", "3", "--trace", "0", "--rehearse-cpu", "--llm", '{"kv_cache_dtype": "fp8"}'],
+        cwd=ROOT, env=env, capture_output=True, text=True, timeout=900)
+    lines = [json.loads(line) for line in p.stdout.splitlines() if line.startswith("{")]
+    assert lines[-1]["rehearsal"] and lines[-1]["rehearsal_correct"] is False, p.stdout[-2000:]
+    assert p.returncode != 0 and lines[-1]["failed"] == 0
+    ref = [j for j in lines if j.get("note") == "reference"][0]
+    pool = 1024 * 16 * 2 * 2 * 2 * 32  # pages x tokens x periods x (k, v) x heads x values: a byte each saved
+    assert ref["resident_bytes_short"] >= 0.9 * pool
+    window = [j for j in lines if j.get("note") == "window"][0]
+    assert window["resolved"]["kv_dtype"].startswith("float8")
+
+
+def test_the_limits_file_holds_sound_and_control_readings():
+    assert set(LIMITS) >= {"comment", "logit_gap"}
+    for word in ("sound", "fp8", "kv_cache_dtype", "1.25"):
+        assert word in LIMITS["comment"], word
+    assert "resident_bytes_short" not in LIMITS and "not_comparable_share" not in LIMITS  # the defaults': 0
