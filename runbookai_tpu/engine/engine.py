@@ -271,6 +271,34 @@ def _with_state(results: tuple, state) -> tuple:
     return results if state is None else (*results, state)
 
 
+# A model that brings its own drafter (``cfg.drafter()``: models/joyai.py's
+# prediction module) keeps one more layer of the paged pool, written one
+# position behind the trunk's: row ``i`` is made of the trunk's hidden state
+# at ``i`` and the token at ``i + 1``. Every step program, asked with
+# ``self_draft``, therefore runs the module over the positions it fed, once
+# the token after each is known (the next prompt token, or the one it just
+# sampled), and hands back, last, the module's draft for each row: the
+# token after the one it sampled. The invariant between programs: a row
+# with ``n`` committed tokens has trunk rows and module rows ``0 .. n - 2``
+# and a draft of token ``n``. For every other model, and with speculation
+# off, ``self_draft`` is False: no operand, no result, the same program.
+
+
+def _trunk(forward, self_draft: bool, state, state_rows, *args, **kw):
+    """A family's forward as ``(logits, kv_k, kv_v, experts, state',
+    hidden)``: the trunk's last hidden state where the module needs it."""
+    if self_draft:
+        logits, kv_k, kv_v, experts, hidden = forward(*args, hidden_out=True, **kw)
+        return logits, kv_k, kv_v, experts, state, hidden
+    return (*_run_forward(forward, state, state_rows, *args, **kw), None)
+
+
+def _with_drafts(results: tuple, drafts) -> tuple:
+    """A step program's results, with the module's drafts last where the
+    model drafts for itself."""
+    return results if drafts is None else (*results, drafts)
+
+
 @partial(jax.jit, donate_argnums=(0,))
 def _state_admit(state, snaps, slot, src):
     """Slot ``slot`` of the state pool starts a sequence: from row ``src``
@@ -295,18 +323,18 @@ def _state_snapshot(snaps, state, dst, slot):
 
 
 @partial(jax.jit, static_argnames=("cfg", "page_size", "block_pages", "attn_impl",
-                                   "mesh", "qmm_impl"),
+                                   "mesh", "qmm_impl", "self_draft"),
          donate_argnums=(4, 5, 14), donate_argnames=("state",))
 def _decode_step(
     params, cfg, tokens, positions, kv_k, kv_v, tables, ctx_lens,
     temps, top_ps, top_ks, key, mask, adapter_ids, counts=None, pres=None,
     freq=None, seeds=None, bias=None, *, page_size: int,
     block_pages: int, attn_impl: str = "xla", mesh=None, qmm_impl: str = "xla",
-    state=None,
+    state=None, self_draft: bool = False,
 ):
     forward, _ = cfg.forwards()
-    logits, kv_k, kv_v, experts, state = _run_forward(
-        forward, state, None,
+    logits, kv_k, kv_v, experts, state, hidden = _trunk(
+        forward, self_draft, state, None,
         params, cfg, tokens, positions, kv_k, kv_v, tables, ctx_lens,
         page_size=page_size, block_pages=block_pages, attn_impl=attn_impl,
         mesh=mesh, adapter_ids=adapter_ids, qmm_impl=qmm_impl,
@@ -316,19 +344,28 @@ def _decode_step(
                         seeds=seeds, positions=ctx_lens, bias=bias)
     if counts is not None:
         counts = counts.at[jnp.arange(tok.shape[0]), tok].add(1)
-    return _with_state((tok, logits[:, -1], kv_k, kv_v, counts, experts), state)
+    drafts = None
+    if self_draft:
+        module_pass, _, draft_tokens = cfg.drafter()
+        y, kv_k, kv_v, module_experts = module_pass(
+            params, cfg, hidden, tok[:, None], positions, kv_k, kv_v, tables,
+            ctx_lens, page_size, block_pages)
+        experts = experts + module_experts
+        drafts = draft_tokens(params, cfg, y[:, -1])
+    return _with_state(_with_drafts(
+        (tok, logits[:, -1], kv_k, kv_v, counts, experts), drafts), state)
 
 
 @partial(jax.jit,
          static_argnames=("cfg", "page_size", "block_pages", "k_steps", "attn_impl",
-                          "mesh", "qmm_impl"),
+                          "mesh", "qmm_impl", "self_draft"),
          donate_argnums=(4, 5, 13), donate_argnames=("state",))
 def _decode_multi(
     params, cfg, tokens, positions, kv_k, kv_v, tables, ctx_lens,
     temps, top_ps, top_ks, key, adapter_ids, counts=None, pres=None,
     freq=None, seeds=None, bias=None, *, page_size: int, block_pages: int,
     k_steps: int, attn_impl: str = "xla", mesh=None, qmm_impl: str = "xla",
-    state=None,
+    state=None, self_draft: bool = False,
 ):
     """K autoregressive decode steps in ONE dispatch (on-device sampling).
 
@@ -349,9 +386,9 @@ def _decode_multi(
     forward, _ = cfg.forwards()
 
     def step(carry, _):
-        tokens, positions, kv_k, kv_v, ctx_lens, key, counts, state = carry
-        logits, kv_k, kv_v, experts, state = _run_forward(
-            forward, state, None,
+        tokens, positions, kv_k, kv_v, ctx_lens, key, counts, state, y = carry
+        logits, kv_k, kv_v, experts, state, hidden = _trunk(
+            forward, self_draft, state, None,
             params, cfg, tokens, positions, kv_k, kv_v, tables, ctx_lens,
             page_size=page_size, block_pages=block_pages, attn_impl=attn_impl,
             mesh=mesh, adapter_ids=adapter_ids, qmm_impl=qmm_impl,
@@ -362,26 +399,36 @@ def _decode_multi(
                             seeds=seeds, positions=ctx_lens, bias=bias)
         if counts is not None:
             counts = counts.at[jnp.arange(tok.shape[0]), tok].add(1)
+        if self_draft:
+            y, kv_k, kv_v, module_experts = cfg.drafter()[0](
+                params, cfg, hidden, tok[:, None], positions, kv_k, kv_v,
+                tables, ctx_lens, page_size, block_pages)
+            experts, y = experts + module_experts, y[:, -1]
         carry = (tok[:, None], positions + 1, kv_k, kv_v, ctx_lens + 1, key,
-                 counts, state)
+                 counts, state, y)
         return carry, (tok, experts)
 
-    (_, _, kv_k, kv_v, _, _, counts, state), (toks, experts) = jax.lax.scan(
-        step, (tokens, positions, kv_k, kv_v, ctx_lens, key, counts, state),
+    # The module's output rides the carry: only the last pass's is drafted of.
+    y0 = (jnp.zeros((tokens.shape[0], params["embed"].shape[1]),
+                    params["embed"].dtype) if self_draft else None)
+    (_, positions, kv_k, kv_v, _, _, counts, state, y), (toks, experts) = jax.lax.scan(
+        step, (tokens, positions, kv_k, kv_v, ctx_lens, key, counts, state, y0),
         None, length=k_steps,
     )
     if experts is not None:
         experts = jnp.sum(experts, axis=0)  # over the K passes
-    return _with_state((toks.T, kv_k, kv_v, counts, experts), state)  # [B, K]
+    drafts = cfg.drafter()[2](params, cfg, y) if self_draft else None
+    return _with_state(_with_drafts(
+        (toks.T, kv_k, kv_v, counts, experts), drafts), state)  # [B, K]
 
 
 @partial(jax.jit, static_argnames=("cfg", "page_size", "block_pages", "attn_impl",
-                                   "mesh", "qmm_impl"),
+                                   "mesh", "qmm_impl", "rounds"),
          donate_argnums=(4, 5))
 def _decode_spec(
     params, cfg, tokens, positions, kv_k, kv_v, tables, ctx_lens,
     adapter_ids, page_size: int, block_pages: int, attn_impl: str = "xla",
-    mesh=None, qmm_impl: str = "xla",
+    mesh=None, qmm_impl: str = "xla", drafts=None, rounds: int = 0,
 ):
     """Verify a speculated chunk: one T=K forward, greedy argmax per position.
 
@@ -395,8 +442,54 @@ def _decode_spec(
     With ``attn_impl="pallas"`` the T>1 verify forward runs the Pallas chunk
     kernel (``paged_chunk_attention``) — positions are contiguous from
     ``ctx-1``, satisfying the kernel's contiguity contract.
+
+    With ``rounds`` (a model that drafts for itself, ``cfg.drafter()``) the
+    program runs that many ROUNDS, the drafts never leaving the device.
+    ``tokens`` [B] is each row's last sampled token, ``drafts`` [B] the
+    module's draft of the one after it, ``positions`` [B] where the first
+    goes, ``ctx_lens`` [B] nonzero for a live row. A round feeds ``[last,
+    draft]`` at ``[p, p + 1]``; the trunk's argmaxes are ``a0, a1``; ``a0``
+    is committed, and ``a1`` too iff ``draft == a0``; the module runs over
+    both positions with the token after each (``a0, a1``) and the output at
+    the last committed one makes the next draft. A rejected position leaves
+    a trunk row and a module row that the next round overwrites. Returns
+    (``[a0, a1, accepted]`` [B, rounds, 3] — one array, one fetch — kv_k,
+    kv_v, experts over all rounds, the last token [B], the next draft [B]).
     """
     forward, _ = cfg.forwards()
+    if rounds:
+        module_pass, _, draft_tokens = cfg.drafter()
+        live = ctx_lens > 0
+
+        def one_round(carry, _):
+            last, draft, pos, kv_k, kv_v = carry
+            fed = jnp.stack([last, draft], axis=1)
+            at = jnp.stack([pos, pos + 1], axis=1)
+            ctx = jnp.where(live, pos + 2, 0)
+            logits, kv_k, kv_v, experts, hidden = forward(
+                params, cfg, fed, at, kv_k, kv_v, tables, ctx,
+                page_size=page_size, block_pages=block_pages,
+                attn_impl=attn_impl, mesh=mesh, adapter_ids=adapter_ids,
+                qmm_impl=qmm_impl, hidden_out=True)
+            a = jnp.argmax(logits, axis=-1).astype(jnp.int32)  # [B, 2]
+            accepted = (draft == a[:, 0]).astype(jnp.int32)
+            y, kv_k, kv_v, module_experts = module_pass(
+                params, cfg, hidden, a, at, kv_k, kv_v, tables, ctx,
+                page_size, block_pages)
+            pos = pos + 1 + accepted
+            draft = draft_tokens(
+                params, cfg,
+                jnp.take_along_axis(y, accepted[:, None, None], axis=1)[:, 0])
+            last = jnp.take_along_axis(a, accepted[:, None], axis=1)[:, 0]
+            return (last, draft, pos, kv_k, kv_v), (a, accepted,
+                                                    experts + module_experts)
+
+        (last, draft, _, kv_k, kv_v), (toks, accepted, experts) = jax.lax.scan(
+            one_round, (tokens, drafts, positions, kv_k, kv_v), None,
+            length=rounds)
+        out = jnp.concatenate([toks, accepted[..., None]], axis=-1)
+        return (out.transpose(1, 0, 2), kv_k, kv_v, jnp.sum(experts, axis=0),
+                last, draft)
     logits, kv_k, kv_v, experts = forward(
         params, cfg, tokens, positions, kv_k, kv_v, tables, ctx_lens,
         page_size=page_size, block_pages=block_pages, attn_impl=attn_impl,
@@ -406,6 +499,22 @@ def _decode_spec(
             experts)  # tokens [B, K]
 
 
+@partial(jax.jit, static_argnames=("cfg", "page_size", "block_pages"),
+         donate_argnums=(5, 6))
+def _module_step(params, cfg, hidden, tokens, positions, kv_k, kv_v, tables,
+                 ctx_lens, *, page_size: int, block_pages: int):
+    """The module's row at the LAST position of a prompt, which waits for
+    the first sampled token (``_run_prefill`` samples it outside its
+    program): ``hidden`` [b, D] the trunk's state there, ``tokens`` [b] the
+    token just sampled, ``positions`` [b] (a row that ended no prompt: the
+    trash position). Returns (drafts [b], kv_k, kv_v, experts)."""
+    module_pass, _, draft_tokens = cfg.drafter()
+    y, kv_k, kv_v, experts = module_pass(
+        params, cfg, hidden[:, None], tokens[:, None], positions[:, None],
+        kv_k, kv_v, tables, ctx_lens, page_size, block_pages)
+    return draft_tokens(params, cfg, y[:, 0]), kv_k, kv_v, experts
+
+
 @partial(jax.jit, static_argnames=("cfg", "page_size", "block_pages", "attn_impl",
                                    "mesh", "qmm_impl"),
          donate_argnums=(3, 4), donate_argnames=("state",))
@@ -413,19 +522,29 @@ def _prefill_step(
     params, cfg, tokens, kv_k, kv_v, positions, tables, ctx_lens,
     last_idx, adapter_ids, page_size: int, block_pages: int,
     attn_impl: str = "xla", mesh=None, qmm_impl: str = "xla",
-    state=None, state_rows=None,
+    state=None, state_rows=None, next_tokens=None,
 ):
     """Prefill one chunk for a BATCH of sequences; returns each row's final
-    real-token logits ([B, vocab])."""
+    real-token logits ([B, vocab]). With ``next_tokens`` [B, T] (a model
+    that drafts for itself: the prompt's token AFTER each position) the
+    module runs over the chunk too, and each row's hidden state at
+    ``last_idx`` comes back last: where that position ends a prompt, its
+    next token is not known yet and :func:`_module_step` writes its row."""
     forward, _ = cfg.forwards()
-    logits, kv_k, kv_v, experts, state = _run_forward(
-        forward, state, state_rows,
+    logits, kv_k, kv_v, experts, state, hidden = _trunk(
+        forward, next_tokens is not None, state, state_rows,
         params, cfg, tokens, positions, kv_k, kv_v, tables, ctx_lens,
         page_size=page_size, block_pages=block_pages, attn_impl=attn_impl,
         mesh=mesh, adapter_ids=adapter_ids, qmm_impl=qmm_impl,
     )
     rows = jnp.arange(logits.shape[0])
-    return _with_state((logits[rows, last_idx], kv_k, kv_v, experts), state)
+    if next_tokens is not None:
+        _, kv_k, kv_v, module_experts = cfg.drafter()[0](
+            params, cfg, hidden, next_tokens, positions, kv_k, kv_v, tables,
+            ctx_lens, page_size, block_pages)
+        experts, hidden = experts + module_experts, hidden[rows, last_idx]
+    return _with_state(_with_drafts(
+        (logits[rows, last_idx], kv_k, kv_v, experts), hidden), state)
 
 
 # Row-run alignment of the mixed ragged token buffer: every row's token run
@@ -447,7 +566,7 @@ def _mixed_step(
     pf_pres=None, pf_freq=None, pf_seeds=None, pf_bias=None, *,
     page_size: int, block_pages: int, attn_impl: str = "xla", mesh=None,
     qmm_impl: str = "xla", ragged_block: int = _RAGGED_BLOCK,
-    state=None, state_rows=None,
+    state=None, state_rows=None, next_tokens=None,
 ):
     """ONE unified mixed prefill+decode dispatch (the ragged forward).
 
@@ -471,13 +590,20 @@ def _mixed_step(
     first read — a prompt completing in THIS dispatch had its row seeded
     pre-dispatch, so an unmasked add would pollute it before the
     first-token gather below reads it.
+
+    With ``next_tokens`` [N] (a model that drafts for itself: the prompt's
+    token after each prefill position) the module runs over the same flat
+    buffer once the tokens are sampled: a decode row's next token is the
+    one it just sampled, and so is that of a prompt's last position. The
+    decode rows go undrafted here (one token each), and every row leaves
+    the module's cache and a draft behind, which come back last, by slot.
     """
     b = feed_toks.shape[0]
     tokens = tokens.at[dec_idx].set(feed_toks)
     sel_idx = jnp.concatenate([dec_idx, pf_last_idx])
     _, forward_ragged = cfg.forwards()
-    logits, kv_k, kv_v, experts, state = _run_forward(
-        forward_ragged, state, state_rows,
+    logits, kv_k, kv_v, experts, state, hidden = _trunk(
+        forward_ragged, next_tokens is not None, state, state_rows,
         params, cfg, tokens, positions, row_ids, kv_k, kv_v, tables,
         ctx_lens, sel_idx, page_size=page_size, block_pages=block_pages,
         attn_impl=attn_impl, mesh=mesh, adapter_ids=adapter_rows,
@@ -500,8 +626,22 @@ def _mixed_step(
     if counts is not None:
         counts = counts.at[pf_slot_map, pf_tok].add(pf_live, mode="drop")
     feed_new = dec_tok.at[pf_slot_map].set(pf_tok, mode="drop")
-    return _with_state(
-        (dec_tok[:, None], pf_tok, feed_new, kv_k, kv_v, counts, experts), state)
+    drafts = None
+    if next_tokens is not None:
+        _, module_pass_ragged, draft_tokens = cfg.drafter()
+        nxt = next_tokens.at[dec_idx].set(dec_tok)
+        # (a pad row's index is 0, a decode position: it keeps what is there)
+        nxt = nxt.at[pf_last_idx].set(
+            jnp.where(pf_slot_map < b, pf_tok, nxt[pf_last_idx]))
+        y, kv_k, kv_v, module_experts = module_pass_ragged(
+            params, cfg, hidden, nxt, positions, row_ids, kv_k, kv_v, tables,
+            ctx_lens, page_size, block_pages, ragged_block)
+        experts = experts + module_experts
+        d = draft_tokens(params, cfg, y[sel_idx])
+        drafts = d[:b].at[pf_slot_map].set(d[b:], mode="drop")
+    return _with_state(_with_drafts(
+        (dec_tok[:, None], pf_tok, feed_new, kv_k, kv_v, counts, experts),
+        drafts), state)
 
 
 @functools.lru_cache(maxsize=8)
@@ -1038,6 +1178,11 @@ class EngineCore:
 
             kv_sharding = kv_pool_sharding(model_cfg, mesh)
 
+        # A model with a prediction module of its own is its own drafter
+        # (models/joyai.py): chosen by the model, with speculation on; no
+        # option selects it and prompt lookup is not its fallback.
+        self._mtp = bool(getattr(model_cfg, "self_draft", False)
+                         and self.ecfg.speculative)
         (pool_layers, pool_heads, pool_dim), v_side = model_cfg.kv_pool_spec
         # Recurrent layers keep their state a SLOT, not a token: a second
         # pool beside the pages, and a pool of snapshots behind prefix hits.
@@ -1055,6 +1200,8 @@ class EngineCore:
             spill_pages=self.ecfg.kv_spill_pages,
             state_snapshots=(model_cfg.state_snapshots if state_spec
                              else None),
+            # The module's row of a position is made of the NEXT token too.
+            lookahead=1 if self._mtp else 0,
         )
         self._kv_k = self.kv.pool.kv_k
         self._kv_v = self.kv.pool.kv_v
@@ -1118,6 +1265,11 @@ class EngineCore:
         # backoff (each probe costs a drain).
         self._feed_toks = _home(
             jnp.zeros((self.ecfg.max_batch_slots,), jnp.int32))
+        # The module's draft of each slot's NEXT token (the one after
+        # ``_feed_toks``'), device-resident like the feed; None: the model
+        # has no drafter of its own, or speculation is off.
+        self._draft_toks = (_home(jnp.zeros((self.ecfg.max_batch_slots,),
+                                            jnp.int32)) if self._mtp else None)
         self._pending: Optional[_PendingDecode] = None
         self._sched_epoch = 0
         self._slot_cache: Optional[_SlotInputs] = None
@@ -1682,7 +1834,8 @@ class EngineCore:
                 # cache namespace (base = seed 0).
                 req.block_hashes = hash_blocks(req.prompt_ids,
                                                self.ecfg.page_size,
-                                               seed=req.adapter_idx)
+                                               seed=req.adapter_idx,
+                                               lookahead=self.kv.lookahead)
             if self.kv.spill is not None:
                 # Spill-tier readmit: blocks evicted from HBM but still in
                 # host RAM come back as ordinary prefix pages, so the
@@ -1811,6 +1964,21 @@ class EngineCore:
             return results
         *rest, self._state = results
         return tuple(rest)
+
+    def _keep_drafts(self, results: tuple) -> tuple:
+        """A step program's results less the module's drafts, by slot,
+        which it hands back last (a model that drafts for itself)."""
+        if not self._mtp:
+            return results
+        *rest, self._draft_toks = results
+        return tuple(rest)
+
+    def _next_tokens(self, req: EngineRequest, lo: int, hi: int) -> list[int]:
+        """The prompt's token AFTER each position ``lo .. hi - 1`` (0 past
+        its end: the first sampled token, which the step program or
+        ``_module_step`` puts there)."""
+        nxt = req.prompt_ids[lo + 1: hi + 1]
+        return nxt + [0] * (hi - lo - len(nxt))
 
     def _free_slot(self, req: EngineRequest) -> int:
         """The batch slot a request that finished its prompt decodes in:
@@ -2074,12 +2242,17 @@ class EngineCore:
             adapter_ids = np.zeros((b,), dtype=np.int32)
             tables = self._tables_for([r for r, _, _ in rows] +
                                       [None] * (b - len(rows)))
+            next_tokens = np.zeros((b, t), dtype=np.int32) if self._mtp else None
             for i, (req, chunk_len, new_ctx) in enumerate(rows):
                 tokens[i, :chunk_len] = req.prompt_ids[req.prefill_pos:new_ctx]
                 positions[i, :chunk_len] = np.arange(req.prefill_pos, new_ctx)
                 ctx_lens[i] = new_ctx
                 last_idx[i] = chunk_len - 1
                 adapter_ids[i] = req.adapter_idx
+                if self._mtp:
+                    next_tokens[i, :chunk_len] = self._next_tokens(
+                        req, req.prefill_pos, new_ctx)
+            tables, ctx_dev = jnp.asarray(tables), jnp.asarray(ctx_lens)
 
         pf_meta: dict[str, Any] = {"batch": len(rows),
                                    "tokens": int(sum(c for _, c, _ in rows))}
@@ -2089,16 +2262,18 @@ class EngineCore:
             pf_meta["requests"] = [r.request_id for r, _, _ in rows]
         with self.tracer.span("engine.prefill", **pf_meta), \
                 annotate("prefill"), self._span("issue"):
-            last_logits, self._kv_k, self._kv_v, experts = self._keep_state(_prefill_step(
+            results = self._keep_state(_prefill_step(
                 self.params, self.cfg, jnp.asarray(tokens), self._kv_k, self._kv_v,
-                jnp.asarray(positions), jnp.asarray(tables),
-                jnp.asarray(ctx_lens), jnp.asarray(last_idx),
+                jnp.asarray(positions), tables, ctx_dev, jnp.asarray(last_idx),
                 jnp.asarray(adapter_ids),
                 page_size=self.ecfg.page_size, block_pages=self.ecfg.block_pages,
                 attn_impl=self.ecfg.attn_impl, mesh=self.mesh,
                 qmm_impl=self.ecfg.qmm_impl, state=self._state,
                 state_rows=self._state_rows([r for r, _, _ in rows], b),
+                next_tokens=(jnp.asarray(next_tokens) if self._mtp else None),
             ))
+            # (a model that drafts for itself: each row's last hidden state)
+            last_logits, self._kv_k, self._kv_v, experts, *last_hidden = results
         for req, _, new_ctx in rows:
             self._snapshot_state(req, new_ctx)
         if self._open is not None:
@@ -2207,6 +2382,20 @@ class EngineCore:
                     feed_idx[i] = req.slot
                 self._feed_toks = self._feed_toks.at[jnp.asarray(feed_idx)].set(
                     toks, mode="drop")
+                if self._mtp:
+                    # The module's row at each prompt's last position, now
+                    # that the token after it exists, and the first draft.
+                    at = np.full((b,), self._trash_pos(), dtype=np.int32)
+                    for i, req in done_rows:
+                        at[i] = len(req.prompt_ids) - 1
+                    drafts, self._kv_k, self._kv_v, module_experts = _module_step(
+                        self.params, self.cfg, last_hidden[0], toks,
+                        jnp.asarray(at), self._kv_k, self._kv_v, tables, ctx_dev,
+                        page_size=self.ecfg.page_size,
+                        block_pages=self.ecfg.block_pages)
+                    self._draft_toks = self._draft_toks.at[
+                        jnp.asarray(feed_idx)].set(drafts, mode="drop")
+                    self._experts_parked.append(("_module_step", 1, module_experts))
             with self._span("fetch"):
                 # runbook: noqa[RBK002] — sanctioned sync: the one batched
                 # first-token fetch per prefill dispatch (TTFT emission point).
@@ -2316,10 +2505,12 @@ class EngineCore:
             if any(s in tail for s in req.sampling.stop_strings):
                 self._finish(req, FinishReason.STOP_STRING)
 
-    def _pick_k(self) -> int:
-        """Decode tokens per dispatch: 1 when any guided request needs
+    def _pick_k(self, per_step: int = 1) -> int:
+        """Decode steps per dispatch: 1 when any guided request needs
         per-token masks, else the largest power of two ≤ config that fits
-        every sequence's remaining max_seq headroom."""
+        every sequence's remaining max_seq headroom, a step taking up to
+        ``per_step`` positions of it (a speculative round: two) — 0 where
+        not even one such step fits."""
         if any(r.sampling.forced_sync for r in self.decoding):
             return 1
         k = max(1, self.ecfg.decode_steps_per_dispatch)
@@ -2327,8 +2518,10 @@ class EngineCore:
         # occupy context the host hasn't consumed yet.
         remaining = min(self.ecfg.max_seq_len - (r.ctx_len + self._lead(r))
                         for r in self.decoding)
-        while k > 1 and (k > remaining):
+        while k > 1 and (k * per_step > remaining):
             k //= 2
+        if k * per_step > remaining:
+            return 0
         # power-of-two clamp bounds distinct compiled programs
         p = 1
         while p * 2 <= k:
@@ -2454,6 +2647,75 @@ class EngineCore:
         t_end = time.perf_counter()
         self.metrics["decode_tokens"] += emitted
         self.metrics["decode_steps"] += 1
+        self.metrics["decode_dispatches"] += 1
+        self.metrics["decode_dispatch_time_s"] += t_fetch - t_issue
+        self.metrics["decode_host_time_s"] += (
+            (t_issue - t0) + (t_end - t_fetch))
+        self.metrics["decode_time_s"] += t_end - t0
+
+    def _run_spec_rounds(self, rounds: int) -> None:
+        """``rounds`` speculative rounds in ONE dispatch, drafted by the
+        model's own prediction module on the device, with one fetch: a row
+        comes back with ``rounds`` to ``2 * rounds`` tokens. The host view
+        is current (the caller drained the window), pages are reserved for
+        the most a row can take, and what a row's stop leaves over is
+        dropped, as a multi-step window's is."""
+        t0 = time.perf_counter()
+        with self._span("build"):
+            self._grow_pages_for_decode(2 * rounds)
+        if not self.decoding:
+            return
+        b = self.ecfg.max_batch_slots
+        with self._span("build"):
+            positions = np.zeros((b,), dtype=np.int32)
+            ctx_lens = np.zeros((b,), dtype=np.int32)
+            for req in self.decoding:
+                positions[req.slot] = req.ctx_len - 1
+                ctx_lens[req.slot] = req.ctx_len
+            si = self._slot_inputs()
+        rows = list(self.decoding)
+        spec_meta: dict[str, Any] = {"k": rounds, "batch": len(rows)}
+        if self.tracer.enabled:
+            spec_meta["requests"] = [r.request_id for r in rows]
+        if self._open is not None:
+            self._open.dispatched("_decode_spec", rounds, len(rows),
+                                  self._pages_held(ctx_lens))
+        with self.tracer.span("engine.decode_spec", **spec_meta), \
+                annotate("decode_spec"), self._span("issue"):
+            t_issue = time.perf_counter()
+            (out, self._kv_k, self._kv_v, experts, self._feed_toks,
+             self._draft_toks) = _decode_spec(
+                self.params, self.cfg, self._feed_toks, jnp.asarray(positions),
+                self._kv_k, self._kv_v, si.tables, jnp.asarray(ctx_lens),
+                si.adapters,
+                page_size=self.ecfg.page_size, block_pages=self.ecfg.block_pages,
+                attn_impl=self.ecfg.attn_impl, mesh=self.mesh,
+                qmm_impl=self.ecfg.qmm_impl, drafts=self._draft_toks,
+                rounds=rounds,
+            )
+            out_host = self._fetch_tokens(out, experts, "_decode_spec",
+                                          rounds)  # [B, rounds, 3]
+            t_fetch = time.perf_counter()
+
+        drafted = accepted = 0
+        with self._span("emit"):
+            for req in rows:
+                for a0, a1, took in out_host[req.slot].tolist():
+                    if req.state != RequestState.DECODE:
+                        break  # a stop: the rest of the row is dropped
+                    drafted += 1
+                    self._emit_token(req, a0)
+                    if took and req.state == RequestState.DECODE:
+                        self._emit_token(req, a1)
+                        accepted += 1
+        t_end = time.perf_counter()
+        if self._open is not None:
+            self._open.spec = {"rounds": rounds, "drafted": drafted,
+                               "accepted": accepted, "rows": len(rows)}
+        self.metrics["spec_drafted"] += drafted
+        self.metrics["spec_accepted"] += accepted
+        self.metrics["decode_tokens"] += drafted + accepted
+        self.metrics["decode_steps"] += rounds
         self.metrics["decode_dispatches"] += 1
         self.metrics["decode_dispatch_time_s"] += t_fetch - t_issue
         self.metrics["decode_host_time_s"] += (
@@ -2651,10 +2913,14 @@ class EngineCore:
                 adapters[s] = req.adapter_idx
                 dec_live[s] = 1
             pf_last = np.zeros((n_pf,), dtype=np.int32)
+            next_tokens = np.zeros((n,), dtype=np.int32) if self._mtp else None
             off = b * rq
             for j, (req, chunk, new_ctx) in enumerate(pf_rows):
                 r = b + j
                 tokens[off: off + chunk] = req.prompt_ids[req.prefill_pos:new_ctx]
+                if self._mtp:
+                    next_tokens[off: off + chunk] = self._next_tokens(
+                        req, req.prefill_pos, new_ctx)
                 positions[off: off + chunk] = np.arange(req.prefill_pos, new_ctx)
                 row_ids[off: off + (-(-chunk // rq) * rq)] = r
                 ctx_lens[r] = new_ctx
@@ -2740,7 +3006,7 @@ class EngineCore:
                 annotate("mixed"), self._span("issue"):
             t_issue = time.perf_counter()
             (toks_win, pf_toks, feed_new, self._kv_k, self._kv_v,
-             counts_out, experts) = self._keep_state(_mixed_step(
+             counts_out, experts) = self._keep_drafts(self._keep_state(_mixed_step(
                 self.params, self.cfg, jnp.asarray(tokens), self._feed_toks,
                 jnp.asarray(dec_idx), jnp.asarray(positions),
                 jnp.asarray(row_ids), self._kv_k, self._kv_v,
@@ -2768,7 +3034,8 @@ class EngineCore:
                 state_rows=self._state_rows(
                     list(self._slots) + [r for r, _, _ in pf_rows],
                     self._mix_rows),
-            ))
+                next_tokens=(jnp.asarray(next_tokens) if self._mtp else None),
+            )))
         for req, _, new_ctx in pf_rows:
             self._snapshot_state(req, new_ctx)
         if counts_out is not None:
@@ -2877,23 +3144,31 @@ class EngineCore:
             if not self.decoding:
                 return
         k = self._pick_k()
-        # Prompt-lookup speculation for all-greedy batches: one T=k verify
+        # Speculation serves all-greedy batches: the verify forward takes
+        # plain argmaxes. Penalized greedy shifts the argmax per position
+        # as counts evolve and it has no count plumbing — multi-step
+        # handles these; logit_bias likewise shifts the verify argmax.
+        spec = self.ecfg.speculative and all(
+            r.sampling.temperature == 0.0 and not r.sampling.guided
+            and not r.sampling.logprobs and not r.sampling.penalized
+            and not r.sampling.logit_bias for r in self.decoding)
+        if spec and self._mtp:
+            # The model's own module drafts, on the device, several rounds
+            # a dispatch. The rounds are built from the host's view, so
+            # the lagged window (a mixed step's) is settled first.
+            self._drain_pending()
+            rounds = self._pick_k(per_step=2) if self.decoding else 0
+            if rounds:
+                self._run_spec_rounds(rounds)
+            if rounds or not self.decoding:
+                return
+        # Prompt-lookup speculation: one T=k verify
         # forward replaces k sequential decode steps when any draft exists.
         # Drafting needs the host-current history, so each probe drains the
         # lagged window; a draftless probe backs off re-probing so
         # non-repetitive traffic keeps the overlap instead of paying a
         # drain every step.
-        if (k > 1 and self.ecfg.speculative
-                and all(r.sampling.temperature == 0.0
-                        and not r.sampling.guided
-                        and not r.sampling.logprobs
-                        # Penalized greedy shifts the argmax per position
-                        # as counts evolve; the verify forward has no
-                        # count plumbing — multi-step handles these.
-                        # logit_bias likewise shifts the verify argmax.
-                        and not r.sampling.penalized
-                        and not r.sampling.logit_bias
-                        for r in self.decoding)):
+        elif k > 1 and spec:
             if self._spec_backoff > 0:
                 self._spec_backoff -= 1
             else:
@@ -2982,7 +3257,7 @@ class EngineCore:
             last_logits = None
             if k == 1:
                 (toks, last_logits, self._kv_k, self._kv_v,
-                 counts_out, experts) = self._keep_state(_decode_step(
+                 counts_out, experts) = self._keep_drafts(self._keep_state(_decode_step(
                     self.params, self.cfg, tokens_dev, jnp.asarray(positions),
                     self._kv_k, self._kv_v, si.tables, jnp.asarray(ctx_lens),
                     si.temps, si.top_ps, si.top_ks, sub,
@@ -2991,12 +3266,13 @@ class EngineCore:
                     page_size=self.ecfg.page_size, block_pages=self.ecfg.block_pages,
                     attn_impl=self.ecfg.attn_impl, mesh=self.mesh,
                     qmm_impl=self.ecfg.qmm_impl, state=self._state,
-                ))
+                    self_draft=self._mtp,
+                )))
                 self._feed_toks = toks
                 toks_win = toks[:, None]  # [B, 1]
             else:
                 (toks_win, self._kv_k, self._kv_v, counts_out,
-                 experts) = self._keep_state(_decode_multi(
+                 experts) = self._keep_drafts(self._keep_state(_decode_multi(
                     self.params, self.cfg, tokens_dev, jnp.asarray(positions),
                     self._kv_k, self._kv_v, si.tables, jnp.asarray(ctx_lens),
                     si.temps, si.top_ps, si.top_ks, sub,
@@ -3004,7 +3280,8 @@ class EngineCore:
                     page_size=self.ecfg.page_size, block_pages=self.ecfg.block_pages,
                     k_steps=k, attn_impl=self.ecfg.attn_impl, mesh=self.mesh,
                     qmm_impl=self.ecfg.qmm_impl, state=self._state,
-                ))
+                    self_draft=self._mtp,
+                )))
                 self._feed_toks = toks_win[:, -1]
             if counts_out is not None:
                 self._tok_counts = counts_out
@@ -3171,6 +3448,8 @@ class EngineCore:
         }
         if step.experts is not None:
             rec["experts"] = step.experts
+        if step.spec is not None:
+            rec["spec"] = step.spec
         if self._state is not None:
             # Slots that hold a sequence's state (decoding or prefilling),
             # and the snapshot counters' growth over this step.

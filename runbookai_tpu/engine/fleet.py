@@ -491,7 +491,7 @@ class AsyncFleet:
             hashes = hash_blocks(
                 prompt_ids, self._page_size,
                 max_blocks=(len(prompt_ids) - 1) // self._page_size,
-                seed=hash_seed)
+                seed=hash_seed, lookahead=self.cores[0].kv.lookahead)
         # (idx, matched, load, queue_depth): load is the full live count
         # (waiting + prefilling + decoding); queue_depth is the not-yet-
         # decoding backlog — the tiebreak between equally-loaded replicas

@@ -65,7 +65,14 @@ STEP_RECORD_FIELDS = (
 # pool that hold a sequence), and this step's ``snapshots_taken``,
 # ``snapshots_restored``, ``snapshot_evictions``, ``hash_tokens_matched``
 # and ``hash_tokens_granted`` (engine/kv_cache.py ``StateSnapshots``).
-OPTIONAL_STEP_FIELDS = ("experts", "state")
+# ``spec``: in a step whose ``_decode_spec`` dispatch ran ROUNDS drafted by
+# the model's own prediction module (models/joyai.py): ``rounds`` a row in
+# the dispatch (also the record's ``k``), drafts verified (``drafted``: one
+# a round and row, up to the row's stop), those whose second token was
+# served (``accepted``), and the ``rows`` dispatched. Such a step's
+# ``decode_tokens`` is ``drafted + accepted``: a row takes ``rounds`` to
+# ``2 * rounds`` tokens of it.
+OPTIONAL_STEP_FIELDS = ("experts", "state", "spec")
 
 # ``phases`` keys besides "other" (= wall_s less their sum), and the
 # profiler span that marks the same boundaries on the device trace's
@@ -96,12 +103,13 @@ class OpenStep:
     drains nest. Written by the step thread only."""
 
     __slots__ = ("t_start", "phases", "programs", "k", "rows",
-                 "kv_pages_live", "experts", "_stack", "_t")
+                 "kv_pages_live", "experts", "spec", "_stack", "_t")
 
     def __init__(self):
         self.phases = dict.fromkeys(STEP_PHASES, 0.0)
         self.programs: list[str] = []
         self.experts: Optional[dict[str, Any]] = None
+        self.spec: Optional[dict[str, int]] = None  # self-drafted rounds
         self.k = 0  # decode steps in the dispatch
         self.rows = 0  # decode rows dispatched
         self.kv_pages_live = 0  # KV pages the dispatch's rows held

@@ -113,7 +113,8 @@ class PagePool:
 
 
 def hash_blocks(token_ids: Sequence[int], page_size: int,
-                max_blocks: Optional[int] = None, seed: int = 0) -> list[int]:
+                max_blocks: Optional[int] = None, seed: int = 0,
+                lookahead: int = 0) -> list[int]:
     """FNV-1a hash chain over full pages of ``token_ids``.
 
     Block i's hash folds in block i-1's, so equal hashes imply equal full
@@ -124,7 +125,23 @@ def hash_blocks(token_ids: Sequence[int], page_size: int,
     adapter hold DIFFERENT values for the same tokens (adapters on wk/wv),
     so each adapter_idx seeds its own chain and can never match another
     adapter's (or the base model's) pages.
+
+    ``lookahead`` (0 or 1): a page's rows also depend on the FIRST token
+    after it (a prediction module's row of position ``i`` is made of token
+    ``i + 1``: models/joyai.py), so that token is folded into the page's
+    hash — not into the chain, whose next block holds it anyway — and a
+    page with no token after it yet has no hash.
     """
+    if lookahead:
+        n_full = (len(token_ids) - lookahead) // page_size
+        if max_blocks is not None:
+            n_full = min(n_full, max_blocks)
+        if n_full <= 0:
+            return []
+        chain = hash_blocks(token_ids, page_size, n_full, seed)
+        return [((h ^ ((token_ids[(b + 1) * page_size] + 1) & 0xFFFFFFFFFFFFFFFF))
+                 * 0x100000001B3) & 0xFFFFFFFFFFFFFFFF
+                for b, h in enumerate(chain)]
     from runbookai_tpu import native
 
     if seed == 0 and native.available():
@@ -493,7 +510,11 @@ class KVCacheManager:
         spill_pages: int = 0,
         v_side: Optional[tuple[int, int, int]] = None,
         state_snapshots: Optional[int] = None,
+        lookahead: int = 0,
     ):
+        # Tokens past a page's end that its rows depend on (``hash_blocks``):
+        # a page is published, matched and verified with that many more.
+        self.lookahead = lookahead
         # None: every layer's state is token rows in pages, and a page hit
         # is the whole hit. A number (0 included): the model also keeps
         # recurrent state, and a hit is granted only to a boundary whose
@@ -548,7 +569,13 @@ class KVCacheManager:
         if hashes is not None:
             return hashes[:max_blocks]
         return hash_blocks(prompt_ids, self.page_size, max_blocks,
-                           seed=hash_seed)
+                           seed=hash_seed, lookahead=self.lookahead)
+
+    def _block(self, token_ids: Sequence[int], b: int) -> tuple[int, ...]:
+        """The tokens page ``b``'s rows are made of: what a match is
+        verified against."""
+        return tuple(token_ids[b * self.page_size:
+                               (b + 1) * self.page_size + self.lookahead])
 
     def _match_pages(self, prompt_ids: Sequence[int],
                      hashes: Optional[list[int]],
@@ -561,8 +588,7 @@ class KVCacheManager:
             page = self.allocator.lookup(h)
             if page is None:
                 break
-            blk = tuple(prompt_ids[b * self.page_size : (b + 1) * self.page_size])
-            if self._page_tokens.get(page) != blk:
+            if self._page_tokens.get(page) != self._block(prompt_ids, b):
                 break  # hash collision or stale publish — treat as a miss
             matched.append(page)
         return matched
@@ -663,16 +689,16 @@ class KVCacheManager:
         alloc = self.seqs.get(seq_id)
         if alloc is None:
             return
-        max_blocks = min(len(token_ids) // self.page_size, len(alloc.pages))
+        max_blocks = min((len(token_ids) - self.lookahead) // self.page_size,
+                         len(alloc.pages))
         if hashes is None or len(hashes) < max_blocks:
             hashes = hash_blocks(token_ids, self.page_size, max_blocks,
-                                 seed=alloc.hash_seed)
+                                 seed=alloc.hash_seed, lookahead=self.lookahead)
         for b in range(alloc.registered_blocks, max_blocks):
             page = alloc.pages[b]
             self.allocator.register(page, hashes[b])
             if self.allocator.lookup(hashes[b]) == page:  # publish took effect
-                self._page_tokens[page] = tuple(
-                    token_ids[b * self.page_size : (b + 1) * self.page_size])
+                self._page_tokens[page] = self._block(token_ids, b)
         alloc.registered_blocks = max(alloc.registered_blocks, max_blocks)
 
     def take_snapshot(self, seq_id: str, token_ids: Sequence[int],
@@ -690,7 +716,8 @@ class KVCacheManager:
         block = len(token_ids) // self.page_size - 1
         alloc = self.seqs[seq_id]
         if hashes is None or len(hashes) <= block:
-            hashes = hash_blocks(token_ids, self.page_size, seed=alloc.hash_seed)
+            hashes = hash_blocks(token_ids, self.page_size, seed=alloc.hash_seed,
+                                 lookahead=self.lookahead)
         page = self.allocator.lookup(hashes[block])
         if page is None:
             return None
@@ -712,7 +739,7 @@ class KVCacheManager:
             page = self.allocator.lookup(h)
             if page is None:
                 break
-            blk = tuple(prompt_ids[b * self.page_size:(b + 1) * self.page_size])
+            blk = self._block(prompt_ids, b)
             if self._page_tokens.get(page) != blk:
                 break
             pages.append(page)
@@ -932,7 +959,7 @@ class KVCacheManager:
         items = []
         for b in range(start, len(chain)):
             entry = self.spill.get(chain[b])
-            blk = tuple(prompt_ids[b * self.page_size:(b + 1) * self.page_size])
+            blk = self._block(prompt_ids, b)
             if entry is None or entry.blocks != blk:
                 break
             if _block_digest(entry.leaves_k, entry.leaves_v, 0,

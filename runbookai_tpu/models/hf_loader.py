@@ -66,6 +66,10 @@ def config_from_hf(model_dir: str | Path, name: str = "hf-model") -> LlamaConfig
         raise NotImplementedError(
             f"model_type {model_type!r}: the qwen3-next family runs on seeded "
             f"random weights only (models/qwen3_next.py); no checkpoint loader yet")
+    if model_type.startswith("joyai"):
+        raise NotImplementedError(
+            f"model_type {model_type!r}: the joyai family runs on seeded "
+            f"random weights only (models/joyai.py); no checkpoint loader yet")
     if model_type not in SUPPORTED_MODEL_TYPES:
         raise ValueError(
             f"model_type {model_type!r} not supported; known: "
@@ -278,9 +282,16 @@ def load_or_init(
     Random init keeps every serving path exercisable in the no-egress
     environment (BASELINE.md configs run with real weights when provided).
     """
+    from runbookai_tpu.models.joyai import JoyaiConfig
     from runbookai_tpu.models.longcat import LongcatConfig
     from runbookai_tpu.models.qwen3_next import Qwen3NextConfig
 
+    joyai = isinstance(CONFIGS.get(model_name), JoyaiConfig)
+    if joyai and model_path and Path(model_path).exists():
+        raise NotImplementedError(
+            f"model {model_name!r}: no loader for checkpoints of the joyai "
+            f"family yet (MLA, expert and prediction-module tensor names); "
+            f"leave llm.model_path unset to serve seeded random weights")
     longcat = isinstance(CONFIGS.get(model_name), LongcatConfig)
     if longcat and model_path and Path(model_path).exists():
         raise NotImplementedError(
@@ -320,16 +331,18 @@ def load_or_init(
             f"unknown model {model_name!r} and no checkpoint at "
             f"{str(model_path)!r}; known configs: {sorted(CONFIGS)}")
     cfg = CONFIGS[model_name]
-    if longcat or qwen3_next:
+    if longcat or qwen3_next or joyai:
+        from runbookai_tpu.models import joyai as joyai_model
         from runbookai_tpu.models import longcat as longcat_model
         from runbookai_tpu.models import qwen3_next as qwen3_next_model
 
+        model, family = ((longcat_model, "longcat") if longcat else
+                         (qwen3_next_model, "qwen3-next") if qwen3_next else
+                         (joyai_model, "joyai"))
         if quantize_int8 or shardings:
             raise ValueError(
-                f"model {model_name!r} (family "
-                f"{'longcat' if longcat else 'qwen3-next'}) serves bf16 or "
+                f"model {model_name!r} (family {family}) serves bf16 or "
                 f"float32 weights on one chip: no int8 matrices, no mesh")
-        model = longcat_model if longcat else qwen3_next_model
         return cfg, quiet_control_tokens(model.init_params(
             jax.random.PRNGKey(seed), cfg, dtype=dtype), cfg.vocab_size)
     # int8 leaves are sampled directly: a 7B bf16 tree (15 GB) plus the

@@ -34,6 +34,8 @@ from typing import Any, Optional
 import jax
 import jax.numpy as jnp
 
+from runbookai_tpu.models.joyai import CONFIGS as _JOYAI_CONFIGS
+from runbookai_tpu.models.joyai import JoyaiConfig
 from runbookai_tpu.models.longcat import CONFIGS as _LONGCAT_CONFIGS
 from runbookai_tpu.models.longcat import LongcatConfig
 from runbookai_tpu.models.qwen3_next import CONFIGS as _QWEN3_NEXT_CONFIGS
@@ -142,7 +144,7 @@ class LlamaConfig:
                 + ffn_delta)
 
 
-CONFIGS: dict[str, LlamaConfig | LongcatConfig | Qwen3NextConfig] = {
+CONFIGS: dict[str, LlamaConfig | LongcatConfig | Qwen3NextConfig | JoyaiConfig] = {
     "llama3-8b-instruct": LlamaConfig(
         name="llama3-8b-instruct", vocab_size=128_256, dim=4096, n_layers=32,
         n_heads=32, n_kv_heads=8, ffn_dim=14_336,
@@ -253,10 +255,13 @@ CONFIGS: dict[str, LlamaConfig | LongcatConfig | Qwen3NextConfig] = {
     **_LONGCAT_CONFIGS,
     # A period of unlike layers over two kinds of state (models/qwen3_next.py).
     **_QWEN3_NEXT_CONFIGS,
+    # A leading dense layer, then expert layers, and the model's own
+    # prediction module as the drafter (models/joyai.py).
+    **_JOYAI_CONFIGS,
 }
 
 
-def get_config(name: str) -> LlamaConfig | LongcatConfig | Qwen3NextConfig:
+def get_config(name: str) -> LlamaConfig | LongcatConfig | Qwen3NextConfig | JoyaiConfig:
     if name not in CONFIGS:
         raise KeyError(f"Unknown model {name!r}; known: {sorted(CONFIGS)}")
     return CONFIGS[name]

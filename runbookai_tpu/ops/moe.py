@@ -25,7 +25,7 @@ exact transformers numerics; perf-tuned serving lowers the factor.
 from __future__ import annotations
 
 import math
-from typing import Any
+from typing import Any, Optional
 
 import jax
 import jax.numpy as jnp
@@ -132,15 +132,37 @@ def route_renormalised(
     return chosen, w / jnp.sum(w, axis=-1, keepdims=True)
 
 
+def route_sigmoid(
+    u: jnp.ndarray,            # [N, D] post-norm hidden
+    router: jnp.ndarray,       # [D, E] float32
+    bias: jnp.ndarray,         # [E] float32, moves the CHOICE only
+    top_k: int,
+    scale: float,
+) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """Scores ``s = sigmoid_f32(u W_r)``, each expert's own; the ``top_k``
+    of ``s + bias`` are chosen (one group: no group limit); a chosen
+    expert's weight is ``scale * s / sum of the chosen s``. Returns (chosen
+    ids [N, K], weights [N, K] f32). As :func:`route_scaled`, the product
+    runs at ``highest`` precision."""
+    logits = jnp.dot(u.astype(jnp.float32), router.astype(jnp.float32),
+                     precision=jax.lax.Precision.HIGHEST)
+    s = jax.nn.sigmoid(logits)
+    _, chosen = jax.lax.top_k(s + bias.astype(jnp.float32), top_k)
+    w = jnp.take_along_axis(s, chosen, axis=-1)
+    return chosen, scale * w / jnp.sum(w, axis=-1, keepdims=True)
+
+
 def shared_expert(u: jnp.ndarray, w_gate: Any, w_up: Any, w_down: Any,
-                  gate: jnp.ndarray) -> jnp.ndarray:
-    """``sigmoid(u w_sg) * SwiGLU_shared(u)``: the expert every token runs,
-    under its own sigmoid gate (``gate`` [D, 1]). Every share of an
-    expert-parallel group computes it for its own tokens; a sum over the
-    shares counts it once."""
+                  gate: Optional[jnp.ndarray] = None) -> jnp.ndarray:
+    """``SwiGLU_shared(u)``, the expert every token runs — under its own
+    sigmoid gate, ``sigmoid(u w_sg)``, where the model has one (``gate``
+    [D, 1]). Every share of an expert-parallel group computes it for its
+    own tokens; a sum over the shares counts it once."""
     from runbookai_tpu.models.llama import qmm  # deferred: models->ops cycle
 
     y = qmm(jax.nn.silu(qmm(u, w_gate)) * qmm(u, w_up), w_down)
+    if gate is None:
+        return y
     g = jax.nn.sigmoid(u.astype(jnp.float32) @ gate.astype(jnp.float32))
     return (g * y.astype(jnp.float32)).astype(u.dtype)
 
