@@ -555,13 +555,13 @@ def _forward_hidden(
         # The readers take the layer's slice of the carry, [tokens, n_kv,
         # hd], as when the scan handed it to them. On the chip XLA stages
         # that slice in on-chip memory and the Pallas kernels fetch their
-        # 16 KB pages from there: the chunk kernel one a grid step, the
-        # decode kernel its rows' live pages, several a step of its walk
-        # (PR 28; the page view it takes is a bitcast of the slice).
-        # Fetching one page a grid step straight out of the pool in HBM (a
-        # layer coordinate in the kernels' index_maps, no slice) was
-        # measured and doubled both kernels' time (PERF.md section 6, PR
-        # 25); the walk reading the pool in place is ROADMAP A5.
+        # 16 KB pages from there: both walk a row's (a query block's) live
+        # pages inside the kernel, several a step (decode since PR 28,
+        # chunks since PR 36; the page views they take are bitcasts of the
+        # slice). Fetching one page a grid step straight out of the pool
+        # in HBM (a layer coordinate in the kernels' index_maps, no slice)
+        # was measured and doubled both kernels' time (PERF.md section 6,
+        # PR 25); the walks reading the pool in place is ROADMAP A5.
         def layer_slice(pool):  # int8 pools are (values, scales)
             return jax.tree.map(lambda a: jax.lax.dynamic_index_in_dim(
                 a, li, keepdims=False), pool)

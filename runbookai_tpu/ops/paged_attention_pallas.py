@@ -6,25 +6,32 @@ table every step; these kernels read the **scalar-prefetched** page table
 and fetch exactly the pages a sequence owns into VMEM, flash-accumulating
 (m, l, acc) in float32 scratch (PAPERS.md "Ragged Paged Attention").
 
-Two kernels:
+Two entry points, ONE page walk (:func:`_walk_pages`). **The walk is inside
+the kernel**: a grid step loops over ITS row's page table, several pages a
+step, so an empty slot costs one grid step and a row of 400 tokens the
+work of 400 tokens, whatever the table's width. The pool stays where XLA
+put it (``memory_space=pl.ANY``); a step issues one async copy a LIVE page
+into a double-buffered VMEM group, the next group's copies in flight while
+this one is accumulated (:func:`_flash_accumulate`, the one body). Pages a
+step comes from the page's bytes against a fixed VMEM budget
+(:func:`decode_pages_per_step`).
 
-- :func:`paged_decode_attention` — decode-shaped (T = 1). **The page walk is
-  inside the kernel**: grid = (rows,), and each row loops
-  ``cdiv(ctx_lens[row], pages a step x page_size)`` times over ITS page
-  table, so an empty slot costs one grid step and a row of 400 tokens the
-  work of 400 tokens, whatever the table's width. The pool stays where XLA
-  put it (``memory_space=pl.ANY``); a step issues one async copy a live
-  page into a double-buffered VMEM group, the next group's copies in
-  flight while this one is accumulated. Pages a step comes from the page's
-  bytes against a fixed VMEM budget (:func:`decode_pages_per_step`). One
-  walk serves every pool: raw pages, int8 pages with scales, and a page-
-  split shard that skips the pages it does not own and returns partials.
-- :func:`paged_chunk_attention` — T > 1 (chunked prefill and the speculative
-  verify forward), grid (batch, q_blocks, pages) with the page axis innermost
-  so scratch carries across a sequence's pages, one page a grid step; query
-  positions are scalar-prefetched for the causal+ragged mask, and the query
-  dimension is blocked to bound VMEM scratch (TQ·n_q accumulator rows per
-  step). (ROADMAP A2: the decode walk is what it should take over.)
+- :func:`paged_decode_attention` — decode-shaped (T = 1), grid = (rows,):
+  a row walks ``cdiv(ctx_lens[row], pages a step x page_size)`` steps. All
+  kv heads of a group go through one product (a foreign head's column is
+  masked like a dead position). One kernel serves every pool: raw pages,
+  int8 pages with scales, and a page-split shard that skips the pages it
+  does not own and returns partials.
+- :func:`paged_chunk_attention` — T > 1 (chunked prefill, the speculative
+  verify forward, and through :func:`paged_ragged_attention` the mixed
+  step's flat buffer), grid = (rows, query blocks): a block of TQ queries
+  walks ``cdiv(min(ctx, q0 + TQ), pages a step x page_size)`` steps — the
+  causal bound ends the walk as well as the context, so a prompt's first
+  block walks one step and a pad block (``ctx == 0``) none. One product a
+  KV HEAD over that head's keys alone (TQ x group rows); the mask is per
+  query row (``pos < ctx`` and ``pos <= q0 + t``: positions contiguous, so
+  they derive from the scalar-prefetched start). TQ bounds the VMEM
+  scratch (:func:`chunk_q_block`).
 
 Selected by ``EngineConfig.attn_impl = "pallas"``; interpret mode keeps it
 testable on CPU meshes.
@@ -41,19 +48,22 @@ from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 
-# VMEM the decode walk may spend on page buffers (K and V, two groups
-# each), and the most cache positions one step takes: past that a step's
-# scores outgrow the vector registers and a row's last, part-filled group
-# wastes more than a longer step saves in loop trips.
+# VMEM a walk may spend on page buffers (K and V, two groups each), and
+# the most cache positions one step takes: past that a step's scores
+# outgrow the vector registers and a row's last, part-filled group wastes
+# more than a longer step saves in loop trips.
 _DECODE_KV_VMEM_BYTES = 1 << 20
 _DECODE_STEP_POSITIONS = 512
 
 
 def decode_pages_per_step(page_size: int, n_kv: int, hd: int, kv_dtype,
                           pages_per_seq: int) -> int:
-    """Pages one step of the decode walk fetches and accumulates: what the
-    VMEM budget holds of this pool's pages, at most ``_DECODE_STEP_POSITIONS``
-    positions and never more than a row's table has columns."""
+    """Pages one step of a walk (the decode kernel's and the chunk
+    kernel's) fetches and accumulates: what the VMEM budget holds of this
+    pool's pages, at most ``_DECODE_STEP_POSITIONS`` positions and never
+    more than a row's table has columns. The chunk walk's score block
+    (hundreds of query rows a step) does not enter: cutting the step to it
+    was measured twice as slow at 896 rows (PERF.md section 6, PR 36)."""
     page_bytes = page_size * n_kv * hd * jnp.dtype(kv_dtype).itemsize
     return max(1, min(_DECODE_KV_VMEM_BYTES // (4 * page_bytes),
                       _DECODE_STEP_POSITIONS // page_size, pages_per_seq))
@@ -95,6 +105,68 @@ def _flash_accumulate(q, k, v, valid, m_ref, l_ref, acc_ref,
         preferred_element_type=jnp.float32)  # [n_q, hd]
 
 
+def _flash_init(m_ref, l_ref, acc_ref) -> None:
+    """The scratch of a row (a query block) that has accumulated nothing."""
+    m_ref[:] = jnp.full_like(m_ref, NEG_INF)
+    l_ref[:] = jnp.zeros_like(l_ref)
+    acc_ref[:] = jnp.zeros_like(acc_ref)
+
+
+def _flash_output(l_ref, acc_ref):
+    """The normalised output; a row that accumulated nothing reads zeros."""
+    return acc_ref[:] / jnp.maximum(l_ref[:, :1], 1e-30)
+
+
+def _walk_pages(n_steps, pages_per_step: int, page, place, srcs, bufs,
+                sems, accumulate) -> None:
+    """The page walk of BOTH kernels: ``n_steps`` groups of
+    ``pages_per_step`` table columns, one async copy a LIVE page (and
+    source) into the group's VMEM slot, the next group's copies in flight
+    while ``accumulate(step, slot)`` works on this one. ``page(col)`` says
+    whether the walk reads table column ``col`` and where the page lies in
+    the sources; ``place(buf, slot, i)`` is the group's ``i``-th page in a
+    buffer. A dead column is never fetched."""
+    def copies(step, slot, wait: bool) -> None:
+        """Start, or wait for, the copies of one group into ``slot``."""
+        def one(i, _):
+            live, pid = page(step * pages_per_step + i)
+
+            @pl.when(live)
+            def _():
+                for s, (src, buf) in enumerate(zip(srcs, bufs)):
+                    copy = pltpu.make_async_copy(
+                        src.at[pid], place(buf, slot, i), sems.at[slot, s])
+                    copy.wait() if wait else copy.start()
+
+            if wait:
+                # A page the walk does not read is masked out of p, and
+                # 0 x what its buffer held must be 0: never-written VMEM
+                # or another row's page may hold a NaN. Zero the V side.
+                @pl.when(jnp.logical_not(live))
+                def _():
+                    for buf in bufs[1::2]:
+                        dst = place(buf, slot, i)
+                        dst[...] = jnp.zeros(dst.shape, dst.dtype)
+
+        jax.lax.fori_loop(0, pages_per_step, one, None)
+
+    @pl.when(n_steps > 0)
+    def _first():
+        copies(0, 0, wait=False)
+
+    def step(i, _):
+        slot = jax.lax.rem(i, 2)
+
+        @pl.when(i + 1 < n_steps)
+        def _next():
+            copies(i + 1, 1 - slot, wait=False)
+
+        copies(i, slot, wait=True)
+        accumulate(i, slot)
+
+    jax.lax.fori_loop(0, n_steps, step, None)
+
+
 def _decode_walk_kernel(*refs, page_size: int, n_kv: int, group: int,
                         pages_per_step: int, sm_scale: float, scaled: bool,
                         pages_local: int | None):
@@ -133,43 +205,17 @@ def _decode_walk_kernel(*refs, page_size: int, n_kv: int, group: int,
     span = pages_per_step * page_size
     last_col = tables_ref.shape[1] - 1
 
-    m_ref[:] = jnp.full_like(m_ref, NEG_INF)
-    l_ref[:] = jnp.zeros_like(l_ref)
-    acc_ref[:] = jnp.zeros_like(acc_ref)
+    _flash_init(m_ref, l_ref, acc_ref)
 
-    def page(step, i):
-        """Table column ``step * pages_per_step + i`` of this row: whether
-        the walk reads it, and its index in the sources."""
-        col = step * pages_per_step + i
+    def page(col):
+        """Table column ``col`` of this row: whether the walk reads it,
+        and its index in the sources."""
         live = col * page_size < ctx
         pid = tables_ref[row, jnp.minimum(col, last_col)]
         if partial:
             live = live & (pid // pages_local == shard)
             pid = pid - shard * pages_local
         return live, pid
-
-    def copies(step, slot, wait: bool) -> None:
-        """Start, or wait for, the copies of one group into ``slot``."""
-        def one(i, _):
-            live, pid = page(step, i)
-
-            @pl.when(live)
-            def _():
-                for s, (src, buf) in enumerate(zip(srcs, bufs)):
-                    copy = pltpu.make_async_copy(
-                        src.at[pid], buf.at[slot, i], sems.at[slot, s])
-                    copy.wait() if wait else copy.start()
-
-            if wait:
-                # A page the walk does not read is masked out of p, and
-                # 0 x what its buffer held must be 0: never-written VMEM
-                # or another row's page may hold a NaN. Zero the V side.
-                @pl.when(jnp.logical_not(live))
-                def _():
-                    for buf in bufs[1::2]:
-                        buf[slot, i] = jnp.zeros(buf.shape[2:], buf.dtype)
-
-        jax.lax.fori_loop(0, pages_per_step, one, None)
 
     # A column of a group is (position, kv head), as the pool lays a page
     # out; query head r reads kv head r // group (kv-major head order, the
@@ -201,27 +247,15 @@ def _decode_walk_kernel(*refs, page_size: int, n_kv: int, group: int,
             preferred_element_type=jnp.float32)  # [span, columns]
         return jnp.sum(jnp.where(own_pos, x, 0.0), axis=0, keepdims=True)
 
-    n_steps = pl.cdiv(ctx, span)
-
-    @pl.when(n_steps > 0)
-    def _first():
-        copies(0, 0, wait=False)
-
-    def step(i, _):
-        slot = jax.lax.rem(i, 2)
-
-        @pl.when(i + 1 < n_steps)
-        def _next():
-            copies(i + 1, 1 - slot, wait=False)
-
-        copies(i, slot, wait=True)
+    def accumulate(i, slot):
         valid = own_head & (i * span + col_pos < ctx)
         if partial:
             col_page = jax.lax.div(col, rows_per_page)
             mine = jax.lax.fori_loop(
                 0, pages_per_step,
                 lambda j, mine: jnp.where(
-                    col_page == j, page(i, j)[0].astype(jnp.int32), mine),
+                    col_page == j,
+                    page(i * pages_per_step + j)[0].astype(jnp.int32), mine),
                 jnp.zeros_like(col))
             valid = valid & (mine > 0)
         _flash_accumulate(
@@ -230,13 +264,14 @@ def _decode_walk_kernel(*refs, page_size: int, n_kv: int, group: int,
             k_scale=scale_row(bufs[2], slot) if scaled else None,
             v_scale=scale_row(bufs[3], slot) if scaled else None)
 
-    jax.lax.fori_loop(0, n_steps, step, None)
+    _walk_pages(pl.cdiv(ctx, span), pages_per_step, page,
+                lambda buf, slot, i: buf.at[slot, i], srcs, bufs, sems,
+                accumulate)
 
     if partial:
         outs[0][0], outs[1][0], outs[2][0] = acc_ref[:], m_ref[:], l_ref[:]
     else:
-        outs[0][0] = (acc_ref[:] / jnp.maximum(l_ref[:, :1], 1e-30)
-                      ).astype(outs[0].dtype)
+        outs[0][0] = _flash_output(l_ref, acc_ref).astype(outs[0].dtype)
 
 
 def _lane_pad(x: jnp.ndarray) -> jnp.ndarray:
@@ -245,6 +280,25 @@ def _lane_pad(x: jnp.ndarray) -> jnp.ndarray:
     whole lane tiles."""
     short = -x.shape[-1] % 128
     return jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, short)]) if short else x
+
+
+def _chunk_pages(pool: jnp.ndarray, page_size: int) -> jnp.ndarray:
+    """``pool [tokens, n_kv, hd]`` as the pages the chunk walk copies,
+    ``[pages, page_size, n_kv, hd]``: the bytes as they lie, for the
+    serving shapes (4 or 8 kv heads of 128 in bf16 or fp8). Mosaic slices
+    such an operand only in whole sublane tiles — the next power of two
+    rows between a 32-bit word's and eight words' — so any other head
+    count is zero-padded to them, and a lone head (a tp shard's) gives up
+    its axis, ``[pages, page_size, hd]``: XLA lays that pool out without
+    one, and padding it would copy the pool a layer."""
+    pool = _lane_pad(pool)
+    n_kv, hd = pool.shape[1:]
+    if n_kv == 1:
+        return pool.reshape(-1, page_size, hd)
+    packing = 4 // pool.dtype.itemsize
+    tile = min(8 * packing, max(packing, pl.next_power_of_2(n_kv)))
+    pool = jnp.pad(pool, ((0, 0), (0, -n_kv % tile), (0, 0)))
+    return pool.reshape(-1, page_size, *pool.shape[1:])
 
 
 def _decode_walk(q, k_flat, v_flat, page_tables, ctx_lens, page_size: int,
@@ -325,118 +379,79 @@ def paged_decode_attention(
                         interpret)
 
 
-def _chunk_kernel(
-    # scalar prefetch:
-    page_tables_ref,  # [B, P] int32 (SMEM)
-    ctx_lens_ref,  # [B] int32 (SMEM)
-    q_start_ref,  # [B] int32 (SMEM) — absolute position of each row's query 0
-    # blocks:
-    q_ref,  # [1, TQ, n_q, hd]
-    k_ref,  # [1, page_size, n_kv, hd]
-    v_ref,  # [1, page_size, n_kv, hd]
-    o_ref,  # [1, TQ, n_q, hd]
-    # scratch:
-    m_ref,  # [TQ*n_q, 128] f32
-    l_ref,  # [TQ*n_q, 128] f32
-    acc_ref,  # [TQ*n_q, hd] f32
-    *,
-    page_size: int,
-    n_kv: int,
-    group: int,
-    tq: int,
-    pages_per_seq: int,
-):
-    b = pl.program_id(0)
-    qb = pl.program_id(1)
-    p = pl.program_id(2)
+def _chunk_walk_kernel(tables_ref, ctx_ref, q_start_ref, q_ref, k_src, v_src,
+                       o_ref, k_buf, v_buf, sems, q_scr, m_ref, l_ref,
+                       acc_ref, *, page_size: int, n_kv: int, group: int,
+                       tq: int, pages_per_step: int, sm_scale: float):
+    """One grid step = one block of ``tq`` queries of one row: walk the
+    pages it can see, ``pages_per_step`` at a time. The causal bound ends
+    the walk as well as the context does, so a prompt's first block walks
+    one step and a pad block (``ctx == 0``) none: it writes zeros.
 
-    @pl.when(p == 0)
-    def _init():
-        m_ref[:] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[:] = jnp.zeros_like(l_ref)
-        acc_ref[:] = jnp.zeros_like(acc_ref)
+    The scratch rows are kv-major: rows ``[h * tq * group, (h + 1) * tq *
+    group)`` are kv head ``h``'s queries, token-major inside the head, and
+    each head's block goes through :func:`_flash_accumulate` against ITS
+    keys ``[positions, hd]`` alone — at ``tq * n_q`` score rows the
+    decode walk's one product over every head would spend ``n_kv - 1``
+    parts in ``n_kv`` of its operations on masked columns."""
+    row, qb = pl.program_id(0), pl.program_id(1)
+    ctx = ctx_ref[row]
+    # Query positions are contiguous per row (the wrapper's contract), so
+    # they derive from the scalar start: no vector SMEM reads.
+    q0 = q_start_ref[row] + qb * tq
+    seen = jnp.minimum(ctx, q0 + tq)  # positions some query of the block sees
+    hd = q_ref.shape[-1]
+    rows = tq * group
+    span = pages_per_step * page_size
+    last_col = tables_ref.shape[1] - 1
 
-    ctx = ctx_lens_ref[b]
-    base = p * page_size
-    # Query positions are contiguous per sequence (wrapper contract), so row
-    # positions derive from the scalar start — no vector SMEM reads needed.
-    q0 = q_start_ref[b] + qb * tq
-    qpos_max = q0 + tq - 1
+    _flash_init(m_ref, l_ref, acc_ref)
+    q = q_ref[0].astype(jnp.float32) * sm_scale  # [tq, n_q, hd]
+    for h in range(n_kv):
+        q_scr[h * rows:(h + 1) * rows] = (
+            q[:, h * group:(h + 1) * group].reshape(rows, hd))
 
-    @pl.when((base < ctx) & (base <= qpos_max))
-    def _accumulate():
-        q = q_ref[0].astype(jnp.float32)  # [TQ, n_q, hd]
-        hd = q.shape[-1]
-        scale = 1.0 / (hd ** 0.5)
-        # Row r of a per-kv-head block is query token r // group; mask built
-        # entirely from 2D iotas (Mosaic-friendly).
-        shape = (tq * group, page_size)
-        cache_pos = base + jax.lax.broadcasted_iota(jnp.int32, shape, 1)
-        qpos_rows = q0 + jax.lax.broadcasted_iota(jnp.int32, shape, 0) // group
-        mask = (cache_pos < ctx) & (cache_pos <= qpos_rows)
+    def page(col):
+        return (col * page_size < seen,
+                tables_ref[row, jnp.minimum(col, last_col)])
 
-        m_prev = m_ref[:, :1]  # [TQ*n_q, 1]
-        l_prev = l_ref[:, :1]
-        acc_prev = acc_ref[:]
+    # The mask is per query ROW of a head's block, built from 2D iotas.
+    pos = jax.lax.broadcasted_iota(jnp.int32, (1, span), 1)
+    q_pos = q0 + jax.lax.div(
+        jax.lax.broadcasted_iota(jnp.int32, (rows, 1), 0), group)
 
-        s_rows = []
-        v_heads = []
+    def accumulate(i, slot):
+        at = i * span + pos
+        valid = (at < ctx) & (at <= q_pos)  # [rows, span]
         for h in range(n_kv):
-            k_h = k_ref[0, :, h, :].astype(jnp.float32)  # [ps, hd]
-            # [TQ, group, hd] -> [TQ*group, hd] rows (t-major within the head)
-            q_h = q[:, h * group : (h + 1) * group].reshape(tq * group, hd)
-            s_h = jax.lax.dot_general(
-                q_h * scale, k_h, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )  # [TQ*group, ps]
-            s_rows.append(jnp.where(mask, s_h, NEG_INF))
-            v_heads.append(v_ref[0, :, h, :].astype(jnp.float32))  # [ps, hd]
-        s = jnp.concatenate(s_rows, axis=0)  # [TQ*n_q, ps] (kv-major blocks)
+            mine = pl.ds(h * rows, rows)
+            k, v = ((buf[slot] if n_kv == 1 else buf[slot, :, h, :])
+                    for buf in (k_buf, v_buf))  # [span, hd]
+            _flash_accumulate(q_scr[mine], k, v, valid, m_ref.at[mine],
+                              l_ref.at[mine], acc_ref.at[mine])
 
-        m_blk = jnp.max(s, axis=1, keepdims=True)
-        m_new = jnp.maximum(m_prev, m_blk)
-        alpha = jnp.exp(m_prev - m_new)
-        # Fully-masked rows keep m == NEG_INF; exp(s - m) would be exp(0)=1
-        # there, so zero masked probabilities explicitly (keeps l exact and
-        # padded rows normalizing to zero).
-        p_blk = jnp.where(jnp.concatenate([mask] * n_kv, axis=0),
-                          jnp.exp(s - m_new), 0.0)
-        l_new = l_prev * alpha + jnp.sum(p_blk, axis=1, keepdims=True)
+    _walk_pages(
+        pl.cdiv(seen, span), pages_per_step, page,
+        lambda buf, slot, i: buf.at[slot, pl.ds(i * page_size, page_size)],
+        [k_src, v_src], [k_buf, v_buf], sems, accumulate)
 
-        pv_rows = []
-        for h in range(n_kv):
-            p_h = p_blk[h * tq * group : (h + 1) * tq * group]
-            pv_rows.append(jax.lax.dot_general(
-                p_h, v_heads[h], (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            ))  # [TQ*group, hd]
-        pv = jnp.concatenate(pv_rows, axis=0)
-
-        acc_ref[:] = acc_prev * alpha + pv
-        m_ref[:, :1] = m_new
-        l_ref[:, :1] = l_new
-
-    @pl.when(p == pages_per_seq - 1)
-    def _finalize():
-        l_final = jnp.maximum(l_ref[:, :1], 1e-30)
-        out = acc_ref[:] / l_final  # [TQ*n_q, hd] in kv-major head blocks
-        hd = out.shape[-1]
-        # Per-head static slices back to [TQ, group, hd] (no 4D transpose).
-        for h in range(n_kv):
-            blk = out[h * tq * group : (h + 1) * tq * group]
-            o_ref[0, :, h * group : (h + 1) * group, :] = (
-                blk.reshape(tq, group, hd).astype(o_ref.dtype))
+    out = _flash_output(l_ref, acc_ref)  # [tq * n_q, hd], kv-major
+    # Per-head static slices back to [tq, group, hd] (no 4D transpose).
+    for h in range(n_kv):
+        o_ref[0, :, h * group:(h + 1) * group, :] = (
+            out[h * rows:(h + 1) * rows].reshape(tq, group, hd)
+            .astype(o_ref.dtype))
 
 
 def chunk_q_block(t: int, n_q: int) -> int:
     """Query rows per grid step of :func:`paged_chunk_attention`: about
     1k accumulator rows (TQ * n_q) to bound VMEM scratch, and a multiple
-    of 8 — every per-kv-head row block is TQ * group high, and Mosaic
-    stacks those blocks (scores, masks, probabilities) along sublanes.
-    With a GQA group of 7 an unaligned block (1024 // 28 = 36 rows, 252
-    per head) fails to compile: the i1 mask concatenate dies in
-    ``tpu.bitcast_vreg`` with "Invalid vector register cast" (TPU v5
-    lite, jax 0.9.0). A short chunk pads up to one 8-row block."""
+    of 8 — every per-kv-head row block (queries, scores, m, l, acc) is TQ
+    * group high and lies at a multiple of that along sublanes. With a
+    GQA group of 7 an unaligned block (1024 // 28 = 36 rows, 252 per
+    head) was refused: stacking such blocks died in ``tpu.bitcast_vreg``
+    with "Invalid vector register cast" (TPU v5 lite, jax 0.9.0). A short
+    chunk pads up to one 8-row block."""
     cap = max(8, (1024 // n_q) // 8 * 8)
     return min(-(-t // 8) * 8, cap)
 
@@ -465,50 +480,42 @@ def paged_chunk_attention(
     """
     b, t, n_q, hd = q.shape
     n_kv = k_flat.shape[1]
-    group = n_q // n_kv
-    pages_per_seq = page_tables.shape[1]
-    k_pages = k_flat.reshape(-1, page_size, n_kv, hd)
-    v_pages = v_flat.reshape(-1, page_size, n_kv, hd)
-    q_start = q_positions[:, 0].astype(jnp.int32)
-
     tq = q_block if q_block is not None else chunk_q_block(t, n_q)
-    t_pad = ((t + tq - 1) // tq) * tq
-    n_qb = t_pad // tq
+    t_pad = -(-t // tq) * tq
     if t_pad != t:
         # Padded rows act like later queries (q0 + t): they attend at most the
         # whole context and their outputs are sliced off on return.
         q = jnp.pad(q, ((0, 0), (0, t_pad - t), (0, 0), (0, 0)))
+    q = _lane_pad(q)
+    hd_lanes = q.shape[-1]
+    pages = [_chunk_pages(a, page_size) for a in (k_flat, v_flat)]
+    g = decode_pages_per_step(page_size, n_kv, hd_lanes, k_flat.dtype,
+                              page_tables.shape[1])
 
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
-        grid=(b, n_qb, pages_per_seq),
-        in_specs=[
-            pl.BlockSpec((1, tq, n_q, hd),
-                         lambda b_, qb_, p_, pt, cl, qs: (b_, qb_, 0, 0)),
-            pl.BlockSpec((1, page_size, n_kv, hd),
-                         lambda b_, qb_, p_, pt, cl, qs: (pt[b_, p_], 0, 0, 0)),
-            pl.BlockSpec((1, page_size, n_kv, hd),
-                         lambda b_, qb_, p_, pt, cl, qs: (pt[b_, p_], 0, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, tq, n_q, hd),
-                               lambda b_, qb_, p_, pt, cl, qs: (b_, qb_, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((tq * n_q, 128), jnp.float32),  # m
-            pltpu.VMEM((tq * n_q, 128), jnp.float32),  # l
-            pltpu.VMEM((tq * n_q, hd), jnp.float32),  # acc
-        ],
-    )
-    kernel = functools.partial(
-        _chunk_kernel, page_size=page_size, n_kv=n_kv, group=group, tq=tq,
-        pages_per_seq=pages_per_seq,
-    )
+    q_blocks = pl.BlockSpec((1, tq, n_q, hd_lanes),
+                            lambda r, qb, *_: (r, qb, 0, 0))
     out = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, t_pad, n_q, hd), q.dtype),
+        functools.partial(
+            _chunk_walk_kernel, page_size=page_size, n_kv=n_kv,
+            group=n_q // n_kv, tq=tq, pages_per_step=g, sm_scale=hd ** -0.5),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(b, t_pad // tq),
+            in_specs=[q_blocks] + [pl.BlockSpec(memory_space=pl.ANY)] * 2,
+            out_specs=q_blocks,
+            scratch_shapes=[pltpu.VMEM((2, g * page_size, *pages[0].shape[2:]),
+                                       k_flat.dtype)] * 2 + [
+                pltpu.SemaphoreType.DMA((2, 2)),
+                pltpu.VMEM((tq * n_q, hd_lanes), jnp.float32),  # q, scaled
+                pltpu.VMEM((tq * n_q, 128), jnp.float32),  # m
+                pltpu.VMEM((tq * n_q, 128), jnp.float32),  # l
+                pltpu.VMEM((tq * n_q, hd_lanes), jnp.float32),  # acc
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         interpret=interpret,
-    )(page_tables, ctx_lens, q_start, q, k_pages, v_pages)
-    return out[:, :t]
+    )(page_tables, ctx_lens, q_positions[:, 0].astype(jnp.int32), q, *pages)
+    return out[:, :t, :, :hd]
 
 
 def paged_ragged_attention(
@@ -532,16 +539,15 @@ def paged_ragged_attention(
     row's token run is contiguous ascending and starts at a multiple of
     ``ragged_block``, so every ``ragged_block``-sized q block belongs to
     exactly one row — the flat batch maps onto the chunk kernel's
-    (sequence, q_block, page) grid with the q-block axis re-labelled by a
-    per-block row gather. Each grid step still scalar-prefetches the
-    owning row's page table and flash-accumulates in VMEM, and K/V pages
-    are fetched once per ``ragged_block`` queries rather than once per
-    token (the reason this beats running the decode kernel at B = N).
-    Per-row raggedness is carried by the per-block ``ctx_lens`` /
-    ``q_start`` scalars: a pad block (null row, ``ctx_len = 0``) skips
-    every accumulation and finalizes to zeros; pad tokens inside a real
-    row's last block act as later queries whose outputs the caller
-    discards (their K/V writes go to the null page via trash positions).
+    (rows, query blocks) grid as one block a row, each with its owning
+    row's page table and context gathered for it. A block walks the pages
+    it can see, and K/V pages are fetched once per ``ragged_block`` queries
+    rather than once per token (the reason this beats running the decode
+    kernel at B = N). Per-row raggedness is carried by the per-block
+    ``ctx_lens`` / ``q_start`` scalars: a pad block (null row, ``ctx_len =
+    0``) walks nothing and writes zeros; pad tokens inside a real row's
+    last block act as later queries whose outputs the caller discards
+    (their K/V writes go to the null page via trash positions).
 
     Returns [N, n_q, hd].
     """

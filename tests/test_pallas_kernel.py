@@ -78,6 +78,28 @@ def test_pallas_decode_null_pages_are_masked():
 WALK_PS = 8
 
 
+def _poison_pool(rng, live, width, n_kv, hd, num_pages):
+    """A float32 pool of ``num_pages`` in which every page but the ones
+    laid here is poison (NaN), and the rows' tables ``[rows, width]``:
+    row ``i`` owns ``live[i]`` pages, in its first columns, and every
+    other column points at a poison page."""
+    k = np.full((num_pages * WALK_PS, n_kv, hd), np.nan, np.float32)
+    v = np.full_like(k, np.nan)
+    order = rng.permutation(np.arange(1, num_pages))  # page 0 is null
+    tables = np.full((len(live), width), order[-1], np.int32)  # poison
+    nxt = 0
+    for i, n in enumerate(live):
+        for col in range(n):
+            page = order[nxt]
+            nxt += 1
+            tables[i, col] = page
+            rows = slice(page * WALK_PS, (page + 1) * WALK_PS)
+            k[rows] = rng.normal(size=(WALK_PS, n_kv, hd))
+            v[rows] = rng.normal(size=(WALK_PS, n_kv, hd))
+    assert nxt < num_pages - 1  # the poison page stays poison
+    return jnp.asarray(k), jnp.asarray(v), jnp.asarray(tables)
+
+
 def _walk_case(ctx_lens, n_kv, group, seed=0, hd=128):
     """Rows of ``ctx_lens`` tokens over a pool in which every page a row
     does NOT own is poison (NaN; an int8 pool's scales), and every table
@@ -90,24 +112,10 @@ def _walk_case(ctx_lens, n_kv, group, seed=0, hd=128):
     width = 2 * g + 1  # two steps and a page: no multiple of the step
     live = [-(-c // WALK_PS) for c in ctx_lens]
     num_pages = 2 * (1 + sum(live) // 2 + 1)  # even: two shards of pages
-    k = np.full((num_pages * WALK_PS, n_kv, hd), np.nan, np.float32)
-    v = np.full_like(k, np.nan)
-    order = rng.permutation(np.arange(1, num_pages))  # page 0 is null
-    tables = np.full((len(ctx_lens), width), order[-1], np.int32)  # poison
-    nxt = 0
-    for i, ctx in enumerate(ctx_lens):
-        for col in range(live[i]):
-            page = order[nxt]
-            nxt += 1
-            tables[i, col] = page
-            rows = slice(page * WALK_PS, (page + 1) * WALK_PS)
-            k[rows] = rng.normal(size=(WALK_PS, n_kv, hd))
-            v[rows] = rng.normal(size=(WALK_PS, n_kv, hd))
-    assert nxt < num_pages - 1  # the poison page stays poison
+    k, v, tables = _poison_pool(rng, live, width, n_kv, hd, num_pages)
     q = jnp.asarray(rng.normal(size=(len(ctx_lens), n_kv * group, hd)),
                     jnp.float32)
-    return (q, jnp.asarray(k), jnp.asarray(v), jnp.asarray(tables),
-            jnp.asarray(ctx_lens, jnp.int32), g, width)
+    return q, k, v, tables, jnp.asarray(ctx_lens, jnp.int32), g, width
 
 
 def _walk(pool, q, k, v, tables, ctx, interpret=True):
@@ -222,6 +230,162 @@ def test_walk_pages_per_step_follows_the_shapes():
     assert decode_pages_per_step(16, 4, 128, jnp.int8, 513) == 32
     assert decode_pages_per_step(4, 2, 32, jnp.float32, 8) == 8
     assert decode_pages_per_step(16, 64, 256, jnp.float32, 513) == 1
+
+
+# ----------------------------------------------------------- the chunk walk
+#
+# The chunk kernel (T > 1) walks the pages a block of ``tq`` queries can
+# SEE: those of its row's context AND under its causal bound. The cases lay
+# one query block a row, as ``_mixed_step`` lays its buffer out (a row's
+# table and context gathered per block), so that a block's own table can
+# point every column it must not read at poison.
+
+
+def _chunk_case(blocks, n_kv, group, tq, t=None, seed=0, hd=128):
+    """``blocks``: a ``(q0, live, ctx)`` a row — the first query position,
+    how many of the row's ``t`` tokens are real (the rest carry the trash
+    position, as the engine's pads do) and the row's context; ``ctx == 0``
+    is a pad block on the null row. Every page a row must not read is
+    poison (NaN): those behind its dead table columns AND those inside its
+    context but past the causal bound of its last query block."""
+    from runbookai_tpu.ops.paged_attention_pallas import decode_pages_per_step
+
+    t = t or tq
+    rng = np.random.default_rng(seed)
+    g = decode_pages_per_step(WALK_PS, n_kv, hd, jnp.float32, 10**6)
+    seen = [-(-min(ctx, q0 + t) // WALK_PS) for q0, _, ctx in blocks]
+    width = max(2 * g + 1, max(seen))  # two steps and a page, at least
+    k, v, tables = _poison_pool(rng, seen, width, n_kv, hd, sum(seen) + 3)
+    positions = np.full((len(blocks), t), width * WALK_PS, np.int32)  # trash
+    for i, (q0, live, _) in enumerate(blocks):
+        positions[i, :live] = q0 + np.arange(live)
+    q = jnp.asarray(rng.normal(size=(len(blocks), t, n_kv * group, hd)),
+                    jnp.float32)
+    return (q, k, v, tables,
+            jnp.asarray([ctx for _, _, ctx in blocks], jnp.int32),
+            jnp.asarray(positions), g)
+
+
+def _assert_chunk_walk(blocks, n_kv, group, tq, t=None, interpret=True):
+    from runbookai_tpu.ops.paged_attention_pallas import paged_chunk_attention
+
+    q, k, v, tables, ctx, positions, _ = _chunk_case(
+        blocks, n_kv, group, tq, t)
+    got = paged_chunk_attention(q, k, v, tables, ctx, positions,
+                                page_size=WALK_PS, interpret=interpret,
+                                q_block=tq)
+    # The XLA gather reads dead columns (it masks them afterwards), so the
+    # reference reads a pool whose poison is zeros.
+    want = paged_attention(q, jnp.nan_to_num(k), jnp.nan_to_num(v), tables,
+                           ctx, positions, page_size=WALK_PS, block_pages=4)
+    got, want = np.asarray(got), np.asarray(want)
+    for i, (_, live, n_ctx) in enumerate(blocks):
+        if n_ctx == 0:
+            assert np.all(got[i] == 0.0)  # a pad block writes zeros
+        np.testing.assert_allclose(got[i, :live], want[i, :live],
+                                   rtol=2e-5, atol=2e-5)
+
+
+def _chunk_step(n_kv):
+    """Positions one step of the chunk walk takes, at the cases' shapes."""
+    from runbookai_tpu.ops.paged_attention_pallas import decode_pages_per_step
+
+    return WALK_PS * decode_pages_per_step(WALK_PS, n_kv, 128, jnp.float32,
+                                            10**6)
+
+
+@pytest.mark.parametrize("tq", [8, 32])
+def test_chunk_walk_skips_pad_blocks(tq):
+    """``ctx == 0`` in the first block, in the middle and in the last."""
+    pad = (0, 0, 0)
+    _assert_chunk_walk([pad, (0, tq, tq + 5), pad, pad,
+                        (40, tq, 40 + tq), pad], n_kv=2, group=2, tq=tq)
+
+
+@pytest.mark.parametrize("n_kv", [4, 1])
+def test_chunk_walk_mixed_layout_gqa_group_seven(n_kv):
+    """The buffer of a mixed step at Qwen2.5-7B's group of 7 (four KV
+    heads, and the one a tp 4 shard holds): decode-shaped blocks (one live
+    token, seven pads), an empty slot, then a 22-token chunk behind 128
+    cached tokens in three blocks, the last part-filled, and pad blocks.
+    The chunk's later tokens lie in the row's pages already: to the first
+    two blocks they are past the causal bound, and poison."""
+    _assert_chunk_walk(
+        [(149, 1, 150), (0, 0, 0), (8, 1, 9), (0, 1, 1),
+         (128, 8, 150), (136, 8, 150), (144, 6, 150), (0, 0, 0)],
+        n_kv=n_kv, group=7, tq=8)
+
+
+@pytest.mark.parametrize("tq", [8, 32])
+@pytest.mark.parametrize("ctx_of", [
+    "one", "page", "page+1", "step", "step+1", "table"])
+def test_chunk_walk_context_edges(ctx_of, tq):
+    """A context of 1, a page, a page + 1, exactly one step of the walk,
+    one step + 1 and the whole table: as a decode-shaped block (its one
+    token the context's last) and as the block of queries that ends the
+    context."""
+    step = _chunk_step(2)
+    ctx = {"one": 1, "page": WALK_PS, "page+1": WALK_PS + 1, "step": step,
+           "step+1": step + 1, "table": 2 * step + WALK_PS}[ctx_of]
+    live = min(ctx, tq)
+    _assert_chunk_walk([(ctx - 1, 1, ctx), (ctx - live, live, ctx)],
+                       n_kv=2, group=2, tq=tq)
+
+
+@pytest.mark.parametrize("tq", [8, 32])
+def test_chunk_walk_block_straddles_a_page_and_a_step(tq):
+    """A query block whose positions cross a page boundary that is also a
+    step boundary of the walk: its first queries see one step, its last
+    two; once with the context ending at the block, once with 20 more
+    tokens of the same chunk behind it (past the causal bound: poison)."""
+    q0 = _chunk_step(2) - 3
+    _assert_chunk_walk([(q0, tq, q0 + tq), (q0, tq, q0 + tq + 20)],
+                       n_kv=2, group=2, tq=tq)
+
+
+@pytest.mark.parametrize("tq", [8, 32])
+@pytest.mark.parametrize("start", [0, 2048])
+def test_chunk_walk_prompt_start_and_behind_a_prefix(start, tq):
+    """Rows of several query blocks (``paged_chunk_attention`` as
+    ``_prefill_step`` calls it): a chunk that starts the prompt — its
+    first block walks one step — and one behind a 2,048-token prefix;
+    the last block part-filled."""
+    t = 2 * tq
+    _assert_chunk_walk([(start, t - 3, start + t - 3)],
+                       n_kv=2, group=2, tq=tq, t=t)
+
+
+def test_chunk_walk_at_serving_block_shapes():
+    """TQ 32 at the group of 7: 224 rows a kv head, as ``_prefill_step``
+    runs Qwen2.5-7B's chunk."""
+    _assert_chunk_walk([(0, 32, 32), (300, 32, 340)], n_kv=4, group=7, tq=32)
+
+
+def test_chunk_walk_never_fetches_past_its_bounds():
+    """The poison the cases above lay is live: a LIVE column pointed at
+    the poison page does turn the block into NaN, so a kernel that fetched
+    a dead column, or one past the causal bound, would have failed them."""
+    from runbookai_tpu.ops.paged_attention_pallas import paged_chunk_attention
+
+    q, k, v, tables, ctx, positions, _ = _chunk_case(
+        [(16, 8, 40), (0, 5, 5)], n_kv=2, group=2, tq=8)
+    poison = tables[0, -1]
+    got = paged_chunk_attention(q, k, v, tables.at[0, 1].set(poison), ctx,
+                                positions, page_size=WALK_PS,
+                                interpret=True, q_block=8)
+    assert np.isnan(np.asarray(got[0])).all()
+    assert not np.isnan(np.asarray(got[1, :5])).any()
+
+
+def test_chunk_walk_under_the_tpu_interpreter():
+    """The same walk where never-written VMEM reads as NaN and the copies
+    and their semaphores are simulated: a group's unfetched tail, and the
+    buffers of a block that fetched nothing, must not reach the output."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    _assert_chunk_walk([(0, 0, 0), (3, 8, 11), (140, 8, 150), (149, 1, 150)],
+                       n_kv=2, group=2, tq=8,
+                       interpret=pltpu.InterpretParams())
 
 
 def _build_pool(rng, ctx_lens_list, n_kv, hd, ps, pages, max_pages):

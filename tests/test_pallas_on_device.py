@@ -563,12 +563,14 @@ def test_decode_kernel_at_serving_shapes(n_q, n_kv, hd):
 CELL_CTX = [430, 3, 0, 612, 5, 250, 0, 1, 8192, 7, 0, 520, 2, 0, 260, 4]
 
 
-def _cell_case(rng, n_q=28, n_kv=4, hd=128, num_pages=1024):
-    live = [-(-c // PS) for c in CELL_CTX]
+def _poison_pool(rng, rows_ctx, n_kv=4, hd=128, num_pages=1024):
+    """A bf16 pool and the rows' tables in which every page no row owns,
+    and every table column past a row's live pages, is poison (NaN)."""
+    live = [-(-c // PS) for c in rows_ctx]
     k = np.full((num_pages * PS, n_kv, hd), np.nan, np.float32)
     v = np.full_like(k, np.nan)
     order = rng.permutation(np.arange(1, num_pages))
-    tables = np.full((len(CELL_CTX), SERVE_MAX_PAGES), order[-1], np.int32)
+    tables = np.full((len(rows_ctx), SERVE_MAX_PAGES), order[-1], np.int32)
     nxt = 0
     for i, n in enumerate(live):
         for col in range(n):
@@ -579,9 +581,14 @@ def _cell_case(rng, n_q=28, n_kv=4, hd=128, num_pages=1024):
             k[rows] = rng.normal(size=(PS, n_kv, hd))
             v[rows] = rng.normal(size=(PS, n_kv, hd))
     assert nxt < num_pages - 1
+    return (jnp.asarray(k, jnp.bfloat16), jnp.asarray(v, jnp.bfloat16),
+            jnp.asarray(tables))
+
+
+def _cell_case(rng, n_q=28, n_kv=4, hd=128):
+    k, v, tables = _poison_pool(rng, CELL_CTX, n_kv, hd)
     q = jnp.asarray(rng.normal(size=(len(CELL_CTX), n_q, hd)), jnp.bfloat16)
-    return (q, jnp.asarray(k, jnp.bfloat16), jnp.asarray(v, jnp.bfloat16),
-            jnp.asarray(tables), jnp.asarray(CELL_CTX, jnp.int32))
+    return q, k, v, tables, jnp.asarray(CELL_CTX, jnp.int32)
 
 
 @pytest.mark.usefixtures("serving_precision")
@@ -646,6 +653,35 @@ def test_chunk_kernel_at_serving_shapes(n_q, n_kv, hd):
                                np.asarray(want, np.float32), **ATTN_TOL)
 
 
+def _mixed_layout(dec_ctx, pf, rq, pf_tokens=512):
+    """The mixed dispatch's flat buffer as ``EngineCore._run_mixed`` lays
+    it out: a ``rq``-wide block a decode slot (one live token, an empty
+    slot none), then ``pf_tokens`` prefill tokens holding the chunks ``pf``
+    (``(first position, length)`` a row, each run padded to whole blocks),
+    the rest pad blocks on a null row; pads carry the trash position.
+    Returns the rows' contexts, every token's position and row, the real
+    tokens' indices and where the pad blocks start."""
+    slots = len(dec_ctx)
+    n = slots * rq + pf_tokens
+    rows_ctx = list(dec_ctx) + [start + ln for start, ln in pf] + [0]
+    positions = np.full((n,), (SERVE_MAX_PAGES - 1) * PS, np.int32)  # trash
+    row_ids = np.full((n,), len(rows_ctx) - 1, np.int32)  # the null row
+    real = []
+    for s, c in enumerate(dec_ctx):
+        row_ids[s * rq:(s + 1) * rq] = s
+        if c:
+            positions[s * rq] = c - 1
+            real.append(s * rq)
+    off = slots * rq
+    for j, (start, ln) in enumerate(pf):
+        padded = -(-ln // rq) * rq
+        positions[off:off + ln] = np.arange(start, start + ln)
+        row_ids[off:off + padded] = slots + j
+        real.extend(range(off, off + ln))
+        off += padded
+    return rows_ctx, positions, row_ids, real, off
+
+
 @pytest.mark.usefixtures("serving_precision")
 @pytest.mark.parametrize("n_q,n_kv,hd", SERVING_HEADS)
 def test_ragged_kernel_at_serving_shapes(n_q, n_kv, hd):
@@ -660,30 +696,12 @@ def test_ragged_kernel_at_serving_shapes(n_q, n_kv, hd):
     )
 
     rng = np.random.default_rng(12)
-    slots, pf_tokens = 8, 512
-    n = slots * RQ + pf_tokens
-    trash = (SERVE_MAX_PAGES - 1) * PS
     dec_ctx = [1, 40, 0, 513, 900, 0, 17, 1564]  # 0 = empty slot
-    pf = [(0, 300), (512, 100)]  # (first position, chunk length)
-    rows_ctx = dec_ctx + [start + ln for start, ln in pf] + [0]
-    pad_row = len(rows_ctx) - 1
+    rows_ctx, positions, row_ids, real, _ = _mixed_layout(
+        dec_ctx, [(0, 300), (512, 100)], RQ)
+    n = len(positions)
     k_flat, v_flat = _pool(rng, num_pages=320, n_kv=n_kv, hd=hd)
     tables = _tables(rows_ctx, max_pages=SERVE_MAX_PAGES)
-    positions = np.full((n,), trash, np.int32)
-    row_ids = np.full((n,), pad_row, np.int32)
-    real = []
-    for s, c in enumerate(dec_ctx):
-        row_ids[s * RQ:(s + 1) * RQ] = s
-        if c:
-            positions[s * RQ] = c - 1
-            real.append(s * RQ)
-    off = slots * RQ
-    for j, (start, ln) in enumerate(pf):
-        padded = -(-ln // RQ) * RQ
-        positions[off:off + ln] = np.arange(start, start + ln)
-        row_ids[off:off + padded] = slots + j
-        real.extend(range(off, off + ln))
-        off += padded
     q = jnp.asarray(rng.normal(size=(n, n_q, hd)), jnp.bfloat16)
     args = (q, k_flat, v_flat, tables, jnp.asarray(rows_ctx, jnp.int32),
             jnp.asarray(positions), jnp.asarray(row_ids))
@@ -694,6 +712,43 @@ def test_ragged_kernel_at_serving_shapes(n_q, n_kv, hd):
     np.testing.assert_allclose(np.asarray(got, np.float32)[real],
                                np.asarray(want, np.float32)[real],
                                **ATTN_TOL)
+
+
+@pytest.mark.usefixtures("serving_precision")
+def test_ragged_walk_at_the_cells_shape():
+    """The benchmark cell's mixed dispatch: 80 eight-token blocks — the 16
+    decode slots of ``CELL_CTX`` (one row at the full 8192 tokens, five
+    empty), then 512 prefill tokens of two rows (a chunk behind 1,024
+    cached tokens, the start of a prompt) and pad blocks on the null row —
+    over a table 513 columns wide at Qwen2.5-7B's 28 / 4 heads. Pages no
+    row owns are poison, and so is every table column past a row's live
+    pages: the chunk walk may not fetch them."""
+    from runbookai_tpu.engine.engine import _RAGGED_BLOCK as RQ
+    from runbookai_tpu.ops.attention import ragged_paged_attention
+    from runbookai_tpu.ops.paged_attention_pallas import (
+        paged_ragged_attention,
+    )
+
+    rng = np.random.default_rng(14)
+    rows_ctx, positions, row_ids, real, pads = _mixed_layout(
+        CELL_CTX, [(1024, 300), (0, 200)], RQ)
+    assert len(positions) // RQ == 80
+    k, v, tables = _poison_pool(rng, rows_ctx)
+    q = jnp.asarray(rng.normal(size=(len(positions), 28, 128)), jnp.bfloat16)
+    rest = (tables, jnp.asarray(rows_ctx, jnp.int32),
+            jnp.asarray(positions), jnp.asarray(row_ids))
+
+    got = np.asarray(paged_ragged_attention(
+        q, k, v, *rest, page_size=PS, ragged_block=RQ, interpret=False),
+        np.float32)
+    # XLA's gather reads dead columns (and masks them afterwards).
+    want = ragged_paged_attention(q, jnp.nan_to_num(k), jnp.nan_to_num(v),
+                                  *rest, page_size=PS, ragged_block=RQ)
+    np.testing.assert_allclose(got[real], np.asarray(want, np.float32)[real],
+                               **ATTN_TOL)
+    assert np.all(got[pads:] == 0.0)  # a pad block writes zeros
+    empty = [s * RQ for s, c in enumerate(CELL_CTX) if not c]
+    assert np.all(got[empty] == 0.0)  # and so does an empty slot
 
 
 @pytest.mark.usefixtures("serving_precision")
