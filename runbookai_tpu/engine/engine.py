@@ -980,6 +980,13 @@ def _token_logprobs(logits, toks):
     return chosen, top_ids, top_lp
 
 
+def _dispatch_stat(entry: Optional[dict]) -> dict[str, int]:
+    """A dispatch annotation's stats: its number in the ledger, by which
+    the profiler's clock joins the records'; nothing with the recorder
+    off."""
+    return {} if entry is None else {"dispatch": entry["n"]}
+
+
 @dataclass
 class _PendingDecode:
     """One in-flight decode window awaiting host consumption.
@@ -999,6 +1006,9 @@ class _PendingDecode:
     # with the tokens, and what the step record attributes them to.
     experts_dev: Optional[jax.Array] = None
     program: str = ""
+    # The dispatch's ledger entry (its number and ``t_issued``; None with
+    # the recorder off): the drain stamps it ready and counts its tokens.
+    dispatch: Optional[dict] = None
 
 
 @dataclass
@@ -1353,6 +1363,8 @@ class EngineCore:
         self._open: Optional[OpenStep] = None
         self._admitted_log: list[list] = []
         self._finished_log: list[dict] = []
+        # The dispatch whose tokens are being emitted (its ledger entry).
+        self._emitting: Optional[dict] = None
         self._install_metrics()
 
     def _install_metrics(self) -> None:
@@ -1510,11 +1522,12 @@ class EngineCore:
             self.params["lora"] = self.lora.stacked()
 
     @contextlib.contextmanager
-    def _span(self, phase: str):
+    def _span(self, phase: str, **meta):
         """One phase of a step on both clocks: its seconds in the open
         step's ``phases`` (time.monotonic()), and the same two boundaries
-        as a span on the profiler's (``PHASE_SPANS``; a no-op without a
-        profiler session). Outside a recorded step only the latter.
+        as a span on the profiler's (``PHASE_SPANS``, ``meta`` its stats;
+        a no-op without a profiler session). Outside a recorded step only
+        the latter.
 
         The always-on counters keep their own perf_counter() stamps
         beside these (``t_build`` / ``t_issue`` / ``t_fetch`` below:
@@ -1527,7 +1540,7 @@ class EngineCore:
         of that an in-flight window hid. Neither set derives from the
         other."""
         name = PHASE_SPANS.get(phase)
-        with annotate(name) if name else contextlib.nullcontext():
+        with annotate(name, **meta) if name else contextlib.nullcontext():
             step = self._open
             if step is None:
                 yield
@@ -1537,6 +1550,78 @@ class EngineCore:
                 yield
             finally:
                 step.exit()
+
+    # The dispatch as a span (flight_recorder.DispatchLedger). Every helper
+    # takes the entry ``_dispatching`` returned, which is None with the
+    # recorder off, and then does nothing.
+
+    def _dispatching(self, program: str, k: int = 0, rows: int = 0,
+                     ctx_lens: Optional[np.ndarray] = None,
+                     prefill_tokens: int = 0) -> Optional[dict]:
+        """Before a step program is called in a recorded step: name it in
+        the open step, with the pages its decode rows hold, and open its
+        ledger entry."""
+        if self._open is None:
+            return None
+        pages = 0 if ctx_lens is None else self._pages_held(ctx_lens)
+        self._open.dispatched(program, k, rows, pages)
+        return self.flight.dispatches.open(program, k, rows, pages,
+                                           prefill_tokens)
+
+    def _issued(self, entry: Optional[dict], result: jax.Array,
+                emits: bool = True) -> None:
+        if entry is not None:
+            self.flight.dispatches.issued(entry, result, emits)
+
+    @contextlib.contextmanager
+    def _fetching(self, entry: Optional[dict]):
+        """The fetch phase around the ``device_get`` that consumes a
+        dispatch's result: stamps the dispatch ready as it returns, and
+        any dispatch issued before it and never fetched (a prefill chunk
+        short of its prompt's end, the window in flight under a mixed
+        step's first-token fetch) as its own result arrives, which the
+        fetch was about to wait for anyway."""
+        ledger = self.flight.dispatches
+        # (a dispatch issued before a ``flight.reset()`` is of no ledger now)
+        if entry is None or not ledger.mine(entry):
+            with self._span("fetch"):
+                yield
+            return
+        with self._span("fetch", dispatch=entry["n"]):
+            for earlier, result in ledger.before(entry):
+                # runbook: noqa[RBK002] — sanctioned sync: the fetch below
+                # waits for this result first in any case (one device,
+                # dispatches in order); waiting here gives it its own stamp.
+                jax.block_until_ready(result)
+                ledger.ready(earlier)
+            yield
+            ledger.ready(entry)
+
+    @contextlib.contextmanager
+    def _emitting_from(self, entry: Optional[dict], last: bool = True):
+        """The emit phase of one dispatch's tokens: ``_emit_token`` counts
+        them to it; after its ``last`` the entry goes to the next step
+        record."""
+        with self._span("emit"):
+            self._emitting = entry
+            try:
+                yield
+            finally:
+                self._emitting = None
+        if last and entry is not None:
+            self.flight.dispatches.emitted(entry)
+
+    def _first_token(self, req: EngineRequest, token: int) -> None:
+        """Emit the token a request's prefill sampled: its first, unless
+        it was preempted (the TTFT is the first admission's)."""
+        if req.first_token_time is None:
+            req.first_token_time = time.perf_counter()
+            self.hist_ttft.observe(req.first_token_time - req.arrival_time)
+            if self._open is not None:
+                ledger = self.flight.dispatches
+                req.rode_mark = (ledger, ledger.at(req.first_token_time))
+                req.rode_tokens = {}
+        self._emit_token(req, token)
 
     def submit(self, req: EngineRequest) -> None:
         req.t_enqueued = time.monotonic()
@@ -1594,8 +1679,11 @@ class EngineCore:
         """Crash recovery only: drop the in-flight window WITHOUT fetching
         (the device may be poisoned — a drain would raise again and wedge
         ``has_work`` forever). Callers must have failed/aborted the owning
-        requests first; the window's tokens are lost with it."""
+        requests first; the window's tokens are lost with it, and its
+        dispatch reaches no record."""
         self._pending = None
+        if self.flight.dispatches is not None:
+            self.flight.dispatches.in_flight.clear()
 
     # ------------------------------------------------- page import / export
 
@@ -1720,7 +1808,8 @@ class EngineCore:
         return si
 
     def _fetch_tokens(self, toks_dev: jax.Array, experts_dev=None,
-                      program: str = "", passes: int = 1) -> np.ndarray:
+                      program: str = "", passes: int = 1,
+                      dispatch: Optional[dict] = None) -> np.ndarray:
         """THE decode-loop token egress. Every decode path (lagged drain,
         forced-sync, guided k=1, speculative verify) consumes its sampled
         tokens through this single point; the host copy was started
@@ -1728,8 +1817,9 @@ class EngineCore:
         wait is bounded by whatever device time the host failed to hide.
         The dispatch's expert counts (``experts_dev``, five integers) come
         over in the same ``device_get``, with those of any earlier
-        dispatch that fetched no token (``_experts_parked``)."""
-        with self._span("fetch"):
+        dispatch that fetched no token (``_experts_parked``). ``dispatch``
+        is the ledger entry of the dispatch whose tokens these are."""
+        with self._fetching(dispatch):
             parked, self._experts_parked = self._experts_parked, []
             if experts_dev is not None:
                 parked.append((program, passes, experts_dev))
@@ -1767,11 +1857,12 @@ class EngineCore:
         executes on device — the time the pipeline hides."""
         t0 = time.perf_counter()
         toks_host = self._fetch_tokens(pending.toks_dev, pending.experts_dev,
-                                       pending.program, pending.k)
+                                       pending.program, pending.k,
+                                       pending.dispatch)
         pending.experts_dev = None  # booked once, whoever fetches again
         t_fetch = time.perf_counter()
         emitted = 0
-        with self._span("emit"):
+        with self._emitting_from(pending.dispatch):
             for step_idx in range(pending.k):
                 for req, slot in pending.reqs:
                     if req.state == RequestState.DECODE:
@@ -2089,6 +2180,11 @@ class EngineCore:
             "reason": req.finish_reason.value if req.finish_reason else None,
             "max_emit_gap_s": req.max_emit_gap_s,
         }
+        if self.flight.enabled:
+            ledger, mark = req.rode_mark or (None, None)
+            # (a ring reset since the first token took the mark's sums)
+            life["rode"] = (ledger.rode(mark, now, req.rode_tokens)
+                            if ledger is self.flight.dispatches else None)
         req.lifecycle = life
         # The handler thread may have flushed the first chunk between the
         # read above and the assignment (EngineRequest.mark_first_write).
@@ -2260,8 +2356,11 @@ class EngineCore:
             # Request attribution for `runbook timeline`: which sequences'
             # chunks rode this dispatch (built only when tracing is on).
             pf_meta["requests"] = [r.request_id for r, _, _ in rows]
+        dispatch = self._dispatching("_prefill_step",
+                                     prefill_tokens=pf_meta["tokens"])
         with self.tracer.span("engine.prefill", **pf_meta), \
-                annotate("prefill"), self._span("issue"):
+                annotate("prefill", **_dispatch_stat(dispatch)), \
+                self._span("issue"):
             results = self._keep_state(_prefill_step(
                 self.params, self.cfg, jnp.asarray(tokens), self._kv_k, self._kv_v,
                 jnp.asarray(positions), tables, ctx_dev, jnp.asarray(last_idx),
@@ -2274,10 +2373,15 @@ class EngineCore:
             ))
             # (a model that drafts for itself: each row's last hidden state)
             last_logits, self._kv_k, self._kv_v, experts, *last_hidden = results
+        if dispatch is not None:
+            # A chunk short of every prompt's end emits nothing and is
+            # waited on by a later fetch: the ledger holds one element of
+            # its result until then, not ``[rows, vocab]``.
+            emits = any(new_ctx >= len(req.prompt_ids) for req, _, new_ctx in rows)
+            self._issued(dispatch, last_logits if emits else last_logits[:1, :1],
+                         emits)
         for req, _, new_ctx in rows:
             self._snapshot_state(req, new_ctx)
-        if self._open is not None:
-            self._open.dispatched("_prefill_step")
         if experts is not None:
             self._experts_parked.append(("_prefill_step", 1, experts))
 
@@ -2396,7 +2500,7 @@ class EngineCore:
                     self._draft_toks = self._draft_toks.at[
                         jnp.asarray(feed_idx)].set(drafts, mode="drop")
                     self._experts_parked.append(("_module_step", 1, module_experts))
-            with self._span("fetch"):
+            with self._fetching(dispatch):
                 # runbook: noqa[RBK002] — sanctioned sync: the one batched
                 # first-token fetch per prefill dispatch (TTFT emission point).
                 toks_host = np.asarray(jax.device_get(toks))
@@ -2417,13 +2521,9 @@ class EngineCore:
                     self._tok_counts, jnp.asarray(slot_map),
                     jnp.asarray(toks_host.astype(np.int32)),
                     jnp.asarray(live))
-            with self._span("emit"):
+            with self._emitting_from(dispatch):
                 for i, req in done_rows:
-                    if req.first_token_time is None:  # true TTFT across preemption
-                        req.first_token_time = time.perf_counter()
-                        self.hist_ttft.observe(req.first_token_time
-                                               - req.arrival_time)
-                    self._emit_token(req, int(toks_host[i]))
+                    self._first_token(req, int(toks_host[i]))
         self.metrics["prefill_time_s"] += time.perf_counter() - t0
 
     def _seed_counts_for(self, req: EngineRequest,
@@ -2484,6 +2584,13 @@ class EngineCore:
                     and now - req.last_emit_time > req.max_emit_gap_s):
                 req.max_emit_gap_s = now - req.last_emit_time
             req.last_emit_time = now
+        dispatch = self._emitting
+        if dispatch is not None:
+            dispatch["tokens"] += 1
+            # (the token AT t_first_token opens the interval ``rode`` is of)
+            if req.rode_tokens is not None and req.num_generated:
+                program = dispatch["program"]
+                req.rode_tokens[program] = req.rode_tokens.get(program, 0) + 1
         req.out_ids.append(token)
         if req.on_token is not None:
             req.on_token(token)
@@ -2602,11 +2709,11 @@ class EngineCore:
         spec_meta: dict[str, Any] = {"k": k, "batch": len(self.decoding)}
         if self.tracer.enabled:
             spec_meta["requests"] = [r.request_id for r in self.decoding]
-        if self._open is not None:
-            self._open.dispatched("_decode_spec", k, len(self.decoding),
-                                  self._pages_held(ctx_lens))
+        dispatch = self._dispatching("_decode_spec", k, len(self.decoding),
+                                     ctx_lens)
         with self.tracer.span("engine.decode_spec", **spec_meta), \
-                annotate("decode_spec"), self._span("issue"):
+                annotate("decode_spec", **_dispatch_stat(dispatch)), \
+                self._span("issue"):
             t_issue = time.perf_counter()
             toks, self._kv_k, self._kv_v, experts = _decode_spec(
                 self.params, self.cfg, jnp.asarray(tokens), jnp.asarray(positions),
@@ -2616,11 +2723,13 @@ class EngineCore:
                 attn_impl=self.ecfg.attn_impl, mesh=self.mesh,
                 qmm_impl=self.ecfg.qmm_impl,
             )
-            toks_host = self._fetch_tokens(toks, experts, "_decode_spec")  # [B, k]
+            self._issued(dispatch, toks)
+            toks_host = self._fetch_tokens(toks, experts, "_decode_spec",
+                                           dispatch=dispatch)  # [B, k]
             t_fetch = time.perf_counter()
 
         emitted = 0
-        with self._span("emit"):
+        with self._emitting_from(dispatch):
             for req in list(self.decoding):
                 i = req.slot
                 feed = feeds[req.request_id]
@@ -2677,11 +2786,11 @@ class EngineCore:
         spec_meta: dict[str, Any] = {"k": rounds, "batch": len(rows)}
         if self.tracer.enabled:
             spec_meta["requests"] = [r.request_id for r in rows]
-        if self._open is not None:
-            self._open.dispatched("_decode_spec", rounds, len(rows),
-                                  self._pages_held(ctx_lens))
+        dispatch = self._dispatching("_decode_spec", rounds, len(rows),
+                                     ctx_lens)
         with self.tracer.span("engine.decode_spec", **spec_meta), \
-                annotate("decode_spec"), self._span("issue"):
+                annotate("decode_spec", **_dispatch_stat(dispatch)), \
+                self._span("issue"):
             t_issue = time.perf_counter()
             (out, self._kv_k, self._kv_v, experts, self._feed_toks,
              self._draft_toks) = _decode_spec(
@@ -2693,12 +2802,13 @@ class EngineCore:
                 qmm_impl=self.ecfg.qmm_impl, drafts=self._draft_toks,
                 rounds=rounds,
             )
+            self._issued(dispatch, out)
             out_host = self._fetch_tokens(out, experts, "_decode_spec",
-                                          rounds)  # [B, rounds, 3]
+                                          rounds, dispatch)  # [B, rounds, 3]
             t_fetch = time.perf_counter()
 
         drafted = accepted = 0
-        with self._span("emit"):
+        with self._emitting_from(dispatch):
             for req in rows:
                 for a0, a1, took in out_host[req.slot].tolist():
                     if req.state != RequestState.DECODE:
@@ -2999,11 +3109,12 @@ class EngineCore:
             mix_meta["requests"] = (
                 [r.request_id for r in dec_snapshot]
                 + [r.request_id for r, _, _ in pf_rows])
-        if self._open is not None:
-            self._open.dispatched("_mixed_step", 1, len(dec_snapshot),
-                                  self._pages_held(ctx_lens))
+        dispatch = self._dispatching(
+            "_mixed_step", 1, len(dec_snapshot), ctx_lens,
+            prefill_tokens=real_tokens - len(dec_snapshot))
         with self.tracer.span("engine.mixed", **mix_meta), \
-                annotate("mixed"), self._span("issue"):
+                annotate("mixed", **_dispatch_stat(dispatch)), \
+                self._span("issue"):
             t_issue = time.perf_counter()
             (toks_win, pf_toks, feed_new, self._kv_k, self._kv_v,
              counts_out, experts) = self._keep_drafts(self._keep_state(_mixed_step(
@@ -3036,6 +3147,7 @@ class EngineCore:
                     self._mix_rows),
                 next_tokens=(jnp.asarray(next_tokens) if self._mtp else None),
             )))
+        self._issued(dispatch, toks_win)
         for req, _, new_ctx in pf_rows:
             self._snapshot_state(req, new_ctx)
         if counts_out is not None:
@@ -3047,6 +3159,7 @@ class EngineCore:
             reqs=[(r, r.slot) for r in dec_snapshot],
             req_ids=frozenset(r.request_id for r in dec_snapshot),
             k=1, experts_dev=experts, program="_mixed_step",
+            dispatch=dispatch,
         )
         if hasattr(toks_win, "copy_to_host_async"):
             toks_win.copy_to_host_async()
@@ -3065,18 +3178,14 @@ class EngineCore:
             self.decoding.append(req)
         if done:
             self._bump_epoch()  # slot→request mapping changed
-            with self._span("fetch"):
+            with self._fetching(dispatch):
                 # runbook: noqa[RBK002] — sanctioned sync: the one batched
                 # mixed-step first-token fetch (TTFT emission; decode rows
                 # stay device-resident in the overlap window).
                 pf_host = np.asarray(jax.device_get(pf_toks))
-            with self._span("emit"):
+            with self._emitting_from(dispatch, last=False):
                 for j, req, slot in done:
-                    if req.first_token_time is None:
-                        req.first_token_time = time.perf_counter()
-                        self.hist_ttft.observe(req.first_token_time
-                                               - req.arrival_time)
-                    self._emit_token(req, int(pf_host[j]))
+                    self._first_token(req, int(pf_host[j]))
 
         # Decode rows ride the overlap pipeline exactly like _run_decode.
         if self.ecfg.overlap_decode:
@@ -3247,12 +3356,11 @@ class EngineCore:
         dec_meta: dict[str, Any] = {"k": k, "batch": len(self.decoding)}
         if self.tracer.enabled:
             dec_meta["requests"] = [r.request_id for r in self.decoding]
-        if self._open is not None:
-            self._open.dispatched("_decode_step" if k == 1 else "_decode_multi",
-                                  k, len(self.decoding),
-                                  self._pages_held(ctx_lens))
+        program = "_decode_step" if k == 1 else "_decode_multi"
+        dispatch = self._dispatching(program, k, len(self.decoding), ctx_lens)
         with self.tracer.span("engine.decode", **dec_meta), \
-                annotate("decode"), self._span("issue"):
+                annotate("decode", **_dispatch_stat(dispatch)), \
+                self._span("issue"):
             t_issue = time.perf_counter()
             last_logits = None
             if k == 1:
@@ -3286,13 +3394,13 @@ class EngineCore:
             if counts_out is not None:
                 self._tok_counts = counts_out
             t_done = time.perf_counter()
+        self._issued(dispatch, toks_win)
 
         pending = _PendingDecode(
             toks_dev=toks_win,
             reqs=[(r, r.slot) for r in self.decoding],
             req_ids=frozenset(r.request_id for r in self.decoding),
-            k=k, experts_dev=experts,
-            program="_decode_step" if k == 1 else "_decode_multi",
+            k=k, experts_dev=experts, program=program, dispatch=dispatch,
         )
         # Start the token egress behind the (async) dispatch: by the time
         # the window is drained, the DMA has had a full device step to land.
@@ -3313,7 +3421,8 @@ class EngineCore:
             # consumers, and their tail flush must never observe the final
             # token's entry still missing.
             if k == 1 and any(r.sampling.logprobs for r, _ in pending.reqs):
-                toks_host = self._fetch_tokens(pending.toks_dev)
+                toks_host = self._fetch_tokens(pending.toks_dev,
+                                               dispatch=dispatch)
                 self._score_logprobs(last_logits, toks_win[:, 0],
                                      toks_host[:, 0], pending.reqs)
             self._drain(pending, overlapped=False)
@@ -3445,6 +3554,7 @@ class EngineCore:
             "compile_s": round(compile_s, 6),
             "admitted": self._admitted_log,
             "finished": self._finished_log,
+            "dispatches": self.flight.dispatches.take_log(),
         }
         if step.experts is not None:
             rec["experts"] = step.experts

@@ -15,7 +15,11 @@ split, preemptions, and the replica index when fleeted. The record is
 also the program's span of the step: its two ends on ``time.monotonic()``,
 the seconds of each phase (:class:`OpenStep`), the step program(s) it
 dispatched, seconds the process spent compiling in it, and the requests
-first admitted and retired in it (``docs/observability.md``).
+first admitted and retired in it (``docs/observability.md``). A DISPATCH
+is a span of its own (:class:`DispatchLedger`): under the overlapped
+pipeline a step issues one dispatch and fetches another, so a record
+lists the dispatches that came back in it, and a retired request's
+lifecycle record says which it rode.
 
 Design constraints (pinned by ``tests/test_observability.py``):
 
@@ -28,8 +32,7 @@ Design constraints (pinned by ``tests/test_observability.py``):
 - **Bounded**: ``capacity`` records, oldest overwritten. A 1800s soak at
   ~50 steps/s stays a few MB regardless of run length.
 - **Dumpable**: :meth:`snapshot` (newest-last dicts) for ``/debug/steps``
-  and the AsyncFleet aggregation, :meth:`dump_jsonl` for offline diffing,
-  :meth:`summary` for a window's step-level roll-up.
+  and the AsyncFleet aggregation, :meth:`dump_jsonl` for offline diffing.
 """
 
 from __future__ import annotations
@@ -38,8 +41,6 @@ import json
 import time
 from pathlib import Path
 from typing import Any, Optional
-
-from runbookai_tpu.utils.trace import _percentile
 
 # The per-step record keys, in emission order (documentation + the
 # /debug/steps shape test import this so the wire contract is pinned).
@@ -53,6 +54,11 @@ STEP_RECORD_FIELDS = (
     # the requests that entered and left the engine in it.
     "t_start", "t_end", "phases", "program", "k", "rows", "kv_pages_live",
     "prefill_tokens", "decode_tokens", "compile_s", "admitted", "finished",
+    # The dispatches whose last tokens reached the host since the record
+    # before (``DISPATCH_FIELDS``, ``DispatchLedger``): under the
+    # overlapped pipeline NOT the ones ``program`` names, which this step
+    # issued and a later one fetches.
+    "dispatches",
 )
 # Keys a record has only in some steps. ``experts``: where the model has an
 # expert share (models/longcat.py), the expert counts this step FETCHED (a
@@ -83,6 +89,16 @@ PHASE_SPANS = {"admit": "engine.admit", "build": "engine.build",
                "fetch": "engine.fetch_tokens", "emit": "engine.emit",
                "draft": "engine.draft"}
 
+# One entry of ``dispatches``: a dispatch of a step program as a span of
+# its own (``DispatchLedger``). ``n`` numbers the engine's dispatches
+# since the ledger began; ``program``, ``k``, ``rows`` and
+# ``kv_pages_live`` are what ``OpenStep.dispatched`` got, ``prefill_tokens``
+# the prompt tokens it computed; ``t_issued`` and ``t_ready`` its two
+# stamps on time.monotonic(); ``tokens`` what it gave its rows, after
+# stops (rounds: ``drafted + accepted``).
+DISPATCH_FIELDS = ("n", "program", "k", "rows", "kv_pages_live",
+                   "prefill_tokens", "t_issued", "t_ready", "tokens")
+
 # One entry of ``finished``: a request's lifecycle, built once, in
 # EngineCore._retire. Its times are on one clock, CLOCK_MONOTONIC:
 # ``t_received``, ``t_enqueued`` and ``t_first_write`` read
@@ -93,6 +109,9 @@ LIFECYCLE_FIELDS = (
     "t_first_token", "t_first_write", "t_finished", "prompt_tokens",
     "cached_tokens", "generated", "preemptions", "reason",
     "max_emit_gap_s",
+    # What the request waited behind over (t_first_token, t_finished]
+    # (``DispatchLedger.rode``); None for one that had no first token.
+    "rode",
 )
 
 
@@ -154,15 +173,170 @@ class OpenStep:
             e["programs"].append(program)
 
 
+class DispatchLedger:
+    """The engine's dispatches as spans, and the one definition of a
+    dispatch's device-side interval on the host's clock.
+
+    A dispatch has two stamps: ``t_issued``, read when its jitted call
+    returned, and ``t_ready``, read when the first ``device_get`` that
+    consumed its result returned. The device runs one engine's dispatches
+    in the order they were issued, so the time from the ready stamp before
+    this one's (``n - 1``'s) to ``t_ready`` falls in two parts::
+
+        (t_ready(n - 1), max(t_issued(n), t_ready(n - 1))]   "between"
+        (max(t_issued(n), t_ready(n - 1)), t_ready(n)]       the dispatch's
+
+    Queued behind ``n - 1`` the device goes from one to the next and the
+    whole of it is ``n``'s; where the host issued late, the part before
+    ``t_issued`` is time the device had nothing of this engine's. The
+    intervals tile the clock from the ledger's start to the newest ready
+    stamp. The ledger keeps their sums (dispatches and seconds by
+    program, and ``between``), so that what a request waited behind is two
+    reads of them (:meth:`at`) and a subtraction (:meth:`rode`), nothing a
+    row a step.
+
+    A stamp is the HOST's, so the interval is what the host saw of the
+    dispatch, an UPPER bound of the device's time in it: ``t_ready`` is
+    late by whatever kept the step thread from its fetch, and that time
+    goes to the dispatch, not to the one after it. Under the overlapped
+    pipeline a step issues ``n + 1`` and only then fetches ``n``, so
+    ``t_ready(n) > t_issued(n + 1)`` always and ``between`` is 0 by
+    construction: where the host issued ``n + 1`` after the device had
+    finished ``n``, the device's idle time is inside ``n``'s interval.
+    ``between`` sees a DRAINED pipeline only (an empty engine, a
+    synchronous dispatch, a first-token fetch). The device's own idle
+    share is the profiler's to give, not this ledger's.
+
+    Written by the step thread only; it lives and dies with the
+    recorder's ring (:meth:`FlightRecorder.reset`). A dispatch in flight
+    across a reset is of the ledger that went: this one stamps, books and
+    logs nothing of it (:meth:`mine`)."""
+
+    __slots__ = ("n", "t0", "t_ready", "in_flight", "count", "seconds",
+                 "between", "log")
+
+    def __init__(self):
+        self.n = 0  # the next dispatch's number
+        # The ledger's start, and the newest ready stamp (until there is
+        # one, the start).
+        self.t0 = self.t_ready = time.monotonic()
+        # Issued and not ready, by number: (entry, an array of its result
+        # to wait on, whether tokens will be emitted from it).
+        self.in_flight: list[tuple[dict[str, Any], Any, bool]] = []
+        self.count: dict[str, int] = {}
+        self.seconds: dict[str, float] = {}
+        self.between = 0.0
+        # Entries the next step record takes: ready, their tokens emitted.
+        self.log: list[dict[str, Any]] = []
+
+    def open(self, program: str, k: int, rows: int, kv_pages_live: int,
+             prefill_tokens: int) -> dict[str, Any]:
+        """The entry of the dispatch about to be issued (``n`` is also the
+        ``dispatch`` stat of its annotation on the profiler's clock)."""
+        entry = {"n": self.n, "program": program, "k": k, "rows": rows,
+                 "kv_pages_live": kv_pages_live,
+                 "prefill_tokens": prefill_tokens, "t_issued": None,
+                 "t_ready": None, "tokens": 0}
+        self.n += 1
+        return entry
+
+    def issued(self, entry: dict[str, Any], result: Any, emits: bool) -> None:
+        """The jitted call returned. ``emits``: the engine will call
+        :meth:`emitted` once the dispatch's last token is out; a dispatch
+        that gives no token (a prefill chunk short of its prompt's end) is
+        logged as soon as it is ready."""
+        entry["t_issued"] = time.monotonic()
+        self.in_flight.append((entry, result, emits))
+
+    def before(self, entry: dict[str, Any]) -> list[tuple[dict[str, Any], Any]]:
+        """Dispatches issued before ``entry`` and not ready: the engine
+        waits on each, in order, before it fetches ``entry``'s result, so
+        that every dispatch has a ready stamp of its own."""
+        return [(e, result) for e, result, _ in self.in_flight
+                if e["n"] < entry["n"]]
+
+    def mine(self, entry: dict[str, Any]) -> bool:
+        """Whether this ledger issued the dispatch (one issued before the
+        ledger began is of the ledger a reset replaced)."""
+        return entry["t_issued"] is not None and entry["t_issued"] >= self.t0
+
+    def ready(self, entry: dict[str, Any]) -> None:
+        """The first fetch of the dispatch's result returned: stamp it and
+        book its interval (a second fetch of it changes nothing)."""
+        if entry["t_ready"] is not None or not self.mine(entry):
+            return
+        now = entry["t_ready"] = time.monotonic()
+        start = max(entry["t_issued"], self.t_ready)
+        self.between += start - self.t_ready
+        program = entry["program"]
+        self.count[program] = self.count.get(program, 0) + 1
+        self.seconds[program] = self.seconds.get(program, 0.0) + now - start
+        self.t_ready = now
+        for i, (e, _, emits) in enumerate(self.in_flight):
+            if e is entry:
+                del self.in_flight[i]
+                if not emits:
+                    self.log.append(entry)
+                break
+
+    def emitted(self, entry: dict[str, Any]) -> None:
+        if self.mine(entry):
+            self.log.append(entry)
+
+    def take_log(self) -> list[dict[str, Any]]:
+        """The entries for the step record being made, by number (a
+        prefill's first token is fetched and emitted ahead of the window
+        that was in flight when it was issued)."""
+        log, self.log = self.log, []
+        log.sort(key=lambda entry: entry["n"])
+        return log
+
+    def at(self, t: float) -> tuple[dict[str, int], dict[str, float], float]:
+        """(dispatches, seconds by program, ``between``) as they stand at
+        ``t``, a time not before the newest ready stamp: the sums, and the
+        open interval up to ``t`` by the same rule (the dispatch the
+        device is in, where one is in flight)."""
+        seconds, between = dict(self.seconds), self.between
+        if self.in_flight:
+            head = self.in_flight[0][0]
+            start = min(max(head["t_issued"], self.t_ready), t)
+            program = head["program"]
+            seconds[program] = seconds.get(program, 0.0) + max(0.0, t - start)
+        else:
+            start = t
+        between += max(0.0, start - self.t_ready)
+        return dict(self.count), seconds, between
+
+    def rode(self, mark: tuple, t: float,
+             tokens: dict[str, int]) -> dict[str, Any]:
+        """A lifecycle record's ``rode``: ``{program: [dispatches made
+        ready, tokens they gave this request, seconds], ..., "between":
+        seconds}`` from ``mark`` (:meth:`at` the request's first token) to
+        ``t``. The seconds add up to ``t`` less the mark's time."""
+        count0, seconds0, between0 = mark
+        count, seconds, between = self.at(t)
+        out: dict[str, Any] = {}
+        for program in sorted(set(seconds) | set(tokens)):
+            row = [count.get(program, 0) - count0.get(program, 0),
+                   tokens.get(program, 0),
+                   seconds.get(program, 0.0) - seconds0.get(program, 0.0)]
+            if any(row):
+                out[program] = row
+        out["between"] = between - between0
+        return out
+
+
 class FlightRecorder:
     """Preallocated ring of the last ``capacity`` step records."""
 
-    __slots__ = ("capacity", "_buf", "_next")
+    __slots__ = ("capacity", "_buf", "_next", "dispatches")
 
     def __init__(self, capacity: int):
         self.capacity = max(0, int(capacity))
         self._buf: list[Optional[dict[str, Any]]] = [None] * self.capacity
         self._next = 0  # monotonically increasing step cursor
+        # None with the recorder off: no dispatch is numbered or stamped.
+        self.dispatches = DispatchLedger() if self.capacity else None
 
     @property
     def enabled(self) -> bool:
@@ -191,6 +365,8 @@ class FlightRecorder:
         exclude the warm-up's)."""
         self._buf = [None] * self.capacity
         self._next = 0
+        if self.dispatches is not None:
+            self.dispatches = DispatchLedger()
 
     def snapshot(self, last_n: Optional[int] = None) -> list[dict[str, Any]]:
         """Oldest→newest copies of the retained records (at most
@@ -213,81 +389,3 @@ class FlightRecorder:
             for rec in records:
                 fh.write(json.dumps(rec) + "\n")
         return len(records)
-
-    @staticmethod
-    def merge_summaries(summaries: list[dict[str, Any]]) -> dict[str, Any]:
-        """Fleet-wide roll-up of per-replica :meth:`summary` blocks:
-        dispatch kinds and tokens sum, pressure peaks take the max, and
-        occupancy percentiles report the worst replica (the one whose
-        batch ran fullest — the capacity-planning signal)."""
-        kinds: dict[str, int] = {}
-        classes: dict[str, int] = {}
-        merged: dict[str, Any] = {
-            "steps_recorded": 0, "steps_total": 0, "capacity": 0,
-            "tokens": 0, "prefill_tokens": 0, "decode_tokens": 0,
-            "decode_row_steps": 0, "occupancy_p50": 0.0, "occupancy_p95": 0.0,
-            "kv_utilization_peak": 0.0, "queue_depth_peak": 0,
-        }
-        for s in summaries:
-            for kind, count in s.get("dispatch_kinds", {}).items():
-                kinds[kind] = kinds.get(kind, 0) + count
-            for cls, count in s.get("class_slot_steps", {}).items():
-                classes[cls] = classes.get(cls, 0) + count
-            for key in ("steps_recorded", "steps_total", "capacity",
-                        "tokens", "prefill_tokens", "decode_tokens",
-                        "decode_row_steps"):
-                merged[key] += s.get(key, 0)
-            for key in ("occupancy_p50", "occupancy_p95",
-                        "kv_utilization_peak", "queue_depth_peak"):
-                merged[key] = max(merged[key], s.get(key, 0))
-        merged["dispatch_kinds"] = dict(sorted(kinds.items()))
-        merged["class_slot_steps"] = dict(sorted(classes.items()))
-        return merged
-
-    def summary(self) -> dict[str, Any]:
-        """Step-level provenance for a measured run:
-        per-dispatch-kind step counts, tokens by
-        side against the decode row-steps dispatched, occupancy p50/p95,
-        and the KV-pressure peak over the retained window."""
-        records = self.snapshot()
-        kinds: dict[str, int] = {}
-        classes: dict[str, int] = {}
-        occ: list[float] = []
-        kv_peak = 0.0
-        queue_peak = 0
-        tokens = prefill_tokens = decode_tokens = decode_row_steps = 0
-        for rec in records:
-            kinds[str(rec.get("kind", "?"))] = (
-                kinds.get(str(rec.get("kind", "?")), 0) + 1)
-            for cls, n in (rec.get("classes") or {}).items():
-                # Slot-steps per priority class: who actually occupied
-                # the decode batch over the window (the scheduler's
-                # fairness evidence, tests/test_sched.py).
-                classes[str(cls)] = classes.get(str(cls), 0) + int(n)
-            occ.append(float(rec.get("occupancy", 0.0)))
-            kv_peak = max(kv_peak, float(rec.get("kv_utilization", 0.0)))
-            queue_peak = max(queue_peak, int(rec.get("queue_depth", 0)))
-            tokens += int(rec.get("tokens", 0))
-            prefill_tokens += int(rec.get("prefill_tokens", 0))
-            decode_tokens += int(rec.get("decode_tokens", 0))
-            decode_row_steps += int(rec.get("rows", 0)) * int(rec.get("k", 0))
-        occ.sort()
-        return {
-            "steps_recorded": len(records),
-            "steps_total": self.total_steps,
-            "capacity": self.capacity,
-            "dispatch_kinds": dict(sorted(kinds.items())),
-            "class_slot_steps": dict(sorted(classes.items())),
-            "tokens": tokens,
-            # ``tokens`` by side, and the decode side's denominator: row x
-            # step pairs dispatched (``rows`` * ``k``). decode_tokens over
-            # it is the share of dispatched decode work that became a
-            # token (a window runs on past a row's stop or length limit).
-            "prefill_tokens": prefill_tokens,
-            "decode_tokens": decode_tokens,
-            "decode_row_steps": decode_row_steps,
-            "occupancy_p50": round(_percentile(occ, 50), 4),
-            "occupancy_p95": round(_percentile(occ, 95), 4),
-            "kv_utilization_peak": round(kv_peak, 4),
-            "queue_depth_peak": queue_peak,
-        }

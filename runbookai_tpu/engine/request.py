@@ -181,6 +181,11 @@ class EngineRequest:
     max_emit_gap_s: float = 0.0  # longest interval between two emits
     preemptions: int = 0
     lifecycle: Optional[dict] = None  # the record, once retired
+    # For the record's ``rode``, with the recorder on: the dispatch
+    # ledger and its sums at the first token, and this request's tokens
+    # after that one by the program that gave them.
+    rode_mark: Optional[tuple] = None
+    rode_tokens: Optional[dict] = None
 
     def mark_first_write(self, t: float) -> None:
         """The server flushed this request's first content chunk at ``t``

@@ -1493,6 +1493,14 @@ class TestTreeIsClean:
             "_append_logprob_entries": 1,
             # THE decode-loop token fetch (async egress consumption):
             "_fetch_tokens": 1,
+            # Recorder on only, inside the fetch phase of the three fetches
+            # above: a dispatch issued BEFORE the one being fetched and not
+            # yet known ready (a prefill chunk nothing fetches, the window
+            # in flight under a first-token fetch) is waited for first, so
+            # that it gets a ready stamp of its own. One device runs the
+            # dispatches in order: the fetch waits for it in any case, so
+            # no wait is added, only split in two (docs/lint.md).
+            "_fetching": 1,
         }, engine
         draft = self._rbk002_sites(
             ROOT / "runbookai_tpu" / "engine" / "draft.py")

@@ -40,7 +40,7 @@ def rec(i, kind="decode", **kw):
             "program": ["_decode_multi"], "k": 8, "rows": 1,
             "kv_pages_live": 3,
             "prefill_tokens": 0, "decode_tokens": 2, "compile_s": 0.0,
-            "admitted": [], "finished": []}
+            "admitted": [], "finished": [], "dispatches": []}
     base.update(kw)
     return base
 
@@ -75,7 +75,7 @@ def test_ring_zero_capacity_disables():
     assert not fr.enabled
     fr.append(rec(0))  # no-op, no raise
     assert len(fr) == 0 and fr.snapshot() == [] and fr.total_steps == 0
-    assert fr.summary()["steps_recorded"] == 0
+    assert fr.dispatches is None  # no dispatch is numbered or stamped
 
 
 def test_ring_reset_restarts_cursor():
@@ -114,35 +114,23 @@ def test_ring_concurrent_append_and_snapshot():
     assert len(fr) == 16 and fr.total_steps == 3000
 
 
-def test_summary_percentiles_and_kinds():
+def test_the_ring_keeps_what_a_window_is_rolled_up_from():
+    """Kinds, tokens, occupancy and the pressure peaks of a window are
+    read off the records themselves (``snapshot()``): the ring keeps each
+    as it was appended, in order."""
     fr = FlightRecorder(64)
     for i in range(10):
         fr.append(rec(i, kind="mixed" if i < 3 else "decode",
                       occupancy=(i + 1) / 10.0, kv_utilization=0.05 * i,
                       queue_depth=i, tokens=3))
-    s = fr.summary()
-    assert s["dispatch_kinds"] == {"decode": 7, "mixed": 3}
-    assert s["tokens"] == 30
-    assert s["occupancy_p50"] == pytest.approx(0.55, abs=1e-6)
-    assert s["occupancy_p95"] == pytest.approx(0.955, abs=1e-6)
-    assert s["kv_utilization_peak"] == pytest.approx(0.45)
-    assert s["queue_depth_peak"] == 9
-    assert s["steps_recorded"] == 10 and s["capacity"] == 64
-
-
-def test_merge_summaries_fleet_rollup():
-    fr0, fr1 = FlightRecorder(8), FlightRecorder(8)
-    for i in range(4):
-        fr0.append(rec(i, kind="mixed", occupancy=0.5, kv_utilization=0.2))
-        fr1.append(rec(i, kind="decode", occupancy=0.9, kv_utilization=0.7,
-                       queue_depth=5))
-    m = FlightRecorder.merge_summaries([fr0.summary(), fr1.summary()])
-    assert m["dispatch_kinds"] == {"decode": 4, "mixed": 4}
-    assert m["steps_recorded"] == 8
-    # Pressure peaks report the WORST replica, not a mean.
-    assert m["occupancy_p95"] == pytest.approx(0.9)
-    assert m["kv_utilization_peak"] == pytest.approx(0.7)
-    assert m["queue_depth_peak"] == 5
+    snap = fr.snapshot()
+    assert [r["kind"] for r in snap].count("mixed") == 3
+    assert [r["kind"] for r in snap].count("decode") == 7
+    assert sum(r["tokens"] for r in snap) == 30
+    assert [r["occupancy"] for r in snap] == [(i + 1) / 10.0 for i in range(10)]
+    assert max(r["kv_utilization"] for r in snap) == pytest.approx(0.45)
+    assert max(r["queue_depth"] for r in snap) == 9
+    assert len(snap) == 10 and fr.capacity == 64 and fr.total_steps == 10
 
 
 def test_dump_jsonl_round_trips(tmp_path):
@@ -472,9 +460,7 @@ def test_live_engine_appends_one_record_per_step(live_core):
     # Tokens booked across the run cover every generated token (decode
     # tokens book at window drain — totals match once idle).
     assert sum(r["tokens"] for r in snap) >= 12
-    s = live_core.flight.summary()
-    assert s["steps_recorded"] == steps
-    assert sum(s["dispatch_kinds"].values()) == steps
+    assert len(snap) == steps == live_core.flight.total_steps
 
 
 def test_flight_recorder_can_be_disabled(live_core):

@@ -16,6 +16,7 @@ import pytest
 
 from runbookai_tpu.engine.engine import EngineConfig, EngineCore
 from runbookai_tpu.engine.flight_recorder import (
+    DISPATCH_FIELDS,
     LIFECYCLE_FIELDS,
     PHASE_SPANS,
     STEP_PHASES,
@@ -33,7 +34,7 @@ from runbookai_tpu.utils.tokens import ByteTokenizer
 
 NEW_FIELDS = ("t_start", "t_end", "phases", "program", "k", "rows",
               "kv_pages_live", "prefill_tokens", "decode_tokens", "compile_s",
-              "admitted", "finished")
+              "admitted", "finished", "dispatches")
 ORDER = ("t_received", "t_enqueued", "t_admitted", "t_first_token",
          "t_finished")
 
@@ -184,26 +185,25 @@ def test_the_record_counts_the_pages_its_rows_hold(parts, monkeypatch,
     assert all(s["kv_pages_live"] == 0 for s in steps if not s["k"])
 
 
-def test_the_summary_reads_the_dispatch_fields(parts):
-    """``flight.summary()`` is the program's
-    reader of ``k``, ``rows``, ``prefill_tokens`` and ``decode_tokens``."""
-    from runbookai_tpu.engine.flight_recorder import FlightRecorder
-
+def test_the_records_hold_the_dispatch_fields(parts):
+    """``k``, ``rows``, ``prefill_tokens`` and ``decode_tokens``, read off
+    the records: every prompt token once, and more decode row-steps
+    dispatched than tokens they gave."""
     core = make_core(parts, decode_steps_per_dispatch=4)
     for text in (b"one prompt here", b"and another"):
         core.submit(request(text, n=6))
     core.run_until_idle()
-    steps, s = core.flight.snapshot(), core.flight.summary()
-    assert s["prefill_tokens"] == sum(x["prefill_tokens"] for x in steps) \
+    steps = core.flight.snapshot()
+    assert sum(x["prefill_tokens"] for x in steps) \
         == len(b"one prompt here") + len(b"and another")
-    assert s["prefill_tokens"] + s["decode_tokens"] == s["tokens"]
-    assert s["decode_row_steps"] == sum(x["rows"] * x["k"] for x in steps)
+    assert all(x["prefill_tokens"] + x["decode_tokens"] == x["tokens"]
+               for x in steps)
     # Every decode token came out of a dispatched row-step; the windows
     # ran on past max_new_tokens, so some row-steps gave none.
-    assert 0 < s["decode_tokens"] < s["decode_row_steps"]
-    merged = FlightRecorder.merge_summaries([s, s])
-    for key in ("prefill_tokens", "decode_tokens", "decode_row_steps"):
-        assert merged[key] == 2 * s[key]
+    decode_tokens = sum(x["decode_tokens"] for x in steps)
+    assert 0 < decode_tokens < sum(x["rows"] * x["k"] for x in steps)
+    assert sum(d["prefill_tokens"] for x in steps for d in x["dispatches"]) \
+        == sum(x["prefill_tokens"] for x in steps)
 
 
 def test_a_compile_is_booked_to_the_step_it_stalled(parts):
@@ -280,6 +280,9 @@ def test_with_the_recorder_off_nothing_is_built(parts):
     assert req.max_emit_gap_s == 0.0 and req.last_emit_time is None
     assert core._admitted_log == [] and core._finished_log == []
     assert len(core.flight) == 0
+    # No dispatch was numbered, stamped or waited on, no request marked.
+    assert core.flight.dispatches is None and core._emitting is None
+    assert req.rode_mark is None and req.rode_tokens is None
 
 
 def test_the_first_write_reaches_the_record_whoever_comes_first(parts):
@@ -308,6 +311,287 @@ def test_the_first_write_reaches_the_record_whoever_comes_first(parts):
     finally:
         sys.setswitchinterval(old)
     assert rounds > 100 and len(core._finished_log) == rounds
+
+
+# ---- the dispatch as a span ---------------------------------------------------
+
+DECODE_PROGRAMS = ("_decode_step", "_decode_multi", "_decode_spec")
+
+
+def serve(core: EngineCore, arrivals, **sampling) -> list:
+    """``arrivals`` = (prompt bytes, max_new_tokens, joins late) through
+    the core's ``AsyncEngine``: a late one is submitted once a request is
+    decoding, so that its prompt meets a running batch. Returns the
+    outputs, in order."""
+    import asyncio
+
+    from runbookai_tpu.engine.async_engine import AsyncEngine
+
+    sampling.setdefault("stop_token_ids", ())
+    sampling.setdefault("temperature", 0.0)
+
+    async def every() -> list:
+        engine = AsyncEngine(core)
+
+        async def one(text: bytes, n: int, late: bool):
+            while late and not core.decoding:
+                await asyncio.sleep(0.001)
+            return await engine.generate(list(text), SamplingParams(
+                max_new_tokens=n, **sampling))
+
+        outs = await asyncio.gather(*(one(*a) for a in arrivals))
+        await engine.stop()
+        return outs
+
+    return asyncio.run(every())
+
+
+def check_dispatches(core: EngineCore, steps: list[dict]) -> list[dict]:
+    """Every dispatch the engine issued is in exactly one record, by
+    number and ready in that order; the ledger's sums are the sums of the
+    intervals the definition gives; every unpreempted request's ``rode``
+    adds up to its decoding time and its tokens."""
+    ledger = core.flight.dispatches
+    listed = [d for s in steps for d in s["dispatches"]]
+    assert [d["n"] for d in listed] == list(range(ledger.n))
+    assert not ledger.in_flight and not ledger.log
+    # What a record says it ISSUED (``program``), over all records, is
+    # what the records list as having come BACK, in the same order.
+    assert [p for s in steps for p in s["program"]] == [d["program"] for d in listed]
+    for d in listed:
+        assert tuple(d) == DISPATCH_FIELDS
+        assert d["t_issued"] <= d["t_ready"]
+        owner = [s for s in steps if d in s["dispatches"]]
+        assert len(owner) == 1 and d["t_ready"] <= owner[0]["t_end"]
+        assert (d["k"] > 0) == (d["rows"] > 0) == (d["kv_pages_live"] > 0)
+        assert (d["program"] in DECODE_PROGRAMS) == (d["prefill_tokens"] == 0)
+    ready = [d["t_ready"] for d in listed]
+    assert ready == sorted(ready)
+    life = [f for s in steps for f in s["finished"]]
+    assert sum(d["tokens"] for d in listed) == sum(f["generated"] for f in life)
+    # The definition, written out: each dispatch's interval and the time
+    # before it, from the stamps alone.
+    seconds: dict[str, float] = {}
+    between, t_prev = 0.0, None
+    for d in listed:
+        if t_prev is not None:
+            start = max(d["t_issued"], t_prev)
+            between += start - t_prev
+            seconds[d["program"]] = seconds.get(d["program"], 0.0) + d["t_ready"] - start
+        t_prev = d["t_ready"]
+    # The ledger's sums are those, and the first dispatch's own interval:
+    # issued after the ledger's start, so all of issue-to-ready.
+    first = listed[0]
+    seconds[first["program"]] = (seconds.get(first["program"], 0.0)
+                                 + first["t_ready"] - first["t_issued"])
+    assert ledger.seconds == pytest.approx(seconds, abs=1e-9)
+    assert between <= ledger.between  # (and the time before the first)
+    assert ledger.count == {p: sum(d["program"] == p for d in listed)
+                            for p in seconds}
+    for f in life:
+        if f["t_first_token"] is None:
+            assert f["rode"] is None
+            continue
+        rode = dict(f["rode"])
+        idle = rode.pop("between")
+        assert idle >= 0.0 and all(
+            n >= 0 and tokens >= 0 and s >= 0.0 for n, tokens, s in rode.values())
+        if f["preemptions"]:
+            continue  # marked: its interval holds time it did not ride
+        assert sum(s for _, _, s in rode.values()) + idle == pytest.approx(
+            f["t_finished"] - f["t_first_token"], abs=1e-6)
+        assert sum(tokens for _, tokens, _ in rode.values()) == f["generated"] - 1
+        # A program it has tokens of, it waited behind.
+        assert all(s > 0.0 for _, tokens, s in rode.values() if tokens)
+    return listed
+
+
+KINDS = {
+    # kind: (engine settings, arrivals, sampling, a program it must run)
+    "decode_multi": (dict(decode_steps_per_dispatch=4, mixed_dispatch=False),
+                     [(b"a first prompt", 14, False), (b"second", 9, False),
+                      (b"a late one, past a chunk of eight", 6, True)],
+                     {}, "_decode_multi"),
+    "decode_step": (dict(decode_steps_per_dispatch=1, mixed_dispatch=False),
+                    [(b"one token a dispatch", 7, False), (b"x", 5, True)],
+                    {}, "_decode_step"),
+    "logprobs": (dict(decode_steps_per_dispatch=4, mixed_dispatch=False),
+                 [(b"scored tokens", 6, False), (b"and more", 5, False)],
+                 {"logprobs": 2}, "_decode_step"),
+    "mixed": (dict(decode_steps_per_dispatch=2, mixed_dispatch=True),
+              [(b"the batch that runs", 24, False),
+               (b"a prompt of three chunks rides along", 6, True),
+               (b"short", 5, True)],
+              {}, "_mixed_step"),
+    "spec": (dict(decode_steps_per_dispatch=4, mixed_dispatch=False,
+                  speculative=True, spec_ngram=1),
+             [(b"restart the api service; restart the api service; restart",
+               24, False)],
+             {}, "_decode_spec"),
+}
+
+
+@pytest.mark.parametrize("kind", sorted(KINDS))
+def test_every_dispatch_is_a_span_and_every_request_says_what_it_rode(parts, kind):
+    settings, arrivals, sampling, program = KINDS[kind]
+    core = make_core(parts, **settings)
+    outs = serve(core, arrivals, **sampling)
+    assert [len(o.token_ids) for o in outs] == [n for _, n, _ in arrivals]
+    steps = core.flight.snapshot()
+    check_steps(steps)
+    listed = check_dispatches(core, steps)
+    programs = {d["program"] for d in listed}
+    assert program in programs and "_prefill_step" in programs
+    life = {f["generated"]: f for s in steps for f in s["finished"]}
+    for _, n, _ in arrivals:
+        rode = life[n]["rode"]
+        # Its tokens after the first came from the decode side (a mixed
+        # step's decode rows included), and it waited behind the program
+        # of this case unless it was gone before one ran.
+        assert sum(rode.get(p, (0, 0, 0.0))[1]
+                   for p in DECODE_PROGRAMS + ("_mixed_step",)) == n - 1
+    assert any(program in f["rode"] for f in life.values())
+    if kind == "mixed":
+        # A prompt that ended in a mixed step had its first token fetched
+        # in the step that issued it, ahead of the window in flight: that
+        # window was waited for first and has a stamp of its own.
+        mixed = [d for d in listed if d["program"] == "_mixed_step"]
+        assert sum(d["prefill_tokens"] for d in mixed) > 0
+        assert sum(d["tokens"] > d["rows"] for d in mixed) >= 1
+        late = life[6]["rode"]
+        assert "_mixed_step" in late or "_decode_multi" in late
+    if kind == "logprobs":
+        assert all(len(o.logprobs) == len(o.token_ids) for o in outs)
+
+
+def test_under_the_pipeline_a_record_lists_the_dispatch_its_fetch_waited_for(parts):
+    """A step issues dispatch n + 1 and fetches dispatch n: its record
+    names the first under ``program`` and lists the second, whose ready
+    stamp lies inside the step, in its fetch."""
+    core = make_core(parts, decode_steps_per_dispatch=2, mixed_dispatch=False)
+    core.submit(request(b"pipelined", n=17))
+    core.run_until_idle()
+    steps = core.flight.snapshot()
+    check_dispatches(core, steps)
+    issued_at, n = {}, 0
+    for s in steps:
+        for _ in s["program"]:
+            issued_at[n] = s["step"]
+            n += 1
+    lagged = 0
+    for s in steps:
+        for d in s["dispatches"]:
+            assert s["t_start"] <= d["t_ready"] <= s["t_end"]
+            if d["program"] == "_decode_multi" and s["program"]:
+                assert issued_at[d["n"]] == s["step"] - 1
+                assert issued_at[d["n"] + 1] == s["step"]
+                assert s["phases"]["fetch"] > 0.0
+                lagged += 1
+    assert lagged >= 5
+
+
+def test_a_dispatch_of_rounds_books_what_was_drafted_and_accepted():
+    from runbookai_tpu.models import hf_loader
+
+    params = hf_loader.load_or_init("joyai-test", None, seed=11,
+                                    dtype=jnp.float32)[1]
+    core = EngineCore(CONFIGS["joyai-test"], params, ByteTokenizer(), EngineConfig(
+        page_size=16, num_pages=128, max_batch_slots=4, prefill_chunk=32,
+        max_seq_len=512, block_pages=2, speculative=True, kv_dtype=jnp.float32,
+        decode_steps_per_dispatch=4, mixed_dispatch=False,
+        flight_recorder_steps=256), seed=0)
+    serve(core, [(bytes(range(40, 90)), 21, False), (bytes(range(60, 101)), 13, False)])
+    steps = core.flight.snapshot()
+    listed = check_dispatches(core, steps)
+    rounds = [(s, d) for s in steps for d in s["dispatches"]
+              if d["program"] == "_decode_spec"]
+    assert len(rounds) >= 2
+    for s, d in rounds:
+        # No overlap under rounds: issued and fetched in the same step.
+        assert s["spec"]["drafted"] + s["spec"]["accepted"] == d["tokens"]
+        assert d["k"] == s["spec"]["rounds"] and d["rows"] == s["spec"]["rows"]
+    for f in (f for s in steps for f in s["finished"]):
+        assert f["rode"]["_decode_spec"][1] == f["generated"] - 1
+
+
+def test_a_preempted_request_is_marked_and_the_rest_still_add_up(parts):
+    core = make_core(parts, num_pages=20, max_batch_slots=2,
+                     decode_steps_per_dispatch=1, admit_headroom_tokens=8)
+    for ch in b"ab":
+        core.submit(request(bytes([ch]) * 21, n=40))
+    core.run_until_idle()
+    steps = core.flight.snapshot()
+    check_dispatches(core, steps)
+    life = [f for s in steps for f in s["finished"]]
+    assert any(f["preemptions"] for f in life)
+    for f in life:
+        # Preempted or not, the seconds tile the interval; what a
+        # preempted one was given on the way back (its prompt and tokens
+        # computed again, a prefill's token) is counted to it too.
+        rode = dict(f["rode"])
+        idle = rode.pop("between")
+        assert sum(s for _, _, s in rode.values()) + idle == pytest.approx(
+            f["t_finished"] - f["t_first_token"], abs=1e-6)
+        assert sum(t for _, t, _ in rode.values()) == f["generated"] - 1
+        if f["preemptions"]:
+            assert rode.get("_prefill_step", (0, 0, 0.0))[1] >= 1
+
+
+def test_a_ring_reset_restarts_the_ledger(parts):
+    core = make_core(parts)
+    core.submit(request(b"before"))
+    core.run_until_idle()
+    assert core.flight.dispatches.n > 0
+    core.reset_metrics()
+    assert core.flight.dispatches.n == 0 and not core.flight.dispatches.count
+    core.submit(request(b"after the reset"))
+    core.run_until_idle()
+    assert check_dispatches(core, core.flight.snapshot())[0]["n"] == 0
+
+
+def test_a_dispatch_in_flight_across_a_reset_is_of_no_ledger(parts):
+    """``reset()`` swaps the ledger while a window may be in flight: its
+    drain then stamps, books and logs nothing in the NEW ledger, whose
+    numbers start at 0 and whose sums hold its own dispatches only."""
+    core = make_core(parts, decode_steps_per_dispatch=2, mixed_dispatch=False)
+    core.submit(request(b"over a reset", n=15))
+    while core._pending is None:
+        core.step()
+    old, stale = core.flight.dispatches, core._pending.dispatch
+    assert stale["t_issued"] is not None and stale["t_ready"] is None
+    core.flight.reset()
+    ledger = core.flight.dispatches
+    assert ledger is not old and not ledger.mine(stale)
+    core.run_until_idle()
+    steps = core.flight.snapshot()
+    listed = [d for s in steps for d in s["dispatches"]]
+    assert listed and [d["n"] for d in listed] == list(range(ledger.n))
+    assert stale["t_ready"] is None and all(d is not stale for d in listed)
+    assert sum(ledger.count.values()) == len(listed)
+    assert sum(ledger.seconds.values()) + ledger.between == pytest.approx(
+        ledger.t_ready - ledger.t0, abs=1e-9)
+    assert not ledger.in_flight and not ledger.log
+    # The request's mark was of the ledger that went: no ``rode``.
+    (life,) = [f for s in steps for f in s["finished"]]
+    assert life["generated"] == 15 and life["rode"] is None
+
+
+def test_a_chunk_that_emits_nothing_holds_one_element_of_its_result(parts):
+    """A prefill chunk short of its prompt's end is waited on by a later
+    fetch; until then the ledger keeps one element of its result alive,
+    not ``[rows, vocab]`` logits a chunk of a long prompt."""
+    core = make_core(parts, mixed_dispatch=False)
+    core.submit(request(b"a prompt of more than three chunks of eight", n=3))
+    core.step()
+    core.step()
+    waiting = core.flight.dispatches.in_flight
+    assert [(e["program"], emits) for e, _, emits in waiting] == [
+        ("_prefill_step", False)] * 2
+    assert all(result.shape == (1, 1) for _, result, _ in waiting)
+    core.run_until_idle()
+    listed = check_dispatches(core, core.flight.snapshot())
+    assert sum(d["program"] == "_prefill_step" and not d["tokens"]
+               for d in listed) >= 3
 
 
 # ---- through the front door --------------------------------------------------
