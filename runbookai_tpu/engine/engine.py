@@ -657,9 +657,11 @@ def _probe_pallas_attn_cached(backend: str, n_kv: int, n_q: int,
     construction with Mosaic's own message instead of failing the first
     request. Nothing is caught: selecting a kernel that does not compile
     is an error, never a reason to serve another path under the same
-    name. Callers pass PER-SHARD n_kv/n_q (the shard_map-local shapes);
-    on a page-split mesh the PARTIAL kernel is what decode runs, so that
-    is probed too. Successes are cached per process — tests build many
+    name. The call is the step programs': a STACKED ``[L, tokens, n_kv,
+    hd]`` pool (two layers stand for any depth) and a layer's number.
+    Callers pass PER-SHARD n_kv/n_q (the shard_map-local shapes); on a
+    page-split mesh the PARTIAL kernel is what decode runs, so that is
+    probed too. Successes are cached per process — tests build many
     engines."""
     from runbookai_tpu.ops.paged_attention_pallas import (
         paged_chunk_attention,
@@ -669,13 +671,15 @@ def _probe_pallas_attn_cached(backend: str, n_kv: int, n_q: int,
     kv_dtype = jnp.dtype(kv_dtype_name)
     act_dtype = jnp.dtype(act_dtype_name)
     interp = backend == "cpu"
-    kv = jnp.zeros((2 * page_size, n_kv, head_dim), kv_dtype)
+    kv = jnp.zeros((2, 2 * page_size, n_kv, head_dim), kv_dtype)
     tables = jnp.zeros((1, 2), jnp.int32)
+    layer = jnp.ones((), jnp.int32)
 
     q1 = jnp.zeros((1, n_q, head_dim), act_dtype)
     out = paged_decode_attention(q1, kv, kv, tables,
                                  jnp.ones((1,), jnp.int32),
-                                 page_size=page_size, interpret=interp)
+                                 page_size=page_size, interpret=interp,
+                                 layer=layer)
     # runbook: noqa[RBK002] — probe barrier: the compile/execute must
     # finish (or raise) before serving trusts the decode kernel.
     jax.block_until_ready(out)
@@ -685,7 +689,8 @@ def _probe_pallas_attn_cached(backend: str, n_kv: int, n_q: int,
     positions = jnp.arange(t, dtype=jnp.int32)[None]
     out = paged_chunk_attention(qt, kv, kv, tables,
                                 jnp.full((1,), t, jnp.int32), positions,
-                                page_size=page_size, interpret=interp)
+                                page_size=page_size, interpret=interp,
+                                layer=layer)
     # runbook: noqa[RBK002] — probe barrier: chunk-kernel lowering must
     # prove out before prefill dispatches it.
     jax.block_until_ready(out)
@@ -697,7 +702,7 @@ def _probe_pallas_attn_cached(backend: str, n_kv: int, n_q: int,
         out = paged_decode_attention_partial(
             q1, kv, kv, tables, jnp.ones((1,), jnp.int32),
             jnp.int32(0), page_size=page_size, pages_local=1,
-            interpret=interp)
+            interpret=interp, layer=layer)
         # runbook: noqa[RBK002] — probe barrier: the PARTIAL kernel is
         # the program a page-split mesh actually runs; prove it here.
         jax.block_until_ready(out)
@@ -734,20 +739,21 @@ def _probe_pallas_attn_int8_cached(backend: str, n_kv: int, n_q: int,
                                    head_dim: int, page_size: int,
                                    act_dtype_name: str) -> bool:
     """The int8-scaled decode kernel (tuple pool: int8 values + f32
-    per-token scales) at the engine's shapes; raises what Mosaic raises.
-    Decode only: chunked prefill routes to XLA for int8."""
+    per-token scales, two layers stacked, and a layer's number) at the
+    engine's shapes; raises what Mosaic raises. Decode only: chunked
+    prefill routes to XLA for int8."""
     from runbookai_tpu.ops.paged_attention_pallas import (
         paged_decode_attention,
     )
 
-    kv_vals = jnp.zeros((2 * page_size, n_kv, head_dim), jnp.int8)
-    kv_scales = jnp.zeros((2 * page_size, n_kv), jnp.float32)
+    kv_vals = jnp.zeros((2, 2 * page_size, n_kv, head_dim), jnp.int8)
+    kv_scales = jnp.zeros((2, 2 * page_size, n_kv), jnp.float32)
     tables = jnp.zeros((1, 2), jnp.int32)
     q1 = jnp.zeros((1, n_q, head_dim), jnp.dtype(act_dtype_name))
     out = paged_decode_attention(
         q1, (kv_vals, kv_scales), (kv_vals, kv_scales), tables,
         jnp.ones((1,), jnp.int32), page_size=page_size,
-        interpret=backend == "cpu")
+        interpret=backend == "cpu", layer=jnp.ones((), jnp.int32))
     # runbook: noqa[RBK002] — probe barrier: int8 widen-multiply must
     # lower (or raise) before serving reads int8 pages through it.
     jax.block_until_ready(out)
@@ -816,13 +822,14 @@ def _probe_pallas_ragged_cached(backend: str, n_kv: int, n_q: int,
     """The ragged mixed-dispatch kernel path (``paged_ragged_attention``
     — the chunk kernel at the blocked ragged layout with per-block
     gathered tables) at a representative 2-row mix (one decode-shaped
-    row, one chunk-shaped row); raises what Mosaic raises."""
+    row, one chunk-shaped row), over layer 1 of a two-layer pool; raises
+    what Mosaic raises."""
     from runbookai_tpu.ops.paged_attention_pallas import (
         paged_ragged_attention,
     )
 
     rq = _RAGGED_BLOCK
-    kv = jnp.zeros((2 * page_size, n_kv, head_dim),
+    kv = jnp.zeros((2, 2 * page_size, n_kv, head_dim),
                    jnp.dtype(kv_dtype_name))
     tables = jnp.zeros((2, 2), jnp.int32)
     q = jnp.zeros((2 * rq, n_q, head_dim), jnp.dtype(act_dtype_name))
@@ -832,7 +839,7 @@ def _probe_pallas_ragged_cached(backend: str, n_kv: int, n_q: int,
     out = paged_ragged_attention(
         q, kv, kv, tables, jnp.asarray([1, rq], jnp.int32), q_pos,
         row_ids, page_size=page_size, ragged_block=rq,
-        interpret=backend == "cpu")
+        interpret=backend == "cpu", layer=jnp.ones((), jnp.int32))
     # runbook: noqa[RBK002] — probe barrier: the ragged mixed-dispatch
     # kernel must lower (or raise) before mixed traffic relies on it.
     jax.block_until_ready(out)

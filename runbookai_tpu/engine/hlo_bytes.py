@@ -18,6 +18,10 @@ COMPILED program (VERDICT r4 next-round #2):
   :func:`layer_weight_copies` hunts the same shapes at the STORED width:
   one layer's int8 matrix copied out of the stacked array in front of
   the Pallas matmul, which reads the stack in place.
+- :func:`kv_pool_materializations` hunts a second KV pool or a layer
+  written through, :func:`kv_layer_slices` one layer of the pool copied
+  out in front of the Pallas attention kernels, which read the carried
+  pool in place.
 - :func:`lower_decode` lowers+compiles the engine's REAL decode dispatch
   (the same jitted ``_decode_step`` serving uses) without executing it,
   so the analysis covers the program that runs, not a proxy.
@@ -236,17 +240,22 @@ def lower_decode(core, *, qmm_impl: str | None = None,
     return step.lower(*args, **static, **traced).compile()
 
 
-def kv_pool_shapes(core) -> set[tuple[int, ...]]:
+def kv_pool_shapes(core, n_layers: int | None = None
+                   ) -> set[tuple[int, ...]]:
     """Dims a materialized KV pool would take in the compiled program:
-    ``[L, tokens, n_kv, hd]`` and its row and page views. int8 pools add
-    the scale arrays' forms."""
+    ``[L, tokens, n_kv, hd]`` and its row and page views (the chunk
+    walk's ``[pages, page_size, n_kv, hd]``, the decode walk's ``[pages,
+    page_size x n_kv, hd]``). int8 pools add the scale arrays' forms.
+    With ``n_layers=1``: the dims of ONE layer of it."""
     ps = core.ecfg.page_size
     shapes: set[tuple[int, ...]] = set()
     for leaf in jax.tree.leaves((core._kv_k, core._kv_v)):
-        n_layers, tokens, *rest = leaf.shape
-        for lead in ((n_layers, tokens), (n_layers * tokens,),
-                     (n_layers * tokens // ps, ps)):
+        layers, tokens, *rest = leaf.shape
+        rows = (n_layers or layers) * tokens
+        for lead in ((rows // tokens, tokens), (rows,), (rows // ps, ps)):
             shapes.add(lead + tuple(rest))
+        if len(rest) == 2:
+            shapes.add((rows // ps, ps * rest[0], rest[1]))
     return shapes
 
 
@@ -286,7 +295,7 @@ def kv_pool_materializations(compiled, core) -> list[str]:
     program has none: a ``copy``, a ``broadcast`` or ``AllocateBuffer``
     (a second pool) or a layer-sized ``dynamic-update-slice`` (a layer
     written through) each move gigabytes a pass at serving size. The
-    readers' layer slice is not hunted: it is the kernels' feed."""
+    readers' layer slice is :func:`kv_layer_slices`' to hunt."""
     targets = kv_pool_shapes(core)
     layer_elems = min(math.prod(leaf.shape[1:]) for leaf in
                       jax.tree.leaves((core._kv_k, core._kv_v)))
@@ -305,6 +314,22 @@ def kv_pool_materializations(compiled, core) -> list[str]:
                 continue
             bad.append(line[:200])
     return bad
+
+
+def kv_layer_slices(compiled, core) -> list[str]:
+    """Offending lines of a compiled step program: instructions outside
+    fusion bodies that own a buffer of ONE layer of the KV pool —
+    ``[tokens, n_kv, hd]``, its keep-dims form or a page view of it. That
+    is the readers' layer slice (on the chip ``%dynamic-slice_fusion =
+    bf16[1, tokens, n_kv, hd]`` staged on-chip in the layer scan's body,
+    twice a layer, in front of a Pallas attention kernel that read 3% of
+    it: PERF.md section 6, PR 38). The kernels take the carried pool and
+    the layer's number, so a program whose pool they read in place
+    (``paged_attention_pallas.reads_in_place``) has none; one whose pool
+    fits on-chip memory, or whose readers are XLA's, keeps the slice."""
+    return _weight_shaped_buffers(
+        compiled.as_text(), kv_pool_shapes(core, n_layers=1),
+        {*_WIDE_DTYPES, *_NARROW_DTYPES})
 
 
 def param_nbytes(params: Any) -> int:

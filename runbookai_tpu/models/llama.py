@@ -451,9 +451,10 @@ def _forward_hidden(
     the layer's parameters and its number — less the int8 matrices the
     Pallas matmul reads in place in their stacked arrays. The page
     writers (the flat scatter, and kv-split's ``shard_map`` one) take the
-    whole pool and that number and write their rows in place; every
-    reader (XLA gather, Pallas kernels, their TP and kv-split wraps, int8
-    pools) takes the layer's slice of the carry.
+    whole pool and that number and write their rows in place, and the
+    Pallas attention kernels read their pages from it the same way
+    (``paged_attention_pallas.reads_in_place``); XLA's readers take the
+    layer's slice of the carry.
     """
     b, t = tokens.shape
     hd, n_kv = cfg.head_dim, cfg.n_kv_heads
@@ -511,6 +512,14 @@ def _forward_hidden(
         layers = {name: {"s": w["s"]} if name in stacked else w
                   for name, w in layers.items()}
 
+    # Whether the Pallas attention kernels read the carried pool where it
+    # lies: static, by the pool's shape.
+    in_place = False
+    if attn_impl == "pallas":
+        from runbookai_tpu.ops.paged_attention_pallas import reads_in_place
+
+        in_place = reads_in_place(kv_k, mesh)
+
     def layer_step(carry, layer_in):
         # The pool rides the CARRY, whole: a scan's stacked output can
         # never share its scanned input's buffer, so handing the pool in
@@ -552,21 +561,24 @@ def _forward_hidden(
                                         page_size, layer=li)
             kv_v = write_kv_pages_batch(kv_v, v, positions, page_tables,
                                         page_size, layer=li)
-        # The readers take the layer's slice of the carry, [tokens, n_kv,
-        # hd], as when the scan handed it to them. On the chip XLA stages
-        # that slice in on-chip memory and the Pallas kernels fetch their
-        # 16 KB pages from there: both walk a row's (a query block's) live
-        # pages inside the kernel, several a step (decode since PR 28,
-        # chunks since PR 36; the page views they take are bitcasts of the
-        # slice). Fetching one page a grid step straight out of the pool
-        # in HBM (a layer coordinate in the kernels' index_maps, no slice)
-        # was measured and doubled both kernels' time (PERF.md section 6,
-        # PR 25); the walks reading the pool in place is ROADMAP A5.
+        # The Pallas kernels take the carried pool and the layer's
+        # number: they walk a row's (a query block's) live pages inside
+        # the kernel, 16 copies a source in flight out of HBM (decode
+        # since PR 28, chunks since PR 36), at ``layer x pages + id`` of
+        # the pool's page view, a bitcast. A slice in front of them is a
+        # copy XLA stages on chip whole — 2 x 50 MB a layer in the 7B
+        # cell, five times the decode kernel it fed (PERF.md section 6,
+        # PR 38). XLA's readers gather out of the slice, and so do the
+        # kernels where the pool is not read in place (one that fits
+        # on-chip memory, or whose page view must be padded): the same
+        # kernel at L = 1.
         def layer_slice(pool):  # int8 pools are (values, scales)
             return jax.tree.map(lambda a: jax.lax.dynamic_index_in_dim(
                 a, li, keepdims=False), pool)
 
         k_pages, v_pages = layer_slice(kv_k), layer_slice(kv_v)
+        k_walk, v_walk, layer = ((kv_k, kv_v, li) if in_place
+                                 else (k_pages, v_pages, None))
 
         # int8 pools: the decode kernel reads int8 pages + scales
         # directly (widened in VMEM); chunked prefill is compute-bound
@@ -605,24 +617,23 @@ def _forward_hidden(
             if shardable:
                 if t == 1:
                     attn = paged_decode_attention_tp(
-                        mesh, q[:, 0], k_pages, v_pages, page_tables,
+                        mesh, q[:, 0], k_walk, v_walk, page_tables,
                         ctx_lens, page_size=page_size, interpret=interp,
-                    )[:, None]
+                        layer=layer)[:, None]
                 else:
                     attn = paged_chunk_attention_tp(
-                        mesh, q, k_pages, v_pages, page_tables, ctx_lens,
+                        mesh, q, k_walk, v_walk, page_tables, ctx_lens,
                         positions, page_size=page_size, interpret=interp,
-                    )
+                        layer=layer)
             elif t == 1:
                 attn = paged_decode_attention(
-                    q[:, 0], k_pages, v_pages, page_tables, ctx_lens,
-                    page_size=page_size, interpret=interp,
+                    q[:, 0], k_walk, v_walk, page_tables, ctx_lens,
+                    page_size=page_size, interpret=interp, layer=layer,
                 )[:, None]
             else:
                 attn = paged_chunk_attention(
-                    q, k_pages, v_pages, page_tables, ctx_lens, positions,
-                    page_size=page_size, interpret=interp,
-                )
+                    q, k_walk, v_walk, page_tables, ctx_lens, positions,
+                    page_size=page_size, interpret=interp, layer=layer)
         elif kv_split_active:
             from runbookai_tpu.parallel.kv_split import (
                 paged_attention_kv_split,
@@ -634,9 +645,10 @@ def _forward_hidden(
                 # masked local pages + seq-axis flash merge); chunked
                 # prefill stays on the XLA kv-split path (compute-bound).
                 attn = paged_decode_attention_kv_split_pallas(
-                    mesh, q[:, 0], k_pages, v_pages, page_tables, ctx_lens,
+                    mesh, q[:, 0], k_walk, v_walk, page_tables, ctx_lens,
                     page_size=page_size,
-                    interpret=jax.default_backend() == "cpu")[:, None]
+                    interpret=jax.default_backend() == "cpu",
+                    layer=layer)[:, None]
             else:
                 attn = paged_attention_kv_split(
                     mesh, q, k_pages, v_pages, page_tables, ctx_lens,

@@ -16,6 +16,14 @@ this one is accumulated (:func:`_flash_accumulate`, the one body). Pages a
 step comes from the page's bytes against a fixed VMEM budget
 (:func:`decode_pages_per_step`).
 
+**The pool is the one the layer scan carries**, ``[L, tokens, n_kv, hd]``,
+and the layer's number one more scalar-prefetch operand: a page's index is
+``layer x pages + id`` in the pool's page view, its two leading axes
+merged (a bitcast). No layer slice is staged in front of a call: in the
+7B cell that was 2 x 50 MB a layer, five times the kernel it fed. A
+one-layer pool ``[tokens, n_kv, hd]`` is the same kernel at L = 1, layer
+0. Which of the two a forward hands over is :func:`reads_in_place`.
+
 - :func:`paged_decode_attention` — decode-shaped (T = 1), grid = (rows,):
   a row walks ``cdiv(ctx_lens[row], pages a step x page_size)`` steps. All
   kv heads of a group go through one product (a foreign head's column is
@@ -54,6 +62,13 @@ NEG_INF = -1e30
 # more than a longer step saves in loop trips.
 _DECODE_KV_VMEM_BYTES = 1 << 20
 _DECODE_STEP_POSITIONS = 512
+
+# A stacked pool that FITS on-chip memory is not left where it lies: in the
+# layer scan's body XLA prefetches all L layers of such an operand there
+# before every call (``ops/qmm_pallas.py`` ``_ON_CHIP_BYTES``, PR 30), L
+# times the slice it would replace. So only a pool that cannot fit there
+# is read in place; of any other the kernels get the layer's slice.
+_ON_CHIP_BYTES = 128 * 1024 * 1024
 
 
 def decode_pages_per_step(page_size: int, n_kv: int, hd: int, kv_dtype,
@@ -169,7 +184,7 @@ def _walk_pages(n_steps, pages_per_step: int, page, place, srcs, bufs,
 
 def _decode_walk_kernel(*refs, page_size: int, n_kv: int, group: int,
                         pages_per_step: int, sm_scale: float, scaled: bool,
-                        pages_local: int | None):
+                        pages_local: int | None, layer_pages: int):
     """One grid step = one row: walk its live pages, ``pages_per_step`` at a
     time. What a page is and what the row writes at the end are the two
     things the pools differ in:
@@ -182,14 +197,18 @@ def _decode_walk_kernel(*refs, page_size: int, n_kv: int, group: int,
       shards are neither fetched nor counted, and the row writes the flash
       partials ``(acc, m, l)`` for the cross-shard merge
       (``parallel/kv_split.py``) instead of the normalised output.
+
+    The sources hold every layer's pages, ``layer_pages`` a layer: the
+    walk reads those of the scalar-prefetched layer.
     """
     partial = pages_local is not None
     n_src = 4 if scaled else 2
     it = iter(refs)
-    # scalar prefetch (SMEM): page table [B, P], context lengths [B], and
-    # the kv-split shard index [1]
+    # scalar prefetch (SMEM): page table [B, P], context lengths [B], the
+    # kv-split shard index [1], and the layer [1]
     tables_ref, ctx_ref = next(it), next(it)
     shard = next(it)[0] if partial else None
+    first_page = next(it)[0] * layer_pages
     q_ref = next(it)  # [1, n_q, hd]
     srcs = [next(it) for _ in range(n_src)]  # k, v[, k scales, v scales]
     outs = [next(it) for _ in range(3 if partial else 1)]
@@ -215,7 +234,7 @@ def _decode_walk_kernel(*refs, page_size: int, n_kv: int, group: int,
         if partial:
             live = live & (pid // pages_local == shard)
             pid = pid - shard * pages_local
-        return live, pid
+        return live, first_page + pid
 
     # A column of a group is (position, kv head), as the pool lays a page
     # out; query head r reads kv head r // group (kv-major head order, the
@@ -282,38 +301,83 @@ def _lane_pad(x: jnp.ndarray) -> jnp.ndarray:
     return jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, short)]) if short else x
 
 
+def _kv_tile(n_kv: int, kv_dtype) -> int:
+    """The rows Mosaic slices a page's kv-head axis in: whole sublane
+    tiles, the next power of two between a 32-bit word's rows and eight
+    words'."""
+    packing = 4 // jnp.dtype(kv_dtype).itemsize
+    return min(8 * packing, max(packing, pl.next_power_of_2(n_kv)))
+
+
 def _chunk_pages(pool: jnp.ndarray, page_size: int) -> jnp.ndarray:
-    """``pool [tokens, n_kv, hd]`` as the pages the chunk walk copies,
-    ``[pages, page_size, n_kv, hd]``: the bytes as they lie, for the
-    serving shapes (4 or 8 kv heads of 128 in bf16 or fp8). Mosaic slices
-    such an operand only in whole sublane tiles — the next power of two
-    rows between a 32-bit word's and eight words' — so any other head
-    count is zero-padded to them, and a lone head (a tp shard's) gives up
-    its axis, ``[pages, page_size, hd]``: XLA lays that pool out without
-    one, and padding it would copy the pool a layer."""
+    """``pool [(L,) tokens, n_kv, hd]`` as the pages the chunk walk
+    copies, ``[(L x) pages, page_size, n_kv, hd]``: the bytes as they lie,
+    for the serving shapes (4 or 8 kv heads of 128 in bf16 or fp8). Mosaic
+    slices such an operand only in whole sublane tiles (:func:`_kv_tile`),
+    so any other head count is zero-padded to them, and a lone head (a tp
+    shard's) gives up its axis, ``[pages, page_size, hd]``: XLA lays that
+    pool out without one, and padding it would copy the pool a layer."""
     pool = _lane_pad(pool)
-    n_kv, hd = pool.shape[1:]
+    n_kv, hd = pool.shape[-2:]
     if n_kv == 1:
         return pool.reshape(-1, page_size, hd)
-    packing = 4 // pool.dtype.itemsize
-    tile = min(8 * packing, max(packing, pl.next_power_of_2(n_kv)))
-    pool = jnp.pad(pool, ((0, 0), (0, -n_kv % tile), (0, 0)))
-    return pool.reshape(-1, page_size, *pool.shape[1:])
+    short = -n_kv % _kv_tile(n_kv, pool.dtype)
+    pool = jnp.pad(pool, [(0, 0)] * (pool.ndim - 2) + [(0, short), (0, 0)])
+    return pool.reshape(-1, page_size, *pool.shape[-2:])
+
+
+def reads_in_place(pool, mesh=None) -> bool:
+    """Whether a forward hands the kernels the pool its layer scan
+    carries, ``[L, tokens, n_kv, hd]``, and the layer's number (True) or
+    the layer's slice (False): static, by shape alone, as
+    ``qmm_pallas.reads_in_place``. In place where the pool (a device's
+    shard of it under ``mesh``) cannot fit on-chip memory and its page
+    views are the bytes as they lie: what the wrappers must pad in XLA —
+    a head narrower than the lanes, an int8 pool's scales, a head count
+    off Mosaic's tile — would be padded for EVERY layer before each call,
+    a copy of the whole pool where the slice copies one layer."""
+    if isinstance(pool, tuple):
+        return False
+    n_layers, tokens, n_kv, hd = pool.shape
+    if mesh is not None:
+        from runbookai_tpu.parallel.mesh import SEQ_AXIS
+
+        tokens //= mesh.shape.get(SEQ_AXIS, 1)
+        if tp_shardable(mesh, n_kv):
+            n_kv //= _model_tp(mesh)
+    lies_as_pages = hd % 128 == 0 and (
+        n_kv == 1 or n_kv % _kv_tile(n_kv, pool.dtype) == 0)
+    return lies_as_pages and (n_layers * tokens * n_kv * hd
+                              * pool.dtype.itemsize >= _ON_CHIP_BYTES)
+
+
+def _stacked(pools, layer):
+    """``(pools [L, tokens, ...], the layer's number as the kernels'
+    scalar-prefetch operand i32[1])``: one layer's ``[tokens, ...]``, given
+    with no ``layer``, is L = 1 and layer 0."""
+    if layer is None:
+        return (jax.tree.map(lambda a: a[None], pools),
+                jnp.zeros((1,), jnp.int32))
+    return pools, jnp.asarray(layer, jnp.int32).reshape(1)
 
 
 def _decode_walk(q, k_flat, v_flat, page_tables, ctx_lens, page_size: int,
-                 interpret: bool, shard=None, pages_local: int | None = None):
+                 interpret: bool, shard=None, pages_local: int | None = None,
+                 layer=None):
     """Launch :func:`_decode_walk_kernel` over the rows of ``q``. The page
     table is the call's first operand and the result ``[rows, n_q, hd]``:
     the benchmark finds the kernel in a trace by those two shapes
     (``benchmark/kernels/paged_attention_decode.py``). A head narrower than
     the lanes (the test-size models) is zero-padded to them, which leaves
-    every score and, once sliced, the output what they were."""
+    every score and, once sliced, the output what they were. With a
+    ``layer`` the pools are ``[L, tokens, ...]`` and the sources their
+    page views with L merged in front."""
     b, n_q, hd = q.shape
+    (k_flat, v_flat), layer = _stacked((k_flat, v_flat), layer)
     scaled = isinstance(k_flat, tuple)
     k_vals, k_scales = k_flat if scaled else (k_flat, None)
     v_vals, v_scales = v_flat if scaled else (v_flat, None)
-    n_kv = k_vals.shape[1]
+    n_layers, _, n_kv, _ = k_vals.shape
     q, k_vals, v_vals = _lane_pad(q), _lane_pad(k_vals), _lane_pad(v_vals)
     hd_lanes = q.shape[-1]
     g = decode_pages_per_step(page_size, n_kv, hd_lanes, k_vals.dtype,
@@ -331,6 +395,7 @@ def _decode_walk(q, k_flat, v_flat, page_tables, ctx_lens, page_size: int,
     partial = pages_local is not None
     if partial:
         prefetch.append(shard.reshape(1))
+    prefetch.append(layer)
 
     def row_block(width):
         return pl.BlockSpec((1, n_q, width), lambda r, *_: (r, 0, 0))
@@ -341,7 +406,8 @@ def _decode_walk(q, k_flat, v_flat, page_tables, ctx_lens, page_size: int,
         functools.partial(
             _decode_walk_kernel, page_size=page_size, n_kv=n_kv,
             group=n_q // n_kv, pages_per_step=g, sm_scale=hd ** -0.5,
-            scaled=scaled, pages_local=pages_local),
+            scaled=scaled, pages_local=pages_local,
+            layer_pages=srcs[0].shape[0] // n_layers),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=len(prefetch),
             grid=(b,),
@@ -371,22 +437,26 @@ def paged_decode_attention(
     ctx_lens: jnp.ndarray,  # [B] int32
     page_size: int,
     interpret: bool = False,
+    layer=None,  # with it the pools are [L, tokens, ...]: read layer `layer`
 ) -> jnp.ndarray:
     """Ragged paged attention for decode (one query token per sequence).
     An int8 pool is ``(values [tokens, n_kv, hd], f32 scales [tokens,
     n_kv])``: HBM still moves 1 byte a value, widened in VMEM."""
     return _decode_walk(q, k_flat, v_flat, page_tables, ctx_lens, page_size,
-                        interpret)
+                        interpret, layer=layer)
 
 
-def _chunk_walk_kernel(tables_ref, ctx_ref, q_start_ref, q_ref, k_src, v_src,
-                       o_ref, k_buf, v_buf, sems, q_scr, m_ref, l_ref,
-                       acc_ref, *, page_size: int, n_kv: int, group: int,
-                       tq: int, pages_per_step: int, sm_scale: float):
+def _chunk_walk_kernel(tables_ref, ctx_ref, q_start_ref, layer_ref, q_ref,
+                       k_src, v_src, o_ref, k_buf, v_buf, sems, q_scr, m_ref,
+                       l_ref, acc_ref, *, page_size: int, n_kv: int,
+                       group: int, tq: int, pages_per_step: int,
+                       sm_scale: float, layer_pages: int):
     """One grid step = one block of ``tq`` queries of one row: walk the
     pages it can see, ``pages_per_step`` at a time. The causal bound ends
     the walk as well as the context does, so a prompt's first block walks
-    one step and a pad block (``ctx == 0``) none: it writes zeros.
+    one step and a pad block (``ctx == 0``) none: it writes zeros. The
+    sources hold every layer's pages, ``layer_pages`` a layer: the walk
+    reads those of the scalar-prefetched layer.
 
     The scratch rows are kv-major: rows ``[h * tq * group, (h + 1) * tq *
     group)`` are kv head ``h``'s queries, token-major inside the head, and
@@ -411,9 +481,11 @@ def _chunk_walk_kernel(tables_ref, ctx_ref, q_start_ref, q_ref, k_src, v_src,
         q_scr[h * rows:(h + 1) * rows] = (
             q[:, h * group:(h + 1) * group].reshape(rows, hd))
 
+    first_page = layer_ref[0] * layer_pages
+
     def page(col):
         return (col * page_size < seen,
-                tables_ref[row, jnp.minimum(col, last_col)])
+                first_page + tables_ref[row, jnp.minimum(col, last_col)])
 
     # The mask is per query ROW of a head's block, built from 2D iotas.
     pos = jax.lax.broadcasted_iota(jnp.int32, (1, span), 1)
@@ -466,6 +538,7 @@ def paged_chunk_attention(
     page_size: int,
     interpret: bool = False,
     q_block: int | None = None,
+    layer=None,  # with it the pools are [L, tokens, ...]: read layer `layer`
 ) -> jnp.ndarray:
     """Ragged paged attention for T>1 chunks (prefill / speculative verify).
 
@@ -479,7 +552,8 @@ def paged_chunk_attention(
     their K/V go to the null page.
     """
     b, t, n_q, hd = q.shape
-    n_kv = k_flat.shape[1]
+    (k_flat, v_flat), layer = _stacked((k_flat, v_flat), layer)
+    n_layers, _, n_kv, _ = k_flat.shape
     tq = q_block if q_block is not None else chunk_q_block(t, n_q)
     t_pad = -(-t // tq) * tq
     if t_pad != t:
@@ -497,9 +571,10 @@ def paged_chunk_attention(
     out = pl.pallas_call(
         functools.partial(
             _chunk_walk_kernel, page_size=page_size, n_kv=n_kv,
-            group=n_q // n_kv, tq=tq, pages_per_step=g, sm_scale=hd ** -0.5),
+            group=n_q // n_kv, tq=tq, pages_per_step=g, sm_scale=hd ** -0.5,
+            layer_pages=pages[0].shape[0] // n_layers),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3,
+            num_scalar_prefetch=4,
             grid=(b, t_pad // tq),
             in_specs=[q_blocks] + [pl.BlockSpec(memory_space=pl.ANY)] * 2,
             out_specs=q_blocks,
@@ -514,7 +589,8 @@ def paged_chunk_attention(
         ),
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         interpret=interpret,
-    )(page_tables, ctx_lens, q_positions[:, 0].astype(jnp.int32), q, *pages)
+    )(page_tables, ctx_lens, q_positions[:, 0].astype(jnp.int32), layer, q,
+      *pages)
     return out[:, :t, :, :hd]
 
 
@@ -529,6 +605,7 @@ def paged_ragged_attention(
     page_size: int,
     ragged_block: int = 8,
     interpret: bool = False,
+    layer=None,  # with it the pools are [L, tokens, ...]: read layer `layer`
 ) -> jnp.ndarray:
     """Ragged paged attention over a FLAT mixed prefill+decode batch.
 
@@ -558,7 +635,7 @@ def paged_ragged_attention(
     return paged_chunk_attention(
         q.reshape(nb, rq, n_q, hd), k_flat, v_flat,
         page_tables[rows], ctx_lens[rows], q_positions.reshape(nb, rq),
-        page_size=page_size, interpret=interpret, q_block=rq,
+        page_size=page_size, interpret=interpret, q_block=rq, layer=layer,
     ).reshape(n, n_q, hd)
 
 
@@ -572,6 +649,7 @@ def paged_decode_attention_partial(
     page_size: int,
     pages_local: int,
     interpret: bool = False,
+    layer=None,  # with it the slices are [L, tokens, ...]: read layer `layer`
 ):
     """Flash partials over a LOCAL page slice (the kv-split walk of
     :func:`_decode_walk_kernel`); returns (acc, m, l), the shard_map
@@ -579,7 +657,7 @@ def paged_decode_attention_partial(
     axis and normalizes)."""
     acc, m, l = _decode_walk(q, k_local, v_local, page_tables, ctx_lens,
                              page_size, interpret, shard=my_pg,
-                             pages_local=pages_local)
+                             pages_local=pages_local, layer=layer)
     return acc, m[..., 0], l[..., 0]  # m/l are lane-padded: column 0
 
 
@@ -613,43 +691,54 @@ def tp_shardable(mesh, n_kv: int) -> bool:
 
 def paged_decode_attention_tp(
     mesh, q, k_flat, v_flat, page_tables, ctx_lens, page_size: int,
-    interpret: bool = False,
+    interpret: bool = False, layer=None,
 ) -> jnp.ndarray:
     """:func:`paged_decode_attention` over a TP mesh (heads sharded)."""
     from jax.sharding import PartitionSpec as P
 
     from runbookai_tpu.parallel.mesh import MODEL_AXIS
 
+    (k_flat, v_flat), layer = _stacked((k_flat, v_flat), layer)
     heads = P(None, MODEL_AXIS, None)
-    fn = functools.partial(paged_decode_attention, page_size=page_size,
-                           interpret=interpret)
+    kv_heads = P(None, None, MODEL_AXIS, None)
+
+    def fn(q, k, v, tables, ctx, layer):
+        return paged_decode_attention(
+            q, k, v, tables, ctx, page_size=page_size, interpret=interpret,
+            layer=layer)
+
     return jax.shard_map(
         fn, mesh=mesh,
-        in_specs=(heads, heads, heads, P(None, None), P(None)),
+        in_specs=(heads, kv_heads, kv_heads, P(None, None), P(None),
+                  P(None)),
         out_specs=heads,
         # pallas_call out_shapes carry no varying-mesh-axes info; the wrap
         # itself is collective-free so the vma check adds nothing here.
         check_vma=False,
-    )(q, k_flat, v_flat, page_tables, ctx_lens)
+    )(q, k_flat, v_flat, page_tables, ctx_lens, layer)
 
 
 def paged_chunk_attention_tp(
     mesh, q, k_flat, v_flat, page_tables, ctx_lens, q_positions,
-    page_size: int, interpret: bool = False,
+    page_size: int, interpret: bool = False, layer=None,
 ) -> jnp.ndarray:
     """:func:`paged_chunk_attention` over a TP mesh (heads sharded)."""
     from jax.sharding import PartitionSpec as P
 
     from runbookai_tpu.parallel.mesh import MODEL_AXIS
 
-    kv_heads = P(None, MODEL_AXIS, None)
-    q_heads = P(None, None, MODEL_AXIS, None)
-    fn = functools.partial(paged_chunk_attention, page_size=page_size,
-                           interpret=interpret)
+    (k_flat, v_flat), layer = _stacked((k_flat, v_flat), layer)
+    q_heads = kv_heads = P(None, None, MODEL_AXIS, None)
+
+    def fn(q, k, v, tables, ctx, q_positions, layer):
+        return paged_chunk_attention(
+            q, k, v, tables, ctx, q_positions, page_size=page_size,
+            interpret=interpret, layer=layer)
+
     return jax.shard_map(
         fn, mesh=mesh,
         in_specs=(q_heads, kv_heads, kv_heads, P(None, None), P(None),
-                  P(None, None)),
+                  P(None, None), P(None)),
         out_specs=q_heads,
         check_vma=False,
-    )(q, k_flat, v_flat, page_tables, ctx_lens, q_positions)
+    )(q, k_flat, v_flat, page_tables, ctx_lens, q_positions, layer)

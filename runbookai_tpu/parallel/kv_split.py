@@ -251,6 +251,7 @@ def paged_decode_attention_kv_split_pallas(
     ctx_lens: jnp.ndarray,
     page_size: int,
     interpret: bool = False,
+    layer=None,  # with it the pools are [L, tokens, ...]: read layer `layer`
 ) -> jnp.ndarray:
     """Decode attention on the page-split pool via the Pallas partial
     kernel: each device runs the kv-split walk of ``_decode_walk_kernel``
@@ -258,24 +259,26 @@ def paged_decode_attention_kv_split_pallas(
     nor counted; owned ones are indexed locally) and the flash partials
     merge across ``seq`` exactly like the XLA path."""
     from runbookai_tpu.ops.paged_attention_pallas import (
+        _stacked,
         paged_decode_attention_partial,
     )
 
+    (k_flat, v_flat), layer = _stacked((k_flat, v_flat), layer)
     pg_shards = mesh.shape.get(SEQ_AXIS, 1)
-    num_pages = k_flat.shape[0] // page_size
+    num_pages = k_flat.shape[1] // page_size
     if num_pages % pg_shards != 0:
         raise ValueError(
             f"num_pages={num_pages} must divide by pg_shards={pg_shards}")
     pages_local = num_pages // pg_shards
 
-    def local_fn(q_l, k_l, v_l, tables, ctx):
+    def local_fn(q_l, k_l, v_l, tables, ctx, layer):
         my_pg = jax.lax.axis_index(SEQ_AXIS)
         nql = q_l.shape[1]
         q_full = jax.lax.all_gather(q_l, SEQ_AXIS, axis=1, tiled=True)
         acc, m, l = paged_decode_attention_partial(
             q_full, k_l, v_l, tables, ctx, my_pg.astype(jnp.int32),
             page_size=page_size, pages_local=pages_local,
-            interpret=interpret)
+            interpret=interpret, layer=layer)
         m_g = jax.lax.pmax(m, SEQ_AXIS)
         corr = jnp.exp(m - m_g)
         l_g = jax.lax.psum(l * corr, SEQ_AXIS)
@@ -284,13 +287,13 @@ def paged_decode_attention_kv_split_pallas(
         return jax.lax.dynamic_slice_in_dim(out, my_pg * nql, nql, axis=1)
 
     heads = P(None, (MODEL_AXIS, SEQ_AXIS), None)
-    kv_spec = P(SEQ_AXIS, MODEL_AXIS, None)
+    kv_spec = P(None, SEQ_AXIS, MODEL_AXIS, None)
     return jax.shard_map(
         local_fn, mesh=mesh,
-        in_specs=(heads, kv_spec, kv_spec, P(None, None), P(None)),
+        in_specs=(heads, kv_spec, kv_spec, P(None, None), P(None), P(None)),
         out_specs=heads,
         check_vma=False,  # pallas out_shapes carry no vma info
-    )(q, k_flat, v_flat, page_tables, ctx_lens)
+    )(q, k_flat, v_flat, page_tables, ctx_lens, layer)
 
 
 # ----------------------------------------------------------------- write

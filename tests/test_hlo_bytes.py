@@ -21,7 +21,11 @@ arguments. These tests pin it to the COMPILED decode program instead:
   it was given, nor writes a whole layer through
   (`kv_pool_materializations`): the pool rides the layer scan's carry.
   Checked on the CPU's program and on the program compiled for a v5e
-  chip that is described, not attached.
+  chip that is described, not attached;
+- no step program whose pool the Pallas attention kernels read in place
+  owns one LAYER of the pool either (`kv_layer_slices`): the slice XLA
+  staged on chip in front of every attention call, at the dense cell's
+  real size too; a pool that fits on-chip memory keeps the slice.
 
 The on-device twins (real Mosaic, no interpret) live in
 ``test_pallas_on_device.py``.
@@ -35,6 +39,7 @@ import pytest
 from runbookai_tpu.engine.engine import EngineConfig, EngineCore
 from runbookai_tpu.engine.hlo_bytes import (
     decode_accounting,
+    kv_layer_slices,
     kv_pool_materializations,
     kv_pool_nbytes,
     layer_weight_copies,
@@ -51,7 +56,7 @@ from runbookai_tpu.models.llama import (
     init_params_quantized,
 )
 from runbookai_tpu.models.quant import LAYER_QUANT_KEYS, quantize_params
-from runbookai_tpu.ops import qmm_pallas
+from runbookai_tpu.ops import paged_attention_pallas, qmm_pallas
 from runbookai_tpu.utils.tokens import ByteTokenizer
 
 CFG = CONFIGS["llama3-test"]
@@ -327,6 +332,51 @@ def test_detector_flags_pool_scanned_in_and_stacked_out(kv_core):
         jax.jit(carried, donate_argnums=0).lower(k).compile(), kv_core) == []
 
 
+# The layer scan's body as the TPU's compiler wrote it while the Pallas
+# attention kernels took ``pool[li]`` (the lines of ``_decode_multi``
+# compiled for a described v5e, shortened, at ``kv_core``'s dims): the
+# slice is a fusion that OWNS one layer of the pool, staged on chip
+# (``S(1)``), and the kernel's page view a bitcast of it.
+_STAGED_BODY = """
+%fused_computation.63.clone (param_0.1: f32[4,2048,2,16], param_1.2: s32[]) -> f32[1,2048,2,16] {
+  %param_0.1 = f32[4,2048,2,16]{3,2,1,0:T(2,128)} parameter(0)
+  %param_1.2 = s32[]{:T(128)} parameter(1)
+  ROOT %dynamic-slice.9 = f32[1,2048,2,16]{3,2,1,0:T(2,128)} dynamic-slice(%param_0.1, %param_1.2, %c, %c, %c), dynamic_slice_sizes={1,2048,2,16}
+}
+
+%region_1.17 (arg_tuple.1: (s32[], f32[4,2048,2,16])) -> (s32[], f32[4,2048,2,16]) {
+  %arg_tuple.1 = (s32[], f32[4,2048,2,16]) parameter(0)
+  %get-tuple-element.2054 = s32[]{:T(128)} get-tuple-element(%arg_tuple.1), index=0
+  %get-tuple-element.2103 = f32[4,2048,2,16]{3,2,1,0:T(2,128)} get-tuple-element(%arg_tuple.1), index=1
+  %constant_dynamic-slice_fusion.15 = f32[1,2048,2,16]{3,2,1,0:T(2,128)S(1)} fusion(%get-tuple-element.2103, %get-tuple-element.2054), kind=kLoop, calls=%fused_computation.63.clone
+  %bitcast.249 = f32[512,8,16]{2,1,0:T(8,128)S(1)} bitcast(%constant_dynamic-slice_fusion.15)
+  %closed_call.16 = f32[4,4,16]{2,1,0:T(8,128)S(1)} custom-call(%copy-done, %get-tuple-element.2104, %bitcast.249), custom_call_target="tpu_custom_call"
+}
+"""
+
+
+def test_detector_flags_a_layer_sliced_out_of_the_pool(kv_core):
+    """The detector names the fusion that owns one layer of the pool —
+    not its page view, nor the values inside the fusion's body — and is
+    silent once the kernel's operand is a page view of the carried pool
+    itself."""
+    from types import SimpleNamespace
+
+    bad = kv_layer_slices(SimpleNamespace(as_text=lambda: _STAGED_BODY),
+                          kv_core)
+    assert [ln.split()[0] for ln in bad] == [
+        "%constant_dynamic-slice_fusion.15"]
+    in_place = "\n".join(
+        ln.replace("f32[512,8,16]", "f32[2048,8,16]").replace(
+            "bitcast(%constant_dynamic-slice_fusion.15)",
+            "bitcast(%get-tuple-element.2103)")
+        for ln in _STAGED_BODY.splitlines()
+        if not ln.lstrip().startswith("%constant_dynamic-slice_fusion.15"))
+    assert "f32[2048,8,16]{2,1,0:T(8,128)S(1)} bitcast(%get-tuple" in in_place
+    assert kv_layer_slices(SimpleNamespace(as_text=lambda: in_place),
+                           kv_core) == []
+
+
 def _assert_one_pool(compiled, core):
     bad = kv_pool_materializations(compiled, core)
     assert bad == [], "\n".join(bad)
@@ -386,6 +436,66 @@ def test_step_program_for_the_chip_owns_no_second_kv_pool(
                             sharding=one_chip)
     assert "tpu_custom_call" in compiled.as_text()
     _assert_one_pool(compiled, chip_core)
+    # ... and no layer of it: the kernels read this pool where it lies.
+    assert paged_attention_pallas.reads_in_place(chip_core._kv_k)
+    bad = kv_layer_slices(compiled, chip_core)
+    assert bad == [], "\n".join(bad)
+
+
+def test_a_pool_that_fits_on_chip_keeps_the_layer_slice(
+        one_chip, chip_core, monkeypatch):
+    """The control, RED, and the other side of the shape rule: a stacked
+    operand that fits on-chip memory XLA would prefetch there WHOLE before
+    every call, so of such a pool the kernels get the layer's slice (the
+    same kernel at L = 1) and the program owns a K and a V layer. The
+    budget is read while a program is traced."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(paged_attention_pallas, "_ON_CHIP_BYTES", 1 << 40)
+    assert not paged_attention_pallas.reads_in_place(chip_core._kv_k)
+    jax.clear_caches()
+    try:
+        compiled = lower_decode(chip_core, program="_decode_multi",
+                                attn_impl="pallas", sharding=one_chip)
+    finally:
+        jax.clear_caches()
+    assert len(kv_layer_slices(compiled, chip_core)) >= 2
+    _assert_one_pool(compiled, chip_core)
+
+
+@pytest.fixture(scope="module")
+def dense_cell_core():
+    """`qwen7b.chat-open`'s engine at its real size (Qwen2.5-7B-Instruct
+    int8, 28 layers, 16 slots, a table 513 wide, 3072 pages: pools of
+    ``bf16[28, 49152, 4, 128]``, 1.41 GB a side), the weights zeros."""
+    cfg = CONFIGS["qwen2.5-7b-instruct"]
+    shapes = jax.eval_shape(lambda: init_params_quantized(
+        jax.random.PRNGKey(0), cfg, dtype=jnp.bfloat16))
+    params = jax.tree.map(lambda a: jnp.zeros(a.shape, a.dtype), shapes)
+    return EngineCore(cfg, params, ByteTokenizer(), EngineConfig(
+        page_size=16, num_pages=3072, max_batch_slots=16, prefill_chunk=512,
+        max_seq_len=8192, kv_dtype=jnp.bfloat16, attn_impl="pallas",
+        qmm_impl="pallas", mixed_dispatch=True, decode_steps_per_dispatch=8))
+
+
+@pytest.mark.parametrize("program", STEP_PROGRAMS)
+def test_dense_cell_step_program_stages_no_layer_of_the_pool(
+        one_chip, dense_cell_core, program, monkeypatch):
+    """What `qwen7b.chat-open` runs, compiled by the chip's own compiler:
+    no ``bf16[1, 49152, 4, 128]`` buffer (two of them, 50 MB each, were
+    staged on chip in front of every attention call: 3.7 ms of a 19.1 ms
+    pass), no second pool, and temporaries far under one layer's slice."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    core = dense_cell_core
+    assert core._kv_k.shape == (28, 49152, 4, 128)
+    compiled = lower_decode(core, program=program, sharding=one_chip)
+    txt = compiled.as_text()
+    # The page table is the attention call's first operand.
+    rows = 80 if program == "_mixed_step" else 16
+    assert f"operand_layout_constraints={{s32[{rows},513]" in txt
+    assert "49152,4,128]" not in txt.replace("[28,49152,4,128]", "")
+    bad = (kv_layer_slices(compiled, core)
+           + kv_pool_materializations(compiled, core))
+    assert bad == [], "\n".join(bad)
 
 
 @pytest.fixture(scope="module")

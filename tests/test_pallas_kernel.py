@@ -388,6 +388,153 @@ def test_chunk_walk_under_the_tpu_interpreter():
                        interpret=pltpu.InterpretParams())
 
 
+# ------------------------------------------------- the pool where it lies
+#
+# The kernels take the pool the layer scan carries, ``[L, tokens, n_kv,
+# hd]``, and the layer's number (a traced scalar in the scan's body), where
+# they took ``pool[layer]``. The cases give layer ``layer`` of a 3-layer
+# pool a case's pages and fill the two other layers with poison (NaN; an
+# int8 pool's scales): the call must equal the one on the layer's slice
+# BIT FOR BIT, so a walk that read another layer's page — layer 0 for every
+# layer, say — turns NaN and fails.
+
+LAYERS = [0, 1, 2]
+
+
+def _in_layer(pool, layer):
+    """``pool [tokens, ...]`` (or an int8 pool's pair) as layer ``layer``
+    of three, the other two poison."""
+    def stack(a):
+        poison = (jnp.full_like(a, jnp.nan) if a.dtype == jnp.float32
+                  else jnp.full_like(a, 77))
+        return jnp.stack([a if i == layer else poison for i in range(3)])
+    return jax.tree.map(stack, pool)
+
+
+def _int8_pool(k, v):
+    """The raw float pool as (int8 values, float32 scales) pairs."""
+    from runbookai_tpu.ops.attention import quantize_kv
+
+    return tuple(quantize_kv(jnp.nan_to_num(a)) for a in (k, v))
+
+
+@pytest.mark.parametrize("layer", LAYERS)
+@pytest.mark.parametrize("pool", WALK_POOLS)
+def test_decode_walk_reads_its_layer_of_the_pool(pool, layer):
+    """Decode, every kind of pool, an empty row first, in the middle and
+    last: the pool-and-layer call against the call on the slice."""
+    from runbookai_tpu.ops.paged_attention_pallas import (
+        paged_decode_attention_partial,
+    )
+
+    q, k, v, tables, ctx, _, _ = _walk_case([0, 9, 0, 0, 150, 0], 4, 2)
+    if pool == "int8":
+        k, v = _int8_pool(k, v)
+    if pool == "partial":
+        local = k.shape[0] // 2  # the second shard's page slice
+        k, v = k[local:], v[local:]
+
+        def walk(k, v, layer=None):
+            return paged_decode_attention_partial(
+                q, k, v, tables, ctx, jnp.int32(1), page_size=WALK_PS,
+                pages_local=local // WALK_PS, interpret=True, layer=layer)
+    else:
+        def walk(k, v, layer=None):
+            return paged_decode_attention(q, k, v, tables, ctx,
+                                          page_size=WALK_PS, interpret=True,
+                                          layer=layer)
+    want = walk(k, v)
+    got = jax.jit(walk)(_in_layer(k, layer), _in_layer(v, layer),
+                        jnp.int32(layer))
+    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        assert not np.isnan(np.asarray(g)).any()
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+
+
+@pytest.mark.parametrize("layer", LAYERS)
+@pytest.mark.parametrize("entry", ["chunk", "ragged"])
+def test_chunk_walk_reads_its_layer_of_the_pool(entry, layer):
+    """The chunk kernel as ``_prefill_step`` calls it and, through
+    ``paged_ragged_attention``, over a mixed step's flat buffer: pad
+    blocks first, in the middle and last."""
+    from runbookai_tpu.ops.paged_attention_pallas import (
+        paged_chunk_attention,
+        paged_ragged_attention,
+    )
+
+    pad = (0, 0, 0)
+    q, k, v, tables, ctx, positions, _ = _chunk_case(
+        [pad, (149, 1, 150), pad, pad, (128, 8, 150), (3, 8, 11), pad],
+        n_kv=2, group=2, tq=8)
+    if entry == "chunk":
+        def walk(k, v, layer=None):
+            return paged_chunk_attention(
+                q, k, v, tables, ctx, positions, page_size=WALK_PS,
+                interpret=True, q_block=8, layer=layer)
+    else:
+        nb, rq = positions.shape
+        flat_q = q.reshape(nb * rq, *q.shape[2:])
+        rows = jnp.repeat(jnp.arange(nb, dtype=jnp.int32), rq)
+
+        def walk(k, v, layer=None):
+            return paged_ragged_attention(
+                flat_q, k, v, tables, ctx, positions.reshape(-1), rows,
+                page_size=WALK_PS, ragged_block=rq, interpret=True,
+                layer=layer)
+    want = np.asarray(walk(k, v))
+    got = np.asarray(jax.jit(walk)(_in_layer(k, layer), _in_layer(v, layer),
+                                   jnp.int32(layer)))
+    live = ~np.isnan(want)  # a row's trash-position pads read poison
+    assert live.any() and np.array_equal(np.isnan(got), ~live)
+    np.testing.assert_array_equal(got[live], want[live])
+
+
+@pytest.mark.parametrize("entry", ["decode", "int8", "chunk"])
+def test_a_one_layer_pool_is_the_same_kernel_at_one_layer(entry):
+    """``layer=None`` on ``[tokens, n_kv, hd]`` is L = 1, layer 0."""
+    from runbookai_tpu.ops.paged_attention_pallas import paged_chunk_attention
+
+    if entry == "chunk":
+        q, k, v, tables, ctx, positions, _ = _chunk_case(
+            [(0, 0, 0), (128, 8, 150)], n_kv=2, group=2, tq=8)
+
+        def walk(k, v, layer=None):
+            return paged_chunk_attention(
+                q, k, v, tables, ctx, positions, page_size=WALK_PS,
+                interpret=True, q_block=8, layer=layer)[1]
+    else:
+        q, k, v, tables, ctx, _, _ = _walk_case([0, 41], 4, 2)
+        if entry == "int8":
+            k, v = _int8_pool(k, v)
+
+        def walk(k, v, layer=None):
+            return paged_decode_attention(q, k, v, tables, ctx,
+                                          page_size=WALK_PS, interpret=True,
+                                          layer=layer)
+    one = jax.tree.map(lambda a: a[None], (k, v))
+    got, want = np.asarray(walk(*one, layer=0)), np.asarray(walk(k, v))
+    assert not np.isnan(want).any()
+    np.testing.assert_array_equal(got, want)
+
+
+def test_which_pools_are_read_in_place():
+    """In place: a pool that cannot fit on-chip memory and whose page
+    views are the bytes as they lie. Static, by shape alone."""
+    from jax import ShapeDtypeStruct as S
+
+    from runbookai_tpu.ops.paged_attention_pallas import reads_in_place
+
+    bf16, f8 = jnp.bfloat16, jnp.float8_e4m3fn
+    assert reads_in_place(S((28, 49152, 4, 128), bf16))  # the 7B cell
+    assert reads_in_place(S((32, 49152, 8, 128), f8))  # Llama-3-8B, fp8
+    assert reads_in_place(S((28, 196608, 1, 128), bf16))  # a tp 4 shard
+    assert not reads_in_place(S((2, 2048, 4, 128), bf16))  # fits on chip
+    assert not reads_in_place(S((28, 49152, 4, 64), bf16))  # lanes padded
+    assert not reads_in_place(S((28, 98304, 2, 128), f8))  # heads padded
+    assert not reads_in_place(  # an int8 pool: its scales are padded
+        (S((28, 49152, 4, 128), jnp.int8), S((28, 49152, 4), jnp.float32)))
+
+
 def _build_pool(rng, ctx_lens_list, n_kv, hd, ps, pages, max_pages):
     kf = jnp.zeros((pages * ps, n_kv, hd), jnp.float32)
     vf = jnp.zeros((pages * ps, n_kv, hd), jnp.float32)

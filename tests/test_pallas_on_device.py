@@ -751,6 +751,132 @@ def test_ragged_walk_at_the_cells_shape():
     assert np.all(got[empty] == 0.0)  # and so does an empty slot
 
 
+# The cell's pool where it lies: ``bf16[28, 49152, 4, 128]``, the layer a
+# traced scalar as in the layer scan's body, against the same call on the
+# layer's slice (what the scan handed the kernels before), BIT FOR BIT.
+# The layers tried hold the case's pages, each scaled differently; every
+# other layer is poison (NaN; an int8 pool's scales), so a walk that read
+# another layer's page fails. That Mosaic takes the page view with the two
+# leading axes merged, 86,016 pages, is what these prove.
+CELL_LAYERS = [0, 13, 27]
+CELL_PAGES = 3072
+
+
+def _in_cells_pool(pool):
+    """``pool [49152, ...]`` (or an int8 pool's pair) as layers 0, 13 and
+    27 of 28, times 1, 2 and 3; the other layers poison."""
+    def stack(a):
+        if a.dtype == jnp.int8:  # values: any; their scales are the poison
+            out = jnp.full((28, *a.shape), 77, a.dtype)
+            return out.at[jnp.asarray(CELL_LAYERS)].set(a)
+        out = jnp.full((28, *a.shape), jnp.nan, a.dtype)
+        for i, layer in enumerate(CELL_LAYERS):
+            out = out.at[layer].set(a * (i + 1))
+        return out
+    return jax.tree.map(stack, pool)
+
+
+def _assert_same_as_on_the_slice(walk, k, v):
+    k, v = _in_cells_pool(k), _in_cells_pool(v)
+    assert jax.tree.leaves(k)[0].shape == (28, CELL_PAGES * PS, 4, 128)
+    in_place = jax.jit(walk)
+    outs = []
+    for layer in CELL_LAYERS:
+        got = in_place(k, v, jnp.int32(layer))
+        want = walk(*jax.tree.map(lambda a: a[layer], (k, v)))
+        for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+            np.testing.assert_array_equal(np.asarray(g, np.float32),
+                                          np.asarray(w, np.float32))
+        outs.append(np.asarray(jax.tree.leaves(got)[0], np.float32))
+    assert not np.array_equal(outs[0], outs[1])  # the layers do differ
+    return outs[0]
+
+
+@pytest.mark.usefixtures("serving_precision")
+@pytest.mark.parametrize("pool", ["raw", "int8", "partial"])
+def test_decode_walk_reads_its_layer_of_the_cells_pool(pool):
+    """The decode dispatch of ``test_decode_walk_at_the_cells_shape`` (16
+    rows, a table 513 wide, one row at 8192 tokens, five empty) for every
+    kind of pool."""
+    from runbookai_tpu.ops.attention import quantize_kv
+    from runbookai_tpu.ops.paged_attention_pallas import (
+        paged_decode_attention_partial,
+    )
+
+    rng = np.random.default_rng(15)
+    k, v, tables = _poison_pool(rng, CELL_CTX, num_pages=CELL_PAGES)
+    q = jnp.asarray(rng.normal(size=(len(CELL_CTX), 28, 128)), jnp.bfloat16)
+    ctx = jnp.asarray(CELL_CTX, jnp.int32)
+    if pool == "int8":
+        dead = jnp.isnan(k[:, :, 0].astype(jnp.float32))
+        (kq, ks), (vq, vs) = (quantize_kv(jnp.nan_to_num(a)) for a in (k, v))
+        k = (kq, jnp.where(dead, jnp.nan, ks))
+        v = (vq, jnp.where(dead, jnp.nan, vs))
+
+    def walk(k, v, layer=None):
+        if pool != "partial":
+            return paged_decode_attention(q, k, v, tables, ctx, page_size=PS,
+                                          interpret=False, layer=layer)
+        # The second of two shards: its page slice of every layer.
+        half = k.shape[-3] // 2
+        return paged_decode_attention_partial(
+            q, k[..., half:, :, :], v[..., half:, :, :], tables, ctx,
+            jnp.int32(1), page_size=PS, pages_local=half // PS,
+            interpret=False, layer=layer)
+
+    got = _assert_same_as_on_the_slice(walk, k, v)
+    live = np.asarray(CELL_CTX) > 0
+    assert not np.isnan(got).any() and np.all(got[~live] == 0.0)
+
+
+@pytest.mark.usefixtures("serving_precision")
+def test_ragged_walk_reads_its_layer_of_the_cells_pool():
+    """The mixed dispatch of ``test_ragged_walk_at_the_cells_shape``: 80
+    eight-token blocks over the cell's pool."""
+    from runbookai_tpu.engine.engine import _RAGGED_BLOCK as RQ
+    from runbookai_tpu.ops.paged_attention_pallas import (
+        paged_ragged_attention,
+    )
+
+    rng = np.random.default_rng(16)
+    rows_ctx, positions, row_ids, real, pads = _mixed_layout(
+        CELL_CTX, [(1024, 300), (0, 200)], RQ)
+    assert len(positions) // RQ == 80
+    k, v, tables = _poison_pool(rng, rows_ctx, num_pages=CELL_PAGES)
+    q = jnp.asarray(rng.normal(size=(len(positions), 28, 128)), jnp.bfloat16)
+    rest = (tables, jnp.asarray(rows_ctx, jnp.int32),
+            jnp.asarray(positions), jnp.asarray(row_ids))
+
+    def walk(k, v, layer=None):
+        return paged_ragged_attention(
+            q, k, v, *rest, page_size=PS, ragged_block=RQ, interpret=False,
+            layer=layer)[jnp.asarray(real + list(range(pads, len(positions))))]
+
+    got = _assert_same_as_on_the_slice(walk, k, v)
+    assert not np.isnan(got).any() and np.all(got[len(real):] == 0.0)
+
+
+@pytest.mark.usefixtures("serving_precision")
+def test_chunk_walk_reads_its_layer_of_the_cells_pool():
+    """``_prefill_step``'s call: a 512-token chunk behind 1,024 cached
+    tokens and the start of a prompt, at the query block the engine gets
+    (32 rows), over the cell's pool."""
+    rng = np.random.default_rng(17)
+    t, ctx_lens = 512, [1536, 512]
+    k, v, tables = _poison_pool(rng, ctx_lens, num_pages=CELL_PAGES)
+    ctx = jnp.asarray(ctx_lens, jnp.int32)
+    positions = jnp.stack(
+        [jnp.arange(c - t, c, dtype=jnp.int32) for c in ctx_lens])
+    q = jnp.asarray(rng.normal(size=(2, t, 28, 128)), jnp.bfloat16)
+
+    def walk(k, v, layer=None):
+        return paged_chunk_attention(q, k, v, tables, ctx, positions,
+                                     page_size=PS, interpret=False,
+                                     layer=layer)
+
+    assert not np.isnan(_assert_same_as_on_the_slice(walk, k, v)).any()
+
+
 @pytest.mark.usefixtures("serving_precision")
 @pytest.mark.parametrize("k,n", [(3584, 3584), (3584, 512),
                                  (3584, 18944), (18944, 3584)])

@@ -313,3 +313,63 @@ def test_engine_int8_dp_mesh_serves(setup, in_place, monkeypatch):
     got = greedy(make_core(tok, rep, mesh=dp_mesh, qmm_impl="pallas"),
                  prompts)
     assert got[0].out_ids == ref[0].out_ids
+
+
+# ------------------------------------------------------------------ #
+# The Pallas attention kernels read the carried pool where it lies    #
+# ------------------------------------------------------------------ #
+
+LANE_CFG = type(CFG)(
+    name="pool-in-place-test", vocab_size=262, dim=512, n_layers=3,
+    n_heads=4, n_kv_heads=2, ffn_dim=256, max_seq_len=256,
+    rope_theta=10_000.0)
+
+
+@pytest.mark.parametrize("layout", ["one_device", "tp2", "kv_split_2x2"])
+def test_engine_reads_the_pool_in_place(layout, monkeypatch):
+    """The full cycle (chunked prefill, mixed steps, multi-step decode)
+    with the Pallas kernels handed the whole ``[L, tokens, n_kv, hd]`` pool and the
+    layer's number — alone, per head shard under ``shard_map`` and on the
+    page-split mesh's partial kernel — equals the XLA engine's greedy
+    tokens. Heads of 128 (a page view that is the bytes as they lie); the
+    size rule, which at this size says "slice", is set aside as the qmm
+    tests set theirs aside. It is read while a program is traced."""
+    from runbookai_tpu.ops import paged_attention_pallas
+
+    monkeypatch.setattr(paged_attention_pallas, "_ON_CHIP_BYTES", 0)
+    jax.clear_caches()
+    tok = ByteTokenizer()
+    params = init_params(jax.random.PRNGKey(0), LANE_CFG, dtype=jnp.float32)
+    mesh = {"one_device": None, "tp2": build_mesh(1, 2),
+            "kv_split_2x2": build_mesh(1, model=2, seq=2)}[layout]
+    placed = params if mesh is None else jax.tree.map(
+        jax.device_put, params, param_shardings(LANE_CFG, mesh))
+
+    def core(params, mesh=None, **kw):
+        return EngineCore(LANE_CFG, params, tok, EngineConfig(
+            page_size=4, num_pages=64, max_batch_slots=4, prefill_chunk=8,
+            max_seq_len=128, block_pages=4, kv_dtype=jnp.float32, **kw),
+            mesh=mesh)
+
+    prompts = [tok.encode("investigate high latency in checkout"),
+               tok.encode("pods crashlooping"),
+               tok.encode("error rate spike after the deploy of payments")]
+    try:
+        ref = greedy(core(params), prompts, max_new=24)
+        served = core(placed, mesh=mesh, attn_impl="pallas",
+                      mixed_dispatch=True)  # (off again on a page split)
+        assert paged_attention_pallas.reads_in_place(served._kv_k, mesh)
+        # The first request decodes while the others prefill beside it.
+        got = [EngineRequest(prompt_ids=list(p), sampling=SamplingParams(
+            temperature=0.0, max_new_tokens=24)) for p in prompts]
+        served.submit(got[0])
+        while not served.decoding:
+            served.step()
+        for r in got[1:]:
+            served.submit(r)
+        served.run_until_idle()
+    finally:
+        jax.clear_caches()
+    assert served.metrics["mixed_steps"] > 0 or layout == "kv_split_2x2"
+    for r, g in zip(ref, got):
+        assert g.out_ids == r.out_ids
