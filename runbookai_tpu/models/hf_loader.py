@@ -70,6 +70,10 @@ def config_from_hf(model_dir: str | Path, name: str = "hf-model") -> LlamaConfig
         raise NotImplementedError(
             f"model_type {model_type!r}: the joyai family runs on seeded "
             f"random weights only (models/joyai.py); no checkpoint loader yet")
+    if model_type.startswith("nemotron_h"):
+        raise NotImplementedError(
+            f"model_type {model_type!r}: the nemotron-h family runs on seeded "
+            f"random weights only (models/nemotron_h.py); no checkpoint loader yet")
     if model_type not in SUPPORTED_MODEL_TYPES:
         raise ValueError(
             f"model_type {model_type!r} not supported; known: "
@@ -284,8 +288,15 @@ def load_or_init(
     """
     from runbookai_tpu.models.joyai import JoyaiConfig
     from runbookai_tpu.models.longcat import LongcatConfig
+    from runbookai_tpu.models.nemotron_h import NemotronHConfig
     from runbookai_tpu.models.qwen3_next import Qwen3NextConfig
 
+    nemotron_h = isinstance(CONFIGS.get(model_name), NemotronHConfig)
+    if nemotron_h and model_path and Path(model_path).exists():
+        raise NotImplementedError(
+            f"model {model_name!r}: no loader for checkpoints of the "
+            f"nemotron-h family yet (Mamba-2 mixer and expert tensor names); "
+            f"leave llm.model_path unset to serve seeded random weights")
     joyai = isinstance(CONFIGS.get(model_name), JoyaiConfig)
     if joyai and model_path and Path(model_path).exists():
         raise NotImplementedError(
@@ -331,13 +342,15 @@ def load_or_init(
             f"unknown model {model_name!r} and no checkpoint at "
             f"{str(model_path)!r}; known configs: {sorted(CONFIGS)}")
     cfg = CONFIGS[model_name]
-    if longcat or qwen3_next or joyai:
+    if longcat or qwen3_next or joyai or nemotron_h:
         from runbookai_tpu.models import joyai as joyai_model
         from runbookai_tpu.models import longcat as longcat_model
+        from runbookai_tpu.models import nemotron_h as nemotron_h_model
         from runbookai_tpu.models import qwen3_next as qwen3_next_model
 
         model, family = ((longcat_model, "longcat") if longcat else
                          (qwen3_next_model, "qwen3-next") if qwen3_next else
+                         (nemotron_h_model, "nemotron-h") if nemotron_h else
                          (joyai_model, "joyai"))
         if quantize_int8 or shardings:
             raise ValueError(

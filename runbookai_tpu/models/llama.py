@@ -38,6 +38,8 @@ from runbookai_tpu.models.joyai import CONFIGS as _JOYAI_CONFIGS
 from runbookai_tpu.models.joyai import JoyaiConfig
 from runbookai_tpu.models.longcat import CONFIGS as _LONGCAT_CONFIGS
 from runbookai_tpu.models.longcat import LongcatConfig
+from runbookai_tpu.models.nemotron_h import CONFIGS as _NEMOTRON_H_CONFIGS
+from runbookai_tpu.models.nemotron_h import NemotronHConfig
 from runbookai_tpu.models.qwen3_next import CONFIGS as _QWEN3_NEXT_CONFIGS
 from runbookai_tpu.models.qwen3_next import Qwen3NextConfig
 from runbookai_tpu.ops.attention import paged_attention, write_kv_pages_batch
@@ -144,7 +146,10 @@ class LlamaConfig:
                 + ffn_delta)
 
 
-CONFIGS: dict[str, LlamaConfig | LongcatConfig | Qwen3NextConfig | JoyaiConfig] = {
+AnyConfig = (LlamaConfig | LongcatConfig | Qwen3NextConfig | JoyaiConfig
+             | NemotronHConfig)
+
+CONFIGS: dict[str, AnyConfig] = {
     "llama3-8b-instruct": LlamaConfig(
         name="llama3-8b-instruct", vocab_size=128_256, dim=4096, n_layers=32,
         n_heads=32, n_kv_heads=8, ffn_dim=14_336,
@@ -258,10 +263,13 @@ CONFIGS: dict[str, LlamaConfig | LongcatConfig | Qwen3NextConfig | JoyaiConfig] 
     # A leading dense layer, then expert layers, and the model's own
     # prediction module as the drafter (models/joyai.py).
     **_JOYAI_CONFIGS,
+    # A pattern of single-mixer layers: Mamba-2 state-space layers beside
+    # position-free attention and two-matrix experts (models/nemotron_h.py).
+    **_NEMOTRON_H_CONFIGS,
 }
 
 
-def get_config(name: str) -> LlamaConfig | LongcatConfig | Qwen3NextConfig | JoyaiConfig:
+def get_config(name: str) -> AnyConfig:
     if name not in CONFIGS:
         raise KeyError(f"Unknown model {name!r}; known: {sorted(CONFIGS)}")
     return CONFIGS[name]

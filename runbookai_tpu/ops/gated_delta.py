@@ -119,18 +119,21 @@ def chunk_gated_delta(q, k, v, g, beta, state, block: int = BLOCK):
     return o.reshape(b, t, h, dv), state
 
 
-def causal_conv_tail(x, tail, weight, n_live):
+def causal_conv_tail(x, tail, weight, n_live, bias=None):
     """Causal depthwise convolution of width ``W`` over a run, then SiLU.
     ``x`` [B, T, C] the run's inputs, ``tail`` [B, W - 1, C] the inputs
     before it, ``weight`` [W, C] (``weight[W - 1]`` multiplies the current
     input), ``n_live`` [B] how many of the run's tokens are real (they come
-    first). Returns (y [B, T, C] in ``x``'s dtype, tail' [B, W - 1, C]: the
+    first), ``bias`` [C] added before the SiLU where the layer has one.
+    Returns (y [B, T, C] in ``x``'s dtype, tail' [B, W - 1, C]: the
     last ``W - 1`` inputs up to the last real token)."""
     width = weight.shape[0]
     t = x.shape[1]
     full = jnp.concatenate([tail.astype(x.dtype), x], axis=1)    # [B, T + W - 1, C]
     y = sum(full[:, i:i + t].astype(jnp.float32) * weight[i].astype(jnp.float32)
             for i in range(width))
+    if bias is not None:
+        y = y + bias.astype(jnp.float32)
     idx = n_live[:, None] + jnp.arange(width - 1)[None, :]       # [B, W - 1]
     new_tail = jnp.take_along_axis(full, idx[..., None], axis=1)
     return jax.nn.silu(y).astype(x.dtype), new_tail.astype(tail.dtype)

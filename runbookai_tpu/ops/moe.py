@@ -152,15 +152,25 @@ def route_sigmoid(
     return chosen, scale * w / jnp.sum(w, axis=-1, keepdims=True)
 
 
-def shared_expert(u: jnp.ndarray, w_gate: Any, w_up: Any, w_down: Any,
-                  gate: Optional[jnp.ndarray] = None) -> jnp.ndarray:
-    """``SwiGLU_shared(u)``, the expert every token runs — under its own
-    sigmoid gate, ``sigmoid(u w_sg)``, where the model has one (``gate``
-    [D, 1]). Every share of an expert-parallel group computes it for its
-    own tokens; a sum over the shares counts it once."""
+def expert_ffn(x: jnp.ndarray, w_gate: Any, w_up: Any, w_down: Any) -> jnp.ndarray:
+    """One expert's two forms: ``SwiGLU`` — ``(silu(x W_gate) * x W_up)
+    W_down`` — or, where the model's experts have two matrices (``w_gate``
+    None), ``relu(x W_up)^2 W_down``. Leading axes batch (``qmm``)."""
     from runbookai_tpu.models.llama import qmm  # deferred: models->ops cycle
 
-    y = qmm(jax.nn.silu(qmm(u, w_gate)) * qmm(u, w_up), w_down)
+    if w_gate is None:
+        with jax.named_scope("moe.relu2"):
+            return qmm(jnp.square(jax.nn.relu(qmm(x, w_up))), w_down)
+    return qmm(jax.nn.silu(qmm(x, w_gate)) * qmm(x, w_up), w_down)
+
+
+def shared_expert(u: jnp.ndarray, w_gate: Any, w_up: Any, w_down: Any,
+                  gate: Optional[jnp.ndarray] = None) -> jnp.ndarray:
+    """The expert every token runs (:func:`expert_ffn`'s form) — under its
+    own sigmoid gate, ``sigmoid(u w_sg)``, where the model has one (``gate``
+    [D, 1]). Every share of an expert-parallel group computes it for its
+    own tokens; a sum over the shares counts it once."""
+    y = expert_ffn(u, w_gate, w_up, w_down)
     if gate is None:
         return y
     g = jax.nn.sigmoid(u.astype(jnp.float32) @ gate.astype(jnp.float32))
@@ -183,14 +193,16 @@ def held_expert_ffn(
     u: jnp.ndarray,            # [N, D]
     local: jnp.ndarray,        # [N, K] chosen expert as an index into the held ones; n_held = not held here
     weights: jnp.ndarray,      # [N, K] f32 gate weights of the choices
-    w_gate: Any,               # [E_held, D, F], or [L, E_held, D, F] with ``layer``
+    w_gate: Any,               # [E_held, D, F], or [L, E_held, D, F] with ``layer``; None: two-matrix experts
     w_up: Any,                 # [E_held, D, F]
     w_down: Any,               # [E_held, F, D]
     cap: int,
     layer=None,                # traced scalar: which layer of stacked weights
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
-    """``sum_{chosen j held here} w_j SwiGLU_j(u)``, [N, D] float32, and
-    whether the call took the slow path (int32 0 or 1). Dropless.
+    """``sum_{chosen j held here} w_j FFN_j(u)``, [N, D] float32, and
+    whether the call took the slow path (int32 0 or 1). Dropless. An
+    expert is :func:`expert_ffn`: SwiGLU, or ``relu(u W_up)^2 W_down``
+    where ``w_gate`` is None — one dispatch for both.
 
     Only pairs that fell on a held expert are dispatched, so the cost does
     not grow with the experts that live elsewhere. Fast path: every held
@@ -207,24 +219,22 @@ def held_expert_ffn(
     a ``lax.cond`` materialises its operands, so a layer's slice taken
     outside would be copied (three matrices of every held expert, each
     pass); taken inside a branch it is read in place by the product."""
-    from runbookai_tpu.models.llama import qmm  # deferred: models->ops cycle
-
     n, d = u.shape
     k = local.shape[1]
-    e = jax.tree.leaves(w_gate)[0].shape[0 if layer is None else 1]
+    e = jax.tree.leaves(w_up)[0].shape[0 if layer is None else 1]
     flat = local.reshape(-1)                                        # [N*K]
     onehot = jax.nn.one_hot(flat, e, dtype=jnp.int32)               # not held -> zeros
     slot = jnp.sum((jnp.cumsum(onehot, axis=0) - onehot) * onehot, axis=-1)
     fits = jnp.max(jnp.sum(onehot, axis=0)) <= cap
 
-    def swiglu(x, idx=None):
+    def ffn(x, idx=None):
         at = tuple(i for i in (layer, idx) if i is not None)
 
         def pick(w):
             return jax.tree.map(lambda a: a[at], w) if at else w
 
-        act = jax.nn.silu(qmm(x, pick(w_gate))) * qmm(x, pick(w_up))
-        return qmm(act, pick(w_down))
+        return expert_ffn(x, None if w_gate is None else pick(w_gate),
+                          pick(w_up), pick(w_down))
 
     def slotted(_):
         dest = jnp.where((flat < e) & (slot < cap), flat * cap + slot, e * cap)
@@ -233,7 +243,7 @@ def held_expert_ffn(
         w_slot = jnp.zeros((e * cap + 1,), jnp.float32).at[dest].set(
             weights.reshape(-1))[:-1]
         u_pad = jnp.concatenate([u, jnp.zeros((1, d), u.dtype)])
-        out_e = swiglu(u_pad[src].reshape(e, cap, d)).reshape(e * cap, d)
+        out_e = ffn(u_pad[src].reshape(e, cap, d)).reshape(e * cap, d)
         out = jnp.zeros((n + 1, d), jnp.float32).at[src].add(
             out_e.astype(jnp.float32) * w_slot[:, None])
         return out[:n]
@@ -243,7 +253,7 @@ def held_expert_ffn(
             jnp.arange(n)[:, None], local].add(weights)[:, :e]     # [N, E_held]
 
         def one(acc, i):
-            return acc + swiglu(u, i).astype(jnp.float32) * w_dense[:, i, None], None
+            return acc + ffn(u, i).astype(jnp.float32) * w_dense[:, i, None], None
 
         acc, _ = jax.lax.scan(one, jnp.zeros((n, d), jnp.float32),
                               jnp.arange(e))
