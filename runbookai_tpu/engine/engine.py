@@ -57,7 +57,7 @@ from runbookai_tpu.engine.request import (
     FinishReason,
     RequestState,
 )
-from runbookai_tpu.ops.sampling import sample_tokens
+from runbookai_tpu.ops.sampling import needs_sort, sample_tokens
 from runbookai_tpu.sched import class_label, class_name
 from runbookai_tpu.utils import metrics as metrics_mod
 from runbookai_tpu.utils.trace import annotate, get_tracer
@@ -907,6 +907,12 @@ LEGACY_COUNTER_EXPORTS: tuple[tuple[str, str, str], ...] = (
      "Speculative tokens drafted"),
     ("spec_accepted", "runbook_spec_accepted_total",
      "Speculative tokens accepted"),
+    ("sampler_calls", "runbook_sampler_calls_total",
+     "Calls of the sampler by dispatched programs (one a decode pass, two "
+     "a mixed step, one a prefill's first tokens)"),
+    ("sampler_sorted_calls", "runbook_sampler_sorted_calls_total",
+     "Of those, the calls with a row that samples: they sort the "
+     "vocabulary, an all-greedy call takes the argmax"),
     ("grammar_forced_tokens", "runbook_grammar_forced_tokens_total",
      "Tokens emitted by grammar fast-forward without a dispatch"),
     ("decode_time_s", "runbook_decode_time_seconds_total",
@@ -1041,6 +1047,7 @@ class _SlotInputs:
     use_pen: bool
     use_seed: bool
     use_bias: bool
+    sorts: bool  # needs_sort(temps): a decode row samples
 
 
 class EngineCore:
@@ -1329,6 +1336,11 @@ class EngineCore:
                         "expert_pairs_held": 0, "expert_pairs_zero": 0,
                         "expert_pairs_absent": 0, "experts_touched": 0,
                         "expert_overflows": 0,
+                        # Calls of ``sample_tokens`` by dispatched programs
+                        # and, of them, those that took the sorted path:
+                        # counted on the host, from the temperatures it
+                        # uploads, by the function the device decides by.
+                        "sampler_calls": 0, "sampler_sorted_calls": 0,
                         # A model with recurrent state (the KV manager's
                         # StateSnapshots counts them; 0 for any other):
                         # snapshots taken at page boundaries, admissions
@@ -1575,6 +1587,14 @@ class EngineCore:
         return self.flight.dispatches.open(program, k, rows, pages,
                                            prefill_tokens)
 
+    def _sampling(self, calls: int, sorts) -> None:
+        """Count ``calls`` calls of ``sample_tokens`` by the program about
+        to be dispatched, over rows whose temperatures ``needs_sort`` read
+        as ``sorts``."""
+        self.metrics["sampler_calls"] += calls
+        if sorts:
+            self.metrics["sampler_sorted_calls"] += calls
+
     def _issued(self, entry: Optional[dict], result: jax.Array,
                 emits: bool = True) -> None:
         if entry is not None:
@@ -1810,6 +1830,7 @@ class EngineCore:
             freq=jnp.asarray(freq), seeds=jnp.asarray(seeds),
             bias=jnp.asarray(bias) if bias is not None else None,
             use_pen=use_pen, use_seed=use_seed, use_bias=use_bias,
+            sorts=bool(needs_sort(temps)),
         )
         self._slot_cache = si
         return si
@@ -2472,6 +2493,7 @@ class EngineCore:
                                         jnp.asarray(slot_map), axis=0)
                                if use_pen else None)
                 self._key, sub = jax.random.split(self._key)
+                self._sampling(1, needs_sort(temps))
                 toks = sample_tokens(
                     last_logits, sub, jnp.asarray(temps), jnp.asarray(top_ps),
                     jnp.asarray(mask) if need_mask else None,
@@ -3119,6 +3141,8 @@ class EngineCore:
         dispatch = self._dispatching(
             "_mixed_step", 1, len(dec_snapshot), ctx_lens,
             prefill_tokens=real_tokens - len(dec_snapshot))
+        self._sampling(1, si.sorts)  # the decode rows', then the prompts'
+        self._sampling(1, needs_sort(pf_temps))
         with self.tracer.span("engine.mixed", **mix_meta), \
                 annotate("mixed", **_dispatch_stat(dispatch)), \
                 self._span("issue"):
@@ -3365,6 +3389,7 @@ class EngineCore:
             dec_meta["requests"] = [r.request_id for r in self.decoding]
         program = "_decode_step" if k == 1 else "_decode_multi"
         dispatch = self._dispatching(program, k, len(self.decoding), ctx_lens)
+        self._sampling(k, si.sorts)
         with self.tracer.span("engine.decode", **dec_meta), \
                 annotate("decode", **_dispatch_stat(dispatch)), \
                 self._span("issue"):
@@ -3475,7 +3500,8 @@ class EngineCore:
                    m["mixed_steps"], m["prefill_tokens"],
                    m["decode_tokens"], m["decode_dispatch_time_s"],
                    m["decode_host_time_s"], m["decode_host_overlap_s"],
-                   m["preemptions"])
+                   m["preemptions"], m["sampler_calls"],
+                   m["sampler_sorted_calls"])
             self._open = OpenStep()
         compiles0, compile_s0 = _compile_totals
         try:
@@ -3562,6 +3588,8 @@ class EngineCore:
             "admitted": self._admitted_log,
             "finished": self._finished_log,
             "dispatches": self.flight.dispatches.take_log(),
+            "sampler": {"calls": m["sampler_calls"] - pre[9],
+                        "sorted": m["sampler_sorted_calls"] - pre[10]},
         }
         if step.experts is not None:
             rec["experts"] = step.experts

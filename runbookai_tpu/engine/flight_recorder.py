@@ -59,6 +59,12 @@ STEP_RECORD_FIELDS = (
     # overlapped pipeline NOT the ones ``program`` names, which this step
     # issued and a later one fetches.
     "dispatches",
+    # Calls of the sampler by the programs this step DISPATCHED (``k`` for
+    # a ``_decode_multi``, two for a mixed step, one for a ``_decode_step``
+    # or a prefill's first tokens) and, of them, those with a row that
+    # samples, which sort the vocabulary (``ops/sampling.py``
+    # ``needs_sort``): {"calls", "sorted"}.
+    "sampler",
 )
 # Keys a record has only in some steps. ``experts``: where the model has an
 # expert share (models/longcat.py), the expert counts this step FETCHED (a

@@ -22,6 +22,8 @@ COMPILED program (VERDICT r4 next-round #2):
   written through, :func:`kv_layer_slices` one layer of the pool copied
   out in front of the Pallas attention kernels, which read the carried
   pool in place.
+- :func:`sorts_by_conditional` counts the ``sort`` instructions every run
+  pays beside those behind a ``conditional`` (the sampler's).
 - :func:`lower_decode` lowers+compiles the engine's REAL decode dispatch
   (the same jitted ``_decode_step`` serving uses) without executing it,
   so the analysis covers the program that runs, not a proxy.
@@ -330,6 +332,59 @@ def kv_layer_slices(compiled, core) -> list[str]:
     return _weight_shaped_buffers(
         compiled.as_text(), kv_pool_shapes(core, n_layers=1),
         {*_WIDE_DTYPES, *_NARROW_DTYPES})
+
+
+# The computations an instruction calls: one by attribute, or a list.
+_CALLED = re.compile(
+    r"\b(?:calls|to_apply|body|condition|true_computation|"
+    r"false_computation)=%?([\w.\-]+)"
+    r"|\b(?:branch_computations|called_computations)=\{([^}]*)\}")
+
+
+# An instruction's op, whatever its result: on the chip a sort's is a tuple
+# (values and indices), as a conditional's always is.
+_OP = re.compile(
+    r"^(?:ROOT\s+)?%?[\w.\-]+\s*=\s*(?:\(.*?\)|\S+)\s+([\w\-]+)\(")
+
+
+def _op(line: str) -> str | None:
+    m = _OP.match(line)
+    return m.group(1) if m else None
+
+
+def _called(line: str) -> list[str]:
+    names: list[str] = []
+    for one, many in _CALLED.findall(line):
+        names += [one] if one else [
+            n.strip().lstrip("%") for n in many.split(",")]
+    return names
+
+
+def sorts_by_conditional(hlo_text: str) -> tuple[int, int]:
+    """(inside, outside): the ``sort`` instructions of a compiled program
+    that lie in a branch of a ``conditional`` (in the branch's computation
+    or in one it calls), and those that every run of the program pays. The
+    sampler sorts the vocabulary only where a row samples
+    (``ops/sampling.py``), so a step program has none outside: at the dense
+    cell's size a ``sort`` of ``f32[16, 152064]`` outside a branch was 3.64
+    ms of a 16.04 ms pass (PERF.md section 6, PR 40)."""
+    comps = _computations(hlo_text)
+    behind = [name for lines in comps.values() for line in lines
+              if _op(line) == "conditional" for name in _called(line)]
+    branch: set[str] = set()
+    while behind:
+        name = behind.pop()
+        if name not in branch and name in comps:
+            branch.add(name)
+            behind += [n for line in comps[name] for n in _called(line)]
+    inside = outside = 0
+    for name, lines in comps.items():
+        n = sum(_op(line) == "sort" for line in lines)
+        if name in branch:
+            inside += n
+        else:
+            outside += n
+    return inside, outside
 
 
 def param_nbytes(params: Any) -> int:

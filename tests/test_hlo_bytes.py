@@ -25,7 +25,12 @@ arguments. These tests pin it to the COMPILED decode program instead:
 - no step program whose pool the Pallas attention kernels read in place
   owns one LAYER of the pool either (`kv_layer_slices`): the slice XLA
   staged on chip in front of every attention call, at the dense cell's
-  real size too; a pool that fits on-chip memory keeps the slice.
+  real size too; a pool that fits on-chip memory keeps the slice;
+- the sampler's vocabulary-wide ``sort`` lies in a branch of a
+  ``conditional`` in every step program that samples, and none outside
+  one (`sorts_by_conditional`), on the CPU, for the described v5e and at
+  the dense cell's real size; a sampling request compiles no step program
+  a greedy one had not.
 
 The on-device twins (real Mosaic, no interpret) live in
 ``test_pallas_on_device.py``.
@@ -36,6 +41,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from runbookai_tpu.engine import engine as engine_module
 from runbookai_tpu.engine.engine import EngineConfig, EngineCore
 from runbookai_tpu.engine.hlo_bytes import (
     decode_accounting,
@@ -46,9 +52,11 @@ from runbookai_tpu.engine.hlo_bytes import (
     lower_decode,
     param_nbytes,
     quantized_weight_shapes,
+    sorts_by_conditional,
     wide_weight_materializations,
 )
 from runbookai_tpu.engine.memory_plan import plan_serving
+from runbookai_tpu.engine.request import EngineRequest, SamplingParams
 from runbookai_tpu.models.llama import (
     CONFIGS,
     LlamaConfig,
@@ -477,17 +485,32 @@ def dense_cell_core():
         qmm_impl="pallas", mixed_dispatch=True, decode_steps_per_dispatch=8))
 
 
+@pytest.fixture(scope="module")
+def dense_cell_compiled(one_chip, dense_cell_core):
+    """The dense cell's step programs as the chip's compiler makes them,
+    each compiled once for the tests that read it (25-35 s a program)."""
+    compiled = {}
+
+    def of(program, monkeypatch):
+        if program not in compiled:
+            monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+            compiled[program] = lower_decode(dense_cell_core, program=program,
+                                             sharding=one_chip)
+        return compiled[program]
+
+    return of
+
+
 @pytest.mark.parametrize("program", STEP_PROGRAMS)
 def test_dense_cell_step_program_stages_no_layer_of_the_pool(
-        one_chip, dense_cell_core, program, monkeypatch):
+        dense_cell_core, dense_cell_compiled, program, monkeypatch):
     """What `qwen7b.chat-open` runs, compiled by the chip's own compiler:
     no ``bf16[1, 49152, 4, 128]`` buffer (two of them, 50 MB each, were
     staged on chip in front of every attention call: 3.7 ms of a 19.1 ms
     pass), no second pool, and temporaries far under one layer's slice."""
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     core = dense_cell_core
     assert core._kv_k.shape == (28, 49152, 4, 128)
-    compiled = lower_decode(core, program=program, sharding=one_chip)
+    compiled = dense_cell_compiled(program, monkeypatch)
     txt = compiled.as_text()
     # The page table is the attention call's first operand.
     rows = 80 if program == "_mixed_step" else 16
@@ -496,6 +519,125 @@ def test_dense_cell_step_program_stages_no_layer_of_the_pool(
     bad = (kv_layer_slices(compiled, core)
            + kv_pool_materializations(compiled, core))
     assert bad == [], "\n".join(bad)
+
+
+# ---------------------------------------------------- the sampler's sort
+#
+# `sample_tokens` sorts the vocabulary only where a row of the call samples:
+# the sorted path is a branch of a `lax.cond` inside the one program
+# (`ops/sampling.py`). At the dense cell's size the sort of
+# `f32[16, 152064]` every pass paid was 3.64 ms of 16.04 (PERF.md section 6,
+# PR 40). The RED control is the sampler without the condition
+# (`tests/test_sampling.py` keeps it), passed in: it owns a top-level sort.
+
+SAMPLING_PROGRAMS = ["_decode_multi", "_mixed_step"]
+
+
+@pytest.fixture
+def unconditional_sampler(monkeypatch):
+    """The step programs traced over the sampler as it was: every call sorts."""
+    from test_sampling import reference_sample_tokens
+
+    monkeypatch.setattr(engine_module, "sample_tokens", reference_sample_tokens)
+    jax.clear_caches()
+    yield
+    jax.clear_caches()
+
+
+def _sampler_calls(program):
+    return 2 if program == "_mixed_step" else 1
+
+
+@pytest.mark.parametrize("program", SAMPLING_PROGRAMS)
+def test_step_program_sorts_only_behind_the_condition(kv_core, program):
+    inside, outside = sorts_by_conditional(
+        lower_decode(kv_core, program=program).as_text())
+    assert (inside, outside) == (_sampler_calls(program), 0)
+
+
+@pytest.mark.parametrize("program", SAMPLING_PROGRAMS)
+def test_step_program_over_the_old_sampler_sorts_every_call(
+        kv_core, program, unconditional_sampler):
+    inside, outside = sorts_by_conditional(
+        lower_decode(kv_core, program=program).as_text())
+    assert (inside, outside) == (0, _sampler_calls(program))
+
+
+@pytest.mark.parametrize("program", SAMPLING_PROGRAMS)
+def test_step_program_for_the_chip_sorts_only_behind_the_condition(
+        one_chip, chip_core, program, monkeypatch):
+    """On the chip a sort's result is a tuple (values and indices): the
+    detector reads that spelling too."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    compiled = lower_decode(chip_core, program=program, attn_impl="pallas",
+                            sharding=one_chip)
+    assert sorts_by_conditional(compiled.as_text()) == (
+        _sampler_calls(program), 0)
+
+
+def test_step_program_for_the_chip_over_the_old_sampler_sorts_every_call(
+        one_chip, chip_core, unconditional_sampler, monkeypatch):
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    compiled = lower_decode(chip_core, program="_decode_multi",
+                            attn_impl="pallas", sharding=one_chip)
+    assert sorts_by_conditional(compiled.as_text()) == (0, 1)
+
+
+@pytest.mark.parametrize("program", SAMPLING_PROGRAMS)
+def test_dense_cell_step_program_sorts_only_behind_the_condition(
+        dense_cell_compiled, program, monkeypatch):
+    """`qwen7b.chat-open`'s programs: the `f32[16, 152064]` sort (and the
+    prompts' `f32[4, 152064]` one) in a branch, the logits handed to the
+    conditional as the head wrote them, and temporaries of megabytes (a
+    branch that passed a pool through would copy it: PERF.md section 4,
+    PR 39)."""
+    compiled = dense_cell_compiled(program, monkeypatch)
+    txt = compiled.as_text()
+    assert sorts_by_conditional(txt) == (_sampler_calls(program), 0)
+    assert not [line for line in txt.splitlines()
+                if "152064]" in line.split("=", 2)[-1][:48]
+                and " copy(" in line]
+    assert compiled.memory_analysis().temp_size_in_bytes < 32 << 20
+
+
+def test_a_sampling_request_compiles_no_step_program():
+    """Greedy, sampling, greedy through one engine: the condition is inside
+    the one program, so the sampling request meets the programs the first
+    greedy one compiled, and the greedy answers are the same before and
+    after it."""
+    params = init_params(jax.random.PRNGKey(0), KV_CFG, dtype=jnp.float32)
+    core = EngineCore(KV_CFG, params, ByteTokenizer(), EngineConfig(
+        page_size=4, num_pages=512, max_batch_slots=4, prefill_chunk=8,
+        max_seq_len=128, block_pages=4, kv_dtype=jnp.float32,
+        mixed_dispatch=True, decode_steps_per_dispatch=4))
+    programs = (engine_module._decode_multi, engine_module._mixed_step,
+                engine_module._decode_step, engine_module._prefill_step)
+
+    def serve(prompt, **sampling):
+        # A second prompt arrives while the first decodes: a mixed step.
+        reqs = [EngineRequest(prompt_ids=list(p), sampling=SamplingParams(
+            max_new_tokens=12, stop_token_ids=(), **sampling))
+            for p in (prompt, prompt[::-1])]
+        core.submit(reqs[0])
+        for _ in range(3):
+            core.step()
+        core.submit(reqs[1])
+        core.run_until_idle()
+        return [r.all_out_ids for r in reqs]
+
+    prompt = list(range(5, 27))
+    before = serve(prompt)
+    sizes = [p._cache_size() for p in programs]
+    calls = core.metrics["sampler_calls"]
+    assert sizes[0] >= 1 and sizes[1] >= 1 and calls > 0
+    assert core.metrics["sampler_sorted_calls"] == 0
+    sampled = serve(prompt, temperature=0.9, top_p=0.9, top_k=20)
+    assert core.metrics["sampler_sorted_calls"] > 0
+    assert all(sampled)  # (an end-of-sequence token may cut one short)
+    sorted_calls = core.metrics["sampler_sorted_calls"]
+    assert serve(prompt) == before
+    assert core.metrics["sampler_sorted_calls"] == sorted_calls
+    assert [p._cache_size() for p in programs] == sizes
 
 
 @pytest.fixture(scope="module")

@@ -40,7 +40,8 @@ def rec(i, kind="decode", **kw):
             "program": ["_decode_multi"], "k": 8, "rows": 1,
             "kv_pages_live": 3,
             "prefill_tokens": 0, "decode_tokens": 2, "compile_s": 0.0,
-            "admitted": [], "finished": [], "dispatches": []}
+            "admitted": [], "finished": [], "dispatches": [],
+            "sampler": {"calls": 8, "sorted": 0}}
     base.update(kw)
     return base
 

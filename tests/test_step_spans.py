@@ -34,7 +34,7 @@ from runbookai_tpu.utils.tokens import ByteTokenizer
 
 NEW_FIELDS = ("t_start", "t_end", "phases", "program", "k", "rows",
               "kv_pages_live", "prefill_tokens", "decode_tokens", "compile_s",
-              "admitted", "finished", "dispatches")
+              "admitted", "finished", "dispatches", "sampler")
 ORDER = ("t_received", "t_enqueued", "t_admitted", "t_first_token",
          "t_finished")
 
@@ -514,6 +514,81 @@ def test_a_dispatch_of_rounds_books_what_was_drafted_and_accepted():
         assert f["rode"]["_decode_spec"][1] == f["generated"] - 1
 
 
+# ---- the sampler's calls -------------------------------------------------------
+
+
+def sampler_calls_of(listed: list[dict]) -> int:
+    """What the dispatches in the records must have counted: ``k`` calls of
+    the sampler for a ``_decode_multi`` of ``k`` passes, one for a
+    ``_decode_step``, two for a mixed step (the decode rows', the
+    prompts'), one for a prefill that gave first tokens, none for a
+    ``_decode_spec`` (its rounds take the argmax)."""
+    per = {"_decode_multi": lambda d: d["k"], "_decode_step": lambda d: 1,
+           "_mixed_step": lambda d: 2, "_decode_spec": lambda d: 0,
+           "_prefill_step": lambda d: 1 if d["tokens"] else 0}
+    return sum(per[d["program"]](d) for d in listed)
+
+
+@pytest.mark.parametrize("kind", ["decode_multi", "decode_step", "mixed", "spec"])
+def test_the_sampler_counts_a_call_a_pass_and_sorts_for_no_greedy_row(parts, kind):
+    settings, arrivals, sampling, program = KINDS[kind]
+    core = make_core(parts, **settings)
+    serve(core, arrivals, **sampling)
+    steps = core.flight.snapshot()
+    listed = check_dispatches(core, steps)
+    assert program in {d["program"] for d in listed}
+    m = core.metrics
+    assert m["sampler_calls"] == sampler_calls_of(listed) > 0
+    assert m["sampler_sorted_calls"] == 0
+    assert sum(s["sampler"]["calls"] for s in steps) == m["sampler_calls"]
+    assert all(s["sampler"]["sorted"] == 0 for s in steps)
+    if kind == "decode_multi":
+        k = settings["decode_steps_per_dispatch"]
+        assert any(s["sampler"]["calls"] == k for s in steps
+                   if s["program"] == ["_decode_multi"])
+    if kind == "mixed":
+        assert all(s["sampler"]["calls"] == 2 for s in steps
+                   if s["program"] == ["_mixed_step"])
+
+
+@pytest.mark.parametrize("mixed", [False, True], ids=["split", "mixed"])
+def test_one_sampling_row_sends_its_calls_down_the_sorted_path(parts, mixed):
+    """Greedy rows decode, a sampling request joins them and leaves: the
+    calls with its row in them are counted sorted, those before and after
+    are not, and the greedy rows' tokens are what they are without it."""
+    def run(temperature):
+        core = make_core(parts, decode_steps_per_dispatch=2,
+                         mixed_dispatch=mixed)
+        greedy = [request(b"a greedy row that stays", 30),
+                  request(b"and another one", 30)]
+        for r in greedy:
+            core.submit(r)
+        while not core.decoding:
+            core.step()
+        before = dict(core.metrics)
+        joined = EngineRequest(prompt_ids=list(b"the row that samples"),
+                               sampling=SamplingParams(
+                                   temperature=temperature, top_p=0.9, seed=5,
+                                   max_new_tokens=6, stop_token_ids=()))
+        core.submit(joined)
+        core.run_until_idle()
+        return core, before, [r.all_out_ids for r in greedy]
+
+    core, before, tokens = run(0.8)
+    m, steps = core.metrics, core.flight.snapshot()
+    assert before["sampler_calls"] > 0 and before["sampler_sorted_calls"] == 0
+    assert 0 < m["sampler_sorted_calls"] < m["sampler_calls"] - before["sampler_calls"]
+    for key, field in (("sampler_calls", "calls"), ("sampler_sorted_calls", "sorted")):
+        assert sum(s["sampler"][field] for s in steps) == m[key]
+    assert all(s["sampler"]["sorted"] in (0, s["sampler"]["calls"])
+               or "_mixed_step" in s["program"] for s in steps)
+    # The last dispatches ran without the sampling row: greedy again.
+    assert steps[-1]["sampler"]["sorted"] == 0
+    plain, _, plain_tokens = run(0.0)
+    assert plain.metrics["sampler_sorted_calls"] == 0
+    assert tokens == plain_tokens
+
+
 def test_a_preempted_request_is_marked_and_the_rest_still_add_up(parts):
     core = make_core(parts, num_pages=20, max_batch_slots=2,
                      decode_steps_per_dispatch=1, admit_headroom_tokens=8)
@@ -671,6 +746,22 @@ async def test_the_fleets_debug_steps_keep_the_new_fields():
     finished = {f["trace_id"]: f for s in steps for f in s["finished"]}
     assert set(finished) == {"rid-a", "rid-b"}
     assert all(f["t_received"] == t_received for f in finished.values())
+
+
+def test_the_front_door_exports_the_samplers_counters(server):
+    chat(server, "count my passes", False, "rid-sampler")
+    with urllib.request.urlopen(
+            f"http://127.0.0.1:{server.port}/healthz", timeout=30) as r:
+        health = json.loads(r.read())["metrics"]
+    assert health["sampler_calls"] > 0 and health["sampler_sorted_calls"] == 0
+    with urllib.request.urlopen(
+            f"http://127.0.0.1:{server.port}/metrics", timeout=30) as r:
+        text = r.read().decode()
+    # (The registry is the process's: its callbacks read the engine built
+    # last, which other tests of this module may have made.)
+    for name in ("runbook_sampler_calls_total", "runbook_sampler_sorted_calls_total"):
+        assert f"# TYPE {name} counter" in text
+        assert any(ln.split()[0] == name for ln in text.splitlines() if ln)
 
 
 def test_the_tracer_is_on_the_shared_clock(tmp_path):
