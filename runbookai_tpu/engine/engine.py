@@ -48,6 +48,8 @@ from runbookai_tpu.engine.flight_recorder import (
 )
 from runbookai_tpu.engine.kv_cache import (
     STATE_COUNTERS,
+    WINDOW_COUNTERS,
+    WindowSpec,
     KVCacheManager,
     hash_blocks,
 )
@@ -958,6 +960,17 @@ LEGACY_COUNTER_EXPORTS: tuple[tuple[str, str, str], ...] = (
      "recurrent layers)"),
     ("state_hash_tokens_granted", "runbook_state_hash_tokens_granted_total",
      "Of those, the tokens granted: up to a boundary with a snapshot"),
+    ("kv_window_rows_released", "runbook_kv_window_rows_released_total",
+     "Token rows of the window layers' pool given back behind the window "
+     "while their sequence lived (models with window layers)"),
+    ("kv_window_hash_tokens_matched",
+     "runbook_kv_window_hash_tokens_matched_total",
+     "Prompt tokens admissions matched by page hash (models with window "
+     "layers)"),
+    ("kv_window_hash_tokens_granted",
+     "runbook_kv_window_hash_tokens_granted_total",
+     "Of those, the tokens granted: up to a boundary whose window pages "
+     "were still resident"),
 )
 
 def export_expert_pairs(reg, value_of: Callable[[str], float]) -> None:
@@ -1133,6 +1146,11 @@ class EngineCore:
             raise ValueError(
                 f"model {model_cfg.name!r} (family {model_cfg.family!r}) "
                 f"does not support: {'; '.join(refused)}")
+        rows = getattr(model_cfg, "max_prefill_rows", None)
+        if rows is not None and rows < self.ecfg.prefill_batch:
+            # The configuration bounds the prefill rows of a dispatch (one
+            # compiled width where its prompts prefill alone for seconds).
+            self.ecfg = _dc.replace(self.ecfg, prefill_batch=rows)
         if not model_cfg.pallas_attention and self.ecfg.attn_impl == "pallas":
             # The Pallas kernels read per-head K/V pages; this family's
             # forward has its own attention over its own pool.
@@ -1211,6 +1229,10 @@ class EngineCore:
         # Recurrent layers keep their state a SLOT, not a token: a second
         # pool beside the pages, and a pool of snapshots behind prefix hits.
         state_spec = getattr(model_cfg, "state_pool_spec", None)
+        # Window layers keep the last ``window`` positions only: a second
+        # group of the pool, with its own pages (``cfg.kv_window_spec``:
+        # the layers in it and the window; engine/kv_cache.py WindowSpec).
+        window_spec = getattr(model_cfg, "kv_window_spec", None)
         self.kv = KVCacheManager(
             n_layers=pool_layers,
             num_pages=self.ecfg.num_pages,
@@ -1226,6 +1248,11 @@ class EngineCore:
                              else None),
             # The module's row of a position is made of the NEXT token too.
             lookahead=1 if self._mtp else 0,
+            window=(WindowSpec(
+                n_layers=window_spec[0], window=window_spec[1],
+                max_step=max(self.ecfg.prefill_chunk,
+                             self.ecfg.decode_steps_per_dispatch + 1),
+                slots=self.ecfg.max_batch_slots) if window_spec else None),
         )
         self._kv_k = self.kv.pool.kv_k
         self._kv_v = self.kv.pool.kv_v
@@ -1347,7 +1374,12 @@ class EngineCore:
                         # restored from one, snapshots evicted by a full
                         # pool, and the prompt tokens admissions MATCHED
                         # by page hash beside those they were GRANTED.
-                        **{"state_" + k: 0 for k in STATE_COUNTERS}}
+                        **{"state_" + k: 0 for k in STATE_COUNTERS},
+                        # A model with window layers (the KV manager counts
+                        # them; 0 for any other): rows of the window pool
+                        # given back behind the window, and the prompt
+                        # tokens admissions matched beside those granted.
+                        **{"kv_window_" + k: 0 for k in WINDOW_COUNTERS}}
         # Expert counts of dispatches that fetched no token of their own
         # (a prefill chunk that completed no prompt): (program, passes,
         # device array), riding the next token fetch.
@@ -1358,6 +1390,10 @@ class EngineCore:
         # rather than an intra-step delta that would always read 0.
         self._flight_kv_mark = (0, 0)
         self._state_mark: dict[str, int] = {}
+        self._window_mark = 0
+        # Of the prefill chunks dispatched since the last record: the
+        # query-key pairs and the distinct key rows inside the window.
+        self._window_chunks = [0, 0]
         # Workload-fingerprint tap (runbookai_tpu/obs): called once per
         # finishing request from _observe_finish with the EngineRequest.
         # None = no observer; the callee appends to a bounded deque — one
@@ -1528,6 +1564,8 @@ class EngineCore:
         # recorded step report a negative import delta.
         self._flight_kv_mark = (0, 0)
         self._state_mark = {}
+        self._window_mark = 0
+        self.kv.window_counters = dict.fromkeys(WINDOW_COUNTERS, 0)
         if self.kv.snapshots is not None:
             self.kv.snapshots.reset_counters()
         self.hist_ttft.reset()
@@ -1586,6 +1624,18 @@ class EngineCore:
         self._open.dispatched(program, k, rows, pages)
         return self.flight.dispatches.open(program, k, rows, pages,
                                            prefill_tokens)
+
+    def _note_window_chunks(self, rows) -> None:
+        """The prefill chunks ``(request, tokens, new context)`` of a
+        dispatch, as the window layers' walks see them (a model with window
+        layers, a recorded step): what the next record's ``window`` says of
+        their work."""
+        if self.kv.window is None or self._open is None:
+            return
+        for _, chunk, new_ctx in rows:
+            pairs, seen = self.kv.window.chunk_work(new_ctx - chunk, chunk)
+            self._window_chunks[0] += pairs
+            self._window_chunks[1] += seen
 
     def _sampling(self, calls: int, sorts) -> None:
         """Count ``calls`` calls of ``sample_tokens`` by the program about
@@ -1762,12 +1812,19 @@ class EngineCore:
         return ids
 
     def _tables_for(self, reqs: list[Optional[EngineRequest]]) -> np.ndarray:
-        """[N, max_pages + 1] page tables with the trailing trash column."""
+        """[N, max_pages + 1] page tables with the trailing trash column;
+        for a model with window layers a second such half beside it, the
+        window group's (the family's forward splits the row in two)."""
         n = len(reqs)
-        out = np.zeros((n, self.kv.max_pages_per_seq + 1), dtype=np.int32)
+        half = self.kv.max_pages_per_seq + 1
+        windowed = self.kv.window is not None
+        out = np.zeros((n, half * (2 if windowed else 1)), dtype=np.int32)
         for i, r in enumerate(reqs):
             if r is not None and r.request_id in self.kv.seqs:
-                out[i, : self.kv.max_pages_per_seq] = self.kv.page_table_row(r.request_id)
+                out[i, : half - 1] = self.kv.page_table_row(r.request_id)
+                if windowed:
+                    out[i, half: 2 * half - 1] = self.kv.window_table_row(
+                        r.request_id)
         return out
 
     # ------------------------------------------------- overlapped pipeline
@@ -2350,6 +2407,7 @@ class EngineCore:
                 rows.append((req, chunk_len, new_ctx))
         if not rows:
             return
+        self._note_window_chunks(rows)
 
         # Pad the row count to a power of two so the compile count stays
         # O(log prefill_batch); pad rows write to the null page and attend
@@ -3029,6 +3087,7 @@ class EngineCore:
             self._grow_pages_for_decode(1)
             if not self.decoding:
                 return False
+        self._note_window_chunks(pf_rows)
 
         t_build = time.perf_counter()
         with self._span("build"):
@@ -3520,6 +3579,9 @@ class EngineCore:
                     # prefill share one level against the live TPOT burn.
                     # None (the default) = untouched.
                     self.feedback.on_step(self)
+                if self.kv.window is not None:
+                    for name, count in self.kv.window_counters.items():
+                        self.metrics["kv_window_" + name] = count
                 compiles, compile_s = _compile_totals
                 if compiles != compiles0:
                     self.metrics["compiles"] += compiles - compiles0
@@ -3603,6 +3665,30 @@ class EngineCore:
                 **{k: m["state_" + k] - self._state_mark.get(k, 0)
                    for k in STATE_COUNTERS}}
             self._state_mark = {k: m["state_" + k] for k in STATE_COUNTERS}
+        if self.kv.window is not None:
+            # Over the sequences that live (the dispatch's rows): the token
+            # rows the window layers hold for them, their contexts, and the
+            # rows this step gave back behind the window; over the decoding
+            # ones, the rows inside the window (what a decode pass's walk
+            # must read a layer); of the step's prefill chunks, the
+            # query-key pairs and distinct key rows inside the window.
+            live = [r for r in self.decoding + self.prefilling
+                    if r.request_id in self.kv.seqs]
+            released = m["kv_window_rows_released"]
+            width = self.kv.window.window
+            kept = [self.kv.window_rows(r.request_id) for r in live]
+            rec["window"] = {
+                "rows_kept": sum(kept),
+                "rows_context": sum(self.kv.seqs[r.request_id].ctx_len
+                                    for r in live),
+                "rows_kept_max": max(kept, default=0),
+                "rows_released": released - self._window_mark,
+                "rows_seen": sum(min(r.ctx_len + self._lead(r), width)
+                                 for r in self.decoding),
+                "chunk_pairs": self._window_chunks[0],
+                "chunk_rows_seen": self._window_chunks[1]}
+            self._window_mark = released
+            self._window_chunks = [0, 0]
         self._admitted_log, self._finished_log = [], []
         # Page transfers land BETWEEN steps (cross-replica pulls, disagg
         # handoffs, spill readmits run under the engine lock outside

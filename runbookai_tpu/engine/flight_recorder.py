@@ -83,8 +83,18 @@ STEP_RECORD_FIELDS = (
 # a round and row, up to the row's stop), those whose second token was
 # served (``accepted``), and the ``rows`` dispatched. Such a step's
 # ``decode_tokens`` is ``drafted + accepted``: a row takes ``rounds`` to
-# ``2 * rounds`` tokens of it.
-OPTIONAL_STEP_FIELDS = ("experts", "state", "spec")
+# ``2 * rounds`` tokens of it. ``window``: where the model has window layers
+# (models/afmoe.py), in every step, over the sequences that live:
+# ``rows_kept`` (token rows the window layers' pool holds for them),
+# ``rows_context`` (their contexts: what the rows would be with no window),
+# ``rows_kept_max`` (the most one of them holds: never over
+# ``WindowSpec.rows_bound``), ``rows_released`` (rows this step gave back
+# behind the window), ``rows_seen`` (over the DECODING sequences, the rows
+# inside the window: ``min(context, window)`` each, what one layer's decode
+# walk must read), and of the step's prefill chunks ``chunk_pairs`` (query-key
+# pairs inside the window) and ``chunk_rows_seen`` (the distinct key rows
+# their queries see).
+OPTIONAL_STEP_FIELDS = ("experts", "state", "spec", "window")
 
 # ``phases`` keys besides "other" (= wall_s less their sum), and the
 # profiler span that marks the same boundaries on the device trace's

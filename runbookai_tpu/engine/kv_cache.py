@@ -27,6 +27,16 @@ pool of such states, each tied to the chain hash of the page that ends at
 its boundary; with one, a match is GRANTED only back to the deepest
 boundary that has a snapshot (to nothing if none has).
 
+A model some of whose layers attend to a WINDOW (the last ``window``
+positions: ``models/afmoe.py``) has two GROUPS of layers with unlike
+lifetimes. The full-attention group keeps a row for every position, in the
+pages above. The window group gets a second pool with its own allocator,
+its own prefix index and a second half of every page-table row
+(:class:`WindowSpec`): a sequence holds there the pages its next dispatch
+can read and no more, what falls behind the window is given back WHILE the
+sequence lives (:meth:`KVCacheManager.extend`), and a page-hash hit is
+granted only to a boundary whose window pages are still resident.
+
 No reference counterpart (SURVEY.md §2.9 item 2 — green-field requirement).
 """
 
@@ -61,11 +71,23 @@ class PagePool:
         dtype=jnp.bfloat16,
         sharding=None,
         v_side: Optional[tuple[int, int, int]] = None,
+        window: Optional[tuple[int, int]] = None,
     ) -> "PagePool":
         """``v_side``: (layers, heads, values a head) of ``kv_v`` where it
         is not ``kv_k``'s (a latent pool: ``kv_k`` holds the latent of every
         attention sublayer, ``kv_v`` the rotated keys). Both sides keep
-        ``num_pages * page_size`` token rows on axis 1."""
+        ``num_pages * page_size`` token rows on axis 1. ``window``: (layers,
+        pages) of a second group of layers with a pool of its own; each
+        side is then ``{"full": ..., "window": ...}``, one tree through
+        every step program."""
+        if window is not None:
+            full = PagePool.create(n_layers, num_pages, page_size, n_kv_heads,
+                                   head_dim, dtype, sharding, v_side)
+            win = PagePool.create(window[0], window[1], page_size, n_kv_heads,
+                                  head_dim, dtype, sharding)
+            return PagePool(kv_k={"full": full.kv_k, "window": win.kv_k},
+                            kv_v={"full": full.kv_v, "window": win.kv_v},
+                            page_size=page_size, num_pages=num_pages)
         tokens = num_pages * page_size
         k_shape = (n_layers, tokens, n_kv_heads, head_dim)
         v_shape = (k_shape if v_side is None
@@ -475,6 +497,47 @@ class StateSnapshots:
         return snap.idx
 
 
+WINDOW_COUNTERS = ("rows_released", "hash_tokens_matched", "hash_tokens_granted")
+
+
+@dataclass(frozen=True)
+class WindowSpec:
+    """The window group of a model's layers, as the manager needs it: a
+    property of the CONFIGURATION (``cfg.kv_window_spec``) and of the
+    engine's shapes, never a model's name."""
+
+    n_layers: int  # layers whose queries see the last ``window`` positions
+    window: int
+    max_step: int  # the most tokens one dispatch adds to a sequence
+    slots: int  # the most sequences that live at once
+
+    def first_block(self, query: int, page_size: int) -> int:
+        """The page that holds the oldest position a query at ``query``
+        sees (``query - window + 1``)."""
+        return max(0, query - self.window + 1) // page_size
+
+    def chunk_work(self, first: int, n: int) -> tuple[int, int]:
+        """(query-key pairs, distinct key rows) the window leaves of ``n``
+        queries at positions ``first ..``: a query at ``p`` sees ``min(p + 1,
+        window)`` keys, and together they see the rows from the first
+        query's edge to the last query."""
+        w = self.window
+        full = max(0, first + n - max(first, w - 1))  # queries that see a whole window
+        short = n - full  # the first ones of a sequence see p + 1
+        pairs = full * w + short * (2 * first + short + 1) // 2
+        return pairs, min(first + n, n + w - 1)
+
+    def rows_bound(self, page_size: int) -> int:
+        """The most token rows a live sequence holds in the window group,
+        whatever its context: the window, the dispatch being written, and
+        a page's slack at either end."""
+        return self.window + self.max_step + 2 * page_size
+
+    def pages(self, page_size: int) -> int:
+        """The window pool: every slot at its bound, and the null page."""
+        return self.slots * -(-self.rows_bound(page_size) // page_size) + 1
+
+
 @dataclass
 class SequenceAllocation:
     """Pages owned by one live sequence."""
@@ -486,6 +549,15 @@ class SequenceAllocation:
     # Row of the snapshot pool the admission was granted its prefix from
     # (a model with recurrent state): the engine restores it into the slot.
     restore_from: Optional[int] = None
+    # The window group's pages (a model with window layers): block -> page,
+    # the blocks its next dispatch can read; ``win_lo`` is the lowest block
+    # it may still ask for (what lies under it was given back or never
+    # held), ``prompt`` / ``chain`` the tokens and hashes its pages are
+    # published under when they are given back.
+    win_pages: dict[int, int] = field(default_factory=dict)
+    win_lo: int = 0
+    prompt: Optional[Sequence[int]] = None
+    chain: Optional[list[int]] = None
 
     def pages_needed(self, new_len: int, page_size: int) -> int:
         have = len(self.pages)
@@ -511,6 +583,7 @@ class KVCacheManager:
         v_side: Optional[tuple[int, int, int]] = None,
         state_snapshots: Optional[int] = None,
         lookahead: int = 0,
+        window: Optional[WindowSpec] = None,
     ):
         # Tokens past a page's end that its rows depend on (``hash_blocks``):
         # a page is published, matched and verified with that many more.
@@ -521,14 +594,31 @@ class KVCacheManager:
         # snapshot is among that many (the engine owns the device pool).
         self.snapshots: Optional[StateSnapshots] = (
             None if state_snapshots is None else StateSnapshots(state_snapshots))
-        self.pool = PagePool.create(n_layers, num_pages, page_size, n_kv_heads,
-                                    head_dim, dtype, sharding=sharding,
-                                    v_side=v_side)
-        if allocator is None:
-            from runbookai_tpu.native import make_page_allocator
+        # None: every layer keeps every position, in the pages. A spec: the
+        # model's window layers page a pool of their own (``win_allocator``,
+        # ``_win_tokens``: the allocator and the verified tokens of THAT
+        # pool's pages), sized so that no live sequence ever waits for it.
+        self.window = window
+        if window is not None and (spill_pages or state_snapshots is not None
+                                   or lookahead or sharding is not None):
+            raise ValueError(
+                "a model with window layers is not built with the host spill "
+                "tier, recurrent-state snapshots, a drafter's lookahead or a "
+                "sharded pool")
+        self.pool = PagePool.create(
+            n_layers, num_pages, page_size, n_kv_heads, head_dim, dtype,
+            sharding=sharding, v_side=v_side,
+            window=(None if window is None
+                    else (window.n_layers, window.pages(page_size))))
+        from runbookai_tpu.native import make_page_allocator
 
+        if allocator is None:
             allocator = make_page_allocator(num_pages)
         self.allocator = allocator
+        self.win_allocator = (None if window is None
+                              else make_page_allocator(window.pages(page_size)))
+        self._win_tokens: dict[int, tuple[int, ...]] = {}
+        self.window_counters = dict.fromkeys(WINDOW_COUNTERS, 0)
         self.page_size = page_size
         self.max_pages_per_seq = (max_seq_len + page_size - 1) // page_size
         self.seqs: dict[str, SequenceAllocation] = {}
@@ -600,6 +690,9 @@ class KVCacheManager:
         where pages are the whole state; with recurrent state, the pages
         up to the deepest boundary that has a snapshot, and that snapshot
         (nothing, if no boundary of the match has one)."""
+        if self.window is not None:
+            chain = self._prompt_hashes(prompt_ids, hashes, hash_seed)
+            return matched[:self._window_grant(len(matched), prompt_ids, chain)], None
         if self.snapshots is None:
             return matched, None
         if sweep:
@@ -610,6 +703,30 @@ class KVCacheManager:
             if snap is not None:
                 return matched[:b], snap
         return [], None
+
+    def _window_page(self, prompt_ids: Sequence[int], chain: list[int],
+                     b: int) -> Optional[int]:
+        """The window pool's resident, verified page of the prompt's block
+        ``b``, or None."""
+        page = self.win_allocator.lookup(chain[b])
+        if page is None or self._win_tokens.get(page) != self._block(prompt_ids, b):
+            return None
+        return page
+
+    def _window_grant(self, n_matched: int, prompt_ids: Sequence[int],
+                      chain: list[int]) -> int:
+        """The deepest boundary ``b <= n_matched`` (in pages) at which the
+        window layers can resume: every page the query at ``b * page_size``
+        sees is still resident in the window pool. 0: none is."""
+        runs, run = [], 0  # resident window pages in a row, ending at block b
+        for b in range(n_matched):
+            run = run + 1 if self._window_page(prompt_ids, chain, b) is not None else 0
+            runs.append(run)
+        for b in range(n_matched, 0, -1):
+            if runs[b - 1] >= b - self.window.first_block(b * self.page_size,
+                                                          self.page_size):
+                return b
+        return 0
 
     def match_prefix(self, prompt_ids: Sequence[int],
                      hashes: Optional[list[int]] = None,
@@ -654,6 +771,22 @@ class KVCacheManager:
         namespace the pages were matched from."""
         alloc = SequenceAllocation(hash_seed=hash_seed)
         cached = 0
+        if prompt_ids and self.window is not None:
+            # As with snapshots below: matched against granted, counted once
+            # an admission; the window pages the boundary's query sees are
+            # shared with whoever holds them, like the pages of the match.
+            alloc.prompt, alloc.chain = prompt_ids, hashes
+            full = self._match_pages(prompt_ids, hashes, hash_seed)
+            matched, _ = self._grant(full, prompt_ids, hashes, hash_seed)
+            self.window_counters["hash_tokens_matched"] += len(full) * self.page_size
+            self.window_counters["hash_tokens_granted"] += len(matched) * self.page_size
+            chain = self._prompt_hashes(prompt_ids, hashes, hash_seed)
+            alloc.win_lo = self.window.first_block(len(matched) * self.page_size,
+                                                   self.page_size)
+            for b in range(alloc.win_lo, len(matched)):
+                page = self._window_page(prompt_ids, chain, b)
+                self.win_allocator.acquire(page)
+                alloc.win_pages[b] = page
         if prompt_ids:
             if self.snapshots is not None:
                 # The match is walked again: what was matched and what of
@@ -699,7 +832,16 @@ class KVCacheManager:
             self.allocator.register(page, hashes[b])
             if self.allocator.lookup(hashes[b]) == page:  # publish took effect
                 self._page_tokens[page] = self._block(token_ids, b)
+            if b in alloc.win_pages:
+                self._publish_window(alloc.win_pages[b], hashes[b],
+                                     self._block(token_ids, b))
         alloc.registered_blocks = max(alloc.registered_blocks, max_blocks)
+
+    def _publish_window(self, page: int, block_hash: int,
+                        tokens: tuple[int, ...]) -> None:
+        self.win_allocator.register(page, block_hash)
+        if self.win_allocator.lookup(block_hash) == page:
+            self._win_tokens[page] = tokens
 
     def take_snapshot(self, seq_id: str, token_ids: Sequence[int],
                       hashes: Optional[list[int]] = None) -> Optional[int]:
@@ -768,6 +910,9 @@ class KVCacheManager:
         (acquire/free) across the device→host copy so pool pressure
         cannot recycle them mid-export.
         """
+        if self.window is not None:
+            raise ValueError("page export between replicas is not built for "
+                             "a model with window layers")
         pages, keep_hashes, blocks = self._matched_chain(prompt_ids, hashes,
                                                          hash_seed)
         if max_pages is not None:
@@ -875,6 +1020,9 @@ class KVCacheManager:
         only, one batched pool write, contiguous-prefix stop on a full
         pool) live in :meth:`_install_blocks` — partial prefixes are
         still byte-exact wins."""
+        if self.window is not None:
+            raise ValueError("page import between replicas is not built for "
+                             "a model with window layers")
         self.last_import_digest_mismatch = False
         if exported.page_size != self.page_size \
                 or not self._leaves_compatible(kv_k, exported.leaves_k):
@@ -1004,15 +1152,68 @@ class KVCacheManager:
         if new_ctx_len > self.max_pages_per_seq * self.page_size:
             raise MemoryError(f"sequence {seq_id} exceeds max_seq_len")
         need = alloc.pages_needed(new_ctx_len, self.page_size)
+        win_need = self._window_turn(alloc, new_ctx_len)
         if need:
             alloc.pages.extend(self.allocator.alloc(need))
             self.version += 1
+        if win_need:
+            alloc.win_pages.update(
+                zip(win_need, self.win_allocator.alloc(len(win_need))))
         alloc.ctx_len = new_ctx_len
+
+    def _window_turn(self, alloc: SequenceAllocation, new_ctx_len: int) -> list[int]:
+        """The window group's side of an extension to ``new_ctx_len``: give
+        back the pages no later query can see, and name the blocks the next
+        dispatch writes or reads that the sequence does not hold yet. The
+        next dispatch's first query is at ``new_ctx_len - max_step`` or
+        later, whoever asks (a prefill chunk, a decode window, the mixed
+        step asking twice), so the edge is taken from there: the sequence's
+        rows stay under ``WindowSpec.rows_bound``. Raises ``MemoryError``,
+        with nothing given out, where the window pool cannot hold them (it
+        is sized so that it can: ``WindowSpec.pages``)."""
+        if self.window is None:
+            return []
+        ps = self.page_size
+        keep = self.window.first_block(max(0, new_ctx_len - self.window.max_step), ps)
+        behind = [b for b in alloc.win_pages if b < keep]
+        if behind:
+            for b in behind:
+                page = alloc.win_pages.pop(b)
+                if alloc.chain is not None and b < len(alloc.chain) \
+                        and (b + 1) * ps <= len(alloc.prompt):
+                    # Published as it goes, like a page of the prompt at a
+                    # prefill's end: it stays matchable until the pool
+                    # needs it back.
+                    self._publish_window(page, alloc.chain[b],
+                                         self._block(alloc.prompt, b))
+                self.win_allocator.free([page])
+            self.window_counters["rows_released"] += len(behind) * ps
+            self.version += 1
+        alloc.win_lo = max(alloc.win_lo, keep)
+        want = [b for b in range(alloc.win_lo, -(-new_ctx_len // ps))
+                if b not in alloc.win_pages]
+        if len(want) > self.win_allocator.free_pages:
+            raise MemoryError(
+                f"window page pool exhausted: want {len(want)}, have "
+                f"{self.win_allocator.free_pages}")
+        if want:
+            self.version += 1
+        return want
+
+    def window_rows(self, seq_id: str) -> int:
+        """Token rows ``seq_id`` holds in the window group."""
+        return len(self.seqs[seq_id].win_pages) * self.page_size
 
     def can_extend(self, seq_id: str, new_ctx_len: int) -> bool:
         alloc = self.seqs.get(seq_id)
         if alloc is None:
             return False
+        if self.window is not None:
+            # (an upper bound: the turn gives pages back before it takes any)
+            blocks = -(-new_ctx_len // self.page_size) - max(
+                alloc.win_lo, max(alloc.win_pages, default=-1) + 1)
+            if blocks > self.win_allocator.free_pages:
+                return False
         return alloc.pages_needed(new_ctx_len, self.page_size) <= self.allocator.free_pages
 
     def can_admit(self, prompt_len: int, headroom_tokens: int = 0,
@@ -1031,6 +1232,8 @@ class KVCacheManager:
             self.register_prefix(seq_id, token_ids)
         del self.seqs[seq_id]
         self.allocator.free(alloc.pages)
+        if alloc.win_pages:
+            self.win_allocator.free(list(alloc.win_pages.values()))
         self.version += 1
 
     # ------------------------------------------------------------ page tables
@@ -1040,6 +1243,18 @@ class KVCacheManager:
         row = np.full(self.max_pages_per_seq, PageAllocator.NULL_PAGE, dtype=np.int32)
         pages = self.seqs[seq_id].pages
         row[: len(pages)] = pages
+        return row
+
+    def window_table_row(self, seq_id: str) -> np.ndarray:
+        """The row's second half, for the window group: the window pool's
+        page of every block the sequence holds there, null elsewhere (a
+        block behind the window is never read: the walks start at the
+        window's edge)."""
+        row = np.full(self.max_pages_per_seq, PageAllocator.NULL_PAGE, dtype=np.int32)
+        held = self.seqs[seq_id].win_pages
+        if held:
+            row[np.fromiter(held.keys(), np.int64, len(held))] = np.fromiter(
+                held.values(), np.int32, len(held))
         return row
 
     def page_tables(self, seq_ids: list[str]) -> np.ndarray:

@@ -52,6 +52,11 @@ class ServingPlan:
     # pool of ``batch`` slots and its snapshot pool, already taken out of
     # ``pool_budget_bytes``. 0 for every other model.
     state_pool_bytes: int = 0
+    # A model with window layers (``cfg.kv_window_spec``): the window
+    # group's pool, every slot at its bound of rows, already taken out of
+    # ``pool_budget_bytes``; ``kv_bytes_per_token_per_chip`` is then the
+    # full-attention group's alone. 0 for every other model.
+    window_pool_bytes: int = 0
 
     @property
     def context_bytes_per_chip(self) -> float:
@@ -83,6 +88,9 @@ class ServingPlan:
         if self.state_pool_bytes:
             spill += (f"; recurrent state pool {self.state_pool_bytes / GiB:.2f}"
                       f" GiB (slots and snapshots, out of the pool budget)")
+        if self.window_pool_bytes:
+            spill += (f"; window layers' pool {self.window_pool_bytes / GiB:.2f}"
+                      f" GiB (every slot at its bound, out of the pool budget)")
         return (
             f"{self.model} tp{self.tp} (kv{self.kv_shards}×pg"
             f"{self.pg_shards}): weights {self.weight_bytes_per_chip / GiB:.2f}"
@@ -107,6 +115,7 @@ def plan_serving(
     headroom_bytes: int = int(1.5 * GiB),
     kv_spill_pages: int = 0,
     page_size: int = 16,
+    prefill_chunk: int = 512,
 ) -> ServingPlan:
     """Arithmetic plan for serving ``cfg`` at ``max_seq_len`` × ``batch``.
 
@@ -124,7 +133,7 @@ def plan_serving(
     # The pool's layout is the configuration's own (two K/V sides of n_kv
     # heads a layer, or a latent and a rotated key a sublayer).
     sides = cfg.kv_pool_spec  # (layers, heads, values a head), K and V
-    if hasattr(cfg, "n_kv_heads"):
+    if hasattr(cfg, "ffn_dim"):  # the llama family: laid out across chips
         plan = plan_kv_split(cfg, tp)
         layer_matmul = cfg.matmul_params - cfg.dim * cfg.vocab_size
         wkv = cfg.n_layers * 2 * cfg.dim * cfg.n_kv_heads * cfg.head_dim
@@ -156,7 +165,19 @@ def plan_serving(
         slot = sum(math.prod(shape) * np.dtype(dtype).itemsize
                    for shape, dtype in cfg.state_pool_spec)
         state_bytes = (batch + cfg.state_snapshots) * slot
-    budget = max(0, hbm_bytes - int(per_chip) - headroom_bytes - state_bytes)
+    # Window layers' rows do not grow with the context: a pool of their
+    # own, a slot at ``window + prefill_chunk + 2 pages`` rows.
+    window_bytes = 0
+    if getattr(cfg, "kv_window_spec", None):
+        from runbookai_tpu.engine.kv_cache import WindowSpec
+
+        layers, window = cfg.kv_window_spec
+        spec = WindowSpec(layers, window, prefill_chunk, batch)
+        _, heads, dim = sides[0]
+        window_bytes = (spec.pages(page_size) * page_size * layers * 2 * heads
+                        * (dim * kv_dtype_bytes + kv_scale_bytes))
+    budget = max(0, hbm_bytes - int(per_chip) - headroom_bytes - state_bytes
+                 - window_bytes)
     return ServingPlan(
         model=cfg.name, tp=tp, kv_shards=plan.kv_shards,
         pg_shards=plan.pg_shards, hbm_bytes=hbm_bytes,
@@ -164,5 +185,5 @@ def plan_serving(
         kv_bytes_per_token_per_chip=kv_per_token,
         pool_budget_bytes=budget, max_seq_len=max_seq_len, batch=batch,
         host_spill_bytes=int(kv_spill_pages * page_size * spill_token),
-        state_pool_bytes=state_bytes,
+        state_pool_bytes=state_bytes, window_pool_bytes=window_bytes,
     )

@@ -510,6 +510,12 @@ class JaxTpuClient(BaseLLMClient):
             "state_pool_bytes": sum(
                 leaf.nbytes
                 for leaf in jax.tree.leaves((core._state, core._snaps))),
+            # The window layers' pool (0 for a model without window
+            # layers): part of ``kv_pool_bytes``, both sides.
+            "kv_window_pool_bytes": sum(
+                leaf.nbytes for side in (core._kv_k, core._kv_v)
+                if isinstance(side, dict)
+                for leaf in jax.tree.leaves(side["window"])),
             "compile_cache_dir": jax.config.jax_compilation_cache_dir,
             # Which devices hold each engine replica's KV pool.
             "replicas": [

@@ -47,12 +47,79 @@ _BIAS_MAP = {
 }
 
 
-# HF model_type values this loader serves. All share the Llama block
-# (pre-norm GQA attention + SwiGLU); qwen2 adds q/k/v projection biases,
-# mixtral swaps the dense FFN for an 8-expert top-2 MoE. Mistral
-# sliding-window checkpoints load fine and are served with full attention
-# (exact for contexts up to the window).
-SUPPORTED_MODEL_TYPES = ("llama", "qwen2", "mistral", "mixtral")
+# HF model_type values this loader serves. The first four share the Llama
+# block (pre-norm GQA attention + SwiGLU); qwen2 adds q/k/v projection
+# biases, mixtral swaps the dense FFN for an 8-expert top-2 MoE. A window is
+# served as a window where the family declares one (``afmoe``:
+# models/afmoe.py, its sliding layers over a pool of their own); the Llama
+# block declares none, so a Mistral sliding-window checkpoint loads and is
+# served with full attention, exact for contexts up to the window.
+SUPPORTED_MODEL_TYPES = ("llama", "qwen2", "mistral", "mixtral", "afmoe")
+
+# afmoe tensor names (``modeling_afmoe.py``): leaf -> (template, transpose).
+# Attention and the four norms a layer; the dense FFN of the leading layers;
+# router, balance bias, shared expert and routed experts of the rest.
+_AFMOE_LAYER_MAP = {
+    "wq": ("self_attn.q_proj.weight", True),
+    "wk": ("self_attn.k_proj.weight", True),
+    "wv": ("self_attn.v_proj.weight", True),
+    "wg": ("self_attn.gate_proj.weight", True),
+    "wo": ("self_attn.o_proj.weight", True),
+    "q_norm": ("self_attn.q_norm.weight", False),
+    "k_norm": ("self_attn.k_norm.weight", False),
+    "norm1": ("input_layernorm.weight", False),
+    "norm2": ("post_attention_layernorm.weight", False),
+    "norm3": ("pre_mlp_layernorm.weight", False),
+    "norm4": ("post_mlp_layernorm.weight", False),
+}
+_AFMOE_FFN = (("gate", "gate_proj"), ("up", "up_proj"), ("down", "down_proj"))
+
+
+def afmoe_config_from_hf(raw: dict, name: str):
+    """The program's configuration of an ``afmoe`` ``config.json``: every
+    key that is a field of the dataclass, every expert held."""
+    import dataclasses
+
+    from runbookai_tpu.models.afmoe import AfmoeConfig
+
+    fields = {f.name for f in dataclasses.fields(AfmoeConfig)} - {"name", "family"}
+    return AfmoeConfig(name=name, n_experts_held=raw["num_experts"],
+                       **{k: v for k, v in raw.items() if k in fields})
+
+
+def load_afmoe_params(model_dir: str | Path, cfg, dtype=jnp.bfloat16):
+    """Stacked params (``models/afmoe.py`` ``leaf_shapes``) from an HF
+    ``afmoe`` directory: the held experts ``first_expert ..`` of every
+    expert layer, the router float32 with all its outputs."""
+    idx = _ShardIndex(Path(model_dir))
+    L, k = cfg.num_hidden_layers, cfg.num_dense_layers
+
+    def get(i: int, suffix: str, transpose: bool) -> np.ndarray:
+        w = idx.get(f"model.layers.{i}.{suffix}")
+        return w.T if transpose else w
+
+    layers: dict[str, Any] = {}
+    for leaf, (suffix, transpose) in _AFMOE_LAYER_MAP.items():
+        layers[leaf] = _put(np.stack([get(i, suffix, transpose) for i in range(L)]),
+                            jnp.float32 if "norm" in leaf else dtype)
+    held = range(cfg.first_expert, cfg.first_expert + cfg.n_experts_held)
+    for short, proj in _AFMOE_FFN:
+        layers[f"d_{short}"] = _put(np.stack(
+            [get(i, f"mlp.{proj}.weight", True) for i in range(k)]), dtype)
+        layers[f"s_{short}"] = _put(np.stack(
+            [get(i, f"mlp.shared_experts.{proj}.weight", True)
+             for i in range(k, L)]), dtype)
+        layers[f"e_{short}"] = _put(np.stack(
+            [np.stack([get(i, f"mlp.experts.{e}.{proj}.weight", True) for e in held])
+             for i in range(k, L)]), dtype)
+    layers["router"] = _put(np.stack(
+        [get(i, "mlp.router.gate.weight", True) for i in range(k, L)]), jnp.float32)
+    layers["router_bias"] = _put(np.stack(
+        [get(i, "mlp.expert_bias", False) for i in range(k, L)]), jnp.float32)
+    return cfg, {"embed": _put(idx.get("model.embed_tokens.weight"), dtype),
+                 "layers": layers,
+                 "final_norm": _put(idx.get("model.norm.weight"), jnp.float32),
+                 "lm_head": _put(idx.get("lm_head.weight").T, dtype)}
 
 
 def config_from_hf(model_dir: str | Path, name: str = "hf-model") -> LlamaConfig:
@@ -78,6 +145,8 @@ def config_from_hf(model_dir: str | Path, name: str = "hf-model") -> LlamaConfig
         raise ValueError(
             f"model_type {model_type!r} not supported; known: "
             f"{SUPPORTED_MODEL_TYPES}")
+    if model_type == "afmoe":
+        return afmoe_config_from_hf(raw, name)
     # Llama-3.1-style long-context rope scaling (rope_type "llama3").
     # Other scaling schemes (linear/dynamic/yarn) would silently produce
     # wrong logits past the original context if dropped — refuse loudly,
@@ -107,9 +176,11 @@ def config_from_hf(model_dir: str | Path, name: str = "hf-model") -> LlamaConfig
         rope_theta=raw.get("rope_theta", 500_000.0),
         rope_scaling=rope_scaling,
         norm_eps=raw.get("rms_norm_eps", 1e-5),
-        # Sliding-window checkpoints (Mistral v0.1) are served with full
-        # attention — exact only up to the window, so the window clamps the
-        # serveable context rather than silently changing semantics past it.
+        # The Llama block declares no window (a family that does serves it
+        # as one: models/afmoe.py): its sliding-window checkpoints (Mistral
+        # v0.1) are served with full attention — exact only up to the window,
+        # so the window clamps the serveable context rather than silently
+        # changing semantics past it.
         max_seq_len=min(raw.get("max_position_embeddings", 8192),
                         raw.get("sliding_window") or 1 << 30),
         tie_embeddings=raw.get("tie_word_embeddings", False),
@@ -180,6 +251,12 @@ def load_params(
 
     model_dir = Path(model_dir)
     cfg = cfg or config_from_hf(model_dir)
+    if not isinstance(cfg, LlamaConfig):  # afmoe: its own leaves, one chip
+        if quantize_int8 or shardings:
+            raise ValueError(
+                f"model {cfg.name!r} (family afmoe) serves bf16 or float32 "
+                f"weights on one chip: no int8 matrices, no mesh")
+        return load_afmoe_params(model_dir, cfg, dtype)
     idx = _ShardIndex(model_dir)
     sh = shardings or {}
 
@@ -286,6 +363,7 @@ def load_or_init(
     Random init keeps every serving path exercisable in the no-egress
     environment (BASELINE.md configs run with real weights when provided).
     """
+    from runbookai_tpu.models.afmoe import AfmoeConfig
     from runbookai_tpu.models.joyai import JoyaiConfig
     from runbookai_tpu.models.longcat import LongcatConfig
     from runbookai_tpu.models.nemotron_h import NemotronHConfig
@@ -342,7 +420,9 @@ def load_or_init(
             f"unknown model {model_name!r} and no checkpoint at "
             f"{str(model_path)!r}; known configs: {sorted(CONFIGS)}")
     cfg = CONFIGS[model_name]
-    if longcat or qwen3_next or joyai or nemotron_h:
+    afmoe = isinstance(cfg, AfmoeConfig)
+    if longcat or qwen3_next or joyai or nemotron_h or afmoe:
+        from runbookai_tpu.models import afmoe as afmoe_model
         from runbookai_tpu.models import joyai as joyai_model
         from runbookai_tpu.models import longcat as longcat_model
         from runbookai_tpu.models import nemotron_h as nemotron_h_model
@@ -351,6 +431,7 @@ def load_or_init(
         model, family = ((longcat_model, "longcat") if longcat else
                          (qwen3_next_model, "qwen3-next") if qwen3_next else
                          (nemotron_h_model, "nemotron-h") if nemotron_h else
+                         (afmoe_model, "afmoe") if afmoe else
                          (joyai_model, "joyai"))
         if quantize_int8 or shardings:
             raise ValueError(

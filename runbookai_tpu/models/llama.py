@@ -34,6 +34,8 @@ from typing import Any, Optional
 import jax
 import jax.numpy as jnp
 
+from runbookai_tpu.models.afmoe import CONFIGS as _AFMOE_CONFIGS
+from runbookai_tpu.models.afmoe import AfmoeConfig
 from runbookai_tpu.models.joyai import CONFIGS as _JOYAI_CONFIGS
 from runbookai_tpu.models.joyai import JoyaiConfig
 from runbookai_tpu.models.longcat import CONFIGS as _LONGCAT_CONFIGS
@@ -147,7 +149,7 @@ class LlamaConfig:
 
 
 AnyConfig = (LlamaConfig | LongcatConfig | Qwen3NextConfig | JoyaiConfig
-             | NemotronHConfig)
+             | NemotronHConfig | AfmoeConfig)
 
 CONFIGS: dict[str, AnyConfig] = {
     "llama3-8b-instruct": LlamaConfig(
@@ -266,6 +268,9 @@ CONFIGS: dict[str, AnyConfig] = {
     # A pattern of single-mixer layers: Mamba-2 state-space layers beside
     # position-free attention and two-matrix experts (models/nemotron_h.py).
     **_NEMOTRON_H_CONFIGS,
+    # Sliding-window layers beside full ones over two groups of the paged
+    # pool, sparse experts past two dense layers (models/afmoe.py).
+    **_AFMOE_CONFIGS,
 }
 
 

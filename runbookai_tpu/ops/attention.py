@@ -137,8 +137,16 @@ def paged_attention(
     block_pages: int = 32,
     walk_live: bool = False,
     gather_pages: bool = False,
+    window: int | None = None,
 ) -> jnp.ndarray:
     """Blockwise ragged paged attention. Returns [B, T, n_q, head_dim].
+
+    ``window``: a query at position ``i`` sees key ``j`` iff ``i - j <
+    window`` (and ``j <= i``): the mask gets a lower edge, and the walk
+    starts at the block that holds the batch's lowest one. The table's
+    columns behind a row's window may point anywhere (the manager gives
+    those pages back while the sequence lives): they are masked. None: no
+    edge is computed and the program is what it was.
 
     ``walk_live``: stop at the block that holds the batch's longest context
     (a loop whose length the device decides) instead of walking every block
@@ -192,6 +200,8 @@ def paged_attention(
         # Causal + ragged mask: position visible iff < ctx_len and <= q_position.
         valid = (cache_pos[None, :] < ctx_lens[:, None])[:, None, :]  # [B,1,block]
         causal = cache_pos[None, None, :] <= q_positions[:, :, None]  # [B,T,block]
+        if window is not None:
+            causal &= cache_pos[None, None, :] > q_positions[:, :, None] - window
         mask = (valid & causal)[:, :, None, None, :]  # [B,T,1,1,block]
 
         scores = jnp.einsum("btkgd,bskd->btkgs", qf, kb)  # [B,T,n_kv,group,block]
@@ -209,11 +219,18 @@ def paged_attention(
     m0 = jnp.full((b, t, n_kv, group), NEG_INF, dtype=jnp.float32)
     l0 = jnp.zeros((b, t, n_kv, group), dtype=jnp.float32)
     acc0 = jnp.zeros((b, t, n_kv, group, d), dtype=jnp.float32)
-    if walk_live:
+    if walk_live or window is not None:
         live_blocks = jnp.minimum(
             n_blocks, (jnp.max(ctx_lens) + block_tokens - 1) // block_tokens)
+        first = 0
+        if window is not None:
+            # The lowest edge of the batch's live queries (a pad's position
+            # is the trash column's, past every context).
+            lowest = jnp.min(jnp.where(q_positions < ctx_lens[:, None],
+                                       q_positions, jnp.iinfo(jnp.int32).max))
+            first = jnp.clip((lowest - window + 1) // block_tokens, 0, live_blocks)
         m, l, acc = jax.lax.fori_loop(
-            0, live_blocks, lambda blk, c: block_step(c, blk)[0], (m0, l0, acc0))
+            first, live_blocks, lambda blk, c: block_step(c, blk)[0], (m0, l0, acc0))
     else:
         (m, l, acc), _ = jax.lax.scan(block_step, (m0, l0, acc0), jnp.arange(n_blocks))
 
@@ -232,6 +249,7 @@ def ragged_paged_attention(
     page_size: int,
     block_pages: int = 32,
     ragged_block: int = 8,
+    window: int | None = None,
 ) -> jnp.ndarray:
     """Portable XLA ragged paged attention over a FLAT mixed token batch.
 
@@ -265,6 +283,6 @@ def ragged_paged_attention(
     out = paged_attention(
         q.reshape(nb, rq, n_q, d), k_flat, v_flat,
         page_tables[rows], ctx_lens[rows], q_positions.reshape(nb, rq),
-        page_size, block_pages=block_pages,
+        page_size, block_pages=block_pages, window=window,
     )
     return out.reshape(n, n_q, d)

@@ -133,18 +133,23 @@ def _flash_output(l_ref, acc_ref):
 
 
 def _walk_pages(n_steps, pages_per_step: int, page, place, srcs, bufs,
-                sems, accumulate) -> None:
+                sems, accumulate, first=None) -> None:
     """The page walk of BOTH kernels: ``n_steps`` groups of
     ``pages_per_step`` table columns, one async copy a LIVE page (and
     source) into the group's VMEM slot, the next group's copies in flight
     while ``accumulate(step, slot)`` works on this one. ``page(col)`` says
     whether the walk reads table column ``col`` and where the page lies in
     the sources; ``place(buf, slot, i)`` is the group's ``i``-th page in a
-    buffer. A dead column is never fetched."""
+    buffer. A dead column is never fetched. ``first`` (a window's lower
+    edge): the group the walk starts at, ``n_steps`` counted from it; None
+    is group 0 and adds nothing to the program."""
+    def group(step):
+        return step if first is None else first + step
+
     def copies(step, slot, wait: bool) -> None:
         """Start, or wait for, the copies of one group into ``slot``."""
         def one(i, _):
-            live, pid = page(step * pages_per_step + i)
+            live, pid = page(group(step) * pages_per_step + i)
 
             @pl.when(live)
             def _():
@@ -177,14 +182,15 @@ def _walk_pages(n_steps, pages_per_step: int, page, place, srcs, bufs,
             copies(i + 1, 1 - slot, wait=False)
 
         copies(i, slot, wait=True)
-        accumulate(i, slot)
+        accumulate(group(i), slot)
 
     jax.lax.fori_loop(0, n_steps, step, None)
 
 
 def _decode_walk_kernel(*refs, page_size: int, n_kv: int, group: int,
                         pages_per_step: int, sm_scale: float, scaled: bool,
-                        pages_local: int | None, layer_pages: int):
+                        pages_local: int | None, layer_pages: int,
+                        window: int | None = None):
     """One grid step = one row: walk its live pages, ``pages_per_step`` at a
     time. What a page is and what the row writes at the end are the two
     things the pools differ in:
@@ -200,6 +206,12 @@ def _decode_walk_kernel(*refs, page_size: int, n_kv: int, group: int,
 
     The sources hold every layer's pages, ``layer_pages`` a layer: the
     walk reads those of the scalar-prefetched layer.
+
+    ``window``: the row's query, at ``ctx - 1``, sees the last ``window``
+    positions only. The walk starts at the group that holds ``ctx -
+    window``, the pages wholly behind it are dead columns (their table
+    entries may be anything: the manager gave them back), and the mask
+    drops the keys behind it in the edge's own page.
     """
     partial = pages_local is not None
     n_src = 4 if scaled else 2
@@ -225,11 +237,14 @@ def _decode_walk_kernel(*refs, page_size: int, n_kv: int, group: int,
     last_col = tables_ref.shape[1] - 1
 
     _flash_init(m_ref, l_ref, acc_ref)
+    lo = None if window is None else jnp.maximum(ctx - window, 0)
 
     def page(col):
         """Table column ``col`` of this row: whether the walk reads it,
         and its index in the sources."""
         live = col * page_size < ctx
+        if lo is not None:
+            live = live & ((col + 1) * page_size > lo)
         pid = tables_ref[row, jnp.minimum(col, last_col)]
         if partial:
             live = live & (pid // pages_local == shard)
@@ -268,6 +283,8 @@ def _decode_walk_kernel(*refs, page_size: int, n_kv: int, group: int,
 
     def accumulate(i, slot):
         valid = own_head & (i * span + col_pos < ctx)
+        if lo is not None:
+            valid = valid & (i * span + col_pos >= lo)
         if partial:
             col_page = jax.lax.div(col, rows_per_page)
             mine = jax.lax.fori_loop(
@@ -283,9 +300,13 @@ def _decode_walk_kernel(*refs, page_size: int, n_kv: int, group: int,
             k_scale=scale_row(bufs[2], slot) if scaled else None,
             v_scale=scale_row(bufs[3], slot) if scaled else None)
 
-    _walk_pages(pl.cdiv(ctx, span), pages_per_step, page,
+    first, n_steps = None, pl.cdiv(ctx, span)
+    if lo is not None:
+        first = lo // span
+        n_steps = n_steps - first
+    _walk_pages(n_steps, pages_per_step, page,
                 lambda buf, slot, i: buf.at[slot, i], srcs, bufs, sems,
-                accumulate)
+                accumulate, first)
 
     if partial:
         outs[0][0], outs[1][0], outs[2][0] = acc_ref[:], m_ref[:], l_ref[:]
@@ -363,7 +384,7 @@ def _stacked(pools, layer):
 
 def _decode_walk(q, k_flat, v_flat, page_tables, ctx_lens, page_size: int,
                  interpret: bool, shard=None, pages_local: int | None = None,
-                 layer=None):
+                 layer=None, window: int | None = None):
     """Launch :func:`_decode_walk_kernel` over the rows of ``q``. The page
     table is the call's first operand and the result ``[rows, n_q, hd]``:
     the benchmark finds the kernel in a trace by those two shapes
@@ -371,7 +392,8 @@ def _decode_walk(q, k_flat, v_flat, page_tables, ctx_lens, page_size: int,
     the lanes (the test-size models) is zero-padded to them, which leaves
     every score and, once sliced, the output what they were. With a
     ``layer`` the pools are ``[L, tokens, ...]`` and the sources their
-    page views with L merged in front."""
+    page views with L merged in front. A call with a ``window`` is named
+    for it in the trace (``swa_decode_walk``)."""
     b, n_q, hd = q.shape
     (k_flat, v_flat), layer = _stacked((k_flat, v_flat), layer)
     scaled = isinstance(k_flat, tuple)
@@ -407,7 +429,7 @@ def _decode_walk(q, k_flat, v_flat, page_tables, ctx_lens, page_size: int,
             _decode_walk_kernel, page_size=page_size, n_kv=n_kv,
             group=n_q // n_kv, pages_per_step=g, sm_scale=hd ** -0.5,
             scaled=scaled, pages_local=pages_local,
-            layer_pages=srcs[0].shape[0] // n_layers),
+            layer_pages=srcs[0].shape[0] // n_layers, window=window),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=len(prefetch),
             grid=(b,),
@@ -424,6 +446,7 @@ def _decode_walk(q, k_flat, v_flat, page_tables, ctx_lens, page_size: int,
         out_shape=[jax.ShapeDtypeStruct((b, n_q, w), dt)
                    for w, dt in out_kinds],
         interpret=interpret,
+        name=None if window is None else "swa_decode_walk",
     )(*prefetch, q, *srcs)
     out, *stats = outs
     return (out[..., :hd], *stats) if partial else out[..., :hd]
@@ -438,19 +461,21 @@ def paged_decode_attention(
     page_size: int,
     interpret: bool = False,
     layer=None,  # with it the pools are [L, tokens, ...]: read layer `layer`
+    window: int | None = None,  # the row sees its last `window` positions
 ) -> jnp.ndarray:
     """Ragged paged attention for decode (one query token per sequence).
     An int8 pool is ``(values [tokens, n_kv, hd], f32 scales [tokens,
     n_kv])``: HBM still moves 1 byte a value, widened in VMEM."""
     return _decode_walk(q, k_flat, v_flat, page_tables, ctx_lens, page_size,
-                        interpret, layer=layer)
+                        interpret, layer=layer, window=window)
 
 
 def _chunk_walk_kernel(tables_ref, ctx_ref, q_start_ref, layer_ref, q_ref,
                        k_src, v_src, o_ref, k_buf, v_buf, sems, q_scr, m_ref,
                        l_ref, acc_ref, *, page_size: int, n_kv: int,
                        group: int, tq: int, pages_per_step: int,
-                       sm_scale: float, layer_pages: int):
+                       sm_scale: float, layer_pages: int,
+                       window: int | None = None):
     """One grid step = one block of ``tq`` queries of one row: walk the
     pages it can see, ``pages_per_step`` at a time. The causal bound ends
     the walk as well as the context does, so a prompt's first block walks
@@ -463,7 +488,12 @@ def _chunk_walk_kernel(tables_ref, ctx_ref, q_start_ref, layer_ref, q_ref,
     each head's block goes through :func:`_flash_accumulate` against ITS
     keys ``[positions, hd]`` alone — at ``tq * n_q`` score rows the
     decode walk's one product over every head would spend ``n_kv - 1``
-    parts in ``n_kv`` of its operations on masked columns."""
+    parts in ``n_kv`` of its operations on masked columns.
+
+    ``window``: a query at ``p`` sees the keys ``p - window < j <= p``. The
+    walk starts at the group that holds the block's FIRST query's edge,
+    the pages wholly behind that edge are dead columns, and the mask drops
+    the keys behind each query's own."""
     row, qb = pl.program_id(0), pl.program_id(1)
     ctx = ctx_ref[row]
     # Query positions are contiguous per row (the wrapper's contract), so
@@ -482,10 +512,13 @@ def _chunk_walk_kernel(tables_ref, ctx_ref, q_start_ref, layer_ref, q_ref,
             q[:, h * group:(h + 1) * group].reshape(rows, hd))
 
     first_page = layer_ref[0] * layer_pages
+    lo = None if window is None else jnp.maximum(q0 - window + 1, 0)
 
     def page(col):
-        return (col * page_size < seen,
-                first_page + tables_ref[row, jnp.minimum(col, last_col)])
+        live = col * page_size < seen
+        if lo is not None:
+            live = live & ((col + 1) * page_size > lo)
+        return live, first_page + tables_ref[row, jnp.minimum(col, last_col)]
 
     # The mask is per query ROW of a head's block, built from 2D iotas.
     pos = jax.lax.broadcasted_iota(jnp.int32, (1, span), 1)
@@ -495,6 +528,8 @@ def _chunk_walk_kernel(tables_ref, ctx_ref, q_start_ref, layer_ref, q_ref,
     def accumulate(i, slot):
         at = i * span + pos
         valid = (at < ctx) & (at <= q_pos)  # [rows, span]
+        if window is not None:
+            valid = valid & (at > q_pos - window)
         for h in range(n_kv):
             mine = pl.ds(h * rows, rows)
             k, v = ((buf[slot] if n_kv == 1 else buf[slot, :, h, :])
@@ -502,10 +537,14 @@ def _chunk_walk_kernel(tables_ref, ctx_ref, q_start_ref, layer_ref, q_ref,
             _flash_accumulate(q_scr[mine], k, v, valid, m_ref.at[mine],
                               l_ref.at[mine], acc_ref.at[mine])
 
+    first, n_steps = None, pl.cdiv(seen, span)
+    if lo is not None:
+        first = lo // span
+        n_steps = n_steps - first
     _walk_pages(
-        pl.cdiv(seen, span), pages_per_step, page,
+        n_steps, pages_per_step, page,
         lambda buf, slot, i: buf.at[slot, pl.ds(i * page_size, page_size)],
-        [k_src, v_src], [k_buf, v_buf], sems, accumulate)
+        [k_src, v_src], [k_buf, v_buf], sems, accumulate, first)
 
     out = _flash_output(l_ref, acc_ref)  # [tq * n_q, hd], kv-major
     # Per-head static slices back to [tq, group, hd] (no 4D transpose).
@@ -539,6 +578,7 @@ def paged_chunk_attention(
     interpret: bool = False,
     q_block: int | None = None,
     layer=None,  # with it the pools are [L, tokens, ...]: read layer `layer`
+    window: int | None = None,  # a query sees its last `window` positions
 ) -> jnp.ndarray:
     """Ragged paged attention for T>1 chunks (prefill / speculative verify).
 
@@ -572,7 +612,7 @@ def paged_chunk_attention(
         functools.partial(
             _chunk_walk_kernel, page_size=page_size, n_kv=n_kv,
             group=n_q // n_kv, tq=tq, pages_per_step=g, sm_scale=hd ** -0.5,
-            layer_pages=pages[0].shape[0] // n_layers),
+            layer_pages=pages[0].shape[0] // n_layers, window=window),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=4,
             grid=(b, t_pad // tq),
@@ -589,6 +629,7 @@ def paged_chunk_attention(
         ),
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         interpret=interpret,
+        name=None if window is None else "swa_chunk_walk",
     )(page_tables, ctx_lens, q_positions[:, 0].astype(jnp.int32), layer, q,
       *pages)
     return out[:, :t, :, :hd]
@@ -606,6 +647,7 @@ def paged_ragged_attention(
     ragged_block: int = 8,
     interpret: bool = False,
     layer=None,  # with it the pools are [L, tokens, ...]: read layer `layer`
+    window: int | None = None,
 ) -> jnp.ndarray:
     """Ragged paged attention over a FLAT mixed prefill+decode batch.
 
@@ -636,6 +678,7 @@ def paged_ragged_attention(
         q.reshape(nb, rq, n_q, hd), k_flat, v_flat,
         page_tables[rows], ctx_lens[rows], q_positions.reshape(nb, rq),
         page_size=page_size, interpret=interpret, q_block=rq, layer=layer,
+        window=window,
     ).reshape(n, n_q, hd)
 
 
