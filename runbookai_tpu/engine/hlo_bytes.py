@@ -24,6 +24,10 @@ COMPILED program (VERDICT r4 next-round #2):
   pool in place.
 - :func:`sorts_by_conditional` counts the ``sort`` instructions every run
   pays beside those behind a ``conditional`` (the sampler's).
+- :func:`held_expert_copies` hunts one held expert's matrix, or a stack of
+  them, copied in front of the product that reads it in place;
+  :func:`expert_conditionals` finds the held experts' dispatch and the
+  loop over the experts touched inside it.
 - :func:`lower_decode` lowers+compiles the engine's REAL decode dispatch
   (the same jitted ``_decode_step`` serving uses) without executing it,
   so the analysis covers the program that runs, not a proxy.
@@ -360,6 +364,17 @@ def _called(line: str) -> list[str]:
     return names
 
 
+def _reached(comps: dict[str, list[str]], names: Iterable[str]) -> set[str]:
+    """The computations ``names`` and every computation they call."""
+    todo, seen = list(names), set()
+    while todo:
+        name = todo.pop()
+        if name not in seen and name in comps:
+            seen.add(name)
+            todo += [n for line in comps[name] for n in _called(line)]
+    return seen
+
+
 def sorts_by_conditional(hlo_text: str) -> tuple[int, int]:
     """(inside, outside): the ``sort`` instructions of a compiled program
     that lie in a branch of a ``conditional`` (in the branch's computation
@@ -369,14 +384,8 @@ def sorts_by_conditional(hlo_text: str) -> tuple[int, int]:
     cell's size a ``sort`` of ``f32[16, 152064]`` outside a branch was 3.64
     ms of a 16.04 ms pass (PERF.md section 6, PR 40)."""
     comps = _computations(hlo_text)
-    behind = [name for lines in comps.values() for line in lines
-              if _op(line) == "conditional" for name in _called(line)]
-    branch: set[str] = set()
-    while behind:
-        name = behind.pop()
-        if name not in branch and name in comps:
-            branch.add(name)
-            behind += [n for line in comps[name] for n in _called(line)]
+    branch = _reached(comps, [name for lines in comps.values() for line in lines
+                              if _op(line) == "conditional" for name in _called(line)])
     inside = outside = 0
     for name, lines in comps.items():
         n = sum(_op(line) == "sort" for line in lines)
@@ -385,6 +394,53 @@ def sorts_by_conditional(hlo_text: str) -> tuple[int, int]:
         else:
             outside += n
     return inside, outside
+
+
+_LAYOUT = re.compile(r"\{[^{}]*\}")
+
+
+def held_expert_copies(hlo_text: str,
+                       stacks: Iterable[tuple[int, ...]]) -> list[str]:
+    """Offending lines: instructions that own a bf16 buffer with the dims of one
+    held expert's matrix, of one layer's experts or of a whole stacked
+    leaf ``[layers, E_held, in, out]`` (``stacks``; keep-dims forms too).
+    The held experts' fast path reads the matrix of an expert that has a
+    row where it lies in the stack (``ops/moe.py`` ``held_expert_ffn``): a
+    copy in front of the product would be that expert's bytes twice, and a
+    stack re-laid out (found: 3.7 GB a dispatch, a leaf whose last axis is
+    off the 128 lanes and a program whose products all want it the other
+    way; PERF.md section 6, PR 42) every expert's. A fusion NESTED in a
+    product's fused computation is the operand read in place, and is
+    skipped with the fusion bodies."""
+    shapes: set[tuple[int, ...]] = set()
+    for stack in stacks:
+        for cut in range(len(stack) - 1):
+            tail = tuple(stack[cut:])
+            shapes |= {(1,) * lead + tail for lead in range(cut + 1)}
+    return _weight_shaped_buffers(hlo_text, shapes, {"bf16"})
+
+
+def expert_conditionals(hlo_text: str, rows: int, hidden: int
+                        ) -> list[tuple[str, int]]:
+    """(the instruction with its layouts taken out, as a trace names it;
+    the ``while`` loops behind the conditionals INSIDE its branches) for
+    every ``conditional`` whose result is ``f32[rows, hidden]``: the held
+    experts' dispatch (fast path or exact slow path), which the
+    benchmark's ``*_expert_ffn_ms`` readers find by that result. The loop
+    over the experts touched sits behind the fast path's own condition."""
+    comps = _computations(hlo_text)
+    found = []
+    for lines in comps.values():
+        for line in lines:
+            if _op(line) != "conditional" or f"f32[{rows},{hidden}]" not in line.split(
+                    " conditional(")[0]:
+                continue
+            inner = [n for name in _reached(comps, _called(line)) for inside in comps[name]
+                     if _op(inside) == "conditional" for n in _called(inside)]
+            loops = sum(_op(at) == "while" for name in _reached(comps, inner)
+                        for at in comps[name])
+            found.append((_LAYOUT.sub("", line), loops))
+    return found
 
 
 def param_nbytes(params: Any) -> int:

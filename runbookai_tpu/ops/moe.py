@@ -20,6 +20,12 @@ inserts the all-to-alls from the shardings (scaling-book recipe). Capacity
 ``C = clamp(ceil(capacity_factor * N * top_k / E), 1, N)``, with
 ``capacity_factor <= 0`` (the config default) meaning dropless ``C = N`` —
 exact transformers numerics; perf-tuned serving lowers the factor.
+
+That is ``moe_ffn``, a layer that holds every expert. A layer that holds a
+SHARE of them (``held_expert_ffn``, below) dispatches the same way but is
+dropless by an exact slow path, and its fast path visits only the held
+experts that have a row, a turn of a device-side loop each: an expert no
+token chose is not read.
 """
 
 from __future__ import annotations
@@ -184,9 +190,29 @@ def held_capacity(n_tokens: int, top_k: int, n_outputs: int,
     n_outputs``), at least 8, never more than every token. A queue that
     would overflow sends the call down the exact slow path instead
     (:func:`held_expert_ffn`), so the number trades speed only, never a
-    token."""
+    token. The slots of an expert no token chose cost nothing but their
+    zeros: the fast path does not read that expert."""
     expected = n_tokens * top_k / n_outputs
     return max(1, min(n_tokens, max(8, math.ceil(factor * expected))))
+
+
+# The share of the held experts with a row above which the fast path reads
+# them all in one batched product instead of one a turn (PERF.md section 7
+# has the sweep that set it: the two meet at 0.61-0.92 of the held experts).
+# That product's place in the program is also what keeps a stack the device
+# holds transposed read as it lies (``tests/test_hlo_bytes.py``).
+BATCHED_ABOVE = 0.5
+
+
+def touched_first(counts: jnp.ndarray) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """The experts with a count above zero, in their own order, at the front
+    of an ``[E]`` list of ids (the rest of it 0), and how many they are."""
+    e = counts.shape[0]
+    touched = counts > 0
+    rank = jnp.cumsum(touched) - 1
+    at = touched[None, :] & (rank[None, :] == jnp.arange(e)[:, None])   # [place, expert]
+    ids = jnp.sum(jnp.where(at, jnp.arange(e, dtype=jnp.int32)[None, :], 0), axis=1)
+    return ids, jnp.sum(touched, dtype=jnp.int32)
 
 
 def held_expert_ffn(
@@ -207,8 +233,17 @@ def held_expert_ffn(
     Only pairs that fell on a held expert are dispatched, so the cost does
     not grow with the experts that live elsewhere. Fast path: every held
     expert's queue has ``cap`` slots; tokens are gathered into ``[E_held,
-    cap, D]``, the experts run as one batched product, and the results are
-    scatter-added back under their weights. If any queue is longer than
+    cap, D]``, each held expert THAT HAS A ROW runs over its queue — a turn
+    of a device-side loop an expert, its matrices read where they lie in
+    the stack; an expert without a row is not read, its slots stay zero —
+    and the results are scatter-added back under their weights. The trip
+    count is what the routing touched (the step record's
+    ``experts.touched``): a decode pass of a few rows reads a seventh to a
+    quarter of what it holds. Where more than ``BATCHED_ABOVE`` of the held
+    experts have a row (a mixed step's hundreds of rows, a chip whose peers
+    send it theirs) one batched product over all of them is no slower and
+    runs instead, behind a condition on that same count: one algorithm,
+    the work following its input. If any queue is longer than
     ``cap`` (decided on the device, ``lax.cond``), the call instead runs
     each held expert over every token under a weight that is zero where it
     was not chosen: slower, the same sum. The caller sends pads and free
@@ -225,7 +260,8 @@ def held_expert_ffn(
     flat = local.reshape(-1)                                        # [N*K]
     onehot = jax.nn.one_hot(flat, e, dtype=jnp.int32)               # not held -> zeros
     slot = jnp.sum((jnp.cumsum(onehot, axis=0) - onehot) * onehot, axis=-1)
-    fits = jnp.max(jnp.sum(onehot, axis=0)) <= cap
+    counts = jnp.sum(onehot, axis=0)                                # pairs a held expert
+    fits = jnp.max(counts) <= cap
 
     def ffn(x, idx=None):
         at = tuple(i for i in (layer, idx) if i is not None)
@@ -243,9 +279,29 @@ def held_expert_ffn(
         w_slot = jnp.zeros((e * cap + 1,), jnp.float32).at[dest].set(
             weights.reshape(-1))[:-1]
         u_pad = jnp.concatenate([u, jnp.zeros((1, d), u.dtype)])
-        out_e = ffn(u_pad[src].reshape(e, cap, d)).reshape(e * cap, d)
-        out = jnp.zeros((n + 1, d), jnp.float32).at[src].add(
-            out_e.astype(jnp.float32) * w_slot[:, None])
+        x = u_pad[src].reshape(e, cap, d)
+        ids, n_touched = touched_first(counts)
+
+        def each_touched():
+            def turn(i, out_e):
+                j = ids[i]
+                return out_e.at[j].set(ffn(x[j], j))
+
+            like = jax.eval_shape(ffn, x)
+            return jax.lax.fori_loop(0, n_touched, turn,
+                                     jnp.zeros(like.shape, like.dtype))
+
+        def combined(out_e):
+            return jnp.zeros((n + 1, d), jnp.float32).at[src].add(
+                out_e.reshape(e * cap, d).astype(jnp.float32) * w_slot[:, None])
+
+        # The branches hand back the combined rows WITH the pads' row
+        # ([N + 1, D]): the batched product then fuses into its scatter-add
+        # as it always did, and no second ``f32[N, D]`` conditional sits
+        # inside the one the benchmark's readers time.
+        out = jax.lax.cond(n_touched > int(BATCHED_ABOVE * e),
+                           lambda: combined(ffn(x)),
+                           lambda: combined(each_touched()))
         return out[:n]
 
     def every_token(_):

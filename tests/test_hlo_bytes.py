@@ -32,9 +32,19 @@ arguments. These tests pin it to the COMPILED decode program instead:
   the dense cell's real size; a sampling request compiles no step program
   a greedy one had not.
 
+- the held experts' fast path reads an expert that has a row where its
+  matrices lie in the stack (`held_expert_copies`: no expert's matrix, no
+  layer's experts and no stack copied), its loop over the experts touched
+  sits behind a condition INSIDE the dispatch's ``conditional``, and that
+  ``conditional`` keeps the ``f32[rows, hidden]`` result the benchmark's
+  readers find it by (`expert_conditionals`): on the CPU, for the described
+  v5e, and at the widths of the one leaf the device keeps transposed.
+
 The on-device twins (real Mosaic, no interpret) live in
 ``test_pallas_on_device.py``.
 """
+
+import re
 
 import jax
 import jax.numpy as jnp
@@ -45,6 +55,8 @@ from runbookai_tpu.engine import engine as engine_module
 from runbookai_tpu.engine.engine import EngineConfig, EngineCore
 from runbookai_tpu.engine.hlo_bytes import (
     decode_accounting,
+    expert_conditionals,
+    held_expert_copies,
     kv_layer_slices,
     kv_pool_materializations,
     kv_pool_nbytes,
@@ -736,3 +748,115 @@ def test_decode_program_for_the_chip_copied_every_layer_matrix(
     finally:
         jax.clear_caches()
     assert len(layer_weight_copies(txt, shapes)) >= 3
+
+
+# --------------------------------------------------------------------------- #
+# The held experts' fast path: the experts touched, read where they lie        #
+# --------------------------------------------------------------------------- #
+
+# benchmark/layer_metrics/afmoe_expert_ffn_ms.py (and expert_ffn_ms,
+# relu2_expert_ffn_ms, spec_expert_ffn_ms): how a reader finds the dispatch
+# on the trace's "XLA Ops" line, by the ``conditional``'s result.
+READERS_PATTERN = r"^%cond[\w.]* = \(?f32\[{slots},{hidden}\]\)? conditional\("
+
+
+@pytest.fixture(scope="module")
+def expert_core():
+    """Held experts whose matrices share their dims with nothing else in the
+    model (512 x 384), stacked over two double layers, eight held."""
+    from runbookai_tpu.models import longcat
+
+    cfg = longcat.LongcatConfig(
+        name="hlo-held-experts-test", vocab_size=262, hidden_size=512,
+        ffn_hidden_size=1024, expert_ffn_hidden_size=384, num_layers=2,
+        num_attention_heads=8, q_lora_rank=256, kv_lora_rank=512,
+        qk_nope_head_dim=128, qk_rope_head_dim=64, v_head_dim=128,
+        n_routed_experts=32, zero_expert_num=16, moe_topk=4,
+        routed_scaling_factor=6.0, n_experts_held=8,
+        max_position_embeddings=512)
+    params = longcat.init_params(jax.random.PRNGKey(0), cfg, jnp.bfloat16)
+    return EngineCore(cfg, params, ByteTokenizer(), EngineConfig(
+        page_size=16, num_pages=512, max_batch_slots=8, prefill_chunk=64,
+        max_seq_len=512, block_pages=4, kv_dtype=jnp.bfloat16))
+
+
+def _held_expert_program(core, compiled, rows):
+    """What every program with the held experts' dispatch must show, the
+    dispatch over ``rows`` tokens."""
+    text = compiled.as_text()
+    layers = core.params["layers"]
+    stacks = [tuple(layers[k].shape) for k in ("e_gate", "e_up", "e_down")]
+    assert stacks == [(2, 8, 512, 384), (2, 8, 512, 384), (2, 8, 384, 512)]
+    assert held_expert_copies(text, stacks) == []
+    dispatches = expert_conditionals(text, rows, 512)
+    pattern = re.compile(READERS_PATTERN.format(slots=rows, hidden=512))
+    assert dispatches and all(pattern.match(line.removeprefix("ROOT "))
+                              for line, _ in dispatches), dispatches
+    # the loop over the experts touched, behind the fast path's own condition
+    assert all(loops >= 1 for _, loops in dispatches), dispatches
+
+
+@pytest.mark.parametrize("program", ["_decode_step", "_decode_multi"])
+def test_decode_program_reads_the_touched_experts_where_they_lie(expert_core, program):
+    _held_expert_program(expert_core, lower_decode(expert_core, program=program),
+                         expert_core.ecfg.max_batch_slots)
+
+
+@pytest.mark.parametrize("program", ["_decode_multi", "_mixed_step"])
+def test_step_program_for_the_chip_reads_the_touched_experts_where_they_lie(
+        one_chip, expert_core, program):
+    rows = expert_core.ecfg.max_batch_slots
+    if program == "_mixed_step":
+        rows = rows * engine_module._RAGGED_BLOCK + expert_core._mix_pf_tokens
+    _held_expert_program(expert_core, lower_decode(expert_core, program=program,
+                                                   sharding=one_chip), rows)
+
+
+def test_detector_flags_an_expert_sliced_out_of_the_stack():
+    """The control: one expert's matrix taken out of the stack in front of a
+    ``lax.cond`` (which materialises its operands) is a copy, and is found."""
+    def f(w, x, i, go):
+        one = w[1, i]
+        return jax.lax.cond(go, lambda m: x @ m, lambda m: x @ (2 * m), one)
+
+    text = jax.jit(f).lower(
+        jax.ShapeDtypeStruct((2, 8, 512, 384), jnp.bfloat16),
+        jax.ShapeDtypeStruct((4, 512), jnp.bfloat16),
+        jax.ShapeDtypeStruct((), jnp.int32), jax.ShapeDtypeStruct((), jnp.bool_),
+    ).compile().as_text()
+    assert held_expert_copies(text, [(2, 8, 512, 384)])
+
+
+def _scan_of_held_experts(layers, held, d, f, rows, cap, sharding):
+    """``held_expert_ffn`` over stacked two-matrix experts inside a scan
+    over layers, as a model's layer scan calls it, from shapes alone."""
+    from runbookai_tpu.ops import moe
+
+    def run(u, local, weights, w_up, w_down):
+        def layer(acc, li):
+            out, _ = moe.held_expert_ffn((u + acc).astype(u.dtype), local, weights, None,
+                                         w_up, w_down, cap, layer=li)
+            return acc + out.astype(acc.dtype), None
+
+        return jax.lax.scan(layer, jnp.zeros_like(u), jnp.arange(layers))[0]
+
+    def shape(*dims, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=sharding)
+
+    return jax.jit(run).lower(
+        shape(rows, d), shape(rows, 6, dtype=jnp.int32), shape(rows, 6, dtype=jnp.float32),
+        shape(layers, held, d, f), shape(layers, held, f, d)).compile()
+
+
+def test_a_stack_the_device_keeps_transposed_is_not_copied(one_chip):
+    """Nemotron-3-Nano's widths: ``e_up`` ``[.., 2688, 1856]`` has a last
+    axis off the 128 lanes, and the device keeps it in the other order. A
+    program whose products ALL wanted it row-major was given a copy of the
+    whole stack in front of its layer scan (3.7 GB a dispatch at 23
+    layers: compiled and read, PR 42); with the batched product beside the
+    loop in one program every product reads it as it lies."""
+    compiled = _scan_of_held_experts(2, 16, 2688, 1856, 48, 9, one_chip)
+    stack_bytes = 2 * 16 * 2688 * 1856 * 2
+    assert compiled.memory_analysis().temp_size_in_bytes < stack_bytes // 8
+    assert held_expert_copies(compiled.as_text(), [(2, 16, 2688, 1856),
+                                                   (2, 16, 1856, 2688)]) == []
