@@ -10,11 +10,20 @@ multiplies ``heads`` absorbed queries of ``rank + rope`` against it and
 accumulates ``heads`` weighted latents of ``rank``. Queries and outputs are
 left out (a lower bound).
 
-The attention is XLA's, not a kernel with a name of its own: on the "XLA
-Ops" line it is the ``while`` that walks the page tables a block at a time,
-told from every other loop of the program by what it carries — the running
-softmax's float32 accumulator ``f32[rows, 1, heads, rank]`` (the layer scan
-and the K-step scan carry nothing of that shape).
+The count is of the WORK, whatever implements it, and ``pattern`` finds the
+call by either of two spellings on the "XLA Ops" line. While the attention
+is XLA's it has no name of its own: it is the ``while`` that walks the page
+tables a block at a time, told from every other loop of the program by what
+it carries — the running softmax's float32 accumulator ``f32[rows, 1,
+heads, rank]`` (the layer scan and the K-step scan carry nothing of that
+shape). A Pallas walk over each row's live pages (ROADMAP A14) leaves no
+such loop, so it is to carry the name ``mla_decode_walk`` (the kernel's
+``name``, as ``%swa_decode_walk`` is ``kernels/swa_decode.py``'s) and is
+found by it: a device call whose instruction starts ``%mla_decode_walk``.
+A speculative round's walk is ``mla_spec_walk`` (``kernels/mla_spec.py``),
+and a mixed step's or a prefill chunk's takes a third name,
+``mla_chunk_walk``, so that neither reader counts it, as neither counts the
+``[blocks, 8, ..]`` carry today.
 """
 
 from __future__ import annotations
@@ -22,8 +31,19 @@ from __future__ import annotations
 import re
 
 
+WALK = "mla_decode_walk"  # the name a Pallas kernel for this call takes
+
+
+def call_pattern(walk: str, rows: int, positions: int, heads: int, rank: int) -> re.Pattern:
+    r"""One call of the latent attention: the device call named ``walk``, or
+    XLA's page-walk ``while`` by its ``f32[rows, positions, heads, rank]``
+    carry. ``[.\d]* = `` ends the name: ``%<walk>_other`` is another kernel."""
+    return re.compile(rf"^(?:%{walk}[.\d]* = "
+                      rf"|%while[.\d]* = \(.*f32\[{rows},{positions},{heads},{rank}\])")
+
+
 def pattern(rows: int, heads: int, rank: int) -> re.Pattern:
-    return re.compile(rf"^%while[.\d]* = \(.*f32\[{rows},1,{heads},{rank}\]")
+    return call_pattern(WALK, rows, 1, heads, rank)
 
 
 def bytes_per_call(live_tokens: float, rank: int, rope: int, kv_bytes: int = 2) -> float:

@@ -12,12 +12,15 @@ heads and of query positions; for every live token and row it multiplies
 accumulates as many weighted latents of ``rank``. Queries, outputs and the
 two rows a call writes first are left out (a lower bound).
 
-The attention is XLA's, not a kernel with a name of its own: on the "XLA
-Ops" line it is the ``while`` that walks the page tables a block at a time,
-told from every other loop by what it carries — the running softmax's
-float32 accumulator ``f32[rows, 2, heads, rank]``. The undrafted decode
-programs carry ``[rows, 1, ...]`` (``kernels/mla_decode.py``) and the mixed
-step ``[blocks, 8, ...]``: neither is counted here.
+The count is of the WORK, whatever implements it (``mla_decode.call_pattern``):
+while the attention is XLA's, its call on the "XLA Ops" line is the
+``while`` that walks the page tables a block at a time, told from every
+other loop by what it carries — the running softmax's float32 accumulator
+``f32[rows, 2, heads, rank]``; a Pallas walk (ROADMAP A14) is to carry the
+name ``mla_spec_walk`` and is found by it. The undrafted decode programs
+carry ``[rows, 1, ...]`` or are named ``mla_decode_walk``
+(``kernels/mla_decode.py``), and the mixed step carries ``[blocks, 8, ...]``
+or is named ``mla_chunk_walk``: neither is counted here.
 """
 
 from __future__ import annotations
@@ -32,8 +35,11 @@ POSITIONS = 2  # [last, draft] a row and round
 bytes_per_call = mla_decode.bytes_per_call
 
 
+WALK = "mla_spec_walk"  # the name a Pallas kernel for this call takes
+
+
 def pattern(rows: int, heads: int, rank: int) -> re.Pattern:
-    return re.compile(rf"^%while[.\d]* = \(.*f32\[{rows},{POSITIONS},{heads},{rank}\]")
+    return mla_decode.call_pattern(WALK, rows, POSITIONS, heads, rank)
 
 
 def ops_per_call(live_tokens: float, heads: int, rank: int, rope: int) -> float:

@@ -7,7 +7,7 @@ under the MXU's peak."""
 
 from benchmark.layer_metrics._common import decode_steps_traced, live_in_trace
 
-NAME, UNIT, LAYER = "decode_hbm_roofline", "%", "model step"
+NAME, UNIT, LAYER = "decode_hbm_mfu", "%", "model step"
 MOVES, SOURCE = "tpot_p50_ms", "device_trace"
 
 

@@ -1,7 +1,7 @@
 """A decode pass (or round) as the whole window's rows and contexts make
 it: the median, over the pure decode dispatches made ready inside the
 window, of the dispatch's device-side interval on the host's clock
-(``_dispatches``) over its ``k``. The slice's ``decode_hbm_roofline``
+(``_dispatches``) over its ``k``. The slice's ``decode_hbm_mfu``
 holds the first seconds' pass against its bytes; this is every pass of
 the window, batch grown and contexts long. The interval is the HOST's
 view, an upper bound: under the overlapped pipeline device idle time

@@ -3,8 +3,8 @@ traced slice — the larger of the bytes a call must read (the live latent
 cache of one sublayer, ``kernels/mla_decode.py``; contexts counted as prompt
 tokens, a lower bound) over the peak bytes per second and the operations it
 must do over the peak bf16 rate, over the mean device time of a call (the
-page-walk ``while`` on the "XLA Ops" line). Nothing to read in a model with
-no latent cache."""
+page-walk ``while``, or the call named ``mla_decode_walk``, on the "XLA
+Ops" line). Nothing to read in a model with no latent cache."""
 
 from benchmark.kernels import mla_decode as kernel
 from benchmark.layer_metrics._common import events_matching, live_in_trace

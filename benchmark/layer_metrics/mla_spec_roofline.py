@@ -4,8 +4,9 @@ included: its share of its roofline over the traced slice. The larger of
 the bytes a call must read (the live latent cache of one block,
 ``kernels/mla_spec.py``; contexts counted as prompt tokens, a lower bound)
 over the peak bytes per second and the operations it must do over the peak
-bf16 rate, over the mean device time of a call (the page-walk ``while`` on
-the "XLA Ops" line). Nothing to read in a program that runs no rounds."""
+bf16 rate, over the mean device time of a call (the page-walk ``while``, or
+the call named ``mla_spec_walk``, on the "XLA Ops" line). Nothing to read in
+a program that runs no rounds."""
 
 from benchmark.kernels import mla_spec as kernel
 from benchmark.layer_metrics._common import events_matching, live_in_trace

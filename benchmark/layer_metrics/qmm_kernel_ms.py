@@ -1,9 +1,11 @@
 """Device time of the Pallas int8 matmul per pass over the weights: its
 events on the "XLA Ops" line of the traced slice, over the passes the
 decode programs made there (the block's ``bytes.PROGRAMS``; in this
-benchmark's mixes only they call the kernel). No share of a peak: in some
-programs the kernel reads its matrix from on-chip memory, where the copy
-beside it (``qmm_feed_copy_ms``) has put it (``kernels/qmm_pallas.py``)."""
+benchmark's mixes only they call the kernel). No share of a peak yet:
+until PR 30 the kernel read its matrix from on-chip memory in some
+programs, where a copy in front of it had put it (``kernels/qmm_pallas.py``);
+it now reads the stacked array in place, and a roofline by shape is a
+later PR's (PERF.md section 7)."""
 
 from benchmark.kernels import qmm_pallas
 from benchmark.layer_metrics._common import decode_steps_traced, events_matching

@@ -9,6 +9,20 @@ the argmax of the PROGRAM's logits, so the gap is zero unless the
 program's logits differ from the reference's by about the gap: the widest
 gap over the sample is the first number compared (``logit_gap``).
 
+WHICH statistic of the gaps decides is the limits' to say (``deciding``):
+a configuration whose limits hold ``logit_gap_mean`` (and, if it has one,
+``logit_gap_p99``) is decided by the MEAN gap over the sampled positions
+(and their 99th percentile), and its widest gap is printed beside them
+without a limit; limits that hold neither are decided by the widest gap.
+Where rounding is amplified by the model itself (a delta rule's linear
+system, attention scores with a deviation near 6) the widest of ~1,000
+gaps is a draw from a heavy tail that a sound program takes anew in every
+run, while the mean stands ten times under the control's. What a mean
+cannot see is a fault confined to a few positions: 1% of them at a gap of
+10 moves it by 0.1 (the configurations' limits files say what the 99th
+percentile reads, and why it has a limit or none). A control is held to
+the same numbers as the program, and has to fail one of them.
+
 The second (``resident_bytes_short``) holds the program to the precisions
 the configuration file states for what it KEEPS: the bytes of its live
 device arrays when the window has closed may not be under the stated
@@ -46,12 +60,29 @@ LIMITS = Path(__file__).with_name("limits.json")
 CONFIGS = Path(__file__).parents[1] / "configs"
 
 
+# The statistics of the sampled gaps. The widest decides unless a
+# configuration's limits hold one of the others: then those decide.
+WIDEST = "logit_gap"
+STATS = {WIDEST: np.max, "logit_gap_mean": np.mean,
+         "logit_gap_p99": lambda gaps: np.percentile(gaps, 99)}
+
+
 def limits_for(config_name: str) -> dict:
     """{"logit_gap": limit, "resident_bytes_short": limit,
-    "not_comparable_share": limit}."""
+    "not_comparable_share": limit}, and ``logit_gap_mean`` /
+    ``logit_gap_p99`` where the configuration's own file holds them."""
     own = CONFIGS / f"{config_name}.limits.json"
     return {**json.loads(LIMITS.read_text())["default"],
             **(json.loads(own.read_text()) if own.is_file() else {})}
+
+
+def deciding(limits: dict) -> list[str]:
+    """The statistics of the gaps that ``ok`` is held to under ``limits``."""
+    return [k for k in STATS if k != WIDEST and k in limits] or [WIDEST]
+
+
+def gap_stats(gaps: np.ndarray) -> dict:
+    return {name: float(of(gaps)) for name, of in STATS.items()}
 
 
 def choose_sample(reqs: list[dict], n: int, seed: int) -> list[dict]:
@@ -101,12 +132,19 @@ def gaps_of(block, params: dict, cfg: dict, req: dict, lowp: str | None = None) 
 
 def compare(block, params: dict, cfg: dict, sample: list[dict], limits: dict,
             resident: dict, control_kinds: str | None = None) -> dict:
-    """{"ok", "logit_gap", "limit", ...}: every number beside its limit.
-    ``resident``: {"live_bytes": measured, "stated_bytes": lower bound}."""
-    limit = limits["logit_gap"]
+    """{"ok", "decided_by", "logit_gap", ...}: every number beside its limit
+    (``limit_<name>``; a statistic of the gaps that does not decide has
+    none). ``resident``: {"live_bytes": measured, "stated_bytes": lower
+    bound}."""
+    decides = deciding(limits)
+
+    def within(stats: dict) -> bool:
+        return all(stats[k] <= limits[k] for k in decides)
+
     short = max(0, resident["stated_bytes"] - resident["live_bytes"])
-    out = {"requests": len(sample), "served_tokens": 0, "logit_gap": None,
-           "limit": limit, "resident_bytes_short": short,
+    out = {"requests": len(sample), "served_tokens": 0, WIDEST: None,
+           "decided_by": decides, **{f"limit_{k}": limits[k] for k in decides},
+           "resident_bytes_short": short,
            "limit_resident_bytes_short": limits["resident_bytes_short"],
            "not_comparable": 0, "not_comparable_share": None,
            "limit_not_comparable_share": limits["not_comparable_share"],
@@ -126,15 +164,14 @@ def compare(block, params: dict, cfg: dict, sample: list[dict], limits: dict,
     gaps = np.concatenate(gaps) if gaps else np.zeros(0)
     if not gaps.size:  # nothing served, or nothing the block lets be compared
         return out
-    out.update(logit_gap=float(gaps.max()), logit_gap_mean=float(gaps.mean()),
-               tokens_off_the_reference_best=int((gaps > 0).sum()),
+    out.update(gap_stats(gaps), tokens_off_the_reference_best=int((gaps > 0).sum()),
                not_comparable_share=out["not_comparable"] / (out["not_comparable"] + gaps.size))
-    out["ok"] = (not out["prompt_token_mismatches"] and out["logit_gap"] <= limit
+    out["ok"] = (not out["prompt_token_mismatches"]
+                 and within(out)
                  and short <= limits["resident_bytes_short"]
                  and out["not_comparable_share"] <= limits["not_comparable_share"])
-    for kind, cg in control.items():  # has to come out NOT ok
-        cg = np.concatenate(cg)
+    for kind, cg in control.items():  # has to come out NOT ok, by the same numbers
+        stats = gap_stats(np.concatenate(cg))
         out.setdefault("control", {})[kind] = {
-            "logit_gap": float(cg.max()), "logit_gap_mean": float(cg.mean()),
-            "ok": bool(cg.max() <= limit)}
+            **stats, "ok": within(stats)}
     return out

@@ -367,9 +367,12 @@ def main(argv=None) -> int:
                                            "weight_dtype", "mixed_dispatch")})
     correct = bool(verdict["ok"]) and compiled_in_window == 0
     # Every number `correct` compares, beside its limit: the result line's
-    # last key, and the run's last lines on standard error.
+    # last key, and the run's last lines on standard error. Which statistic
+    # of the gaps that is, the configuration's limits say (`check.deciding`);
+    # the others are recorded before it, with no limit.
+    recorded = {k: verdict.get(k) for k in check.STATS if k not in verdict["decided_by"]}
     compared = {
-        "logit_gap": (verdict["logit_gap"], verdict["limit"]),
+        **{k: (verdict.get(k), verdict[f"limit_{k}"]) for k in verdict["decided_by"]},
         "resident_bytes_short": (verdict["resident_bytes_short"],
                                  verdict["limit_resident_bytes_short"]),
         "not_comparable_share": (verdict["not_comparable_share"],
@@ -413,15 +416,18 @@ def main(argv=None) -> int:
             result["breakdown"] = trace_reduce.breakdown(reduced)
             say("trace", modules=reduced["modules"], host_spans=reduced["host_spans"],
                 file=str(xplane.relative_to(ROOT)))
+    for name, value in recorded.items():
+        print(f"recorded {name} {value}", file=sys.stderr)
     for name, c in compared.items():
         print(f"compared {name} {c['value']} limit {c['limit']}", file=sys.stderr)
     if rehearsal:
         print(json.dumps({"rehearsal": True, "rehearsal_correct": bool(verdict["ok"]),
                           "values": values, **{k: result[k] for k in
                                                ("attempted", "failed", "metrics")},
-                          "platform": platform, "compared": compared}), flush=True)
+                          "platform": platform, "recorded": recorded,
+                          "compared": compared}), flush=True)
         return 0 if verdict["ok"] and not failures else 1
-    print(json.dumps({**result, "compared": compared}), flush=True)
+    print(json.dumps({**result, "recorded": recorded, "compared": compared}), flush=True)
     return 0
 
 

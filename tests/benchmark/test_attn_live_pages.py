@@ -64,4 +64,7 @@ def test_it_is_declared_as_the_issue_gave_it():
     assert entry == {"name": NAME, "unit": "pages", "better": "higher",
                      "source": "program_counter", "layer": "kernels",
                      "moves": "tpot_p50_ms", "workloads": ["qwen7b.chat-open"]}
-    assert BENCH["per_layer"][-1]["name"] == NAME  # appended, nothing moved
+    # appended when it came (PR 31 found it last; later PRs appended theirs):
+    # once, and with nothing of its layer or its cell moved by it
+    assert [m["name"] for m in BENCH["per_layer"]].count(NAME) == 1
+    assert "kernels" in {m["layer"] for m in BENCH["per_layer"] if m["name"] != NAME}

@@ -351,14 +351,15 @@ def test_union_and_base_name():
 
 @pytest.mark.parametrize("k,n", [(3584, 3584), (3584, 512), (3584, 18944), (18944, 3584)])
 def test_the_four_qwen_matrices_and_their_feed_copies(k, n):
+    """The kernel's call is read by its shapes; the copy that fed it until
+    PR 30 (its reader went with PR 44) is never taken for a call."""
     from benchmark.kernels import qmm_pallas
 
     qwen = _load(ROOT / "benchmark" / "configs" / "qwen2.5-7b-int8.json")
     d, f, kv = qwen["dim"], qwen["ffn_dim"], qwen["n_kv_heads"] * qwen["dim"] // qwen["n_heads"]
     assert (k, n) in [(d, d), (d, kv), (d, f), (f, d)]
     feed = f"%dynamic-slice_bitcast_fusion.3 = s8[{k},{n}] fusion(s8[28,{k},{n}] %gte.1, s32[] %gte.2)"
-    m = qmm_pallas.FEED.search(feed)
-    assert (int(m.group(1)), int(m.group(2))) == (k, n)
+    assert qmm_pallas.shape_of(feed) is None
     for rows in (16, 128):
         op = f"%qmm_pallas.7 = bf16[{rows},{n}] custom-call(bf16[{rows},{k}] %x, s8[{k},{n}] %y)"
         assert qmm_pallas.shape_of(op) == (rows, k, n)
@@ -402,16 +403,16 @@ def test_kernel_patterns_on_the_traces_own_instruction_texts():
     """Texts as a v5e trace gave them (my chip run, PR 23), layouts removed."""
     from benchmark import trace_reduce
     from benchmark.kernels import paged_attention_decode, qmm_pallas
-    from benchmark.layer_metrics import qmm_feed_copy_ms, qmm_kernel_ms
+    from benchmark.layer_metrics import qmm_kernel_ms
 
     assert qmm_pallas.shape_of(QMM_OP) == (16, 3584, 18944)
-    assert qmm_pallas.FEED.search(FEED_OP) and not qmm_pallas.PATTERN.search(FEED_OP)
-    assert not qmm_pallas.FEED.search(QMM_OP)
+    assert not qmm_pallas.PATTERN.search(FEED_OP)
     attn = paged_attention_decode.pattern(16)
     assert attn.search(ATTN_OP) and attn.search(VERIFY_OP) and not attn.search(CHUNK_OP)
     assert trace_reduce.own_name(QMM_OP) == "qmm_pallas"
     # 17 passes of 28 layers (2 runs of 8 steps and a single step): 476
-    # calls of the kernel at 75 us and of its feed at 91 us each.
+    # calls of the kernel at 75 us; the feed's 91 us (a trace from before
+    # PR 30) are no part of the kernel's time.
     qwen = _load(ROOT / "benchmark" / "configs" / "qwen2.5-7b-int8.json")
     run = {"peaks": {"hbm_bytes_per_s": 819e9}, "model": qwen, "llm": qwen["llm"],
            "block": blocks.load("dense"),
@@ -421,7 +422,6 @@ def test_kernel_patterns_on_the_traces_own_instruction_texts():
                              FEED_OP: {"count": 476, "seconds": 476 * 91e-6},
                              ATTN_OP: {"count": 476, "seconds": 0.25}}}}
     assert qmm_kernel_ms.read(run) == pytest.approx(28 * 75e-3)
-    assert qmm_feed_copy_ms.read(run) == pytest.approx(28 * 91e-3)
     assert qmm_kernel_ms.read({**run, "trace": None}) is None
     red = {"ops": {"%while.6 = (s32[]) while(...)": {"count": 1, "seconds": 9.0},
                    **run["trace"]["ops"]}, "idle_gaps": {"decode": 0.1}}
@@ -445,8 +445,8 @@ def _read_on_slice(metric, slice_name, block, rows=8, prompt_tokens=600):
     return load_metric_file(ROOT / "benchmark" / "layer_metrics" / f"{metric}.py").read(run)
 
 
-DEVICE_READERS = ["attn_decode_roofline", "decode_hbm_roofline", "prefill_dev_ms_per_ktok",
-                  "qmm_feed_copy_ms", "qmm_kernel_ms"]
+DEVICE_READERS = ["attn_decode_roofline", "decode_hbm_mfu", "prefill_dev_ms_per_ktok",
+                  "qmm_kernel_ms"]
 
 
 @pytest.mark.parametrize("slice_name", ["decode_spec_slice", "decode_multi_slice"])
@@ -458,14 +458,14 @@ def test_device_readers_find_their_events_in_both_kinds_of_slice(metric, slice_n
     run's line is refused), and no share passes 100%."""
     value = _read_on_slice(metric, slice_name, blocks.load("dense"))
     assert value is not None and value > 0
-    if metric.endswith("_roofline"):
+    if metric.endswith("_roofline") or "mfu" in metric.split("_"):
         assert value < 100
 
 
 @pytest.mark.parametrize("slice_name,metric,parents", [
-    ("decode_multi_slice", "decode_hbm_roofline", 25.7764493041407),
+    ("decode_multi_slice", "decode_hbm_mfu", 25.7764493041407),
     ("decode_multi_slice", "attn_decode_roofline", 2.059266225381598),
-    ("decode_spec_slice", "decode_hbm_roofline", 12.909504132861011),
+    ("decode_spec_slice", "decode_hbm_mfu", 12.909504132861011),
     ("decode_spec_slice", "attn_decode_roofline", 1.4452421554771608),
 ])
 def test_the_dense_blocks_shares_are_the_closed_formulas_to_the_last_digit(
@@ -573,6 +573,90 @@ def test_each_number_has_its_own_limit(short, gap, ok, monkeypatch):
     assert out["logit_gap"] == pytest.approx(gap) and out["served_tokens"] == 2
 
 
+def _compare_gaps(monkeypatch, limits, widest, mean, control_mean=None, n=1000):
+    """``check.compare`` over one request of ``n`` served positions whose
+    gaps have this widest and this mean (one position at the widest, the
+    rest level), and a control whose gaps are the same shape at its mean."""
+    from benchmark.reference import check
+
+    def gaps(widest, mean):
+        g = np.full(n, (mean * n - widest) / (n - 1))
+        g[n // 2] = widest
+        return g
+
+    control = {} if control_mean is None else {"fp8": gaps(widest, control_mean)}
+    monkeypatch.setattr(check, "gaps_of", lambda *a: {
+        "prompt_matches": True, "prompt_tokens": 3, "served_tokens": n,
+        "gaps": gaps(widest, mean), "not_comparable": 0, "control_gaps": control})
+    return check.compare(None, {}, {}, [{"id": "r0"}],
+                         {**check.limits_for("none-of-its-own"), **limits},
+                         {"live_bytes": 1, "stated_bytes": 1}, "fp8" if control else None)
+
+
+@pytest.mark.parametrize("limits,widest,mean,ok,decided_by", [
+    # a limits file with the mean: PR 43's refused run passes, a control's mean fails
+    ({"logit_gap_mean": 0.28}, 3.3, 0.09, True, ["logit_gap_mean"]),
+    ({"logit_gap_mean": 0.28}, 3.3, 0.95, False, ["logit_gap_mean"]),
+    ({"logit_gap_mean": 0.28}, 0.29, 0.285, False, ["logit_gap_mean"]),  # whatever the widest
+    # ... and with the 99th percentile beside it: both are held
+    ({"logit_gap_mean": 0.28, "logit_gap_p99": 1.0}, 3.3, 0.09, True,
+     ["logit_gap_mean", "logit_gap_p99"]),
+    ({"logit_gap_mean": 2.0, "logit_gap_p99": 1.0}, 3.3, 1.2, False,
+     ["logit_gap_mean", "logit_gap_p99"]),
+    # a limits file without it: the widest gap decides, as before PR 44
+    ({"logit_gap": 2.5}, 3.3, 0.09, False, ["logit_gap"]),
+    ({"logit_gap": 2.5}, 2.4, 0.09, True, ["logit_gap"]),
+    ({}, 0.09, 0.01, True, ["logit_gap"]),  # the default's 0.1
+    ({}, 0.11, 0.01, False, ["logit_gap"]),
+])
+def test_the_limits_choose_the_statistic_of_the_gaps_that_decides(
+        limits, widest, mean, ok, decided_by, monkeypatch):
+    out = _compare_gaps(monkeypatch, limits, widest, mean)
+    assert out["ok"] is ok and out["decided_by"] == decided_by
+    assert out["logit_gap"] == pytest.approx(widest) and out["logit_gap_mean"] == pytest.approx(mean)
+    assert 0 < out["logit_gap_p99"] < widest  # one position in a thousand holds the widest
+    # a limit beside each number that decides, and beside no other statistic
+    assert {k for k in out if k.startswith("limit_logit_gap")} == {f"limit_{k}" for k in decided_by}
+
+
+@pytest.mark.parametrize("limits,control_mean,control_ok", [
+    ({"logit_gap_mean": 0.28}, 0.95, False),  # the fp8 reference's mean, ten times the sound one
+    ({"logit_gap_mean": 0.28}, 0.2, True),    # a control that passes is seen to pass: by the mean,
+    ({"logit_gap": 2.5}, 0.95, False),        # and by the widest gap where that decides (3.3)
+    ({"logit_gap": 3.5}, 0.95, True),
+])
+def test_a_control_is_held_to_the_number_that_decides(limits, control_mean, control_ok,
+                                                      monkeypatch):
+    out = _compare_gaps(monkeypatch, limits, 3.3, 0.09, control_mean)
+    control = out["control"]["fp8"]
+    assert control["ok"] is control_ok
+    assert control["logit_gap_mean"] == pytest.approx(control_mean)
+    assert control["logit_gap"] == pytest.approx(3.3) and "logit_gap_p99" in control
+
+
+@pytest.mark.parametrize("config", CONFIGS)
+def test_which_statistic_decides_is_the_configurations_own_file(config):
+    """A configuration whose own file holds the mean is decided by it, and
+    its widest gap has no limit of that file's; the others' files hold the
+    widest gap's limit, or none (the default's). No configuration's name is
+    in ``check.py``."""
+    from benchmark.reference import check
+
+    own = ROOT / "benchmark" / "configs" / f"{config}.limits.json"
+    held = _load(own) if own.is_file() else {}
+    decides = check.deciding(check.limits_for(config))
+    if "logit_gap_mean" in held:
+        assert decides[0] == "logit_gap_mean" and "logit_gap" not in held
+        assert "logit_gap_p99" in held["comment"]  # read, and given a limit or said why not
+        assert 0.1 <= held["logit_gap_mean"] <= 0.6
+    else:
+        assert decides == ["logit_gap"] and "logit_gap_p99" not in held
+    # the configuration PR 43 was refused in is decided by the mean
+    assert ("logit_gap_mean" in held) or config != "qwen3-next-80b-ep4-bf16"
+    source = (ROOT / "benchmark" / "reference" / "check.py").read_text()
+    assert config not in source and config.split("-")[0] not in source
+
+
 def test_choose_sample_holds_the_longest_and_is_seeded():
     from benchmark.reference import check
 
@@ -616,15 +700,21 @@ def test_every_id_streams_as_one_character_and_comes_back(ids):
 def test_an_unknown_block_or_family_names_what_the_directory_holds():
     from benchmark.reference import tokens
 
-    assert blocks.names() == ["dense"]
-    with pytest.raises(SystemExit, match=r"unknown block 'latent'.*\['dense'\]"):
+    held = sorted(p.name for p in (ROOT / "benchmark" / "blocks").iterdir()
+                  if (p / "forward.py").is_file())
+    assert blocks.names() == held and "dense" in held
+    assert {_load(ROOT / c["file"])["block"] for c in BENCH["configs"]} <= set(held)
+    with pytest.raises(SystemExit, match=rf"unknown block 'latent'.*{re.escape(str(held))}"):
         blocks.load("latent")
-    with pytest.raises(SystemExit, match=r"no chat template for family 'mistral'.*\['qwen2'\]"):
+    templates = sorted(p.stem for p in (ROOT / "benchmark/reference/templates").glob("*.py"))
+    assert "qwen2" in templates and "mistral" not in templates
+    with pytest.raises(SystemExit, match=rf"no chat template for family 'mistral'.*"
+                                         rf"{re.escape(str(templates))}"):
         tokens.prompt_ids([{"role": "user", "content": "x"}], "mistral")
 
 
-@pytest.mark.parametrize("metric", ["decode_hbm_roofline", "attn_decode_roofline",
-                                    "qmm_kernel_ms", "qmm_feed_copy_ms"])
+@pytest.mark.parametrize("metric", ["decode_hbm_mfu", "attn_decode_roofline",
+                                    "qmm_kernel_ms"])
 def test_a_block_without_the_count_is_not_measured_against_anothers(metric):
     """On a slice every dense reader reads, a block that brings only its
     resident bytes gets no share of a roofline and no time per pass."""
@@ -710,12 +800,19 @@ def test_rehearsal_end_to_end(cell):
     freed = [j for j in lines if j.get("note") == "freed"][0]
     assert freed["live_array_bytes_after_shutdown"] == 0
     ref = [j for j in lines if j.get("note") == "reference"][0]
-    assert ref["served_tokens"] >= 8 and ref["logit_gap"] <= ref["limit"]
+    assert ref["served_tokens"] >= 8 and ref["decided_by"]
+    for name in ref["decided_by"]:  # the statistic of the gaps its limits name
+        assert ref[name] <= ref[f"limit_{name}"] and name in last["compared"]
+    assert set(last["recorded"]) | set(ref["decided_by"]) == {
+        "logit_gap", "logit_gap_mean", "logit_gap_p99"}
     assert ref["resident_bytes_short"] == 0 and ref["live_bytes"] >= ref["stated_bytes"] > 0
-    # the configuration's engine_plan reached the engine through llm.plan
+    # the configuration's engine_plan reached the engine through llm.plan:
+    # drafts are made in the one cell whose plan turns speculation on
+    config = {w["name"]: w["config"] for w in BENCH["workloads"]}[cell]
+    plan = _load(ROOT / {c["name"]: c for c in BENCH["configs"]}[config]["file"])["engine_plan"]
     window = [j for j in lines if j.get("note") == "window"][0]
-    assert window["engine_plan"] == {"speculative": False}
-    assert window["counters"]["spec_drafted"] == 0
+    assert window["engine_plan"] == plan
+    assert (window["counters"]["spec_drafted"] > 0) is plan["speculative"]
 
 
 def test_the_programs_own_fp8_cache_comes_out_not_correct():
@@ -853,7 +950,8 @@ def test_a_new_architecture_needs_files_and_entries_only(tmp_path):
     assert ref["resident_bytes_short"] == ref["stated_bytes"] - ref["live_bytes"] > 0
     assert ref["requests"] == ref["not_comparable"] == 4 and ref["served_tokens"] >= 8
     assert ref["not_comparable_share"] == pytest.approx(4 / ref["served_tokens"])
-    assert (ref["limit"], ref["limit_resident_bytes_short"],
+    assert ref["decided_by"] == ["logit_gap"]
+    assert (ref["limit_logit_gap"], ref["limit_resident_bytes_short"],
             ref["limit_not_comparable_share"]) == (0.25, 2_000_000, 0.5)
     assert ref["prompt_token_mismatches"] == [] and ref["ok"] is True
     # the last key of the last line, and the last lines of standard error
@@ -867,4 +965,5 @@ def test_an_unknown_block_ends_the_run_before_anything_is_built(tmp_path):
     _tree_with_a_throwaway_architecture(tmp_path, block="latent")
     p = _run_throwaway(tmp_path)
     assert p.returncode != 0 and not p.stdout.strip()
-    assert "unknown block 'latent'" in p.stderr and "['dense', 'throwaway']" in p.stderr
+    held = sorted(blocks.names() + ["throwaway"])  # the copy's directory, the added one in it
+    assert f"unknown block 'latent'; benchmark/blocks/ holds: {held}" in p.stderr

@@ -197,24 +197,54 @@ def test_the_modules_interval_on_hand_made_events():
     assert mod.intervals(events, 64, 4096, 129280) == []
 
 
+def _slice_run(ops):
+    """``testdata/joyai_spec_slice.json``'s slice, with ``ops`` on its
+    "XLA Ops" line."""
+    run = _run(trace={"ops": ops, "modules": SLICE["modules"]})
+    run["reqs"] = [dict(run["reqs"][0], prompt_tokens=SLICE["live_tokens"] / SLICE["live_rows"],
+                        times=[0.0, SLICE["slice_seconds"]])] * SLICE["live_rows"]
+    run["traced"].update(t_start=0.0, t_stop=SLICE["slice_seconds"])
+    return run
+
+
 def test_the_device_readers_on_the_builders_own_slice():
     """``testdata/joyai_spec_slice.json``: the walks, programs and module
     intervals of one traced slice of the cell on the chip, reduced, with
     what the run's readers printed."""
-    run = _run(trace={"ops": SLICE["ops"], "modules": SLICE["modules"]})
-    run["reqs"] = [dict(run["reqs"][0], prompt_tokens=SLICE["live_tokens"] / SLICE["live_rows"])
-                   ] * SLICE["live_rows"]
-    run["traced"].update(t_start=0.0, t_stop=SLICE["slice_seconds"])
-    run["reqs"] = [dict(r, times=[0.0, SLICE["slice_seconds"]]) for r in run["reqs"]]
+    run = _slice_run(SLICE["ops"])
     got = _read("mla_spec_roofline", run)
     assert 0 < got < 100 and got == pytest.approx(SLICE["printed"]["mla_spec_roofline"], rel=1e-6)
     calls = sum(t["count"] for t in SLICE["ops"].values())
     rounds = SLICE["modules"]["jit__decode_spec"]["count"] * 8
     assert calls == pytest.approx(14 * rounds, rel=0.15)  # a walk an attention block and round
-    hbm = _read("decode_hbm_roofline", run)
-    assert 0 < hbm < 100 and hbm == pytest.approx(SLICE["printed"]["decode_hbm_roofline"], rel=1e-6)
+    hbm = _read("decode_hbm_mfu", run)
+    assert 0 < hbm < 100 and hbm == pytest.approx(SLICE["printed"]["decode_hbm_mfu"], rel=1e-6)
     assert 0 < SLICE["printed"]["mtp_draft_ms"] < 1e3 * (
         SLICE["modules"]["jit__decode_spec"]["seconds"] / rounds)
+
+
+@pytest.mark.parametrize("spelling", ["while", "named"])
+def test_the_rounds_roofline_counts_the_work_whatever_implements_it(spelling):
+    """The builder's slice as it was traced (XLA's ``while``, by its carry)
+    and with every walk re-spelt as the Pallas call it is to become
+    (``%mla_spec_walk``), count for count and second for second: the same
+    share. A decode pass's walk and a chunk's, by either spelling, count for
+    nothing."""
+    ops = dict(SLICE["ops"])
+    if spelling == "named":
+        ops = {f"%mla_spec_walk.{i} = bf16[64,2,32,512] custom-call(s32[64,1025] %t, "
+               f"bf16[64,2,32,576] %q, bf16[172032,16,512] %latents)": t
+               for i, t in enumerate(ops.values())}
+        assert all(mla_spec.pattern(64, 32, 512).search(name) for name in ops)
+    others = {name: {"count": 10, "seconds": 5.0} for name in SLICE["other_events"]}
+    others.update({
+        "%mla_decode_walk.1 = bf16[64,32,512] custom-call(s32[64,1025] %t)": {"count": 9, "seconds": 5.0},
+        "%mla_chunk_walk.1 = bf16[128,8,32,512] custom-call(s32[128,1025] %t)": {"count": 9, "seconds": 5.0},
+        "%while.9 = (s32[], f32[64,1,32], f32[64,1,32], f32[64,1,32,512], s32[]) while(": {
+            "count": 9, "seconds": 5.0}})
+    got = _read("mla_spec_roofline", _slice_run({**ops, **others}))
+    assert got == pytest.approx(SLICE["printed"]["mla_spec_roofline"], rel=1e-6)
+    assert _read("mla_spec_roofline", _slice_run(others)) is None
 
 
 @pytest.mark.parametrize("name", NEW)
@@ -300,7 +330,9 @@ def test_the_cell_rehearses_on_the_cpu_with_drafting_on(tree):
         window["counters"]["spec_drafted"] + window["counters"]["spec_accepted"])
     # the fp8 reference control comes out NOT correct, through the harness's own comparison
     ref = [j for j in lines if j.get("note") == "reference"][0]
-    assert ref["control"]["fp8"]["ok"] is False and ref["control"]["fp8"]["logit_gap"] > ref["limit"]
+    assert ref["decided_by"] == ["logit_gap"]  # this configuration's limits hold no mean
+    assert (ref["control"]["fp8"]["ok"] is False
+            and ref["control"]["fp8"]["logit_gap"] > ref["limit_logit_gap"])
     assert 0 < ref["not_comparable_share"] <= ref["limit_not_comparable_share"]
 
 

@@ -13,12 +13,13 @@ from pathlib import Path
 import pytest
 
 from benchmark import blocks, serving
-from benchmark.kernels import mla_decode
+from benchmark.kernels import mla_decode, mla_spec
 from benchmark.layer_metrics._common import load_metric_file
 
 ROOT = Path(__file__).resolve().parents[2]
 BENCH = json.loads((ROOT / "BENCHMARK.json").read_text())
 CONFIG = json.loads((ROOT / "benchmark/configs/longcat-flash-ep32-bf16.json").read_text())
+LIMITS = json.loads((ROOT / "benchmark/configs/longcat-flash-ep32-bf16.limits.json").read_text())
 METRICS = ROOT / "benchmark" / "layer_metrics"
 NEW = ["mla_decode_roofline", "expert_ffn_ms", "expert_pairs_per_held_expert",
        "zero_expert_pick_share", "expert_overflow_share"]
@@ -118,6 +119,42 @@ def test_device_readers_on_hand_made_operations():
     assert _read("mla_decode_roofline", run) == pytest.approx(100 * (50_000 * 1152 / 819e9) / 1e-3)
 
 
+WHILE_WALK = "%while.9 = (s32[], s32[], f32[64,1,64], f32[64,1,64], f32[64,1,64,512], bf16[6"
+# What is NOT a decode pass's call, whichever way it is implemented: a
+# speculative round's walk, a mixed step's or chunk's, another kernel whose
+# name only starts alike, and the other loops of the program.
+NOT_THE_CALL = {
+    "%mla_spec_walk.4 = bf16[64,2,64,512] custom-call(s32[64,1025] %t, bf16[64,2,64,576] %q)": {
+        "count": 80, "seconds": 9.0},
+    "%mla_chunk_walk.2 = bf16[128,8,64,512] custom-call(s32[128,1025] %t)": {
+        "count": 8, "seconds": 9.0},
+    "%mla_decode_walk_grad.1 = bf16[64,64,512] custom-call(s32[64,1025] %t)": {
+        "count": 8, "seconds": 9.0},
+    "%while.7 = (s32[], f32[128,8,64], f32[128,8,64], f32[128,8,64,512]) while(": {
+        "count": 8, "seconds": 9.0},
+    "%while.5 = (s32[], bf16[64,1,6144], bf16[8,196608,1,512], s32[4]) while(%t)": {
+        "count": 5, "seconds": 9.0},
+}
+
+
+@pytest.mark.parametrize("call", [
+    WHILE_WALK,  # XLA's page-walk loop, by its carry: what the cell runs today
+    "%mla_decode_walk.3 = bf16[64,64,512] custom-call(s32[64,1025] %t, bf16[64,64,576] %q, "
+    "bf16[196608,1,512] %latents)",  # a Pallas walk, by the name it is to take
+    "%mla_decode_walk = bf16[64,64,512] custom-call(s32[64,1025] %t)",  # the first of its name
+])
+def test_the_latent_roofline_counts_the_work_whatever_implements_it(call):
+    """The same seconds give the same share, from a reduced trace that holds
+    only the ``while`` and from one that holds only the named call."""
+    assert mla_decode.pattern(64, 64, 512).search(call)
+    assert not mla_spec.pattern(64, 64, 512).search(call)
+    run = _run(ops={call: {"count": 320, "seconds": 0.32}, **NOT_THE_CALL})
+    # 50 live rows of 1,000 prompt tokens: 57.6 MB a call is 70.3 us; a call took 1 ms
+    assert _read("mla_decode_roofline", run) == pytest.approx(
+        100 * (50_000 * 1152 / 819e9) / 1e-3)
+    assert _read("mla_decode_roofline", _run(ops=NOT_THE_CALL)) is None
+
+
 def test_entries_of_the_new_cell():
     cell = [w for w in BENCH["workloads"] if w["name"] == "longcat.reason-open"][0]
     assert (cell["config"], cell["traffic"], cell["chips"]) == (CONFIG["name"], "reason-open", 1)
@@ -125,7 +162,7 @@ def test_entries_of_the_new_cell():
     for name in NEW:
         assert by_name[name]["workloads"] == ["longcat.reason-open"]
         assert by_name[name]["moves"] == "tpot_p50_ms"
-    for name in ("qmm_kernel_ms", "qmm_feed_copy_ms", "attn_decode_roofline"):
+    for name in ("qmm_kernel_ms", "attn_decode_roofline"):
         assert by_name[name]["workloads"] == ["qwen7b.chat-open"]
     traffic = json.loads((ROOT / "benchmark/traffic/reason-open.json").read_text())
     assert traffic["generator"] == "open_loop" and traffic["check_sample"] == 4
@@ -134,3 +171,17 @@ def test_entries_of_the_new_cell():
     assert not re.search(r"<\|", traffic["system"])
     rate = json.loads((ROOT / "benchmark/cells/longcat.reason-open.json").read_text())["rate_rps"]
     assert rate > 0
+
+
+def test_the_limits_file_holds_sound_and_control_readings():
+    """The mean and the 99th percentile decide (PR 44); each limit stands
+    1.25 times clear of the readings its comment lists, both ways."""
+    from benchmark.reference import check
+
+    assert check.deciding(LIMITS) == ["logit_gap_mean", "logit_gap_p99"] and "logit_gap" not in LIMITS
+    for name, sound_max, control_min in (("logit_gap_mean", 0.149, 1.381),
+                                         ("logit_gap_p99", 0.956, 3.495)):
+        assert 1.25 * sound_max <= LIMITS[name] <= control_min / 1.25, name
+        assert str(sound_max) in LIMITS["comment"] and str(control_min) in LIMITS["comment"]
+    for word in ("sound", "fp8", "1.25", "1.909", "resident_bytes_short"):
+        assert word in LIMITS["comment"], word

@@ -19,6 +19,7 @@ import pytest
 from benchmark import blocks, serving
 from benchmark.kernels import gdn_chunk, gdn_step
 from benchmark.layer_metrics._common import load_metric_file
+from benchmark.reference import check
 
 ROOT = Path(__file__).resolve().parents[2]
 BENCH = json.loads((ROOT / "BENCHMARK.json").read_text())
@@ -196,9 +197,11 @@ def test_a_program_or_block_without_it_is_read_as_nothing(name):
 def test_entries_of_the_new_cell():
     cell = [w for w in BENCH["workloads"] if w["name"] == CELL][0]
     assert (cell["config"], cell["traffic"], cell["chips"]) == (CONFIG["name"], "sysprompt-open", 1)
-    assert BENCH["workloads"][-1] is cell and BENCH["configs"][-1]["name"] == CONFIG["name"]
+    assert [w["name"] for w in BENCH["workloads"]].count(CELL) == 1
+    assert [c["name"] for c in BENCH["configs"]].count(CONFIG["name"]) == 1
     by_name = {m["name"]: m for m in BENCH["per_layer"]}
-    assert [m["name"] for m in BENCH["per_layer"][-5:]] == NEW
+    declared = [m["name"] for m in BENCH["per_layer"]]  # the five, together and in their order
+    assert declared[declared.index(NEW[0]):][:5] == NEW
     for name in NEW:
         assert by_name[name]["workloads"] == [CELL] and by_name[name]["moves"] == "tpot_p50_ms"
     for name, m in by_name.items():  # nothing that listed its cells was given this one
@@ -265,20 +268,30 @@ def _gaps(seed, lowp=None):
         *kv, jnp.arange(1, 26, dtype=jnp.int32)[None], jnp.asarray([t]), page_size=16,
         state=qwen3_next.empty_state(cfg, 1))
     rows = np.arange(t)
-    gap = lambda lg: float((ref.max(axis=1) - ref[rows, np.asarray(lg).argmax(axis=1)]).max())  # noqa: E731
+    gap = lambda lg: check.gap_stats(  # noqa: E731
+        ref.max(axis=1) - ref[rows, np.asarray(lg).argmax(axis=1)])
     return gap(served[0]), {k: gap(block.forward.logits(params, ref_cfg, ids, t, k))
                             for k in (lowp or [])}
 
 
 @pytest.mark.parametrize("seed", [3, 2 ** 31 + 11])
 def test_the_fp8_control_reads_over_the_served_path(seed):
-    """The reference in fp8 where the configuration states bfloat16 reads
-    at least twice what the served bf16 path reads, which is under the
-    cell's limit; rounding the paged cache alone to fp8 moves nothing
-    (why ``correct`` also compares the bytes)."""
+    """By the number the cell's limits decide on, the MEAN gap: the served
+    bf16 path reads under the limit with the 1.25 times the issue asks, the
+    reference in fp8 where the configuration states bfloat16 over it with
+    as much, and at least twice the served path's by the widest gap too;
+    rounding the paged cache alone to fp8 moves nothing (why ``correct``
+    also compares the bytes)."""
     sound, control = _gaps(seed, ["fp8", "kv_fp8"])
-    assert sound <= LIMITS["logit_gap"] and 2 * sound < control["fp8"], (sound, control)
-    assert control["kv_fp8"] < sound
+    assert check.deciding(LIMITS) == ["logit_gap_mean", "logit_gap_p99"]
+    limit = LIMITS["logit_gap_mean"]
+    assert 1.25 * sound["logit_gap_mean"] <= limit <= control["fp8"]["logit_gap_mean"] / 1.25, (
+        sound, control)
+    assert 2 * sound["logit_gap"] < control["fp8"]["logit_gap"], (sound, control)
+    assert control["kv_fp8"]["logit_gap_mean"] < limit
+    # the 99th percentile's limit, set at the published widths: the control is over it here too
+    assert control["fp8"]["logit_gap_p99"] > LIMITS["logit_gap_p99"], control
+    assert control["kv_fp8"]["logit_gap"] < sound["logit_gap"]
 
 
 def test_the_programs_own_fp8_cache_comes_out_not_correct():
@@ -303,7 +316,13 @@ def test_the_programs_own_fp8_cache_comes_out_not_correct():
 
 
 def test_the_limits_file_holds_sound_and_control_readings():
-    assert set(LIMITS) >= {"comment", "logit_gap"}
-    for word in ("sound", "fp8", "kv_cache_dtype", "1.25"):
+    # the mean decides; the widest gap has no limit of this file's any more
+    assert set(LIMITS) >= {"comment", "logit_gap_mean"} and "logit_gap" not in LIMITS
+    for word in ("sound", "fp8", "kv_cache_dtype", "1.25", "logit_gap_p99", "3.308", "2.713"):
         assert word in LIMITS["comment"], word
+    # the readings the limit was set from, as the comment lists them
+    for name, sound_max, control_min in (("logit_gap_mean", 0.092, 0.95),
+                                         ("logit_gap_p99", 0.855, 2.794)):
+        assert 1.25 * sound_max <= LIMITS[name] <= control_min / 1.25, name
+        assert str(sound_max) in LIMITS["comment"] and str(control_min) in LIMITS["comment"]
     assert "resident_bytes_short" not in LIMITS and "not_comparable_share" not in LIMITS  # the defaults': 0
