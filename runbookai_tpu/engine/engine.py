@@ -655,9 +655,9 @@ def _probe_pallas_attn_cached(backend: str, n_kv: int, n_q: int,
     """One small compile-and-run of each attention kernel the engine WILL
     dispatch, at its real block shapes — GQA group, head width, page
     size, dtypes and the chunk kernel's query block (``chunk_t`` is one
-    full block of it) — so a kernel Mosaic refuses stops engine
-    construction with Mosaic's own message instead of failing the first
-    request. Nothing is caught: selecting a kernel that does not compile
+    full block of it; 0: the family dispatches none) — so a kernel Mosaic
+    refuses stops engine construction with Mosaic's own message instead of
+    failing the first request. Nothing is caught: selecting a kernel that does not compile
     is an error, never a reason to serve another path under the same
     name. The call is the step programs': a STACKED ``[L, tokens, n_kv,
     hd]`` pool (two layers stand for any depth) and a layer's number.
@@ -687,15 +687,16 @@ def _probe_pallas_attn_cached(backend: str, n_kv: int, n_q: int,
     jax.block_until_ready(out)
 
     t = chunk_t
-    qt = jnp.zeros((1, t, n_q, head_dim), act_dtype)
-    positions = jnp.arange(t, dtype=jnp.int32)[None]
-    out = paged_chunk_attention(qt, kv, kv, tables,
-                                jnp.full((1,), t, jnp.int32), positions,
-                                page_size=page_size, interpret=interp,
-                                layer=layer)
-    # runbook: noqa[RBK002] — probe barrier: chunk-kernel lowering must
-    # prove out before prefill dispatches it.
-    jax.block_until_ready(out)
+    if t:
+        qt = jnp.zeros((1, t, n_q, head_dim), act_dtype)
+        positions = jnp.arange(t, dtype=jnp.int32)[None]
+        out = paged_chunk_attention(qt, kv, kv, tables,
+                                    jnp.full((1,), t, jnp.int32), positions,
+                                    page_size=page_size, interpret=interp,
+                                    layer=layer)
+        # runbook: noqa[RBK002] — probe barrier: chunk-kernel lowering must
+        # prove out before prefill dispatches it.
+        jax.block_until_ready(out)
     if kv_split:
         from runbookai_tpu.ops.paged_attention_pallas import (
             paged_decode_attention_partial,
@@ -722,13 +723,22 @@ def _shard_heads(model_cfg, mesh) -> tuple[int, int]:
     return model_cfg.n_kv_heads // kv_sh, model_cfg.n_heads // kv_sh
 
 
+def _pallas_prefill(model_cfg) -> bool:
+    """Whether the family's prefill rows (a chunk, a mixed step's runs) call
+    a Pallas kernel under ``attn_impl="pallas"``, as its decode rows do. The
+    recurrent families' do not (``pallas_prefill = False``: XLA's one-row
+    walk is ahead there), so no chunk or ragged kernel is probed for them."""
+    return getattr(model_cfg, "pallas_prefill", True)
+
+
 def _probe_pallas_attn(model_cfg, ecfg, act_dtype, mesh=None) -> None:
     from runbookai_tpu.ops.paged_attention_pallas import chunk_q_block
     from runbookai_tpu.parallel.mesh import SEQ_AXIS
 
     kv_split = mesh is not None and mesh.shape.get(SEQ_AXIS, 1) > 1
     n_kv, n_q = _shard_heads(model_cfg, mesh)
-    chunk_t = chunk_q_block(ecfg.prefill_chunk, n_q)
+    chunk_t = (chunk_q_block(ecfg.prefill_chunk, n_q)
+               if _pallas_prefill(model_cfg) else 0)
     _probe_pallas_attn_cached(jax.default_backend(), n_kv, n_q,
                               model_cfg.head_dim, ecfg.page_size,
                               jnp.dtype(ecfg.kv_dtype).name,
@@ -1153,7 +1163,10 @@ class EngineCore:
             self.ecfg = _dc.replace(self.ecfg, prefill_batch=rows)
         if not model_cfg.pallas_attention and self.ecfg.attn_impl == "pallas":
             # The Pallas kernels read per-head K/V pages; this family's
-            # forward has its own attention over its own pool.
+            # forward has its own attention over its own pool (the latent
+            # cache of ``ops/mla.py``: LongCat, JoyAI). The recurrent
+            # families' softmax layers page per-head K/V and take the decode
+            # walk, probed below at their own head count and size.
             self.ecfg = _dc.replace(self.ecfg, attn_impl="xla")
         if _kv_int8 and _kv_split_mesh:
             raise ValueError(
@@ -1191,7 +1204,8 @@ class EngineCore:
             mixed = jax.default_backend() == "tpu"
         if mixed and _kv_split_mesh:
             mixed = False
-        if mixed and self.ecfg.attn_impl == "pallas" and not _kv_int8:
+        if (mixed and self.ecfg.attn_impl == "pallas" and not _kv_int8
+                and _pallas_prefill(model_cfg)):
             _probe_pallas_ragged(model_cfg, self.ecfg, act_dtype,
                                  mesh=mesh)
         self._mixed = bool(mixed)

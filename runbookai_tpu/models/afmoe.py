@@ -424,29 +424,12 @@ def attend(q, pool_k, pool_v, gi, tables, ctx_lens, positions, page_size,
     contiguous a row) or XLA's, each with the group's ``window`` as its
     lower edge (None: the full group)."""
     if attn_impl == "pallas":
-        from runbookai_tpu.ops.paged_attention_pallas import (
-            paged_chunk_attention,
-            paged_decode_attention,
-            reads_in_place,
-        )
+        from runbookai_tpu.ops.paged_attention_pallas import paged_layer_attention
 
-        # The carried pool and the layer's number where the kernels read it
-        # in place (static, by the pool's shape: models/llama.py), else the
-        # layer's slice: the same kernel at L = 1.
-        if reads_in_place(pool_k):
-            k_walk, v_walk, layer = pool_k, pool_v, gi
-        else:
-            k_walk, v_walk = (jax.lax.dynamic_index_in_dim(a, gi, keepdims=False)
-                              for a in (pool_k, pool_v))
-            layer = None
-        interp = jax.default_backend() == "cpu"
-        if q.shape[1] == 1:
-            return paged_decode_attention(
-                q[:, 0], k_walk, v_walk, tables, ctx_lens, page_size=page_size,
-                interpret=interp, layer=layer, window=window)[:, None]
-        return paged_chunk_attention(
-            q, k_walk, v_walk, tables, ctx_lens, positions, page_size=page_size,
-            interpret=interp, layer=layer, window=window)
+        # (the carried pool and the layer's number where the kernels read it
+        # in place, else the layer's slice: static, by the pool's shape)
+        return paged_layer_attention(q, pool_k, pool_v, gi, tables, ctx_lens,
+                                     positions, page_size, window=window)
     k_pages, v_pages = (jax.lax.dynamic_index_in_dim(a, gi, keepdims=False)
                         for a in (pool_k, pool_v))
     return paged_attention(q, k_pages, v_pages, tables, ctx_lens, positions,

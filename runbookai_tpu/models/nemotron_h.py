@@ -61,7 +61,12 @@ import jax
 import jax.numpy as jnp
 
 from runbookai_tpu.models.longcat import EXPERT_COUNTS, _stacked_normal
-from runbookai_tpu.models.qwen3_next import _row_state, attend, empty_state  # noqa: F401
+from runbookai_tpu.models.qwen3_next import (  # noqa: F401
+    _row_state,
+    attend,
+    empty_state,
+    pallas_walks,
+)
 from runbookai_tpu.ops.attention import write_kv_pages_batch
 from runbookai_tpu.ops.gated_delta import causal_conv_tail
 from runbookai_tpu.ops.moe import (
@@ -135,9 +140,12 @@ class NemotronHConfig:
     family: str = "qwen2"  # the chat template: ChatML (assumed)
 
     tie_embeddings = False
-    # Attention here is the XLA page walk (group 16 over 2 KV heads: the
-    # Pallas kernels have not been proven at it), whatever ``attn_impl``.
-    pallas_attention = False
+    # ``attn_impl="pallas"`` is the Pallas decode walk over the paged pool
+    # for the one-token rows (2 kv heads of 128, a group of 16 query rows a
+    # head: :func:`attend_live`); a prefill run keeps XLA's one-row walk, so
+    # the engine probes no chunk kernel for this family.
+    pallas_attention = True
+    pallas_prefill = False
 
     def __post_init__(self):
         p = self.hybrid_override_pattern
@@ -157,6 +165,10 @@ class NemotronHConfig:
     @property
     def n_heads(self) -> int:
         return self.num_attention_heads
+
+    @property
+    def n_kv_heads(self) -> int:
+        return self.num_key_value_heads
 
     @property
     def norm_eps(self) -> float:
@@ -491,15 +503,20 @@ ATTEND_ROWS = 8
 
 
 def attend_live(q, ai, kv_k, kv_v, page_tables, ctx_lens, positions, live,
-                page_size, block_pages):
-    """``qwen3_next.attend`` (the page walk out of the WHOLE pool's row view,
-    the layer's pages found by shifting the table) for one token a slot (``q`` [S, 1, H, hd]) over the
-    LIVE rows only (``live`` [S]), ``ATTEND_ROWS`` of them a turn of a loop
-    whose length the device decides; zero where nothing ran. The walk
+                page_size, block_pages, attn_impl="xla"):
+    """``qwen3_next.attend`` for one token a slot (``q`` [S, 1, H, hd]),
+    zero where the slot is free. The Pallas decode walk takes every slot as
+    it is: a free one costs it an empty grid step. XLA's walk (the page walk
+    out of the WHOLE pool's row view, the layer's pages found by shifting the
+    table) runs over the LIVE rows only (``live`` [S]), ``ATTEND_ROWS`` of
+    them a turn of a loop whose length the device decides. That walk
     gathers ``block_pages`` pages a row and block whether the row is live or
     free and as far as the longest context of its rows: over all 48 slots
     with five rows live it was a third of a decode pass on the chip (1.2 ms
     a layer, its two gathers at 111 GB/s; PERF.md, PR 39)."""
+    if pallas_walks(q, kv_k, attn_impl):
+        return attend(q, ai, kv_k, kv_v, page_tables, ctx_lens, positions,
+                      page_size, block_pages, attn_impl)
     s = q.shape[0]
     rows = ATTEND_ROWS
     while s % rows:
@@ -523,16 +540,17 @@ def attention_output(attn, w, ai):
 
 
 def attention(x, w, ai, cfg, positions, kv_k, kv_v, page_tables, ctx_lens,
-              page_size, block_pages):
+              page_size, block_pages, attn_impl="xla"):
     """Attention layer ``ai`` over ``x`` [B, T, D]: (out, kv_k', kv_v'). One
-    token a row (a decode pass) walks the live rows only."""
+    token a row is a decode pass (:func:`attend_live`)."""
     q, k, v = attention_inputs(x, w, ai, cfg)
     kv_k = write_kv_pages_batch(kv_k, k, positions, page_tables, page_size, layer=ai)
     kv_v = write_kv_pages_batch(kv_v, v, positions, page_tables, page_size, layer=ai)
     if x.shape[1] == 1:
         attn = attend_live(q, ai, kv_k, kv_v, page_tables, ctx_lens, positions,
-                           positions[:, 0] < ctx_lens, page_size, block_pages)
-    else:
+                           positions[:, 0] < ctx_lens, page_size, block_pages,
+                           attn_impl)
+    else:  # a prefill run: XLA's walk, whatever ``attn_impl``
         attn = attend(q, ai, kv_k, kv_v, page_tables, ctx_lens, positions, page_size,
                       block_pages)
     return attention_output(attn, w, ai), kv_k, kv_v
@@ -626,7 +644,7 @@ def _put_row_state(state, mi, rows, new):
 
 def _forward_hidden(params, cfg, tokens, positions, kv_k, kv_v, page_tables,
                     ctx_lens, page_size, block_pages, state, ssm_mixer,
-                    attn_mixer=None):
+                    attn_mixer=None, attn_impl="xla"):
     """The stack over one paged chunk ``[B, T]``, without the head: (hidden
     [B, T, D], kv_k', kv_v', expert counts, state'). ``ssm_mixer(x, live,
     mi, state) -> (out, state')`` runs a Mamba layer over the normed hidden:
@@ -664,7 +682,8 @@ def _forward_hidden(params, cfg, tokens, positions, kv_k, kv_v, page_tables,
             o, kv_k, kv_v = attn_mixer(x, ai, kv_k, kv_v)
         else:
             o, kv_k, kv_v = attention(x, w, ai, cfg, positions, kv_k, kv_v,
-                                      page_tables, ctx_lens, page_size, block_pages)
+                                      page_tables, ctx_lens, page_size, block_pages,
+                                      attn_impl)
         return hidden + o.astype(hidden.dtype), kv_k, kv_v, state, counts
 
     h, kv_k, kv_v, state, counts = run_plan(
@@ -691,7 +710,7 @@ def forward_counted(params, cfg, tokens, positions, kv_k, kv_v, page_tables,
     ``i`` runs from and writes back slot ``state_rows[i]`` of the state pool
     (None: slot ``i``, the decode programs; a slot out of range is a pad
     row's and is dropped)."""
-    del attn_impl, mesh, adapter_ids, qmm_impl  # one path; the engine refuses the rest
+    del mesh, adapter_ids, qmm_impl  # one path; the engine refuses the rest
     w = params["layers"]
     rows = (jnp.arange(tokens.shape[0], dtype=jnp.int32) if state_rows is None
             else state_rows)
@@ -708,7 +727,7 @@ def forward_counted(params, cfg, tokens, positions, kv_k, kv_v, page_tables,
 
     h, kv_k, kv_v, counts, state = _forward_hidden(
         params, cfg, tokens, positions, kv_k, kv_v, page_tables, ctx_lens,
-        page_size, block_pages, state, ssm_mixer)
+        page_size, block_pages, state, ssm_mixer, attn_impl=attn_impl)
     return _head(params, cfg, h), kv_k, kv_v, counts, state
 
 
@@ -726,7 +745,7 @@ def forward_ragged_counted(params, cfg, tokens, positions, row_ids, kv_k, kv_v,
     row's chunk gathered into a run of its own, a Mamba layer from the state
     of its slot (``state_rows[row]``) and written back to it, an attention
     layer over its own page table."""
-    del attn_impl, mesh, adapter_ids, qmm_impl
+    del mesh, adapter_ids, qmm_impl
     n = tokens.shape[0]
     rq = ragged_block
     nb = n // rq
@@ -786,7 +805,8 @@ def forward_ragged_counted(params, cfg, tokens, positions, row_ids, kv_k, kv_v,
         q = q.reshape(n, *q.shape[2:])
         dec_pos = positions[:n_dec].reshape(slots, rq)[:, :1]
         out = attend_live(dec(q), ai, kv_k, kv_v, page_tables[:slots], ctx_lens[:slots],
-                          dec_pos, dec_pos[:, 0] < ctx_lens[:slots], page_size, block_pages)
+                          dec_pos, dec_pos[:, 0] < ctx_lens[:slots], page_size, block_pages,
+                          attn_impl)
         attn = jnp.zeros((n + 1, *q.shape[1:]), q.dtype)
         attn = attn.at[jnp.arange(slots) * rq].set(out[:, 0])
 
@@ -816,5 +836,5 @@ def forward_impl(params: Params, cfg: NemotronHConfig, tokens, positions, kv_k,
     state')."""
     logits, kv_k, kv_v, _, state = forward_counted(
         params, cfg, tokens, positions, kv_k, kv_v, page_tables, ctx_lens,
-        page_size, block_pages, state=state, state_rows=state_rows)
+        page_size, block_pages, attn_impl, state=state, state_rows=state_rows)
     return logits, kv_k, kv_v, state

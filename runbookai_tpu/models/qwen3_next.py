@@ -124,9 +124,12 @@ class Qwen3NextConfig:
     family: str = "qwen2"  # the chat template: the family renders ChatML
 
     tie_embeddings = False
-    # Attention here is the XLA page walk (head size 256, group 8: the
-    # Pallas kernels have not been proven at it), whatever ``attn_impl``.
-    pallas_attention = False
+    # ``attn_impl="pallas"`` is the Pallas decode walk over the paged pool
+    # for the one-token rows (2 kv heads of 256, a group of 8 query rows a
+    # head: :func:`attend`); a prefill run keeps XLA's one-row walk, so the
+    # engine probes no chunk kernel for this family.
+    pallas_attention = True
+    pallas_prefill = False
 
     @property
     def dim(self) -> int:
@@ -139,6 +142,10 @@ class Qwen3NextConfig:
     @property
     def n_heads(self) -> int:
         return self.num_attention_heads
+
+    @property
+    def n_kv_heads(self) -> int:
+        return self.num_key_value_heads
 
     @property
     def norm_eps(self) -> float:
@@ -436,13 +443,44 @@ def attention_inputs(x, w, pi, cfg, positions):
     return rotary(q), gate, rotary(k), v
 
 
+def pallas_walks(q, kv_k, attn_impl: str) -> bool:
+    """Whether :func:`attend` runs the Pallas decode walk: ``attn_impl=
+    "pallas"``, one token a row, and a kv-head axis on Mosaic's tile. Two
+    fp8 heads are under it: the kernels would pad them, a copy of what they
+    are handed a call, so that pool takes XLA's walk. Static, by shape."""
+    from runbookai_tpu.ops.paged_attention_pallas import heads_on_tile
+
+    return (attn_impl == "pallas" and q.shape[1] == 1
+            and heads_on_tile(kv_k.shape[-2], kv_k.dtype))
+
+
 def attend(q, pi, kv_k, kv_v, page_tables, ctx_lens, positions, page_size,
-           block_pages):
-    """Softmax attention of ``q`` [B, T, H, hd] over period ``pi``'s pages.
-    The walk gathers its pages out of the WHOLE pool's row view, the
-    period's pages found by shifting the table: a layer's slice handed to
-    the loop was copied out first, 67 MB a side (seen in the compiled
-    program)."""
+           block_pages, attn_impl="xla"):
+    """Softmax attention of ``q`` [B, T, H, hd] over period ``pi``'s pages
+    (a row's positions contiguous; a pad at the trash position, its output
+    dropped by the caller).
+
+    One token a row (a decode pass, a mixed step's decode rows) is the
+    Pallas decode walk where :func:`pallas_walks` says so, over the pool the
+    scan carries, ``pi`` the kernel's layer operand: a row reads its own
+    live pages, no other's, and a free slot (its position not under its
+    context) is one empty grid step that writes zeros.
+
+    A prefill run, and every row under ``attn_impl="xla"``, is XLA's walk: it
+    gathers its pages out of the WHOLE pool's row view, the period's pages
+    found by shifting the table (a layer's slice handed to the loop was
+    copied out first, 67 MB a side, seen in the compiled program),
+    ``block_pages`` pages for every row, live or free, as far as the batch's
+    longest context. Over one row's run that is 2x ahead of the chunk walk
+    (PERF.md section 6, PR 43); over 64 slots with 14 live it was a third
+    of the device's time."""
+    if pallas_walks(q, kv_k, attn_impl):
+        from runbookai_tpu.ops.paged_attention_pallas import paged_layer_attention
+
+        live = positions[:, 0] < ctx_lens
+        return paged_layer_attention(
+            q, kv_k, kv_v, pi, page_tables, jnp.where(live, ctx_lens, 0),
+            positions, page_size, name="paged_decode_walk")
     shifted = page_tables + pi * (kv_k.shape[1] // page_size)
     return paged_attention(
         q, pool_rows(kv_k, pi)[0], pool_rows(kv_v, pi)[0], shifted, ctx_lens,
@@ -459,14 +497,14 @@ def attention_output(attn, gate, w, pi):
 
 
 def gated_attention(x, w, pi, cfg, positions, kv_k, kv_v, page_tables,
-                    ctx_lens, page_size, block_pages):
+                    ctx_lens, page_size, block_pages, attn_impl="xla"):
     """The full-attention mixer of period ``pi`` over ``x`` [B, T, D]:
     (out [B, T, D], kv_k', kv_v')."""
     q, gate, k, v = attention_inputs(x, w, pi, cfg, positions)
     kv_k = write_kv_pages_batch(kv_k, k, positions, page_tables, page_size, layer=pi)
     kv_v = write_kv_pages_batch(kv_v, v, positions, page_tables, page_size, layer=pi)
     attn = attend(q, pi, kv_k, kv_v, page_tables, ctx_lens, positions, page_size,
-                  block_pages)
+                  block_pages, attn_impl)
     return attention_output(attn, gate, w, pi), kv_k, kv_v
 
 
@@ -541,7 +579,7 @@ def _put_state_layer(state, li, new):
 
 def _forward_hidden(params, cfg, tokens, positions, kv_k, kv_v, page_tables,
                     ctx_lens, page_size, block_pages, state, linear_mixer,
-                    full_mixer=None):
+                    full_mixer=None, attn_impl="xla"):
     """The stack over one paged chunk ``[B, T]``, without the head: (hidden
     [B, T, D], kv_k', kv_v', expert counts, state'). ``linear_mixer(x, live,
     li, state) -> (out, state')`` runs a linear layer over the normed
@@ -571,7 +609,7 @@ def _forward_hidden(params, cfg, tokens, positions, kv_k, kv_v, page_tables,
             elif j == interval - 1:
                 o, kv_k, kv_v = gated_attention(
                     x, w, pi, cfg, positions, kv_k, kv_v, page_tables,
-                    ctx_lens, page_size, block_pages)
+                    ctx_lens, page_size, block_pages, attn_impl)
             else:
                 o, state = linear_mixer(x, live, pi * (interval - 1) + j, state)
             hidden = hidden + o
@@ -609,7 +647,7 @@ def forward_counted(params, cfg, tokens, positions, kv_k, kv_v, page_tables,
     ``i`` runs from and writes back slot ``state_rows[i]`` of the state pool
     (None: slot ``i``, the decode programs; a slot out of range is a pad
     row's and is dropped)."""
-    del attn_impl, mesh, adapter_ids, qmm_impl  # one path; the engine refuses the rest
+    del mesh, adapter_ids, qmm_impl  # one path; the engine refuses the rest
     w = params["layers"]
 
     def linear_mixer(x, live, li, state):
@@ -626,7 +664,7 @@ def forward_counted(params, cfg, tokens, positions, kv_k, kv_v, page_tables,
 
     h, kv_k, kv_v, counts, state = _forward_hidden(
         params, cfg, tokens, positions, kv_k, kv_v, page_tables, ctx_lens,
-        page_size, block_pages, state, linear_mixer)
+        page_size, block_pages, state, linear_mixer, attn_impl=attn_impl)
     return _head(params, cfg, h), kv_k, kv_v, counts, state
 
 
@@ -647,7 +685,7 @@ def forward_ragged_counted(params, cfg, tokens, positions, row_ids, kv_k, kv_v,
     row's chunk gathered into a run of its own — a linear layer from the
     state of its slot (``state_rows[row]``) and written back to it, a
     full-attention layer over its own page table."""
-    del attn_impl, mesh, adapter_ids, qmm_impl
+    del mesh, adapter_ids, qmm_impl
     n = tokens.shape[0]
     rq = ragged_block
     nb = n // rq
@@ -717,7 +755,8 @@ def forward_ragged_counted(params, cfg, tokens, positions, row_ids, kv_k, kv_v,
         q = q.reshape(n, *q.shape[2:])
         dec = attend(q[:n_dec].reshape(slots, rq, *q.shape[1:])[:, :1], pi, kv_k,
                      kv_v, page_tables[:slots], ctx_lens[:slots],
-                     positions[:n_dec].reshape(slots, rq)[:, :1], page_size, block_pages)
+                     positions[:n_dec].reshape(slots, rq)[:, :1], page_size, block_pages,
+                     attn_impl)
         attn = jnp.zeros((n + 1, *q.shape[1:]), q.dtype)
         attn = attn.at[jnp.arange(slots) * rq].set(dec[:, 0])
         def prefill_row(j, attn):
@@ -748,5 +787,5 @@ def forward_impl(params: Params, cfg: Qwen3NextConfig, tokens, positions, kv_k,
     state')."""
     logits, kv_k, kv_v, _, state = forward_counted(
         params, cfg, tokens, positions, kv_k, kv_v, page_tables, ctx_lens,
-        page_size, block_pages, state=state, state_rows=state_rows)
+        page_size, block_pages, attn_impl, state=state, state_rows=state_rows)
     return logits, kv_k, kv_v, state

@@ -29,7 +29,9 @@ one-layer pool ``[tokens, n_kv, hd]`` is the same kernel at L = 1, layer
   kv heads of a group go through one product (a foreign head's column is
   masked like a dead position). One kernel serves every pool: raw pages,
   int8 pages with scales, and a page-split shard that skips the pages it
-  does not own and returns partials.
+  does not own and returns partials. A raw pool whose heads are wider than
+  the lanes takes the chunk walk at one query a block instead
+  (:func:`_walks_by_head`: its page keeps the kv-head axis).
 - :func:`paged_chunk_attention` — T > 1 (chunked prefill, the speculative
   verify forward, and through :func:`paged_ragged_attention` the mixed
   step's flat buffer), grid = (rows, query blocks): a block of TQ queries
@@ -40,6 +42,17 @@ one-layer pool ``[tokens, n_kv, hd]`` is the same kernel at L = 1, layer
   query row (``pos < ctx`` and ``pos <= q0 + t``: positions contiguous, so
   they derive from the scalar-prefetched start). TQ bounds the VMEM
   scratch (:func:`chunk_q_block`).
+
+Who calls them, at which head shapes (query heads x kv heads x head size):
+the dense families (``models/llama.py``: Qwen2.5-7B 28 x 4 x 128, Llama-3-8B
+32 x 8 x 128, a tp shard's lone kv head), Trinity-Mini's full and sliding
+layers (``models/afmoe.py``: 32 x 4 x 128, the window a lower edge of the
+walk), and the ONE-TOKEN rows of the two recurrent families' softmax layers
+through :func:`paged_layer_attention`: Qwen3-Next (``models/qwen3_next.py``:
+16 x 2 x 256, a decode row by kv head) and Nemotron-3-Nano
+(``models/nemotron_h.py``: 32 x 2 x 128); their prefill runs keep XLA's
+one-row walk. The latent (MLA) families have their own attention over their
+own pool (``ops/mla.py``).
 
 Selected by ``EngineConfig.attn_impl = "pallas"``; interpret mode keeps it
 testable on CPU meshes.
@@ -333,7 +346,8 @@ def _kv_tile(n_kv: int, kv_dtype) -> int:
 def _chunk_pages(pool: jnp.ndarray, page_size: int) -> jnp.ndarray:
     """``pool [(L,) tokens, n_kv, hd]`` as the pages the chunk walk
     copies, ``[(L x) pages, page_size, n_kv, hd]``: the bytes as they lie,
-    for the serving shapes (4 or 8 kv heads of 128 in bf16 or fp8). Mosaic
+    for the serving shapes (2, 4 or 8 kv heads of 128 or 256 in bf16, 4 or
+    8 in fp8). Mosaic
     slices such an operand only in whole sublane tiles (:func:`_kv_tile`),
     so any other head count is zero-padded to them, and a lone head (a tp
     shard's) gives up its axis, ``[pages, page_size, hd]``: XLA lays that
@@ -345,6 +359,15 @@ def _chunk_pages(pool: jnp.ndarray, page_size: int) -> jnp.ndarray:
     short = -n_kv % _kv_tile(n_kv, pool.dtype)
     pool = jnp.pad(pool, [(0, 0)] * (pool.ndim - 2) + [(0, short), (0, 0)])
     return pool.reshape(-1, page_size, *pool.shape[-2:])
+
+
+def heads_on_tile(n_kv: int, kv_dtype) -> bool:
+    """Whether a pool's kv-head axis is whole sublane tiles
+    (:func:`_kv_tile`), or absent: the chunk walk slices a page of such a
+    pool as it lies. Any other count (two fp8 heads, three bf16 ones) it
+    pads in XLA first, a copy of what it is handed. A family with an XLA
+    walk of its own sends such a pool there instead."""
+    return n_kv == 1 or n_kv % _kv_tile(n_kv, kv_dtype) == 0
 
 
 def reads_in_place(pool, mesh=None) -> bool:
@@ -366,8 +389,7 @@ def reads_in_place(pool, mesh=None) -> bool:
         tokens //= mesh.shape.get(SEQ_AXIS, 1)
         if tp_shardable(mesh, n_kv):
             n_kv //= _model_tp(mesh)
-    lies_as_pages = hd % 128 == 0 and (
-        n_kv == 1 or n_kv % _kv_tile(n_kv, pool.dtype) == 0)
+    lies_as_pages = hd % 128 == 0 and heads_on_tile(n_kv, pool.dtype)
     return lies_as_pages and (n_layers * tokens * n_kv * hd
                               * pool.dtype.itemsize >= _ON_CHIP_BYTES)
 
@@ -384,7 +406,8 @@ def _stacked(pools, layer):
 
 def _decode_walk(q, k_flat, v_flat, page_tables, ctx_lens, page_size: int,
                  interpret: bool, shard=None, pages_local: int | None = None,
-                 layer=None, window: int | None = None):
+                 layer=None, window: int | None = None,
+                 name: str | None = None):
     """Launch :func:`_decode_walk_kernel` over the rows of ``q``. The page
     table is the call's first operand and the result ``[rows, n_q, hd]``:
     the benchmark finds the kernel in a trace by those two shapes
@@ -392,8 +415,8 @@ def _decode_walk(q, k_flat, v_flat, page_tables, ctx_lens, page_size: int,
     the lanes (the test-size models) is zero-padded to them, which leaves
     every score and, once sliced, the output what they were. With a
     ``layer`` the pools are ``[L, tokens, ...]`` and the sources their
-    page views with L merged in front. A call with a ``window`` is named
-    for it in the trace (``swa_decode_walk``)."""
+    page views with L merged in front. ``name``: the call's in a device
+    trace (None: the compiler's ``closed_call``)."""
     b, n_q, hd = q.shape
     (k_flat, v_flat), layer = _stacked((k_flat, v_flat), layer)
     scaled = isinstance(k_flat, tuple)
@@ -446,10 +469,25 @@ def _decode_walk(q, k_flat, v_flat, page_tables, ctx_lens, page_size: int,
         out_shape=[jax.ShapeDtypeStruct((b, n_q, w), dt)
                    for w, dt in out_kinds],
         interpret=interpret,
-        name=None if window is None else "swa_decode_walk",
+        name=name,
     )(*prefetch, q, *srcs)
     out, *stats = outs
     return (out[..., :hd], *stats) if partial else out[..., :hd]
+
+
+def _walks_by_head(k_flat) -> bool:
+    """Whether a decode row walks its pages one KV HEAD at a time: a raw
+    pool of several heads each wider than the 128 lanes (Qwen3-Next: 2 of
+    256). The decode walk's page ``[page_size x n_kv, hd]`` puts every
+    head's keys in the rows of one product, and that view is the bytes as
+    they lie only while a head is ONE lane tile: the device tiles the pool's
+    two minor axes together, so at 256 a token's second half of head 0 lies
+    behind head 1's first, and the merged view of a ``[tokens, 2, 256]``
+    pool was a copy of the whole pool a call (805 MB; PERF.md section 6, PR
+    31). The chunk walk's page ``[page_size, n_kv, hd]`` keeps both axes:
+    such a row is a block of one query of it. Static, by shape."""
+    return (not isinstance(k_flat, tuple) and k_flat.shape[-2] > 1
+            and k_flat.shape[-1] > 128)
 
 
 def paged_decode_attention(
@@ -462,12 +500,26 @@ def paged_decode_attention(
     interpret: bool = False,
     layer=None,  # with it the pools are [L, tokens, ...]: read layer `layer`
     window: int | None = None,  # the row sees its last `window` positions
+    name: str | None = None,  # the call's name in a device trace
 ) -> jnp.ndarray:
     """Ragged paged attention for decode (one query token per sequence).
     An int8 pool is ``(values [tokens, n_kv, hd], f32 scales [tokens,
-    n_kv])``: HBM still moves 1 byte a value, widened in VMEM."""
+    n_kv])``: HBM still moves 1 byte a value, widened in VMEM. A pool whose
+    head is wider than the lanes is walked by kv head
+    (:func:`_walks_by_head`): the chunk walk, one query a block where the
+    head's ``group`` query rows fill whole sublane tiles. A call with a
+    ``window`` and no ``name`` is named for it (``swa_decode_walk``)."""
+    if window is not None and name is None:
+        name = "swa_decode_walk"
+    if _walks_by_head(k_flat):
+        group = q.shape[1] // k_flat.shape[-2]
+        return paged_chunk_attention(
+            q[:, None], k_flat, v_flat, page_tables, ctx_lens,
+            (ctx_lens - 1)[:, None], page_size=page_size,
+            interpret=interpret, q_block=None if group % 8 else 1,
+            layer=layer, window=window, name=name)[:, 0]
     return _decode_walk(q, k_flat, v_flat, page_tables, ctx_lens, page_size,
-                        interpret, layer=layer, window=window)
+                        interpret, layer=layer, window=window, name=name)
 
 
 def _chunk_walk_kernel(tables_ref, ctx_ref, q_start_ref, layer_ref, q_ref,
@@ -579,6 +631,7 @@ def paged_chunk_attention(
     q_block: int | None = None,
     layer=None,  # with it the pools are [L, tokens, ...]: read layer `layer`
     window: int | None = None,  # a query sees its last `window` positions
+    name: str | None = None,  # the call's name in a device trace
 ) -> jnp.ndarray:
     """Ragged paged attention for T>1 chunks (prefill / speculative verify).
 
@@ -629,7 +682,7 @@ def paged_chunk_attention(
         ),
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         interpret=interpret,
-        name=None if window is None else "swa_chunk_walk",
+        name=name or (None if window is None else "swa_chunk_walk"),
     )(page_tables, ctx_lens, q_positions[:, 0].astype(jnp.int32), layer, q,
       *pages)
     return out[:, :t, :, :hd]
@@ -680,6 +733,42 @@ def paged_ragged_attention(
         page_size=page_size, interpret=interpret, q_block=rq, layer=layer,
         window=window,
     ).reshape(n, n_q, hd)
+
+
+def paged_layer_attention(
+    q: jnp.ndarray,  # [B, T, n_q, hd]
+    pool_k: jnp.ndarray,  # [L, tokens, n_kv, hd], as the layer scan carries it
+    pool_v: jnp.ndarray,
+    layer,  # which of the L
+    page_tables: jnp.ndarray,  # [B, P] int32
+    ctx_lens: jnp.ndarray,  # [B] int32, the chunk included
+    q_positions: jnp.ndarray,  # [B, T] int32, contiguous a row
+    page_size: int,
+    window: int | None = None,
+    name: str | None = None,  # the call's name in a device trace
+) -> jnp.ndarray:
+    """``q`` over layer ``layer`` of a raw stacked pool, as a forward's
+    layer scan calls the walks: one token a row is the decode walk (every
+    slot a grid step; a free one, ``ctx == 0``, fetches nothing), more the
+    chunk walk. The kernels get the carried pool and the layer's number
+    where they read it in place (:func:`reads_in_place`, static, by the
+    pool's shape), else the layer's slice: the same kernel at L = 1."""
+    if reads_in_place(pool_k):
+        k_walk, v_walk = pool_k, pool_v
+    else:
+        k_walk, v_walk = (jax.lax.dynamic_index_in_dim(a, layer, keepdims=False)
+                          for a in (pool_k, pool_v))
+        layer = None
+    interpret = jax.default_backend() == "cpu"
+    if q.shape[1] == 1:
+        return paged_decode_attention(
+            q[:, 0], k_walk, v_walk, page_tables, ctx_lens,
+            page_size=page_size, interpret=interpret, layer=layer,
+            window=window, name=name)[:, None]
+    return paged_chunk_attention(
+        q, k_walk, v_walk, page_tables, ctx_lens, q_positions,
+        page_size=page_size, interpret=interpret, layer=layer, window=window,
+        name=name)
 
 
 def paged_decode_attention_partial(

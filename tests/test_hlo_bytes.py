@@ -44,6 +44,8 @@ The on-device twins (real Mosaic, no interpret) live in
 ``test_pallas_on_device.py``.
 """
 
+import dataclasses
+import hashlib
 import re
 
 import jax
@@ -860,3 +862,147 @@ def test_a_stack_the_device_keeps_transposed_is_not_copied(one_chip):
     assert compiled.memory_analysis().temp_size_in_bytes < stack_bytes // 8
     assert held_expert_copies(compiled.as_text(), [(2, 16, 2688, 1856),
                                                    (2, 16, 1856, 2688)]) == []
+
+
+# ------------------------------- the recurrent families on the Pallas walk
+#
+# Qwen3-Next and Nemotron-3-Nano page per-head keys and values of 2 kv heads
+# for their softmax layers; with `attn_impl="pallas"` the ONE-TOKEN rows of
+# those layers (a decode pass, a mixed step's decode rows) call the decode
+# walk on the pool their layer scan carries, and a prefill run keeps XLA's
+# one-row walk. The engines below keep what the kernels and the pool see of
+# the two benchmark cells — head counts and sizes, 8,192 pages of 16, the
+# slots, a table as wide, chunks of 512, 8 passes a dispatch — and cut what
+# only a compile's seconds hang on (depth, widths outside attention, experts,
+# vocabulary). At the cells' whole size the same programs were compiled by a
+# scratch script (CHANGES.md, PR 45: no copy of either pool).
+
+
+def _recurrent_core(cfg, init_params, slots, max_seq_len):
+    params = init_params(jax.random.PRNGKey(0), cfg, jnp.bfloat16)
+    return EngineCore(cfg, params, ByteTokenizer(), EngineConfig(
+        page_size=16, num_pages=8192, max_batch_slots=slots,
+        prefill_chunk=512, max_seq_len=max_seq_len, kv_dtype=jnp.bfloat16,
+        attn_impl="pallas", mixed_dispatch=True, speculative=False,
+        decode_steps_per_dispatch=8))
+
+
+@pytest.fixture(scope="module")
+def recurrent_chip_cores():
+    """{family: engine}: `qwen3next.sysprompt-open`'s attention (16 query
+    heads over 2 kv heads of 256, 64 slots, a table 1,025 wide) over two
+    periods, and `nemotron3nano.reason-open`'s (32 over 2 of 128, 48 slots,
+    513) over a pattern with two attention layers: pools of
+    ``bf16[2, 131072, 2, 256]`` and ``bf16[2, 131072, 2, 128]`` a side."""
+    from runbookai_tpu.models import nemotron_h, qwen3_next
+
+    cores = {}
+    cfg = dataclasses.replace(
+        CONFIGS["qwen3-next-test"], name="hlo-qwen3next-chip-test",
+        hidden_size=256, num_attention_heads=16, head_dim=256,
+        linear_key_head_dim=128, linear_value_head_dim=128)
+    cores["qwen3next"] = _recurrent_core(cfg, qwen3_next.init_params, 64, 16384)
+    cfg = dataclasses.replace(
+        CONFIGS["nemotron-h-test"], name="hlo-nemotron-chip-test",
+        hidden_size=256, num_attention_heads=32, head_dim=128,
+        mamba_head_dim=64, ssm_state_size=128, chunk_size=128)
+    cores["nemotron"] = _recurrent_core(cfg, nemotron_h.init_params, 48, 8192)
+    return cores
+
+
+def _slot_gathers(txt, core):
+    """XLA's walk over the decode rows: its gathers of 32 pages a row for
+    every slot (Qwen3-Next) or for 8 live rows a turn (Nemotron's
+    `attend_live`). A prefill run's, one row's 32 pages, are not among them."""
+    n_kv, hd = core._kv_k.shape[2:]
+    rows = "|".join(str(32 * r) for r in (core.ecfg.max_batch_slots, 8))
+    return re.findall(rf"bf16\[(?:{rows}),16,{n_kv},{hd}\]", txt)
+
+
+@pytest.mark.parametrize("program", STEP_PROGRAMS)
+@pytest.mark.parametrize("family", ["qwen3next", "nemotron"])
+def test_recurrent_step_program_for_the_chip_reads_the_pool_where_it_lies(
+        one_chip, recurrent_chip_cores, family, program, monkeypatch):
+    """Compiled by the chip's own compiler: the decode walk is in the program
+    under its name, no copy or re-laid-out view of either side of the pool
+    is (a ``[pages, page_size x n_kv, hd]`` view of a ``[tokens, 2, 256]``
+    pool was one, 805 MB a call: PR 31), no layer of it is staged in front of
+    the call, and XLA's walk over the decode rows, with its 32-page gathers
+    for every slot, is gone; the mixed step's prefill runs keep theirs, one
+    row's pages a gather."""
+    core = recurrent_chip_cores[family]
+    assert core.ecfg.attn_impl == "pallas"
+    assert paged_attention_pallas.reads_in_place(core._kv_k)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    compiled = lower_decode(core, program=program, sharding=one_chip)
+    txt = compiled.as_text()
+    assert "%paged_decode_walk" in txt
+    _assert_one_pool(compiled, core)
+    bad = kv_layer_slices(compiled, core)
+    assert bad == [], "\n".join(bad)
+    assert _slot_gathers(txt, core) == []
+    n_kv, hd = core._kv_k.shape[2:]
+    assert (f"bf16[32,16,{n_kv},{hd}]" in txt) == (program == "_mixed_step")
+
+
+@pytest.mark.parametrize("family", ["qwen3next", "nemotron"])
+def test_recurrent_decode_program_on_xlas_walk_gathers_for_every_slot(
+        one_chip, recurrent_chip_cores, family, monkeypatch):
+    """The control, RED: the same engine's `_decode_multi` with
+    `attn_impl="xla"` holds no walk kernel and gathers 32 pages for each of
+    the 64 slots, live or free, an iteration (a third of the device's time in
+    `qwen3next.sysprompt-open` before PR 45), or for 8 live rows a turn."""
+    core = recurrent_chip_cores[family]
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    txt = lower_decode(core, program="_decode_multi", attn_impl="xla",
+                       sharding=one_chip).as_text()
+    assert "%paged_decode_walk" not in txt
+    assert _slot_gathers(txt, core)
+
+
+# The walks' other callers must launch what they launched: the dense cell's
+# two kernels and Trinity-Mini's two window kernels, traced at the cells'
+# shapes, as digests of their jaxprs' text (it holds no source location, so
+# equal text is an unchanged kernel: PR 41). Taken on PR 42's tree and equal
+# on PR 45's, whose `name=` and by-head route are statically absent here
+# (under the conftest's matmul precision, which the text carries);
+# the four compiled step programs (`_decode_multi` / `_mixed_step` of both
+# cells, for a described v5e) equalled the parent's instruction for
+# instruction too, by a scratch script (CHANGES.md, PR 45). A PR that means to
+# change one of these kernels refreshes its digest:
+#   hashlib.sha256(str(jax.make_jaxpr(f)(*shapes)).encode()).hexdigest()[:16]
+_DENSE_POOL = ((28, 49152, 4, 128), jnp.bfloat16)
+_WINDOW_POOL = ((24, 41488, 4, 128), jnp.bfloat16)
+KERNEL_DIGESTS = {
+    "dense decode": ("f6e0d5d4d56b3f2b", "decode", _DENSE_POOL, (16, 28), 513, None),
+    "dense ragged": ("7aa7f059cc0964a4", "ragged", _DENSE_POOL, (640, 28), 513, None),
+    "window decode": ("fcd5255e4f40520e", "decode", _WINDOW_POOL, (16, 32), 1089, 2048),
+    "window chunk": ("27b3a94a3318c13b", "chunk", _WINDOW_POOL, (80, 32), 1089, 2048),
+}
+
+
+@pytest.mark.parametrize("kernel", list(KERNEL_DIGESTS))
+def test_the_other_families_kernels_are_the_parents(kernel):
+    from jax import ShapeDtypeStruct as S
+
+    pap = paged_attention_pallas
+    digest, entry, (pool, dtype), (rows, n_q), width, window = KERNEL_DIGESTS[kernel]
+    i32, hd, layer = jnp.int32, pool[-1], jnp.int32(3)
+    k = v = S(pool, dtype)
+    if entry == "decode":
+        f = lambda q, k, v, t, c: pap.paged_decode_attention(  # noqa: E731
+            q, k, v, t, c, 16, layer=layer, window=window)
+        args = (S((rows, n_q, hd), dtype), k, v, S((rows, width), i32), S((rows,), i32))
+    elif entry == "ragged":
+        # (the mixed step's 21 rows: 16 slots, 4 prefill rows, the null row)
+        f = lambda q, k, v, t, c, p, r: pap.paged_ragged_attention(  # noqa: E731
+            q, k, v, t, c, p, r, 16, layer=layer)
+        args = (S((rows, n_q, hd), dtype), k, v, S((21, width), i32), S((21,), i32),
+                S((rows,), i32), S((rows,), i32))
+    else:
+        f = lambda q, k, v, t, c, p: pap.paged_chunk_attention(  # noqa: E731
+            q, k, v, t, c, p, 16, layer=layer, window=window)
+        args = (S((rows, 8, n_q, hd), dtype), k, v, S((rows, width), i32),
+                S((rows,), i32), S((rows, 8), i32))
+    text = str(jax.make_jaxpr(f)(*args))
+    assert hashlib.sha256(text.encode()).hexdigest()[:16] == digest

@@ -1,5 +1,8 @@
 """Pallas ragged paged decode attention vs the XLA fallback (interpret mode)."""
 
+import functools
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -533,6 +536,80 @@ def test_which_pools_are_read_in_place():
     assert not reads_in_place(S((28, 98304, 2, 128), f8))  # heads padded
     assert not reads_in_place(  # an int8 pool: its scales are padded
         (S((28, 49152, 4, 128), jnp.int8), S((28, 49152, 4), jnp.float32)))
+
+
+# ------------------------------------------ two kv heads, where they lie
+#
+# The recurrent families' softmax layers: Qwen3-Next's 16 query heads over 2
+# kv heads of 256 (a decode row walks BY KV HEAD: the chunk walk at one
+# query a block, since a page's ``[page_size x n_kv, hd]`` view is not the
+# bytes as they lie once a head is wider than the lanes) and
+# Nemotron-3-Nano's 32 over 2 of 128 (the decode walk), beside the dense
+# cell's 28 over 4 of 128. The same poison pools: every unowned page NaN,
+# every column a row must not read at one.
+
+LAYER_WALK_SHAPES = [(16, 2, 256), (32, 2, 128), (28, 4, 128)]  # (n_q, n_kv, hd)
+_layer_walk_shapes = pytest.mark.parametrize(
+    "n_q,n_kv,hd", LAYER_WALK_SHAPES, ids=["qwen3next", "nemotron", "dense"])
+
+
+def test_which_decode_rows_walk_by_kv_head():
+    """Static, by the pool's shape: several heads, each wider than the
+    lanes. An int8 pool's pair keeps the decode walk (its scales)."""
+    from jax import ShapeDtypeStruct as S
+
+    from runbookai_tpu.ops.paged_attention_pallas import _walks_by_head
+
+    bf16 = jnp.bfloat16
+    assert _walks_by_head(S((3, 131072, 2, 256), bf16))  # Qwen3-Next
+    assert not _walks_by_head(S((6, 131072, 2, 128), bf16))  # Nemotron
+    assert not _walks_by_head(S((28, 49152, 4, 128), bf16))  # the 7B cell
+    assert not _walks_by_head(S((28, 196608, 1, 256), bf16))  # a lone head
+    assert not _walks_by_head(
+        (S((28, 49152, 4, 256), jnp.int8), S((28, 49152, 4), jnp.float32)))
+
+
+@_layer_walk_shapes
+@pytest.mark.parametrize("layer", [0, 2])
+def test_decode_walk_of_a_layer_of_the_stacked_pool(n_q, n_kv, hd, layer):
+    """As a forward's layer scan calls it: the stacked ``[L, tokens, n_kv,
+    hd]`` pool and a traced ``layer``, the other layers poison, against
+    XLA's walk over the layer's slice. Ragged contexts: free slots first,
+    among the live rows and last, a context of 1, one ending mid-page, one
+    a step of the walk + 1 and one filling the table."""
+    from runbookai_tpu.ops.paged_attention_pallas import decode_pages_per_step
+
+    g = decode_pages_per_step(WALK_PS, n_kv, hd, jnp.float32, 10**6)
+    ctx_lens = [0, 1, 0, 5 * WALK_PS + 3, g * WALK_PS + 1,
+                (2 * g + 1) * WALK_PS, 0]  # (the table is 2g + 1 wide)
+    q, k, v, tables, ctx, _, _ = _walk_case(ctx_lens, n_kv, n_q // n_kv, hd=hd)
+    got = jax.jit(lambda k, v, layer: paged_decode_attention(
+        q, k, v, tables, ctx, page_size=WALK_PS, interpret=True, layer=layer,
+        name="paged_decode_walk"))(
+            _in_layer(k, layer), _in_layer(v, layer), jnp.int32(layer))
+    want = paged_attention(q[:, None], jnp.nan_to_num(k), jnp.nan_to_num(v),
+                           tables, ctx, jnp.maximum(ctx - 1, 0)[:, None],
+                           page_size=WALK_PS, block_pages=4)[:, 0]
+    got, want = np.asarray(got), np.asarray(want)
+    live = np.asarray(ctx_lens) > 0
+    assert np.all(got[~live] == 0.0)  # a free slot writes zeros
+    np.testing.assert_allclose(got[live], want[live], rtol=2e-5, atol=2e-5)
+
+
+def test_a_walk_is_named_by_its_caller():
+    """``name=`` reaches the ``pallas_call`` (what a device trace calls the
+    kernel), by either route; None leaves the dense family's calls as they
+    were."""
+    def names(n_kv, group, hd, **kw):
+        q, k, v, tables, ctx, _, _ = _walk_case([9, 0], n_kv, group, hd=hd)
+        text = str(jax.make_jaxpr(functools.partial(
+            paged_decode_attention, page_size=WALK_PS, interpret=True, **kw))(
+                q, k, v, tables, ctx))
+        return set(re.findall(r"name=(\w+)", text))
+
+    for shape in [(2, 2, 128), (2, 8, 256)]:  # the decode walk; by kv head
+        assert "paged_decode_walk" in names(*shape, name="paged_decode_walk")
+        assert "paged_decode_walk" not in names(*shape)
 
 
 def _build_pool(rng, ctx_lens_list, n_kv, hd, ps, pages, max_pages):

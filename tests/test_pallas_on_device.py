@@ -563,14 +563,15 @@ def test_decode_kernel_at_serving_shapes(n_q, n_kv, hd):
 CELL_CTX = [430, 3, 0, 612, 5, 250, 0, 1, 8192, 7, 0, 520, 2, 0, 260, 4]
 
 
-def _poison_pool(rng, rows_ctx, n_kv=4, hd=128, num_pages=1024):
+def _poison_pool(rng, rows_ctx, n_kv=4, hd=128, num_pages=1024,
+                 width=SERVE_MAX_PAGES):
     """A bf16 pool and the rows' tables in which every page no row owns,
     and every table column past a row's live pages, is poison (NaN)."""
     live = [-(-c // PS) for c in rows_ctx]
     k = np.full((num_pages * PS, n_kv, hd), np.nan, np.float32)
     v = np.full_like(k, np.nan)
     order = rng.permutation(np.arange(1, num_pages))
-    tables = np.full((len(rows_ctx), SERVE_MAX_PAGES), order[-1], np.int32)
+    tables = np.full((len(rows_ctx), width), order[-1], np.int32)
     nxt = 0
     for i, n in enumerate(live):
         for col in range(n):
@@ -749,6 +750,51 @@ def test_ragged_walk_at_the_cells_shape():
     assert np.all(got[pads:] == 0.0)  # a pad block writes zeros
     empty = [s * RQ for s, c in enumerate(CELL_CTX) if not c]
     assert np.all(got[empty] == 0.0)  # and so does an empty slot
+
+
+# The two recurrent cells' softmax layers: 2 kv heads, the one-token rows on
+# the decode walk (a prefill run keeps XLA's). Qwen3-Next's decode dispatch
+# is 64 slots over a table 1,025 wide at 16 query heads of 256 (a row walks
+# by kv head: the chunk walk at one query a block), Nemotron-3-Nano's 48
+# over 513 at 32 heads of 128 (the decode walk). The pool is stacked, the
+# layer the last, every other layer and every page no row owns poison; most
+# slots are free, as in the cells.
+RECURRENT_CELLS = [(16, 2, 256, 64, 1025, 3), (32, 2, 128, 48, 513, 6)]
+_recurrent = pytest.mark.parametrize(
+    "n_q,n_kv,hd,slots,width,layers", RECURRENT_CELLS,
+    ids=["qwen3next", "nemotron"])
+
+
+def _in_last_layer(a, layers):
+    out = jnp.full((layers, *a.shape), jnp.nan, a.dtype)
+    return out.at[layers - 1].set(a)
+
+
+@pytest.mark.usefixtures("serving_precision")
+@_recurrent
+def test_decode_walk_at_the_recurrent_cells_shapes(n_q, n_kv, hd, slots,
+                                                   width, layers):
+    rng = np.random.default_rng(43)
+    ctx_lens = [0] * slots
+    for slot, c in zip(rng.permutation(slots),
+                       [1, PS, PS + 1, 2500, 3100, 4097, 5000, 8191]):
+        ctx_lens[slot] = min(c, (width - 1) * PS)
+    k, v, tables = _poison_pool(rng, ctx_lens, n_kv, hd, num_pages=2048,
+                                width=width)
+    ctx = jnp.asarray(ctx_lens, jnp.int32)
+    q = jnp.asarray(rng.normal(size=(slots, n_q, hd)), jnp.bfloat16)
+    got = jax.jit(lambda k, v, layer: paged_decode_attention(
+        q, k, v, tables, ctx, page_size=PS, interpret=False, layer=layer,
+        name="paged_decode_walk"))(
+            _in_last_layer(k, layers), _in_last_layer(v, layers),
+            jnp.int32(layers - 1))
+    want = paged_attention(q[:, None], jnp.nan_to_num(k), jnp.nan_to_num(v),
+                           tables, ctx, jnp.maximum(ctx - 1, 0)[:, None],
+                           page_size=PS)[:, 0]
+    got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
+    live = np.asarray(ctx_lens) > 0
+    assert np.all(got[~live] == 0.0)  # a free slot writes zeros
+    np.testing.assert_allclose(got[live], want[live], **ATTN_TOL)
 
 
 # The cell's pool where it lies: ``bf16[28, 49152, 4, 128]``, the layer a

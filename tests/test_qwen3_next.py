@@ -196,13 +196,18 @@ def test_one_full_prefill_matches_the_reference(params):
     assert all(float(jnp.abs(a[:, 2]).max()) > 0 for a in state)
 
 
-def test_chunked_prefill_then_decode_through_pool_and_state(params):
+ATTN_IMPLS = ["xla", "pallas"]  # XLA's page walk; the Pallas decode walk, interpreted
+
+
+@pytest.mark.parametrize("attn_impl", ATTN_IMPLS)
+def test_chunked_prefill_then_decode_through_pool_and_state(params, attn_impl):
     """Two rows prefilled in chunks of 32 by ``_prefill_step`` — across
     chunk and page boundaries, into slots 3 and 1 of the state pool — then
     ``_decode_step`` and the 8-step ``_decode_multi`` with the rows in
     those slots: every logit and every greedy token against ONE full pass
     of the reference. The paged pool holds keys and values of the two
     full-attention layers only, asserted from the live arrays."""
+    static = dict(STATIC, attn_impl=attn_impl)
     prompts = [_ids(70, 1), _ids(45, 2)]
     slot_of = [3, 1]
     kv_k, kv_v, state = _pools()
@@ -224,7 +229,7 @@ def test_chunked_prefill_then_decode_through_pool_and_state(params):
         out, kv_k, kv_v, experts, state = _prefill_step(
             params, CFG, jnp.asarray(tokens), kv_k, kv_v, jnp.asarray(positions),
             tables, jnp.asarray(ctx), jnp.asarray(last_idx),
-            jnp.zeros((2,), jnp.int32), state=state, state_rows=jnp.asarray(rows), **STATIC)
+            jnp.zeros((2,), jnp.int32), state=state, state_rows=jnp.asarray(rows), **static)
         assert experts.shape == (5,) and int(experts[1]) == 0  # no identity experts
         for r, p in enumerate(prompts):
             if lo < len(p) <= lo + 32:
@@ -250,7 +255,7 @@ def test_chunked_prefill_then_decode_through_pool_and_state(params):
     toks, pos, ctx = feed()
     tok, logits, kv_k, kv_v, _, experts, state = _decode_step(
         params, CFG, toks, pos, kv_k, kv_v, jnp.asarray(table4), ctx, *greedy,
-        jax.random.PRNGKey(0), None, jnp.zeros((4,), jnp.int32), state=state, **STATIC)
+        jax.random.PRNGKey(0), None, jnp.zeros((4,), jnp.int32), state=state, **static)
     for s, ids in seqs.items():
         np.testing.assert_allclose(np.asarray(logits[s]), _reference(params, ids, 1)[0],
                                    atol=ATOL, rtol=0)
@@ -258,7 +263,7 @@ def test_chunked_prefill_then_decode_through_pool_and_state(params):
     toks, pos, ctx = feed()
     window, kv_k, kv_v, _, experts, state = _decode_multi(
         params, CFG, toks, pos, kv_k, kv_v, jnp.asarray(table4), ctx, *greedy,
-        jax.random.PRNGKey(0), jnp.zeros((4,), jnp.int32), k_steps=8, state=state, **STATIC)
+        jax.random.PRNGKey(0), jnp.zeros((4,), jnp.int32), k_steps=8, state=state, **static)
     assert int(experts[:3].sum()) == 2 * 8 * CFG.num_experts_per_tok * CFG.num_hidden_layers
     for s, ids in seqs.items():
         full = ids + [int(t) for t in window[s]]
@@ -268,14 +273,50 @@ def test_chunked_prefill_then_decode_through_pool_and_state(params):
     assert all(float(jnp.abs(a[:, [0, 2]]).max()) == 0 for a in state)  # free slots untouched
 
 
-@pytest.mark.parametrize("mixed", [False, True], ids=["split", "mixed"])
-def test_the_engine_serves_the_references_tokens(params, mixed):
+def test_which_rows_take_the_pallas_walk():
+    """Static, by shape: one token a row under ``attn_impl="pallas"``, and a
+    kv-head axis on Mosaic's tile. A prefill run and a pool of two fp8 heads
+    (the benchmark's served fp8 control) keep XLA's walk."""
+    S = jax.ShapeDtypeStruct
+    q1, q8 = S((4, 1, 16, 256), jnp.bfloat16), S((4, 8, 16, 256), jnp.bfloat16)
+    pool, fp8 = (S((3, 1024, 2, 256), dt) for dt in (jnp.bfloat16, jnp.float8_e4m3fn))
+    assert qwen3_next.pallas_walks(q1, pool, "pallas")
+    assert not qwen3_next.pallas_walks(q1, pool, "xla")
+    assert not qwen3_next.pallas_walks(q8, pool, "pallas")
+    assert not qwen3_next.pallas_walks(q1, fp8, "pallas")
+
+
+def test_the_pallas_walk_gives_xlas_decode_pass_and_mixed_step(params):
+    """``attn_impl="pallas"`` against ``"xla"``, the forwards called as the
+    step programs call them: a decode pass with free slots among the live
+    ones, and a mixed step with decode rows beside one filled and one
+    unfilled prefill row (``tests/recurrent_walks.py``)."""
+    import recurrent_walks
+
+    recurrent_walks.check_decode_pass_and_mixed_step(
+        qwen3_next, CFG, params, _pools(), _ids, ATOL)
+
+
+@pytest.mark.parametrize("mixed, attn_impl", [
+    (False, "xla"), (True, "xla"), (True, "pallas")],
+    ids=["split", "mixed", "mixed-pallas"])
+def test_the_engine_serves_the_references_tokens(params, mixed, attn_impl,
+                                                 monkeypatch):
     """Through ``EngineCore`` — admission into a slot of the state pool,
     chunked prefill from and into it, the mixed (ragged) dispatch with
     decode rows beside prefill chunks or the split one, ``_decode_multi``'s
     windows: every served token is the reference's best within ``ATOL``.
     Requests arrive a step apart so that chunks meet decoding rows."""
-    core = _engine(params, mixed_dispatch=mixed)
+    # The family calls the decode walk alone: no chunk or ragged kernel is
+    # probed for it (``pallas_prefill``).
+    from runbookai_tpu.engine import engine
+    from runbookai_tpu.ops import paged_attention_pallas
+
+    monkeypatch.setattr(engine, "_probe_pallas_ragged", None)
+    monkeypatch.setattr(paged_attention_pallas, "paged_chunk_attention", None)
+    engine._probe_pallas_attn_cached.cache_clear()
+    core = _engine(params, mixed_dispatch=mixed, attn_impl=attn_impl)
+    assert core.ecfg.attn_impl == attn_impl  # the family keeps what was asked
     reqs = [_request(f"r{i}", _ids(n, 3 + i), max_new=14 + 3 * i)
             for i, n in enumerate((150, 40, 200, 97, 64, 130))]
     for r in reqs:
@@ -290,6 +331,9 @@ def test_the_engine_serves_the_references_tokens(params, mixed):
     assert m["state_snapshots_taken"] > 0 and m["state_hash_tokens_matched"] == 0
     recs = core.flight.snapshot()
     assert all("state" in s for s in recs)
+    # what the decode walk reads: the pages the dispatch's rows held
+    assert all(s["kv_pages_live"] > 0 for s in recs
+               if s["program"] in ("_decode_multi", "_mixed_step"))
     assert sum(s["state"]["snapshots_taken"] for s in recs) == m["state_snapshots_taken"]
     assert max(s["state"]["slots_live"] for s in recs) == 4
 
