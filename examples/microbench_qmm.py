@@ -29,9 +29,9 @@ import time
 import jax
 import jax.numpy as jnp
 
-from runbookai_tpu.models.llama import qmm
 from runbookai_tpu.models.quant import quantize_tensor
 from runbookai_tpu.ops import qmm_pallas
+from runbookai_tpu.ops.dense import qmm
 
 LAYERS = 28
 # Qwen2.5-7B's layer matrices (K, N): the dense benchmark cell.

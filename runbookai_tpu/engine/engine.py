@@ -247,10 +247,13 @@ def resolve_kv_dtype(name: Optional[str], default: Any) -> Any:
 
 
 # The step programs take their forward from the configuration
-# (``cfg.forwards()``: models/llama.py, models/longcat.py), never from a model
-# module by name, and each returns, last, the forward's expert counts: four
-# integers for a model with an expert share (models/longcat.py
-# ``EXPERT_COUNTS``), None — no output at all — for any other.
+# (``cfg.forwards()``: ``models/family.py`` ``Family`` is all the engine reads
+# of a model), never from a model module by name. Every family's forward has
+# ONE signature and returns ``(logits, kv_k, kv_v, experts, state, hidden)``:
+# the expert counts of a model with an expert share (five integers,
+# ``family.EXPERT_COUNTS``), the state pool of a model with recurrent layers,
+# the trunk's last hidden state of a model that drafts for itself — and None,
+# no output at all, for any other.
 #
 # A model with recurrent layers (``cfg.state_pool_spec``: models/
 # qwen3_next.py) has a second pool beside the pages, indexed by batch slot:
@@ -259,13 +262,6 @@ def resolve_kv_dtype(name: Optional[str], default: Any) -> Any:
 # not its own slot — and handed back as one more result, last. For every
 # other model both are None: no operand goes in, no result comes out
 # (``_with_state``), and the program compiles to what it was.
-
-
-def _run_forward(forward, state, state_rows, *args, **kw):
-    """A family's forward as ``(logits, kv_k, kv_v, experts, state')``."""
-    if state is None:
-        return (*forward(*args, **kw), None)
-    return forward(*args, state=state, state_rows=state_rows, **kw)
 
 
 def _with_state(results: tuple, state) -> tuple:
@@ -284,15 +280,6 @@ def _with_state(results: tuple, state) -> tuple:
 # with ``n`` committed tokens has trunk rows and module rows ``0 .. n - 2``
 # and a draft of token ``n``. For every other model, and with speculation
 # off, ``self_draft`` is False: no operand, no result, the same program.
-
-
-def _trunk(forward, self_draft: bool, state, state_rows, *args, **kw):
-    """A family's forward as ``(logits, kv_k, kv_v, experts, state',
-    hidden)``: the trunk's last hidden state where the module needs it."""
-    if self_draft:
-        logits, kv_k, kv_v, experts, hidden = forward(*args, hidden_out=True, **kw)
-        return logits, kv_k, kv_v, experts, state, hidden
-    return (*_run_forward(forward, state, state_rows, *args, **kw), None)
 
 
 def _with_drafts(results: tuple, drafts) -> tuple:
@@ -335,11 +322,10 @@ def _decode_step(
     state=None, self_draft: bool = False,
 ):
     forward, _ = cfg.forwards()
-    logits, kv_k, kv_v, experts, state, hidden = _trunk(
-        forward, self_draft, state, None,
+    logits, kv_k, kv_v, experts, state, hidden = forward(
         params, cfg, tokens, positions, kv_k, kv_v, tables, ctx_lens,
         page_size=page_size, block_pages=block_pages, attn_impl=attn_impl,
-        mesh=mesh, adapter_ids=adapter_ids, qmm_impl=qmm_impl,
+        mesh=mesh, adapter_ids=adapter_ids, qmm_impl=qmm_impl, state=state,
     )
     tok = sample_tokens(logits[:, -1], key, temps, top_ps, mask, top_ks,
                         counts=counts, presence=pres, frequency=freq,
@@ -389,11 +375,10 @@ def _decode_multi(
 
     def step(carry, _):
         tokens, positions, kv_k, kv_v, ctx_lens, key, counts, state, y = carry
-        logits, kv_k, kv_v, experts, state, hidden = _trunk(
-            forward, self_draft, state, None,
+        logits, kv_k, kv_v, experts, state, hidden = forward(
             params, cfg, tokens, positions, kv_k, kv_v, tables, ctx_lens,
             page_size=page_size, block_pages=block_pages, attn_impl=attn_impl,
-            mesh=mesh, adapter_ids=adapter_ids, qmm_impl=qmm_impl,
+            mesh=mesh, adapter_ids=adapter_ids, qmm_impl=qmm_impl, state=state,
         )
         key, sub = jax.random.split(key)
         tok = sample_tokens(logits[:, -1], sub, temps, top_ps, None, top_ks,
@@ -468,11 +453,11 @@ def _decode_spec(
             fed = jnp.stack([last, draft], axis=1)
             at = jnp.stack([pos, pos + 1], axis=1)
             ctx = jnp.where(live, pos + 2, 0)
-            logits, kv_k, kv_v, experts, hidden = forward(
+            logits, kv_k, kv_v, experts, _, hidden = forward(
                 params, cfg, fed, at, kv_k, kv_v, tables, ctx,
                 page_size=page_size, block_pages=block_pages,
                 attn_impl=attn_impl, mesh=mesh, adapter_ids=adapter_ids,
-                qmm_impl=qmm_impl, hidden_out=True)
+                qmm_impl=qmm_impl)
             a = jnp.argmax(logits, axis=-1).astype(jnp.int32)  # [B, 2]
             accepted = (draft == a[:, 0]).astype(jnp.int32)
             y, kv_k, kv_v, module_experts = module_pass(
@@ -492,7 +477,7 @@ def _decode_spec(
         out = jnp.concatenate([toks, accepted[..., None]], axis=-1)
         return (out.transpose(1, 0, 2), kv_k, kv_v, jnp.sum(experts, axis=0),
                 last, draft)
-    logits, kv_k, kv_v, experts = forward(
+    logits, kv_k, kv_v, experts, _, _ = forward(
         params, cfg, tokens, positions, kv_k, kv_v, tables, ctx_lens,
         page_size=page_size, block_pages=block_pages, attn_impl=attn_impl,
         mesh=mesh, adapter_ids=adapter_ids, qmm_impl=qmm_impl,
@@ -533,20 +518,21 @@ def _prefill_step(
     ``last_idx`` comes back last: where that position ends a prompt, its
     next token is not known yet and :func:`_module_step` writes its row."""
     forward, _ = cfg.forwards()
-    logits, kv_k, kv_v, experts, state, hidden = _trunk(
-        forward, next_tokens is not None, state, state_rows,
+    logits, kv_k, kv_v, experts, state, hidden = forward(
         params, cfg, tokens, positions, kv_k, kv_v, tables, ctx_lens,
         page_size=page_size, block_pages=block_pages, attn_impl=attn_impl,
-        mesh=mesh, adapter_ids=adapter_ids, qmm_impl=qmm_impl,
+        mesh=mesh, adapter_ids=adapter_ids, qmm_impl=qmm_impl, state=state,
+        state_rows=state_rows,
     )
     rows = jnp.arange(logits.shape[0])
+    last_hidden = None
     if next_tokens is not None:
         _, kv_k, kv_v, module_experts = cfg.drafter()[0](
             params, cfg, hidden, next_tokens, positions, kv_k, kv_v, tables,
             ctx_lens, page_size, block_pages)
-        experts, hidden = experts + module_experts, hidden[rows, last_idx]
+        experts, last_hidden = experts + module_experts, hidden[rows, last_idx]
     return _with_state(_with_drafts(
-        (logits[rows, last_idx], kv_k, kv_v, experts), hidden), state)
+        (logits[rows, last_idx], kv_k, kv_v, experts), last_hidden), state)
 
 
 # Row-run alignment of the mixed ragged token buffer: every row's token run
@@ -604,12 +590,12 @@ def _mixed_step(
     tokens = tokens.at[dec_idx].set(feed_toks)
     sel_idx = jnp.concatenate([dec_idx, pf_last_idx])
     _, forward_ragged = cfg.forwards()
-    logits, kv_k, kv_v, experts, state, hidden = _trunk(
-        forward_ragged, next_tokens is not None, state, state_rows,
+    logits, kv_k, kv_v, experts, state, hidden = forward_ragged(
         params, cfg, tokens, positions, row_ids, kv_k, kv_v, tables,
         ctx_lens, sel_idx, page_size=page_size, block_pages=block_pages,
         attn_impl=attn_impl, mesh=mesh, adapter_ids=adapter_rows,
-        qmm_impl=qmm_impl, ragged_block=ragged_block,
+        qmm_impl=qmm_impl, ragged_block=ragged_block, state=state,
+        state_rows=state_rows,
     )
     dec_logits, pf_logits = logits[:b], logits[b:]
     key_dec, key_pf = jax.random.split(key)
@@ -723,14 +709,6 @@ def _shard_heads(model_cfg, mesh) -> tuple[int, int]:
     return model_cfg.n_kv_heads // kv_sh, model_cfg.n_heads // kv_sh
 
 
-def _pallas_prefill(model_cfg) -> bool:
-    """Whether the family's prefill rows (a chunk, a mixed step's runs) call
-    a Pallas kernel under ``attn_impl="pallas"``, as its decode rows do. The
-    recurrent families' do not (``pallas_prefill = False``: XLA's one-row
-    walk is ahead there), so no chunk or ragged kernel is probed for them."""
-    return getattr(model_cfg, "pallas_prefill", True)
-
-
 def _probe_pallas_attn(model_cfg, ecfg, act_dtype, mesh=None) -> None:
     from runbookai_tpu.ops.paged_attention_pallas import chunk_q_block
     from runbookai_tpu.parallel.mesh import SEQ_AXIS
@@ -738,7 +716,7 @@ def _probe_pallas_attn(model_cfg, ecfg, act_dtype, mesh=None) -> None:
     kv_split = mesh is not None and mesh.shape.get(SEQ_AXIS, 1) > 1
     n_kv, n_q = _shard_heads(model_cfg, mesh)
     chunk_t = (chunk_q_block(ecfg.prefill_chunk, n_q)
-               if _pallas_prefill(model_cfg) else 0)
+               if model_cfg.pallas_prefill else 0)
     _probe_pallas_attn_cached(jax.default_backend(), n_kv, n_q,
                               model_cfg.head_dim, ecfg.page_size,
                               jnp.dtype(ecfg.kv_dtype).name,
@@ -1156,7 +1134,7 @@ class EngineCore:
             raise ValueError(
                 f"model {model_cfg.name!r} (family {model_cfg.family!r}) "
                 f"does not support: {'; '.join(refused)}")
-        rows = getattr(model_cfg, "max_prefill_rows", None)
+        rows = model_cfg.max_prefill_rows
         if rows is not None and rows < self.ecfg.prefill_batch:
             # The configuration bounds the prefill rows of a dispatch (one
             # compiled width where its prompts prefill alone for seconds).
@@ -1205,7 +1183,7 @@ class EngineCore:
         if mixed and _kv_split_mesh:
             mixed = False
         if (mixed and self.ecfg.attn_impl == "pallas" and not _kv_int8
-                and _pallas_prefill(model_cfg)):
+                and model_cfg.pallas_prefill):
             _probe_pallas_ragged(model_cfg, self.ecfg, act_dtype,
                                  mesh=mesh)
         self._mixed = bool(mixed)
@@ -1237,16 +1215,15 @@ class EngineCore:
         # A model with a prediction module of its own is its own drafter
         # (models/joyai.py): chosen by the model, with speculation on; no
         # option selects it and prompt lookup is not its fallback.
-        self._mtp = bool(getattr(model_cfg, "self_draft", False)
-                         and self.ecfg.speculative)
+        self._mtp = bool(model_cfg.self_draft and self.ecfg.speculative)
         (pool_layers, pool_heads, pool_dim), v_side = model_cfg.kv_pool_spec
         # Recurrent layers keep their state a SLOT, not a token: a second
         # pool beside the pages, and a pool of snapshots behind prefix hits.
-        state_spec = getattr(model_cfg, "state_pool_spec", None)
+        state_spec = model_cfg.state_pool_spec
         # Window layers keep the last ``window`` positions only: a second
         # group of the pool, with its own pages (``cfg.kv_window_spec``:
         # the layers in it and the window; engine/kv_cache.py WindowSpec).
-        window_spec = getattr(model_cfg, "kv_window_spec", None)
+        window_spec = model_cfg.kv_window_spec
         self.kv = KVCacheManager(
             n_layers=pool_layers,
             num_pages=self.ecfg.num_pages,
@@ -1931,7 +1908,7 @@ class EngineCore:
         return np.asarray(toks)
 
     def _note_experts(self, program: str, passes: int, counts) -> None:
-        """Book one dispatch's expert counts (models/longcat.py
+        """Book one dispatch's expert counts (models/family.py
         ``EXPERT_COUNTS``): the engine's totals, and the record of the step
         that fetched them."""
         held, zero, absent, touched, overflow = (int(c) for c in counts)

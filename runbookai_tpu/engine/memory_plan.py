@@ -133,27 +133,13 @@ def plan_serving(
     # The pool's layout is the configuration's own (two K/V sides of n_kv
     # heads a layer, or a latent and a rotated key a sublayer).
     sides = cfg.kv_pool_spec  # (layers, heads, values a head), K and V
-    if hasattr(cfg, "ffn_dim"):  # the llama family: laid out across chips
-        plan = plan_kv_split(cfg, tp)
-        layer_matmul = cfg.matmul_params - cfg.dim * cfg.vocab_size
-        wkv = cfg.n_layers * 2 * cfg.dim * cfg.n_kv_heads * cfg.head_dim
-        emb_head = 2 * cfg.vocab_size * cfg.dim  # embed + lm head (or tied x2)
-        if weights == "int8":
-            # wk/wv shard kv_shards-way only; everything else full-tp.
-            per_chip = ((layer_matmul - wkv) / max(tp, 1)
-                        + wkv / max(plan.kv_shards, 1)
-                        + layer_matmul / cfg.dim * 4 / max(tp, 1)  # scales
-                        + emb_head * 2 / max(tp, 1))  # bf16
-        else:
-            per_chip = ((layer_matmul - wkv) * 2 / max(tp, 1)
-                        + wkv * 2 / max(plan.kv_shards, 1)
-                        + emb_head * 2 / max(tp, 1))
-        per_chip += (cfg.n_layers * 2 + 1) * cfg.dim * 4  # norms, replicated
-    else:
-        # A family with no layout across chips yet (the engine refuses a
-        # model axis for it): every chip holds the whole share, as stored.
-        plan = KVSplitPlan(tp=tp, kv_shards=1, pg_shards=1)
-        per_chip = cfg.total_params * 2
+    # How the pool splits over ``tp`` chips; a family with no layout across
+    # chips yet (``one_path``: the engine refuses a model axis for it) keeps
+    # the whole pool on every chip. The weight bytes a chip holds are the
+    # family's to state.
+    plan = (KVSplitPlan(tp=tp, kv_shards=1, pg_shards=1) if cfg.one_path
+            else plan_kv_split(cfg, tp))
+    per_chip = cfg.weight_bytes_per_chip(tp, weights, plan.kv_shards)
 
     spill_token = sum(layers * heads * (dim * kv_dtype_bytes + kv_scale_bytes)
                       for layers, heads, dim in sides)
@@ -161,14 +147,14 @@ def plan_serving(
     # Recurrent layers' state (the configuration says which arrays, in
     # which precision): a batch slot and a snapshot.
     state_bytes = 0
-    if getattr(cfg, "state_pool_spec", None):
+    if cfg.state_pool_spec:
         slot = sum(math.prod(shape) * np.dtype(dtype).itemsize
                    for shape, dtype in cfg.state_pool_spec)
         state_bytes = (batch + cfg.state_snapshots) * slot
     # Window layers' rows do not grow with the context: a pool of their
     # own, a slot at ``window + prefill_chunk + 2 pages`` rows.
     window_bytes = 0
-    if getattr(cfg, "kv_window_spec", None):
+    if cfg.kv_window_spec:
         from runbookai_tpu.engine.kv_cache import WindowSpec
 
         layers, window = cfg.kv_window_spec

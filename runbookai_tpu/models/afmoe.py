@@ -48,6 +48,7 @@ carry and are written in place.
 
 from __future__ import annotations
 
+import dataclasses
 import math
 from dataclasses import dataclass
 from typing import Any, Optional
@@ -55,8 +56,16 @@ from typing import Any, Optional
 import jax
 import jax.numpy as jnp
 
-from runbookai_tpu.models.longcat import EXPERT_COUNTS, _stacked_normal
+from runbookai_tpu.models.family import (
+    EXPERT_COUNTS,
+    Family,
+    Params,
+    _stacked_normal,
+    register,
+    serving_forwards,
+)
 from runbookai_tpu.ops.attention import paged_attention, write_kv_pages_batch
+from runbookai_tpu.ops.dense import qmm, rms_norm
 from runbookai_tpu.ops.moe import (
     held_capacity,
     held_expert_ffn,
@@ -65,7 +74,6 @@ from runbookai_tpu.ops.moe import (
 )
 from runbookai_tpu.ops.rope import apply_rope
 
-Params = dict[str, Any]
 SLIDING, FULL = "sliding_attention", "full_attention"
 
 
@@ -83,7 +91,7 @@ class LayerTypes(tuple):
 
 
 @dataclass(frozen=True)
-class AfmoeConfig:
+class AfmoeConfig(Family):
     name: str
     vocab_size: int
     hidden_size: int
@@ -129,12 +137,16 @@ class AfmoeConfig:
     router_bias_scale: float = 1e-3
     family: str = "qwen2"  # the chat template: ChatML (assumed)
 
-    tie_embeddings = False
-    # 4 or 8 KV heads of 128 in bf16: the dense family's pool shape, so the
-    # engine's Pallas attention kernels read this family's pages.
-    pallas_attention = True
-    # No layer keeps state that is not token rows in pages.
-    state_pool_spec = None
+    # (4 or 8 KV heads of 128 in bf16: the dense family's pool shape, so the
+    # engine's Pallas attention kernels read this family's pages.)
+    # (The host spill tier and page export between replicas are refused
+    # where they are asked for: ``engine/kv_cache.py``.)
+    one_path = True
+    no_prompt_lookup = ("prompt-lookup speculation (a verify chunk over a "
+                        "window has not been proven)")
+    no_draft_model = "draft-model speculation"
+    family_name = "afmoe"
+    hf_model_types = ("afmoe",)
     # The most sequences whose prefill chunks share one dispatch. A long
     # prompt prefills alone for seconds (16k tokens: 32 chunks), so a second
     # arrival joins it while nothing decodes, and ``_prefill_step`` would
@@ -214,35 +226,18 @@ class AfmoeConfig:
         return self.n_kind(SLIDING), self.sliding_window
 
     def forwards(self):
-        """(forward, ragged forward) as the engine's step programs call
-        them, returning ``(logits, kv_k, kv_v, expert counts)``."""
         return forward_counted, forward_ragged_counted
 
-    def unsupported(self, *, lora: bool, model_axis: int, seq_axis: int,
-                    kv_dtype, quantized: bool, speculative: bool = False,
-                    draft: bool = False) -> list[str]:
-        """What this family's forward does not do yet, of what the engine
-        was asked for — refused by name at engine init, never served
-        wrong. (The host spill tier and page export between replicas are
-        refused where they are asked for: ``engine/kv_cache.py``.)"""
-        no = []
-        if speculative:
-            no.append("prompt-lookup speculation (a verify chunk over a "
-                      "window has not been proven)")
-        if draft:
-            no.append("draft-model speculation")
-        if lora:
-            no.append("LoRA adapters")
-        if model_axis > 1:
-            no.append(f"a model axis of {model_axis} (tensor/expert "
-                      f"parallelism across chips)")
-        if seq_axis > 1:
-            no.append("the KV page-split (seq) mesh axis")
-        if jnp.dtype(kv_dtype) == jnp.int8:
-            no.append("an int8 KV pool (per-token scales)")
-        if quantized:
-            no.append("int8 weight-only matrices")
-        return no
+    def init_params(self, key, dtype=jnp.bfloat16, quantized=False) -> Params:
+        return init_params(key, self, dtype)
+
+    @classmethod
+    def from_hf(cls, raw: dict, name: str) -> "AfmoeConfig":
+        """Every key of ``config.json`` that is a field of the dataclass,
+        every expert held."""
+        fields = {f.name for f in dataclasses.fields(cls)} - {"name", "family"}
+        return cls(name=name, n_experts_held=raw["num_experts"],
+                   **{k: v for k, v in raw.items() if k in fields})
 
     # ---- counts (the memory plan's and the MFU model's) ----------------
 
@@ -295,7 +290,7 @@ _PUBLISHED = dict(
     layer_types=_pattern(32, 4), sliding_window=2048,
     global_attn_every_n_layers=4, route_scale=2.826)
 
-CONFIGS: dict[str, AfmoeConfig] = {
+CONFIGS: dict[str, AfmoeConfig] = register({
     # The published model (config.json): every expert held. 26B
     # parameters: no single process of this repo holds it; it is the entry
     # a cut configuration is checked against.
@@ -318,7 +313,7 @@ CONFIGS: dict[str, AfmoeConfig] = {
         num_dense_layers=2, layer_types=_pattern(12, 4), sliding_window=32,
         global_attn_every_n_layers=4, route_scale=2.826, n_experts_held=8,
         first_expert=8, max_position_embeddings=8192, router_bias_scale=2e-2),
-}
+})
 
 
 def leaf_shapes(cfg: AfmoeConfig) -> dict[str, tuple[tuple[int, ...], int]]:
@@ -386,7 +381,7 @@ def moe_block(u: jnp.ndarray, live: jnp.ndarray, w: dict, e, cfg: AfmoeConfig,
               ) -> tuple[jnp.ndarray, jnp.ndarray]:
     """``MoE(u)`` of this share for ``u`` [N, D] in expert layer ``e`` (a
     traced scalar, or a number) of the stacked leaves ``w``, and its counts
-    (``longcat.EXPERT_COUNTS``; ``zero`` always 0) over the tokens ``live``
+    (``family.EXPERT_COUNTS``; ``zero`` always 0) over the tokens ``live``
     [N]."""
     n = u.shape[0]
     held_n = cfg.n_experts_held
@@ -444,8 +439,6 @@ def _layer(w, cfg: AfmoeConfig, hidden, live, l, kind: str, gi, ffn, positions,
     ("experts", e). Returns (hidden', kv_k', kv_v', expert counts). Every
     leaf is indexed where it is used, out of the stacked array, so a product
     reads its slice in place."""
-    from runbookai_tpu.models.llama import qmm, rms_norm  # deferred: cycle
-
     b, t, d = hidden.shape
     eps, hd = cfg.rms_norm_eps, cfg.head_dim
     group = "window" if kind == SLIDING else "full"
@@ -537,51 +530,14 @@ def _forward_hidden(params, cfg, tokens, positions, kv_k, kv_v, page_tables,
     return carry
 
 
-def _head(params, cfg, hidden):
-    from runbookai_tpu.models.llama import rms_norm
-
-    return (rms_norm(hidden, params["final_norm"], cfg.rms_norm_eps)
-            @ params["lm_head"]).astype(jnp.float32)
-
-
-def forward_counted(params, cfg, tokens, positions, kv_k, kv_v, page_tables,
-                    ctx_lens, page_size, block_pages=32, attn_impl="xla",
-                    mesh=None, adapter_ids=None, qmm_impl="xla"):
-    """One forward chunk ``[B, T]`` (decode: T = 1; a prefill chunk a row):
-    (logits [B, T, vocab] f32, kv_k', kv_v', expert counts)."""
-    del mesh, adapter_ids, qmm_impl  # one path; the engine refuses the rest
-    h, kv_k, kv_v, counts = _forward_hidden(
-        params, cfg, tokens, positions, kv_k, kv_v, page_tables, ctx_lens,
-        page_size, block_pages, attn_impl)
-    return _head(params, cfg, h), kv_k, kv_v, counts
+# The step programs' pair (``AfmoeConfig.forwards``). The mixed step runs
+# the whole stack as ``[N / ragged_block, ragged_block]`` with per-block
+# gathered tables (``family.serving_forwards``), a block of queries its row's
+# window.
+forward_counted, forward_ragged_counted = serving_forwards(_forward_hidden)
 
 
-def forward_ragged_counted(params, cfg, tokens, positions, row_ids, kv_k, kv_v,
-                           page_tables, ctx_lens, sel_idx, page_size,
-                           block_pages=32, attn_impl="xla", mesh=None,
-                           adapter_ids=None, qmm_impl="xla", ragged_block=8):
-    """The mixed prefill+decode forward over one flat ragged batch,
-    llama.py's layout and transform: the whole stack as ``[N / ragged_block,
-    ragged_block]`` with per-block gathered tables, a block of queries its
-    row's window. (logits [S, vocab] f32 of ``sel_idx``, kv_k', kv_v',
-    expert counts)."""
-    del mesh, adapter_ids, qmm_impl
-    n = tokens.shape[0]
-    nb = n // ragged_block
-    block_rows = row_ids.reshape(nb, ragged_block)[:, 0]
-    h, kv_k, kv_v, counts = _forward_hidden(
-        params, cfg, tokens.reshape(nb, ragged_block),
-        positions.reshape(nb, ragged_block), kv_k, kv_v, page_tables[block_rows],
-        ctx_lens[block_rows], page_size, block_pages, attn_impl)
-    h_sel = h.reshape(n, h.shape[-1])[sel_idx]
-    return _head(params, cfg, h_sel), kv_k, kv_v, counts
-
-
-def forward_impl(params: Params, cfg: AfmoeConfig, tokens, positions, kv_k,
-                 kv_v, page_tables, ctx_lens, page_size: int,
-                 block_pages: int = 32, attn_impl: str = "xla", mesh=None,
-                 adapter_ids: Optional[jnp.ndarray] = None,
-                 qmm_impl: str = "xla"):
-    """:func:`forward_counted` without the counts: (logits, kv_k', kv_v')."""
-    return forward_counted(params, cfg, tokens, positions, kv_k, kv_v, page_tables,
-                           ctx_lens, page_size, block_pages, attn_impl)[:3]
+def forward_impl(params: Params, cfg: AfmoeConfig, *chunk, **kw):
+    """One forward chunk ``[B, T]`` (decode: T = 1; a prefill chunk a row),
+    the serving signature: (logits [B, T, vocab] f32, kv_k', kv_v')."""
+    return forward_counted(params, cfg, *chunk, **kw)[:3]

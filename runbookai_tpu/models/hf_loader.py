@@ -19,12 +19,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from runbookai_tpu.models.llama import (
-    CONFIGS,
-    LlamaConfig,
-    init_params,
-    init_params_quantized,
-)
+from runbookai_tpu.models.family import CONFIGS, FAMILIES, Family
 
 # Our layer-stacked param leaf -> (HF template, transpose?)
 _LAYER_MAP = {
@@ -47,14 +42,15 @@ _BIAS_MAP = {
 }
 
 
-# HF model_type values this loader serves. The first four share the Llama
-# block (pre-norm GQA attention + SwiGLU); qwen2 adds q/k/v projection
-# biases, mixtral swaps the dense FFN for an 8-expert top-2 MoE. A window is
-# served as a window where the family declares one (``afmoe``:
-# models/afmoe.py, its sliding layers over a pool of their own); the Llama
-# block declares none, so a Mistral sliding-window checkpoint loads and is
-# served with full attention, exact for contexts up to the window.
-SUPPORTED_MODEL_TYPES = ("llama", "qwen2", "mistral", "mixtral", "afmoe")
+# Which family a checkpoint's ``model_type`` belongs to is the families' to
+# say (``Family.hf_model_types``; :func:`supported_model_types` lists those
+# with a loader here). Four share the Llama block (pre-norm GQA attention +
+# SwiGLU); qwen2 adds q/k/v projection biases, mixtral swaps the dense FFN for
+# an 8-expert top-2 MoE. A window is served as a window where the family
+# declares one (``afmoe``: models/afmoe.py, its sliding layers over a pool of
+# their own); the Llama block declares none, so a Mistral sliding-window
+# checkpoint loads and is served with full attention, exact for contexts up to
+# the window.
 
 # afmoe tensor names (``modeling_afmoe.py``): leaf -> (template, transpose).
 # Attention and the four norms a layer; the dense FFN of the leading layers;
@@ -73,18 +69,6 @@ _AFMOE_LAYER_MAP = {
     "norm4": ("post_mlp_layernorm.weight", False),
 }
 _AFMOE_FFN = (("gate", "gate_proj"), ("up", "up_proj"), ("down", "down_proj"))
-
-
-def afmoe_config_from_hf(raw: dict, name: str):
-    """The program's configuration of an ``afmoe`` ``config.json``: every
-    key that is a field of the dataclass, every expert held."""
-    import dataclasses
-
-    from runbookai_tpu.models.afmoe import AfmoeConfig
-
-    fields = {f.name for f in dataclasses.fields(AfmoeConfig)} - {"name", "family"}
-    return AfmoeConfig(name=name, n_experts_held=raw["num_experts"],
-                       **{k: v for k, v in raw.items() if k in fields})
 
 
 def load_afmoe_params(model_dir: str | Path, cfg, dtype=jnp.bfloat16):
@@ -122,73 +106,26 @@ def load_afmoe_params(model_dir: str | Path, cfg, dtype=jnp.bfloat16):
                  "lm_head": _put(idx.get("lm_head.weight").T, dtype)}
 
 
-def config_from_hf(model_dir: str | Path, name: str = "hf-model") -> LlamaConfig:
+def supported_model_types() -> tuple[str, ...]:
+    """The ``model_type``s of the families with a loader here."""
+    return tuple(t for fam in FAMILIES if not fam.checkpoint_tensors
+                 for t in fam.hf_model_types)
+
+
+# The loaders of the families whose leaves are not the Llama block's
+# (:func:`load_params` holds that one), by ``Family.family_name``.
+_OWN_LEAVES = {"afmoe": load_afmoe_params}
+
+
+def config_from_hf(model_dir: str | Path, name: str = "hf-model") -> Family:
     raw = json.loads((Path(model_dir) / "config.json").read_text())
     model_type = raw.get("model_type", "llama")
-    if model_type.startswith("longcat"):
-        raise NotImplementedError(
-            f"model_type {model_type!r}: the longcat family runs on seeded "
-            f"random weights only (models/longcat.py); no checkpoint loader yet")
-    if model_type.startswith("qwen3_next"):
-        raise NotImplementedError(
-            f"model_type {model_type!r}: the qwen3-next family runs on seeded "
-            f"random weights only (models/qwen3_next.py); no checkpoint loader yet")
-    if model_type.startswith("joyai"):
-        raise NotImplementedError(
-            f"model_type {model_type!r}: the joyai family runs on seeded "
-            f"random weights only (models/joyai.py); no checkpoint loader yet")
-    if model_type.startswith("nemotron_h"):
-        raise NotImplementedError(
-            f"model_type {model_type!r}: the nemotron-h family runs on seeded "
-            f"random weights only (models/nemotron_h.py); no checkpoint loader yet")
-    if model_type not in SUPPORTED_MODEL_TYPES:
-        raise ValueError(
-            f"model_type {model_type!r} not supported; known: "
-            f"{SUPPORTED_MODEL_TYPES}")
-    if model_type == "afmoe":
-        return afmoe_config_from_hf(raw, name)
-    # Llama-3.1-style long-context rope scaling (rope_type "llama3").
-    # Other scaling schemes (linear/dynamic/yarn) would silently produce
-    # wrong logits past the original context if dropped — refuse loudly,
-    # matching the unsupported-model_type behavior.
-    rs = raw.get("rope_scaling") or {}
-    rope_scaling = None
-    rs_type = rs.get("rope_type", rs.get("type"))
-    if rs_type == "llama3":
-        rope_scaling = (
-            float(rs["factor"]),
-            float(rs.get("low_freq_factor", 1.0)),
-            float(rs.get("high_freq_factor", 4.0)),
-            int(rs.get("original_max_position_embeddings", 8192)),
-        )
-    elif rs_type not in (None, "default"):
-        raise ValueError(
-            f"rope_scaling type {rs_type!r} not supported (only 'llama3'); "
-            f"loading without it would silently change long-context numerics")
-    return LlamaConfig(
-        name=name,
-        vocab_size=raw["vocab_size"],
-        dim=raw["hidden_size"],
-        n_layers=raw["num_hidden_layers"],
-        n_heads=raw["num_attention_heads"],
-        n_kv_heads=raw.get("num_key_value_heads", raw["num_attention_heads"]),
-        ffn_dim=raw["intermediate_size"],
-        rope_theta=raw.get("rope_theta", 500_000.0),
-        rope_scaling=rope_scaling,
-        norm_eps=raw.get("rms_norm_eps", 1e-5),
-        # The Llama block declares no window (a family that does serves it
-        # as one: models/afmoe.py): its sliding-window checkpoints (Mistral
-        # v0.1) are served with full attention — exact only up to the window,
-        # so the window clamps the serveable context rather than silently
-        # changing semantics past it.
-        max_seq_len=min(raw.get("max_position_embeddings", 8192),
-                        raw.get("sliding_window") or 1 << 30),
-        tie_embeddings=raw.get("tie_word_embeddings", False),
-        qkv_bias=model_type == "qwen2",
-        family=model_type,
-        n_experts=raw.get("num_local_experts", 0) if model_type == "mixtral" else 0,
-        top_k_experts=raw.get("num_experts_per_tok", 2),
-    )
+    for fam in FAMILIES:
+        if fam.claims(model_type):
+            return fam.from_hf(raw, name)
+    raise ValueError(
+        f"model_type {model_type!r} not supported; known: "
+        f"{supported_model_types()}")
 
 
 class _ShardIndex:
@@ -233,11 +170,11 @@ def _put(arr: np.ndarray, dtype, sharding=None) -> jax.Array:
 
 def load_params(
     model_dir: str | Path,
-    cfg: Optional[LlamaConfig] = None,
+    cfg: Optional[Family] = None,
     dtype=jnp.bfloat16,
     shardings: Optional[dict[str, Any]] = None,
     quantize_int8: bool = False,
-) -> tuple[LlamaConfig, Any]:
+) -> tuple[Family, Any]:
     """Load stacked params from an HF Llama directory.
 
     ``shardings``, when given, is a pytree-shaped dict matching the params
@@ -251,12 +188,10 @@ def load_params(
 
     model_dir = Path(model_dir)
     cfg = cfg or config_from_hf(model_dir)
-    if not isinstance(cfg, LlamaConfig):  # afmoe: its own leaves, one chip
-        if quantize_int8 or shardings:
-            raise ValueError(
-                f"model {cfg.name!r} (family afmoe) serves bf16 or float32 "
-                f"weights on one chip: no int8 matrices, no mesh")
-        return load_afmoe_params(model_dir, cfg, dtype)
+    own_leaves = _OWN_LEAVES.get(cfg.family_name)
+    if own_leaves is not None:  # a family with leaves of its own, on one chip
+        _one_chip_only(cfg, quantize_int8, shardings)
+        return own_leaves(model_dir, cfg, dtype)
     idx = _ShardIndex(model_dir)
     sh = shardings or {}
 
@@ -329,6 +264,13 @@ def load_params(
     return cfg, params
 
 
+def _one_chip_only(cfg: Family, quantize_int8: bool, shardings) -> None:
+    if quantize_int8 or shardings:
+        raise ValueError(
+            f"model {cfg.name!r} (family {cfg.family_name}) serves bf16 or "
+            f"float32 weights on one chip: no int8 matrices, no mesh")
+
+
 def quiet_control_tokens(params: Any, vocab_size: int) -> Any:
     """Seeded weights with the head's columns of the byte tokenizer's control
     ids (``ByteTokenizer.special_ids``: begin/end of text, headers, eot,
@@ -357,43 +299,19 @@ def load_or_init(
     shardings: Optional[dict[str, Any]] = None,
     seed: int = 0,
     quantize_int8: bool = False,
-) -> tuple[LlamaConfig, Any]:
+) -> tuple[Family, Any]:
     """Load from ``model_path`` when present, else random-init ``model_name``.
 
     Random init keeps every serving path exercisable in the no-egress
     environment (BASELINE.md configs run with real weights when provided).
     """
-    from runbookai_tpu.models.afmoe import AfmoeConfig
-    from runbookai_tpu.models.joyai import JoyaiConfig
-    from runbookai_tpu.models.longcat import LongcatConfig
-    from runbookai_tpu.models.nemotron_h import NemotronHConfig
-    from runbookai_tpu.models.qwen3_next import Qwen3NextConfig
-
-    nemotron_h = isinstance(CONFIGS.get(model_name), NemotronHConfig)
-    if nemotron_h and model_path and Path(model_path).exists():
+    known = CONFIGS.get(model_name)
+    if (known is not None and known.checkpoint_tensors and model_path
+            and Path(model_path).exists()):
         raise NotImplementedError(
             f"model {model_name!r}: no loader for checkpoints of the "
-            f"nemotron-h family yet (Mamba-2 mixer and expert tensor names); "
+            f"{known.family_name} family yet ({known.checkpoint_tensors}); "
             f"leave llm.model_path unset to serve seeded random weights")
-    joyai = isinstance(CONFIGS.get(model_name), JoyaiConfig)
-    if joyai and model_path and Path(model_path).exists():
-        raise NotImplementedError(
-            f"model {model_name!r}: no loader for checkpoints of the joyai "
-            f"family yet (MLA, expert and prediction-module tensor names); "
-            f"leave llm.model_path unset to serve seeded random weights")
-    longcat = isinstance(CONFIGS.get(model_name), LongcatConfig)
-    if longcat and model_path and Path(model_path).exists():
-        raise NotImplementedError(
-            f"model {model_name!r}: no loader for checkpoints of the longcat "
-            f"family yet (MLA and expert tensor names); leave llm.model_path "
-            f"unset to serve seeded random weights")
-    qwen3_next = isinstance(CONFIGS.get(model_name), Qwen3NextConfig)
-    if qwen3_next and model_path and Path(model_path).exists():
-        raise NotImplementedError(
-            f"model {model_name!r}: no loader for checkpoints of the "
-            f"qwen3-next family yet (linear-attention and expert tensor "
-            f"names); leave llm.model_path unset to serve seeded random "
-            f"weights")
     if model_path and Path(model_path).exists():
         from runbookai_tpu.models.checkpoint import is_checkpoint, load_checkpoint
 
@@ -420,29 +338,11 @@ def load_or_init(
             f"unknown model {model_name!r} and no checkpoint at "
             f"{str(model_path)!r}; known configs: {sorted(CONFIGS)}")
     cfg = CONFIGS[model_name]
-    afmoe = isinstance(cfg, AfmoeConfig)
-    if longcat or qwen3_next or joyai or nemotron_h or afmoe:
-        from runbookai_tpu.models import afmoe as afmoe_model
-        from runbookai_tpu.models import joyai as joyai_model
-        from runbookai_tpu.models import longcat as longcat_model
-        from runbookai_tpu.models import nemotron_h as nemotron_h_model
-        from runbookai_tpu.models import qwen3_next as qwen3_next_model
-
-        model, family = ((longcat_model, "longcat") if longcat else
-                         (qwen3_next_model, "qwen3-next") if qwen3_next else
-                         (nemotron_h_model, "nemotron-h") if nemotron_h else
-                         (afmoe_model, "afmoe") if afmoe else
-                         (joyai_model, "joyai"))
-        if quantize_int8 or shardings:
-            raise ValueError(
-                f"model {model_name!r} (family {family}) serves bf16 or "
-                f"float32 weights on one chip: no int8 matrices, no mesh")
-        return cfg, quiet_control_tokens(model.init_params(
-            jax.random.PRNGKey(seed), cfg, dtype=dtype), cfg.vocab_size)
-    # int8 leaves are sampled directly: a 7B bf16 tree (15 GB) plus the
-    # float32 temporaries of quantizing it cannot exist on a 16 GB chip.
-    init = init_params_quantized if quantize_int8 else init_params
-    params = init(jax.random.PRNGKey(seed), cfg, dtype=dtype)
+    key = jax.random.PRNGKey(seed)
+    if cfg.one_path:  # the families whose seeded recipe is this repo's own
+        _one_chip_only(cfg, quantize_int8, shardings)
+        return cfg, quiet_control_tokens(cfg.init_params(key, dtype), cfg.vocab_size)
+    params = cfg.init_params(key, dtype, quantized=quantize_int8)
     if shardings:
         params = jax.tree.map(
             lambda x, s: jax.device_put(x, s) if s is not None else x,

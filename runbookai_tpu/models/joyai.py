@@ -48,7 +48,7 @@ layers, then the ``M`` = ``num_nextn_predict_layers`` modules) and their
 matrices stacked on that axis; expert layers are numbered ``0 .. L - K + M
 - 1`` (``K`` = ``first_k_dense_replace``); the leading dense FFNs ``0 .. K
 - 1``. The module's layer is one more layer of the same stacks and of the
-same paged pool. The pool is :mod:`runbookai_tpu.models.longcat`'s pair:
+same paged pool. The pool is ``models/longcat.py``'s pair:
 latents ``[L + M, tokens, 1, kv_rank]``, and rotated keys ``[(L + M + 1) //
 2, tokens, 1, 2 * rope]`` — blocks ``2j`` and ``2j + 1`` keep theirs side
 by side in one row (``ops/mla.py`` says why a 64-value row will not do).
@@ -60,13 +60,21 @@ from __future__ import annotations
 
 import math
 from dataclasses import dataclass
-from typing import Any, Optional
+from typing import Any
 
 import jax
 import jax.numpy as jnp
 
-from runbookai_tpu.models.longcat import EXPERT_COUNTS, _stacked_normal
+from runbookai_tpu.models.family import (
+    EXPERT_COUNTS,
+    Family,
+    Params,
+    _stacked_normal,
+    register,
+    serving_forwards,
+)
 from runbookai_tpu.ops.attention import pool_rows, write_kv_pages_batch
+from runbookai_tpu.ops.dense import qmm, rms_norm
 from runbookai_tpu.ops.mla import (
     absorb_queries,
     expand_values,
@@ -80,11 +88,9 @@ from runbookai_tpu.ops.moe import (
 )
 from runbookai_tpu.ops.rope import apply_rope
 
-Params = dict[str, Any]
-
 
 @dataclass(frozen=True)
-class JoyaiConfig:
+class JoyaiConfig(Family):
     name: str
     vocab_size: int
     hidden_size: int
@@ -123,10 +129,13 @@ class JoyaiConfig:
     # The chat template the family renders (model/chat_template.py).
     family: str = "qwen2"
 
-    tie_embeddings = False
     # As longcat: attention is this module's, over its own latent pool.
     pallas_attention = False
-    state_pool_spec = None
+    one_path = True
+    no_draft_model = "a separate draft model beside its own prediction module"
+    family_name = "joyai"
+    hf_model_types = ("joyai",)
+    checkpoint_tensors = "MLA, expert and prediction-module tensor names"
 
     def __post_init__(self):
         routing = (self.scoring_func, self.topk_method, self.norm_topk_prob,
@@ -188,10 +197,8 @@ class JoyaiConfig:
         return self.num_nextn_predict_layers > 0
 
     def forwards(self):
-        """(forward, ragged forward) as the engine's step programs call
-        them: the serving signatures, returning ``(logits, kv_k, kv_v,
-        expert counts)`` and, asked with ``hidden_out``, the trunk's last
-        hidden state."""
+        """The serving pair; the trunk's last hidden state (before the
+        final norm) comes back as its sixth result: the module reads it."""
         return forward_counted, forward_ragged_counted
 
     def drafter(self):
@@ -199,28 +206,8 @@ class JoyaiConfig:
         speculative round runs beside the forward."""
         return module_pass, module_pass_ragged, draft_tokens
 
-    def unsupported(self, *, lora: bool, model_axis: int, seq_axis: int,
-                    kv_dtype, quantized: bool, draft: bool = False,
-                    **_asked) -> list[str]:
-        """What this family's forward does not do yet, of what the engine
-        was asked for — refused by name at engine init, never served
-        wrong."""
-        no = []
-        if draft:
-            no.append("a separate draft model beside its own prediction "
-                      "module")
-        if lora:
-            no.append("LoRA adapters")
-        if model_axis > 1:
-            no.append(f"a model axis of {model_axis} (tensor/expert "
-                      f"parallelism across chips)")
-        if seq_axis > 1:
-            no.append("the KV page-split (seq) mesh axis")
-        if jnp.dtype(kv_dtype) == jnp.int8:
-            no.append("an int8 KV pool (per-token scales)")
-        if quantized:
-            no.append("int8 weight-only matrices")
-        return no
+    def init_params(self, key, dtype=jnp.bfloat16, quantized=False) -> Params:
+        return init_params(key, self, dtype)
 
     # ---- counts (the memory plan's and the MFU model's) ----------------
 
@@ -275,7 +262,7 @@ _WIDTHS = dict(hidden_size=2048, intermediate_size=7168,
                qk_rope_head_dim=64, v_head_dim=128, n_routed_experts=256,
                num_experts_per_tok=8, routed_scaling_factor=2.5)
 
-CONFIGS: dict[str, JoyaiConfig] = {
+CONFIGS: dict[str, JoyaiConfig] = register({
     # The published model (config.json): 40 layers, every routed expert
     # held, one prediction module. 48B parameters: no single process of
     # this repo holds it; it is the entry a cut configuration is checked
@@ -302,7 +289,7 @@ CONFIGS: dict[str, JoyaiConfig] = {
         n_routed_experts=16, num_experts_per_tok=4, routed_scaling_factor=2.5,
         n_experts_held=8, first_expert=8, rope_theta=10_000.0,
         max_position_embeddings=8192, router_bias_scale=2e-2),
-}
+})
 
 
 def leaf_shapes(cfg: JoyaiConfig) -> dict[str, tuple[tuple[int, ...], int]]:
@@ -375,7 +362,7 @@ def moe_block(u: jnp.ndarray, live: jnp.ndarray, w: dict, e, cfg: JoyaiConfig,
               ) -> tuple[jnp.ndarray, jnp.ndarray]:
     """``MoE(u)`` of this share for ``u`` [N, D] in expert layer ``e`` (a
     traced scalar, or a number) of the stacked leaves ``w``, and its counts
-    (``longcat.EXPERT_COUNTS``; no identity experts here, so ``zero`` is 0)
+    (``family.EXPERT_COUNTS``; no identity experts here, so ``zero`` is 0)
     over the tokens ``live`` [N]."""
     n = u.shape[0]
     held_n = cfg.n_experts_held
@@ -424,8 +411,6 @@ def _layer(w, cfg: JoyaiConfig, hidden, live, block, ffn, positions, kv_k,
     FFN ``ffn`` = ("dense", k) or ("experts", e). Returns (hidden', kv_k',
     kv_v', expert counts). Every leaf is indexed where it is used, out of
     the stacked array, so a product reads its slice in place."""
-    from runbookai_tpu.models.llama import qmm, rms_norm  # deferred: cycle
-
     b, t, d = hidden.shape
     n_h, eps = cfg.num_attention_heads, cfg.rms_norm_eps
     nope, rope, rank = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.kv_lora_rank
@@ -502,8 +487,6 @@ def module_pass(params, cfg, hidden, tokens, positions, kv_k, kv_v,
     of them. Writes its cache rows at ``positions`` (``ctx_lens`` counts
     them) and returns (y [B, T, D] — :func:`draft_tokens` makes drafts of
     it — kv_k', kv_v', expert counts)."""
-    from runbookai_tpu.models.llama import qmm, rms_norm  # deferred: cycle
-
     _check(params, kv_k)
     b, t = tokens.shape
     m, eps = params["mtp"], cfg.rms_norm_eps
@@ -538,8 +521,6 @@ def module_pass_ragged(params, cfg, hidden, tokens, positions, row_ids, kv_k,
 def draft_logits(params, cfg, y) -> jnp.ndarray:
     """The shared head over the module's output, under the module's own
     final norm: float32 logits of the token two ahead."""
-    from runbookai_tpu.models.llama import rms_norm  # deferred: cycle
-
     h = rms_norm(y, params["mtp"]["final_norm"][0], cfg.rms_norm_eps)
     return (h @ params["lm_head"]).astype(jnp.float32)
 
@@ -549,52 +530,12 @@ def draft_tokens(params, cfg, y) -> jnp.ndarray:
     return jnp.argmax(draft_logits(params, cfg, y), axis=-1).astype(jnp.int32)
 
 
-def forward_counted(params, cfg, tokens, positions, kv_k, kv_v, page_tables,
-                    ctx_lens, page_size, block_pages=32, attn_impl="xla",
-                    mesh=None, adapter_ids=None, qmm_impl="xla",
-                    hidden_out=False):
-    """:func:`forward_impl` with the expert counts as a fourth result and,
-    with ``hidden_out``, the trunk's last hidden state [B, T, D] (before
-    the final norm) as a fifth."""
-    from runbookai_tpu.models.llama import lm_head_logits
-
-    del attn_impl, mesh, adapter_ids, qmm_impl  # one path; the engine refuses the rest
-    h, kv_k, kv_v, counts = _forward_hidden(
-        params, cfg, tokens, positions, kv_k, kv_v, page_tables, ctx_lens,
-        page_size, block_pages)
-    out = (lm_head_logits(params, cfg, h), kv_k, kv_v, counts)
-    return (*out, h) if hidden_out else out
+# The step programs' pair (``JoyaiConfig.forwards``).
+forward_counted, forward_ragged_counted = serving_forwards(_forward_hidden)
 
 
-def forward_ragged_counted(params, cfg, tokens, positions, row_ids, kv_k, kv_v,
-                           page_tables, ctx_lens, sel_idx, page_size,
-                           block_pages=32, attn_impl="xla", mesh=None,
-                           adapter_ids=None, qmm_impl="xla", ragged_block=8,
-                           hidden_out=False):
-    """:func:`forward_ragged_impl` with the expert counts as a fourth
-    result and, with ``hidden_out``, the hidden state of every token of the
-    flat buffer [N, D] as a fifth."""
-    from runbookai_tpu.models.llama import lm_head_logits
-
-    del attn_impl, mesh, adapter_ids, qmm_impl
-    n = tokens.shape[0]
-    nb = n // ragged_block
-    block_rows = row_ids.reshape(nb, ragged_block)[:, 0]
-    h, kv_k, kv_v, counts = _forward_hidden(
-        params, cfg, tokens.reshape(nb, ragged_block),
-        positions.reshape(nb, ragged_block), kv_k, kv_v,
-        page_tables[block_rows], ctx_lens[block_rows], page_size, block_pages)
-    h = h.reshape(n, h.shape[-1])
-    out = (lm_head_logits(params, cfg, h[sel_idx]), kv_k, kv_v, counts)
-    return (*out, h) if hidden_out else out
-
-
-def forward_impl(params: Params, cfg: JoyaiConfig, tokens, positions, kv_k,
-                 kv_v, page_tables, ctx_lens, page_size: int,
-                 block_pages: int = 32, attn_impl: str = "xla", mesh=None,
-                 adapter_ids: Optional[jnp.ndarray] = None,
-                 qmm_impl: str = "xla"):
-    """One forward chunk, llama.py's signature: (logits [B, T, vocab] f32,
-    kv_k', kv_v'). ``kv_k`` is the latent pool, ``kv_v`` the rotated keys'."""
-    return forward_counted(params, cfg, tokens, positions, kv_k, kv_v,
-                           page_tables, ctx_lens, page_size, block_pages)[:3]
+def forward_impl(params: Params, cfg: JoyaiConfig, *chunk, **kw):
+    """One forward chunk, the serving signature (``family.serving_forwards``):
+    (logits [B, T, vocab] f32, kv_k', kv_v'). ``kv_k`` is the latent pool,
+    ``kv_v`` the rotated keys'."""
+    return forward_counted(params, cfg, *chunk, **kw)[:3]

@@ -26,7 +26,6 @@ Design (TPU-first, no reference counterpart — RunbookAI calls hosted APIs):
 
 from __future__ import annotations
 
-import math
 from dataclasses import dataclass
 from functools import partial
 from typing import Any, Optional
@@ -34,24 +33,23 @@ from typing import Any, Optional
 import jax
 import jax.numpy as jnp
 
-from runbookai_tpu.models.afmoe import CONFIGS as _AFMOE_CONFIGS
-from runbookai_tpu.models.afmoe import AfmoeConfig
-from runbookai_tpu.models.joyai import CONFIGS as _JOYAI_CONFIGS
-from runbookai_tpu.models.joyai import JoyaiConfig
-from runbookai_tpu.models.longcat import CONFIGS as _LONGCAT_CONFIGS
-from runbookai_tpu.models.longcat import LongcatConfig
-from runbookai_tpu.models.nemotron_h import CONFIGS as _NEMOTRON_H_CONFIGS
-from runbookai_tpu.models.nemotron_h import NemotronHConfig
-from runbookai_tpu.models.qwen3_next import CONFIGS as _QWEN3_NEXT_CONFIGS
-from runbookai_tpu.models.qwen3_next import Qwen3NextConfig
+from runbookai_tpu.models.family import (  # noqa: F401 — get_config: this module's API
+    CONFIGS,
+    Family,
+    Params,
+    get_config,
+    lm_head_logits,
+    register,
+    serving_forwards,
+)
 from runbookai_tpu.ops.attention import paged_attention, write_kv_pages_batch
+from runbookai_tpu.ops.dense import qmm, rms_norm
+from runbookai_tpu.ops.moe import moe_ffn
 from runbookai_tpu.ops.rope import apply_rope
-
-Params = dict[str, Any]
 
 
 @dataclass(frozen=True)
-class LlamaConfig:
+class LlamaConfig(Family):
     name: str
     vocab_size: int
     dim: int
@@ -87,10 +85,8 @@ class LlamaConfig:
     # e.g. 1.25–2.0 (token-expert assignments past the capacity drop).
     capacity_factor: float = 0.0
 
-    # The engine's Pallas attention kernels read this family's pages.
-    pallas_attention = True
-    # No layer keeps state that is not token rows in pages.
-    state_pool_spec = None
+    family_name = "llama"
+    hf_model_types = ("llama", "qwen2", "mistral", "mixtral")
 
     @property
     def head_dim(self) -> int:
@@ -103,19 +99,75 @@ class LlamaConfig:
         return side, side
 
     def forwards(self):
-        """(forward, ragged forward) as the engine's step programs call
-        them: the serving signatures, returning ``(logits, kv_k, kv_v,
-        expert counts)`` — None here: a family that counts nothing adds no
-        output to a step program."""
-        def counted(fn):
-            return lambda *a, **kw: (*fn(*a, **kw), None)
+        return forward_counted, forward_ragged_counted
 
-        return counted(forward_impl), counted(forward_ragged_impl)
+    def init_params(self, key, dtype=jnp.bfloat16, quantized=False) -> Params:
+        # int8 leaves are sampled directly: a 7B bf16 tree (15 GB) plus the
+        # float32 temporaries of quantizing it cannot exist on a 16 GB chip.
+        return (init_params_quantized if quantized else init_params)(key, self, dtype)
 
-    def unsupported(self, **_asked) -> list[str]:
-        """This family's forward covers everything the engine can be asked
-        for; what it resolves or refuses is the engine's own table."""
-        return []
+    def weight_bytes_per_chip(self, tp: int, weights: str, kv_shards: int) -> float:
+        """This family is laid out across chips: wk/wv shard ``kv_shards``-way
+        only, everything else full-tp; the norms are replicated."""
+        layer_matmul = self.matmul_params - self.dim * self.vocab_size
+        wkv = self.n_layers * 2 * self.dim * self.n_kv_heads * self.head_dim
+        emb_head = 2 * self.vocab_size * self.dim  # embed + lm head (or tied x2)
+        if weights == "int8":
+            per_chip = ((layer_matmul - wkv) / max(tp, 1)
+                        + wkv / max(kv_shards, 1)
+                        + layer_matmul / self.dim * 4 / max(tp, 1)  # scales
+                        + emb_head * 2 / max(tp, 1))  # bf16
+        else:
+            per_chip = ((layer_matmul - wkv) * 2 / max(tp, 1)
+                        + wkv * 2 / max(kv_shards, 1)
+                        + emb_head * 2 / max(tp, 1))
+        return per_chip + (self.n_layers * 2 + 1) * self.dim * 4
+
+    @classmethod
+    def from_hf(cls, raw: dict, name: str) -> "LlamaConfig":
+        model_type = raw.get("model_type", "llama")
+        # Llama-3.1-style long-context rope scaling (rope_type "llama3").
+        # Other scaling schemes (linear/dynamic/yarn) would silently produce
+        # wrong logits past the original context if dropped — refuse loudly,
+        # matching the unsupported-model_type behavior.
+        rs = raw.get("rope_scaling") or {}
+        rope_scaling = None
+        rs_type = rs.get("rope_type", rs.get("type"))
+        if rs_type == "llama3":
+            rope_scaling = (
+                float(rs["factor"]),
+                float(rs.get("low_freq_factor", 1.0)),
+                float(rs.get("high_freq_factor", 4.0)),
+                int(rs.get("original_max_position_embeddings", 8192)),
+            )
+        elif rs_type not in (None, "default"):
+            raise ValueError(
+                f"rope_scaling type {rs_type!r} not supported (only 'llama3'); "
+                f"loading without it would silently change long-context numerics")
+        return cls(
+            name=name,
+            vocab_size=raw["vocab_size"],
+            dim=raw["hidden_size"],
+            n_layers=raw["num_hidden_layers"],
+            n_heads=raw["num_attention_heads"],
+            n_kv_heads=raw.get("num_key_value_heads", raw["num_attention_heads"]),
+            ffn_dim=raw["intermediate_size"],
+            rope_theta=raw.get("rope_theta", 500_000.0),
+            rope_scaling=rope_scaling,
+            norm_eps=raw.get("rms_norm_eps", 1e-5),
+            # The Llama block declares no window (a family that does serves it
+            # as one: models/afmoe.py): its sliding-window checkpoints (Mistral
+            # v0.1) are served with full attention — exact only up to the window,
+            # so the window clamps the serveable context rather than silently
+            # changing semantics past it.
+            max_seq_len=min(raw.get("max_position_embeddings", 8192),
+                            raw.get("sliding_window") or 1 << 30),
+            tie_embeddings=raw.get("tie_word_embeddings", False),
+            qkv_bias=model_type == "qwen2",
+            family=model_type,
+            n_experts=raw.get("num_local_experts", 0) if model_type == "mixtral" else 0,
+            top_k_experts=raw.get("num_experts_per_tok", 2),
+        )
 
     @property
     def matmul_params(self) -> int:
@@ -148,10 +200,9 @@ class LlamaConfig:
                 + ffn_delta)
 
 
-AnyConfig = (LlamaConfig | LongcatConfig | Qwen3NextConfig | JoyaiConfig
-             | NemotronHConfig | AfmoeConfig)
-
-CONFIGS: dict[str, AnyConfig] = {
+# This family's entries of the registry (``family.CONFIGS``, which this
+# module's ``CONFIGS`` IS: the other families' files add theirs to it).
+register({
     "llama3-8b-instruct": LlamaConfig(
         name="llama3-8b-instruct", vocab_size=128_256, dim=4096, n_layers=32,
         n_heads=32, n_kv_heads=8, ffn_dim=14_336,
@@ -258,26 +309,7 @@ CONFIGS: dict[str, AnyConfig] = {
         n_kv_heads=2, ffn_dim=128, max_seq_len=8192, rope_theta=10_000.0,
         family="mixtral", n_experts=4, top_k_experts=2,
     ),
-    # Another architecture, its own dataclass and forward (models/longcat.py).
-    **_LONGCAT_CONFIGS,
-    # A period of unlike layers over two kinds of state (models/qwen3_next.py).
-    **_QWEN3_NEXT_CONFIGS,
-    # A leading dense layer, then expert layers, and the model's own
-    # prediction module as the drafter (models/joyai.py).
-    **_JOYAI_CONFIGS,
-    # A pattern of single-mixer layers: Mamba-2 state-space layers beside
-    # position-free attention and two-matrix experts (models/nemotron_h.py).
-    **_NEMOTRON_H_CONFIGS,
-    # Sliding-window layers beside full ones over two groups of the paged
-    # pool, sparse experts past two dense layers (models/afmoe.py).
-    **_AFMOE_CONFIGS,
-}
-
-
-def get_config(name: str) -> AnyConfig:
-    if name not in CONFIGS:
-        raise KeyError(f"Unknown model {name!r}; known: {sorted(CONFIGS)}")
-    return CONFIGS[name]
+})
 
 
 def _layer_shapes(cfg: LlamaConfig) -> dict[str, tuple[tuple[int, ...], int]]:
@@ -376,65 +408,17 @@ def init_params_quantized(key: jax.Array, cfg: LlamaConfig,
     return _build_params(key, cfg, dtype, qdense)
 
 
-def qmm(x: jnp.ndarray, w: Any, impl: str = "xla") -> jnp.ndarray:
-    """Matmul that accepts int8 weight-only quantized weights.
-
-    Quantized leaves are ``{"q": int8 [.., in, out], "s": f32 [.., 1, out]}``
-    (:mod:`runbookai_tpu.models.quant`). The matmul runs on the MXU in the
-    activation dtype (int8→bf16 cast is exact) and the per-output-channel
-    scale applies to the result — identical math to dequantize-first, since
-    the scale is constant along the contraction.
-
-    ``impl="pallas"`` reads the int8 matrix through the Pallas kernel
-    (:mod:`runbookai_tpu.ops.qmm_pallas`) at decode/verify shapes — the
-    convert happens in VMEM, so HBM moves half the bf16 bytes by
-    construction instead of by fusion luck. A leaf that also carries
-    ``"layer"`` holds the layer scan's STACKED ``q [L, in, out]`` with the
-    layer's number and that layer's scales (:func:`_forward_hidden`, which
-    has checked the shape): the kernel reads that layer's matrix where it
-    lies. Shapes the kernel does not cover (chunked prefill M, ragged
-    dims, unquantized leaves) fall back to the XLA expression below, same
-    math.
-    """
-    if isinstance(w, dict):
-        if "layer" in w or (impl == "pallas" and w["q"].ndim == 2):
-            from runbookai_tpu.ops.qmm_pallas import (
-                qmm_pallas,
-                qmm_pallas_eligible,
-            )
-
-            lead = x.shape[:-1]
-            k_dim, n = w["q"].shape[-2:]
-            if "layer" in w or qmm_pallas_eligible(math.prod(lead), k_dim, n):
-                out = qmm_pallas(
-                    x.reshape(-1, k_dim), w["q"], w["s"].reshape(1, n),
-                    w.get("layer"),
-                    interpret=jax.default_backend() == "cpu",
-                )
-                return out.reshape(*lead, n)
-        return (x @ w["q"].astype(x.dtype)) * w["s"].astype(x.dtype)
-    return x @ w
-
-
 def ffn_block(y: jnp.ndarray, lp: dict, cfg: LlamaConfig,
               qmm_impl: str = "xla") -> jnp.ndarray:
     """SwiGLU FFN (dense) or Mixtral MoE, by config — shared by the paged
     serving forward, the dense training forward, and the pipeline stages.
     Residual is added by the caller."""
     if cfg.n_experts:
-        from runbookai_tpu.ops.moe import moe_ffn
-
         return moe_ffn(y, lp["router"], lp["w_gate"], lp["w_up"],
                        lp["w_down"], cfg.top_k_experts, cfg.capacity_factor)
     mm = partial(qmm, impl=qmm_impl)
     return mm(jax.nn.silu(mm(y, lp["w_gate"])) * mm(y, lp["w_up"]),
               lp["w_down"])
-
-
-def rms_norm(x: jnp.ndarray, weight: jnp.ndarray, eps: float) -> jnp.ndarray:
-    xf = x.astype(jnp.float32)
-    norm = xf * jax.lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True) + eps)
-    return (norm * weight).astype(x.dtype)
 
 
 def _forward_hidden(
@@ -688,6 +672,11 @@ def _forward_hidden(
     return h, kv_k, kv_v
 
 
+# The step programs' pair (``LlamaConfig.forwards``): the one serving
+# signature and six-field result of ``family.serving_forwards``.
+forward_counted, forward_ragged_counted = serving_forwards(_forward_hidden)
+
+
 def forward_impl(
     params: Params,
     cfg: LlamaConfig,
@@ -718,12 +707,9 @@ def forward_impl(
     the caller's buffer and allocates no second pool (without it, XLA
     copies the pool once, on entry).
     """
-    h, kv_k_new, kv_v_new = _forward_hidden(
+    return forward_counted(
         params, cfg, tokens, positions, kv_k, kv_v, page_tables, ctx_lens,
-        page_size=page_size, block_pages=block_pages, attn_impl=attn_impl,
-        mesh=mesh, adapter_ids=adapter_ids, qmm_impl=qmm_impl,
-    )
-    return lm_head_logits(params, cfg, h), kv_k_new, kv_v_new
+        page_size, block_pages, attn_impl, mesh, adapter_ids, qmm_impl)[:3]
 
 
 def forward_ragged_impl(
@@ -763,26 +749,16 @@ def forward_ragged_impl(
     per-BLOCK gathered tables — the same transform
     :func:`runbookai_tpu.ops.attention.ragged_paged_attention` and the
     Pallas ``paged_ragged_attention`` apply per attention call, hoisted
-    here above the layer scan so KV writes and page loads share it.
+    above the layer scan (``family.serving_forwards``, for every family
+    alike) so KV writes and page loads share it.
 
     Returns (logits [S, vocab] f32 for the ``sel_idx`` tokens only — the
     vocab projection is paid for S rows, not N — kv_k', kv_v').
     """
-    n = tokens.shape[0]
-    rq = ragged_block
-    nb = n // rq
-    block_rows = row_ids.reshape(nb, rq)[:, 0]
-    h, kv_k_new, kv_v_new = _forward_hidden(
-        params, cfg, tokens.reshape(nb, rq), positions.reshape(nb, rq),
-        kv_k, kv_v, page_tables[block_rows], ctx_lens[block_rows],
-        page_size=page_size, block_pages=block_pages, attn_impl=attn_impl,
-        mesh=mesh,
-        adapter_ids=(adapter_ids[block_rows]
-                     if adapter_ids is not None else None),
-        qmm_impl=qmm_impl,
-    )
-    h_sel = h.reshape(n, h.shape[-1])[sel_idx]
-    return lm_head_logits(params, cfg, h_sel), kv_k_new, kv_v_new
+    return forward_ragged_counted(
+        params, cfg, tokens, positions, row_ids, kv_k, kv_v, page_tables,
+        ctx_lens, sel_idx, page_size, block_pages, attn_impl, mesh, adapter_ids,
+        qmm_impl, ragged_block)[:3]
 
 
 forward = partial(jax.jit, static_argnames=("cfg", "page_size", "block_pages",
@@ -838,13 +814,6 @@ def transformer_layer(hidden, lp, cfg: LlamaConfig, positions, attn_fn,
     hidden = hidden + o
     y = rms_norm(hidden, lp["mlp_norm"], cfg.norm_eps)
     return hidden + ffn_block(y, lp, cfg)
-
-
-def lm_head_logits(params: Params, cfg: LlamaConfig, hidden) -> jnp.ndarray:
-    """Final norm + (tied or untied) LM head, float32 logits."""
-    h = rms_norm(hidden, params["final_norm"], cfg.norm_eps)
-    head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
-    return (h @ head).astype(jnp.float32)
 
 
 def forward_train(

@@ -32,8 +32,8 @@ output and every pick; this chip computes its own experts' part and the
 identity part for its tokens, and what the absent experts would add is
 left out — no code stands in for the other chips or their exchange.
 
-The serving contract is :mod:`runbookai_tpu.models.llama`'s: the same
-``forward_impl`` / ``forward_ragged_impl`` signatures, one ``lax.scan`` body
+The serving contract is :mod:`runbookai_tpu.models.family`'s
+(``serving_forwards`` over :func:`_forward_hidden`), one ``lax.scan`` body
 per (double) layer, the pool riding the scan's carry and written in place
 at ``(2 * layer + i, dest)``. The pool is a pair, ``[2L, tokens, 1,
 kv_rank]`` and ``[L, tokens, 1, 2 * rope]`` (a layer's two rotated keys in
@@ -50,7 +50,16 @@ from typing import Any, Optional
 import jax
 import jax.numpy as jnp
 
+from runbookai_tpu.models.family import (
+    EXPERT_COUNTS,
+    Family,
+    Params,
+    _stacked_normal,
+    register,
+    serving_forwards,
+)
 from runbookai_tpu.ops.attention import write_kv_pages_batch
+from runbookai_tpu.ops.dense import qmm, rms_norm
 from runbookai_tpu.ops.mla import (
     absorb_queries,
     expand_values,
@@ -59,19 +68,9 @@ from runbookai_tpu.ops.mla import (
 from runbookai_tpu.ops.moe import held_capacity, held_expert_ffn, route_scaled
 from runbookai_tpu.ops.rope import apply_rope
 
-Params = dict[str, Any]
-
-# What a step program counts in the expert layers, summed over layers, of
-# the LIVE tokens it ran (pads and free slots left out): token-expert pairs
-# that fell on experts held here, on identity experts, on experts that live
-# elsewhere, held experts that got at least one pair, and expert layers
-# whose dispatch overflowed its slots and took the slow path
-# (``ops/moe.held_expert_ffn``).
-EXPERT_COUNTS = ("held", "zero", "absent", "touched", "overflow")
-
 
 @dataclass(frozen=True)
-class LongcatConfig:
+class LongcatConfig(Family):
     name: str
     vocab_size: int
     hidden_size: int
@@ -101,14 +100,14 @@ class LongcatConfig:
     router_bias_scale: float = 1e-3
     family: str = "longcat"
 
-    # The names the engine and the memory plan read off any configuration.
-    tie_embeddings = False
     # The engine's Pallas attention kernels read per-head K/V pages; the
     # latent cache has none, so attention here is the XLA path whatever
     # ``attn_impl`` asks for (the engine resolves it to "xla" and says so).
     pallas_attention = False
-    # No layer keeps state that is not token rows in pages.
-    state_pool_spec = None
+    one_path = True
+    family_name = "longcat"
+    hf_model_types = ("longcat",)
+    checkpoint_tensors = "MLA and expert tensor names"
 
     @property
     def dim(self) -> int:
@@ -139,29 +138,10 @@ class LongcatConfig:
                 (self.num_layers, 1, 2 * self.qk_rope_head_dim))
 
     def forwards(self):
-        """(forward, ragged forward) as the engine's step programs call
-        them: the serving signatures, returning ``(logits, kv_k, kv_v,
-        expert counts)``."""
         return forward_counted, forward_ragged_counted
 
-    def unsupported(self, *, lora: bool, model_axis: int, seq_axis: int,
-                    kv_dtype, quantized: bool, **_asked) -> list[str]:
-        """What this family's forward does not do yet, of what the engine
-        was asked for — refused by name at engine init, never served
-        wrong."""
-        no = []
-        if lora:
-            no.append("LoRA adapters")
-        if model_axis > 1:
-            no.append(f"a model axis of {model_axis} (tensor/expert "
-                      f"parallelism across chips)")
-        if seq_axis > 1:
-            no.append("the KV page-split (seq) mesh axis")
-        if jnp.dtype(kv_dtype) == jnp.int8:
-            no.append("an int8 KV pool (per-token scales)")
-        if quantized:
-            no.append("int8 weight-only matrices")
-        return no
+    def init_params(self, key, dtype=jnp.bfloat16, quantized=False) -> Params:
+        return init_params(key, self, dtype)
 
     @property
     def _sublayer_params(self) -> int:
@@ -197,7 +177,7 @@ class LongcatConfig:
         return self.num_layers * per_layer + 2 * d * self.vocab_size + d
 
 
-CONFIGS: dict[str, LongcatConfig] = {
+CONFIGS: dict[str, LongcatConfig] = register({
     # The published model (config.json): 28 double layers, every routed
     # expert held. 560B parameters: no single process of this repo holds
     # it; it is the entry a cut configuration is checked against.
@@ -233,7 +213,7 @@ CONFIGS: dict[str, LongcatConfig] = {
         rope_theta=10_000.0, max_position_embeddings=8192,
         router_bias_scale=2e-2,
     ),
-}
+})
 
 
 def leaf_shapes(cfg: LongcatConfig) -> dict[str, tuple[tuple[int, ...], int]]:
@@ -257,20 +237,6 @@ def leaf_shapes(cfg: LongcatConfig) -> dict[str, tuple[tuple[int, ...], int]]:
         "e_up": ((L, e, d, fe), d),
         "e_down": ((L, e, fe, d), fe),
     }
-
-
-def _stacked_normal(key, shape, fan_in, dtype):
-    """A stacked matrix sampled one ``[in, out]`` slice at a time (a key a
-    slice), so the float32 transient is a slice's, not the leaf's: 16 held
-    experts of four layers are 3.2 GB in float32, beside 10 GB of weights."""
-    lead, mat = shape[:-2], shape[-2:]
-    keys = jax.random.split(key, math.prod(lead))
-
-    def one(k):
-        return (jax.random.normal(k, mat, jnp.float32)
-                / jnp.sqrt(jnp.float32(fan_in))).astype(dtype)
-
-    return jax.lax.map(one, keys).reshape(shape)
 
 
 def init_params(key: jax.Array, cfg: LongcatConfig, dtype=jnp.bfloat16) -> Params:
@@ -342,8 +308,6 @@ def _forward_hidden(params, cfg, tokens, positions, kv_k, kv_v, page_tables,
                     ctx_lens, page_size, block_pages):
     """The stack over one paged chunk, without the head: (hidden [B, T, D],
     kv_k', kv_v', expert counts [len(EXPERT_COUNTS)])."""
-    from runbookai_tpu.models.llama import qmm, rms_norm  # deferred: cycle
-
     if "lora" in params:
         raise ValueError("the longcat forward has no LoRA rows")
     if isinstance(kv_k, tuple):
@@ -412,59 +376,18 @@ def _forward_hidden(params, cfg, tokens, positions, kv_k, kv_v, page_tables,
     return h, kv_k, kv_v, counts
 
 
-def forward_counted(params, cfg, tokens, positions, kv_k, kv_v, page_tables,
-                    ctx_lens, page_size, block_pages=32, attn_impl="xla",
-                    mesh=None, adapter_ids=None, qmm_impl="xla"):
-    """:func:`forward_impl` with the expert counts as a fourth result."""
-    from runbookai_tpu.models.llama import lm_head_logits
-
-    del attn_impl, mesh, adapter_ids, qmm_impl  # one path; the engine refuses the rest
-    h, kv_k, kv_v, counts = _forward_hidden(
-        params, cfg, tokens, positions, kv_k, kv_v, page_tables, ctx_lens,
-        page_size, block_pages)
-    return lm_head_logits(params, cfg, h), kv_k, kv_v, counts
+# The step programs' pair (``LongcatConfig.forwards``).
+forward_counted, forward_ragged_counted = serving_forwards(_forward_hidden)
 
 
-def forward_ragged_counted(params, cfg, tokens, positions, row_ids, kv_k, kv_v,
-                           page_tables, ctx_lens, sel_idx, page_size,
-                           block_pages=32, attn_impl="xla", mesh=None,
-                           adapter_ids=None, qmm_impl="xla", ragged_block=8):
-    """:func:`forward_ragged_impl` with the expert counts as a fourth
-    result. The flat buffer runs as ``[N / ragged_block, ragged_block]``
-    with per-block gathered tables, as llama.py's does."""
-    from runbookai_tpu.models.llama import lm_head_logits
-
-    del attn_impl, mesh, adapter_ids, qmm_impl
-    n = tokens.shape[0]
-    nb = n // ragged_block
-    block_rows = row_ids.reshape(nb, ragged_block)[:, 0]
-    h, kv_k, kv_v, counts = _forward_hidden(
-        params, cfg, tokens.reshape(nb, ragged_block),
-        positions.reshape(nb, ragged_block), kv_k, kv_v,
-        page_tables[block_rows], ctx_lens[block_rows], page_size, block_pages)
-    h_sel = h.reshape(n, h.shape[-1])[sel_idx]
-    return lm_head_logits(params, cfg, h_sel), kv_k, kv_v, counts
+def forward_impl(params: Params, cfg: LongcatConfig, *chunk, **kw):
+    """One forward chunk, the serving signature (``family.serving_forwards``):
+    (logits [B, T, vocab] f32, kv_k', kv_v'). ``kv_k`` is the latent pool,
+    ``kv_v`` the rotated keys'."""
+    return forward_counted(params, cfg, *chunk, **kw)[:3]
 
 
-def forward_impl(params: Params, cfg: LongcatConfig, tokens, positions, kv_k,
-                 kv_v, page_tables, ctx_lens, page_size: int,
-                 block_pages: int = 32, attn_impl: str = "xla", mesh=None,
-                 adapter_ids: Optional[jnp.ndarray] = None,
-                 qmm_impl: str = "xla"):
-    """One forward chunk, llama.py's signature: (logits [B, T, vocab] f32,
-    kv_k', kv_v'). ``kv_k`` is the latent pool, ``kv_v`` the rotated keys'."""
-    return forward_counted(params, cfg, tokens, positions, kv_k, kv_v,
-                           page_tables, ctx_lens, page_size, block_pages)[:3]
-
-
-def forward_ragged_impl(params: Params, cfg: LongcatConfig, tokens, positions,
-                        row_ids, kv_k, kv_v, page_tables, ctx_lens, sel_idx,
-                        page_size: int, block_pages: int = 32,
-                        attn_impl: str = "xla", mesh=None, adapter_ids=None,
-                        qmm_impl: str = "xla", ragged_block: int = 8):
-    """Mixed prefill+decode forward over one flat ragged batch, llama.py's
+def forward_ragged_impl(params: Params, cfg: LongcatConfig, *batch, **kw):
+    """Mixed prefill+decode forward over one flat ragged batch, the serving
     signature: (logits [S, vocab] f32, kv_k', kv_v')."""
-    return forward_ragged_counted(
-        params, cfg, tokens, positions, row_ids, kv_k, kv_v, page_tables,
-        ctx_lens, sel_idx, page_size, block_pages,
-        ragged_block=ragged_block)[:3]
+    return forward_ragged_counted(params, cfg, *batch, **kw)[:3]

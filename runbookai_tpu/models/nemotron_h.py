@@ -43,7 +43,7 @@ every pick; this chip computes its own experts' part and the shared expert
 for its tokens, and what the absent experts would add is left out — no code
 stands in for the other chips or their exchange.
 
-The serving contract is :mod:`runbookai_tpu.models.qwen3_next`'s. The
+The serving contract is ``models/qwen3_next.py``'s. The
 weights are stacked BY KIND and the stack runs the pattern's runs
 (:func:`layer_plan`): a loop over ``EM`` pairs, and a scan over the groups
 ``(EM)^n *`` whose inner loop's length the device reads — seven layer bodies
@@ -55,19 +55,30 @@ from __future__ import annotations
 
 import re
 from dataclasses import dataclass
-from typing import Any, Optional
+from typing import Any
 
 import jax
 import jax.numpy as jnp
 
-from runbookai_tpu.models.longcat import EXPERT_COUNTS, _stacked_normal
-from runbookai_tpu.models.qwen3_next import (  # noqa: F401
+from runbookai_tpu.models.family import (
+    EXPERT_COUNTS,
+    NO_ROLLBACK,
+    Family,
+    Params,
+    _stacked_normal,
+    register,
+    serving_forwards,
+)
+# The other recurrent family: the same walks over the paged pool and the
+# same state pool's rows (the one import between two family files).
+from runbookai_tpu.models.qwen3_next import (  # noqa: F401 — empty_state: this module's API
     _row_state,
     attend,
     empty_state,
     pallas_walks,
 )
 from runbookai_tpu.ops.attention import write_kv_pages_batch
+from runbookai_tpu.ops.dense import qmm, rms_norm
 from runbookai_tpu.ops.gated_delta import causal_conv_tail
 from runbookai_tpu.ops.moe import (
     held_capacity,
@@ -83,12 +94,11 @@ from runbookai_tpu.ops.ssm import (
     ssm_step_live,
 )
 
-Params = dict[str, Any]
 KINDS = "ME*"
 
 
 @dataclass(frozen=True)
-class NemotronHConfig:
+class NemotronHConfig(Family):
     name: str
     vocab_size: int
     hidden_size: int
@@ -139,13 +149,17 @@ class NemotronHConfig:
     state_snapshots: int = 8
     family: str = "qwen2"  # the chat template: ChatML (assumed)
 
-    tie_embeddings = False
     # ``attn_impl="pallas"`` is the Pallas decode walk over the paged pool
     # for the one-token rows (2 kv heads of 128, a group of 16 query rows a
     # head: :func:`attend_live`); a prefill run keeps XLA's one-row walk, so
     # the engine probes no chunk kernel for this family.
-    pallas_attention = True
     pallas_prefill = False
+    one_path = True
+    no_prompt_lookup = f"prompt-lookup speculation ({NO_ROLLBACK})"
+    no_draft_model = f"draft-model speculation ({NO_ROLLBACK})"
+    family_name = "nemotron-h"
+    hf_model_types = ("nemotron_h",)
+    checkpoint_tensors = "Mamba-2 mixer and expert tensor names"
 
     def __post_init__(self):
         p = self.hybrid_override_pattern
@@ -208,35 +222,10 @@ class NemotronHConfig:
                 ((m, self.conv_kernel - 1, self.conv_channels), jnp.float32))
 
     def forwards(self):
-        """(forward, ragged forward) as the engine's step programs call
-        them, returning ``(logits, kv_k, kv_v, expert counts, state)``."""
         return forward_counted, forward_ragged_counted
 
-    def unsupported(self, *, lora: bool, model_axis: int, seq_axis: int,
-                    kv_dtype, quantized: bool, speculative: bool = False,
-                    draft: bool = False) -> list[str]:
-        """What this family's forward does not do yet, of what the engine
-        was asked for — refused by name at engine init, never served
-        wrong."""
-        no = []
-        if speculative:
-            no.append("prompt-lookup speculation (a rejected draft would "
-                      "need the recurrent state rolled back)")
-        if draft:
-            no.append("draft-model speculation (a rejected draft would "
-                      "need the recurrent state rolled back)")
-        if lora:
-            no.append("LoRA adapters")
-        if model_axis > 1:
-            no.append(f"a model axis of {model_axis} (tensor/expert "
-                      f"parallelism across chips)")
-        if seq_axis > 1:
-            no.append("the KV page-split (seq) mesh axis")
-        if jnp.dtype(kv_dtype) == jnp.int8:
-            no.append("an int8 KV pool (per-token scales)")
-        if quantized:
-            no.append("int8 weight-only matrices")
-        return no
+    def init_params(self, key, dtype=jnp.bfloat16, quantized=False) -> Params:
+        return init_params(key, self, dtype)
 
     # ---- counts (the memory plan's and the MFU model's) ----------------
 
@@ -295,7 +284,7 @@ _PUBLISHED = dict(
     moe_intermediate_size=1856, moe_shared_expert_intermediate_size=3712,
     n_routed_experts=128, num_experts_per_tok=6)
 
-CONFIGS: dict[str, NemotronHConfig] = {
+CONFIGS: dict[str, NemotronHConfig] = register({
     # The published model (config.json): every expert held. 31.6B
     # parameters: no single process of this repo holds it; it is the entry
     # a cut configuration is checked against.
@@ -321,7 +310,7 @@ CONFIGS: dict[str, NemotronHConfig] = {
         n_routed_experts=32, num_experts_per_tok=4, n_experts_held=8,
         first_expert=8, chunk_size=16, max_position_embeddings=8192,
         intermediate_size=32, router_bias_scale=2e-2, state_snapshots=4),
-}
+})
 
 
 def layer_plan(pattern: str) -> tuple[tuple, ...]:
@@ -489,8 +478,6 @@ def moe_block(u: jnp.ndarray, live: jnp.ndarray, w: dict, e,
 def attention_inputs(x, w, ai, cfg):
     """Attention layer ``ai``'s projections over ``x`` [B, T, D]: (q [B, T,
     H, hd], k, v [B, T, KV, hd]). No bias, no norm, no rotary embedding."""
-    from runbookai_tpu.models.llama import qmm
-
     b, t, _ = x.shape
     hd = cfg.head_dim
     return (qmm(x, w["wq"][ai]).reshape(b, t, cfg.num_attention_heads, hd),
@@ -534,8 +521,6 @@ def attend_live(q, ai, kv_k, kv_v, page_tables, ctx_lens, positions, live,
 
 
 def attention_output(attn, w, ai):
-    from runbookai_tpu.models.llama import qmm
-
     return qmm(attn.reshape(*attn.shape[:-2], -1), w["wo"][ai])
 
 
@@ -630,8 +615,6 @@ def ssm_decode(xbc, dt, live, w, mi, cfg, state):
 
 def ssm_output(y, z, w, mi, cfg):
     """``RMSNorm_g(y * silu(z))`` over each group apart, then ``W_out``."""
-    from runbookai_tpu.models.llama import qmm
-
     gated = gated_group_norm(y.reshape(*z.shape), z, w["g_norm"][mi],
                              cfg.n_groups, cfg.layer_norm_epsilon)
     return qmm(gated.astype(w["w_out"].dtype), w["w_out"][mi])
@@ -652,8 +635,6 @@ def _forward_hidden(params, cfg, tokens, positions, kv_k, kv_v, page_tables,
     ``attn_mixer(x, ai, kv_k, kv_v) -> (out, kv_k', kv_v')`` likewise for an
     attention layer (None: :func:`attention` over the chunk as it is laid
     out)."""
-    from runbookai_tpu.models.llama import rms_norm  # deferred: cycle
-
     if "lora" in params:
         raise ValueError("the nemotron-h forward has no LoRA rows")
     if isinstance(kv_k, tuple):
@@ -694,23 +675,14 @@ def _forward_hidden(params, cfg, tokens, positions, kv_k, kv_v, page_tables,
     return h, kv_k, kv_v, counts, state
 
 
-def _head(params, cfg, hidden):
-    from runbookai_tpu.models.llama import rms_norm
-
-    return (rms_norm(hidden, params["final_norm"], cfg.layer_norm_epsilon)
-            @ params["lm_head"]).astype(jnp.float32)
-
-
-def forward_counted(params, cfg, tokens, positions, kv_k, kv_v, page_tables,
-                    ctx_lens, page_size, block_pages=32, attn_impl="xla",
-                    mesh=None, adapter_ids=None, qmm_impl="xla", *, state,
-                    state_rows=None):
-    """One forward chunk ``[B, T]`` (decode: T = 1; a prefill chunk a row):
-    (logits [B, T, vocab] f32, kv_k', kv_v', expert counts, state'). Row
+def hidden_chunk(params, cfg, tokens, positions, kv_k, kv_v, page_tables,
+                 ctx_lens, page_size, block_pages, attn_impl="xla", *, state,
+                 state_rows=None):
+    """The stack over one chunk ``[B, T]`` (decode: T = 1; a prefill chunk
+    a row): (hidden [B, T, D], kv_k', kv_v', expert counts, state'). Row
     ``i`` runs from and writes back slot ``state_rows[i]`` of the state pool
     (None: slot ``i``, the decode programs; a slot out of range is a pad
     row's and is dropped)."""
-    del mesh, adapter_ids, qmm_impl  # one path; the engine refuses the rest
     w = params["layers"]
     rows = (jnp.arange(tokens.shape[0], dtype=jnp.int32) if state_rows is None
             else state_rows)
@@ -725,27 +697,23 @@ def forward_counted(params, cfg, tokens, positions, kv_k, kv_v, page_tables,
             state = _put_row_state(state, mi, rows, new)
         return ssm_output(y, z, w, mi, cfg), state
 
-    h, kv_k, kv_v, counts, state = _forward_hidden(
+    return _forward_hidden(
         params, cfg, tokens, positions, kv_k, kv_v, page_tables, ctx_lens,
         page_size, block_pages, state, ssm_mixer, attn_impl=attn_impl)
-    return _head(params, cfg, h), kv_k, kv_v, counts, state
 
 
-def forward_ragged_counted(params, cfg, tokens, positions, row_ids, kv_k, kv_v,
-                           page_tables, ctx_lens, sel_idx, page_size,
-                           block_pages=32, attn_impl="xla", mesh=None,
-                           adapter_ids=None, qmm_impl="xla", ragged_block=8, *,
-                           state, state_rows):
-    """The mixed prefill+decode forward over one flat ragged batch,
-    llama.py's layout: (logits [S, vocab] f32, kv_k', kv_v', expert counts,
-    state'). As ``qwen3_next.forward_ragged_counted``: the projections, the
-    expert layers and the page writes run over the flat buffer; both mixers
-    run it by SEGMENT — the decode tokens as one-token rows of every slot
-    (the Mamba rule over the live ones, in place), and each FILLED prefill
-    row's chunk gathered into a run of its own, a Mamba layer from the state
-    of its slot (``state_rows[row]``) and written back to it, an attention
+def hidden_ragged(params, cfg, tokens, positions, row_ids, kv_k, kv_v,
+                  page_tables, ctx_lens, page_size, block_pages, ragged_block,
+                  attn_impl="xla", *, state, state_rows):
+    """The stack over the mixed step's flat ragged batch, llama.py's layout:
+    (hidden [N / ragged_block, ragged_block, D], kv_k', kv_v', expert counts,
+    state'). As ``qwen3_next.hidden_ragged``: the projections, the expert
+    layers and the page writes run over the flat buffer; both mixers run it
+    by SEGMENT — the decode tokens as one-token rows of every slot (the
+    Mamba rule over the live ones, in place), and each FILLED prefill row's
+    chunk gathered into a run of its own, a Mamba layer from the state of
+    its slot (``state_rows[row]``) and written back to it, an attention
     layer over its own page table."""
-    del mesh, adapter_ids, qmm_impl
     n = tokens.shape[0]
     rq = ragged_block
     nb = n // rq
@@ -820,21 +788,14 @@ def forward_ragged_counted(params, cfg, tokens, positions, row_ids, kv_k, kv_v,
         attn = jax.lax.fori_loop(0, rows_filled, prefill_row, attn)
         return attention_output(attn[:n], w, ai).reshape(nb, rq, -1), kv_k, kv_v
 
-    h, kv_k, kv_v, counts, state = _forward_hidden(
+    return _forward_hidden(
         params, cfg, tokens.reshape(nb, rq), block_pos, kv_k, kv_v, block_tables,
         ctx_lens[block_rows], page_size, block_pages, state, ssm_mixer, attn_mixer)
-    h_sel = h.reshape(n, h.shape[-1])[sel_idx]
-    return _head(params, cfg, h_sel), kv_k, kv_v, counts, state
 
 
-def forward_impl(params: Params, cfg: NemotronHConfig, tokens, positions, kv_k,
-                 kv_v, page_tables, ctx_lens, page_size: int,
-                 block_pages: int = 32, attn_impl: str = "xla", mesh=None,
-                 adapter_ids: Optional[jnp.ndarray] = None,
-                 qmm_impl: str = "xla", *, state, state_rows=None):
-    """:func:`forward_counted` without the counts: (logits, kv_k', kv_v',
-    state')."""
-    logits, kv_k, kv_v, _, state = forward_counted(
-        params, cfg, tokens, positions, kv_k, kv_v, page_tables, ctx_lens,
-        page_size, block_pages, attn_impl, state=state, state_rows=state_rows)
-    return logits, kv_k, kv_v, state
+# The step programs' pair (``NemotronHConfig.forwards``): the family's own
+# ragged body.
+forward_counted, forward_ragged_counted = serving_forwards(hidden_chunk, hidden_ragged)
+# One forward chunk, the serving signature and result: (logits, kv_k', kv_v',
+# expert counts, state', None).
+forward_impl = forward_counted

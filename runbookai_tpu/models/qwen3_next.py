@@ -44,12 +44,13 @@ every pick; this chip computes its own experts' part and the shared expert
 for its tokens, and what the absent experts would add is left out — no code
 stands in for the other chips or their exchange.
 
-The serving contract is :mod:`runbookai_tpu.models.llama`'s with two more
-keywords, ``state`` (the state pool) and ``state_rows`` (which slot each row
-of the call is; None: row ``i`` is slot ``i``), and one more result, the
-pool as the call left it. One ``lax.scan`` over PERIODS, the four layers of
+The serving contract is :mod:`runbookai_tpu.models.family`'s
+(``serving_forwards``) with its two keywords ``state`` (the state pool) and
+``state_rows`` (which slot each row of the call is; None: row ``i`` is slot
+``i``) in use, and the pool as the call left it in the result. One
+``lax.scan`` over PERIODS, the four layers of
 a period unrolled in its body; every leaf is indexed where it is used
-(:mod:`runbookai_tpu.models.longcat` says why), and the two pools ride the
+(``models/longcat.py`` says why), and the two pools ride the
 scan's carry and are written in place.
 """
 
@@ -57,17 +58,26 @@ from __future__ import annotations
 
 import math
 from dataclasses import dataclass
-from typing import Any, Optional
+from typing import Any
 
 import jax
 import jax.numpy as jnp
 
-from runbookai_tpu.models.longcat import EXPERT_COUNTS, _stacked_normal
+from runbookai_tpu.models.family import (
+    EXPERT_COUNTS,
+    NO_ROLLBACK,
+    Family,
+    Params,
+    _stacked_normal,
+    register,
+    serving_forwards,
+)
 from runbookai_tpu.ops.attention import (
     paged_attention,
     pool_rows,
     write_kv_pages_batch,
 )
+from runbookai_tpu.ops.dense import qmm, rms_norm
 from runbookai_tpu.ops.gated_delta import (
     BLOCK,
     causal_conv_tail,
@@ -84,11 +94,9 @@ from runbookai_tpu.ops.moe import (
 )
 from runbookai_tpu.ops.rope import apply_rope
 
-Params = dict[str, Any]
-
 
 @dataclass(frozen=True)
-class Qwen3NextConfig:
+class Qwen3NextConfig(Family):
     name: str
     vocab_size: int
     hidden_size: int
@@ -123,13 +131,17 @@ class Qwen3NextConfig:
     state_snapshots: int = 16
     family: str = "qwen2"  # the chat template: the family renders ChatML
 
-    tie_embeddings = False
     # ``attn_impl="pallas"`` is the Pallas decode walk over the paged pool
     # for the one-token rows (2 kv heads of 256, a group of 8 query rows a
     # head: :func:`attend`); a prefill run keeps XLA's one-row walk, so the
     # engine probes no chunk kernel for this family.
-    pallas_attention = True
     pallas_prefill = False
+    one_path = True
+    no_prompt_lookup = f"prompt-lookup speculation ({NO_ROLLBACK})"
+    no_draft_model = f"draft-model speculation ({NO_ROLLBACK})"
+    family_name = "qwen3-next"
+    hf_model_types = ("qwen3_next",)
+    checkpoint_tensors = "linear-attention and expert tensor names"
 
     @property
     def dim(self) -> int:
@@ -189,35 +201,10 @@ class Qwen3NextConfig:
                   self.conv_channels), jnp.float32))
 
     def forwards(self):
-        """(forward, ragged forward) as the engine's step programs call
-        them, returning ``(logits, kv_k, kv_v, expert counts, state)``."""
         return forward_counted, forward_ragged_counted
 
-    def unsupported(self, *, lora: bool, model_axis: int, seq_axis: int,
-                    kv_dtype, quantized: bool, speculative: bool = False,
-                    draft: bool = False) -> list[str]:
-        """What this family's forward does not do yet, of what the engine
-        was asked for — refused by name at engine init, never served
-        wrong."""
-        no = []
-        if speculative:
-            no.append("prompt-lookup speculation (a rejected draft would "
-                      "need the recurrent state rolled back)")
-        if draft:
-            no.append("draft-model speculation (a rejected draft would "
-                      "need the recurrent state rolled back)")
-        if lora:
-            no.append("LoRA adapters")
-        if model_axis > 1:
-            no.append(f"a model axis of {model_axis} (tensor/expert "
-                      f"parallelism across chips)")
-        if seq_axis > 1:
-            no.append("the KV page-split (seq) mesh axis")
-        if jnp.dtype(kv_dtype) == jnp.int8:
-            no.append("an int8 KV pool (per-token scales)")
-        if quantized:
-            no.append("int8 weight-only matrices")
-        return no
+    def init_params(self, key, dtype=jnp.bfloat16, quantized=False) -> Params:
+        return init_params(key, self, dtype)
 
     # ---- counts (the memory plan's and the MFU model's) ----------------
 
@@ -275,7 +262,7 @@ _PUBLISHED = dict(
     moe_intermediate_size=512, shared_expert_intermediate_size=512,
     num_experts=512, num_experts_per_tok=10)
 
-CONFIGS: dict[str, Qwen3NextConfig] = {
+CONFIGS: dict[str, Qwen3NextConfig] = register({
     # The published model (config.json): 48 layers, every expert held. 80B
     # parameters: no single process of this repo holds it; it is the entry
     # a cut configuration is checked against.
@@ -301,7 +288,7 @@ CONFIGS: dict[str, Qwen3NextConfig] = {
         num_experts=32, num_experts_per_tok=4, n_experts_held=8,
         first_expert=8, rope_theta=10_000.0, max_position_embeddings=8192,
         intermediate_size=128, state_snapshots=4),
-}
+})
 
 
 def leaf_shapes(cfg: Qwen3NextConfig) -> dict[str, tuple[tuple[int, ...], int]]:
@@ -414,8 +401,6 @@ def moe_block(u: jnp.ndarray, live: jnp.ndarray, w: dict, li,
 
 
 def _norm1(x, weight, eps):
-    from runbookai_tpu.models.llama import rms_norm  # deferred: cycle
-
     return rms_norm(x, 1.0 + weight, eps)
 
 
@@ -423,8 +408,6 @@ def attention_inputs(x, w, pi, cfg, positions):
     """The full-attention mixer's projections over ``x`` [B, T, D]: (q after
     its norm and rotary [B, T, H, hd], the output gate [B, T, H, hd], k
     likewise [B, T, KV, hd], v [B, T, KV, hd])."""
-    from runbookai_tpu.models.llama import qmm
-
     b, t, _ = x.shape
     n_h, n_kv, hd = cfg.num_attention_heads, cfg.num_key_value_heads, cfg.head_dim
     rot = int(hd * cfg.partial_rotary_factor)
@@ -490,8 +473,6 @@ def attend(q, pi, kv_k, kv_v, page_tables, ctx_lens, positions, page_size,
 
 def attention_output(attn, gate, w, pi):
     """``(attn * sigmoid(gate)) Wo``."""
-    from runbookai_tpu.models.llama import qmm
-
     attn = attn * jax.nn.sigmoid(gate.astype(jnp.float32)).astype(attn.dtype)
     return qmm(attn.reshape(*attn.shape[:-2], -1), w["wo"][pi])
 
@@ -559,8 +540,6 @@ def gdn_recur(mixed, ba, live, w, li, cfg, s_rows, tail_rows):
 
 def gdn_output(o, z, w, li, cfg):
     """``RMSNorm(o; w) * SiLU(z)`` per value head, then ``Wout``."""
-    from runbookai_tpu.models.llama import qmm, rms_norm
-
     lead = z.shape[:-1]
     zh = z.reshape(*lead, cfg.linear_num_value_heads, cfg.linear_value_head_dim)
     gated = (rms_norm(o, w["g_norm"][li], cfg.rms_norm_eps)
@@ -638,16 +617,14 @@ def _row_state(state, li, rows):
     return tuple(a[li, jnp.clip(rows, 0, a.shape[1] - 1)] for a in state)
 
 
-def forward_counted(params, cfg, tokens, positions, kv_k, kv_v, page_tables,
-                    ctx_lens, page_size, block_pages=32, attn_impl="xla",
-                    mesh=None, adapter_ids=None, qmm_impl="xla", *, state,
-                    state_rows=None):
-    """One forward chunk ``[B, T]`` (decode: T = 1; a prefill chunk a row):
-    (logits [B, T, vocab] f32, kv_k', kv_v', expert counts, state'). Row
+def hidden_chunk(params, cfg, tokens, positions, kv_k, kv_v, page_tables,
+                 ctx_lens, page_size, block_pages, attn_impl="xla", *, state,
+                 state_rows=None):
+    """The stack over one chunk ``[B, T]`` (decode: T = 1; a prefill chunk
+    a row): (hidden [B, T, D], kv_k', kv_v', expert counts, state'). Row
     ``i`` runs from and writes back slot ``state_rows[i]`` of the state pool
     (None: slot ``i``, the decode programs; a slot out of range is a pad
     row's and is dropped)."""
-    del mesh, adapter_ids, qmm_impl  # one path; the engine refuses the rest
     w = params["layers"]
 
     def linear_mixer(x, live, li, state):
@@ -662,30 +639,26 @@ def forward_counted(params, cfg, tokens, positions, kv_k, kv_v, page_tables,
                           for a, n in zip(state, new))
         return gdn_output(o, z, w, li, cfg), state
 
-    h, kv_k, kv_v, counts, state = _forward_hidden(
+    return _forward_hidden(
         params, cfg, tokens, positions, kv_k, kv_v, page_tables, ctx_lens,
         page_size, block_pages, state, linear_mixer, attn_impl=attn_impl)
-    return _head(params, cfg, h), kv_k, kv_v, counts, state
 
 
-def forward_ragged_counted(params, cfg, tokens, positions, row_ids, kv_k, kv_v,
-                           page_tables, ctx_lens, sel_idx, page_size,
-                           block_pages=32, attn_impl="xla", mesh=None,
-                           adapter_ids=None, qmm_impl="xla", ragged_block=8, *,
-                           state, state_rows):
-    """The mixed prefill+decode forward over one flat ragged batch,
-    llama.py's layout: (logits [S, vocab] f32, kv_k', kv_v', expert counts,
+def hidden_ragged(params, cfg, tokens, positions, row_ids, kv_k, kv_v,
+                  page_tables, ctx_lens, page_size, block_pages, ragged_block,
+                  attn_impl="xla", *, state, state_rows):
+    """The stack over the mixed step's flat ragged batch, llama.py's layout:
+    (hidden [N / ragged_block, ragged_block, D], kv_k', kv_v', expert counts,
     state'). The buffer is the engine's: one block of ``ragged_block``
     tokens a decode slot first (slot ``s``'s token at ``s * ragged_block``),
     then the prefill rows' chunks, each from a multiple of ``ragged_block``.
     The projections, the expert layers and the page writes run over the
     flat buffer (as ``[N / ragged_block, ragged_block]`` with per-block
-    gathered tables, llama.py's layout); both mixers run it by SEGMENT: the
-    decode tokens as one-token rows of every slot, and each FILLED prefill
-    row's chunk gathered into a run of its own — a linear layer from the
-    state of its slot (``state_rows[row]``) and written back to it, a
-    full-attention layer over its own page table."""
-    del mesh, adapter_ids, qmm_impl
+    gathered tables, ``family.serving_forwards``' layout); both mixers run
+    it by SEGMENT: the decode tokens as one-token rows of every slot, and
+    each FILLED prefill row's chunk gathered into a run of its own — a
+    linear layer from the state of its slot (``state_rows[row]``) and
+    written back to it, a full-attention layer over its own page table."""
     n = tokens.shape[0]
     rq = ragged_block
     nb = n // rq
@@ -770,22 +743,16 @@ def forward_ragged_counted(params, cfg, tokens, positions, row_ids, kv_k, kv_v,
         out = attention_output(attn[:n], gate.reshape(n, *gate.shape[2:]), w, pi)
         return out.reshape(nb, rq, -1), kv_k, kv_v
 
-    h, kv_k, kv_v, counts, state = _forward_hidden(
+    return _forward_hidden(
         params, cfg, tokens.reshape(nb, rq), block_pos, kv_k, kv_v, block_tables,
         ctx_lens[block_rows], page_size, block_pages, state, linear_mixer,
         full_mixer)
-    h_sel = h.reshape(n, h.shape[-1])[sel_idx]
-    return _head(params, cfg, h_sel), kv_k, kv_v, counts, state
 
 
-def forward_impl(params: Params, cfg: Qwen3NextConfig, tokens, positions, kv_k,
-                 kv_v, page_tables, ctx_lens, page_size: int,
-                 block_pages: int = 32, attn_impl: str = "xla", mesh=None,
-                 adapter_ids: Optional[jnp.ndarray] = None,
-                 qmm_impl: str = "xla", *, state, state_rows=None):
-    """:func:`forward_counted` without the counts: (logits, kv_k', kv_v',
-    state')."""
-    logits, kv_k, kv_v, _, state = forward_counted(
-        params, cfg, tokens, positions, kv_k, kv_v, page_tables, ctx_lens,
-        page_size, block_pages, attn_impl, state=state, state_rows=state_rows)
-    return logits, kv_k, kv_v, state
+# The step programs' pair (``Qwen3NextConfig.forwards``): the family's own
+# ragged body and its own head (the zero-centred norm).
+forward_counted, forward_ragged_counted = serving_forwards(
+    hidden_chunk, hidden_ragged, head=_head)
+# One forward chunk, the serving signature and result: (logits, kv_k', kv_v',
+# expert counts, state', None).
+forward_impl = forward_counted

@@ -36,6 +36,8 @@ from typing import Any, Optional
 import jax
 import jax.numpy as jnp
 
+from runbookai_tpu.ops.dense import qmm
+
 
 def expert_capacity(n_tokens: int, n_experts: int, top_k: int,
                     capacity_factor: float) -> int:
@@ -65,8 +67,6 @@ def moe_ffn(
     n = b * t
     cap = expert_capacity(n, e, top_k, capacity_factor)
     x = y.reshape(n, d)
-
-    from runbookai_tpu.models.llama import qmm  # deferred: models->ops cycle
 
     logits = (x.astype(jnp.float32) @ router.astype(jnp.float32))  # [N, E]
     probs = jax.nn.softmax(logits, axis=-1)
@@ -162,8 +162,6 @@ def expert_ffn(x: jnp.ndarray, w_gate: Any, w_up: Any, w_down: Any) -> jnp.ndarr
     """One expert's two forms: ``SwiGLU`` — ``(silu(x W_gate) * x W_up)
     W_down`` — or, where the model's experts have two matrices (``w_gate``
     None), ``relu(x W_up)^2 W_down``. Leading axes batch (``qmm``)."""
-    from runbookai_tpu.models.llama import qmm  # deferred: models->ops cycle
-
     if w_gate is None:
         with jax.named_scope("moe.relu2"):
             return qmm(jnp.square(jax.nn.relu(qmm(x, w_up))), w_down)

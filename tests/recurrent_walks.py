@@ -50,7 +50,7 @@ def check_decode_pass_and_mixed_step(module, cfg, params, pools, ids, atol):
     for r, s in enumerate((3, 1)):
         n = len(prompts[s])
         tokens[r, :n], positions[r, :n] = prompts[s], np.arange(n)
-    _, kv_k, kv_v, _, state = jax.jit(
+    _, kv_k, kv_v, _, state, _ = jax.jit(
         lambda p, *a: module.forward_counted(p, cfg, *a, PS, 2, "xla", state=state,
                                              state_rows=jnp.asarray([3, 1])))(
         params, jnp.asarray(tokens), jnp.asarray(positions), kv_k, kv_v,

@@ -132,7 +132,7 @@ def test_int8_random_init_never_holds_a_wide_copy(monkeypatch):
     def wide(*args, **kwargs):
         raise AssertionError("a full-width tree was built")
 
-    monkeypatch.setattr(hf_loader, "init_params", wide)
+    monkeypatch.setattr("runbookai_tpu.models.llama.init_params", wide)
     monkeypatch.setattr("runbookai_tpu.models.quant.quantize_params", wide)
     cfg, params = hf_loader.load_or_init("qwen2-test", None,
                                          quantize_int8=True)

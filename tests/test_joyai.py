@@ -143,9 +143,9 @@ def test_prefill_through_the_latent_pages_matches_the_reference(params, chunks):
     got, hidden, lo = [], [], 0
     for n in (*chunks, 1):  # the last: a decode step's shape
         at = jnp.arange(lo, lo + n, dtype=jnp.int32)[None]
-        logits, kv_k, kv_v, counts, h = joyai.forward_counted(
+        logits, kv_k, kv_v, counts, _, h = joyai.forward_counted(
             params, CFG, jnp.asarray([ids[lo:lo + n]], jnp.int32), at, kv_k, kv_v,
-            tables, jnp.asarray([lo + n]), page_size=PS, block_pages=2, hidden_out=True)
+            tables, jnp.asarray([lo + n]), page_size=PS, block_pages=2)
         # two expert layers of the trunk: every pick is held or absent
         assert int(counts[0] + counts[2]) == n * CFG.num_experts_per_tok * 2
         got.append(np.asarray(logits[0]))

@@ -185,7 +185,7 @@ def test_the_convolution_carries_its_last_inputs():
 def test_one_full_prefill_matches_the_reference(params):
     ids = _ids(70)
     kv_k, kv_v, state = _pools()
-    logits, _, _, state = qwen3_next.forward_impl(
+    logits, _, _, _, state, _ = qwen3_next.forward_impl(
         params, CFG, jnp.asarray([ids], jnp.int32),
         jnp.arange(70, dtype=jnp.int32)[None], kv_k, kv_v,
         jnp.arange(1, 9, dtype=jnp.int32)[None], jnp.asarray([70]),
